@@ -8,7 +8,8 @@ over the same compiled step — per-example cost is identical, so the raw
 step is the honest unit).
 
 Knobs (env): ``BENCH_BERT_BATCH`` per-chip batch (default 16),
-``BENCH_BERT_SEQ`` (default 512).  Prints one JSON line like bench.py.
+``BENCH_BERT_SEQ`` (default 512).  Prints one JSON line like bench.py;
+exits non-zero without a TPU.
 """
 
 from __future__ import annotations
@@ -17,23 +18,13 @@ import json
 import os
 import time
 
-from bench_probe import probe_devices_with_retries
-from bench_probe import enable_compile_cache
+import jax
+import numpy as np
 
-enable_compile_cache()
-
-if not probe_devices_with_retries("bench_bert"):
-    raise SystemExit(2)
-
-import jax  # noqa: E402
-import numpy as np  # noqa: E402
-
-if os.environ.get("BENCH_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
-
-
+from bench_common import persist_result, start
 
 def main() -> None:
+    start("bench_bert")
     from distributedtensorflow_tpu.data import InputContext, device_put_batch
     from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
     from distributedtensorflow_tpu.train import create_sharded_state, make_train_step
@@ -77,7 +68,8 @@ def main() -> None:
 
     compiled = step.lower(state, batch, rng).compile()
     n_steps = -(-20 // inner)
-    from bench_probe import timed_steps, mfu_fields
+    from bench_common import timed_steps
+    from distributedtensorflow_tpu.obs.mfu import mfu_fields
 
     state, dt = timed_steps(compiled, state, batch, rng,
                             n_steps=n_steps, warmup=max(1, 3 // inner))
@@ -136,9 +128,7 @@ def main() -> None:
     flash_thresh = os.environ.get("DTF_MIN_SEQ_FOR_PALLAS")
     if flash_thresh:
         result["min_seq_for_pallas"] = int(flash_thresh)
-    from bench_probe import is_tpu_platform, persist_result
-
-    if is_tpu_platform(result["platform"]) and not test_size:
+    if not test_size:
         persist_result("bertab" if flash_thresh else "bert", result)
     print(json.dumps(result))
 

@@ -46,9 +46,9 @@ import optax  # noqa: E402
 
 
 def main() -> None:
-    from bench_probe import enable_compile_cache
+    from distributedtensorflow_tpu import runtime
 
-    enable_compile_cache()
+    runtime.init_compile_cache()
     from distributedtensorflow_tpu.models.gpt import (
         GPTConfig,
         GPTLM,
@@ -287,7 +287,7 @@ def main() -> None:
         ),
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }
-    from bench_probe import persist_result
+    from bench_common import persist_result
 
     if not test:
         persist_result("pipeline", result)

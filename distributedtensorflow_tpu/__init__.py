@@ -10,7 +10,6 @@ pipeline, sequence (ring attention / Ulysses) and expert parallelism.
 
 __version__ = "0.1.0"
 
-from .utils import jax_compat  # noqa: F401  (shims for this image's jax)
 from . import obs  # noqa: F401  (telemetry first: everything writes to it)
 from . import parallel  # noqa: F401
 from . import strategies  # noqa: F401

@@ -301,12 +301,13 @@ class CheckpointManager:
                              reason=str(e)[:300])
             raise
 
-    def item_metadata(self, step: int):
+    def item_metadata(self, step: int) -> dict:
         """Array metadata (shapes/dtypes, no tensor I/O) of a saved step's
-        tree — the probe :func:`~..parallel.zero.saved_opt_layout` uses to
-        detect which ZeRO degree a checkpoint's optimizer state was saved
-        at before building a restore target."""
-        return self._mgr.item_metadata(step)
+        tree, as the nested dict orbax's tree metadata holds — the probe
+        :func:`~..parallel.zero.saved_opt_layout` uses to detect which
+        ZeRO degree a checkpoint's optimizer state was saved at before
+        building a restore target."""
+        return self._mgr.item_metadata(step).tree
 
     def latest_step(self) -> int | None:
         steps = self.all_steps()

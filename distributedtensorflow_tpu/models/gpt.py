@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import LayoutMap
+from ..runtime import on_tpu
 from .layers import FusedLayerNorm, dense, sow_nonfinite
 
 AttnFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
@@ -510,9 +511,7 @@ def _pick_xent(cfg: GPTConfig):
     "fused" (Pallas, logits never leave VMEM)."""
     impl = cfg.xent_impl
     if impl == "auto":
-        from ..ops.flash_attention import _on_tpu
-
-        impl = "fused" if _on_tpu() else "chunked"
+        impl = "fused" if on_tpu() else "chunked"
     if impl == "fused":
         from ..ops.fused_xent import fused_softmax_xent
 

@@ -180,9 +180,8 @@ class PipelinedGPT:
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype, name="wte"
         )
         # Manual Megatron tensor parallelism: the pipeline region is
-        # FULL-manual shard_map (this jax's partial-manual lowering
-        # hard-aborts — see apply()), so GSPMD cannot partition the stage
-        # kernels inside it.  The stage block instead runs with per-shard
+        # FULL-manual shard_map (see apply() for why), so GSPMD cannot
+        # partition the stage kernels inside it.  The stage block instead runs with per-shard
         # head counts / MLP width and an explicit row-parallel psum over
         # ``model`` (reduce_fn), against kernels sliced by the region's
         # in_specs.
@@ -604,10 +603,10 @@ class PipelinedGPT:
         x = self._embed.apply({"params": params["wte"]}, input_ids)
 
         # FULL-manual shard_map: every mesh axis is manual inside the
-        # region.  This jax's (0.4.37) partial-manual lowering goes
-        # through `PartitionId`, which XLA's SPMD partitioner rejects
-        # outright ("meaning is ambiguous"), and the grad path hard-aborts
-        # on `IsManualSubgroup` — probed by tests/test_jax_workarounds.py.
+        # region.  Written around two partial-manual lowering failures of
+        # an earlier jax (a `PartitionId` the SPMD partitioner rejected,
+        # an `IsManualSubgroup` abort under grad) that the installed jax
+        # no longer has — the rewrite is ROADMAP D9.
         # Full-manual sidesteps the partitioner entirely: the batch is
         # manually sharded over the data axes, the stage kernels are
         # manually sliced over ``model`` with the block running per-shard

@@ -2,15 +2,21 @@
 
 Build-on-demand keeps the no-network constraint honest: the .so is compiled
 from the in-repo C++ sources with the system g++, never downloaded.  The
-build is cheap (<5s) and happens at most once per checkout; concurrent
-builders (e.g. pytest-xdist, multi-process tests) are serialized with an
-exclusive lock file.
+build is cheap (<5s) and happens at most once per state of the sources;
+concurrent builders (e.g. pytest-xdist, multi-process tests) are serialized
+with an exclusive lock file.
+
+Whether a built .so is current is decided by CONTENT: a sidecar file holds
+the hash of the sources it was compiled from.  File times say nothing once
+a tree has been copied (the chip tool copies ignored files too, so a .so
+built here from other sources would otherwise ride along and win).
 """
 
 from __future__ import annotations
 
 import ctypes
 import fcntl
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,15 +38,27 @@ def _lib_path() -> Path:
     return _NATIVE_DIR / "libdtf_native.so"
 
 
+def _sources_hash() -> str:
+    h = hashlib.sha256()
+    for rel in _SOURCES + ("src/crc32c.h",):
+        h.update(rel.encode())
+        h.update((_NATIVE_DIR / rel).read_bytes())
+    return h.hexdigest()
+
+
+def _hash_path(so: Path) -> Path:
+    return so.with_suffix(".so.srchash")
+
+
 def _needs_build(so: Path) -> bool:
     if not so.exists():
         return True
-    so_mtime = so.stat().st_mtime
-    for rel in _SOURCES + ("src/crc32c.h",):
-        src = _NATIVE_DIR / rel
-        if src.exists() and src.stat().st_mtime > so_mtime:
-            return True
-    return False
+    if os.environ.get("DTF_NATIVE_LIB"):
+        return False  # a prebuilt library named from outside is used as is
+    try:
+        return _hash_path(so).read_text().strip() != _sources_hash()
+    except FileNotFoundError:
+        return True
 
 
 def build_native_library(force: bool = False) -> Path:
@@ -72,7 +90,11 @@ def build_native_library(force: bool = False) -> Path:
             ]
             logger.info("building native library: %s", " ".join(cmd))
             subprocess.run(cmd, check=True, capture_output=True, text=True)
+            # invalidate first: a crash between the two renames must leave
+            # "stale", never a new hash beside an old library
+            _hash_path(so).unlink(missing_ok=True)
             os.replace(tmp, so)
+            _hash_path(so).write_text(_sources_hash() + "\n")
         except subprocess.CalledProcessError as e:
             raise RuntimeError(
                 f"native build failed:\n{e.stderr}"

@@ -288,7 +288,7 @@ def set_train_state_bytes(report: dict | None,
 def train_state_record_fields() -> dict[str, float]:
     """Flat scalars for the metric record: the WORST (max) per-device
     bytes of params and optimizer state, plus the ZeRO annotations —
-    what run_report and bench_probe surface so a sharding win is a
+    what run_report and the bench rows surface so a sharding win is a
     number, not an assertion."""
     rep = _TRAIN_STATE_BYTES
     if not rep:

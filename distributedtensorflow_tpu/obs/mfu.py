@@ -1,26 +1,18 @@
-"""MFU fields for the live metric stream.
+"""MFU accounting: the device peaks table and the fields built on it.
 
-Reuses ``bench_probe.mfu_fields`` (the repo's one MFU accounting — analytic
-model FLOPs over device peak) when the repo root is importable, so the
-Trainer's per-step ``mfu`` and the bench suite's ``mfu`` can never diverge;
-falls back to the same arithmetic with the local peak table otherwise.
-The repo-root imports are resolved ONCE and cached (a failed import is not
-cached by sys.modules, and this runs at every log boundary).
-Only numeric fields are returned (the ``metrics.jsonl`` writer is
-numbers-only; ``mfu_analytic_source`` stays in the bench JSON world).
+One table (:data:`DEVICE_PEAKS`) holds the published per-chip peaks, keyed
+by ``jax.Device.device_kind``.  A kind that is not in it is an error for
+the bench scripts (:func:`device_peaks` raises) and means *no* ``mfu*``
+field in the Trainer's metric stream (:func:`mfu_record_fields` returns
+``{}``) — a utilization against some other chip's peak is never emitted.
 
-FLOP-counting convention (the 2× reconciliation, BENCH_r02): BOTH
-estimators count one multiply-add as **2 FLOPs** — XLA's
-``cost_analysis()["flops"]`` reports exactly ``2·M·N·K`` for an
-``(M,K)×(K,N)`` matmul (:func:`matmul_flops`, pinned by
+FLOP-counting convention: BOTH estimators count one multiply-add as
+**2 FLOPs** — XLA's ``cost_analysis()["flops"]`` reports exactly
+``2·M·N·K`` for an ``(M,K)×(K,N)`` matmul (:func:`matmul_flops`, pinned by
 ``tests/test_mfu.py``), so any analytic ``flops_per_step`` fed into these
-fields must use the same MACs×2 convention.  The historical 0.16-vs-0.32
-ResNet-50 disagreement was an analytic constant (bench.py
-``RESNET50_TRAIN_FLOPS_PER_IMAGE``) that passed a MAC count where a FLOP
-count was owed; with both sides on MACs×2 the two paths agree within the
-cost model's coarseness (see ``bench_probe.mfu_fields`` for the one
-legitimate residual: a ``lax.scan`` body is counted once regardless of
-trip count — callers pass ``xla_flops_scale``).
+fields must use the same MACs×2 convention.  The one legitimate residual
+between the two: a ``lax.scan`` body is counted once regardless of trip
+count — callers pass ``xla_flops_scale`` (see :func:`mfu_fields`).
 """
 
 from __future__ import annotations
@@ -29,53 +21,43 @@ import logging
 
 logger = logging.getLogger("distributedtensorflow_tpu")
 
-__all__ = ["matmul_flops", "mfu_record_fields", "peak_flops",
+__all__ = ["DEVICE_PEAKS", "device_peaks", "matmul_flops", "mfu_fields",
+           "mfu_record_fields", "peak_flops", "peak_hbm_bytes_per_s",
            "xla_cost_analysis", "xla_cost_flops"]
 
-#: bench.py's PEAK_FLOPS_BY_KIND, duplicated as the in-package fallback for
-#: deployments where the repo root (bench.py) is not on sys.path.
-_PEAK_FLOPS_BY_KIND = {
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v3": 123e12,
+#: Published per-chip peaks by ``device_kind``: dense bf16 FLOP/s and HBM
+#: bytes/s.  Source: Google Cloud TPU documentation, the "System
+#: architecture" page of each generation ("TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM2e; "TPU v4": 275 TFLOP/s, 1,228 GB/s; "TPU v3":
+#: 123 TFLOP/s, 900 GB/s).  ``"TPU v5 lite"`` is how JAX names a v5e chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v4": {"flops": 275e12, "hbm_bytes_per_s": 1228e9},
+    "TPU v3": {"flops": 123e12, "hbm_bytes_per_s": 900e9},
 }
-_DEFAULT_PEAK = 197e12
-
-_UNRESOLVED = object()
-_bench_peak_flops = _UNRESOLVED  # bench._peak_flops | None
-_bench_mfu_fields = _UNRESOLVED  # bench_probe.mfu_fields | None
 
 
-def _resolve_bench() -> None:
-    global _bench_peak_flops, _bench_mfu_fields
-    if _bench_peak_flops is _UNRESOLVED:
-        try:
-            from bench import _peak_flops  # noqa: PLC0415 — repo-root module
-
-            _bench_peak_flops = _peak_flops
-        except Exception:
-            _bench_peak_flops = None
-    if _bench_mfu_fields is _UNRESOLVED:
-        try:
-            from bench_probe import mfu_fields  # noqa: PLC0415
-
-            _bench_mfu_fields = mfu_fields
-        except Exception:
-            _bench_mfu_fields = None
+def device_peaks(device_kind: str) -> dict:
+    """The :data:`DEVICE_PEAKS` row for ``device_kind``; an unknown kind
+    raises — there is no default chip."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)} (add the kind to obs.mfu.DEVICE_PEAKS "
+            "with its source)"
+        ) from None
 
 
 def peak_flops(device_kind: str) -> float:
-    """Peak dense bf16 FLOP/s for a device kind (bench.py table)."""
-    _resolve_bench()
-    if _bench_peak_flops is not None:
-        return _bench_peak_flops(device_kind)
-    kind = device_kind.lower()
-    for sub, peak in _PEAK_FLOPS_BY_KIND.items():
-        if sub in kind:
-            return peak
-    return _DEFAULT_PEAK
+    """Peak dense bf16 FLOP/s of one chip of ``device_kind``."""
+    return device_peaks(device_kind)["flops"]
+
+
+def peak_hbm_bytes_per_s(device_kind: str) -> float:
+    """Peak HBM bandwidth of one chip of ``device_kind``."""
+    return device_peaks(device_kind)["hbm_bytes_per_s"]
 
 
 def matmul_flops(m: int, n: int, k: int) -> float:
@@ -86,20 +68,13 @@ def matmul_flops(m: int, n: int, k: int) -> float:
 
 
 def xla_cost_analysis(compiled) -> dict | None:
-    """One best-effort ``cost_analysis()`` call, normalized to a single
-    dict: older jax (0.4.37) returns a LIST of per-device dicts — the
-    first device's is returned so every consumer sees one shape; None
-    when the backend can't answer.  THE one implementation of this
-    normalization (``bench_probe.compiled_cost`` delegates here) so the
-    analytic and xla-cost MFU paths cannot drift apart again on a jax
-    return-shape change."""
+    """One best-effort ``cost_analysis()`` call: the executable's cost
+    dict, or None when the backend can't answer."""
     try:
         cost = compiled.cost_analysis()
     except Exception as e:
         logger.info("xla cost analysis unavailable (%s)", e)
         return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     return cost or None
 
 
@@ -113,6 +88,41 @@ def xla_cost_flops(compiled) -> float | None:
     return float(cost["flops"])
 
 
+def mfu_fields(compiled, dt: float, n_steps: int, device_kind: str,
+               analytic_flops_per_step: float,
+               analytic_source: str, xla_flops_scale: float = 1.0,
+               cost: dict | None = None) -> dict:
+    """Both MFU accountings for a bench result, as emit-ready fields.
+
+    ``mfu_analytic`` divides ANALYTIC per-chip model FLOPs (6·N·D-style,
+    fixed by the model config, independent of the implementation) by peak —
+    the stable round-over-round number, and what ``mfu`` aliases.
+    ``mfu_xla_cost`` divides XLA's partitioned-module cost analysis by peak
+    — it tracks what the compiled program actually executes, so it MOVES
+    when the implementation changes (e.g. the vocab-chunked CE head raised
+    throughput while lowering executed FLOPs).
+
+    ``xla_flops_scale``: XLA's cost analysis counts a ``lax.scan`` body
+    ONCE regardless of trip count, so a k-steps-per-dispatch executable
+    (engine.make_multi_train_step) under-reports executed FLOPs by ~k;
+    callers bundling k steps per call pass ``xla_flops_scale=k``.
+    ``cost={}`` skips the cost analysis (no AOT executable at hand).
+    Raises on a ``device_kind`` without published peaks."""
+    peak = peak_flops(device_kind)
+    xla_mfu = None
+    if cost is None:
+        cost = xla_cost_analysis(compiled)
+    if cost and cost.get("flops"):
+        xla_mfu = (float(cost["flops"]) * xla_flops_scale * n_steps / dt) / peak
+    analytic_mfu = (analytic_flops_per_step * n_steps / dt) / peak
+    return {
+        "mfu": round(analytic_mfu, 4),
+        "mfu_analytic": round(analytic_mfu, 4),
+        "mfu_analytic_source": analytic_source,
+        "mfu_xla_cost": round(xla_mfu, 4) if xla_mfu is not None else None,
+    }
+
+
 def mfu_record_fields(
     flops_per_step: float,
     dt_per_step: float,
@@ -123,31 +133,17 @@ def mfu_record_fields(
     ``flops_per_step`` is per-chip model FLOPs per optimizer step (analytic
     6·N·D-style, or the XLA cost-analysis estimate from
     ``train.engine.estimate_step_flops``); ``dt_per_step`` the measured
-    wall seconds per step.  Returns ``{}`` when either is unknown.
+    wall seconds per step.  Returns ``{}`` when either is unknown, or when
+    the device (default: this process's first local device) has no
+    published peak — a CPU run carries no ``mfu`` field at all.
     """
     if not flops_per_step or not dt_per_step or dt_per_step <= 0:
         return {}
     if device_kind is None:
-        try:
-            import jax  # noqa: PLC0415
+        import jax  # noqa: PLC0415
 
-            device_kind = jax.local_devices()[0].device_kind
-        except Exception:
-            device_kind = ""
-    _resolve_bench()
-    if _bench_mfu_fields is not None:
-        try:
-            # cost={} skips the executable cost-analysis RPC path: the live
-            # stream only carries the analytic accounting.
-            fields = _bench_mfu_fields(
-                None, dt_per_step, 1, device_kind, flops_per_step,
-                "trainer_flops_per_step", cost={},
-            )
-            return {
-                k: float(v) for k, v in fields.items()
-                if isinstance(v, (int, float)) and v is not None
-            }
-        except Exception:
-            logger.exception("bench_probe.mfu_fields failed; using fallback")
+        device_kind = jax.local_devices()[0].device_kind
+    if device_kind not in DEVICE_PEAKS:
+        return {}
     mfu = flops_per_step / dt_per_step / peak_flops(device_kind)
     return {"mfu": round(mfu, 4), "mfu_analytic": round(mfu, 4)}

@@ -157,14 +157,14 @@ class UsageMeter:
         self.kv_blocks_total = int(kv_blocks_total)
         self.flush_every = max(int(flush_every), 1)
         if device_kind is None:
-            try:
-                import jax  # noqa: PLC0415 — backend probe, not hot path
+            import jax  # noqa: PLC0415 — backend probe, not hot path
 
-                device_kind = jax.local_devices()[0].device_kind
-            except Exception:  # noqa: BLE001 — no backend: generic peak
-                device_kind = ""
+            device_kind = jax.local_devices()[0].device_kind
         self.device_kind = device_kind
-        self.peak_flops = mfu.peak_flops(device_kind)
+        # 0.0 on a device without published peaks: est_compute_s is then
+        # reported as 0.0, never priced against another chip's peak
+        self.peak_flops = mfu.DEVICE_PEAKS.get(device_kind, {}).get(
+            "flops", 0.0)
 
         reg = registry or obs_registry.default_registry()
         self._m_tokens = reg.counter(

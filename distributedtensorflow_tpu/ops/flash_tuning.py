@@ -18,10 +18,13 @@ Resolution order inside the kernel (``flash_attention._resolve_blocks``):
 4. the retuned default chain.
 
 Cache location: ``DTFT_FLASH_TUNE_CACHE`` env var, else
-``~/.cache/distributedtensorflow_tpu/flash_blocks.json``.  Set the env
-var to ``off`` to disable consultation entirely (tests pin tilings that
-way).  The file is read at most once per mtime (an in-process memo), so
-the per-trace cost is a couple of stat calls.
+``flash_blocks.json`` beside this module — a file git tracks, so the
+tiling a run uses is a function of the checkout and never of what some
+other run left in a home directory.  No such file is committed today:
+the default chain decides.  Set the env var to ``off`` to disable
+consultation entirely (tests pin tilings that way).  The file is read at
+most once per mtime (an in-process memo), so the per-trace cost is a
+couple of stat calls.
 
 Schema (validated by ``tools/check_metrics_schema.py``)::
 
@@ -62,8 +65,7 @@ SOURCES = ("sweep", "xplane")
 
 _ENV = "DTFT_FLASH_TUNE_CACHE"
 _DEFAULT = os.path.join(
-    os.path.expanduser("~"), ".cache", "distributedtensorflow_tpu",
-    "flash_blocks.json",
+    os.path.dirname(os.path.abspath(__file__)), "flash_blocks.json"
 )
 
 _memo_lock = threading.Lock()

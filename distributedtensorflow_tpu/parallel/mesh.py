@@ -131,16 +131,12 @@ def build_mesh(
                 )
             devices = list(devices)[:needed]
     shape = spec.resolve(len(devices))
-    if len(devices) == 1:
-        dev_array = np.asarray(devices).reshape(shape)
-    else:
-        try:
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=list(devices), allow_split_physical_axes=True
-            )
-        except (NotImplementedError, ValueError):
-            # Non-TPU backends (CPU test meshes) have no physical topology.
-            dev_array = np.asarray(devices).reshape(shape)
+    # On devices without a physical topology (the CPU test meshes) this is
+    # a plain reshape; on a TPU a shape the torus cannot host raises here
+    # rather than falling back to an order that ignores the ICI links.
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=list(devices), allow_split_physical_axes=True
+    )
     return Mesh(dev_array, CANONICAL_AXES)
 
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..runtime import on_tpu
 from . import mesh as mesh_lib
 
 NEG_INF = -1e9
@@ -69,7 +70,7 @@ def ring_attention(
         # (MIN_SEQ_FOR_PALLAS — the bench_attn.py-evidenced threshold).
         # Callers can always force impl="flash".
         ok = (
-            fa._on_tpu()
+            on_tpu()
             and q.shape == k.shape == v.shape
             and q.shape[1] >= fa.MIN_SEQ_FOR_PALLAS
             and fa._pick_block_q(q.shape[1]) is not None
@@ -77,10 +78,8 @@ def ring_attention(
         )
         impl = "flash" if ok else "xla"
     if impl == "flash":
-        from ..ops.flash_attention import _on_tpu
-
         return _ring_flash(q, k, v, segment_ids, axis_name, causal,
-                           not _on_tpu())
+                           not on_tpu())
     return _ring_attention_xla(q, k, v, axis_name=axis_name, causal=causal,
                                segment_ids=segment_ids)
 

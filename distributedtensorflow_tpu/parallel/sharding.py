@@ -272,3 +272,66 @@ def shard_batch(batch: PyTree, mesh: Mesh) -> PyTree:
     """Shard every leaf's leading (batch) dimension over the batch axes."""
     sharding = NamedSharding(mesh, batch_spec(mesh))
     return jax.tree.map(lambda x: jax.device_put(x, sharding), batch)
+
+
+# --- Mosaic kernels under a mesh -------------------------------------------
+#
+# GSPMD cannot partition a Mosaic (Pallas TPU) custom call: lowering one in
+# a multi-device jit raises "Mosaic kernels cannot be automatically
+# partitioned".  The kernels in ``ops/`` therefore run per shard, inside a
+# shard_map over the mesh the engine traces under (``jax.sharding.set_mesh``
+# in train/engine.py and train/state.py).  The helpers below are all a
+# kernel wrapper needs: which mesh axes may split a dimension, the spec of
+# a per-token operand, and the shard_map itself.
+
+
+def kernel_axes(candidates: Sequence[str], size: int) -> tuple[str, ...] | None:
+    """The mesh axes a kernel operand's dimension of ``size`` is split over.
+
+    Those of ``candidates`` that the context mesh has, larger than 1 and
+    not already manual (an enclosing shard_map owns those) — provided
+    their product divides ``size``; otherwise None (replicate the
+    dimension: every shard computes all of it, which is what a two-row
+    ``init`` batch on a four-way data mesh needs).
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(
+        a for a in candidates
+        if mesh.shape.get(a, 1) > 1 and a not in mesh.manual_axes
+    )
+    if not axes or size % math.prod(mesh.shape[a] for a in axes):
+        return None
+    return axes
+
+
+def token_spec(token_shape: Sequence[int], trailing: int = 0) -> P:
+    """Spec of a per-token kernel operand ``(*token_shape, *trailing dims)``:
+    the first token dimension over the batch axes, the second (the
+    sequence, when there is one) over ``seq``, the rest replicated.
+    Per-token kernels (LayerNorm, the loss head) are indifferent to how
+    their rows are dealt out, so any split that divides is correct."""
+    dims: list = []
+    if token_shape:
+        dims.append(kernel_axes(mesh_lib.BATCH_AXES, token_shape[0]))
+    if len(token_shape) >= 2:
+        dims.append(kernel_axes((mesh_lib.AXIS_SEQ,), token_shape[1]))
+    dims += [None] * (len(token_shape) - len(dims) + trailing)
+    return P(*dims)
+
+
+def shard_kernel(fn: Callable, in_specs, out_specs) -> Callable:
+    """``fn`` run per shard over every automatic axis of the context mesh.
+
+    Without a context mesh, on a one-device mesh, or inside a region
+    that is already fully manual (ring attention, the pipeline stages),
+    ``fn`` is returned as is.  Specs name only axes :func:`kernel_axes`
+    returned; operands are replicated over the rest.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if not auto or mesh.size == 1:
+        return fn
+    return jax.shard_map(
+        fn, in_specs=in_specs, out_specs=out_specs, axis_names=auto,
+        check_vma=False,
+    )

@@ -19,12 +19,10 @@ padded to a multiple of the shard count, then viewed as ``(degree,
 padded_size // degree)`` so any shape shards evenly (the pad tail carries
 zero gradients, so it is inert under elementwise optimizers).
 
-Implementation note: on jax 0.4.37 the partial-manual ``shard_map`` path
-hits the XLA ``PartitionId`` lowering ceiling (pinned by
-tests/test_jax_workarounds.py; the pipeline went full-manual for the
-same reason), so the
-collectives here are expressed as GSPMD sharding *constraints* inside the
-jitted step — XLA lowers the constraint on the summed gradient to a
+Implementation note: the collectives here are expressed as GSPMD sharding
+*constraints* inside the jitted step rather than a partial-manual
+``shard_map`` (that form did not lower on the jax this was written
+against; revisiting it is ROADMAP D9) — XLA lowers the constraint on the summed gradient to a
 reduce-scatter and the constraint back to the parameter layout to an
 all-gather, with the same freedom to fuse/overlap it has for every other
 collective in the program.  The constraint applications are routed through
@@ -304,8 +302,7 @@ def saved_opt_layout(mgr, step: int, tx, param_shapes: PyTree) -> int | None:
     saved shapes match no candidate (a different optimizer family — the
     same failure a plain restore would hit, reported before any I/O).
     """
-    meta = mgr.item_metadata(step)
-    opt_meta = meta.get("opt_state") if isinstance(meta, dict) else None
+    opt_meta = mgr.item_metadata(step).get("opt_state")
     if opt_meta is None:
         raise ValueError(f"checkpoint step {step} has no opt_state metadata")
     got = _shapes(opt_meta)

@@ -130,7 +130,10 @@ def create_sharded_state(
         lambda s: NamedSharding(mesh, s), state_specs,
         is_leaf=lambda x: isinstance(x, P),
     )
-    state = jax.jit(build, out_shardings=out_shardings)(rng)
+    # under the mesh: init traces the model, Pallas kernels included
+    # (parallel.sharding.shard_kernel reads the mesh from this context)
+    with jax.sharding.set_mesh(mesh):
+        state = jax.jit(build, out_shardings=out_shardings)(rng)
     return state, state_specs
 
 

@@ -58,10 +58,14 @@ class MetricWriter:
         if use_tensorboard:
             try:
                 import tensorflow as tf  # noqa: PLC0415
-
+            except ImportError:  # no TF installed -> JSONL only, said once
+                logger.info("tensorflow not importable: %s gets "
+                            "metrics.jsonl only, no TensorBoard events",
+                            logdir)
+            else:
+                # a TF that imports but cannot write is an error, not a
+                # quiet downgrade
                 self._tb = tf.summary.create_file_writer(logdir)
-            except Exception:  # TF missing/broken -> JSONL only
-                self._tb = None
         # JSONL is always written: a human/tool-greppable record of the run
         # (TensorBoard events are the reference-parity surface on top).
         self._jsonl = open(os.path.join(logdir, "metrics.jsonl"), "a")

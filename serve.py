@@ -18,8 +18,8 @@ Examples:
       --max-slots 8 --max-queue 128 --block-size 32
 
 On startup one JSON line goes to stdout — ``{"serving": true, "port": N,
-"logdir": ...}`` — so launchers (and the CI smoke) can find an ephemeral
-port.  SIGINT/SIGTERM drain in-flight requests, flush ``requests.jsonl``
+"logdir": ..., "device": {"platform", "kind", "count"}}`` — so launchers
+(and the smokes) can find an ephemeral port and see what it runs on.  SIGINT/SIGTERM drain in-flight requests, flush ``requests.jsonl``
 / ``metrics.prom``, and exit 0.
 """
 
@@ -214,9 +214,11 @@ def main(argv=None) -> int:
         format="%(asctime)s %(levelname)s %(message)s",
     )
 
-    import jax.numpy as jnp  # noqa: F401 — force backend init before serving
+    from distributedtensorflow_tpu import models, runtime
 
-    from distributedtensorflow_tpu import models
+    runtime.init_compile_cache()
+    device = runtime.device_summary()  # also initialises the backend
+    logging.info("device: %s", json.dumps(device))
     from distributedtensorflow_tpu.serve import Engine, ServeServer
 
     cfg = getattr(models, CONFIGS[args.config][0])()
@@ -352,6 +354,7 @@ def main(argv=None) -> int:
     print(json.dumps({
         "serving": True, "port": server.port, "config": args.config,
         "max_slots": args.max_slots, "logdir": args.logdir,
+        "device": device,
     }), flush=True)
     logging.info(
         "serving %s on %s:%d (slots=%d queue=%d block=%d prefix_cache=%s "

@@ -105,14 +105,7 @@ def test_four_axis_mesh_trains_subprocess():
     code = textwrap.dedent("""
         import jax
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", 16)
-        except AttributeError:  # pre-0.4.3x spelling: XLA_FLAGS only
-            import os
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + " --xla_force_host_platform_device_count=16"
-            )
+        jax.config.update("jax_num_cpu_devices", 16)
         import numpy as np
         from distributedtensorflow_tpu.workloads import get_workload
         from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh

@@ -160,8 +160,7 @@ class TestResolver:
 class TestAutotuneCLI:
     def test_sweep_writes_consultable_cache(self, tmp_path):
         cache = str(tmp_path / "flash_blocks.json")
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_SKIP_PROBE="1",
-                   BENCH_NO_COMPILE_CACHE="1", BENCH_PLATFORM="cpu")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         out = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools",
                                           "autotune_flash.py"),

@@ -1,21 +1,15 @@
-"""Regression gates for the jax-0.4.37 shard_map pipeline workarounds.
+"""Regression gate for the full-manual shard_map pipeline region.
 
 ``models/gpt_pipeline.py`` runs its pipeline region as a FULL-manual
 shard_map (every mesh axis manual, kernels manually sliced, explicit
-row-parallel psums) because this jax's partial-manual lowering is broken
-in two distinct ways, both pinned here so a jax upgrade that moves the
-ground truth fails LOUDLY (in either direction):
-
-1. **forward**: lowering a partial-manual region emits a ``PartitionId``
-   instruction the XLA SPMD partitioner rejects ("meaning is ambiguous");
-2. **grad**: autodiff of a partial-manual region hard-ABORTS the process
-   (``Check failed: sharding.IsManualSubgroup()``) — hence subprocess
-   probes.
-
-If BOTH legs start passing on a jax upgrade, the hybrid (partial-manual)
-formulation — which let GSPMD partition batch and Megatron kernels inside
-the region automatically — becomes viable again and the manual-TP
-machinery in gpt_pipeline.py could be retired.
+row-parallel psums).  It was written that way around two partial-manual
+lowering failures of the jax it was developed on (a ``PartitionId`` the
+SPMD partitioner rejected in the forward, an ``IsManualSubgroup`` abort
+under grad).  The installed jax compiles and differentiates the
+partial-manual form again, so the workaround is removable (ROADMAP D9);
+until that rewrite lands, this file keeps the formulation the pipeline
+actually uses under test.  Subprocess probes: a lowering failure of this
+kind aborts the process.
 """
 
 import os
@@ -29,7 +23,6 @@ _PROBE_PRELUDE = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 jax.config.update("jax_platforms", "cpu")
-import distributedtensorflow_tpu  # installs the jax.shard_map compat shim
 mesh = jax.make_mesh((2, 4), ("data", "pipe"))
 PERM = [(i, (i + 1) % 4) for i in range(4)]
 
@@ -40,13 +33,10 @@ def body(w, xs):
     carry, hist = jax.lax.scan(tick, xs[0], xs)
     return hist
 
-def region(dtype, manual_axes):
-    kwargs = {}
-    if manual_axes is not None:
-        kwargs["axis_names"] = frozenset(manual_axes)
+def region(dtype):
     sm = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(None, "pipe")),
-        out_specs=P(None, "pipe"), check_vma=False, **kwargs,
+        out_specs=P(None, "pipe"), check_vma=False,
     )
     w = jnp.eye(8, dtype=dtype)
     xs = jnp.arange(4 * 8 * 8, dtype=dtype).reshape(4, 8, 8) / 100.0
@@ -73,10 +63,10 @@ def test_full_manual_pipeline_region_compiles_and_grads():
     """The formulation the pipeline actually uses: a full-manual region
     compiles AND differentiates, in fp32 and bf16.  Either leg breaking
     means the entire pipeline path (gpt_pipeline.py and the 1F1B engine)
-    is at risk on this jax."""
+    is at risk."""
     for dtype, leg in (("jnp.float32", "fp32"), ("jnp.bfloat16", "bf16")):
         r = _run_probe(f"""
-        sm, w, xs = region({dtype}, None)
+        sm, w, xs = region({dtype})
         out = jax.jit(sm)(w, xs)
         assert out.dtype == {dtype}
         g = jax.jit(jax.grad(
@@ -87,33 +77,6 @@ def test_full_manual_pipeline_region_compiles_and_grads():
         """)
         assert r.returncode == 0 and f"{leg}-ok" in r.stdout, (
             f"{leg} full-manual pipeline region no longer compiles/grads — "
-            "the whole pipeline path is at risk on this jax:\n"
+            "the whole pipeline path is at risk:\n"
             f"{r.stderr[-2000:]}"
         )
-
-
-def test_partial_manual_still_broken():
-    """The canary pair for the workaround's reason to exist.  On this jax
-    a partial-manual region (data auto, pipe manual) fails at forward
-    compile (PartitionId) and hard-aborts the process under grad
-    (IsManualSubgroup).  If BOTH start succeeding, partial-manual has been
-    fixed upstream: the manual-TP machinery in gpt_pipeline.py could then
-    be replaced by the simpler hybrid region (GSPMD partitioning batch and
-    Megatron kernels automatically inside the region)."""
-    fwd = _run_probe("""
-    sm, w, xs = region(jnp.float32, {"pipe"})
-    out = jax.jit(sm)(w, xs)
-    print("fwd-ok")
-    """)
-    grad = _run_probe("""
-    sm, w, xs = region(jnp.float32, {"pipe"})
-    g = jax.jit(jax.grad(lambda w, xs: sm(w, xs).sum()))(w, xs)
-    print("grad-ok")
-    """)
-    fwd_ok = fwd.returncode == 0 and "fwd-ok" in fwd.stdout
-    grad_ok = grad.returncode == 0 and "grad-ok" in grad.stdout
-    assert not (fwd_ok and grad_ok), (
-        "partial-manual shard_map now compiles AND differentiates: the "
-        "full-manual + manual-TP workaround in models/gpt_pipeline.py is "
-        "likely removable — revisit the hybrid formulation."
-    )

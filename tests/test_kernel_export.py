@@ -1,0 +1,162 @@
+"""Pre-checks of the Pallas kernels for the TPU that need no chip.
+
+Interpret mode (what every other kernel test runs) accepts programs the
+real compiler refuses, and chip time is budgeted.  Two cheaper gates sit
+in between:
+
+1. ``jax.export`` for ``platforms=["tpu"]`` with ``interpret=False`` runs
+   the JAX-side Pallas -> Mosaic lowering of each kernel family at GPT-2
+   shapes: a lowering break is caught before any chip call;
+2. compiling for a *described* v5e topology
+   (``jax.experimental.topologies``) runs libtpu's own Mosaic compiler —
+   scoped-VMEM limits, tile alignment — and XLA's partitioner on a 2x2
+   mesh, still without a chip (skipped where this libtpu cannot describe
+   a topology).  It has already refused one thing interpret mode took:
+   the loss head with fp32 operands overflows the 16 MiB scoped VMEM.
+
+What neither can show is that the compiled kernel computes the right
+numbers on the chip; ``chip_smoke.py`` is for that.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from distributedtensorflow_tpu.ops.attention import _pallas_decode_attention
+from distributedtensorflow_tpu.ops.flash_attention import flash_attention
+from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
+from distributedtensorflow_tpu.ops.layernorm import layer_norm
+
+# GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
+# 64, d 768, vocab 50,257, bf16 activations.
+B, S, H, D, V = 16, 1024, 12, 64, 50257
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+def _sds(shape, dtype, sharding=None):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _sum32(x):
+    return jnp.sum(x.astype(F32))
+
+
+def _flash(**kw):
+    return jax.value_and_grad(
+        lambda q, k, v: _sum32(flash_attention(
+            q, k, v, causal=True, interpret=False, **kw)),
+        argnums=(0, 1, 2),
+    )
+
+
+def _xent(h, w, t):
+    # as models/gpt.py calls it: fp32 table, bf16 compute
+    return jax.value_and_grad(
+        lambda h, w: fused_softmax_xent(h, w, t, compute_dtype=BF16,
+                                        interpret=False),
+        argnums=(0, 1),
+    )(h, w)
+
+
+def _ln(x, g, b):
+    return jax.value_and_grad(
+        lambda x, g, b: _sum32(layer_norm(
+            x, g, b, impl="pallas", interpret=False)),
+        argnums=(0, 1, 2),
+    )(x, g, b)
+
+
+def _decode(q, k, v, valid):
+    return _pallas_decode_attention(q, k, v, valid, interpret=False)
+
+
+def _qkv(seq=S, kv_heads=H, batch=B):
+    return (_sds((batch, seq, H, D), BF16),
+            _sds((batch, seq, kv_heads, D), BF16),
+            _sds((batch, seq, kv_heads, D), BF16))
+
+
+FAMILIES = {
+    "flash_1024": (_flash(), _qkv()),
+    "flash_8192": (_flash(), _qkv(seq=8192, batch=2)),
+    "flash_window": (_flash(window=256), _qkv(seq=2048, batch=4)),
+    "flash_gqa": (_flash(), _qkv(kv_heads=4)),
+    "fused_xent": (_xent, (_sds((B, S, H * D), BF16),
+                           _sds((V, H * D), F32),
+                           _sds((B, S), jnp.int32))),
+    "layer_norm": (_ln, (_sds((B, S, H * D), BF16),
+                         _sds((H * D,), F32), _sds((H * D,), F32))),
+    "decode": (_decode, (_sds((B, 1, H, D), BF16),
+                         _sds((B, H, S, D), BF16),
+                         _sds((B, H, S, D), BF16),
+                         _sds((1, S), jnp.int32))),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kernel_lowers_to_mosaic_for_tpu(family):
+    fn, args = FAMILIES[family]
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module(), family
+
+
+def _v5e_mesh(n):
+    from jax.experimental import topologies
+
+    from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any reason means "not here"
+        pytest.skip(f"this libtpu cannot describe a v5e topology: {e}")
+    return build_mesh(MeshSpec(data=n), topo.devices[:n])
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kernel_compiles_for_one_v5e_chip(family):
+    fn, args = FAMILIES[family]
+    repl = NamedSharding(_v5e_mesh(1), P())
+    args = [_sds(a.shape, a.dtype, repl) for a in args]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), family
+
+
+def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
+    """On four chips each Mosaic call must sit in a shard_map and see the
+    per-device batch: GSPMD refuses to partition one ("Mosaic kernels
+    cannot be automatically partitioned"), which is what this step did
+    before ``parallel.sharding.shard_kernel``."""
+    n_chips = 4
+    mesh = _v5e_mesh(n_chips)
+    batch = NamedSharding(mesh, P("data"))
+    repl = NamedSharding(mesh, P())
+    gb = B * n_chips
+
+    def step(q, k, v, x, g, b, w, t):
+        return _flash()(q, k, v), _ln(x, g, b), _xent(x, w, t)
+
+    args = (
+        *(_sds((gb, S, H, D), BF16, batch) for _ in range(3)),
+        _sds((gb, S, H * D), BF16, batch),
+        _sds((H * D,), F32, repl), _sds((H * D,), F32, repl),
+        _sds((V, H * D), F32, repl),
+        _sds((gb, S), jnp.int32, batch),
+    )
+    with jax.sharding.set_mesh(mesh):
+        lowered = jax.jit(step).lower(*args)
+        compiled = lowered.compile()
+    shapes = re.findall(
+        r'kernel_name = "(\w+)".*?\}\s*:\s*\(tensor<([0-9x]+)x\w+>',
+        lowered.as_text(),
+    )
+    names = {name for name, _ in shapes}
+    assert {"flash_fwd", "flash_bwd", "layer_norm_fwd", "layer_norm_bwd",
+            "fused_xent_fwd", "fused_xent_bwd_dx",
+            "fused_xent_bwd_dw"} <= names, names
+    first_dims = {int(s.split("x")[0]) for _, s in shapes}
+    assert first_dims == {B, B * S}, first_dims  # per device, never global
+    assert "tpu_custom_call" in compiled.as_text()

@@ -42,14 +42,12 @@ def test_xla_cost_matches_analytic_on_known_matmul(compiled_matmul):
 
 
 def test_mfu_fields_agree_on_known_matmul(compiled_matmul):
-    """bench_probe.mfu_fields emits mfu_analytic == mfu_xla_cost when fed
-    the convention-correct analytic count — the end-to-end reconciliation
+    """mfu_fields emits mfu_analytic == mfu_xla_cost when fed the
+    convention-correct analytic count — the end-to-end reconciliation
     (the 2x ResNet-50 disagreement was exactly this pair diverging)."""
-    from bench_probe import mfu_fields
-
     analytic = mfu_lib.matmul_flops(M, N, K)
-    fields = mfu_fields(
-        compiled_matmul, dt=1.0, n_steps=1, device_kind="cpu",
+    fields = mfu_lib.mfu_fields(
+        compiled_matmul, dt=1e-9, n_steps=1, device_kind="TPU v5 lite",
         analytic_flops_per_step=analytic,
         analytic_source="matmul_2mnk",
     )
@@ -68,3 +66,18 @@ def test_resnet_constant_uses_macs_times_two():
     import bench
 
     assert bench.RESNET50_TRAIN_FLOPS_PER_IMAGE == pytest.approx(24.6e9)
+
+
+def test_unknown_device_kind_has_no_peak():
+    """No default chip: the bench accounting raises on a kind without
+    published peaks, and the Trainer's record carries no mfu field."""
+    for kind in ("cpu", "TPU v9 imaginary", ""):
+        with pytest.raises(KeyError, match="no published peaks"):
+            mfu_lib.peak_flops(kind)
+        with pytest.raises(KeyError, match="no published peaks"):
+            mfu_lib.mfu_fields(None, 1.0, 1, kind, 1e12, "test", cost={})
+        assert mfu_lib.mfu_record_fields(1e12, 0.1, device_kind=kind) == {}
+    # default device = this process's (CPU) device: same answer
+    assert mfu_lib.mfu_record_fields(1e12, 0.1) == {}
+    assert mfu_lib.peak_flops("TPU v5 lite") == 197e12
+    assert mfu_lib.peak_hbm_bytes_per_s("TPU v5 lite") == 819e9

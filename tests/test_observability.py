@@ -47,10 +47,7 @@ def test_named_scope_in_hlo():
             return x * 2 + 1
 
     lowered = jax.jit(f).lower(jnp.ones((4,)))
-    try:  # this image's jax (0.4.37) has no as_text(debug_info=...)
-        hlo = lowered.as_text(debug_info=True)
-    except TypeError:
-        hlo = lowered.compile().as_text()  # op metadata survives compile
+    hlo = lowered.as_text(debug_info=True)
     assert "my_marker_scope" in hlo
 
 

@@ -88,8 +88,7 @@ def test_quant_int8_convergence_and_reporting(tmp_path):
          "--shape", "2,4,64,32", "--dtype", "bfloat16",
          "--blocks", "32,64", "--steps", "1", "--cache", cache],
         capture_output=True, text=True,
-        env={**_env(), "BENCH_SKIP_PROBE": "1",
-             "BENCH_NO_COMPILE_CACHE": "1", "BENCH_PLATFORM": "cpu"},
+        env=_env(),
         timeout=900,
     )
     assert out.returncode == 0, out.stderr[-3000:]

@@ -323,13 +323,28 @@ def test_unchunked_checkpoint_restores_into_zero_run(tmp_path, dp_mesh):
     )
 
 
+def _zero_array_data(step_dir) -> None:
+    """Zero the payload of every ARRAY-data file of one saved step (ocdbt
+    data lives under d/ directories) so its CRC cannot match.  All of
+    them: which file holds which array, and how many there are, varies
+    from save to save, so a single victim may be one no array reads."""
+    import glob
+    import os
+
+    files = [
+        p for p in glob.glob(str(step_dir / "**" / "*"), recursive=True)
+        if os.path.isfile(p) and f"{os.sep}d{os.sep}" in p
+    ]
+    assert files
+    for path in files:
+        with open(path, "r+b") as f:
+            f.write(bytes(os.path.getsize(path)))
+
+
 def test_corrupt_zero_checkpoint_falls_back_verified(tmp_path, dp_mesh):
     """A truncated ZeRO checkpoint is rejected by the integrity manifest
     and the restore falls back to the older verified step (the mid-run
     restore acceptance path)."""
-    import glob
-    import os
-
     tx = optax.adam(1e-3)
     sharder = ZeroSharder(dp_mesh)
     state, _, step = _run(dp_mesh, tx, sharder, steps=2)
@@ -340,19 +355,7 @@ def test_corrupt_zero_checkpoint_falls_back_verified(tmp_path, dp_mesh):
     assert mgr.save(3, state3, force=True)
     mgr.wait()
 
-    # corrupt the biggest ARRAY-data file of step 3 (ocdbt data lives
-    # under d/ directories; the metadata JSONs are bigger than the data
-    # at this model size and don't carry checksummed bytes)
-    files = sorted(
-        (p for p in glob.glob(
-            str(tmp_path / "ckpt" / "3" / "**" / "*"), recursive=True
-        ) if os.path.isfile(p) and f"{os.sep}d{os.sep}" in p),
-        key=os.path.getsize,
-    )
-    victim = files[-1]
-    size = os.path.getsize(victim)
-    with open(victim, "r+b") as f:
-        f.write(bytes(bytearray(size)))  # zero the payload: CRC mismatch
+    _zero_array_data(tmp_path / "ckpt" / "3")
 
     fresh, _ = create_sharded_state(
         _uneven_init, tx, dp_mesh, jax.random.PRNGKey(9), zero=sharder
@@ -370,9 +373,6 @@ def test_mixed_layout_history_falls_back_across_layouts(tmp_path, dp_mesh):
     strand older steps saved at a different ZeRO degree: the fallback
     probes each step's layout and rechunks instead of rejecting the
     shape mismatch as corruption."""
-    import glob
-    import os
-
     tx8 = optax.adam(1e-3)
     state8, _, _ = _run(dp_mesh, tx8, ZeroSharder(dp_mesh), steps=2)
     mgr = CheckpointManager(str(tmp_path / "ckpt"), async_save=False)
@@ -381,14 +381,7 @@ def test_mixed_layout_history_falls_back_across_layouts(tmp_path, dp_mesh):
     assert mgr.save(3, state_u, force=True)  # unchunked layout
     mgr.wait()
 
-    files = sorted(
-        (p for p in glob.glob(
-            str(tmp_path / "ckpt" / "3" / "**" / "*"), recursive=True
-        ) if os.path.isfile(p) and f"{os.sep}d{os.sep}" in p),
-        key=os.path.getsize,
-    )
-    with open(files[-1], "r+b") as f:
-        f.write(bytes(bytearray(os.path.getsize(files[-1]))))
+    _zero_array_data(tmp_path / "ckpt" / "3")
 
     fresh, _ = create_sharded_state(
         _uneven_init, optax.adam(1e-3), dp_mesh, jax.random.PRNGKey(9)

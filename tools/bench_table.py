@@ -28,7 +28,7 @@ def load_rows(directory: str) -> list[dict]:
     rows = []
     for path in sorted(glob.glob(os.path.join(directory, "*.json"))):
         base = os.path.basename(path)
-        if base.startswith(("tpu_watch", ".")):
+        if base.startswith("."):
             continue
         try:
             with open(path) as f:
@@ -51,18 +51,6 @@ def fmt(v) -> str:
     if isinstance(v, float):
         return f"{v:,.4g}" if abs(v) < 10 else f"{v:,.1f}"
     return str(v)
-
-
-def stale_marker(row: dict) -> str:
-    """Annotation for rows that are cached re-emissions (``fresh: false``
-    / ``cached_from`` set) rather than fresh measurements — a cached value
-    must never be presented as fresh evidence in the table."""
-    if row.get("fresh") is False or row.get("cached_from"):
-        age = row.get("age_s")
-        if isinstance(age, (int, float)):
-            return f"**STALE** ({age / 3600.0:.1f}h old) "
-        return "**STALE** "
-    return ""
 
 
 def main() -> None:
@@ -95,8 +83,7 @@ def main() -> None:
                 if r.get(k) not in (None, "")
             )
             err = r.get("error")
-            val = (f"ERR:{err}" if err
-                   else stale_marker(r) + fmt(r.get("value")))
+            val = f"ERR:{err}" if err else fmt(r.get("value"))
             print(
                 f"| {r.get('timestamp', '?')} | {val} "
                 f"| {fmt(r.get('vs_baseline'))} "

@@ -15,6 +15,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import json
 import logging
 import os
 import time
@@ -754,11 +755,10 @@ def main() -> None:
     p.add_argument("--test-size", action="store_true",
                    help="shrink the model (CI / smoke tests)")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--device", default=None,
-                   help="reference-parity flag (tpu|cpu); default = auto")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache directory "
-                        "(reruns skip the 20-40s first compile)")
+    p.add_argument("--device", choices=("tpu", "cpu"), default=None,
+                   help="tpu: fail unless JAX's devices are TPU chips; "
+                        "cpu: force the CPU backend (tests); default: "
+                        "whatever JAX finds, named in the start-up log")
     p.add_argument("--sp-scheme", choices=("ring", "ulysses"), default="ring",
                    help="sequence-parallel attention for gpt_lm on seq meshes")
     p.add_argument("--data-dir", default=None, metavar="DIR",
@@ -909,11 +909,11 @@ def main() -> None:
         level=logging.INFO,
         format="%(asctime)s %(name)s %(message)s",
     )
+    from distributedtensorflow_tpu import runtime
+
     if args.device == "cpu":
         jax.config.update("jax_platforms", "cpu")
-    if args.compile_cache:
-        jax.config.update("jax_compilation_cache_dir", args.compile_cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    runtime.init_compile_cache()
     if args.deterministic:
         from distributedtensorflow_tpu.utils import enable_determinism
 
@@ -987,6 +987,11 @@ def main() -> None:
         ).install()
 
     cluster = parallel.initialize()
+    # One line naming what the run is on (chip_smoke.py reads it); with
+    # --device tpu anything else stops here instead of training on it.
+    device = (runtime.require_tpu() if args.device == "tpu"
+              else runtime.device_summary())
+    logging.info("device: %s", json.dumps(device))
     if args.profiler_port is not None:
         from distributedtensorflow_tpu.utils import profiler
 
