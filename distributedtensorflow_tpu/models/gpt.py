@@ -492,13 +492,15 @@ def lm_loss(model: GPTLM):
         )
         targets = batch["input_ids"][:, 1:]
         mask = batch.get("mask")
-        loss = xent(
-            hidden[:, :-1],
-            params["wte"]["embedding"],
-            targets,
-            mask[:, 1:] if mask is not None else None,
-            compute_dtype=model.cfg.dtype,
-        )
+        # the head has no flax module of its own: name it for the trace
+        with jax.named_scope("loss_head"):
+            loss = xent(
+                hidden[:, :-1],
+                params["wte"]["embedding"],
+                targets,
+                mask[:, 1:] if mask is not None else None,
+                compute_dtype=model.cfg.dtype,
+            )
         return loss, ({"perplexity": jnp.exp(loss)}, model_state)
 
     return loss_fn

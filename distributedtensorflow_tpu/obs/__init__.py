@@ -109,6 +109,7 @@ from .server import StatusServer  # noqa: F401
 from .slo import SLOMonitor, SLORule  # noqa: F401
 from .tsdb import MetricsHistory  # noqa: F401
 from .tracing import (  # noqa: F401
+    PhaseTrace,
     Span,
     TraceRecorder,
     active_recorder,

@@ -13,8 +13,14 @@ Design constraints:
   ``StopIteration`` from ``next(it)`` escaping unchanged, so ``span`` is a
   plain class context manager, NOT a ``@contextmanager`` generator (PEP 479
   would turn an in-body StopIteration into RuntimeError).
-- near-zero cost when no recorder is installed: two ``perf_counter`` calls
-  and a list push/pop;
+- near-zero cost when no recorder is installed: two ``perf_counter`` calls,
+  a list push/pop and one ``jax.profiler.TraceAnnotation`` (an atomic
+  load while no profiler session is open);
+- every ``span`` is mirrored into the profiler: while a ``jax.profiler``
+  trace is open — whoever opened it — the span appears on the host thread
+  under its own name, its keyword attributes as the event's stats, on the
+  same clock as the device lanes.  No ``capture_active()`` gate: a trace
+  started outside ``obs.capture`` must see the spans too;
 - spans may complete on any thread (the Prefetcher's ``device_put`` worker);
   roots from any thread land in the currently open step row.
 
@@ -38,6 +44,12 @@ caused in another's.  The context travels as a two-field dict
 client, echoed through ``data/wire.py`` headers, and attached per serve
 request — and :class:`remote_span` is the emitting context manager
 (near-free when no recorder is installed).
+
+A process's **start-up** is such a trace too: :class:`PhaseTrace` writes
+its back-to-back phases (``startup.imports``, ``startup.backend``, ... —
+docs/OBSERVABILITY.md has the list) as ``kind: "span"`` rows under the
+one ``trace_id`` ``"startup"``, so what happens before the first step has
+names and absolute times.
 """
 
 from __future__ import annotations
@@ -61,6 +73,7 @@ __all__ = [
     "new_span_id",
     "record_remote_span",
     "remote_span",
+    "PhaseTrace",
 ]
 
 _tls = threading.local()
@@ -82,18 +95,37 @@ class Span:
         return d
 
 
+#: ``jax.profiler.TraceAnnotation``, imported at the first span (this
+#: module must stay importable before a backend is chosen).
+_TraceAnnotation = None
+
+
+def _annotation(name: str, attrs: dict[str, Any]):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation  # noqa: PLC0415
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **attrs)
+
+
 class span:
-    """``with span("train_step"): ...`` — time a region into the trace."""
+    """``with span("train_step"): ...`` — time a region into the trace.
 
-    __slots__ = ("_span",)
+    Keyword attributes (``span("engine.step", step=7)``) go to the
+    profiler annotation only; the span tree keeps names and durations."""
 
-    def __init__(self, name: str):
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, name: str, **attrs: Any):
         self._span = Span(name)
+        self._ann = _annotation(name, attrs)
 
     def __enter__(self) -> Span:
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
+        self._ann.__enter__()
         self._span.t0 = time.perf_counter()
         stack.append(self._span)
         return self._span
@@ -101,6 +133,7 @@ class span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         s = self._span
         s.dur_s = time.perf_counter() - s.t0
+        self._ann.__exit__(exc_type, exc, tb)
         stack = _tls.stack
         stack.pop()
         if stack:
@@ -156,10 +189,17 @@ class TraceRecorder:
     Only the chief process writes the file (the ``MetricWriter``
     convention); non-chief recorders still accumulate window totals so
     cross-host aggregation has per-host numbers to gather.
+
+    ``step_rows=False`` is for a process with no step loop (``serve.py``:
+    ``begin_step`` is never called, so buffered roots would only grow):
+    completed roots feed the window totals and nothing is kept for a row;
+    the file holds ``kind: "span"`` rows and events only.
     """
 
-    def __init__(self, path: str | None = None, *, chief_only: bool = True):
+    def __init__(self, path: str | None = None, *, chief_only: bool = True,
+                 step_rows: bool = True):
         self._f = None
+        self._step_rows = step_rows
         if path is not None:
             chief = True
             if chief_only:
@@ -205,7 +245,8 @@ class TraceRecorder:
 
     def _add_root(self, s: Span) -> None:
         with self._lock:
-            self._roots.append(s)
+            if self._step_rows:
+                self._roots.append(s)
             self._window[s.name] = self._window.get(s.name, 0.0) + s.dur_s
             self._window_counts[s.name] = self._window_counts.get(s.name, 0) + 1
 
@@ -414,3 +455,46 @@ class remote_span:
             span_id=self.span_id, parent_id=self.parent_id, **self.fields,
         )
         return False
+
+
+class PhaseTrace:
+    """Back-to-back phases of one stage of a process (its start-up) as
+    ``kind: "span"`` rows under one ``trace_id``.
+
+    ``mark(name)`` says: phase ``name`` ran from the previous mark (or
+    ``t0``) until now — so the rows tile the interval with nothing unnamed
+    between them.  ``open(name)`` starts a phase that encloses the marks
+    made with ``parent=name`` until ``close(name)`` writes its row.  Rows
+    made while no recorder is installed (imports and the backend come
+    before a recorder can exist) wait and are written at the next call.
+    """
+
+    def __init__(self, trace_id: str, t0: float | None = None):
+        self.trace_id = trace_id
+        self._t = time.time() if t0 is None else t0
+        self._open: dict[str, tuple[str, float]] = {}  # name -> (id, t0)
+        self._pending: list[dict[str, Any]] = []
+
+    def mark(self, name: str, *, parent: str | None = None,
+             **fields: Any) -> None:
+        if parent is not None:
+            fields["parent_id"] = self._open[parent][0]
+        self._row(name, self._t, **fields)
+
+    def open(self, name: str) -> None:
+        self._open[name] = (new_span_id(), self._t)
+
+    def close(self, name: str, **fields: Any) -> None:
+        span_id, t0 = self._open.pop(name)
+        self._row(name, t0, span_id=span_id, **fields)
+
+    def _row(self, name: str, t0: float, **fields: Any) -> None:
+        """The phase from ``t0`` to now; the clock moves to now."""
+        self._t = time.time()
+        self._pending.append(dict(
+            name=name, t0=t0, dur_s=self._t - t0, trace_id=self.trace_id,
+            **fields))
+        if _recorder is not None:
+            pending, self._pending = self._pending, []
+            for row in pending:
+                record_remote_span(**row)

@@ -79,8 +79,10 @@ summing to ``e2e_s``) and periodic ``metrics.jsonl`` rows +
 ``tools/run_report.py`` and ``tools/check_metrics_schema.py`` consume).
 Every scheduler iteration that did work additionally leaves one step-log
 record — phase mix, occupancy, token/draft deltas, admissions/evictions,
-prefill chunks + budget stalls, and the admit/prefill/decode +
-host-vs-device wall split — in a bounded ring (``GET /stepz`` via the
+prefill chunks + budget stalls, and the admit/prefill/decode wall
+split, read off the iteration's ``engine.*`` span tree (mirrored into any
+open profiler trace; docs/OBSERVABILITY.md has the vocabulary) — in a
+bounded ring (``GET /stepz`` via the
 frontend; :meth:`Engine.step_records`) and ``steps.jsonl``.
 
 Threading model: HTTP/handler threads only touch :meth:`submit` (queue +
@@ -269,6 +271,7 @@ class Engine:
         log_every: int = 50,
         step_ring: int = 512,
         registry=None,
+        capture=None,
     ):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
@@ -402,8 +405,10 @@ class Engine:
             maxlen=self.step_ring_size)
         self._step_id = 0
         self._step_evicted = 0     # requests finished in the current step
-        self._iter_prefill_s = 0.0  # this iteration's prefill-phase wall
-        self._iter_device_s = 0.0   # this iteration's program-dispatch wall
+        #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
+        #: and closes its profiler windows by iteration, so a capture
+        #: armed through ``POST /profilez?steps=N`` holds N iterations.
+        self.capture = capture
         self._prefill_stalled = False
         # prefix_lookups/hits/cached_tokens live on the PagedKVCache (the
         # admission path that owns the success-only counting rule) — one
@@ -720,57 +725,78 @@ class Engine:
         evict.  Public so tests can drive the engine synchronously;
         returns True when any work happened.  Every iteration that did
         work leaves one step-log record (ring + steps.jsonl)."""
-        t0 = time.time()
+        if not (self._queue or self._filling
+                or any(r is not None for r in self._slots)):
+            # nothing queued, filling or decoding: no iteration to name
+            # (the gauges were set when the last request left)
+            return False
+        span = obs_tracing.span
         tokens0 = self.counters["decode_tokens"]
         drafted0 = self.counters["spec_drafted"]
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
-        self._iter_device_s = 0.0
-        admitted = self._admit_from_queue()
-        t1 = time.time()
-        chunks = self._run_prefill_budget()
-        t2 = time.time()
-        self._iter_prefill_s = t2 - t1
-        occupancy = sum(
-            r is not None and r._prefill_done for r in self._slots
-        )
-        if occupancy:
-            self._run_decode_step()
-        t3 = time.time()
-        did = bool(admitted or chunks or occupancy)
-        if did:
-            # Post-eviction census at t3 — the same instant and slot set
-            # the step record's active_slots reflects, so the usage
-            # ledger's per-tenant integrals tile the step-log occupancy
-            # integrals exactly (conservation by construction).
-            held = [
-                (r, self.kv.billed_blocks(i))
-                for i, r in enumerate(self._slots) if r is not None
-            ]
-            self._log_step(
-                t0, t1, t2, t3, admitted, chunks, occupancy,
-                self.counters["decode_tokens"] - tokens0,
-                self.counters["spec_drafted"] - drafted0,
-                self.counters["spec_accepted"] - accepted0,
-                sum(b for _, b in held),
+        # The iteration is one span tree (mirrored into any open profiler
+        # trace): the step record's walls are its durations, and the
+        # `step` attribute is the steps.jsonl `step` this iteration gets.
+        with span("engine.step", step=self._step_id + 1) as root:
+            with span("engine.admit") as s_admit:
+                admitted = self._admit_from_queue()
+            chunks, prefill_s, decode_s = 0, 0.0, 0.0
+            if self._filling:
+                with span("engine.prefill") as s_prefill:
+                    chunks = self._run_prefill_budget()
+                prefill_s = s_prefill.dur_s
+            else:
+                self._prefill_stalled = False
+            occupancy = sum(
+                r is not None and r._prefill_done for r in self._slots
             )
-            self.usage.on_step(t3, t3 - t0, held, self._step_id)
-        if did and self.decode_steps % self.log_every == 0:
-            self._log_metrics_row()
+            if occupancy:
+                with span("engine.decode") as s_decode:
+                    self._run_decode_step(prefill_s)
+                decode_s = s_decode.dur_s
+            did = bool(admitted or chunks or occupancy)
+            if did:
+                with span("engine.log") as s_log:
+                    # Post-eviction census at `now` — the same instant
+                    # and slot set the step record's active_slots
+                    # reflects, so the usage ledger's per-tenant
+                    # integrals tile the step-log occupancy integrals
+                    # exactly (conservation by construction).
+                    now = time.time()
+                    step_s = s_log.t0 - root.t0  # the work, not the log
+                    held = [
+                        (r, self.kv.billed_blocks(i))
+                        for i, r in enumerate(self._slots) if r is not None
+                    ]
+                    self._log_step(
+                        now, s_admit.dur_s, prefill_s, decode_s, step_s,
+                        admitted, chunks, occupancy,
+                        self.counters["decode_tokens"] - tokens0,
+                        self.counters["spec_drafted"] - drafted0,
+                        self.counters["spec_accepted"] - accepted0,
+                        sum(b for _, b in held),
+                    )
+                    self.usage.on_step(now, step_s, held, self._step_id)
+                    if self.decode_steps % self.log_every == 0:
+                        self._log_metrics_row()
         return did
 
-    def _log_step(self, t0: float, t1: float, t2: float, t3: float,
+    def _log_step(self, now: float, admit_s: float, prefill_s: float,
+                  decode_s: float, step_s: float,
                   admitted: list[GenRequest], chunks: int, occupancy: int,
                   tokens: int, drafted: int, accepted: int,
                   blocks_billed: float) -> None:
         """One structured record for the iteration that just ran: phase
-        mix, occupancy, per-phase token deltas, and the wall split —
-        admit/prefill/decode phases plus the device share (time blocked
-        dispatching compiled programs and fetching their results; the
-        remainder is host scheduling/bookkeeping).  ``blocks_billed`` is
-        the pool's refcount-weighted block census at t3 (the usage
-        ledger's conservation reference); admissions are additionally
-        broken down by tenant."""
+        mix, occupancy, per-phase token deltas, and the wall split, read
+        off the iteration's span tree: the ``engine.admit`` /
+        ``engine.prefill`` / ``engine.decode`` durations and ``step_s``,
+        the wall from the start of ``engine.step`` to the start of
+        ``engine.log`` (host wall, all of them: device time per phase is
+        what a profiler trace holding these spans gives).
+        ``blocks_billed`` is the pool's refcount-weighted block census at
+        ``now`` (the usage ledger's conservation reference); admissions
+        are additionally broken down by tenant."""
         phases = []
         if admitted:
             phases.append("admit")
@@ -779,9 +805,8 @@ class Engine:
         if occupancy:
             phases.append("decode")
         self._step_id += 1
-        device_s = min(self._iter_device_s, t3 - t0)
         rec = {
-            "t": t3,
+            "t": now,
             "step": self._step_id,
             "phase": "+".join(phases) or "idle",
             "occupancy": occupancy,
@@ -795,12 +820,10 @@ class Engine:
             "tokens_committed": tokens,
             "spec_drafted": drafted,
             "spec_accepted": accepted,
-            "admit_s": round(t1 - t0, 6),
-            "prefill_s": round(t2 - t1, 6),
-            "decode_s": round(t3 - t2, 6),
-            "step_s": round(t3 - t0, 6),
-            "device_s": round(device_s, 6),
-            "host_s": round(max((t3 - t0) - device_s, 0.0), 6),
+            "admit_s": round(admit_s, 6),
+            "prefill_s": round(prefill_s, 6),
+            "decode_s": round(decode_s, 6),
+            "step_s": round(step_s, 6),
             "kv_blocks_billed": round(blocks_billed, 4),
         }
         if admitted:
@@ -935,10 +958,7 @@ class Engine:
         bound) nor monopolize prefill across iterations (the rotation).
         Always makes progress: at least one chunk runs when any request
         is filling, even with a budget below the chunk width.  Returns
-        the chunk count."""
-        if not self._filling:
-            self._prefill_stalled = False
-            return 0
+        the chunk count.  Called only while a request is filling."""
         budget = self.prefill_budget
         spent = 0
         chunks = 0
@@ -981,34 +1001,36 @@ class Engine:
         # on OTHER requests' work (their chunks, decode steps, admit
         # scans) — interference stall, not its own prefill compute
         req.attr_stall_s += max(t_chunk0 - req._t_attr, 0.0)
-        table_row = jnp.asarray(self.kv.block_tables[slot])
-        if self._prefill_cache_state != (slot, start):
-            if start:
-                self._prefill_cache = self._gather(
-                    self.kv.k_pool, self.kv.v_pool, self._prefill_cache,
-                    table_row, jnp.int32(start),
-                )
-            else:
-                self._prefill_cache = reset_cache_index(self._prefill_cache)
-        last_ix = min(max(len(req.prompt) - 1 - start, 0), c - 1)
-        last_logits, self._prefill_cache, self.kv.k_pool, self.kv.v_pool = (
-            self._prefill(
+        with obs_tracing.span("engine.prefill_chunk"):
+            table_row = jnp.asarray(self.kv.block_tables[slot])
+            if self._prefill_cache_state != (slot, start):
+                if start:
+                    with obs_tracing.span("engine.gather"):
+                        self._prefill_cache = self._gather(
+                            self.kv.k_pool, self.kv.v_pool,
+                            self._prefill_cache, table_row,
+                            jnp.int32(start),
+                        )
+                else:
+                    self._prefill_cache = reset_cache_index(
+                        self._prefill_cache)
+            last_ix = min(max(len(req.prompt) - 1 - start, 0), c - 1)
+            (last_logits, self._prefill_cache, self.kv.k_pool,
+             self.kv.v_pool) = self._prefill(
                 self.params, self.kv.k_pool, self.kv.v_pool,
                 self._prefill_cache,
                 jnp.asarray(req._fill_buf[None, start:start + c]),
                 jnp.int32(start), table_row, jnp.int32(last_ix),
             )
-        )
-        req._fill_next = start + c
-        self._prefill_cache_state = (slot, start + c)
-        self.kv.note_written(
-            slot, max(min(start + c, len(req.prompt)),
-                      int(self.kv.seq_lens[slot]))
-        )
+            req._fill_next = start + c
+            self._prefill_cache_state = (slot, start + c)
+            self.kv.note_written(
+                slot, max(min(start + c, len(req.prompt)),
+                          int(self.kv.seq_lens[slot]))
+            )
         t_chunk1 = time.time()
         req.attr_prefill_s += max(t_chunk1 - t_chunk0, 0.0)
         req._t_attr = t_chunk1
-        self._iter_device_s += t_chunk1 - t_chunk0
         return last_logits
 
     def _finish_prefill(self, req: GenRequest, last_logits) -> None:
@@ -1019,27 +1041,28 @@ class Engine:
             self.kv.register_prefix(req.slot, req.prompt)
         req._prefill_done = True
         self._slot_meta_dirty = True
-        t_sample0 = time.time()
-        if self.fused_sampling:
-            # The prefill program hands logits to the host anyway (its
-            # last chunk); sampling them with the device sampler's exact
-            # math + key schedule (emitted index 0) keeps the request on
-            # ONE sampling stream across the host/device boundary.
-            tok = sampling.sample_one(
-                np.asarray(last_logits), jax.random.PRNGKey(req.seed), 0,
-                req.temperature, req.top_k,
-            )
-            self._dev_tokens = self._dev_tokens.at[req.slot, 0].set(tok)
-        else:
-            tok = self._sample(req, np.asarray(last_logits))
+        # the first-token sample blocks on the last chunk's logits: the
+        # wait for the device and the host sampling are one span
+        with obs_tracing.span("engine.first_token"):
+            if self.fused_sampling:
+                # The prefill program hands logits to the host anyway
+                # (its last chunk); sampling them with the device
+                # sampler's exact math + key schedule (emitted index 0)
+                # keeps the request on ONE sampling stream across the
+                # host/device boundary.
+                tok = sampling.sample_one(
+                    np.asarray(last_logits), jax.random.PRNGKey(req.seed),
+                    0, req.temperature, req.top_k,
+                )
+                self._dev_tokens = self._dev_tokens.at[req.slot, 0].set(tok)
+            else:
+                tok = self._sample(req, np.asarray(last_logits))
         req.t_first_token = time.time()
         req._t_last_token = req.t_first_token
-        # the first-token sample blocks on the last chunk's logits — it
-        # is the tail of this request's prefill compute, for both the
-        # attribution ledger and the step record's device share
+        # ... and the tail of this request's prefill compute in the
+        # attribution ledger
         req.attr_prefill_s += max(req.t_first_token - req._t_attr, 0.0)
         req._t_attr = req.t_first_token
-        self._iter_device_s += req.t_first_token - t_sample0
         req.tokens.append(tok)
         self.usage.on_tokens(req, 1)
         self._last_tokens[req.slot] = tok
@@ -1047,50 +1070,58 @@ class Engine:
         self._stream_emit(req, [tok])
         self._maybe_finish(req)
 
-    def _run_decode_step(self) -> None:
+    def _run_decode_step(self, prefill_s: float) -> None:
         """One decode iteration for every slot whose prefill is done:
         the host-sampling path (one token per slot, numpy fallback
         sampler) or the fused fast path (sampling — and optionally
-        speculative verification — inside the compiled program)."""
+        speculative verification — inside the compiled program).  Both
+        are three spans: ``engine.decode.dispatch`` (CoW guard, slot
+        meta, table upload, launch), ``engine.decode.fetch`` (the wait
+        for the device) and ``engine.decode.commit`` (host sampling,
+        bookkeeping, stream emit).  ``prefill_s`` is this iteration's
+        ``engine.prefill`` wall, for the attribution split."""
         decoding = [
             (i, r) for i, r in enumerate(self._slots)
             if r is not None and r._prefill_done
         ]
         n_active = len(decoding)
         if self.fused_sampling:
-            self._decode_step_fused(decoding, n_active)
+            self._decode_step_fused(decoding, n_active, prefill_s)
             return
-        t_dec0 = time.time()
-        for i, _ in decoding:
-            # CoW guard: never write a shared or indexed block in place.
-            # Steady state this is a no-op (appends land past the shared
-            # prompt blocks) — it is what makes a future scheduler bug a
-            # local copy instead of cross-request cache corruption.
-            self.kv.ensure_writable(i, int(self.kv.seq_lens[i]))
-        self._refresh_slot_meta()
-        logits, self.kv.k_pool, self.kv.v_pool = self._decode(
-            self.params, self.kv.k_pool, self.kv.v_pool,
-            jnp.asarray(self._last_tokens), self._tables_dev(),
-            jnp.asarray(self.kv.seq_lens), self._dev_active,
-        )
-        logits = np.asarray(logits)
-        self.decode_steps += 1
-        self.counters["decode_dispatches"] += 1
-        self.counters["host_sample_rounds"] += 1
-        self.counters["slot_steps"] += n_active
-        self._m_occ.observe(float(n_active))
-        self.occupancy_max = max(self.occupancy_max, n_active)
+        with obs_tracing.span("engine.decode.dispatch") as s_dispatch:
+            for i, _ in decoding:
+                # CoW guard: never write a shared or indexed block in
+                # place.  Steady state this is a no-op (appends land past
+                # the shared prompt blocks) — it is what makes a future
+                # scheduler bug a local copy instead of cross-request
+                # cache corruption.
+                self.kv.ensure_writable(i, int(self.kv.seq_lens[i]))
+            self._refresh_slot_meta()
+            logits, self.kv.k_pool, self.kv.v_pool = self._decode(
+                self.params, self.kv.k_pool, self.kv.v_pool,
+                jnp.asarray(self._last_tokens), self._tables_dev(),
+                jnp.asarray(self.kv.seq_lens), self._dev_active,
+            )
+        with obs_tracing.span("engine.decode.fetch") as s_fetch:
+            logits = np.asarray(logits)
         now = time.time()
-        self._iter_device_s += now - t_dec0
-        decode_dt = now - t_dec0
-        for slot, req in decoding:
-            self.kv.note_written(slot, int(self.kv.seq_lens[slot]) + 1)
-            tok = self._sample(req, logits[slot])
-            self._charge_decode(req, now, decode_dt, spec=False)
-            self._commit_tokens(slot, req, [tok], n_active, now)
+        decode_dt = s_dispatch.dur_s + s_fetch.dur_s
+        with obs_tracing.span("engine.decode.commit"):
+            self.decode_steps += 1
+            self.counters["decode_dispatches"] += 1
+            self.counters["host_sample_rounds"] += 1
+            self.counters["slot_steps"] += n_active
+            self._m_occ.observe(float(n_active))
+            self.occupancy_max = max(self.occupancy_max, n_active)
+            for slot, req in decoding:
+                self.kv.note_written(slot, int(self.kv.seq_lens[slot]) + 1)
+                tok = self._sample(req, logits[slot])
+                self._charge_decode(req, now, decode_dt, prefill_s,
+                                    spec=False)
+                self._commit_tokens(slot, req, [tok], n_active, now)
 
-    def _charge_decode(self, req: GenRequest, now: float,
-                       decode_dt: float, spec: bool) -> None:
+    def _charge_decode(self, req: GenRequest, now: float, decode_dt: float,
+                       prefill_s: float, spec: bool) -> None:
         """Advance the request's attribution frontier to ``now``,
         splitting the interval exclusively: this iteration's decode
         dispatch wall to decode (or the speculative-verify component),
@@ -1104,7 +1135,7 @@ class Engine:
             req.attr_spec_s += d
         else:
             req.attr_decode_s += d
-        s = min(interval - d, max(self._iter_prefill_s, 0.0))
+        s = min(interval - d, max(prefill_s, 0.0))
         req.attr_stall_s += s
         req.attr_gap_s += interval - d - s
         req._t_attr = now
@@ -1128,7 +1159,8 @@ class Engine:
         self._stream_emit(req, kept)
         self._maybe_finish(req)
 
-    def _decode_step_fused(self, decoding, n_active: int) -> None:
+    def _decode_step_fused(self, decoding, n_active: int,
+                           prefill_s: float) -> None:
         """One fused decode iteration: build the (optional) draft
         window, dispatch ONE program, commit the emitted bursts.
 
@@ -1141,109 +1173,112 @@ class Engine:
         truncates the request's tokens AND retreats the K/V extent
         (``kv.rollback``), which by construction never crosses a
         shared (refcount > 1) prefix block."""
-        t_dec0 = time.time()
-        drafts: dict[int, list[int]] = {}
-        if self.speculate:
+        with obs_tracing.span("engine.decode.dispatch") as s_dispatch:
+            drafts: dict[int, list[int]] = {}
+            if self.speculate:
+                for i, r in decoding:
+                    cap = min(self.speculate,
+                              r.max_new_tokens - len(r.tokens) - 1)
+                    if cap > 0:
+                        # min_ngram=2: a single repeated token is mostly
+                        # coincidence on novel text, and every spurious
+                        # proposal pays the T=K+1 verify program for an
+                        # almost-surely-rejected draft — requiring a 2-gram
+                        # match keeps the low-hit-rate regression bounded
+                        # while leaving real repetition (>= 2-gram) intact.
+                        d = spec_draft.propose(
+                            r.prompt + r.tokens, cap,
+                            max_ngram=self.spec_ngram,
+                            min_ngram=min(2, self.spec_ngram),
+                        )
+                        if d:
+                            drafts[i] = d
+            # Program choice is per BATCH: one drafting slot routes every
+            # active slot through the T=K+1 program that iteration (static
+            # shapes — the non-drafting slots' extra positions are pad
+            # writes to scratch, but their forward compute still scales with
+            # T).  The draft-less fallback therefore helps exactly when NO
+            # slot drafts; a mixed batch pays the window for everyone, which
+            # is the right trade only while acceptance is healthy — the
+            # acceptance-rate telemetry is the dial to watch.
+            t_width = self.speculate + 1 if drafts else 1
             for i, r in decoding:
-                cap = min(self.speculate,
-                          r.max_new_tokens - len(r.tokens) - 1)
-                if cap > 0:
-                    # min_ngram=2: a single repeated token is mostly
-                    # coincidence on novel text, and every spurious
-                    # proposal pays the T=K+1 verify program for an
-                    # almost-surely-rejected draft — requiring a 2-gram
-                    # match keeps the low-hit-rate regression bounded
-                    # while leaving real repetition (>= 2-gram) intact.
-                    d = spec_draft.propose(
-                        r.prompt + r.tokens, cap,
-                        max_ngram=self.spec_ngram,
-                        min_ngram=min(2, self.spec_ngram),
-                    )
-                    if d:
-                        drafts[i] = d
-        # Program choice is per BATCH: one drafting slot routes every
-        # active slot through the T=K+1 program that iteration (static
-        # shapes — the non-drafting slots' extra positions are pad
-        # writes to scratch, but their forward compute still scales with
-        # T).  The draft-less fallback therefore helps exactly when NO
-        # slot drafts; a mixed batch pays the window for everyone, which
-        # is the right trade only while acceptance is healthy — the
-        # acceptance-rate telemetry is the dial to watch.
-        t_width = self.speculate + 1 if drafts else 1
-        for i, r in decoding:
-            s = int(self.kv.seq_lens[i])
-            self.kv.ensure_writable_range(
-                i, s, s + 1 + len(drafts.get(i, ())))
-        self._refresh_slot_meta()
-        draft_lens = np.zeros((self.max_slots,), np.int32)
-        if t_width > 1:
-            toks = np.zeros((self.max_slots, t_width), np.int32)
-            toks[:, 0] = self._last_tokens
-            for i, d in drafts.items():
-                toks[i, 1:1 + len(d)] = d
-                draft_lens[i] = len(d)
-            tokens_in = jnp.asarray(toks)
-            dev_draft_lens = jnp.asarray(draft_lens)
-            fn = self._fused_spec
-        else:
-            tokens_in = self._dev_tokens  # device-resident (B, 1) feed
-            dev_draft_lens = self._dev_zero_drafts
-            fn = self._fused1
-        packed, next_feed, self.kv.k_pool, self.kv.v_pool = fn(
-            self.params, self.kv.k_pool, self.kv.v_pool, tokens_in,
-            dev_draft_lens, self._tables_dev(),
-            jnp.asarray(self.kv.seq_lens), self._dev_active,
-            self._dev_keys, self._dev_prompt_lens, self._dev_temp,
-            self._dev_topk,
-        )
-        self._dev_tokens = next_feed
-        packed = np.asarray(packed)  # the ONE small host fetch per
-        out = packed[:, :-1]         # iteration (EOS / logging):
-        n_emit = packed[:, -1]       # emitted tokens + counts, packed
-        self.decode_steps += 1
-        self.counters["decode_dispatches"] += 1
-        self.counters["slot_steps"] += n_active
-        self._m_occ.observe(float(n_active))
-        self.occupancy_max = max(self.occupancy_max, n_active)
+                s = int(self.kv.seq_lens[i])
+                self.kv.ensure_writable_range(
+                    i, s, s + 1 + len(drafts.get(i, ())))
+            self._refresh_slot_meta()
+            draft_lens = np.zeros((self.max_slots,), np.int32)
+            if t_width > 1:
+                toks = np.zeros((self.max_slots, t_width), np.int32)
+                toks[:, 0] = self._last_tokens
+                for i, d in drafts.items():
+                    toks[i, 1:1 + len(d)] = d
+                    draft_lens[i] = len(d)
+                tokens_in = jnp.asarray(toks)
+                dev_draft_lens = jnp.asarray(draft_lens)
+                fn = self._fused_spec
+            else:
+                tokens_in = self._dev_tokens  # device-resident (B, 1) feed
+                dev_draft_lens = self._dev_zero_drafts
+                fn = self._fused1
+            packed, next_feed, self.kv.k_pool, self.kv.v_pool = fn(
+                self.params, self.kv.k_pool, self.kv.v_pool, tokens_in,
+                dev_draft_lens, self._tables_dev(),
+                jnp.asarray(self.kv.seq_lens), self._dev_active,
+                self._dev_keys, self._dev_prompt_lens, self._dev_temp,
+                self._dev_topk,
+            )
+            self._dev_tokens = next_feed
+        with obs_tracing.span("engine.decode.fetch") as s_fetch:
+            packed = np.asarray(packed)  # the ONE small host fetch per
+        out = packed[:, :-1]             # iteration (EOS / logging):
+        n_emit = packed[:, -1]           # emitted tokens + counts, packed
         now = time.time()
-        self._iter_device_s += now - t_dec0
-        decode_dt = now - t_dec0
-        for slot, req in decoding:
-            n = int(n_emit[slot])
-            emitted = [int(t) for t in out[slot, :n]]
-            k_drafted = int(draft_lens[slot])
-            accepted = n - 1
-            s = int(self.kv.seq_lens[slot])
-            # Commit the last input token + every ACCEPTED draft's K/V;
-            # rejected drafts' K/V sits past this extent (dead, masked,
-            # overwritten by the next append).
-            self.kv.note_written(slot, s + 1 + accepted)
-            kept = emitted
-            if req.eos_token_id is not None and req.eos_token_id in emitted:
-                kept = emitted[: emitted.index(req.eos_token_id) + 1]
-                if len(kept) < n:
-                    # tokens after the EOS never happened: retreat the
-                    # K/V extent past the discarded accepted drafts too
-                    self.kv.rollback(slot, s + len(kept))
-            if k_drafted:
-                # acceptance telemetry counts COMMITTED drafts: an
-                # accepted draft discarded by the EOS truncation above
-                # was rolled back as "never happened" and must not
-                # inflate the acceptance rate.  kept == emitted keeps
-                # `accepted`; a truncated burst is all-drafts.
-                committed = accepted if len(kept) == n else len(kept)
-                req.drafted += k_drafted
-                req.accepted += committed
-                self.counters["spec_drafted"] += k_drafted
-                self.counters["spec_accepted"] += committed
-                self._m_spec_drafted.inc(k_drafted)
-                if committed:
-                    self._m_spec_accepted.inc(committed)
-            # a T=K+1 (verify) dispatch charges the speculation
-            # component for EVERY active slot — a mixed batch pays the
-            # window for everyone, and the attribution should say so
-            self._charge_decode(req, now, decode_dt, spec=t_width > 1)
-            self._commit_tokens(slot, req, kept, n_active, now)
+        decode_dt = s_dispatch.dur_s + s_fetch.dur_s
+        with obs_tracing.span("engine.decode.commit"):
+            self.decode_steps += 1
+            self.counters["decode_dispatches"] += 1
+            self.counters["slot_steps"] += n_active
+            self._m_occ.observe(float(n_active))
+            self.occupancy_max = max(self.occupancy_max, n_active)
+            for slot, req in decoding:
+                n = int(n_emit[slot])
+                emitted = [int(t) for t in out[slot, :n]]
+                k_drafted = int(draft_lens[slot])
+                accepted = n - 1
+                s = int(self.kv.seq_lens[slot])
+                # Commit the last input token + every ACCEPTED draft's K/V;
+                # rejected drafts' K/V sits past this extent (dead, masked,
+                # overwritten by the next append).
+                self.kv.note_written(slot, s + 1 + accepted)
+                kept = emitted
+                if req.eos_token_id is not None \
+                        and req.eos_token_id in emitted:
+                    kept = emitted[: emitted.index(req.eos_token_id) + 1]
+                    if len(kept) < n:
+                        # tokens after the EOS never happened: retreat the
+                        # K/V extent past the discarded accepted drafts too
+                        self.kv.rollback(slot, s + len(kept))
+                if k_drafted:
+                    # acceptance telemetry counts COMMITTED drafts: an
+                    # accepted draft discarded by the EOS truncation above
+                    # was rolled back as "never happened" and must not
+                    # inflate the acceptance rate.  kept == emitted keeps
+                    # `accepted`; a truncated burst is all-drafts.
+                    committed = accepted if len(kept) == n else len(kept)
+                    req.drafted += k_drafted
+                    req.accepted += committed
+                    self.counters["spec_drafted"] += k_drafted
+                    self.counters["spec_accepted"] += committed
+                    self._m_spec_drafted.inc(k_drafted)
+                    if committed:
+                        self._m_spec_accepted.inc(committed)
+                # a T=K+1 (verify) dispatch charges the speculation
+                # component for EVERY active slot — a mixed batch pays the
+                # window for everyone, and the attribution should say so
+                self._charge_decode(req, now, decode_dt, prefill_s,
+                                    spec=t_width > 1)
+                self._commit_tokens(slot, req, kept, n_active, now)
 
     def _sample(self, req: GenRequest, logits: np.ndarray) -> int:
         """Host-side sampling fallback (``fused_sampling=False``):
@@ -1390,9 +1425,14 @@ class Engine:
         return self._crashed is None and not self._stopped
 
     def _run(self) -> None:
+        cap = self.capture
         while True:
             try:
+                if cap is not None:
+                    cap.maybe_start(self._step_id)
                 did = self.step()
+                if cap is not None:
+                    cap.maybe_stop(self._step_id)
             except Exception as e:  # noqa: BLE001 — fail every in-flight req
                 self._crashed = repr(e)
                 self._fail_all(f"engine loop error: {e!r}")
@@ -1401,7 +1441,8 @@ class Engine:
                 if self._stop_flag:
                     return
                 if not did and not self._queue:
-                    self._cond.wait(timeout=0.05)
+                    with obs_tracing.span("engine.wait"):
+                        self._cond.wait(timeout=0.05)
 
     def stop(self, drain: bool = True, timeout: float = 60.0) -> None:
         """Stop the loop.  ``drain=True`` (default) finishes in-flight and
@@ -1422,6 +1463,8 @@ class Engine:
                 self._cond.notify_all()
             self._thread.join(timeout=timeout)
             self._thread = None
+        if self.capture is not None:
+            self.capture.abort(self._step_id)  # close a still-open window
         self._stopped = True
         self._fail_all("engine stopped")
         self._log_metrics_row()

@@ -35,6 +35,12 @@ shapes, so a serving process compiles **exactly two** XLA executables:
 — the copy-on-write path — compiled only if a CoW ever fires.)
 
 The pool arrays are donated: steady-state serving does not allocate.
+
+Every stage of the programs sits in a ``jax.named_scope`` (``embed``,
+``cast_params``, per layer ``h<i>/{ln,qkv,kv_write,paged_attn,proj,mlp}``,
+``head``, ``sample``; ``kv_write`` in the prefill program, ``gather_cache``
+for the gather): metadata only, so a profiler trace can say which stage a
+device operation belongs to.  The arithmetic is unchanged.
 """
 
 from __future__ import annotations
@@ -120,24 +126,28 @@ def make_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int):
         logits, cache = prefill(params, tokens, positions, cfg=cfg,
                                 cache=cache)
         num_layers, nb_total, bs, h_kv, d = k_pool.shape
-        pos = start + jnp.arange(chunk)
-        idx = table_row[pos // block_size] * bs + pos % block_size  # (chunk,)
-        k_new = jnp.stack([
-            jax.lax.dynamic_slice_in_dim(
-                cache[f"h{i}"]["attn"]["cached_key"], start, chunk, axis=2
-            )[0].transpose(1, 0, 2)  # (chunk, Hkv, D)
-            for i in range(num_layers)
-        ])  # (L, chunk, Hkv, D)
-        v_new = jnp.stack([
-            jax.lax.dynamic_slice_in_dim(
-                cache[f"h{i}"]["attn"]["cached_value"], start, chunk, axis=2
-            )[0].transpose(1, 0, 2)
-            for i in range(num_layers)
-        ])
-        k_pool = k_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
-            .at[:, idx].set(k_new).reshape(k_pool.shape)
-        v_pool = v_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
-            .at[:, idx].set(v_new).reshape(v_pool.shape)
+        with jax.named_scope("kv_write"):
+            pos = start + jnp.arange(chunk)
+            idx = table_row[pos // block_size] * bs \
+                + pos % block_size  # (chunk,)
+            k_new = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(
+                    cache[f"h{i}"]["attn"]["cached_key"], start, chunk,
+                    axis=2,
+                )[0].transpose(1, 0, 2)  # (chunk, Hkv, D)
+                for i in range(num_layers)
+            ])  # (L, chunk, Hkv, D)
+            v_new = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(
+                    cache[f"h{i}"]["attn"]["cached_value"], start, chunk,
+                    axis=2,
+                )[0].transpose(1, 0, 2)
+                for i in range(num_layers)
+            ])
+            k_pool = k_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
+                .at[:, idx].set(k_new).reshape(k_pool.shape)
+            v_pool = v_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
+                .at[:, idx].set(v_new).reshape(v_pool.shape)
         return logits[0, last_ix], cache, k_pool, v_pool
 
     return prefill_chunk
@@ -161,6 +171,7 @@ def make_gather_cache_fn(cfg: GPTConfig, *, block_size: int):
     num_layers = cfg.num_layers
 
     @functools.partial(jax.jit, donate_argnums=(2,))
+    @jax.named_scope("gather_cache")
     def gather_cache(k_pool, v_pool, cache, table_row, start):
         _, nb_total, bs, h_kv, d = k_pool.shape
         pos = jnp.arange(cfg.max_seq)
@@ -179,6 +190,13 @@ def make_gather_cache_fn(cfg: GPTConfig, *, block_size: int):
         }
 
     return gather_cache
+
+
+def _cast(param, dtype):
+    """A stored (fp32) parameter in the compute dtype: the conversion the
+    decode programs repeat every iteration, under a scope of its own."""
+    with jax.named_scope("cast_params"):
+        return param.astype(dtype)
 
 
 def make_decode_fn(cfg: GPTConfig):
@@ -206,14 +224,16 @@ def make_decode_fn(cfg: GPTConfig):
     def _dense(x, kernel):
         # flax nn.Dense(dtype=cfg.dtype, use_bias=False): both operands
         # cast to the compute dtype, default accumulation.
-        return x @ kernel.astype(cfg.dtype)
+        return x @ _cast(kernel, cfg.dtype)
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def decode(params, k_pool, v_pool, tokens, block_tables, seq_lens,
                active):
         b = tokens.shape[0]
         _, nb_total, bs, _, _ = k_pool.shape
-        x = params["wte"]["embedding"].astype(cfg.dtype)[tokens][:, None, :]
+        with jax.named_scope("embed"):
+            x = _cast(params["wte"]["embedding"],
+                      cfg.dtype)[tokens][:, None, :]
         positions = seq_lens.astype(jnp.int32)[:, None]  # (B, 1)
         tabs = rope_tables(positions, head_dim, cfg.rope_theta, cfg.dtype)
         # Write coordinates for the new token: active slots append at
@@ -228,31 +248,41 @@ def make_decode_fn(cfg: GPTConfig):
         vf = v_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
         for layer in range(num_layers):
             p = params[f"h{layer}"]
-            h = _ln(x, p["ln1"])
-            qkv = _dense(h, p["attn"]["qkv"]["kernel"])
-            q = qkv[..., :hidden].reshape(b, 1, n_heads, head_dim)
-            k = qkv[..., hidden:hidden + kv_width].reshape(b, 1, h_kv,
-                                                           head_dim)
-            v = qkv[..., hidden + kv_width:].reshape(b, 1, h_kv, head_dim)
-            q = rope(q, positions, cfg.rope_theta, tabs)
-            k = rope(k, positions, cfg.rope_theta, tabs)
-            kf = kf.at[layer, idx].set(k[:, 0])
-            vf = vf.at[layer, idx].set(v[:, 0])
-            out = paged_decode_attention(
-                q[:, 0],
-                kf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                vf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                block_tables, attend_lens,
-            ).reshape(b, 1, hidden).astype(cfg.dtype)
-            x = x + _dense(out, p["attn"]["proj"]["kernel"])
-            h = _ln(x, p["ln2"])
-            m = _dense(jax.nn.gelu(_dense(h, p["fc_in"]["kernel"])),
-                       p["fc_out"]["kernel"])
-            x = x + m
-        xf = _ln(x, params["ln_f"], out_dtype=jnp.float32)
-        logits = tied_head_logits(
-            xf[:, 0], params["wte"]["embedding"], cfg.dtype
-        )
+            with jax.named_scope(f"h{layer}"):
+                with jax.named_scope("ln"):
+                    h = _ln(x, p["ln1"])
+                with jax.named_scope("qkv"):
+                    qkv = _dense(h, p["attn"]["qkv"]["kernel"])
+                    q = qkv[..., :hidden].reshape(b, 1, n_heads, head_dim)
+                    k = qkv[..., hidden:hidden + kv_width].reshape(
+                        b, 1, h_kv, head_dim)
+                    v = qkv[..., hidden + kv_width:].reshape(
+                        b, 1, h_kv, head_dim)
+                    q = rope(q, positions, cfg.rope_theta, tabs)
+                    k = rope(k, positions, cfg.rope_theta, tabs)
+                with jax.named_scope("kv_write"):
+                    kf = kf.at[layer, idx].set(k[:, 0])
+                    vf = vf.at[layer, idx].set(v[:, 0])
+                with jax.named_scope("paged_attn"):
+                    out = paged_decode_attention(
+                        q[:, 0],
+                        kf[layer].reshape(nb_total, bs, h_kv, head_dim),
+                        vf[layer].reshape(nb_total, bs, h_kv, head_dim),
+                        block_tables, attend_lens,
+                    ).reshape(b, 1, hidden).astype(cfg.dtype)
+                with jax.named_scope("proj"):
+                    x = x + _dense(out, p["attn"]["proj"]["kernel"])
+                with jax.named_scope("ln"):
+                    h = _ln(x, p["ln2"])
+                with jax.named_scope("mlp"):
+                    m = _dense(jax.nn.gelu(_dense(h, p["fc_in"]["kernel"])),
+                               p["fc_out"]["kernel"])
+                    x = x + m
+        with jax.named_scope("head"):
+            xf = _ln(x, params["ln_f"], out_dtype=jnp.float32)
+            logits = tied_head_logits(
+                xf[:, 0], params["wte"]["embedding"], cfg.dtype
+            )
         return logits, kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
 
     return decode
@@ -306,7 +336,7 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
                           out_dtype=out_dtype or x.dtype)
 
     def _dense(x, kernel):
-        return x @ kernel.astype(cfg.dtype)
+        return x @ _cast(kernel, cfg.dtype)
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def fused_decode(params, k_pool, v_pool, tokens, draft_lens,
@@ -315,7 +345,9 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
         b = tokens.shape[0]
         _, nb_total, bs, _, _ = k_pool.shape
         nb_table = block_tables.shape[1]
-        x = params["wte"]["embedding"].astype(cfg.dtype)[tokens]  # (B,T,H)
+        with jax.named_scope("embed"):
+            x = _cast(params["wte"]["embedding"],
+                      cfg.dtype)[tokens]                        # (B, T, H)
         positions = (seq_lens[:, None]
                      + jnp.arange(t_width, dtype=jnp.int32)[None, :])
         tabs = rope_tables(positions, head_dim, cfg.rope_theta, cfg.dtype)
@@ -338,43 +370,54 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
         vf = v_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
         for layer in range(num_layers):
             p = params[f"h{layer}"]
-            h = _ln(x, p["ln1"])
-            qkv = _dense(h, p["attn"]["qkv"]["kernel"])
-            q = qkv[..., :hidden].reshape(b, t_width, n_heads, head_dim)
-            k = qkv[..., hidden:hidden + kv_width].reshape(
-                b, t_width, h_kv, head_dim)
-            v = qkv[..., hidden + kv_width:].reshape(
-                b, t_width, h_kv, head_dim)
-            q = rope(q, positions, cfg.rope_theta, tabs)
-            k = rope(k, positions, cfg.rope_theta, tabs)
-            kf = kf.at[layer, idx.reshape(-1)].set(
-                k.reshape(b * t_width, h_kv, head_dim))
-            vf = vf.at[layer, idx.reshape(-1)].set(
-                v.reshape(b * t_width, h_kv, head_dim))
-            out = paged_verify_attention(
-                q,
-                kf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                vf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                block_tables, attend_lens,
-            ).reshape(b, t_width, hidden).astype(cfg.dtype)
-            x = x + _dense(out, p["attn"]["proj"]["kernel"])
-            h = _ln(x, p["ln2"])
-            m = _dense(jax.nn.gelu(_dense(h, p["fc_in"]["kernel"])),
-                       p["fc_out"]["kernel"])
-            x = x + m
-        xf = _ln(x, params["ln_f"], out_dtype=jnp.float32)
-        logits = tied_head_logits(
-            xf, params["wte"]["embedding"], cfg.dtype
-        )                                                       # (B, T, V)
+            with jax.named_scope(f"h{layer}"):
+                with jax.named_scope("ln"):
+                    h = _ln(x, p["ln1"])
+                with jax.named_scope("qkv"):
+                    qkv = _dense(h, p["attn"]["qkv"]["kernel"])
+                    q = qkv[..., :hidden].reshape(
+                        b, t_width, n_heads, head_dim)
+                    k = qkv[..., hidden:hidden + kv_width].reshape(
+                        b, t_width, h_kv, head_dim)
+                    v = qkv[..., hidden + kv_width:].reshape(
+                        b, t_width, h_kv, head_dim)
+                    q = rope(q, positions, cfg.rope_theta, tabs)
+                    k = rope(k, positions, cfg.rope_theta, tabs)
+                with jax.named_scope("kv_write"):
+                    kf = kf.at[layer, idx.reshape(-1)].set(
+                        k.reshape(b * t_width, h_kv, head_dim))
+                    vf = vf.at[layer, idx.reshape(-1)].set(
+                        v.reshape(b * t_width, h_kv, head_dim))
+                with jax.named_scope("paged_attn"):
+                    out = paged_verify_attention(
+                        q,
+                        kf[layer].reshape(nb_total, bs, h_kv, head_dim),
+                        vf[layer].reshape(nb_total, bs, h_kv, head_dim),
+                        block_tables, attend_lens,
+                    ).reshape(b, t_width, hidden).astype(cfg.dtype)
+                with jax.named_scope("proj"):
+                    x = x + _dense(out, p["attn"]["proj"]["kernel"])
+                with jax.named_scope("ln"):
+                    h = _ln(x, p["ln2"])
+                with jax.named_scope("mlp"):
+                    m = _dense(jax.nn.gelu(_dense(h, p["fc_in"]["kernel"])),
+                               p["fc_out"]["kernel"])
+                    x = x + m
+        with jax.named_scope("head"):
+            xf = _ln(x, params["ln_f"], out_dtype=jnp.float32)
+            logits = tied_head_logits(
+                xf, params["wte"]["embedding"], cfg.dtype
+            )                                                   # (B, T, V)
         # Emitted-token index of each slot's next sample, derived
         # on-device (decode invariant: seq_len = prompt + emitted - 1)
         # so the host ships nothing per step that it can avoid —
         # prompt_lens changes only at admission.
-        sample_pos = jnp.maximum(seq_lens - prompt_lens + 1, 0)
-        out_tokens, n_emitted, next_feed = sample_burst(
-            logits, tokens, draft_lens, keys, sample_pos, temperature,
-            top_k, active,
-        )
+        with jax.named_scope("sample"):
+            sample_pos = jnp.maximum(seq_lens - prompt_lens + 1, 0)
+            out_tokens, n_emitted, next_feed = sample_burst(
+                logits, tokens, draft_lens, keys, sample_pos, temperature,
+                top_k, active,
+            )
         # out_tokens and n_emitted packed into ONE array so the host
         # pays a single small device->host fetch per iteration;
         # next_feed keeps the feed shape (B, 1) so the next T=1 call

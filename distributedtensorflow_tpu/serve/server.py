@@ -136,7 +136,7 @@ class ServeServer:
         """``GET /stepz`` — live tail of the engine step log: the newest
         ``n`` (default 32) per-iteration records from the bounded ring
         (phase mix, occupancy, token/draft deltas, admissions/evictions,
-        prefill chunks + budget stalls, host-vs-device wall split) —
+        prefill chunks + budget stalls, admit/prefill/decode walls) —
         the same records ``steps.jsonl`` persists."""
         from urllib.parse import parse_qs
 

@@ -275,8 +275,9 @@ def _step_body(loss_fn: LossFn, accum_steps: int, overlap=None,
         grads, metrics, new_mstate = accumulate_gradients(
             loss_fn, state.params, state.model_state, batch, r, accum_steps
         )
-        new_state = state.apply_gradients(grads).replace(
-            model_state=new_mstate)
+        with jax.named_scope("optimizer"):  # a name for the trace
+            new_state = state.apply_gradients(grads).replace(
+                model_state=new_mstate)
         if dynamics_every > 0:
             from ..obs import dynamics as dynlib
 

@@ -226,6 +226,11 @@ class Trainer:
         self.stop_training = False
         self.writer = MetricWriter(config.logdir)
         self.meter = ThroughputMeter(config.global_batch_size)
+        #: The entry point's start-up phases (obs.PhaseTrace) with
+        #: ``startup.first_step`` open, or None: the first dispatch of the
+        #: first fit names its batch wait and compile-or-load, waits for
+        #: the step once, and closes it.
+        self.startup_trace: obs.PhaseTrace | None = None
         #: Span recorder for the current fit (obs.TraceRecorder); feeds the
         #: step-time breakdown and writes <logdir>/trace.jsonl.
         self.tracer: obs.TraceRecorder | None = None
@@ -647,8 +652,20 @@ class Trainer:
                     # k_eff may have shrunk during the fetch (short
                     # prebundled tail); relabel the row with final values.
                     self.tracer.adjust_step(step_next, k_eff)
+                startup, self.startup_trace = self.startup_trace, None
+                if startup is not None:
+                    startup.mark("startup.first_batch",
+                                 parent="startup.first_step")
                 with obs.span("train_step"):
                     state, metrics = self.train_step(state, batch, rng)
+                if startup is not None:
+                    # the call returns once the program is compiled (or
+                    # loaded) and launched; the step itself ends when
+                    # its metrics exist
+                    startup.mark("startup.compile_or_load",
+                                 parent="startup.first_step")
+                    jax.block_until_ready(metrics)
+                    startup.close("startup.first_step", step=step_next)
                 if k > 1:  # stacked (k_eff, ...) metrics; report the last
                     metrics = jax.tree.map(lambda v: v[-1], metrics)
                 self.meter.update(k_eff)

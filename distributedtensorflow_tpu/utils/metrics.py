@@ -89,9 +89,12 @@ class MetricWriter:
                         tf.summary.scalar(k, v)
             self._tb.flush()
         if self._jsonl is not None:
+            # `t`: when the row was written, as steps.jsonl and
+            # requests.jsonl rows carry (jsonl only: not a TB scalar)
             self._jsonl.write(
-                json.dumps(json_sanitize({"step": step, **scalars}),
-                           allow_nan=False) + "\n"
+                json.dumps(json_sanitize(
+                    {"step": step, "t": round(time.time(), 6), **scalars}),
+                    allow_nan=False) + "\n"
             )
             self._jsonl.flush()
 

@@ -25,13 +25,16 @@ On startup one JSON line goes to stdout — ``{"serving": true, "port": N,
 
 from __future__ import annotations
 
-import argparse
-import json
-import logging
-import signal
-import sys
-import threading
 import time
+
+T_PROCESS_START = time.time()  # start-up spans count from here
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import logging  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
 
 
 #: --config choice -> (GPTConfig factory name, matching train.py workload).
@@ -171,8 +174,8 @@ def main(argv=None) -> int:
     p.add_argument("--step-ring", type=int, default=512,
                    help="engine step-log ring size: every scheduler "
                         "iteration leaves one structured record (phase "
-                        "mix, occupancy, token deltas, host-vs-device "
-                        "wall split) in a bounded ring served at GET "
+                        "mix, occupancy, token deltas, phase wall "
+                        "split) in a bounded ring served at GET "
                         "/stepz and appended to <logdir>/steps.jsonl")
     p.add_argument("--history-interval", type=float, default=2.0,
                    help="embedded metrics history store (obs.tsdb): "
@@ -215,20 +218,31 @@ def main(argv=None) -> int:
     )
 
     from distributedtensorflow_tpu import models, runtime
+    from distributedtensorflow_tpu.obs.capture import (
+        CaptureEngine,
+        install_engine,
+    )
+    from distributedtensorflow_tpu.obs.tracing import PhaseTrace, TraceRecorder
+    from distributedtensorflow_tpu.serve import Engine, ServeServer
 
+    # Start-up as spans with absolute time (trace_id "startup" in
+    # <logdir>/trace.jsonl): each mark names the stretch since the last.
+    startup = PhaseTrace("startup", T_PROCESS_START)
+    startup.mark("startup.imports")
     runtime.init_compile_cache()
     device = runtime.device_summary()  # also initialises the backend
     logging.info("device: %s", json.dumps(device))
-    from distributedtensorflow_tpu.serve import Engine, ServeServer
-
-    cfg = getattr(models, CONFIGS[args.config][0])()
-    params = build_params(args, cfg)
+    startup.mark("startup.backend")
     # Distributed request tracing: with a logdir, every completed request
     # leaves queue/prefill/decode spans in <logdir>/trace.jsonl keyed by
     # its trace_id (client-suppliable via POST /generatez) — the stream
-    # tools/timeline.py --fleet stitches across processes.
+    # tools/timeline.py --fleet stitches across processes.  Installed
+    # before the parameters are built, so start-up lands in it too; no
+    # step rows: the engine's iteration trees go to the step log and the
+    # profiler, not to this file.
     tracer = None
     flight = None
+    capture = None
     if args.logdir:
         import os
 
@@ -236,10 +250,9 @@ def main(argv=None) -> int:
             FlightRecorder,
             install_recorder,
         )
-        from distributedtensorflow_tpu.obs.tracing import TraceRecorder
 
         tracer = TraceRecorder(
-            os.path.join(args.logdir, "trace.jsonl")
+            os.path.join(args.logdir, "trace.jsonl"), step_rows=False
         ).install()
         # Flight ring for lifecycle forensics: the drain-timeout
         # `exception` event (and anything else record_event raises)
@@ -249,6 +262,18 @@ def main(argv=None) -> int:
         )
         install_recorder(flight)
         flight.install_crash_hooks()
+        # Profiler windows an operator can ask for: POST /profilez?steps=N
+        # on the status server arms one, the engine loop opens and closes
+        # it by iteration, <logdir>/captures/<id>/ holds the trace with
+        # the engine.* spans beside the device lanes.
+        capture = CaptureEngine(args.logdir)
+        install_engine(capture)
+    cfg = getattr(models, CONFIGS[args.config][0])()
+    params = build_params(args, cfg)
+    import jax
+
+    jax.block_until_ready(params)  # init is asynchronous: charge it here
+    startup.mark("startup.init_params", config=args.config)
     engine = Engine(
         params, cfg,
         max_slots=args.max_slots, max_queue=args.max_queue,
@@ -262,7 +287,9 @@ def main(argv=None) -> int:
         max_context=args.max_context,
         max_new_cap=args.max_new_cap, logdir=args.logdir,
         log_every=args.log_every, step_ring=args.step_ring,
+        capture=capture,
     ).start()
+    startup.mark("startup.engine_build")
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation
     # endpoint (text / ?json / ?tenant= filter; usage.jsonl under
@@ -356,6 +383,7 @@ def main(argv=None) -> int:
         "max_slots": args.max_slots, "logdir": args.logdir,
         "device": device,
     }), flush=True)
+    startup.mark("startup.listen", port=server.port)
     logging.info(
         "serving %s on %s:%d (slots=%d queue=%d block=%d prefix_cache=%s "
         "prefill_budget=%s fused_sampling=%s speculate=%d)",
@@ -408,6 +436,8 @@ def main(argv=None) -> int:
         # stopped after the engine drain: the final tick snapshots the
         # completed run's counters into history.jsonl
         history.stop()
+    if capture is not None:
+        install_engine(None)
     if tracer is not None:
         tracer.uninstall()
         tracer.close()

@@ -8,6 +8,7 @@ producer, so it gets its own surface.
 
 import json
 import sys
+import time
 
 import jax
 import pytest
@@ -15,8 +16,14 @@ import pytest
 from distributedtensorflow_tpu.utils.metrics import MetricWriter, ThroughputMeter
 
 
-def _rows(path):
-    return [json.loads(line) for line in path.read_text().splitlines()]
+def _rows(path, keep_t=False):
+    """The file's rows; every `write` row carries `t`, the unix time it
+    was written (ISSUE 24) — checked here, dropped unless asked for."""
+    rows = [json.loads(line) for line in path.read_text().splitlines()]
+    for row in rows:
+        if "step" in row and not keep_t:
+            assert abs(row.pop("t") - time.time()) < 60
+    return rows
 
 
 def test_writer_jsonl_schema(tmp_path):
@@ -28,6 +35,10 @@ def test_writer_jsonl_schema(tmp_path):
         {"step": 10, "loss": 1.5, "accuracy": 0.25},
         {"step": 20, "loss": 1.0},
     ]
+    # rows are stamped at their write, in order (jsonl only, not a scalar)
+    stamps = [r["t"] for r in _rows(tmp_path / "metrics.jsonl", keep_t=True)]
+    assert stamps == sorted(stamps) and all(
+        isinstance(t, float) for t in stamps)
     # every value a number, step an int — the check_metrics_schema contract
     for row in rows:
         assert isinstance(row["step"], int)
@@ -42,6 +53,7 @@ def test_writer_encodes_non_finite_as_strict_json(tmp_path):
     row = json.loads(line, parse_constant=lambda c: pytest.fail(
         f"bare {c} token in jsonl"
     ))
+    assert row.pop("t") > 0
     assert row == {"step": 3, "loss": "NaN", "grad_norm": "Infinity"}
 
 

@@ -8,7 +8,10 @@ harness's tf.summary-only floor.
 
 import json
 import math
+import os
+import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -193,6 +196,118 @@ def test_span_is_exception_transparent():
     with pytest.raises(KeyError):
         with obs.span("x"):
             raise KeyError("k")
+
+
+def test_span_enters_a_profiler_annotation(monkeypatch):
+    """Every span is mirrored into the profiler under its own name with
+    its keyword attributes (the profiler itself is stubbed: no real trace
+    in tier-1), nested in span order, and stays exception-transparent."""
+    from distributedtensorflow_tpu.obs import tracing
+
+    events = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            events.append(("enter", self.name, self.attrs))
+
+        def __exit__(self, exc_type, exc, tb):
+            events.append(("exit", self.name, exc_type))
+
+    monkeypatch.setattr(tracing, "_TraceAnnotation", FakeAnnotation)
+    with obs.span("engine.step", step=7) as root:
+        with obs.span("engine.decode"):
+            pass
+    assert events == [
+        ("enter", "engine.step", {"step": 7}),
+        ("enter", "engine.decode", {}),
+        ("exit", "engine.decode", None),
+        ("exit", "engine.step", None),
+    ]
+    assert [c.name for c in root.children] == ["engine.decode"]
+    del events[:]
+    with pytest.raises(StopIteration):
+        with obs.span("data_wait"):
+            raise StopIteration
+    assert events[-1] == ("exit", "data_wait", StopIteration)
+
+
+def test_span_annotation_is_the_profilers_own():
+    # unpatched: the class a span enters is jax.profiler.TraceAnnotation
+    import jax.profiler
+
+    from distributedtensorflow_tpu.obs import tracing
+
+    with obs.span("x", step=1):
+        pass
+    assert tracing._TraceAnnotation is jax.profiler.TraceAnnotation
+
+
+def test_trace_recorder_without_step_rows_buffers_nothing(tmp_path):
+    rec = TraceRecorder(str(tmp_path / "trace.jsonl"), step_rows=False)
+    with rec:
+        for _ in range(50):
+            with obs.span("engine.step"):
+                pass
+        assert rec._roots == []
+        assert rec.drain_window()["engine.step"] > 0
+    assert (tmp_path / "trace.jsonl").read_text() == ""
+
+
+def _rows(path):
+    return [json.loads(line) for line in path.read_text().splitlines()]
+
+
+def test_phase_trace_rows_tile_time_under_one_trace_id(tmp_path):
+    """Start-up phases: marks made before a recorder exists wait for it,
+    every row is a kind:"span" row under the one trace_id, top-level rows
+    follow one another with nothing between, children point at their
+    open parent, and the schema checker takes the file."""
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(__file__), "..", "tools"))
+    import check_metrics_schema
+
+    path = tmp_path / "trace.jsonl"
+    t0 = time.time() - 5.0
+    phases = obs.PhaseTrace("startup", t0)
+    phases.mark("startup.imports")          # no recorder yet: waits
+    phases.mark("startup.backend")
+    assert not path.exists()
+    with TraceRecorder(str(path)):
+        phases.mark("startup.workload", workload="w")
+        phases.open("startup.first_step")
+        phases.mark("startup.first_batch", parent="startup.first_step")
+        phases.mark("startup.compile_or_load", parent="startup.first_step")
+        phases.close("startup.first_step", step=1)
+    rows = _rows(path)
+    assert [r["name"] for r in rows] == [
+        "startup.imports", "startup.backend", "startup.workload",
+        "startup.first_batch", "startup.compile_or_load",
+        "startup.first_step"]
+    assert {r["kind"] for r in rows} == {"span"}
+    assert {r["trace_id"] for r in rows} == {"startup"}
+    assert rows[0]["t0"] == pytest.approx(t0) and rows[0]["dur_s"] >= 5.0
+    top = [r for r in rows if "parent_id" not in r]
+    for a, b in zip(top, top[1:]):
+        assert b["t0"] == pytest.approx(a["t0"] + a["dur_s"], abs=2e-6)
+    first_step = rows[-1]
+    kids = [r for r in rows if "parent_id" in r]
+    assert {r["parent_id"] for r in kids} == {first_step["span_id"]}
+    assert kids[0]["t0"] == pytest.approx(first_step["t0"], abs=2e-6)
+    assert kids[1]["t0"] + kids[1]["dur_s"] <= \
+        first_step["t0"] + first_step["dur_s"] + 2e-6
+    assert rows[2]["workload"] == "w" and first_step["step"] == 1
+    assert check_metrics_schema.check_file(str(path)) == ([], [])
+    # an overlap or a foreign trace_id is an error
+    bad = tmp_path / "trace_bad.jsonl"
+    rows[1]["t0"] -= 1.0
+    rows[2]["trace_id"] = "other"
+    bad.write_text("".join(json.dumps(r) + "\n" for r in rows))
+    errors, _ = check_metrics_schema.check_file(str(bad))
+    assert any("before the previous start-up phase" in e for e in errors)
+    assert any("not 'startup'" in e for e in errors)
 
 
 def test_trace_recorder_writes_step_rows(tmp_path):

@@ -561,8 +561,7 @@ def _step_row(t, step, **kw):
         "admitted": 0, "evicted": 0, "prefill_chunks": 0,
         "budget_stall": 0, "tokens_committed": 2, "spec_drafted": 0,
         "spec_accepted": 0, "admit_s": 0.0, "prefill_s": 0.0,
-        "decode_s": 0.004, "step_s": 0.005, "device_s": 0.003,
-        "host_s": 0.002,
+        "decode_s": 0.004, "step_s": 0.005,
     }
     row.update(kw)
     return row
@@ -637,7 +636,7 @@ def test_steps_schema_rejects_bad_rows(tmp_path):
         _step_row(100.3, 4, spec_drafted=1, spec_accepted=2),
         _step_row(100.4, 5, admit_s=0.004, prefill_s=0.004,
                   decode_s=0.004, step_s=0.005),  # phases exceed the step
-        _step_row(100.5, 6, device_s=0.009, step_s=0.005),
+        _step_row(100.5, 6, decode_s=-0.001),  # a negative wall
     ])
     errors, _ = check_metrics_schema.check_file(str(p))
     joined = "\n".join(errors)
@@ -646,7 +645,7 @@ def test_steps_schema_rejects_bad_rows(tmp_path):
     assert "phase" in joined
     assert "budget_stall" in joined
     assert "spec_accepted" in joined
-    assert "step_s" in joined and "device_s" in joined
+    assert "exceeds step_s" in joined and "'decode_s' -0.001" in joined
     assert check_metrics_schema.main([str(p)]) == 1
 
 

@@ -218,7 +218,22 @@ def test_serve_smoke_concurrent_requests(tmp_path):
     assert tail_doc["coverage"]["covered_share"] >= 0.95
     assert tail_doc["evidence"]["overall"]["steps"] > 0
 
-    # and all four serving streams are schema-clean
+    # start-up left its phases as spans: one trace_id, in order, each
+    # starting where the one before ends (ISSUE 24)
+    with open(os.path.join(logdir, "trace.jsonl")) as f:
+        spans = [json.loads(line) for line in f if line.strip()]
+    startup = [r for r in spans if r["name"].startswith("startup.")]
+    assert [r["name"] for r in startup] == [
+        "startup.imports", "startup.backend", "startup.init_params",
+        "startup.engine_build", "startup.listen"]
+    assert {r["kind"] for r in startup} == {"span"}
+    assert {r["trace_id"] for r in startup} == {"startup"}
+    for a, b in zip(startup, startup[1:]):
+        assert abs(b["t0"] - (a["t0"] + a["dur_s"])) <= 2e-6
+    # no per-iteration row in trace.jsonl: requests and start-up only
+    assert not [r for r in spans if r["name"].startswith("engine.")]
+
+    # and all five serving streams are schema-clean
     assert os.path.exists(os.path.join(logdir, "steps.jsonl"))
     assert os.path.exists(os.path.join(logdir, "history.jsonl"))
     chk = subprocess.run(
@@ -227,6 +242,7 @@ def test_serve_smoke_concurrent_requests(tmp_path):
          os.path.join(logdir, "requests.jsonl"),
          os.path.join(logdir, "metrics.jsonl"),
          os.path.join(logdir, "steps.jsonl"),
+         os.path.join(logdir, "trace.jsonl"),
          os.path.join(logdir, "history.jsonl")],
         capture_output=True, text=True, timeout=120,
     )

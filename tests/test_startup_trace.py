@@ -1,0 +1,78 @@
+"""Start-up as spans (ISSUE 24): ``train.py`` run in-process leaves the
+``startup.*`` rows in its ``trace.jsonl`` — one trace_id, in order, tiling
+the time from the top of the file to the end of the first optimizer step —
+and its ``metrics.jsonl`` rows carry the time they were written.
+(``serve.py`` installs signal handlers, so its rows are checked where it
+runs as a process: tests/test_serve_smoke.py.)"""
+
+import json
+import os
+import sys
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "tools"))
+import check_metrics_schema as checker  # noqa: E402
+
+TRAIN_PHASES = [
+    "startup.imports", "startup.backend", "startup.workload",
+    "startup.state_init", "startup.trainer", "startup.data",
+    "startup.first_step",
+]
+
+
+def startup_rows(path):
+    with open(path) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    return [r for r in rows if r.get("kind") == "span"
+            and r["name"].startswith("startup.")]
+
+
+def assert_tiles(rows, phases, max_unnamed_s=0.05):
+    """Top-level start-up rows are exactly ``phases``, in order, each
+    starting where the one before ends."""
+    assert {r["trace_id"] for r in rows} == {"startup"}
+    assert len({r["proc"] for r in rows}) == 1
+    top = [r for r in rows if "parent_id" not in r]
+    assert [r["name"] for r in top] == phases
+    for a, b in zip(top, top[1:]):
+        gap = b["t0"] - (a["t0"] + a["dur_s"])
+        assert -2e-6 <= gap <= max_unnamed_s, (a["name"], b["name"], gap)
+    return top
+
+
+def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch):
+    import train
+
+    logdir = tmp_path / "run"
+    monkeypatch.setattr(sys, "argv", [
+        "train.py", "--workload", "mnist_lenet", "--test-size", "--steps",
+        "4", "--log-every", "2", "--batch-size", "16", "--device", "cpu",
+        "--logdir", str(logdir)])
+    t_before = time.time()
+    train.main()
+    t_after = time.time()
+    rows = startup_rows(logdir / "trace.jsonl")
+    top = assert_tiles(rows, TRAIN_PHASES)
+    # the interval starts at the module's own stamp and ends inside main()
+    assert top[0]["t0"] == pytest.approx(train.T_PROCESS_START, abs=1e-5)
+    first_step = top[-1]
+    assert t_before <= first_step["t0"] + first_step["dur_s"] <= t_after
+    assert first_step["step"] == 1
+    kids = [r for r in rows if "parent_id" in r]
+    assert [r["name"] for r in kids] == [
+        "startup.first_batch", "startup.compile_or_load"]
+    assert {r["parent_id"] for r in kids} == {first_step["span_id"]}
+    assert sum(r["dur_s"] for r in kids) <= first_step["dur_s"] + 2e-6
+    # rows written at their phase's end: file order is time order
+    ends = [r["t0"] + r["dur_s"] for r in rows]
+    assert ends == sorted(ends)
+    assert checker.check_file(str(logdir / "trace.jsonl")) == ([], [])
+    with open(logdir / "metrics.jsonl") as f:
+        metrics = [json.loads(line) for line in f if line.strip()]
+    assert [m["step"] for m in metrics] == [2, 4]
+    ts = [m["t"] for m in metrics]
+    assert ts == sorted(ts) and t_before <= ts[0] and ts[-1] <= t_after
+    assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []

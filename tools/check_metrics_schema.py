@@ -25,7 +25,11 @@ step-log schema (serve/engine.py: strictly-increasing ``step`` ids,
 non-decreasing ``t``, known phase tokens, non-negative counts, phase
 wall split tiling ``step_s``, plus — when present — the ISSUE-19
 non-negative ``kv_blocks_billed`` census and an ``admitted_tenants``
-breakdown summing to ``admitted``); basenames starting with ``usage``
+breakdown summing to ``admitted``); basenames starting with ``trace`` and ending
+``.jsonl`` against the span-trace schema (obs/tracing.py: step rows of
+span trees, anomaly events, ``kind: "span"`` rows, and the ``startup.*``
+phases sharing ``trace_id`` ``"startup"`` and tiling time in order);
+basenames starting with ``usage``
 against the per-tenant usage-ledger schema (obs/usage.py: t-ordered
 ``tenants`` rollup rows with identifier-style tenant names, non-negative
 cumulative integrals that never decrease, per-``request`` closeout rows
@@ -324,8 +328,8 @@ REQUEST_ATTR_FIELDS = (
 #: Engine step-log schema (serve/engine.py ``_log_step``, ISSUE 16):
 #: phase tokens of the per-iteration ``phase`` field, the non-negative
 #: integer count fields, and the non-negative finite wall-split fields
-#: (``admit_s + prefill_s + decode_s == step_s`` up to rounding;
-#: ``device_s <= step_s``).
+#: (``admit_s + prefill_s + decode_s <= step_s`` up to rounding: the
+#: durations of the iteration's ``engine.*`` span tree).
 STEP_PHASE_TOKENS = ("admit", "prefill", "decode")
 STEP_COUNT_FIELDS = (
     "occupancy", "active_slots", "filling_slots", "queue_depth",
@@ -333,7 +337,7 @@ STEP_COUNT_FIELDS = (
     "tokens_committed", "spec_drafted", "spec_accepted",
 )
 STEP_WALL_FIELDS = (
-    "admit_s", "prefill_s", "decode_s", "step_s", "device_s", "host_s",
+    "admit_s", "prefill_s", "decode_s", "step_s",
 )
 
 #: Per-tenant usage ledger schema (obs/usage.py ``UsageMeter``, ISSUE 19
@@ -1204,6 +1208,106 @@ def check_requests_file(path: str) -> tuple[list[str], list[str]]:
     return errors, warnings
 
 
+def _nonneg_finite(v) -> bool:
+    return not isinstance(v, bool) and isinstance(v, (int, float)) \
+        and math.isfinite(v) and v >= 0
+
+
+def _check_trace_span(row: dict, i: int) -> list[str]:
+    errors = []
+    for key in ("name", "trace_id", "span_id"):
+        if not isinstance(row.get(key), str) or not row[key]:
+            errors.append(f"line {i}: span {key!r} {row.get(key)!r} is "
+                          "not a non-empty string")
+    if "parent_id" in row and not isinstance(row["parent_id"], str):
+        errors.append(f"line {i}: span 'parent_id' is not a string")
+    for key in ("t0", "dur_s"):
+        if not _nonneg_finite(row.get(key)):
+            errors.append(f"line {i}: span {key!r} {row.get(key)!r} is "
+                          "not a non-negative finite number")
+    if isinstance(row.get("proc"), bool) \
+            or not isinstance(row.get("proc"), int):
+        errors.append(f"line {i}: span 'proc' {row.get('proc')!r} is not "
+                      "an integer")
+    return errors
+
+
+def check_trace_file(path: str) -> tuple[list[str], list[str]]:
+    """Validate a ``trace.jsonl`` (obs/tracing.py): per-step span-tree
+    rows (``step`` an integer or null, ``spans`` a list of
+    ``{"name", "dur_s", "children"?}`` trees, ``k`` and ``t_wall`` on
+    anchored rows), ``kind: "anomaly"`` events, and ``kind: "span"``
+    cross-process rows (string ``name``/``trace_id``/``span_id``,
+    absolute ``t0``, ``dur_s >= 0``, integer ``proc``).  The start-up
+    phases (``startup.*`` spans, one process's ``PhaseTrace``) all carry
+    ``trace_id`` ``"startup"``, and those with no ``parent_id`` tile time
+    in file order: none starts before the one before it ends."""
+    errors: list[str] = []
+
+    def tree_errors(node, i) -> list[str]:
+        if not isinstance(node, dict) or not isinstance(
+                node.get("name"), str):
+            return [f"line {i}: span tree node {node!r} has no name"]
+        out = []
+        if not _nonneg_finite(node.get("dur_s")):
+            out.append(f"line {i}: span {node['name']!r} dur_s "
+                       f"{node.get('dur_s')!r} is not a non-negative "
+                       "finite number")
+        for c in node.get("children", []):
+            out.extend(tree_errors(c, i))
+        return out
+
+    startup_end: dict[int, float] = {}   # proc -> end of its last phase
+    with open(path) as f:
+        for i, line in enumerate(f, start=1):
+            line = line.strip()
+            if not line:
+                continue
+            try:
+                row = json.loads(line)
+            except json.JSONDecodeError as e:
+                errors.append(f"line {i}: invalid JSON ({e})")
+                continue
+            if not isinstance(row, dict):
+                errors.append(f"line {i}: not a JSON object")
+                continue
+            kind = row.get("kind")
+            if kind == "span":
+                errs = _check_trace_span(row, i)
+                errors.extend(errs)
+                name = row.get("name")
+                if errs or not name.startswith("startup."):
+                    continue
+                if row["trace_id"] != "startup":
+                    errors.append(f"line {i}: {name!r} has trace_id "
+                                  f"{row['trace_id']!r}, not 'startup'")
+                if "parent_id" not in row:
+                    end = startup_end.get(row["proc"])
+                    if end is not None and row["t0"] < end - 1e-5:
+                        errors.append(
+                            f"line {i}: {name!r} starts at {row['t0']:.6f}"
+                            f", before the previous start-up phase ends "
+                            f"({end:.6f})")
+                    startup_end[row["proc"]] = row["t0"] + row["dur_s"]
+            elif kind == "anomaly":
+                if not isinstance(row.get("anomaly"), str):
+                    errors.append(f"line {i}: anomaly row has no kind name")
+            elif kind is not None:
+                errors.append(f"line {i}: unknown trace row kind {kind!r}")
+            else:
+                step = row.get("step", "missing")
+                if step is not None and (isinstance(step, bool)
+                                         or not isinstance(step, int)):
+                    errors.append(f"line {i}: 'step' {step!r} is neither "
+                                  "an integer nor null")
+                if not isinstance(row.get("spans"), list):
+                    errors.append(f"line {i}: step row has no 'spans' list")
+                    continue
+                for node in row["spans"]:
+                    errors.extend(tree_errors(node, i))
+    return errors, []
+
+
 def check_steps_file(path: str) -> tuple[list[str], list[str]]:
     """Validate one engine step log ``steps.jsonl`` (serve/engine.py
     ``_log_step``; docs/API.md "Serving observability"): every row one
@@ -1213,8 +1317,8 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
     non-negative integer count fields (:data:`STEP_COUNT_FIELDS`, with
     ``budget_stall`` in {0, 1} and ``spec_accepted <= spec_drafted``),
     and non-negative finite wall fields whose phase split tiles the
-    iteration: ``admit_s + prefill_s + decode_s <= step_s`` and
-    ``device_s <= step_s`` (up to rounding)."""
+    iteration: ``admit_s + prefill_s + decode_s <= step_s`` (up to
+    rounding)."""
     errors: list[str] = []
     warnings: list[str] = []
     prev_t: float | None = None
@@ -1299,12 +1403,6 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
                         f"{parts:.6f} exceeds step_s "
                         f"{walls['step_s']:.6f}"
                     )
-            if "device_s" in walls and "step_s" in walls \
-                    and walls["device_s"] > walls["step_s"] + 1e-5:
-                errors.append(
-                    f"line {i}: device_s {walls['device_s']:.6f} exceeds "
-                    f"step_s {walls['step_s']:.6f}"
-                )
             # per-tenant usage accounting (ISSUE 19; validated when
             # present so pre-ISSUE-19 logs stay green): the pool's
             # refcount-weighted block census at the iteration boundary,
@@ -2547,6 +2645,9 @@ def check_file(path: str) -> tuple[list[str], list[str]]:
         return check_requests_file(path)
     if os.path.basename(path).startswith("steps"):
         return check_steps_file(path)
+    if os.path.basename(path).startswith("trace") \
+            and path.endswith(".jsonl"):
+        return check_trace_file(path)
     if os.path.basename(path).startswith("usage"):
         return check_usage_file(path)
     if os.path.basename(path).startswith("history"):

@@ -14,14 +14,17 @@ Examples:
 
 from __future__ import annotations
 
-import argparse
-import json
-import logging
-import os
 import time
 
-import jax
-import numpy as np
+T_PROCESS_START = time.time()  # start-up spans count from here
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import logging  # noqa: E402
+import os  # noqa: E402
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
 
 
 #: --remat CLI choice -> get_workload(remat=...) value; single mapping
@@ -971,8 +974,15 @@ def main() -> None:
         make_eval_step,
         make_train_step,
     )
+    from distributedtensorflow_tpu.obs.tracing import PhaseTrace
     from distributedtensorflow_tpu.train.trainer import Trainer, TrainerConfig
     from distributedtensorflow_tpu.workloads import get_workload
+
+    # Start-up as spans with absolute time (trace_id "startup" in
+    # <logdir>/trace.jsonl): each mark names the stretch since the last;
+    # rows wait until the pre-fit recorder below exists.
+    startup = PhaseTrace("startup", T_PROCESS_START)
+    startup.mark("startup.imports")
 
     # Goodput ledger FIRST (before mesh/state/restore) so setup time is
     # honestly booked as `init` — the generation starts here.  Re-loads a
@@ -992,6 +1002,7 @@ def main() -> None:
     device = (runtime.require_tpu() if args.device == "tpu"
               else runtime.device_summary())
     logging.info("device: %s", json.dumps(device))
+    startup.mark("startup.backend")
     if args.profiler_port is not None:
         from distributedtensorflow_tpu.utils import profiler
 
@@ -1078,6 +1089,7 @@ def main() -> None:
         optimizer = wl.make_optimizer(_concrete_decay_mask(wl, rng))
     else:
         optimizer = wl.make_optimizer()
+    startup.mark("startup.workload", workload=wl.name)
     state, specs = create_sharded_state(
         wl.init_fn, optimizer, mesh, rng,
         rules=wl.layout, fsdp=wl.fsdp, zero=zero_sharder,
@@ -1164,6 +1176,8 @@ def main() -> None:
                 "cannot fire"
             )
 
+    jax.block_until_ready(state)  # init is asynchronous: charge it here
+    startup.mark("startup.state_init")
     ctx = current_input_context(wl.global_batch_size)
 
     # Disaggregated input (--data-service N): a loopback dispatcher + N
@@ -1474,6 +1488,9 @@ def main() -> None:
         callbacks=[cb for cb in (chaos, dynamics_monitor, elastic)
                    if cb is not None] or None,
     )
+    # the input-plane services, the restore, the trainer with its metric
+    # writer (TensorBoard import) and status server
+    startup.mark("startup.trainer")
     if dynamics_monitor is not None and trainer.status_server is not None:
         dynamics_monitor.install(trainer.status_server)
     if elastic is not None:
@@ -1767,6 +1784,14 @@ def main() -> None:
     supervise = chaos is not None or args.max_restarts > 0
     try:
         with trainer:  # closes the metric writer on every exit path
+            if not supervise:
+                train_iter = make_train_iter(restored_step)
+            # the monitors behind the trainer and the input iterator.
+            # The trainer ends start-up: it closes startup.first_step
+            # after its first step.
+            startup.mark("startup.data")
+            startup.open("startup.first_step")
+            trainer.startup_trace = startup
             if supervise:
                 from distributedtensorflow_tpu.resilience import (
                     RestartBudgetExhausted,
@@ -1827,7 +1852,6 @@ def main() -> None:
                         goodput_ledger.close(ended="failed")
                     raise SystemExit(4)
             else:
-                train_iter = make_train_iter(restored_step)
                 while True:
                     state = trainer.fit(
                         state, train_iter, rng, eval_iter_fn=eval_iter_fn
