@@ -20,9 +20,17 @@ weights from a seed), and checks what comes out:
                   prompt lengths; streamed greedy tokens equal the blocking
                   reply; no KV block leaked; SIGTERM drains to exit 0 with
                   ``requests.jsonl`` and the final ``metrics.jsonl`` row;
-4. ``resnet50``   ``train.py --workload imagenet_resnet50 --batch-size 128``
+4. ``pool_form``  ``python -m distributedtensorflow_tpu.serve.pool_check`` at
+                  the shapes of the benchmark's serving cells (GPT-2
+                  medium, 32 slots, 2048 K/V blocks of 16 tokens): the six
+                  programs that take the paged K/V pool compile, and none
+                  holds — outside scope ``paged_attn`` — a ``copy``,
+                  ``transpose`` or ``convert`` of a layer of the pool or
+                  more; the five that return the pool alias it in place;
+                  the pool's resident layout is printed;
+5. ``resnet50``   ``train.py --workload imagenet_resnet50 --batch-size 128``
                   (the BASELINE metric's model: conv path + image input);
-5. with four devices: ``gpt_lm`` on ``--mesh data=4 --batch-size 64`` —
+6. with four devices: ``gpt_lm`` on ``--mesh data=4 --batch-size 64`` —
                   every Mosaic call sees a quarter of the batch, the step
                   takes about what one chip takes for batch 16, and the
                   loss matches a one-chip run of the same global batch
@@ -377,6 +385,46 @@ def serve_leg(out: str) -> dict:
 # --- the run ------------------------------------------------------------------------
 
 
+# --- leg 4: the paged K/V pool stays in place --------------------------------
+
+#: ``serve.py``'s settings in the benchmark's two serving cells
+#: (benchmark/configs/gpt2-medium-serve.json), and a draft width for the
+#: speculative program.
+POOL_CELL = ["--config", "gpt_medium", "--max-slots", "32", "--kv-blocks",
+             "2048", "--block-size", "16", "--max-context", "1024",
+             "--prefill-chunk", "16", "--speculate", "4"]
+
+
+def pool_form_leg(out: str) -> dict:
+    """The mechanism's counter (PERF.md, PR 25): pool-sized layout or
+    dtype changes in the six pool programs, compiled for this chip.  It is
+    0 or it is not; the child exits non-zero and names them if it is not."""
+    log, wall = run_child(
+        "pool_form",
+        ["-m", "distributedtensorflow_tpu.serve.pool_check", *POOL_CELL],
+        os.path.join(out, "pool_form"), timeout=1200,
+    )
+    with open(log) as f:
+        rows = [line for line in f if line.startswith('{"device"')]
+    check(len(rows) == 1, f"pool_form: no report line\n{tail(log)}")
+    r = json.loads(rows[0])
+    require_tpu("pool_form", r["device"])
+    check(sorted(r["programs"]) == sorted(
+        ["prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
+         "gather_cache", "copy_block"]),
+        f"pool_form: checked only {sorted(r['programs'])}")
+    compiles = ", ".join(f"{name} {p['compile_s']:.1f}s"
+                         for name, p in r["programs"].items())
+    print(f"chip_smoke: pool_form ok in {wall:.0f}s: pool "
+          f"{r['pool_dtype']}{r['pool_shape']} = {r['pool_bytes'] / 1e9:.3f} "
+          f"GB each, resident layout {r['resident_layout']}, taken as "
+          f"{r['programs']['decode']['k_pool']} by all six programs; no "
+          f"pool-sized copy, transpose or convert outside paged_attn; "
+          f"k_pool and v_pool aliased in place by the five that return "
+          f"them (compile: {compiles})", flush=True)
+    return r
+
+
 def next_run_dir() -> str:
     root = os.path.join(REPO, "chiprun_out", "chip_smoke")
     n = 1
@@ -405,6 +453,7 @@ def main() -> None:
         steps=60, lm=True,
     )
     serve_leg(out)
+    pool_form_leg(out)
     train_leg(
         "resnet50", out,
         ["--workload", "imagenet_resnet50", "--batch-size", "128"], steps=30,

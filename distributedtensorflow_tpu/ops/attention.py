@@ -156,12 +156,32 @@ def cached_decode_attention(
     return out, cached_k, cached_v, ix + s_new
 
 
+def _gather_pages(pool, layer, block_tables, block_size, head_dim):
+    """The page-table walk: layer ``layer`` of the stacked pool, ``(L,
+    num_blocks * block_size, Hkv * D)`` token rows (``serve.kv_cache``
+    "Stored form"), gathered through ``block_tables`` to ``(B, Hkv, cap,
+    D)``.  Whole blocks of rows are gathered (splitting the row dimension
+    into (block, offset) moves no data where a block is a whole number of
+    tiles) and the layer is an index of the same gather, so no layer of
+    the pool is sliced out first; the heads are split on the gathered data
+    only, never on the pool."""
+    b, max_blocks = block_tables.shape
+    num_layers, rows, width = pool.shape
+    x = pool.reshape(num_layers, rows // block_size, block_size,
+                     width)[layer, block_tables]
+    return x.reshape(b, max_blocks * block_size, width // head_dim,
+                     head_dim).transpose(0, 2, 1, 3)
+
+
 def paged_decode_attention(
     q: jax.Array,             # (B, H, D) one new query per serving slot
-    k_pool: jax.Array,        # (num_blocks, block_size, Hkv, D) shared pool
-    v_pool: jax.Array,        # (num_blocks, block_size, Hkv, D)
+    k_pool: jax.Array,        # (L, num_blocks * block_size, Hkv * D) rows
+    v_pool: jax.Array,        # (L, num_blocks * block_size, Hkv * D)
     block_tables: jax.Array,  # (B, max_blocks) int32 physical block ids
     seq_lens: jax.Array,      # (B,) int32 valid tokens incl. this step's
+    *,
+    layer: int,               # which layer of the stacked pools to attend
+    block_size: int,          # rows per physical block
 ) -> jax.Array:
     """Single-token decode attention against a paged (block-pool) KV cache.
 
@@ -170,9 +190,11 @@ def paged_decode_attention(
     live in a pool of fixed-size blocks shared by every slot and each
     slot's ``block_tables`` row names the blocks that hold its sequence —
     so a finished or short sequence pins only the blocks it actually
-    used (``serve.kv_cache`` owns allocation).  Blockwise layout per
+    used (``serve.kv_cache`` owns allocation, and says why the pool is
+    stored as token rows of all heads).  Blockwise layout per
     ``ops/blockwise.py``'s chunking idiom: the sequence axis is tiled in
-    ``block_size`` chunks, here scattered through the pool.
+    ``block_size`` chunks, here scattered through the pool: block ``p`` is
+    rows ``[p * block_size, (p + 1) * block_size)``.
 
     Each slot gathers its blocks to a ``(max_blocks * block_size, Hkv,
     D)`` view, masks positions ``>= seq_lens`` (and whatever a scratch /
@@ -185,12 +207,9 @@ def paged_decode_attention(
     is O(allocated blocks).
     """
     b, h, d = q.shape
-    nb, block_size, h_kv, _ = k_pool.shape
-    cap = block_tables.shape[1] * block_size
-    # (B, max_blocks, bs, Hkv, D) -> (B, Hkv, cap, D); the gather is the
-    # page-table walk.
-    k = k_pool[block_tables].reshape(b, cap, h_kv, d).transpose(0, 2, 1, 3)
-    v = v_pool[block_tables].reshape(b, cap, h_kv, d).transpose(0, 2, 1, 3)
+    k = _gather_pages(k_pool, layer, block_tables, block_size, d)
+    v = _gather_pages(v_pool, layer, block_tables, block_size, d)
+    h_kv, cap = k.shape[1], k.shape[2]
     valid = jnp.arange(cap)[None, :] < seq_lens[:, None]  # (B, cap)
     if h != h_kv:  # GQA: grouped einsums, pool never broadcast to H
         g = h // h_kv
@@ -219,10 +238,13 @@ def paged_decode_attention(
 
 def paged_verify_attention(
     q: jax.Array,             # (B, T, H, D) draft-window queries per slot
-    k_pool: jax.Array,        # (num_blocks, block_size, Hkv, D) shared pool
-    v_pool: jax.Array,        # (num_blocks, block_size, Hkv, D)
+    k_pool: jax.Array,        # (L, num_blocks * block_size, Hkv * D) rows
+    v_pool: jax.Array,        # (L, num_blocks * block_size, Hkv * D)
     block_tables: jax.Array,  # (B, max_blocks) int32 physical block ids
     attend_lens: jax.Array,   # (B,) int32 valid tokens for query 0
+    *,
+    layer: int,               # which layer of the stacked pools to attend
+    block_size: int,          # rows per physical block
 ) -> jax.Array:
     """Multi-token decode attention against the paged pool (speculative
     verification).
@@ -241,10 +263,9 @@ def paged_verify_attention(
     Returns ``(B, T, H, D)``.
     """
     b, t, h, d = q.shape
-    nb, block_size, h_kv, _ = k_pool.shape
-    cap = block_tables.shape[1] * block_size
-    k = k_pool[block_tables].reshape(b, cap, h_kv, d).transpose(0, 2, 1, 3)
-    v = v_pool[block_tables].reshape(b, cap, h_kv, d).transpose(0, 2, 1, 3)
+    k = _gather_pages(k_pool, layer, block_tables, block_size, d)
+    v = _gather_pages(v_pool, layer, block_tables, block_size, d)
+    h_kv, cap = k.shape[1], k.shape[2]
     # (B, T, cap): query t of slot b sees positions < attend_lens[b] + t
     valid = (jnp.arange(cap)[None, None, :]
              < (attend_lens[:, None] + jnp.arange(t)[None, :])[:, :, None])

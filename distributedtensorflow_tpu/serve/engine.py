@@ -332,7 +332,7 @@ class Engine:
         )
         self._prefill = make_prefill_fn(self.cfg, chunk=prefill_chunk,
                                         block_size=block_size)
-        self._decode = make_decode_fn(self.cfg)
+        self._decode = make_decode_fn(self.cfg, block_size=block_size)
         self.fused_sampling = bool(fused_sampling)
         self.speculate = speculate
         self.spec_ngram = int(spec_ngram)

@@ -62,14 +62,40 @@ steps.  Single-writer by design: only the engine loop thread touches a
 ``PagedKVCache`` (the HTTP threads go through the engine's queue), so
 there are no locks here.
 
-Layout: ``(num_layers, num_blocks + 1, block_size, kv_heads, head_dim)``
-per pool — one stacked array for all layers so the decode program indexes
-layers without a pytree of leaves.  The extra physical block at index
-``num_blocks`` is the **scratch block**: inactive slots' writes land
-there (static-shape decode steps always write ``max_slots`` tokens), and
-unallocated page-table entries point at it, so no masking is needed on
-the write path and garbage reads are confined to slots whose outputs the
-engine discards anyway.
+Stored form (:func:`pool_shape`): ``(num_layers, (num_blocks + 1) *
+block_size, kv_heads * head_dim)`` per pool — **token rows**, the heads
+folded into the minor dimension, one stacked array for all layers so the
+decode program indexes layers without a pytree of leaves.  Physical block
+``b`` is rows ``[b * block_size, (b + 1) * block_size)``.  This is the one
+form every program that takes the pool computes in (``serve.model``'s
+prefill chunk, decode, fused decode and cache gather; the block copy
+below), and none of them reshapes it: a K/V write scatters ``(tokens,
+kv_heads * head_dim)`` rows at ``block * block_size + offset``, the
+page-table walk gathers whole blocks of rows, and the heads are split only
+on what was gathered.
+
+Why rows and not ``(..., block_size, kv_heads, head_dim)``: on the TPU an
+array lives in (8, 128) tiles of its two minor dimensions, a 64-wide
+``head_dim`` would fill half of each tile, and so the runtime's layout for
+that 5-D bf16 pool made the *block* dimension minor-most
+(``{1,4,3,2,0:T(8,128)(2,1)}``).  The programs scatter and gather token
+rows, so each converted the whole donated pool to row-major on entry and
+back on exit — 36.6 ms of a 121 ms decode iteration and 39 of a prefill
+chunk's 40 ms on a v5e with a 3.2 GB pool, and a pool-sized temporary
+that kept a pool above a third of HBM from compiling at all (PERF.md §5,
+PR 25).  ``kv_heads * head_dim`` is a multiple of 128 for every preset, so
+rows need no padding, the resident layout is the computing layout, and the
+donated input is the output's buffer.  Any block size and dtype is
+correct; a block that is a multiple of the dtype's sublane tile (16 rows
+for bf16) is a whole number of tiles, which is what keeps the (block,
+offset) split of the row dimension free.  ``serve.pool_check`` reads all
+of this off the compiled programs.
+
+The extra physical block at index ``num_blocks`` is the **scratch block**:
+inactive slots' writes land there (static-shape decode steps always write
+``max_slots`` tokens), and unallocated page-table entries point at it, so
+no masking is needed on the write path and garbage reads are confined to
+slots whose outputs the engine discards anyway.
 """
 
 from __future__ import annotations
@@ -88,17 +114,31 @@ class OutOfBlocksError(RuntimeError):
     instead."""
 
 
-@functools.lru_cache(maxsize=1)
-def _copy_block_fn():
-    """Compiled pool-level block copy (the copy-on-write program).
+def pool_shape(num_layers: int, num_blocks: int, block_size: int,
+               kv_heads: int, head_dim: int) -> tuple[int, int, int]:
+    """Shape of one K or V pool of ``num_blocks`` blocks plus the scratch
+    block: token rows of all heads (module docstring, "Stored form")."""
+    return (num_layers, (num_blocks + 1) * block_size, kv_heads * head_dim)
+
+
+@functools.lru_cache(maxsize=None)
+def _copy_block_fn(block_size: int):
+    """Compiled pool-level block copy (the copy-on-write program): the
+    ``block_size`` rows of block ``src`` over those of ``dst``, in every
+    layer, in place in the donated pools.
 
     Compiled lazily on the first CoW — steady-state serving with
     full-block prefix sharing never triggers it (see module docstring)."""
 
+    def copy_rows(pool, src, dst):
+        rows = jax.lax.dynamic_slice_in_dim(pool, src * block_size,
+                                            block_size, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(pool, rows,
+                                                   dst * block_size, axis=1)
+
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def copy_block(k_pool, v_pool, src, dst):
-        return (k_pool.at[:, dst].set(k_pool[:, src]),
-                v_pool.at[:, dst].set(v_pool[:, src]))
+        return copy_rows(k_pool, src, dst), copy_rows(v_pool, src, dst)
 
     return copy_block
 
@@ -258,10 +298,12 @@ class SlotPages:
 class PagedKVCache:
     """Block-pool KV storage for ``max_slots`` concurrent sequences.
 
-    Device arrays (``k_pool``/``v_pool``) are created once and threaded
-    functionally through the serving programs; the engine assigns the
-    updated arrays back after every call.  Host state (page tables,
-    lengths, the prefix index) advances in lockstep on the engine thread.
+    Device arrays (``k_pool``/``v_pool``, each :func:`pool_shape`: token
+    rows of all heads) are created once and threaded functionally through
+    the serving programs, which donate them and update them in place; the
+    engine assigns the updated arrays back after every call.  Host state
+    (page tables, lengths, the prefix index) advances in lockstep on the
+    engine thread.
     """
 
     def __init__(self, *, num_layers: int, kv_heads: int, head_dim: int,
@@ -280,7 +322,8 @@ class PagedKVCache:
         self.blocks_per_slot = max_context // block_size
         self.scratch_block = num_blocks  # reserved physical block
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
-        shape = (num_layers, num_blocks + 1, block_size, kv_heads, head_dim)
+        shape = pool_shape(num_layers, num_blocks, block_size, kv_heads,
+                           head_dim)
         self.k_pool = jnp.zeros(shape, dtype)
         self.v_pool = jnp.zeros(shape, dtype)
         # Unallocated entries point at the scratch block (always a legal
@@ -464,7 +507,7 @@ class PagedKVCache:
                     "block but the pool is exhausted"
                 )
             dst = fresh[0]
-            self.k_pool, self.v_pool = _copy_block_fn()(
+            self.k_pool, self.v_pool = _copy_block_fn(self.block_size)(
                 self.k_pool, self.v_pool, jnp.int32(b), jnp.int32(dst)
             )
             self.allocator.decref(b)

@@ -34,7 +34,17 @@ shapes, so a serving process compiles **exactly two** XLA executables:
 (There is also a tiny pool-level block-copy program in ``serve.kv_cache``
 — the copy-on-write path — compiled only if a CoW ever fires.)
 
-The pool arrays are donated: steady-state serving does not allocate.
+The pool arrays are donated: steady-state serving does not allocate.  All
+of these programs take a pool in the one form ``serve.kv_cache`` stores it
+in — ``(layers, (num_blocks + 1) * block_size, Hkv * D)``, token rows with
+the heads folded into the minor dimension — and none reshapes it: a K/V
+write scatters ``(tokens, Hkv * D)`` rows at ``block * block_size +
+offset``, the page-table walk gathers whole blocks of rows with the layer
+as an index of the same gather, and heads are split only on what was
+gathered.  So the donated input aliases the output and XLA adds no
+pool-sized copy on entry or exit (with a ``(..., block, Hkv, D)`` pool it
+converted all of it both ways on every call: PERF.md §5, PR 25);
+``serve.pool_check`` reads that off the compiled programs.
 
 Every stage of the programs sits in a ``jax.named_scope`` (``embed``,
 ``cast_params``, per layer ``h<i>/{ln,qkv,kv_write,paged_attn,proj,mlp}``,
@@ -116,7 +126,8 @@ def make_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int):
     ``last_ix`` the in-chunk index whose logits the engine wants (the
     final prompt token's, clamped into range on non-final chunks whose
     logits are discarded).  The chunk's K/V are sliced out of the dense
-    flax cache and scattered to the slot's pool blocks."""
+    flax cache and scattered, as ``(chunk, Hkv * D)`` token rows, to the
+    slot's pool blocks."""
     _check_servable(cfg)
 
     @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
@@ -125,29 +136,27 @@ def make_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int):
         positions = (start + jnp.arange(chunk, dtype=jnp.int32))[None, :]
         logits, cache = prefill(params, tokens, positions, cfg=cfg,
                                 cache=cache)
-        num_layers, nb_total, bs, h_kv, d = k_pool.shape
+        num_layers, _, width = k_pool.shape
+
+        def chunk_rows(name):
+            # per layer (1, Hkv, max_seq, D) -> the chunk's (chunk, Hkv * D)
+            return jnp.stack([
+                jax.lax.dynamic_slice_in_dim(
+                    cache[f"h{i}"]["attn"][name], start, chunk, axis=2,
+                )[0].transpose(1, 0, 2).reshape(chunk, width)
+                for i in range(num_layers)
+            ])  # (L, chunk, Hkv * D)
+
         with jax.named_scope("kv_write"):
             pos = start + jnp.arange(chunk)
-            idx = table_row[pos // block_size] * bs \
-                + pos % block_size  # (chunk,)
-            k_new = jnp.stack([
-                jax.lax.dynamic_slice_in_dim(
-                    cache[f"h{i}"]["attn"]["cached_key"], start, chunk,
-                    axis=2,
-                )[0].transpose(1, 0, 2)  # (chunk, Hkv, D)
-                for i in range(num_layers)
-            ])  # (L, chunk, Hkv, D)
-            v_new = jnp.stack([
-                jax.lax.dynamic_slice_in_dim(
-                    cache[f"h{i}"]["attn"]["cached_value"], start, chunk,
-                    axis=2,
-                )[0].transpose(1, 0, 2)
-                for i in range(num_layers)
-            ])
-            k_pool = k_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
-                .at[:, idx].set(k_new).reshape(k_pool.shape)
-            v_pool = v_pool.reshape(num_layers, nb_total * bs, h_kv, d) \
-                .at[:, idx].set(v_new).reshape(v_pool.shape)
+            idx = table_row[pos // block_size] * block_size \
+                + pos % block_size  # (chunk,) pool rows
+            # (layer, row) index pairs, not ``.at[:, idx]``: for a scatter
+            # over a whole leading dimension XLA re-lays the operand out
+            # rows-major and copies the pool in and out (serve.pool_check)
+            layers = jnp.arange(num_layers)[:, None]
+            k_pool = k_pool.at[layers, idx].set(chunk_rows("cached_key"))
+            v_pool = v_pool.at[layers, idx].set(chunk_rows("cached_value"))
         return logits[0, last_ix], cache, k_pool, v_pool
 
     return prefill_chunk
@@ -173,17 +182,20 @@ def make_gather_cache_fn(cfg: GPTConfig, *, block_size: int):
     @functools.partial(jax.jit, donate_argnums=(2,))
     @jax.named_scope("gather_cache")
     def gather_cache(k_pool, v_pool, cache, table_row, start):
-        _, nb_total, bs, h_kv, d = k_pool.shape
         pos = jnp.arange(cfg.max_seq)
-        idx = table_row[pos // block_size] * bs + pos % bs
-        kf = k_pool.reshape(num_layers, nb_total * bs, h_kv, d)[:, idx]
-        vf = v_pool.reshape(num_layers, nb_total * bs, h_kv, d)[:, idx]
-        # (L, max_seq, Hkv, D) -> per-layer (1, Hkv, max_seq, D), the flax
-        # decode-cache layout make_prefill_cache builds.
+        idx = table_row[pos // block_size] * block_size + pos % block_size
+
+        def dense(pool, i):
+            # the slot's (max_seq, Hkv * D) rows of layer i -> (1, Hkv,
+            # max_seq, D), the flax decode-cache layout
+            # make_prefill_cache builds.
+            return pool[i, idx].reshape(
+                cfg.max_seq, cfg.kv_heads, -1).transpose(1, 0, 2)[None]
+
         return {
             f"h{i}": {"attn": {
-                "cached_key": kf[i].transpose(1, 0, 2)[None],
-                "cached_value": vf[i].transpose(1, 0, 2)[None],
+                "cached_key": dense(k_pool, i),
+                "cached_value": dense(v_pool, i),
                 "cache_index": start.astype(jnp.int32),
             }}
             for i in range(num_layers)
@@ -199,7 +211,7 @@ def _cast(param, dtype):
         return param.astype(dtype)
 
 
-def make_decode_fn(cfg: GPTConfig):
+def make_decode_fn(cfg: GPTConfig, *, block_size: int):
     """Compiled program (b): one decode token for every slot.
 
     ``fn(params, k_pool, v_pool, tokens, block_tables, seq_lens, active)
@@ -230,7 +242,8 @@ def make_decode_fn(cfg: GPTConfig):
     def decode(params, k_pool, v_pool, tokens, block_tables, seq_lens,
                active):
         b = tokens.shape[0]
-        _, nb_total, bs, _, _ = k_pool.shape
+        bs = block_size
+        scratch_row = k_pool.shape[1] - bs  # first row of the scratch block
         with jax.named_scope("embed"):
             x = _cast(params["wte"]["embedding"],
                       cfg.dtype)[tokens][:, None, :]
@@ -241,11 +254,8 @@ def make_decode_fn(cfg: GPTConfig):
         blk = jnp.take_along_axis(
             block_tables, (seq_lens // bs)[:, None], axis=1
         )[:, 0]
-        idx = jnp.where(active, blk * bs + seq_lens % bs,
-                        (nb_total - 1) * bs)
+        idx = jnp.where(active, blk * bs + seq_lens % bs, scratch_row)
         attend_lens = jnp.where(active, seq_lens + 1, 1)
-        kf = k_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
-        vf = v_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
         for layer in range(num_layers):
             p = params[f"h{layer}"]
             with jax.named_scope(f"h{layer}"):
@@ -261,14 +271,14 @@ def make_decode_fn(cfg: GPTConfig):
                     q = rope(q, positions, cfg.rope_theta, tabs)
                     k = rope(k, positions, cfg.rope_theta, tabs)
                 with jax.named_scope("kv_write"):
-                    kf = kf.at[layer, idx].set(k[:, 0])
-                    vf = vf.at[layer, idx].set(v[:, 0])
+                    k_pool = k_pool.at[layer, idx].set(
+                        k.reshape(b, kv_width))
+                    v_pool = v_pool.at[layer, idx].set(
+                        v.reshape(b, kv_width))
                 with jax.named_scope("paged_attn"):
                     out = paged_decode_attention(
-                        q[:, 0],
-                        kf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                        vf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                        block_tables, attend_lens,
+                        q[:, 0], k_pool, v_pool, block_tables,
+                        attend_lens, layer=layer, block_size=bs,
                     ).reshape(b, 1, hidden).astype(cfg.dtype)
                 with jax.named_scope("proj"):
                     x = x + _dense(out, p["attn"]["proj"]["kernel"])
@@ -283,7 +293,7 @@ def make_decode_fn(cfg: GPTConfig):
             logits = tied_head_logits(
                 xf[:, 0], params["wte"]["embedding"], cfg.dtype
             )
-        return logits, kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
+        return logits, k_pool, v_pool
 
     return decode
 
@@ -343,7 +353,8 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
                      block_tables, seq_lens, active, keys, prompt_lens,
                      temperature, top_k):
         b = tokens.shape[0]
-        _, nb_total, bs, _, _ = k_pool.shape
+        bs = block_size
+        scratch_row = k_pool.shape[1] - bs  # first row of the scratch block
         nb_table = block_tables.shape[1]
         with jax.named_scope("embed"):
             x = _cast(params["wte"]["embedding"],
@@ -364,10 +375,8 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
             block_tables, jnp.clip(positions // bs, 0, nb_table - 1), axis=1
         )
         idx = jnp.where(valid_w, blk * bs + positions % bs,
-                        (nb_total - 1) * bs)                    # (B, T)
+                        scratch_row).reshape(-1)                # (B * T,)
         attend_lens = jnp.where(active, seq_lens + 1, 1)
-        kf = k_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
-        vf = v_pool.reshape(num_layers, nb_total * bs, h_kv, head_dim)
         for layer in range(num_layers):
             p = params[f"h{layer}"]
             with jax.named_scope(f"h{layer}"):
@@ -384,16 +393,14 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
                     q = rope(q, positions, cfg.rope_theta, tabs)
                     k = rope(k, positions, cfg.rope_theta, tabs)
                 with jax.named_scope("kv_write"):
-                    kf = kf.at[layer, idx.reshape(-1)].set(
-                        k.reshape(b * t_width, h_kv, head_dim))
-                    vf = vf.at[layer, idx.reshape(-1)].set(
-                        v.reshape(b * t_width, h_kv, head_dim))
+                    k_pool = k_pool.at[layer, idx].set(
+                        k.reshape(b * t_width, kv_width))
+                    v_pool = v_pool.at[layer, idx].set(
+                        v.reshape(b * t_width, kv_width))
                 with jax.named_scope("paged_attn"):
                     out = paged_verify_attention(
-                        q,
-                        kf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                        vf[layer].reshape(nb_total, bs, h_kv, head_dim),
-                        block_tables, attend_lens,
+                        q, k_pool, v_pool, block_tables,
+                        attend_lens, layer=layer, block_size=bs,
                     ).reshape(b, t_width, hidden).astype(cfg.dtype)
                 with jax.named_scope("proj"):
                     x = x + _dense(out, p["attn"]["proj"]["kernel"])
@@ -423,7 +430,6 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
         # next_feed keeps the feed shape (B, 1) so the next T=1 call
         # consumes it with zero host-side reshaping.
         packed = jnp.concatenate([out_tokens, n_emitted[:, None]], axis=1)
-        return (packed, next_feed[:, None],
-                kf.reshape(k_pool.shape), vf.reshape(v_pool.shape))
+        return packed, next_feed[:, None], k_pool, v_pool
 
     return fused_decode

@@ -1,0 +1,239 @@
+"""Is the paged K/V pool kept in place by every program that takes it?
+
+The pool is the largest thing a serving process holds on the device, and
+six compiled programs take it: ``prefill_chunk``, ``decode``,
+``fused_decode`` at T = 1 and at T > 1, ``gather_cache`` and
+``copy_block``.  If the form the pool is stored in is not the form a
+program computes in, XLA converts all of it on the way in and back on the
+way out, on every call — nothing fails, a decode step is just a third
+slower and a prefill chunk forty times (PERF.md §5, PR 25).  This module
+reads that off the compiled programs:
+
+- :func:`pool_programs` builds the six programs with abstract arguments
+  (shapes only, so nothing is allocated and a *described* device will do);
+- :func:`pool_relayouts` lists the ``copy`` / ``transpose`` / ``convert``
+  operations of an optimised HLO module whose result is a whole number of
+  pool layers, outside scope ``paged_attn``;
+- :func:`donated_pools` names the pools that the module's
+  ``input_output_alias`` hands from input to output in place;
+- :func:`check_pool_programs` compiles and applies both.
+
+``python -m distributedtensorflow_tpu.serve.pool_check --max-slots 32 ...``
+runs the check on the device JAX finds and prints one JSON line (a leg of
+``chip_smoke.py``); ``tests/test_kernel_export.py`` runs it against a
+described v5e, without a chip.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+from ..models.gpt import GPTConfig
+from . import kv_cache
+from .model import (
+    make_decode_fn,
+    make_fused_decode_fn,
+    make_gather_cache_fn,
+    make_prefill_cache,
+    make_prefill_fn,
+)
+
+#: The programs whose output holds the pool (``gather_cache`` only reads it).
+RETURN_POOL = ("prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
+               "copy_block")
+
+_RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = \(?(\w+)\[([\d,]*)\](\{[^ ]*\})?\S* ([\w-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def pool_programs(cfg: GPTConfig, *, max_slots: int, num_blocks: int,
+                  block_size: int, chunk: int, draft: int, sharding=None):
+    """``{name: (jitted program, abstract arguments)}`` for the six
+    programs that take the pool, at the shapes an ``Engine`` with these
+    settings gives them (``cfg.max_seq`` is the serving context)."""
+    from ..models import GPTLM
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    i32 = jnp.int32
+    params = abstract(jax.eval_shape(
+        lambda: GPTLM(cfg).init(jax.random.PRNGKey(0),
+                                jnp.zeros((1, 1), i32),
+                                deterministic=True)["params"]))
+    pool = sds(kv_cache.pool_shape(cfg.num_layers, num_blocks, block_size,
+                                   cfg.kv_heads,
+                                   cfg.hidden_size // cfg.num_heads),
+               cfg.dtype)
+    cache = abstract(jax.eval_shape(lambda: make_prefill_cache(cfg)))
+    table_row = sds((cfg.max_seq // block_size,), i32)
+    tables = sds((max_slots, cfg.max_seq // block_size), i32)
+    slots_i32 = sds((max_slots,), i32)
+    active = sds((max_slots,), jnp.bool_)
+    scalar = sds((), i32)
+
+    def fused_args(t_width):
+        return (params, pool, pool, sds((max_slots, t_width), i32),
+                slots_i32, tables, slots_i32, active,
+                sds((max_slots, 2), jnp.uint32), slots_i32,
+                sds((max_slots,), jnp.float32), slots_i32)
+
+    return {
+        "prefill_chunk": (
+            make_prefill_fn(cfg, chunk=chunk, block_size=block_size),
+            (params, pool, pool, cache, sds((1, chunk), i32), scalar,
+             table_row, scalar)),
+        "decode": (
+            make_decode_fn(cfg, block_size=block_size),
+            (params, pool, pool, slots_i32, tables, slots_i32, active)),
+        "fused_decode": (
+            make_fused_decode_fn(cfg, block_size=block_size, draft=0),
+            fused_args(1)),
+        "fused_decode_spec": (
+            make_fused_decode_fn(cfg, block_size=block_size, draft=draft),
+            fused_args(draft + 1)),
+        "gather_cache": (
+            make_gather_cache_fn(cfg, block_size=block_size),
+            (pool, pool, cache, table_row, scalar)),
+        "copy_block": (
+            kv_cache._copy_block_fn(block_size),
+            (pool, pool, scalar, scalar)),
+    }
+
+
+def pool_relayouts(hlo_text: str, layer_elems: int) -> list[str]:
+    """``"<op> <dtype>[<shape>]<layout> <op_name>"`` for every ``copy``,
+    ``transpose`` or ``convert`` (fused or not) of an optimised HLO module
+    whose result holds a whole number of pool layers (``layer_elems``
+    elements each) and whose scope is not ``paged_attn``: the pool, or a
+    layer of it, changing form.  What attention gathers for the slots is
+    smaller than a layer, and a weight's cast (the embedding table is
+    larger than a layer of the cells' pool) is no whole number of them."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(4) not in _RELAYOUT_OPS:
+            continue
+        dtype, dims, layout, op = m.groups()
+        elems = 1
+        for d in dims.split(","):
+            elems *= int(d or 1)
+        if elems < layer_elems or elems % layer_elems:
+            continue
+        name = _OP_NAME.search(line)
+        name = name.group(1) if name else ""
+        if "/paged_attn/" not in name:
+            found.append(f"{op} {dtype}[{dims}]{layout or ''} {name}".strip())
+    return found
+
+
+def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
+    """Parameter number -> (argument name, shape with layout) of the entry
+    computation of an HLO module's text."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    params = {}
+    for m in re.finditer(
+            r"= (\S+) parameter\((\d+)\)[^\n]*?op_name=\"([^\"]*)\"", entry):
+        params[int(m.group(2))] = (m.group(3), m.group(1))
+    return params
+
+
+def donated_pools(hlo_text: str) -> set[str]:
+    """The arguments among ``k_pool`` / ``v_pool`` that the module's
+    ``input_output_alias`` gives to an output: the donation took."""
+    head = hlo_text[:hlo_text.index("\n")]
+    head = head.partition("input_output_alias=")[2]
+    # "{ {1}: (195, {}, may-alias), {2}: (196, {}, may-alias) }, entry_..."
+    aliased = {int(n) for n in re.findall(
+        r"\{[\d, ]*\}: \((\d+), ", head.partition("entry_computation")[0])}
+    return {name for number, (name, _) in _entry_parameters(hlo_text).items()
+            if number in aliased and name in ("k_pool", "v_pool")}
+
+
+def check_pool_programs(programs: dict, layer_elems: int) -> dict:
+    """Compile each of :func:`pool_programs` and report, per program, the
+    pool-sized relayouts, the donated pools, the layout the program takes
+    ``k_pool`` in and the compile time."""
+    report = {}
+    for name, (fn, args) in programs.items():
+        t0 = time.monotonic()
+        text = fn.lower(*args).compile().as_text()
+        layouts = {arg: shape for arg, shape
+                   in _entry_parameters(text).values()}
+        report[name] = {
+            "relayouts": pool_relayouts(text, layer_elems),
+            "donated": sorted(donated_pools(text)),
+            "k_pool": layouts.get("k_pool"),
+            "compile_s": round(time.monotonic() - t0, 2),
+        }
+    return report
+
+
+def failures(report: dict) -> list[str]:
+    """What :func:`check_pool_programs` found wrong, one line each."""
+    bad = []
+    for name, r in report.items():
+        for op in r["relayouts"]:
+            bad.append(f"{name}: pool-sized {op}")
+        if name in RETURN_POOL and r["donated"] != ["k_pool", "v_pool"]:
+            bad.append(f"{name}: donated in place only {r['donated']}")
+    forms = {r["k_pool"] for r in report.values()}
+    if len(forms) != 1:
+        bad.append(f"the programs take the pool in different forms: {forms}")
+    return bad
+
+
+def main(argv=None) -> int:
+    import dataclasses
+
+    from .. import models, runtime
+
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--config", default="gpt_medium")
+    p.add_argument("--max-slots", type=int, default=32)
+    p.add_argument("--kv-blocks", type=int, default=2048)
+    p.add_argument("--block-size", type=int, default=16)
+    p.add_argument("--max-context", type=int, default=1024)
+    p.add_argument("--prefill-chunk", type=int, default=16)
+    p.add_argument("--speculate", type=int, default=4)
+    args = p.parse_args(argv)
+
+    cfg = dataclasses.replace(getattr(models, args.config)(),
+                              max_seq=args.max_context)
+    shape = kv_cache.pool_shape(cfg.num_layers, args.kv_blocks,
+                                args.block_size, cfg.kv_heads,
+                                cfg.hidden_size // cfg.num_heads)
+    report = check_pool_programs(
+        pool_programs(cfg, max_slots=args.max_slots,
+                      num_blocks=args.kv_blocks, block_size=args.block_size,
+                      chunk=args.prefill_chunk, draft=args.speculate),
+        layer_elems=shape[1] * shape[2])
+    # the pool as the process holds it between calls
+    pool = jnp.zeros(shape, cfg.dtype)
+    bad = failures(report)
+    print(json.dumps({
+        "device": runtime.device_summary(),
+        "pool_shape": shape, "pool_dtype": str(pool.dtype),
+        "pool_bytes": pool.nbytes,
+        "resident_layout": str(pool.format.layout),
+        "programs": report, "failures": bad,
+    }))
+    for line in bad:
+        print(f"pool_check: FAILED: {line}", file=sys.stderr)
+    return 1 if bad else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

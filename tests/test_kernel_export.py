@@ -160,3 +160,34 @@ def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
     first_dims = {int(s.split("x")[0]) for _, s in shapes}
     assert first_dims == {B, B * S}, first_dims  # per device, never global
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", [
+    "prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
+    "gather_cache", "copy_block"])
+def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program):
+    """GPT-2 medium's widths and the benchmark cells' pool (2048 blocks of
+    16 tokens, 32 slots), two layers deep and with a small vocabulary to
+    keep the compile short (the fused sampler's is most of it): the v5e
+    compiler takes the pool in the form it is stored in, converts no
+    layer of it outside ``paged_attn`` and hands the donated pools back in
+    place.  ``chip_smoke.py`` makes the same check at full depth on the
+    chip."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import gpt_medium
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    cfg = dataclasses.replace(gpt_medium(), max_seq=1024, num_layers=2,
+                              vocab_size=1024)
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    programs = pool_check.pool_programs(
+        cfg, max_slots=32, num_blocks=2048, block_size=16, chunk=16, draft=4,
+        sharding=one_chip)
+    _, rows, width = kv_cache.pool_shape(2, 2048, 16, 16, 64)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width)
+    assert pool_check.failures(report) == []
+    # rows of all heads, minor dimension a multiple of 128: no padding
+    assert report[program]["k_pool"] == \
+        "bf16[2,32784,1024]{2,1,0:T(8,128)(2,1)}"

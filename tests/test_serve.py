@@ -103,6 +103,15 @@ def test_kv_admit_pressure_and_guards():
 # ------------------------------------------------- paged attention equivalence
 
 
+def _stored_form(pool, rng):
+    """A hand-built ``(blocks, block_size, Hkv, D)`` pool as layer 1 of a
+    two-layer pool in the stored form (``serve.kv_cache``: token rows of
+    all heads); layer 0 is noise that attention must not read."""
+    rows = pool.reshape(-1, pool.shape[2] * pool.shape[3])
+    return jnp.asarray(np.stack(
+        [rng.standard_normal(rows.shape).astype(rows.dtype), rows]))
+
+
 @pytest.mark.parametrize("h,h_kv", [(4, 4), (4, 2)])
 def test_paged_decode_attention_matches_dense(h, h_kv):
     """Gather-through-page-table attention == plain masked attention over
@@ -133,8 +142,8 @@ def test_paged_decode_attention_matches_dense(h, h_kv):
             v_pool[phys] = v_seq[i, j * bs: (j + 1) * bs]
 
     out = np.asarray(paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(tables), jnp.asarray(seq_lens),
+        jnp.asarray(q), _stored_form(k_pool, rng), _stored_form(v_pool, rng),
+        jnp.asarray(tables), jnp.asarray(seq_lens), layer=1, block_size=bs,
     ))
 
     g = h // h_kv
@@ -149,6 +158,142 @@ def test_paged_decode_attention_matches_dense(h, h_kv):
             np.testing.assert_allclose(
                 out[i, head], w @ vh, rtol=1e-5, atol=1e-5
             )
+
+
+# ------------------------------------------------------ the pool's stored form
+
+#: block size x dtype x kv heads (gpt_tiny has 4 heads: MHA and GQA)
+POOL_FORMS = [(bs, dt, kv) for bs in (4, 16)
+              for dt in (jnp.float32, jnp.bfloat16) for kv in (4, 2)]
+_POOL_IDS = [f"bs{bs}-{jnp.dtype(dt).name}-kv{kv}" for bs, dt, kv in POOL_FORMS]
+
+
+@pytest.mark.parametrize("block_size,dtype,kv_heads", POOL_FORMS,
+                         ids=_POOL_IDS)
+def test_pool_prefill_scatter_gather_round_trip(block_size, dtype, kv_heads):
+    """What ``prefill_chunk`` scatters into the pool is what
+    ``gather_cache`` reads back: the dense prefill cache's K/V, byte for
+    byte, through a shuffled page table; no row outside the slot's blocks
+    is written."""
+    from distributedtensorflow_tpu.serve.kv_cache import pool_shape
+    from distributedtensorflow_tpu.serve.model import (
+        make_gather_cache_fn,
+        make_prefill_cache,
+        make_prefill_fn,
+    )
+
+    cfg = dataclasses.replace(gpt_tiny(), dtype=dtype, max_seq=32,
+                              num_kv_heads=kv_heads)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 24), 0,
+                             cfg.vocab_size)
+    params = GPTLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
+    head_dim = cfg.hidden_size // cfg.num_heads
+    num_blocks, chunk = 8, 8
+    shape = pool_shape(cfg.num_layers, num_blocks, block_size, cfg.kv_heads,
+                       head_dim)
+    assert shape == (cfg.num_layers, (num_blocks + 1) * block_size,
+                     cfg.kv_heads * head_dim)
+    k_pool, v_pool = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    # the slot's pages, out of order; the rest point at scratch
+    blocks = [5, 2, 7, 0, 3, 6][: -(-24 // block_size)]
+    table_row = np.full((32 // block_size,), num_blocks, np.int32)
+    table_row[: len(blocks)] = blocks
+    table_row = jnp.asarray(table_row)
+
+    prefill_chunk = make_prefill_fn(cfg, chunk=chunk, block_size=block_size)
+    cache = make_prefill_cache(cfg)
+    for start in range(0, 24, chunk):
+        _, cache, k_pool, v_pool = prefill_chunk(
+            params, k_pool, v_pool, cache, ids[:, start:start + chunk],
+            jnp.int32(start), table_row, jnp.int32(chunk - 1))
+    dense = jax.tree.map(np.asarray, cache)
+
+    k_rows = np.asarray(k_pool, np.float32)
+    written = np.zeros(shape[1], bool)
+    for j, b in enumerate(blocks):
+        n = min(block_size, 24 - j * block_size)
+        written[b * block_size: b * block_size + n] = True
+    assert np.all(k_rows[:, ~written] == 0)
+    assert np.all(np.any(k_rows[:, written] != 0, axis=-1))
+
+    gathered = make_gather_cache_fn(cfg, block_size=block_size)(
+        k_pool, v_pool, make_prefill_cache(cfg), table_row, jnp.int32(24))
+    for i in range(cfg.num_layers):
+        got, want = gathered[f"h{i}"]["attn"], dense[f"h{i}"]["attn"]
+        assert int(got["cache_index"]) == 24
+        for name in ("cached_key", "cached_value"):
+            assert got[name].shape == want[name].shape
+            assert got[name].dtype == want[name].dtype
+            np.testing.assert_array_equal(
+                np.asarray(got[name])[:, :, :24], want[name][:, :, :24])
+
+
+@pytest.mark.parametrize("block_size,dtype,kv_heads", POOL_FORMS,
+                         ids=_POOL_IDS)
+def test_pool_copy_block_copies_one_block_of_every_layer(block_size, dtype,
+                                                         kv_heads):
+    from distributedtensorflow_tpu.serve.kv_cache import (
+        _copy_block_fn,
+        pool_shape,
+    )
+
+    shape = pool_shape(3, 6, block_size, kv_heads, 32)
+    rng = np.random.default_rng(0)
+    k0 = rng.standard_normal(shape).astype(jnp.dtype(dtype))
+    v0 = rng.standard_normal(shape).astype(jnp.dtype(dtype))
+    src, dst = 4, 1
+    k1, v1 = _copy_block_fn(block_size)(
+        jnp.asarray(k0), jnp.asarray(v0), jnp.int32(src), jnp.int32(dst))
+    for before, after in ((k0, np.asarray(k1)), (v0, np.asarray(v1))):
+        want = before.copy()
+        want[:, dst * block_size:(dst + 1) * block_size] = \
+            before[:, src * block_size:(src + 1) * block_size]
+        np.testing.assert_array_equal(after, want)
+        assert not np.array_equal(after, before)
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode",
+                                     "fused_decode", "fused_decode_spec",
+                                     "copy_block"])
+def test_pool_is_donated_in_place(program):
+    """The compiled module's ``input_output_alias`` names both pools: the
+    donated input is the output's buffer, for every program that returns
+    the pool."""
+    from distributedtensorflow_tpu.serve import pool_check
+
+    cfg = dataclasses.replace(gpt_tiny(), max_seq=32)
+    fn, args = pool_check.pool_programs(
+        cfg, max_slots=2, num_blocks=6, block_size=16, chunk=8, draft=2,
+    )[program]
+    assert program in pool_check.RETURN_POOL
+    text = fn.lower(*args).compile().as_text()
+    assert pool_check.donated_pools(text) == {"k_pool", "v_pool"}
+
+
+def test_pool_relayouts_reads_the_old_forms_copies():
+    """``pool_relayouts`` on the lines the 5-D pool compiled to on the v5e
+    (PERF.md §5, PR 25): the entry and exit copies count, what attention
+    does inside its scope and a weight's cast do not."""
+    from distributedtensorflow_tpu.serve.pool_check import pool_relayouts
+
+    layer = 2049 * 16 * 16 * 64
+    pool = "bf16[24,2049,16,16,64]"
+    hlo = "\n".join([
+        f'  %copy.180 = {pool}{{4,3,2,1,0:T(8,128)(2,1)}} copy(%k_pool.1), '
+        'metadata={op_name="k_pool"}',
+        f'  %copy.323 = {pool}{{1,4,3,2,0:T(8,128)(2,1)}} copy(%bitcast.201)',
+        '  %convert.9 = f32[1,2049,16,16,64]{4,3,2,1,0:T(8,128)} '
+        'convert(%slice.3), metadata={op_name="jit(decode)/h0/paged_attn/x"}',
+        '  %convert.1 = bf16[50257,1024]{1,0:T(8,128)(2,1)} convert(%wte), '
+        'metadata={op_name="jit(decode)/embed/cast_params/convert"}',
+        '  %convert.2 = f32[32,1024,16,64]{3,2,1,0:T(8,128)} convert(%g.1)',
+        f'  %fusion.5 = {pool}{{4,3,2,1,0:T(8,128)(2,1)}} fusion(%copy.180), '
+        'kind=kLoop, metadata={op_name="jit(decode)/h0/kv_write/scatter"}',
+    ])
+    assert pool_relayouts(hlo, layer) == [
+        "copy bf16[24,2049,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} k_pool",
+        "copy bf16[24,2049,16,16,64]{1,4,3,2,0:T(8,128)(2,1)}",
+    ]
 
 
 # ---------------------------------------------------------------- the engine
@@ -588,11 +733,15 @@ def test_kv_admit_maps_prefix_and_rolls_back_under_pressure():
 
 def test_kv_cow_copies_shared_block_before_write():
     kv = _kv(num_blocks=8, block_size=4, max_context=16, max_slots=2)
+
+    def rows(block):  # a block's token rows in the pool's stored form
+        return slice(block * kv.block_size, (block + 1) * kv.block_size)
+
     rng = np.random.default_rng(2)
     prompt = _tokens(rng, 8)
     kv.admit(0, tokens=8, prompt=prompt)
     # give the pool recognizable contents for the copy check
-    kv.k_pool = kv.k_pool.at[:, kv.pages[0].blocks[0]].set(7.0)
+    kv.k_pool = kv.k_pool.at[:, rows(kv.pages[0].blocks[0])].set(7.0)
     kv.register_prefix(0, prompt)
     kv.release(0)
     a = kv.admit(0, tokens=8, prompt=prompt)
@@ -607,8 +756,8 @@ def test_kv_cow_copies_shared_block_before_write():
     assert kv.allocator.refcount(kv.pages[1].blocks[0]) == 1
     assert int(kv.block_tables[1, 0]) == kv.pages[1].blocks[0]
     np.testing.assert_array_equal(
-        np.asarray(kv.k_pool[:, kv.pages[1].blocks[0]]),
-        np.asarray(kv.k_pool[:, shared]),
+        np.asarray(kv.k_pool[:, rows(kv.pages[1].blocks[0])]),
+        np.asarray(kv.k_pool[:, rows(shared)]),
     )
     assert kv.stats()["cow_copies"] == 1
     # slot 0's block is now exclusive but still INDEXED: writing it must

@@ -101,9 +101,17 @@ def test_paged_verify_attention_matches_dense(h, h_kv):
             pool_k[blk] = k_seq[i, j * bs:(j + 1) * bs]
             pool_v[blk] = v_seq[i, j * bs:(j + 1) * bs]
 
+    # the pool's stored form (serve.kv_cache): token rows of all heads,
+    # layers stacked; layer 0 is noise that attention must not read
+    def stored(pool):
+        rows = pool.reshape(-1, h_kv * d)
+        return jnp.asarray(np.stack(
+            [rng.standard_normal(rows.shape).astype(np.float32), rows]))
+
     out = np.asarray(paged_verify_attention(
-        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
-        jnp.asarray(tables), jnp.asarray(attend_lens),
+        jnp.asarray(q), stored(pool_k), stored(pool_v),
+        jnp.asarray(tables), jnp.asarray(attend_lens), layer=1,
+        block_size=bs,
     ))
     assert out.shape == (b, t, h, d)
     g = h // h_kv
@@ -282,7 +290,7 @@ def test_spec_greedy_matches_dense_with_all_flags(served_model):
     assert eng.kv.allocator.used_blocks == 0
 
 
-@pytest.mark.parametrize("speculate", [0, 4])
+@pytest.mark.parametrize("speculate", [0, 2, 4])
 def test_fused_greedy_matches_dense_bf16(speculate):
     """Same equivalence at the PRODUCTION dtype (gpt_tiny default
     bf16): the fused/verify program's dtype recipe must track
