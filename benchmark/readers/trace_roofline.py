@@ -1,12 +1,13 @@
 """Reader ``trace_roofline``: least time the chip could take (from shapes,
-``flops.py``, and the published peaks) over the device time the trace
-shows, in percent.
+by the configuration's counts module, and the published peaks) over the
+device time the trace shows, in percent.
 
 ``mode: "train_kernel"``: ``pattern`` names the kernel's events;
-``required`` is ``flash`` or ``xent``.  Only whole executions of the step
-program (``step_pattern`` on the modules line of the first device) count,
-and the requirement is per step, so recomputation under remat lowers the
-share as it should.
+``required`` names the kernel family whose operations and bytes a step
+the counts module gives (``step_kernel(config, required)``).  Only whole
+executions of the step program (``step_pattern`` on the modules line of
+the first device) count, and the requirement is per step, so recomputation
+under remat lowers the share as it should.
 
 ``mode: "decode"``: bytes one decode iteration must read (weights once in
 the compute type, plus the K/V of every live token, the mean over the
@@ -52,7 +53,7 @@ def read(ctx: dict, args: dict):
     if not trace or not trace["devices"]:
         return None
     dev = _first_device(trace)
-    model = ctx["config"]      # the widths sit at the file's top level
+    config, counts = ctx["config"], ctx["counts"]
     kind = ctx["device_kind"]
     if args["mode"] == "decode":
         rx = re.compile(args["pattern"])
@@ -60,8 +61,8 @@ def read(ctx: dict, args: dict):
         live = _live_tokens(ctx)
         if not durs or live is None:
             return None
-        need = flops.decode_iter_bytes(
-            model, live, ctx["config"]["compute_dtype_bytes"])
+        need = counts.decode_iter_bytes(
+            config, live, config["compute_dtype_bytes"])
         floor = need / flops.peaks(kind)["hbm_bytes_per_s"]
         return 100.0 * floor / statistics.fmean(durs)
     steps = trace_reduce.whole_executions(dev["modules"],
@@ -72,14 +73,7 @@ def read(ctx: dict, args: dict):
         dev["ops"], args["pattern"], within=steps)
     if seconds <= 0:
         return None
-    batch, seq = ctx["config"]["per_chip_batch"], ctx["config"]["seq_len"]
-    if args["required"] == "flash":
-        f, b = flops.flash_flops(model, batch, seq), flops.flash_bytes(
-            model, batch, seq)
-        need_f, need_b = f["fwd"] + f["bwd"], b["fwd"] + b["bwd"]
-    else:
-        tokens = batch * (seq - 1)
-        need_f, need_b = flops.xent_flops(model, tokens), flops.xent_bytes(
-            model, tokens)
-    floor = flops.roofline_seconds(need_f, need_b, kind)["seconds"]
+    need = counts.step_kernel(config, args["required"])
+    floor = flops.roofline_seconds(need["flops"], need["bytes"],
+                                   kind)["seconds"]
     return 100.0 * floor * len(steps) / seconds

@@ -15,6 +15,10 @@ scale|bias``, ``h<i>/attn/qkv|proj/kernel``, ``h<i>/fc_in|fc_out/kernel``,
 ``ln_f/scale|bias``), cast to float32.  On a TPU a float32 product runs
 at reduced precision unless told otherwise, so every entry point here runs
 under ``jax.default_matmul_precision("highest")``.
+
+The contract of every ``reference/<name>.py`` (a configuration file names
+its own: ``"reference": "<name>"``), each function taking the configuration
+file's contents: ``init_params``, ``logits``, ``token_nll``, ``loss``.
 """
 
 from __future__ import annotations
@@ -73,29 +77,33 @@ def forward(params, input_ids, n_layer: int, n_head: int):
         return _layer_norm(x, params["ln_f"]) @ wte.T
 
 
-def loss(params, input_ids, n_layer: int, n_head: int):
+def init_params(config: dict, seed: int):
+    """The weights the server makes from ``seed``: the system's own random
+    init of its ``system_config`` preset, in one jitted call.  The only
+    place this file touches the system under test."""
+    import numpy as np
+
+    from distributedtensorflow_tpu import models
+
+    cfg = getattr(models, config["system_config"])()
+    return jax.jit(lambda k: models.GPTLM(cfg).init(
+        k, np.zeros((1, 1), np.int32), deterministic=True)["params"])(
+            jax.random.PRNGKey(seed))
+
+
+def logits(params, input_ids, config: dict):
+    """Next-token logits (B, S, V) in float32 for token ids (B, S)."""
+    return forward(params, input_ids, config["n_layer"], config["n_head"])
+
+
+def token_nll(params, batch: dict, config: dict):
+    """Next-token negative log-likelihood (B, S-1) of
+    ``batch["input_ids"]`` at positions 0..S-2."""
+    input_ids = batch["input_ids"]
+    logp = jax.nn.log_softmax(logits(params, input_ids, config)[:, :-1], -1)
+    return -jnp.take_along_axis(logp, input_ids[:, 1:, None], -1)[..., 0]
+
+
+def loss(params, batch: dict, config: dict):
     """Mean next-token cross-entropy over positions 0..S-2."""
-    logits = forward(params, input_ids, n_layer, n_head)[:, :-1]
-    logp = jax.nn.log_softmax(logits, -1)
-    nll = -jnp.take_along_axis(logp, input_ids[:, 1:, None], -1)[..., 0]
-    return nll.mean()
-
-
-def greedy(params, prompt, n_new: int, n_layer: int, n_head: int):
-    """Greedy continuation of one prompt, recomputing the whole forward per
-    token (no cache).  Returns per new token ``(top1, top2, margin)``: the
-    arg-max id, the runner-up id and the logit gap between them."""
-    fwd = jax.jit(forward, static_argnums=(2, 3))
-    total = len(prompt) + n_new
-    ids = jnp.zeros((1, total), jnp.int32).at[0, :len(prompt)].set(
-        jnp.asarray(prompt, jnp.int32))
-    out = []
-    for t in range(len(prompt), total):
-        # fixed shape (one compile): positions >= t hold zeros, which a
-        # causal model cannot see from position t-1
-        logits = fwd(params, ids, n_layer, n_head)[0, t - 1]
-        top = jnp.argsort(logits)[-2:]
-        top1, top2 = int(top[1]), int(top[0])
-        out.append((top1, top2, float(logits[top1] - logits[top2])))
-        ids = ids.at[0, t].set(top1)
-    return out
+    return token_nll(params, batch, config).mean()

@@ -6,10 +6,13 @@ Everything about a cell is data: ``BENCHMARK.json`` names the cell, its
 configuration file and its traffic mix; ``traffic/<mix>.json`` names the
 traffic kind, whose generator is ``traffic_kinds/<kind>.py``; each
 per-layer metric has ``layer_metrics/<metric>.json`` naming its reader,
-``readers/<reader>.py``.  The last line of standard output is the result:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, in a
-traced run, ``breakdown``.  With ``--trace 0`` the metrics are the cell's
-end-to-end metrics, with ``--trace 1`` its per-layer metrics.
+``readers/<reader>.py``; the configuration file names its plain reference
+(``reference/<name>.py``), its operation counts (``counts/<name>.py``) and
+its on-chip check (``checks/<name>.py``).  The last line of standard
+output is the result: ``correct``, ``attempted``, ``failed``, ``metrics``,
+``device`` and, in a traced run, ``breakdown``.  With ``--trace 0`` the
+metrics are the cell's end-to-end metrics, with ``--trace 1`` its
+per-layer metrics.
 
 ``--manifest`` points at another manifest of the same form (the rehearsal
 under ``tests/``, which runs a tiny model on the CPU and says so).
@@ -62,8 +65,11 @@ def main(argv=None) -> int:
         harness.find_file(roots, "traffic", cell["traffic"], ".json"))
     kind = harness.load_module(
         harness.find_file(roots, "traffic_kinds", traffic["kind"], ".py"))
+    counts_file = harness.find_file(roots, "counts", config["counts"], ".py")
+    counts = harness.load_module(counts_file)
     out = harness.fresh_dir(os.path.join(harness.OUT, cell["name"]))
     ctx = {"cell": cell, "config": config, "traffic": traffic, "out": out,
+           "roots": roots, "counts": counts,
            "seed": args.seed, "seconds": args.seconds,
            "trace": bool(args.trace), "chips": cell["chips"],
            "t_process_start": T_PROCESS_START}
@@ -83,7 +89,8 @@ def main(argv=None) -> int:
     line = {"correct": res["correct"], "attempted": res["attempted"],
             "failed": res["failed"], "metrics": metrics, "device": device}
     if args.trace:
-        layer = {**res["layer"], "config": config, "out": out,
+        layer = {**res["layer"], "config": config, "counts": counts,
+                 "out": out,
                  "device_kind": device["kind"]}
         import trace_reduce
 
@@ -108,7 +115,7 @@ def main(argv=None) -> int:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     if config.get("rehearsal"):
         line["rehearsal"] = True
-    line["detail"] = res["correct_detail"]
+    line["detail"] = {**res["correct_detail"], "counts_file": counts_file}
     print(json.dumps(line), flush=True)
     return 0
 

@@ -20,7 +20,9 @@ the window is an earlier stretch of the cycle, shorter than a period, and
 which stretch depends on the rotation: six rotations read 96.6-112.0
 tokens/s while each repeated to 0.6 % (PERF.md section 6, PR 23).  The
 saturated mix therefore does not rotate: its seeds differ in token ids
-and weights only.
+and weights only.  Nor does the steady mix since PR 27: where the window
+opens moved the mean TTFT of 192 requests by 1.7 ms (sd) between seeds,
+as much again as two runs of one seed differ by (PERF.md section 2).
 """
 
 from __future__ import annotations

@@ -3,9 +3,12 @@ import os
 
 import pytest
 
-import flops
+import flops as peaks_and_roofline
+import harness
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the hand sums below are those of PR 23, on the module they moved to
+flops = harness.load_module(os.path.join(BENCH, "counts", "gpt2.py"))
 
 
 def _model():
@@ -40,7 +43,22 @@ def test_kernel_requirements_agree_with_the_per_token_count():
     assert b == flops.weight_bytes(m, 2) + 1000 * 98304
 
 
+def test_step_kernel_is_what_the_roofline_reader_summed():
+    m = _model()
+    f, b = flops.flash_flops(m, 64, 1024), flops.flash_bytes(m, 64, 1024)
+    assert flops.step_kernel(m, "flash") == {
+        "flops": f["fwd"] + f["bwd"], "bytes": b["fwd"] + b["bwd"]}
+    tokens = 64 * 1023
+    assert flops.step_kernel(m, "xent") == {
+        "flops": flops.xent_flops(m, tokens),
+        "bytes": flops.xent_bytes(m, tokens)}
+    assert flops.train_flops_per_token(m, 1024) == 2271713280.0
+    with pytest.raises(KeyError):
+        flops.step_kernel(m, "conv")
+
+
 def test_peaks_table_has_no_default():
+    flops = peaks_and_roofline
     assert flops.peaks("TPU v5 lite")["flops_per_s"] == 197e12
     assert flops.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     for unknown in ("cpu", "source", "TPU v9"):

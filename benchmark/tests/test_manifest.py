@@ -11,7 +11,8 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-MANIFESTS = ["BENCHMARK.json", "benchmark/tests/rehearsal/BENCHMARK.json"]
+MANIFESTS = ["BENCHMARK.json", "benchmark/tests/rehearsal/BENCHMARK.json",
+             "benchmark/tests/rehearsal/BENCHMARK-second.json"]
 
 
 def _load(rel):
@@ -98,6 +99,13 @@ def test_data_files_are_found_by_name(rel):
     for w in m["workloads"]:
         with open(find("traffic", w["traffic"], ".json")) as f:
             find("traffic_kinds", json.load(f)["kind"], ".py")
+    for c in m["configs"]:
+        config = _load(c["file"])
+        find("reference", config["reference"], ".py")
+        find("counts", config["counts"], ".py")
+        check = config["correctness"].get("preflight")
+        if check:
+            find("checks", check["check"], ".py")
     layers = {}
     for x in m["per_layer"]:
         with open(find("layer_metrics", x["name"], ".json")) as f:

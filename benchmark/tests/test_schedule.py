@@ -28,8 +28,9 @@ def test_the_saturated_mix_differs_between_seeds_in_token_ids_only():
 
 
 def test_two_seeds_offer_the_same_volume_in_another_order():
+    # the generator's rotation by the seed, which a mix may ask for
     for name in ("chat-steady",):
-        mix = _mix(name)
+        mix = {**_mix(name), "rotate_by_seed": True}
         a = _window(schedule.build(mix, 50, 3, 50257))
         b = _window(schedule.build(mix, 50, 2 ** 31 + 7, 50257))
         assert len(a) == len(b) == round(mix["rate_per_s"] * 50)
@@ -44,6 +45,20 @@ def test_two_seeds_offer_the_same_volume_in_another_order():
         k = next(k for k in range(len(a))
                  if shape_a[k:] + shape_a[:k] == shape_b)
         assert k > 0
+
+
+def test_the_serving_mixes_do_not_rotate():
+    """Both serving mixes offer every seed the same arrivals and lengths
+    (PERF.md section 2: the rotation cost 1.7 ms of ttft_mean_ms between
+    seeds); seeds differ in token ids, and in the weights."""
+    for name in ("chat-steady", "chat-saturated"):
+        mix = _mix(name)
+        a = schedule.build(mix, 50, 3, 50257)
+        b = schedule.build(mix, 50, 2 ** 31 + 7, 50257)
+        assert [(r["id"], r["due"], len(r["prompt"]), r["max_new_tokens"])
+                for r in a] == [(r["id"], r["due"], len(r["prompt"]),
+                                 r["max_new_tokens"]) for r in b]
+        assert [r["prompt"] for r in a] != [r["prompt"] for r in b]
 
 
 def test_window_and_warm_in_are_stretches_of_one_cycle():

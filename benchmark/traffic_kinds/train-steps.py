@@ -2,13 +2,14 @@
 trainer's own loop for the window, after warm-up log rows that reach a
 steady step time.
 
-The trainer is started through ``train.py`` with far more steps than the
-run needs; the parent tails ``metrics.jsonl`` and stamps each row with its
-own clock as it arrives (the rows carry no timestamp).  The window opens
-at the arrival of warm-up row ``warmup_rows`` and closes at the first row
-that arrives ``--seconds`` or more later, so it holds whole log intervals
-and every step and second between the two rows counts.  Then the child is
-stopped.
+The trainer is started through the configuration's entry point on the
+mesh the traffic file names (``"mesh": "data={chips}"``), with far more
+steps than the run needs; the parent tails ``metrics.jsonl`` and stamps
+each row with its own clock as it arrives (the rows carry no timestamp).
+The window opens at the arrival of warm-up row ``warmup_rows`` and closes
+at the first row that arrives ``--seconds`` or more later, so it holds
+whole log intervals and every step and second between the two rows counts.
+Then the child is stopped.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ import time
 
 import flops
 import harness
+import preflight
 from harness import BenchError
 
 
@@ -40,16 +42,14 @@ def run(ctx: dict) -> dict:
     per_chip = config["per_chip_batch"]
     seq = config["seq_len"]
     logdir = os.path.join(out, "train")
-    argv = [*config["argv"], "--mesh", f"data={chips}",
+    argv = [*config["argv"], "--mesh", traffic["mesh"].format(chips=chips),
             "--batch-size", str(per_chip * chips),
             "--log-every", str(traffic["log_every"]),
             "--steps", str(traffic["max_steps"]), "--seed", str(child_seed),
             "--logdir", logdir]
-    check = config["correctness"]
-    preflight = {**check["preflight"], "seed": child_seed,
-                 "seq_len": seq, "vocab_size": config["vocab_size"],
-                 "n_layer": config["n_layer"], "n_head": config["n_head"]}
-    child = harness.Child(out, config["entry"], argv, preflight=preflight)
+    child = harness.Child(out, config["entry"], argv,
+                          preflight=preflight.spec_for(
+                              config, ctx["roots"], child_seed))
     metrics_path = os.path.join(logdir, "metrics.jsonl")
     rows: list[dict] = []        # every loss row, with arrival time "t"
     trace_dir = os.path.join(out, "trace")
@@ -114,8 +114,8 @@ def run(ctx: dict) -> dict:
     # a rehearsal names its own nominal peak: its line is not a measurement
     peak = config.get("rehearsal_peak_flops_per_s") or flops.peaks(
         device["kind"])["flops_per_s"]
-    mfu = 100.0 * flops.train_flops_per_token(config, seq) * tokens_per_s / (
-        chips * peak)
+    mfu = 100.0 * ctx["counts"].train_flops_per_token(
+        config, seq) * tokens_per_s / (chips * peak)
     losses = [r["loss"] for r in rows]
     falling = all(math.isfinite(x) for x in losses) and (
         win[-1]["loss"] < win[0]["loss"])
