@@ -13,6 +13,11 @@ from .gpt import (  # noqa: F401
     lm_loss,
     nan_taps,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig,
+    afmoe_tiny,
+    trinity_large_ep8,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

@@ -15,7 +15,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from ..runtime import on_tpu
+from ..runtime import on_tpu, use_kernel
 
 NEG_INF = -1e9  # large-negative in bf16-safe range (bf16 max ~3.4e38; 1e9 fine)
 
@@ -182,6 +182,7 @@ def paged_decode_attention(
     *,
     layer: int,               # which layer of the stacked pools to attend
     block_size: int,          # rows per physical block
+    window: int | None = None,  # attend only the last `window` positions
 ) -> jax.Array:
     """Single-token decode attention against a paged (block-pool) KV cache.
 
@@ -211,6 +212,8 @@ def paged_decode_attention(
     v = _gather_pages(v_pool, layer, block_tables, block_size, d)
     h_kv, cap = k.shape[1], k.shape[2]
     valid = jnp.arange(cap)[None, :] < seq_lens[:, None]  # (B, cap)
+    if window is not None:
+        valid &= jnp.arange(cap)[None, :] >= seq_lens[:, None] - window
     if h != h_kv:  # GQA: grouped einsums, pool never broadcast to H
         g = h // h_kv
         qg = q.reshape(b, h_kv, g, d)
@@ -462,3 +465,253 @@ def xla_attention(q, k, v, *, mask=None, causal=False, window=None):
     else:
         out = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(orig_dtype), v)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Paged attention that reads what a slot attends, not ``max_context``
+# ---------------------------------------------------------------------------
+#
+# The two functions above gather every table column of every slot: their
+# cost follows slots x max_context whatever is resident (PERF.md §5).  The
+# two below are the paths of a family with window layers and long contexts
+# (``models.afmoe``): they take ONE layer group's pool (``serve.kv_cache``
+# row form, ``(L_group, rows, Hkv * D)``), and an optional ``window``: a
+# query at position ``i`` attends keys ``j`` with ``i - window < j <= i``.
+
+#: key rows the decode kernel folds into its running softmax at a time: the
+#: lane width, so the (8, rows) scores, the running maximum and sum (kept
+#: replicated across lanes) and the (8, D) accumulator at D = 128 all share
+#: one shape
+PAGED_ROWS = 128
+#: such stretches a grid step holds: all their block copies are started at
+#: the top of the step and each stretch waits only for its own, so the later
+#: stretches' copies run under the earlier ones' arithmetic
+PAGED_STRETCHES = 4
+
+
+def paged_chunk_attention(
+    q: jax.Array,            # (T, H, D): one slot's chunk of queries
+    start,                   # int32 scalar: position of q[0]
+    k_pool: jax.Array,       # (L_group, rows, Hkv * D)
+    v_pool: jax.Array,
+    table_row: jax.Array,    # (max_blocks,) the slot's page-table row
+    *,
+    layer: int,
+    block_size: int,
+    window: int | None = None,
+    kv_chunk: int = 512,
+) -> jax.Array:
+    """Chunk-prefill attention of one slot against its pages, the chunk's
+    own K/V already written: a loop over ``kv_chunk``-row stretches of the
+    context from the first one a query of the chunk attends to the chunk's
+    end, with a running softmax — ``window + T`` rows at most on a window
+    layer, the rows before the chunk's end on a full one, never the table's
+    whole width."""
+    t, h, d = q.shape
+    width = k_pool.shape[-1]
+    h_kv = width // d
+    g = h // h_kv
+    kv_chunk = max(block_size, kv_chunk // block_size * block_size)
+    bpc = kv_chunk // block_size
+    nb = table_row.shape[0]
+    qpos = start + jnp.arange(t, dtype=jnp.int32)
+    qg = q.reshape(t, h_kv, g, d)
+    lo = 0 if window is None else jnp.maximum(start - window + 1, 0)
+    scale = d ** -0.5
+
+    def pages(pool, c):
+        blocks = table_row[jnp.minimum(c * bpc + jnp.arange(bpc), nb - 1)]
+        x = pool.reshape(pool.shape[0], -1, block_size, width)[layer, blocks]
+        return x.reshape(kv_chunk, h_kv, d)
+
+    def body(c, carry):
+        m, l, acc = carry
+        kpos = c * kv_chunk + jnp.arange(kv_chunk, dtype=jnp.int32)
+        s = jnp.einsum("qhgd,khd->hgqk", qg, pages(k_pool, c),
+                       preferred_element_type=jnp.float32) * scale
+        ok = kpos[None, :] <= qpos[:, None]
+        if window is not None:
+            ok &= kpos[None, :] > qpos[:, None] - window
+        s = jnp.where(ok[None, None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(ok[None, None], jnp.exp(s - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hgqk,khd->hgqd", p.astype(q.dtype), pages(v_pool, c),
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(-1), acc
+
+    init = (jnp.full((h_kv, g, t), NEG_INF, jnp.float32),
+            jnp.zeros((h_kv, g, t), jnp.float32),
+            jnp.zeros((h_kv, g, t, d), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(
+        lo // kv_chunk, -(-(start + t) // kv_chunk), body, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(2, 0, 1, 3).reshape(t, h, d).astype(q.dtype)
+
+
+def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc, *,
+                         layer, block_size, h_kv, d, n_steps, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, c = pl.program_id(0), pl.program_id(1)
+    rows = PAGED_ROWS
+    step_rows = rows * PAGED_STRETCHES
+    bps = rows // block_size                   # blocks a stretch
+    nb = tables_ref.shape[1]
+    n, lo = lens_ref[s], lo_ref[s]
+    first = (lo // step_rows + c) * step_rows  # first key row of this step
+
+    @pl.when((s == 0) & (c == 0))
+    def _():
+        # skipped blocks leave these rows as they were: keep them finite
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(c == 0)
+    def _():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def copies(j):
+        """Whether block ``j`` of this step holds a row the slot attends,
+        and its two copies."""
+        b0 = first + j * block_size
+        blk = tables_ref[s, jnp.minimum(b0 // block_size, nb - 1)]
+        src = pl.ds(blk * block_size, block_size)
+        dst = pl.ds(j * block_size, block_size)
+        return (b0 < n) & (b0 + block_size > lo), (
+            pltpu.make_async_copy(k_hbm.at[layer, src], kbuf.at[dst],
+                                  sem.at[0, j]),
+            pltpu.make_async_copy(v_hbm.at[layer, src], vbuf.at[dst],
+                                  sem.at[1, j]))
+
+    @pl.when(first < n)
+    def _():
+        # only the blocks that hold a row this slot attends are read
+        for j in range(bps * PAGED_STRETCHES):
+            need, (ck, cv) = copies(j)
+
+            @pl.when(need)
+            def _():
+                ck.start()
+                cv.start()
+
+    for part in range(PAGED_STRETCHES):
+        start = first + part * rows
+
+        @pl.when(start < n)
+        def _(part=part, start=start):
+            for j in range(part * bps, (part + 1) * bps):
+                need, (ck, cv) = copies(j)
+
+                @pl.when(need)
+                def _():
+                    ck.wait()
+                    cv.wait()
+
+            kpos = start + jax.lax.broadcasted_iota(jnp.int32, (8, rows), 1)
+            valid = (kpos < n) & (kpos >= lo)
+            here = pl.ds(part * rows, rows)
+            for hh in range(h_kv):
+                lanes = pl.ds(hh * d, d)
+                sc = jax.lax.dot_general(
+                    q_ref[0, hh], kbuf[here, lanes],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (8, rows)
+                sc = jnp.where(valid, sc, NEG_INF)
+                m_prev = m_sc[hh]
+                m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+                l_sc[hh] = alpha * l_sc[hh] + p.sum(axis=1, keepdims=True)
+                acc_sc[hh] = alpha * acc_sc[hh] + jnp.dot(
+                    p.astype(vbuf.dtype), vbuf[here, lanes],
+                    preferred_element_type=jnp.float32)
+                m_sc[hh] = m_new
+
+    @pl.when(c == n_steps - 1)
+    def _():
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def paged_window_decode_attention(
+    q: jax.Array,             # (B, H, D) one query a slot
+    k_pool: jax.Array,        # (L_group, rows, Hkv * D)
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    attend_lens: jax.Array,   # (B,) keys a slot attends, this step's included
+    *,
+    layer: int,
+    block_size: int,
+    window: int | None = None,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Single-token decode attention that reads only what each slot
+    attends: the blocks holding rows ``[max(len - window, 0), len)``, so
+    ``window`` + one block at most on a window layer and the slot's resident
+    blocks on a full one.
+
+    The kernel (``name="paged_attn"``) has the page tables and lengths
+    prefetched into SMEM and the pools left in HBM; grid ``(slot, step)``,
+    a step copying the needed blocks of its 4 x 128 key rows into VMEM and
+    folding them, 128 rows at a time, into a running softmax, the K/V heads
+    one after the other on the MXU (the ``H // Hkv`` query heads of a group
+    are the rows of one small product).  Steps past a slot's length copy
+    and compute nothing.  Needs ``D == 128`` and ``H // Hkv <= 8``;
+    other shapes, and ``impl="xla"``, take the plain formulation."""
+    b, h, d = q.shape
+    width = k_pool.shape[-1]
+    h_kv = width // d
+    g = h // h_kv
+    rows = PAGED_ROWS
+    step_rows = rows * PAGED_STRETCHES
+    fits = d == 128 and g <= 8 and rows % block_size == 0
+    if not (use_kernel(impl) and fits):
+        # the plain formulation (gathers every table column): the tests'
+        # yardstick for the kernel and the path off the TPU
+        return paged_decode_attention(
+            q, k_pool, v_pool, block_tables, attend_lens, layer=layer,
+            block_size=block_size, window=window)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = not on_tpu()
+    cap = block_tables.shape[1] * block_size
+    # a window starts anywhere inside its first step
+    span = cap if window is None else min(cap, window + step_rows - 1)
+    n_steps = -(-span // step_rows)
+    lens = attend_lens.astype(jnp.int32)
+    lo = (jnp.zeros_like(lens) if window is None
+          else jnp.maximum(lens - window, 0))
+    q8 = jnp.zeros((b, h_kv, 8, d), q.dtype).at[:, :, :g].set(
+        q.reshape(b, h_kv, g, d))
+    blk = pl.BlockSpec((1, h_kv, 8, d), lambda s, c, *_: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, layer=layer, block_size=block_size,
+            h_kv=h_kv, d=d, n_steps=n_steps, scale=d ** -0.5),
+        name="paged_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, n_steps),
+            in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=blk,
+            scratch_shapes=[
+                pltpu.VMEM((step_rows, width), k_pool.dtype),
+                pltpu.VMEM((step_rows, width), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, step_rows // block_size)),
+                pltpu.VMEM((h_kv, 8, rows), jnp.float32),
+                pltpu.VMEM((h_kv, 8, rows), jnp.float32),
+                pltpu.VMEM((h_kv, 8, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, h_kv, 8, d), q.dtype),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), lens, lo, q8, k_pool, v_pool)
+    return out[:, :, :g].reshape(b, h, d)

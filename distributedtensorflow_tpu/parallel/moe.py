@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import runtime
 from . import mesh as mesh_lib
 
 PyTree = Any
@@ -371,3 +372,159 @@ def local_moe(
     out = jax.vmap(expert_fn)(expert_params, send.astype(tokens.dtype))
     combined = jnp.einsum("tec,ecd->td", combine, out.astype(jnp.float32))
     return combined.astype(tokens.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless token-choice routing over a router wider than the experts held
+# ---------------------------------------------------------------------------
+#
+# The capacity-slot routers above build (T, E, C) one-hots and drop what
+# overflows a slot; neither survives k = 4 of 256.  The layer below routes
+# over the router's FULL width, sorts the (token, choice) pairs that land on
+# the experts this chip *holds* by expert, multiplies each expert's rows by
+# its matrices once (grouped matmul: every row tile belongs to one expert)
+# and leaves the absent experts' terms out — in an expert-parallel
+# deployment those are other chips' terms, and the sum over the shares plus
+# the shared expert, once, is the whole layer.  No token loses an expert at
+# any load: the row buffer holds every pair plus one tile of padding an
+# expert.
+
+#: rows of one tile of the grouped matmuls (the bf16 sublane tile)
+GROUP_TILE = 16
+
+
+def sigmoid_topk_route(h: jax.Array, router_kernel: jax.Array,
+                       select_bias: jax.Array, *, top_k: int,
+                       route_norm: bool = True, route_scale: float = 1.0):
+    """``(idx, weight)`` of shape ``(T, top_k)``: float32 sigmoid scores
+    over the router's full width, the top ``top_k`` of ``score + bias``
+    (the bias selects only), weights ``score[top] / (sum + 1e-20) *
+    route_scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if route_norm:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * route_scale
+
+
+def group_plan(idx: jax.Array, held: tuple[int, int], token_mask=None,
+               tile: int = GROUP_TILE) -> dict:
+    """Where each routed pair goes in the row buffer of the grouped
+    matmuls.  ``idx`` (T, k) are published expert ids; ``held = (first,
+    count)``.  Pairs on held experts are sorted by expert, each expert's
+    rows padded to whole tiles; the rest (and masked tokens' pairs) get
+    no row.  Returns ``rows`` (static row count), ``src`` (rows,) token of
+    each row (T where empty), ``dest`` (T, k) row of each pair (``rows``
+    where it has none), ``tile_expert`` (rows // tile,) local expert of
+    each tile (the last used tile's expert repeated behind it),
+    ``tiles_used``, and the counters ``pairs``, ``experts_hit``,
+    ``max_load``."""
+    t, k = idx.shape
+    first, count = held
+    local = idx - first
+    here = (local >= 0) & (local < count)
+    if token_mask is not None:
+        here &= token_mask[:, None]
+    key = jnp.where(here, local, count).reshape(-1)          # (T*k,)
+    rows = -(-t * k // tile) * tile + count * tile
+    counts = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    padded = -(-counts // tile) * tile
+    ends = jnp.cumsum(padded)
+    order = jnp.argsort(key, stable=True)
+    skey = key[order]
+    first_sorted = jnp.cumsum(counts) - counts                # per expert
+    e = jnp.minimum(skey, count - 1)
+    dest_sorted = jnp.where(
+        skey < count,
+        (ends - padded)[e] + jnp.arange(t * k) - first_sorted[e], rows)
+    dest = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    src = jnp.full((rows + 1,), t, jnp.int32).at[dest_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")[:rows]
+    tiles_used = ends[-1] // tile
+    # a tile's expert is the one whose padded rows hold its first row; the
+    # tiles behind the used ones repeat the last used tile's
+    tile_start = jnp.minimum(jnp.arange(rows // tile) * tile,
+                             jnp.maximum(ends[-1] - 1, 0))
+    tile_expert = jnp.searchsorted(ends, tile_start, side="right",
+                                   method="compare_all").astype(jnp.int32)
+    return {"rows": rows, "src": src, "dest": dest.reshape(t, k),
+            "tile_expert": jnp.minimum(tile_expert, count - 1),
+            "tiles_used": tiles_used.astype(jnp.int32),
+            "pairs": counts.sum(), "experts_hit": (counts > 0).sum(),
+            "max_load": counts.max()}
+
+
+def _grouped_swiglu_xla(x_rows, w_gate, w_up, w_down, tile_expert,
+                        tiles_used, tile):
+    """Plain formulation of the grouped expert SwiGLU: one loop turn a
+    used tile, the tile's expert sliced out of the stacked matrices."""
+    def body(i, out):
+        e = tile_expert[i]
+        x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile, 0)
+        g = jnp.dot(x, lax.dynamic_index_in_dim(w_gate, e, 0, False),
+                    preferred_element_type=jnp.float32)
+        u = jnp.dot(x, lax.dynamic_index_in_dim(w_up, e, 0, False),
+                    preferred_element_type=jnp.float32)
+        hid = (jax.nn.silu(g) * u).astype(x_rows.dtype)
+        y = jnp.dot(hid, lax.dynamic_index_in_dim(w_down, e, 0, False),
+                    preferred_element_type=jnp.float32)
+        return lax.dynamic_update_slice_in_dim(
+            out, y.astype(out.dtype), i * tile, 0)
+
+    return lax.fori_loop(0, tiles_used, body, jnp.zeros(
+        (x_rows.shape[0], w_down.shape[-1]), x_rows.dtype))
+
+
+def dropless_moe(
+    h: jax.Array,                 # (T, d)
+    router_kernel: jax.Array,     # (d, E_published)
+    select_bias: jax.Array,       # (E_published,)
+    experts: dict,                # w_gate, w_up (E_held, d, m), w_down (E_held, m, d)
+    *,
+    held: tuple[int, int],
+    top_k: int,
+    route_norm: bool = True,
+    route_scale: float = 1.0,
+    token_mask: jax.Array | None = None,
+    impl: str = "auto",
+) -> tuple[jax.Array, dict]:
+    """The held experts' share of a token-choice MoE layer, dropless.
+
+    Routes every token over the router's full width, computes ``sum_j w_j
+    * Expert_{top_j}(h)`` over the choices that are held here, and returns
+    it with the counters of :func:`group_plan` (``pairs``, ``experts_hit``,
+    ``max_load``).  The shared expert is the caller's, added once.  On
+    one chip nothing is exchanged and nothing stands in for the absent
+    experts.  ``impl``: ``"pallas"`` (``ops.grouped_matmul``), ``"xla"``,
+    or ``"auto"`` (the kernel on a TPU)."""
+    from ..ops import grouped_matmul as gmm
+
+    with jax.named_scope("router"):
+        idx, w = sigmoid_topk_route(
+            h, router_kernel, select_bias, top_k=top_k,
+            route_norm=route_norm, route_scale=route_scale)
+        plan = group_plan(idx, held, token_mask)
+    with jax.named_scope("experts"):
+        x_rows = jnp.concatenate(
+            [h, jnp.zeros((1, h.shape[-1]), h.dtype)])[plan["src"]]
+        if runtime.use_kernel(impl):
+            y_rows = gmm.grouped_swiglu(
+                x_rows, experts["w_gate"], experts["w_up"],
+                experts["w_down"], plan["tile_expert"], plan["tiles_used"],
+                tile=GROUP_TILE)
+        else:
+            y_rows = _grouped_swiglu_xla(
+                x_rows, experts["w_gate"], experts["w_up"],
+                experts["w_down"], plan["tile_expert"], plan["tiles_used"],
+                GROUP_TILE)
+        y_rows = jnp.concatenate(
+            [y_rows, jnp.zeros((1, y_rows.shape[-1]), y_rows.dtype)])
+        picked = y_rows[plan["dest"]].astype(jnp.float32)      # (T, k, d)
+        out = (picked * w[..., None]).sum(1).astype(h.dtype)
+    counters = {k: plan[k] for k in ("pairs", "experts_hit", "max_load")}
+    return out, counters
+

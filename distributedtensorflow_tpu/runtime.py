@@ -35,6 +35,14 @@ def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+def use_kernel(impl: str) -> bool:
+    """Whether an op asked for ``impl`` ("auto", "pallas" or "xla") takes
+    its Pallas kernel: always for "pallas", on a TPU for "auto"."""
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
+    return impl == "pallas" or (impl == "auto" and on_tpu())
+
+
 def device_summary() -> dict:
     """``{"platform", "kind", "count"}`` of the default backend."""
     devices = jax.devices()

@@ -113,15 +113,8 @@ from ..obs import usage as obs_usage
 from ..utils.metrics import json_sanitize
 from . import draft as spec_draft
 from . import sampling
-from .kv_cache import PagedKVCache
-from .model import (
-    make_decode_fn,
-    make_fused_decode_fn,
-    make_gather_cache_fn,
-    make_prefill_cache,
-    make_prefill_fn,
-    reset_cache_index,
-)
+from .kv_cache import make_grouped_cache
+from .model import make_programs
 
 __all__ = ["Engine", "GenRequest", "QueueFullError"]
 
@@ -259,6 +252,7 @@ class Engine:
         max_queue: int = 64,
         block_size: int = 16,
         num_blocks: int | None = None,
+        window_blocks: int | None = None,
         prefill_chunk: int = 16,
         prefill_budget: int | None = None,
         prefix_cache: bool = False,
@@ -318,21 +312,28 @@ class Engine:
         self.logdir = logdir
         self.log_every = max(int(log_every), 1)
 
-        head_dim = cfg.hidden_size // cfg.num_heads
-        blocks_per_slot = max_context // block_size
-        if num_blocks is None:
-            # Full provisioning: every slot can hold max_context.  Pass
-            # fewer to oversubscribe (paged memory is the point) — then
-            # admission control, not OOM, absorbs the pressure.
-            num_blocks = max_slots * blocks_per_slot
-        self.kv = PagedKVCache(
-            num_layers=cfg.num_layers, kv_heads=cfg.kv_heads,
-            head_dim=head_dim, max_slots=max_slots, num_blocks=num_blocks,
-            block_size=block_size, max_context=max_context, dtype=cfg.dtype,
+        # layers in groups by attention kind, a pool and a page table
+        # each (a window group holds a ring, not the whole context); a
+        # model of one kind is one full group
+        self.kv = make_grouped_cache(
+            self.cfg, max_slots=max_slots, block_size=block_size,
+            max_context=max_context, write_ahead=prefill_chunk,
+            num_blocks={"full": num_blocks, "window": window_blocks},
         )
-        self._prefill = make_prefill_fn(self.cfg, chunk=prefill_chunk,
-                                        block_size=block_size)
-        self._decode = make_decode_fn(self.cfg, block_size=block_size)
+        if self.prefix_cache and not self.kv.shares_prefixes:
+            raise ValueError(
+                "prefix_cache is not implemented for a model of several "
+                f"layer groups yet ({', '.join(self.kv.groups)}: each would "
+                "need its own prefix index): serve it without")
+        self.programs = make_programs(
+            self.cfg, chunk=prefill_chunk, block_size=block_size,
+            layers=self.kv.layers)
+        #: the newest decode iteration's expert-routing counters (None
+        #: from programs without expert layers): pairs on held experts,
+        #: held experts hit (both summed over the expert layers), largest
+        #: load of one expert
+        self._routed = None
+        self._blocks_recycled0 = 0
         self.fused_sampling = bool(fused_sampling)
         self.speculate = speculate
         self.spec_ngram = int(spec_ngram)
@@ -342,11 +343,9 @@ class Engine:
             # T=1 fused program (always) + the T=K+1 verify program: an
             # iteration where no slot drafted runs the cheap one-token
             # program, so a zero-hit-rate workload pays only the lookup.
-            self._fused1 = make_fused_decode_fn(
-                self.cfg, block_size=block_size, draft=0)
+            self._fused1 = self.programs.fused(0)
             if self.speculate:
-                self._fused_spec = make_fused_decode_fn(
-                    self.cfg, block_size=block_size, draft=self.speculate)
+                self._fused_spec = self.programs.fused(self.speculate)
             # Device-resident sampling state: last sampled token and the
             # per-request base PRNG key per slot (set at admission /
             # prefill completion; read every step with no host feed).
@@ -367,12 +366,6 @@ class Engine:
         self._dev_zero_drafts = jnp.zeros((max_slots,), jnp.int32)
         self._dev_tables = None
         self._dev_tables_version = -1
-        self._gather = make_gather_cache_fn(self.cfg, block_size=block_size)
-        self._prefill_cache = make_prefill_cache(self.cfg)
-        #: (slot, pos): the dense prefill cache currently holds that
-        #: slot's K/V for positions [0, pos) — consecutive chunks of one
-        #: request skip the pool re-gather.  None = unknown/stale.
-        self._prefill_cache_state: tuple[int, int] | None = None
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -517,7 +510,7 @@ class Engine:
             registry=reg, logdir=logdir,
             token_flops=obs_usage.estimate_token_flops(self.cfg),
             max_slots=max_slots,
-            kv_blocks_total=self.kv.allocator.num_blocks,
+            kv_blocks_total=self.kv.num_blocks_total,
             flush_every=log_every,
         )
 
@@ -613,12 +606,7 @@ class Engine:
         # An oversubscribed pool may be smaller than one max_context slot:
         # a request the WHOLE pool can't hold would wedge the strict-FIFO
         # queue head forever — reject it at the door instead.
-        if self.kv.blocks_for(footprint) > self.kv.allocator.num_blocks:
-            raise ValueError(
-                f"request footprint {footprint} tokens needs "
-                f"{self.kv.blocks_for(footprint)} KV blocks but the pool "
-                f"has {self.kv.allocator.num_blocks}"
-            )
+        self.kv.check_fits(footprint)
         req = GenRequest(
             id=f"r{next(self._ids)}", prompt=prompt,
             max_new_tokens=int(max_new_tokens),
@@ -700,7 +688,12 @@ class Engine:
         """Device copy of the page tables, re-shipped only when a table
         actually changed (``PagedKVCache.tables_version``)."""
         if self._dev_tables_version != self.kv.tables_version:
-            self._dev_tables = jnp.asarray(self.kv.block_tables)
+            # copies: a window group's table changes while a program
+            # that was handed it may still be running, and on the CPU
+            # jnp.asarray can alias the numpy buffer
+            self._dev_tables = {
+                name: jnp.asarray(g.block_tables.copy())
+                for name, g in self.kv.groups.items()}
             self._dev_tables_version = self.kv.tables_version
         return self._dev_tables
 
@@ -826,6 +819,7 @@ class Engine:
             "step_s": round(step_s, 6),
             "kv_blocks_billed": round(blocks_billed, 4),
         }
+        rec.update(self._group_step_fields(occupancy))
         if admitted:
             by_tenant: dict[str, int] = {}
             for r in admitted:
@@ -841,6 +835,23 @@ class Engine:
                 return
             self._step_log.write(json.dumps(json_sanitize(rec)) + "\n")
             self._step_log.flush()
+
+    def _group_step_fields(self, occupancy: int) -> dict:
+        """Step-log fields of the layer groups and the expert layers:
+        each pool's blocks in use, the blocks a window group let go since
+        the last record, and (decode iterations of programs with expert
+        layers only) this iteration's routing counters."""
+        fields = {}
+        if occupancy and self._routed is not None:
+            pairs, hit, load = (int(v) for v in np.asarray(self._routed))
+            fields.update(moe_pairs=pairs, moe_experts_hit=hit,
+                          moe_max_load=load)
+        recycled = self.kv.blocks_recycled
+        fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
+        self._blocks_recycled0 = recycled
+        for name, g in self.kv.groups.items():
+            fields[f"kv_blocks_used_{name}"] = g.allocator.used_blocks
+        return fields
 
     def step_records(self, n: int | None = None) -> list[dict]:
         """Snapshot of the newest ``n`` step-log records (all retained
@@ -918,11 +929,7 @@ class Engine:
                     self._dev_keys = self._dev_keys.at[slot].set(
                         jax.random.PRNGKey(head.seed)
                     )
-                if self._prefill_cache_state is not None \
-                        and self._prefill_cache_state[0] == slot:
-                    # the dense cache's claimed contents belonged to this
-                    # slot's PREVIOUS tenant — never alias across requests
-                    self._prefill_cache_state = None
+                self.programs.forget(slot)     # a new tenant
                 self._filling.append(head)
                 reused = self._slot_reused[slot]
                 self._slot_reused[slot] = True
@@ -988,10 +995,8 @@ class Engine:
         return chunks
 
     def _run_prefill_chunk(self, req: GenRequest):
-        """One fixed-width prefill chunk for one request.  The dense
-        prefill cache is re-materialized from the slot's pool blocks
-        (``make_gather_cache_fn``) unless it already holds exactly this
-        slot's K/V through the chunk start — which makes chunks
+        """One fixed-width prefill chunk for one request: it reads the
+        slot's earlier chunks through its page-table rows, so chunks are
         stateless and freely interleavable across requests."""
         slot = req.slot
         c = self.prefill_chunk
@@ -1002,28 +1007,17 @@ class Engine:
         # scans) — interference stall, not its own prefill compute
         req.attr_stall_s += max(t_chunk0 - req._t_attr, 0.0)
         with obs_tracing.span("engine.prefill_chunk"):
-            table_row = jnp.asarray(self.kv.block_tables[slot])
-            if self._prefill_cache_state != (slot, start):
-                if start:
-                    with obs_tracing.span("engine.gather"):
-                        self._prefill_cache = self._gather(
-                            self.kv.k_pool, self.kv.v_pool,
-                            self._prefill_cache, table_row,
-                            jnp.int32(start),
-                        )
-                else:
-                    self._prefill_cache = reset_cache_index(
-                        self._prefill_cache)
             last_ix = min(max(len(req.prompt) - 1 - start, 0), c - 1)
-            (last_logits, self._prefill_cache, self.kv.k_pool,
-             self.kv.v_pool) = self._prefill(
-                self.params, self.kv.k_pool, self.kv.v_pool,
-                self._prefill_cache,
-                jnp.asarray(req._fill_buf[None, start:start + c]),
-                jnp.int32(start), table_row, jnp.int32(last_ix),
+            self.kv.prepare_write(slot, start + c)
+            last_logits, pools = self.programs.prefill(
+                self.params, self.kv.pools(),
+                req._fill_buf[start:start + c], start,
+                {name: jnp.asarray(g.block_tables[slot].copy())
+                 for name, g in self.kv.groups.items()},
+                last_ix, slot,
             )
+            self.kv.set_pools(pools)
             req._fill_next = start + c
-            self._prefill_cache_state = (slot, start + c)
             self.kv.note_written(
                 slot, max(min(start + c, len(req.prompt)),
                           int(self.kv.seq_lens[slot]))
@@ -1097,11 +1091,14 @@ class Engine:
                 # cache corruption.
                 self.kv.ensure_writable(i, int(self.kv.seq_lens[i]))
             self._refresh_slot_meta()
-            logits, self.kv.k_pool, self.kv.v_pool = self._decode(
-                self.params, self.kv.k_pool, self.kv.v_pool,
+            for i, _ in decoding:
+                self.kv.prepare_write(i, int(self.kv.seq_lens[i]) + 1)
+            logits, pools, self._routed = self.programs.decode(
+                self.params, self.kv.pools(),
                 jnp.asarray(self._last_tokens), self._tables_dev(),
                 jnp.asarray(self.kv.seq_lens), self._dev_active,
             )
+            self.kv.set_pools(pools)
         with obs_tracing.span("engine.decode.fetch") as s_fetch:
             logits = np.asarray(logits)
         now = time.time()
@@ -1221,13 +1218,14 @@ class Engine:
                 tokens_in = self._dev_tokens  # device-resident (B, 1) feed
                 dev_draft_lens = self._dev_zero_drafts
                 fn = self._fused1
-            packed, next_feed, self.kv.k_pool, self.kv.v_pool = fn(
-                self.params, self.kv.k_pool, self.kv.v_pool, tokens_in,
+            packed, next_feed, pools = fn(
+                self.params, self.kv.pools(), tokens_in,
                 dev_draft_lens, self._tables_dev(),
                 jnp.asarray(self.kv.seq_lens), self._dev_active,
                 self._dev_keys, self._dev_prompt_lens, self._dev_temp,
                 self._dev_topk,
             )
+            self.kv.set_pools(pools)
             self._dev_tokens = next_feed
         with obs_tracing.span("engine.decode.fetch") as s_fetch:
             packed = np.asarray(packed)  # the ONE small host fetch per
@@ -1316,9 +1314,7 @@ class Engine:
             self.kv.release(req.slot)
             self._slots[req.slot] = None
             self._slot_meta_dirty = True
-            if self._prefill_cache_state is not None \
-                    and self._prefill_cache_state[0] == req.slot:
-                self._prefill_cache_state = None
+            self.programs.forget(req.slot)
         if req in self._filling:  # error paths only; finished fills popped
             self._filling.remove(req)
         req.status = status

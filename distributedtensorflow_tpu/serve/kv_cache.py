@@ -419,6 +419,14 @@ class PagedKVCache:
         """Physical blocks needed to hold ``tokens`` K/V positions."""
         return -(-tokens // self.block_size)
 
+    def reservation(self, tokens: int) -> int:
+        """Blocks admission grants a slot of ``tokens`` positions."""
+        return self.blocks_for(tokens)
+
+    def prepare_write(self, slot: int, end: int) -> None:
+        """Every block of the reservation is mapped at admission: nothing
+        to do before a write (a window group maps as it goes)."""
+
     def admit(self, slot: int, tokens: int, prompt=None) -> SlotPages | None:
         """Reserve a slot's worst-case footprint (``tokens`` positions).
 
@@ -611,6 +619,13 @@ class PagedKVCache:
         pages = self.pages[slot]
         if pages is None:
             return 0.0
+        if not self._block_hash:
+            # a block is shared only through the prefix index, and stays
+            # indexed while it is: none indexed, so each is billed whole —
+            # a count, not a walk over refcounts (at 64 slots of hundreds
+            # of blocks that walk was the engine's largest host cost a
+            # step: PERF.md §6, PR 28)
+            return float(len(pages.blocks))
         alloc = self.allocator
         return sum(1.0 / alloc.refcount(b) for b in pages.blocks)
 
@@ -651,3 +666,280 @@ class PagedKVCache:
             "prefix_evictions": alloc.evictions,
             "cow_copies": self.cow_copies,
         }
+
+
+# ---------------------------------------------------------------------------
+# Layers in groups by attention kind
+# ---------------------------------------------------------------------------
+#
+# One pool and one page table for all layers makes every layer hold every
+# token a slot has: a window layer, which never again reads a key more than
+# ``window`` behind the newest, would pin it all the same.  A model whose
+# layers are of two kinds (``models.afmoe``: three window layers to a full
+# one) gets two GROUPS, each a pool of its own layers in the row form above
+# with its own allocator and page table:
+#
+# - a *full* group is a :class:`PagedKVCache` as it stands;
+# - a *window* group (:class:`WindowKVGroup`) reserves, at admission, only
+#   what a slot can hold at one time — ``window`` + one prefill chunk + one
+#   block, or the whole footprint if that is smaller — maps a logical block
+#   when a write first reaches it, and when a block lies wholly behind
+#   ``next position - window`` un-maps it (its table entry goes back to the
+#   scratch block) and re-uses it for the slot's next block: a ring, by way
+#   of the table.  The reservation is all-or-nothing like the full group's,
+#   so there is still no mid-flight out-of-blocks.
+#
+# :class:`GroupedKVCache` admits against all groups at once and otherwise
+# answers the engine as one cache: it is the only cache the engine holds.  A
+# GPT-2 configuration is one full group — the :class:`PagedKVCache` the
+# engine always had, compiled to the programs it always had — and a cache of
+# one group shares prompt prefixes through that group's index.
+
+
+class WindowKVGroup(PagedKVCache):
+    """A layer group whose layers attend ``window`` keys back at most."""
+
+    def __init__(self, *, window: int, write_ahead: int, **kw):
+        super().__init__(**kw)
+        self.window = window
+        #: most tokens one program writes past the resident ones (a
+        #: prefill chunk), which the ring must hold beside the window
+        self.write_ahead = write_ahead
+        self._stock: list[list[int]] = [[] for _ in range(self.max_slots)]
+        #: per slot: first logical block still mapped, and the first not
+        #: mapped yet (a slot's mapped blocks are [first, next))
+        self._first = [0] * self.max_slots
+        self._next = [0] * self.max_slots
+        self.blocks_recycled = 0
+
+    def reservation(self, tokens: int) -> int:
+        """Blocks a slot of ``tokens`` positions holds at one time."""
+        ring = self.blocks_for(self.window + self.write_ahead) + 1
+        return min(self.blocks_for(tokens), ring)
+
+    def admit(self, slot: int, tokens: int, prompt=None):
+        if self.pages[slot] is not None:
+            raise OutOfBlocksError(f"slot {slot} is already occupied")
+        if tokens > self.max_context:
+            raise ValueError(
+                f"{tokens} tokens exceed max_context={self.max_context}")
+        blocks = self.allocator.alloc(self.reservation(tokens))
+        if blocks is None:
+            return None
+        pages = SlotPages(blocks, self.blocks_for(tokens) * self.block_size)
+        self.pages[slot] = pages
+        self._stock[slot] = list(reversed(blocks))
+        self._first[slot] = self._next[slot] = 0
+        self.block_tables[slot, :] = self.scratch_block
+        self.tables_version += 1
+        self.seq_lens[slot] = 0
+        return pages
+
+    def release(self, slot: int) -> None:
+        super().release(slot)
+        self._stock[slot] = []
+        self._first[slot] = self._next[slot] = 0
+
+    def prepare_write(self, slot: int, end: int) -> None:
+        """Map every logical block a write of positions ``< end`` reaches."""
+        stock = self._stock[slot]
+        row = self.block_tables[slot]
+        for li in range(self._next[slot], self.blocks_for(end)):
+            if not stock:
+                raise OutOfBlocksError(
+                    f"window group: slot {slot} writes block {li} with "
+                    "its ring exhausted")
+            row[li] = stock.pop()
+            self._next[slot] = li + 1
+            self.tables_version += 1
+
+    def note_written(self, slot: int, tokens: int) -> None:
+        """Advance the resident count, then let go of every block wholly
+        behind ``tokens - window``: the next query sits at ``tokens`` at
+        the earliest and attends keys ``> tokens - window``."""
+        super().note_written(slot, tokens)
+        row = self.block_tables[slot]
+        keep_from = max(tokens - self.window + 1, 0) // self.block_size
+        for li in range(self._first[slot], min(keep_from, self._next[slot])):
+            self._stock[slot].append(int(row[li]))
+            row[li] = self.scratch_block
+            self.blocks_recycled += 1
+            self.tables_version += 1
+        self._first[slot] = max(self._first[slot], keep_from)
+        self._next[slot] = max(self._next[slot], self._first[slot])
+
+    def mapped_blocks(self, slot: int) -> int:
+        return self._next[slot] - self._first[slot]
+
+
+class GroupedKVCache:
+    """Several layer groups behind the one interface the engine drives.
+
+    ``groups`` maps a name (``"full"``, ``"window"``) to a
+    :class:`PagedKVCache` or :class:`WindowKVGroup`; ``layers`` maps the
+    same names to the model layers each holds, in pool order.  Admission is
+    all-or-nothing over all groups; everything else fans out.  Prompt
+    prefixes are shared by a cache of one group, through that group's
+    index (several groups would need an index each, kept in step): see
+    ``shares_prefixes``."""
+
+    def __init__(self, groups: dict[str, PagedKVCache],
+                 layers: dict[str, tuple[int, ...]]):
+        self.groups = groups
+        self.layers = layers
+        first = next(iter(groups.values()))
+        self.block_size = first.block_size
+        self.max_slots = first.max_slots
+        self.max_context = first.max_context
+        self.seq_lens = first.seq_lens
+        for g in groups.values():
+            g.seq_lens = self.seq_lens      # one length a slot, shared
+        #: the one group whose prefix index this cache shares through, or
+        #: None: no block of any group is then ever shared between slots
+        self._sharing = first if len(groups) == 1 else None
+
+    @property
+    def shares_prefixes(self) -> bool:
+        return self._sharing is not None
+
+    #: the census the engine's gauges read: the full group's if there is one
+    @property
+    def allocator(self) -> BlockAllocator:
+        return self.groups.get(
+            "full", next(iter(self.groups.values()))).allocator
+
+    @property
+    def tables_version(self) -> int:
+        return sum(g.tables_version for g in self.groups.values())
+
+    @property
+    def num_blocks_total(self) -> int:
+        return sum(g.allocator.num_blocks for g in self.groups.values())
+
+    @property
+    def cow_copies(self) -> int:
+        return sum(g.cow_copies for g in self.groups.values())
+
+    def pools(self) -> dict:
+        """``{group: (k_pool, v_pool)}``, as the programs take them (and
+        donate them: hand the updated ones back through ``set_pools``)."""
+        return {name: (g.k_pool, g.v_pool)
+                for name, g in self.groups.items()}
+
+    def set_pools(self, pools: dict) -> None:
+        for name, (k_pool, v_pool) in pools.items():
+            g = self.groups[name]
+            g.k_pool, g.v_pool = k_pool, v_pool
+
+    def check_fits(self, tokens: int) -> None:
+        """Raise ``ValueError`` if no pool state could ever hold a request
+        of ``tokens`` positions (it would wedge the FIFO head forever)."""
+        for name, g in self.groups.items():
+            if g.reservation(tokens) > g.allocator.num_blocks:
+                raise ValueError(
+                    f"request footprint {tokens} tokens needs "
+                    f"{g.reservation(tokens)} KV blocks of group {name!r} "
+                    f"but its pool has {g.allocator.num_blocks}")
+
+    def admit(self, slot: int, tokens: int, prompt=None):
+        """All groups' reservations or none.  ``prompt`` maps the longest
+        indexed prefix (``PagedKVCache.admit``) where prefixes are shared,
+        and is not looked at where they are not."""
+        if self._sharing is not None:
+            return self._sharing.admit(slot, tokens, prompt)
+        for g in self.groups.values():
+            if g.reservation(tokens) > g.allocator.allocatable_blocks:
+                return None
+        pages = None
+        for g in self.groups.values():
+            pages = g.admit(slot, tokens)
+            if pages is None:       # unreachable: checked above
+                raise OutOfBlocksError("group admission raced its check")
+        return pages
+
+    def release(self, slot: int) -> None:
+        for g in self.groups.values():
+            g.release(slot)
+
+    def prepare_write(self, slot: int, end: int) -> None:
+        for g in self.groups.values():
+            g.prepare_write(slot, end)
+
+    def note_written(self, slot: int, tokens: int) -> None:
+        for g in self.groups.values():
+            g.note_written(slot, tokens)
+
+    # -- prefix sharing: the one group's, and nothing to guard without ----
+
+    def register_prefix(self, slot: int, tokens) -> int:
+        return self._sharing.register_prefix(slot, tokens)
+
+    def ensure_writable(self, slot: int, pos: int):
+        if self._sharing is not None:
+            return self._sharing.ensure_writable(slot, pos)
+        return None
+
+    def ensure_writable_range(self, slot: int, start: int, end: int) -> int:
+        return self._sharing.ensure_writable_range(slot, start, end)
+
+    def rollback(self, slot: int, tokens: int) -> None:
+        self._sharing.rollback(slot, tokens)
+
+    def billed_blocks(self, slot: int) -> float:
+        """Blocks the slot holds over all groups, a shared one at its
+        share (``PagedKVCache.billed_blocks``)."""
+        return sum(g.billed_blocks(slot) for g in self.groups.values())
+
+    @property
+    def blocks_recycled(self) -> int:
+        return sum(getattr(g, "blocks_recycled", 0)
+                   for g in self.groups.values())
+
+    def stats(self) -> dict:
+        """The full group's census at the top level (the keys every reader
+        of ``stats()`` knows), every group's under ``"groups"``."""
+        per_group = {name: g.stats() for name, g in self.groups.items()}
+        top = dict(per_group.get("full", next(iter(per_group.values()))))
+        top["groups"] = {
+            name: {"blocks_total": s["blocks_total"],
+                   "blocks_used": s["blocks_used"],
+                   "blocks_free": s["blocks_free"]}
+            for name, s in per_group.items()}
+        top["blocks_recycled"] = self.blocks_recycled
+        return top
+
+
+def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
+                       max_context: int, num_blocks: dict[str, int | None],
+                       write_ahead: int) -> GroupedKVCache:
+    """The groups of a model: a ``"full"`` and a ``"window"`` group,
+    whichever exist, by the attention kind its config names for a layer
+    (``cfg.window_of(layer)``; a config without it, as GPT-2's, is one full
+    group).  ``num_blocks[name] = None`` provisions every slot's worst
+    case (full provisioning; fewer oversubscribes — paged memory is the
+    point — and admission control, not OOM, then absorbs the pressure)."""
+    per_slot = max_context // block_size
+    window_of = getattr(cfg, "window_of", lambda layer: None)
+    head_dim = getattr(cfg, "head_dim", None) \
+        or cfg.hidden_size // cfg.num_heads
+    groups, layers = {}, {}
+    for name, is_window in (("full", False), ("window", True)):
+        ls = tuple(i for i in range(cfg.num_layers)
+                   if (window_of(i) is not None) == is_window)
+        if not ls:
+            continue
+        kw = dict(num_layers=len(ls), kv_heads=cfg.kv_heads,
+                  head_dim=head_dim, max_slots=max_slots,
+                  block_size=block_size, max_context=max_context,
+                  dtype=cfg.dtype)
+        if is_window:
+            window = window_of(ls[0])
+            ring = -(-(window + write_ahead) // block_size) + 1
+            n = num_blocks.get(name) or max_slots * min(per_slot, ring)
+            groups[name] = WindowKVGroup(
+                window=window, write_ahead=write_ahead, num_blocks=n, **kw)
+        else:
+            n = num_blocks.get(name) or max_slots * per_slot
+            groups[name] = PagedKVCache(num_blocks=n, **kw)
+        layers[name] = ls
+    return GroupedKVCache(groups, layers)

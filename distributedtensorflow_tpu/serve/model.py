@@ -60,11 +60,18 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..models import afmoe
 from ..models.generate import prefill
 from ..models.gpt import GPTConfig, rope, rope_tables
-from ..ops.attention import paged_decode_attention, paged_verify_attention
+from ..ops.attention import (
+    paged_chunk_attention,
+    paged_decode_attention,
+    paged_verify_attention,
+    paged_window_decode_attention,
+)
 from ..ops.layernorm import layer_norm
 from ..ops.xent import tied_head_logits
+from ..obs import tracing as obs_tracing
 from .sampling import sample_burst
 
 __all__ = [
@@ -73,6 +80,9 @@ __all__ = [
     "make_decode_fn",
     "make_fused_decode_fn",
     "make_gather_cache_fn",
+    "make_family_prefill_fn",
+    "make_family_decode_fn",
+    "make_programs",
     "reset_cache_index",
 ]
 
@@ -433,3 +443,254 @@ def make_fused_decode_fn(cfg: GPTConfig, *, block_size: int, draft: int = 0):
         return packed, next_feed[:, None], k_pool, v_pool
 
     return fused_decode
+
+
+# ---------------------------------------------------------------------------
+# The programs of a family given by its layer functions (``models.afmoe``)
+# ---------------------------------------------------------------------------
+#
+# The GPT-2 programs above are that forward written out by hand around a
+# dense flax prefill cache.  A family that brings its own layer functions
+# (``family``: a module with ``embed``, ``block(p, x, cfg, layer, positions,
+# attend)`` and ``head``, as ``models.afmoe``) needs no second copy of them
+# here: a program is the family's embedding, its blocks
+# and its head, with an ``attend`` that writes the new K/V rows into the
+# layer's group pool and reads the pages back (``ops.attention``'s
+# ``paged_chunk_attention`` / ``paged_window_decode_attention``).  So there
+# is no dense cache and no gather program: a prefill chunk reads the slot's
+# earlier chunks through its page-table rows, which is what
+# ``make_gather_cache_fn`` re-materialises for GPT-2.  Weights are stored in
+# the compute type; nothing is cast per iteration.
+#
+# ``pools`` is ``{group: (k_pool, v_pool)}`` and ``tables`` ``{group:
+# page table}`` (``serve.kv_cache.GroupedKVCache``); ``layers`` maps a
+# group to the model layers it holds, in pool order.
+
+
+def _group_of(layers: dict[str, tuple[int, ...]]) -> dict[int, tuple]:
+    return {layer: (name, i) for name, ls in layers.items()
+            for i, layer in enumerate(ls)}
+
+
+def make_family_prefill_fn(family, cfg, *, chunk: int, block_size: int,
+                           layers: dict[str, tuple[int, ...]]):
+    """``fn(params, pools, tokens (chunk,), start, table_rows, last_ix) ->
+    (last_logits, pools)``: one fixed-width prompt chunk of one slot; the
+    pools are donated."""
+    where = _group_of(layers)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, pools, tokens, start, table_rows, last_ix):
+        pools = dict(pools)
+        positions = start + jnp.arange(chunk, dtype=jnp.int32)
+        x = family.embed(params, tokens, cfg)
+        for layer in range(cfg.num_layers):
+            name, li = where[layer]
+            row = table_rows[name]
+
+            def attend(q, k, v, name=name, li=li, row=row, layer=layer):
+                k_pool, v_pool = pools[name]
+                with jax.named_scope("kv_write"):
+                    idx = row[positions // block_size] * block_size \
+                        + positions % block_size
+                    k_pool = k_pool.at[li, idx].set(k.reshape(chunk, -1))
+                    v_pool = v_pool.at[li, idx].set(v.reshape(chunk, -1))
+                pools[name] = (k_pool, v_pool)
+                return paged_chunk_attention(
+                    q, start, k_pool, v_pool, row, layer=li,
+                    block_size=block_size, window=cfg.window_of(layer))
+
+            with jax.named_scope(f"h{layer}"):
+                x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
+                                   positions, attend)
+        last = jax.lax.dynamic_slice_in_dim(x, last_ix, 1, 0)
+        return family.head(params, last, cfg)[0], pools
+
+    return prefill_chunk
+
+
+def make_family_decode_fn(family, cfg, *, block_size: int,
+                          layers: dict[str, tuple[int, ...]]):
+    """``fn(params, pools, tokens (slots,), tables, seq_lens, active) ->
+    (logits, pools, routed)``: one token for every slot.  ``routed`` is
+    int32 ``(3,)``: over the expert layers, the routed (token, choice)
+    pairs that landed on held experts (sum), the held experts hit (sum)
+    and the largest load of one expert (max) — active slots only."""
+    where = _group_of(layers)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode(params, pools, tokens, tables, seq_lens, active):
+        pools = dict(pools)
+        bs = block_size
+        positions = seq_lens.astype(jnp.int32)
+        attend_lens = jnp.where(active, positions + 1, 1)
+        x = family.embed(params, tokens, cfg)
+        routed = []
+        for layer in range(cfg.num_layers):
+            name, li = where[layer]
+
+            def attend(q, k, v, name=name, li=li, layer=layer):
+                k_pool, v_pool = pools[name]
+                with jax.named_scope("kv_write"):
+                    blk = jnp.take_along_axis(
+                        tables[name], (positions // bs)[:, None], axis=1
+                    )[:, 0]
+                    idx = jnp.where(active, blk * bs + positions % bs,
+                                    k_pool.shape[1] - bs)   # else: scratch
+                    k_pool = k_pool.at[li, idx].set(
+                        k.reshape(k.shape[0], -1))
+                    v_pool = v_pool.at[li, idx].set(
+                        v.reshape(v.shape[0], -1))
+                pools[name] = (k_pool, v_pool)
+                return paged_window_decode_attention(
+                    q, k_pool, v_pool, tables[name], attend_lens, layer=li,
+                    block_size=bs, window=cfg.window_of(layer),
+                    impl=cfg.kernel_impl)
+
+            with jax.named_scope(f"h{layer}"):
+                x, counters = family.block(
+                    params[f"h{layer}"], x, cfg, layer, positions, attend,
+                    token_mask=active)
+            if counters is not None:
+                routed.append(counters)
+        if routed:
+            stat = jnp.stack([
+                sum(c["pairs"] for c in routed),
+                sum(c["experts_hit"] for c in routed),
+                functools.reduce(jnp.maximum,
+                                 [c["max_load"] for c in routed]),
+            ]).astype(jnp.int32)
+        else:
+            stat = jnp.zeros((3,), jnp.int32)
+        return family.head(params, x, cfg), pools, stat
+
+    return decode
+
+
+# ---------------------------------------------------------------------------
+# What the engine drives: one set of programs a configuration
+# ---------------------------------------------------------------------------
+#
+# The engine holds layer groups (``serve.kv_cache.GroupedKVCache``) and a
+# set of programs over them, and knows no family: ``pools`` is ``{group:
+# (k_pool, v_pool)}``, ``tables`` / ``table_rows`` ``{group: page table /
+# one slot's row}``.  A set of programs answers
+#
+# - ``prefill(params, pools, tokens (chunk,) on the host, start, table_rows,
+#   last_ix, slot) -> (last_logits, pools)``: one prompt chunk of ``slot``;
+# - ``decode(params, pools, tokens (slots,), tables, seq_lens, active) ->
+#   (logits, pools, routed)``: one token a slot; ``routed`` the expert
+#   layers' counters of the iteration, None where there are none;
+# - ``fused(draft)``: the sampled (``draft`` = 0) or verify program,
+#   ``fn(params, pools, *feeds) -> (packed, next_feed, pools)``, or a
+#   ``ValueError`` that says it is not implemented;
+# - ``forget(slot)``: the slot has a new tenant.
+
+
+class GPTPrograms:
+    """The GPT-2 programs above over their one full group.  The dense
+    prefill cache belongs here: it is re-materialised from the slot's pool
+    blocks (:func:`make_gather_cache_fn`) unless it already holds exactly
+    that slot's K/V through the chunk's start — which makes chunks
+    stateless and freely interleavable across requests."""
+
+    def __init__(self, cfg: GPTConfig, *, chunk: int, block_size: int,
+                 layers: dict[str, tuple[int, ...]]):
+        (self.group,) = layers
+        self.cfg, self.chunk, self.block_size = cfg, chunk, block_size
+        self._prefill = make_prefill_fn(cfg, chunk=chunk,
+                                        block_size=block_size)
+        self._decode = make_decode_fn(cfg, block_size=block_size)
+        self._gather = make_gather_cache_fn(cfg, block_size=block_size)
+        self._cache = make_prefill_cache(cfg)
+        #: (slot, pos): the dense cache holds that slot's K/V for
+        #: positions [0, pos).  None = unknown/stale.
+        self._cache_state: tuple[int, int] | None = None
+
+    def forget(self, slot: int) -> None:
+        """Never alias the dense cache across a slot's tenants."""
+        if self._cache_state is not None and self._cache_state[0] == slot:
+            self._cache_state = None
+
+    def prefill(self, params, pools, tokens, start: int, table_rows,
+                last_ix: int, slot: int):
+        k_pool, v_pool = pools[self.group]
+        table_row = table_rows[self.group]
+        if self._cache_state != (slot, start):
+            if start:
+                with obs_tracing.span("engine.gather"):
+                    self._cache = self._gather(
+                        k_pool, v_pool, self._cache, table_row,
+                        jnp.int32(start))
+            else:
+                self._cache = reset_cache_index(self._cache)
+        last_logits, self._cache, k_pool, v_pool = self._prefill(
+            params, k_pool, v_pool, self._cache, jnp.asarray(tokens[None]),
+            jnp.int32(start), table_row, jnp.int32(last_ix))
+        self._cache_state = (slot, start + self.chunk)
+        return last_logits, {self.group: (k_pool, v_pool)}
+
+    def decode(self, params, pools, tokens, tables, seq_lens, active):
+        logits, k_pool, v_pool = self._decode(
+            params, *pools[self.group], tokens, tables[self.group],
+            seq_lens, active)
+        return logits, {self.group: (k_pool, v_pool)}, None
+
+    def fused(self, draft: int):
+        fn = make_fused_decode_fn(self.cfg, block_size=self.block_size,
+                                  draft=draft)
+        group = self.group
+
+        def fused(params, pools, tokens, draft_lens, tables, *feeds):
+            packed, next_feed, k_pool, v_pool = fn(
+                params, *pools[group], tokens, draft_lens, tables[group],
+                *feeds)
+            return packed, next_feed, {group: (k_pool, v_pool)}
+        return fused
+
+
+class BlockPrograms:
+    """The programs of a family given by its layer functions
+    (:func:`make_family_prefill_fn`, :func:`make_family_decode_fn`): no
+    dense cache, so nothing to forget."""
+
+    def __init__(self, family, cfg, *, chunk: int, block_size: int,
+                 layers: dict[str, tuple[int, ...]]):
+        self.family = family.__name__.rsplit(".", 1)[-1]
+        self._prefill = make_family_prefill_fn(
+            family, cfg, chunk=chunk, block_size=block_size, layers=layers)
+        self.decode = make_family_decode_fn(
+            family, cfg, block_size=block_size, layers=layers)
+
+    def forget(self, slot: int) -> None:
+        pass
+
+    def prefill(self, params, pools, tokens, start: int, table_rows,
+                last_ix: int, slot: int):
+        return self._prefill(params, pools, jnp.asarray(tokens),
+                             jnp.int32(start), table_rows,
+                             jnp.int32(last_ix))
+
+    def fused(self, draft: int):
+        raise ValueError(
+            f"{'speculate' if draft else 'fused_sampling'} is not "
+            f"implemented for the {self.family} family yet (the fused and "
+            "verify programs are GPT-2's): serve it without")
+
+
+#: configuration class -> its programs: the one place that tells the
+#: families apart
+PROGRAMS = {
+    GPTConfig: GPTPrograms,
+    afmoe.AfmoeConfig: functools.partial(BlockPrograms, afmoe),
+}
+
+
+def make_programs(cfg, *, chunk: int, block_size: int,
+                  layers: dict[str, tuple[int, ...]]):
+    """The programs of ``cfg``'s family over the layer groups ``layers``."""
+    for kind, make in PROGRAMS.items():
+        if isinstance(cfg, kind):
+            return make(cfg, chunk=chunk, block_size=block_size,
+                        layers=layers)
+    raise ValueError(f"no serving programs for a {type(cfg).__name__}")

@@ -42,6 +42,9 @@ CONFIGS = {
     "gpt_tiny": ("gpt_tiny", ("gpt_lm", True)),
     "gpt_small": ("gpt_small", ("gpt_lm", False)),
     "gpt_medium": ("gpt_medium", ("gpt_medium_lm", False)),
+    # the afmoe family (models/afmoe.py): random init only, no trainer yet
+    "afmoe_tiny": ("afmoe_tiny", None),
+    "trinity_large_ep8": ("trinity_large_ep8", None),
 }
 
 
@@ -54,6 +57,16 @@ def build_params(args, cfg):
     seeded random init (load tests, CI)."""
     import jax
 
+    if getattr(cfg, "family", "gpt") == "afmoe":
+        from distributedtensorflow_tpu.models import afmoe
+
+        if args.checkpoint:
+            raise SystemExit(
+                f"--checkpoint: --config {args.config} has no trainer to "
+                "have written one; it serves a seeded random init")
+        logging.info("random-init params in %s (no --checkpoint)",
+                     cfg.dtype.__name__)
+        return afmoe.init_params(cfg, jax.random.PRNGKey(args.seed))
     if not args.checkpoint:
         import numpy as np
 
@@ -115,6 +128,12 @@ def main(argv=None) -> int:
     p.add_argument("--kv-blocks", type=int, default=None,
                    help="total KV pool blocks (default: max-slots * "
                         "max-context/block-size = no oversubscription)")
+    p.add_argument("--kv-window-blocks", type=int, default=None,
+                   help="blocks of the window layers' pool, for a model "
+                        "whose layers are in groups by attention kind "
+                        "(--kv-blocks is then the full layers' pool; "
+                        "default: every slot's ring of window + one "
+                        "prefill chunk + one block)")
     p.add_argument("--prefill-chunk", type=int, default=16,
                    help="prefill program width in tokens")
     p.add_argument("--prefill-budget", type=int, default=0,
@@ -278,6 +297,7 @@ def main(argv=None) -> int:
         params, cfg,
         max_slots=args.max_slots, max_queue=args.max_queue,
         block_size=args.block_size, num_blocks=args.kv_blocks,
+        window_blocks=args.kv_window_blocks,
         prefill_chunk=args.prefill_chunk,
         prefill_budget=args.prefill_budget or None,
         prefix_cache=args.prefix_cache,

@@ -25,9 +25,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributedtensorflow_tpu.ops.attention import _pallas_decode_attention
+from distributedtensorflow_tpu.ops.attention import (
+    _pallas_decode_attention,
+    paged_window_decode_attention,
+)
 from distributedtensorflow_tpu.ops.flash_attention import flash_attention
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
+from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
 
 # GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
@@ -73,6 +77,30 @@ def _decode(q, k, v, valid):
     return _pallas_decode_attention(q, k, v, valid, interpret=False)
 
 
+def _paged(window):
+    # the afmoe serving shapes: 64 slots, 48 query / 8 K/V heads of 128,
+    # blocks of 16, a table of 512 columns, one layer group's pool
+    def fn(q, k_pool, v_pool, tables, lens):
+        return paged_window_decode_attention(
+            q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
+            window=window, impl="pallas", interpret=False)
+    pool = _sds((2, 2049 * 16, 1024), BF16)
+    return fn, (_sds((64, 48, 128), BF16), pool, pool,
+                _sds((64, 512), jnp.int32), _sds((64,), jnp.int32))
+
+
+def _grouped(x, w_gate, w_up, w_down, tile_expert, tiles_used):
+    return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert, tiles_used,
+                          tile=16, interpret=False)
+
+
+def _grouped_args(rows=768, experts=8, d=3072, m=3072):
+    # a decode iteration's row buffer at the published expert widths
+    return (_sds((rows, d), BF16), _sds((experts, d, m), BF16),
+            _sds((experts, d, m), BF16), _sds((experts, m, d), BF16),
+            _sds((rows // 16,), jnp.int32), _sds((), jnp.int32))
+
+
 def _qkv(seq=S, kv_heads=H, batch=B):
     return (_sds((batch, seq, H, D), BF16),
             _sds((batch, seq, kv_heads, D), BF16),
@@ -93,6 +121,9 @@ FAMILIES = {
                          _sds((B, H, S, D), BF16),
                          _sds((B, H, S, D), BF16),
                          _sds((1, S), jnp.int32))),
+    "paged_attn_full": _paged(None),
+    "paged_attn_window": _paged(4096),
+    "moe_grouped": (_grouped, _grouped_args()),
 }
 
 
