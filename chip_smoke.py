@@ -18,7 +18,9 @@ weights from a seed), and checks what comes out:
 3. ``serve``      ``serve.py --config gpt_small --port 0``: blocking,
                   streamed and concurrent ``POST /generatez`` of different
                   prompt lengths; streamed greedy tokens equal the blocking
-                  reply; no KV block leaked; SIGTERM drains to exit 0 with
+                  reply; no KV block leaked; the decode program attends
+                  through the ``paged_attn`` kernel, not its silent fallback
+                  (``decode_attention``); SIGTERM drains to exit 0 with
                   ``requests.jsonl`` and the final ``metrics.jsonl`` row;
 4. ``pool_form``  ``python -m distributedtensorflow_tpu.serve.pool_check`` at
                   the shapes of the benchmark's serving cells (GPT-2
@@ -352,6 +354,9 @@ def serve_leg(out: str) -> dict:
               f"server not idle after the requests: {state['slots']}")
         check(kv["blocks_free"] == kv["blocks_total"],
               f"leaked KV blocks: {kv}")
+        check(state["decode_attention"] == "paged_attn",
+              "the decode program fell back to the plain gather of every "
+              f"table column: decode_attention={state['decode_attention']!r}")
         counters = state["counters"]
         check(counters["ok"] == 4 and counters["error"] == 0,
               f"request counters: {counters}")

@@ -202,10 +202,13 @@ def paged_decode_attention(
     unallocated table entry points at), and runs the same fp32-softmax
     scaled dot product as the dense decode path — so paged and dense
     decode agree bit-for-bit up to reduction order (tests pin this).
-    Reference XLA formulation (gather + einsum); a Mosaic kernel that
-    streams blocks without materializing the gather is future work, so
-    compute cost is O(max_blocks * block_size) per slot while *residency*
-    is O(allocated blocks).
+    The plain XLA formulation (gather + einsum): compute cost is
+    O(max_blocks * block_size) per slot whatever is resident, 50 ms an
+    iteration at GPT-2 medium's 32 slots of 1024 (PERF.md §6, PR 29).  The
+    kernel that streams only the blocks a slot holds is
+    :func:`paged_window_decode_attention`, which the decode programs call;
+    this is its yardstick in the tests, its path off the TPU and at shapes
+    it does not take, and what :func:`paged_verify_attention` shares.
     """
     b, h, d = q.shape
     k = _gather_pages(k_pool, layer, block_tables, block_size, d)
@@ -473,15 +476,19 @@ def xla_attention(q, k, v, *, mask=None, causal=False, window=None):
 #
 # The two functions above gather every table column of every slot: their
 # cost follows slots x max_context whatever is resident (PERF.md §5).  The
-# two below are the paths of a family with window layers and long contexts
-# (``models.afmoe``): they take ONE layer group's pool (``serve.kv_cache``
-# row form, ``(L_group, rows, Hkv * D)``), and an optional ``window``: a
-# query at position ``i`` attends keys ``j`` with ``i - window < j <= i``.
+# two below read what a slot attends: the decode programs of both served
+# families (``serve.model``) and the chunk prefill of the one with window
+# layers and long contexts (``models.afmoe``).  They take ONE layer group's
+# pool (``serve.kv_cache`` row form, ``(L_group, rows, Hkv * D)``), and an
+# optional ``window``: a query at position ``i`` attends keys ``j`` with
+# ``i - window < j <= i``.
 
+#: lanes of a vector register: the decode kernel takes the pool's row a
+#: 128-lane tile at a time, one K/V head of 128 or two of 64
+LANES = 128
 #: key rows the decode kernel folds into its running softmax at a time: the
 #: lane width, so the (8, rows) scores, the running maximum and sum (kept
-#: replicated across lanes) and the (8, D) accumulator at D = 128 all share
-#: one shape
+#: replicated across lanes) and the (8, 128) accumulator all share one shape
 PAGED_ROWS = 128
 #: such stretches a grid step holds: all their block copies are started at
 #: the top of the step and each stretch waits only for its own, so the later
@@ -550,9 +557,9 @@ def paged_chunk_attention(
     return out.transpose(2, 0, 1, 3).reshape(t, h, d).astype(q.dtype)
 
 
-def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc, *,
-                         layer, block_size, h_kv, d, n_steps, scale):
+def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
+                         v_hbm, o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc,
+                         *, block_size, n_steps, scale):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -561,7 +568,8 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, q_ref, k_hbm, v_hbm,
     step_rows = rows * PAGED_STRETCHES
     bps = rows // block_size                   # blocks a stretch
     nb = tables_ref.shape[1]
-    n, lo = lens_ref[s], lo_ref[s]
+    tiles, q_rows = q_ref.shape[1], q_ref.shape[2]
+    n, lo, layer = lens_ref[s], lo_ref[s], layer_ref[0]
     first = (lo // step_rows + c) * step_rows  # first key row of this step
 
     @pl.when((s == 0) & (c == 0))
@@ -613,11 +621,12 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, q_ref, k_hbm, v_hbm,
                     ck.wait()
                     cv.wait()
 
-            kpos = start + jax.lax.broadcasted_iota(jnp.int32, (8, rows), 1)
+            kpos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (q_rows, rows), 1)
             valid = (kpos < n) & (kpos >= lo)
             here = pl.ds(part * rows, rows)
-            for hh in range(h_kv):
-                lanes = pl.ds(hh * d, d)
+            for hh in range(tiles):
+                lanes = pl.ds(hh * LANES, LANES)
                 sc = jax.lax.dot_general(
                     q_ref[0, hh], kbuf[here, lanes],
                     (((1,), (1,)), ((), ())),
@@ -637,6 +646,18 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, q_ref, k_hbm, v_hbm,
     def _():
         o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
+                             block_size: int, impl: str = "auto") -> str:
+    """Which formulation :func:`paged_window_decode_attention` takes at
+    these shapes: ``"paged_attn"`` (the kernel) or ``"plain"`` (the gather
+    of every table column).  A test of shapes and of ``impl`` alone, so a
+    program can say what it was built with (``serve.model``)."""
+    fits = (head_dim in (64, LANES) and heads // kv_heads <= 8
+            and PAGED_ROWS % block_size == 0
+            and kv_heads * head_dim % LANES == 0)
+    return "paged_attn" if use_kernel(impl) and fits else "plain"
 
 
 def paged_window_decode_attention(
@@ -660,46 +681,78 @@ def paged_window_decode_attention(
     The kernel (``name="paged_attn"``) has the page tables and lengths
     prefetched into SMEM and the pools left in HBM; grid ``(slot, step)``,
     a step copying the needed blocks of its 4 x 128 key rows into VMEM and
-    folding them, 128 rows at a time, into a running softmax, the K/V heads
-    one after the other on the MXU (the ``H // Hkv`` query heads of a group
-    are the rows of one small product).  Steps past a slot's length copy
-    and compute nothing.  Needs ``D == 128`` and ``H // Hkv <= 8``;
-    other shapes, and ``impl="xla"``, take the plain formulation."""
+    folding them, 128 rows at a time, into a running softmax, one 128-lane
+    tile of the pool's row after the other on the MXU.  A tile is one K/V
+    head of 128 or two of 64, and the query heads that attend it are the
+    rows of one small product: head ``i`` of the tile keeps its query in
+    lanes ``[i * D, (i + 1) * D)`` of its rows and zeros in the others, so
+    the product over all 128 lanes is that head's scores, and its output is
+    the same lanes of the same rows (the other lanes, its weights on the
+    neighbour's values, are dropped).  Steps past a slot's length copy and
+    compute nothing.  Needs ``D`` of 64 or 128, ``H // Hkv <= 8``, a block
+    size that divides 128 and a pool row of whole tiles
+    (:func:`paged_decode_formulation`); other shapes, and ``impl="xla"``,
+    take the plain formulation."""
     b, h, d = q.shape
     width = k_pool.shape[-1]
     h_kv = width // d
     g = h // h_kv
-    rows = PAGED_ROWS
-    step_rows = rows * PAGED_STRETCHES
-    fits = d == 128 and g <= 8 and rows % block_size == 0
-    if not (use_kernel(impl) and fits):
+    step_rows = PAGED_ROWS * PAGED_STRETCHES
+    if paged_decode_formulation(h, h_kv, d, block_size, impl) == "plain":
         # the plain formulation (gathers every table column): the tests'
         # yardstick for the kernel and the path off the TPU
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, attend_lens, layer=layer,
             block_size=block_size, window=window)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     if interpret is None:
         interpret = not on_tpu()
     cap = block_tables.shape[1] * block_size
     # a window starts anywhere inside its first step
     span = cap if window is None else min(cap, window + step_rows - 1)
-    n_steps = -(-span // step_rows)
     lens = attend_lens.astype(jnp.int32)
     lo = (jnp.zeros_like(lens) if window is None
           else jnp.maximum(lens - window, 0))
-    q8 = jnp.zeros((b, h_kv, 8, d), q.dtype).at[:, :, :g].set(
-        q.reshape(b, h_kv, g, d))
-    blk = pl.BlockSpec((1, h_kv, 8, d), lambda s, c, *_: (s, 0, 0, 0))
-    out = pl.pallas_call(
+    per_tile = LANES // d             # K/V heads a tile: 1, or 2 at D = 64
+    tiles = width // LANES
+    q_rows = -(-per_tile * g // 8) * 8
+    # (B, tile, head of the tile, query of the head, lanes of a head, D):
+    # each head's query in its own lanes of the tile, zeros in the others
+    own = jnp.eye(per_tile, dtype=bool)[:, None, :, None]
+    qt = jnp.where(own, q.reshape(b, tiles, per_tile, g, 1, d), 0)
+    qt = jnp.pad(qt.reshape(b, tiles, per_tile * g, LANES),
+                 ((0, 0), (0, 0), (0, q_rows - per_tile * g), (0, 0)))
+    out = _paged_attn_call(
+        block_tables.astype(jnp.int32), lens, lo,
+        jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool,
+        block_size=block_size, n_steps=-(-span // step_rows),
+        scale=d ** -0.5, interpret=interpret)
+    out = out[:, :, :per_tile * g].reshape(b, tiles, per_tile, g, per_tile, d)
+    return jnp.where(own, out, 0).sum(axis=4).reshape(b, h, d)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "n_steps", "scale", "interpret"))
+def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, *,
+                     block_size, n_steps, scale, interpret):
+    """The kernel's call.  A jitted function of its own with the layer as a
+    prefetched scalar, so that the layers of a program that call it at the
+    same shapes share one trace and one lowering of the body (0.8 s a call
+    otherwise: 20 s of GPT-2 medium's start-up)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, tiles, q_rows, _ = qt.shape
+    width = k_pool.shape[-1]
+    step_rows = PAGED_ROWS * PAGED_STRETCHES
+    blk = pl.BlockSpec((1, tiles, q_rows, LANES),
+                       lambda s, c, *_: (s, 0, 0, 0))
+    return pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, layer=layer, block_size=block_size,
-            h_kv=h_kv, d=d, n_steps=n_steps, scale=d ** -0.5),
+            _paged_decode_kernel, block_size=block_size, n_steps=n_steps,
+            scale=scale),
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(b, n_steps),
+            num_scalar_prefetch=4, grid=(b, n_steps),
             in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=blk,
@@ -707,11 +760,10 @@ def paged_window_decode_attention(
                 pltpu.VMEM((step_rows, width), k_pool.dtype),
                 pltpu.VMEM((step_rows, width), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, step_rows // block_size)),
-                pltpu.VMEM((h_kv, 8, rows), jnp.float32),
-                pltpu.VMEM((h_kv, 8, rows), jnp.float32),
-                pltpu.VMEM((h_kv, 8, d), jnp.float32),
+                pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
+                pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
+                pltpu.VMEM((tiles, q_rows, LANES), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, 8, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lens, lo, q8, k_pool, v_pool)
-    return out[:, :, :g].reshape(b, h, d)
+    )(tables, lens, lo, layer, qt, k_pool, v_pool)

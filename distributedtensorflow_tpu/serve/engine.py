@@ -1541,6 +1541,8 @@ class Engine:
             "prefix_cache": self.prefix_cache,
             "fused_sampling": self.fused_sampling,
             "speculate": self.speculate,
+            # "paged_attn" or "plain" (serve.model): the fallback is silent
+            "decode_attention": self.programs.decode_attention,
             "spec_acceptance_rate": (
                 self.counters["spec_accepted"] / self.counters["spec_drafted"]
                 if self.counters["spec_drafted"] else 0.0

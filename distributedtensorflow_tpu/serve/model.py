@@ -14,7 +14,8 @@ shapes, so a serving process compiles **exactly two** XLA executables:
   a scatter of the chunk's K/V into the paged pool.  Any prompt length =
   a Python loop of these fixed-width calls.
 - :func:`make_decode_fn` — one token for all ``max_slots`` slots against
-  the paged pool (``ops.attention.paged_decode_attention``).  The forward
+  the paged pool (``ops.attention.paged_window_decode_attention``: on the
+  TPU a kernel that reads only the blocks a slot holds).  The forward
   is rebuilt here from the raw param tree (flax's cache collection owns a
   dense per-slot buffer and can't address a shared pool); equivalence
   with ``GPTLM`` is pinned by tests/test_serve.py, and every dtype choice
@@ -65,7 +66,7 @@ from ..models.generate import prefill
 from ..models.gpt import GPTConfig, rope, rope_tables
 from ..ops.attention import (
     paged_chunk_attention,
-    paged_decode_attention,
+    paged_decode_formulation,
     paged_verify_attention,
     paged_window_decode_attention,
 )
@@ -286,9 +287,10 @@ def make_decode_fn(cfg: GPTConfig, *, block_size: int):
                     v_pool = v_pool.at[layer, idx].set(
                         v.reshape(b, kv_width))
                 with jax.named_scope("paged_attn"):
-                    out = paged_decode_attention(
+                    out = paged_window_decode_attention(
                         q[:, 0], k_pool, v_pool, block_tables,
                         attend_lens, layer=layer, block_size=bs,
+                        impl=cfg.attn_impl,
                     ).reshape(b, 1, hidden).astype(cfg.dtype)
                 with jax.named_scope("proj"):
                     x = x + _dense(out, p["attn"]["proj"]["kernel"])
@@ -584,7 +586,11 @@ def make_family_decode_fn(family, cfg, *, block_size: int,
 # - ``fused(draft)``: the sampled (``draft`` = 0) or verify program,
 #   ``fn(params, pools, *feeds) -> (packed, next_feed, pools)``, or a
 #   ``ValueError`` that says it is not implemented;
-# - ``forget(slot)``: the slot has a new tenant.
+# - ``forget(slot)``: the slot has a new tenant;
+# - ``decode_attention``: the formulation the decode programs in use attend
+#   the pages with, ``"paged_attn"`` (the kernel that reads only the blocks
+#   a slot holds) or ``"plain"`` (the gather of every table column): the
+#   fallback is silent, so the engine reports it (``Engine.state()``).
 
 
 class GPTPrograms:
@@ -601,6 +607,9 @@ class GPTPrograms:
         self._prefill = make_prefill_fn(cfg, chunk=chunk,
                                         block_size=block_size)
         self._decode = make_decode_fn(cfg, block_size=block_size)
+        self.decode_attention = paged_decode_formulation(
+            cfg.num_heads, cfg.kv_heads, cfg.hidden_size // cfg.num_heads,
+            block_size, cfg.attn_impl)
         self._gather = make_gather_cache_fn(cfg, block_size=block_size)
         self._cache = make_prefill_cache(cfg)
         #: (slot, pos): the dense cache holds that slot's K/V for
@@ -640,6 +649,9 @@ class GPTPrograms:
         fn = make_fused_decode_fn(self.cfg, block_size=self.block_size,
                                   draft=draft)
         group = self.group
+        # the engine decodes through these from now on, and they attend
+        # with ``paged_verify_attention``
+        self.decode_attention = "plain"
 
         def fused(params, pools, tokens, draft_lens, tables, *feeds):
             packed, next_feed, k_pool, v_pool = fn(
@@ -661,6 +673,9 @@ class BlockPrograms:
             family, cfg, chunk=chunk, block_size=block_size, layers=layers)
         self.decode = make_family_decode_fn(
             family, cfg, block_size=block_size, layers=layers)
+        self.decode_attention = paged_decode_formulation(
+            cfg.num_heads, cfg.kv_heads, cfg.head_dim, block_size,
+            cfg.kernel_impl)
 
     def forget(self, slot: int) -> None:
         pass

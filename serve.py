@@ -309,7 +309,8 @@ def main(argv=None) -> int:
         log_every=args.log_every, step_ring=args.step_ring,
         capture=capture,
     ).start()
-    startup.mark("startup.engine_build")
+    startup.mark("startup.engine_build",
+                 decode_attention=engine.programs.decode_attention)
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation
     # endpoint (text / ?json / ?tenant= filter; usage.jsonl under

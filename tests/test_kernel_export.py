@@ -77,16 +77,16 @@ def _decode(q, k, v, valid):
     return _pallas_decode_attention(q, k, v, valid, interpret=False)
 
 
-def _paged(window):
+def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2):
     # the afmoe serving shapes: 64 slots, 48 query / 8 K/V heads of 128,
     # blocks of 16, a table of 512 columns, one layer group's pool
     def fn(q, k_pool, v_pool, tables, lens):
         return paged_window_decode_attention(
             q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
             window=window, impl="pallas", interpret=False)
-    pool = _sds((2, 2049 * 16, 1024), BF16)
-    return fn, (_sds((64, 48, 128), BF16), pool, pool,
-                _sds((64, 512), jnp.int32), _sds((64,), jnp.int32))
+    pool = _sds((layers, 2049 * 16, 1024), BF16)
+    return fn, (_sds((slots, heads, d), BF16), pool, pool,
+                _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32))
 
 
 def _grouped(x, w_gate, w_up, w_down, tile_expert, tiles_used):
@@ -123,6 +123,10 @@ FAMILIES = {
                          _sds((1, S), jnp.int32))),
     "paged_attn_full": _paged(None),
     "paged_attn_window": _paged(4096),
+    # GPT-2 medium's serving shapes: 32 slots, 16 heads of 64 (two a
+    # 128-lane tile of the pool's row), contexts to 1024, 24 layers
+    "paged_attn_gpt2m": _paged(None, slots=32, heads=16, d=64, columns=64,
+                               layers=24),
     "moe_grouped": (_grouped, _grouped_args()),
 }
 
@@ -193,28 +197,47 @@ def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", [
-    "prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
-    "gather_cache", "copy_block"])
-def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program):
-    """GPT-2 medium's widths and the benchmark cells' pool (2048 blocks of
-    16 tokens, 32 slots), two layers deep and with a small vocabulary to
-    keep the compile short (the fused sampler's is most of it): the v5e
-    compiler takes the pool in the form it is stored in, converts no
-    layer of it outside ``paged_attn`` and hands the donated pools back in
-    place.  ``chip_smoke.py`` makes the same check at full depth on the
-    chip."""
+def _as_on_the_chip(monkeypatch):
+    """The programs choose their kernels by ``runtime.on_tpu()``, which
+    sees this sandbox's CPU: answer for the described chip, in the test."""
+    import sys
+
+    for name, module in list(sys.modules.items()):
+        if name.startswith("distributedtensorflow_tpu") \
+                and hasattr(module, "on_tpu"):
+            monkeypatch.setattr(module, "on_tpu", lambda: True)
+
+
+def _gpt2m_pool_programs(one_chip, **changes):
     import dataclasses
 
     from distributedtensorflow_tpu.models import gpt_medium
-    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+    from distributedtensorflow_tpu.serve import pool_check
 
-    cfg = dataclasses.replace(gpt_medium(), max_seq=1024, num_layers=2,
-                              vocab_size=1024)
-    one_chip = NamedSharding(_v5e_mesh(1), P())
-    programs = pool_check.pool_programs(
+    cfg = dataclasses.replace(gpt_medium(), max_seq=1024, **changes)
+    return pool_check.pool_programs(
         cfg, max_slots=32, num_blocks=2048, block_size=16, chunk=16, draft=4,
         sharding=one_chip)
+
+
+@pytest.mark.parametrize("program", [
+    "prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
+    "gather_cache", "copy_block"])
+def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program,
+                                                          monkeypatch):
+    """GPT-2 medium's widths and the benchmark cells' pool (2048 blocks of
+    16 tokens, 32 slots), two layers deep and with a small vocabulary to
+    keep the compile short (the fused sampler's is most of it), built as
+    on the chip (``decode`` attends through the ``paged_attn`` kernel): the
+    v5e compiler takes the pool in the form it is stored in, converts no
+    layer of it outside ``paged_attn`` and hands the donated pools back in
+    place.  ``chip_smoke.py`` makes the same check at full depth on the
+    chip."""
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    programs = _gpt2m_pool_programs(one_chip, num_layers=2, vocab_size=1024)
     _, rows, width = kv_cache.pool_shape(2, 2048, 16, 16, 64)
     report = pool_check.check_pool_programs(
         {program: programs[program]}, layer_elems=rows * width)
@@ -222,3 +245,29 @@ def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program):
     # rows of all heads, minor dimension a multiple of 128: no padding
     assert report[program]["k_pool"] == \
         "bf16[2,32784,1024]{2,1,0:T(8,128)(2,1)}"
+
+
+def test_decode_program_attends_through_the_kernel_on_a_v5e(monkeypatch):
+    """``jit_decode`` of GPT-2 medium as the chip builds it (24 layers, the
+    cells' shapes; lowered for the TPU, which needs no compile): every
+    layer attends through the ``paged_attn`` kernel — one body, lowered
+    once, the layer a prefetched scalar — and nothing gathers every table
+    column of every slot.  The fallback to the plain formulation is silent
+    (a block size that stops dividing 128, a head size the kernel does not
+    take), and costs 50 ms an iteration: it fails here, not in a
+    benchmark."""
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    fn, args = _gpt2m_pool_programs(one_chip)["decode"]
+    text = fn.lower(*args).as_text()
+    calls = re.findall(r"call @(\w*paged_attn\w*)\(", text)
+    assert len(calls) == 24 and len(set(calls)) == 1, calls
+    assert text.count('kernel_name = "paged_attn"') == 1
+    # (slots, table columns, block, row) or (slots, max_context, row)
+    gathered = re.findall(r"tensor<32x(?:64x16|1024)x1024xbf16>", text)
+    assert not gathered, gathered[:3]
+
+    plain = _gpt2m_pool_programs(one_chip, attn_impl="xla")["decode"]
+    text = plain[0].lower(*plain[1]).as_text()
+    assert "paged_attn\"" not in text
+    assert re.search(r"tensor<32x(?:64x16|1024)x1024xbf16>", text)

@@ -112,43 +112,78 @@ def _stored_form(pool, rng):
         [rng.standard_normal(rows.shape).astype(rows.dtype), rows]))
 
 
-@pytest.mark.parametrize("h,h_kv", [(4, 4), (4, 2)])
-def test_paged_decode_attention_matches_dense(h, h_kv):
+#: lengths around the kernel's edges (a block of 16, a stretch of 128, a
+#: grid step of 512, the table's 1024), mixed in one batch with inactive
+#: slots (0: length 1 on the scratch block, as ``make_decode_fn`` feeds them)
+KERNEL_LENS = [1, 15, 16, 17, 0, 127, 128, 129, 511, 512, 513, 0, 1023, 1024]
+
+#: (H, Hkv, dtype of the kernel case or None for the plain one): the plain
+#: formulation against numpy at toy shapes, then the kernel (interpreted)
+#: against the plain formulation at GPT-2's heads of 64
+PAGED_CASES = [(4, 4, None), (4, 2, None), (16, 16, "float32"),
+               (16, 8, "float32"), (16, 2, "float32"), (16, 16, "bfloat16")]
+
+
+@pytest.mark.parametrize(
+    "h,h_kv,kernel", PAGED_CASES,
+    ids=[f"{h}-{kv}" + (f"-kernel-d64-{k}" if k else "")
+         for h, kv, k in PAGED_CASES])
+def test_paged_decode_attention_matches_dense(h, h_kv, kernel):
     """Gather-through-page-table attention == plain masked attention over
-    the same (contiguously laid out) K/V, incl. GQA grouping."""
+    the same (contiguously laid out) K/V, incl. GQA grouping; and the
+    kernel that reads only the blocks a slot holds == that formulation at
+    heads of 64 (two heads a 128-lane tile), every table entry past a
+    slot's length pointing at the scratch block."""
     from distributedtensorflow_tpu.ops.attention import (
         paged_decode_attention,
+        paged_window_decode_attention,
     )
 
-    b, d, bs, max_blocks = 2, 8, 4, 3
+    if kernel:
+        d, bs, max_blocks, lens = 64, 16, 64, KERNEL_LENS
+    else:
+        d, bs, max_blocks, lens = 8, 4, 3, [5, 9]
+    dtype = jnp.dtype(kernel or "float32")
+    b = len(lens)
     rng = np.random.default_rng(0)
     cap = max_blocks * bs
     k_seq = rng.standard_normal((b, cap, h_kv, d)).astype(np.float32)
     v_seq = rng.standard_normal((b, cap, h_kv, d)).astype(np.float32)
     q = rng.standard_normal((b, h, d)).astype(np.float32)
-    seq_lens = np.array([5, 9], np.int32)
 
-    # scatter the sequences into a shuffled pool (+1 scratch block)
-    num_blocks = b * max_blocks
-    perm = rng.permutation(num_blocks)
-    k_pool = np.zeros((num_blocks + 1, bs, h_kv, d), np.float32)
-    v_pool = np.zeros_like(k_pool)
+    # scatter the sequences into a shuffled pool (+1 scratch block); only
+    # the blocks a sequence holds are mapped
+    held = [-(-n // bs) for n in lens]
+    num_blocks = sum(held)
+    perm = iter(rng.permutation(num_blocks))
+    k_pool = rng.standard_normal((num_blocks + 1, bs, h_kv, d)).astype(
+        np.float32)
+    v_pool = rng.standard_normal(k_pool.shape).astype(np.float32)
     tables = np.full((b, max_blocks), num_blocks, np.int32)
     for i in range(b):
-        for j in range(max_blocks):
-            phys = int(perm[i * max_blocks + j])
+        for j in range(held[i]):
+            phys = int(next(perm))
             tables[i, j] = phys
             k_pool[phys] = k_seq[i, j * bs: (j + 1) * bs]
             v_pool[phys] = v_seq[i, j * bs: (j + 1) * bs]
+    seq_lens = jnp.asarray(np.maximum(lens, 1), jnp.int32)
+    args = (jnp.asarray(q, dtype), _stored_form(k_pool, rng).astype(dtype),
+            _stored_form(v_pool, rng).astype(dtype), jnp.asarray(tables),
+            seq_lens)
 
-    out = np.asarray(paged_decode_attention(
-        jnp.asarray(q), _stored_form(k_pool, rng), _stored_form(v_pool, rng),
-        jnp.asarray(tables), jnp.asarray(seq_lens), layer=1, block_size=bs,
-    ))
-
+    if kernel:
+        want, got = (np.asarray(paged_window_decode_attention(
+            *args, layer=1, block_size=bs, impl=impl), np.float32)
+            for impl in ("xla", "pallas"))
+        # bf16: one rounding of the output (2**-8 relative; the values
+        # are of order 1) and the running softmax's other order of sums
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=2e-5 if kernel == "float32" else 2e-2)
+        return
+    out = np.asarray(paged_decode_attention(*args, layer=1, block_size=bs))
     g = h // h_kv
     for i in range(b):
-        n = seq_lens[i]
+        n = lens[i]
         for head in range(h):
             kh = k_seq[i, :n, head // g]       # (n, d)
             vh = v_seq[i, :n, head // g]
@@ -355,6 +390,37 @@ def test_engine_matches_dense_generate_bf16():
     req = eng.submit([int(t) for t in np.asarray(ids)[0]], max_new_tokens=5)
     _drain(eng, [req])
     assert req.tokens == list(dense[0, 8:])
+
+
+def test_engine_kernel_decode_matches_plain_decode_at_heads_of_64():
+    """The decode program of a GPT with heads of 64 serves the same greedy
+    tokens through the kernel that reads only the blocks a slot holds
+    (interpreted) as through the plain formulation, two requests side by
+    side whose contexts cross a stretch of 128 rows and a grid step of 512;
+    the engine says which formulation its decode program was built with."""
+    cfg = dataclasses.replace(gpt_tiny(), dtype=jnp.float32, num_heads=2,
+                              max_seq=640)
+    rng = jax.random.PRNGKey(0)
+    ids = np.asarray(jax.random.randint(rng, (2, 504), 0, cfg.vocab_size))
+    params = GPTLM(cfg).init(rng, jnp.asarray(ids[:, :8]))["params"]
+    served = {}
+    for impl in ("xla", "pallas"):
+        eng = _engine(dataclasses.replace(cfg, attn_impl=impl), params,
+                      block_size=16, prefill_chunk=64, max_context=640)
+        reqs = [eng.submit([int(t) for t in ids[0, :120]], max_new_tokens=16),
+                eng.submit([int(t) for t in ids[1]], max_new_tokens=16)]
+        _drain(eng, reqs)
+        assert [r.status for r in reqs] == ["ok", "ok"]
+        served[impl] = ([r.tokens for r in reqs],
+                        eng.state()["decode_attention"])
+    assert served["pallas"][0] == served["xla"][0]
+    assert served["pallas"][1] == "paged_attn"
+    assert served["xla"][1] == "plain"
+    # a block size that does not divide the kernel's 128 rows: the silent
+    # fallback is named
+    assert _engine(dataclasses.replace(cfg, attn_impl="pallas"), params,
+                   block_size=24, prefill_chunk=24, max_context=624
+                   ).state()["decode_attention"] == "plain"
 
 
 def test_continuous_batching_freed_slot_admission(served_model):
