@@ -24,11 +24,11 @@ weights from a seed), and checks what comes out:
                   ``requests.jsonl`` and the final ``metrics.jsonl`` row;
 4. ``pool_form``  ``python -m distributedtensorflow_tpu.serve.pool_check`` at
                   the shapes of the benchmark's serving cells (GPT-2
-                  medium, 32 slots, 2048 K/V blocks of 16 tokens): the six
+                  medium, 32 slots, 2048 K/V blocks of 16 tokens): the five
                   programs that take the paged K/V pool compile, and none
                   holds — outside scope ``paged_attn`` — a ``copy``,
                   ``transpose`` or ``convert`` of a layer of the pool or
-                  more; the five that return the pool alias it in place;
+                  more; all five return the pool and alias it in place;
                   the pool's resident layout is printed;
 5. ``resnet50``   ``train.py --workload imagenet_resnet50 --batch-size 128``
                   (the BASELINE metric's model: conv path + image input);
@@ -402,7 +402,7 @@ POOL_CELL = ["--config", "gpt_medium", "--max-slots", "32", "--kv-blocks",
 
 def pool_form_leg(out: str) -> dict:
     """The mechanism's counter (PERF.md, PR 25): pool-sized layout or
-    dtype changes in the six pool programs, compiled for this chip.  It is
+    dtype changes in the five pool programs, compiled for this chip.  It is
     0 or it is not; the child exits non-zero and names them if it is not."""
     log, wall = run_child(
         "pool_form",
@@ -416,17 +416,17 @@ def pool_form_leg(out: str) -> dict:
     require_tpu("pool_form", r["device"])
     check(sorted(r["programs"]) == sorted(
         ["prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
-         "gather_cache", "copy_block"]),
+         "copy_block"]),
         f"pool_form: checked only {sorted(r['programs'])}")
     compiles = ", ".join(f"{name} {p['compile_s']:.1f}s"
                          for name, p in r["programs"].items())
     print(f"chip_smoke: pool_form ok in {wall:.0f}s: pool "
           f"{r['pool_dtype']}{r['pool_shape']} = {r['pool_bytes'] / 1e9:.3f} "
           f"GB each, resident layout {r['resident_layout']}, taken as "
-          f"{r['programs']['decode']['k_pool']} by all six programs; no "
+          f"{r['programs']['decode']['k_pool']} by all five programs; no "
           f"pool-sized copy, transpose or convert outside paged_attn; "
-          f"k_pool and v_pool aliased in place by the five that return "
-          f"them (compile: {compiles})", flush=True)
+          f"k_pool and v_pool aliased in place by all five "
+          f"(compile: {compiles})", flush=True)
     return r
 
 

@@ -77,7 +77,6 @@ class AfmoeConfig:
     dtype: jnp.dtype = jnp.bfloat16
     #: "auto" = the Pallas kernels on a TPU, the plain formulations elsewhere
     kernel_impl: str = "auto"
-    family: str = "afmoe"
 
     @property
     def num_layers(self) -> int:

@@ -60,10 +60,10 @@ def prefill(params, tokens, positions, *, cfg: GPTConfig, cache=None):
     cache)`` with the chunk's K/V appended.  ``cache=None`` creates the
     cache collection (flax mutable-apply priming); pass the returned cache
     back to continue — chunked prefill is a loop of fixed-width calls, so
-    one compiled program covers any prompt length.  This is the serving
-    engine's prefill building block (``serve.engine``) as well as
-    :func:`generate`'s priming step.  Pure function: traceable under jit
-    and scan, caller owns the cache pytree.
+    one compiled program covers any prompt length.  This is
+    :func:`generate`'s priming step, and the plain reference the serving
+    engine's tests hold its paged programs to.  Pure function: traceable
+    under jit and scan, caller owns the cache pytree.
     """
     model = GPTLM(cfg, decode=True)
     variables = {"params": params}

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
+from ..ops.layernorm import layer_norm
+from ..ops.xent import tied_head_logits
 from ..parallel.sharding import LayoutMap
 from ..runtime import on_tpu
 from .layers import FusedLayerNorm, dense, sow_nonfinite
@@ -108,6 +110,19 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_heads
+
+    @property
+    def kernel_impl(self) -> str:
+        """``attn_impl`` under the name the serving programs ask a
+        configuration of any family for."""
+        return self.attn_impl
+
+    def window_of(self, layer: int) -> int | None:
+        return self.attn_window
 
 
 def gpt_small() -> GPTConfig:
@@ -436,10 +451,83 @@ class GPTLM(nn.Module):
         # vocab-sharded matrix; standard for decoder LMs).  Shared dtype
         # recipe (ops/xent.tied_head_logits): bf16 operands at MXU rate,
         # fp32 accumulation — identical to the chunked loss head.
-        from ..ops.xent import tied_head_logits
-
         wte = self.variables["params"]["wte"]["embedding"]
         return tied_head_logits(x, wte, cfg.dtype)
+
+
+# -- the serving definition ---------------------------------------------------
+#
+# The layer functions ``serve.model`` builds its programs from, with the
+# signatures ``models.afmoe`` has, over the parameter tree ``GPTLM.init``
+# makes (so a checkpoint of the trainer is served as it is).  ``GPTBlock``
+# above is the training definition of the same block; tests/test_serve.py
+# pins the two together.  Stored float32 weights are cast to the compute type
+# at each use, under the scope ``cast_params``.
+
+def init_params(cfg: GPTConfig, key):
+    """Random parameters: ``GPTLM``'s own initialisers."""
+    return GPTLM(cfg).init(key, jnp.zeros((1, 1), jnp.int32),
+                           deterministic=True)["params"]
+
+
+def _cast(param, dtype):
+    with jax.named_scope("cast_params"):
+        return param.astype(dtype)
+
+
+def _norm(x, p, out_dtype=None):
+    return layer_norm(x, p["scale"], p["bias"], eps=1e-6,
+                      out_dtype=out_dtype or x.dtype)
+
+
+def embed(params, ids, cfg: GPTConfig):
+    with jax.named_scope("embed"):
+        return _cast(params["wte"]["embedding"], cfg.dtype)[ids]
+
+
+def block(p, x, cfg: GPTConfig, layer: int, positions, attend,
+          token_mask=None):
+    """One decoder layer on ``x`` (T, d), ``positions`` (T,).  ``attend(q, k,
+    v)`` returns the attention output (T, H, D) — it owns where K/V live.
+    Returns ``(x, None)``: no expert layer, no counters.
+
+    Inside, the tokens are a batch of T sequences of one position, ``(T, 1,
+    d)``, the training block's (batch, sequence, width): around that shape
+    XLA fuses the small operations of a decode step into fewer (GPT-2
+    medium's ``jit_decode`` at 4 slots: 3.24 ms against 3.42 with ``(T, d)``
+    throughout; my chip run, PR 30)."""
+    t, dt, d = x.shape[0], cfg.dtype, cfg.head_dim
+    q_width, kv_width = cfg.num_heads * d, cfg.kv_heads * d
+    x, positions = x[:, None, :], positions[:, None]
+    with jax.named_scope("ln"):
+        h = _norm(x, p["ln1"])
+    with jax.named_scope("qkv"):
+        qkv = h @ _cast(p["attn"]["qkv"]["kernel"], dt)
+        q = qkv[..., :q_width].reshape(t, 1, cfg.num_heads, d)
+        k = qkv[..., q_width:q_width + kv_width].reshape(
+            t, 1, cfg.kv_heads, d)
+        v = qkv[..., q_width + kv_width:].reshape(t, cfg.kv_heads, d)
+        # the same tables in every layer: XLA keeps one copy
+        tabs = rope_tables(positions, d, cfg.rope_theta, dt)
+        q = rope(q, positions, cfg.rope_theta, tabs)[:, 0]
+        k = rope(k, positions, cfg.rope_theta, tabs)[:, 0]
+    a = attend(q, k, v).reshape(t, 1, q_width).astype(dt)
+    with jax.named_scope("proj"):
+        x = x + a @ _cast(p["attn"]["proj"]["kernel"], dt)
+    with jax.named_scope("ln"):
+        h = _norm(x, p["ln2"])
+    with jax.named_scope("mlp"):
+        m = jax.nn.gelu(h @ _cast(p["fc_in"]["kernel"], dt))
+        x = x + m @ _cast(p["fc_out"]["kernel"], dt)
+    return x[:, 0], None
+
+
+def head(params, x, cfg: GPTConfig):
+    """float32 logits of ``x`` (T, d): the tied head on the float32 final
+    norm."""
+    with jax.named_scope("head"):
+        return tied_head_logits(_norm(x, params["ln_f"], jnp.float32),
+                                params["wte"]["embedding"], cfg.dtype)
 
 
 def nan_taps(model: GPTLM):

@@ -2,9 +2,9 @@
 
 The online half of the stack: ``kv_cache`` (paged block-pool KV with a
 refcounted copy-on-write allocator + prefix index), ``model`` (the
-compiled serving programs — chunked prefill, paged one-token decode, the
-pool→dense cache gather that makes prefill chunks interleavable, and the
-fused decode/verify fast path), ``sampling`` (the one logits→probs
+compiled serving programs of a family of layer functions — chunked prefill
+that reads a slot's earlier chunks from the pool, paged one-token decode,
+and the fused decode/verify fast path), ``sampling`` (the one logits→probs
 reference + the fused/rejection sampler), ``draft`` (model-free n-gram
 draft proposals for self-speculative decoding), ``engine`` (thread-safe
 queue + continuous batching scheduler with decode-integrated budgeted
@@ -18,8 +18,7 @@ from .kv_cache import BlockAllocator, OutOfBlocksError, PagedKVCache  # noqa: F4
 from .model import (  # noqa: F401
     make_decode_fn,
     make_fused_decode_fn,
-    make_gather_cache_fn,
-    make_prefill_cache,
     make_prefill_fn,
+    make_programs,
 )
 from .server import ServeServer  # noqa: F401

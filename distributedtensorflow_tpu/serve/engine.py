@@ -12,8 +12,9 @@ online replacement:
   prefill**: every scheduler iteration first admits queued requests into
   free slots, then runs at most ``prefill_budget`` TOKENS of prefill
   chunks — budget-bounded bursts rotating round-robin across the
-  admitted-but-unfilled requests (consecutive chunks per burst keep the
-  dense-cache fast path; rotation keeps prefill fair across fillers) —
+  admitted-but-unfilled requests (a first token waits for its prompt's
+  last chunk, so a burst goes to one filler; rotation keeps prefill fair
+  across fillers) —
   and then ONE paged decode step for all decoding slots, then evicts
   finished sequences (EOS / max_new_tokens).  Decode never starves: a
   newly arrived long prompt can delay the running requests' next token
@@ -300,7 +301,7 @@ class Engine:
         if speculate and spec_ngram < 1:
             raise ValueError(f"spec_ngram={spec_ngram} must be >= 1")
         #: params stay the caller's (possibly mesh-sharded) arrays — GSPMD
-        #: partitions both programs exactly as it does models.generate.
+        #: partitions the programs exactly as it does models.generate.
         self.params = params
         self.cfg = dataclasses.replace(cfg, max_seq=max_context)
         self.max_slots = max_slots
@@ -929,7 +930,6 @@ class Engine:
                     self._dev_keys = self._dev_keys.at[slot].set(
                         jax.random.PRNGKey(head.seed)
                     )
-                self.programs.forget(slot)     # a new tenant
                 self._filling.append(head)
                 reused = self._slot_reused[slot]
                 self._slot_reused[slot] = True
@@ -957,8 +957,9 @@ class Engine:
         """At most ``prefill_budget`` tokens of prefill chunks this
         iteration, round-robin in budget-bounded BURSTS across the
         admitted-but-unfilled set: the head request runs consecutive
-        chunks (hitting the dense-cache fast path — chunk-granularity
-        interleaving would pay a full pool→cache gather per chunk) until
+        chunks (its first token waits for its last chunk, so a budget
+        spent on one filler brings a first token sooner than the same
+        budget spread chunk by chunk over several) until
         it finishes or the budget runs out, then rotates to the back so
         the next iteration's budget goes to the next filler.  A long
         prompt can therefore neither starve decode (the per-iteration
@@ -1014,7 +1015,7 @@ class Engine:
                 req._fill_buf[start:start + c], start,
                 {name: jnp.asarray(g.block_tables[slot].copy())
                  for name, g in self.kv.groups.items()},
-                last_ix, slot,
+                last_ix,
             )
             self.kv.set_pools(pools)
             req._fill_next = start + c
@@ -1314,7 +1315,6 @@ class Engine:
             self.kv.release(req.slot)
             self._slots[req.slot] = None
             self._slot_meta_dirty = True
-            self.programs.forget(req.slot)
         if req in self._filling:  # error paths only; finished fills popped
             self._filling.remove(req)
         req.status = status

@@ -914,26 +914,29 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                        write_ahead: int) -> GroupedKVCache:
     """The groups of a model: a ``"full"`` and a ``"window"`` group,
     whichever exist, by the attention kind its config names for a layer
-    (``cfg.window_of(layer)``; a config without it, as GPT-2's, is one full
-    group).  ``num_blocks[name] = None`` provisions every slot's worst
+    (``cfg.window_of(layer)``; GPT-2 is one full group).
+    ``num_blocks[name] = None`` provisions every slot's worst
     case (full provisioning; fewer oversubscribes — paged memory is the
     point — and admission control, not OOM, then absorbs the pressure)."""
+    if all(cfg.window_of(i) is not None for i in range(cfg.num_layers)):
+        raise ValueError(
+            "serving a model of window layers only is not implemented yet "
+            "(a lone group is the one prefixes are shared and blocks copied "
+            "on write through, and a ring is neither): serve it with full "
+            "attention, attn_window=None")
     per_slot = max_context // block_size
-    window_of = getattr(cfg, "window_of", lambda layer: None)
-    head_dim = getattr(cfg, "head_dim", None) \
-        or cfg.hidden_size // cfg.num_heads
     groups, layers = {}, {}
     for name, is_window in (("full", False), ("window", True)):
         ls = tuple(i for i in range(cfg.num_layers)
-                   if (window_of(i) is not None) == is_window)
+                   if (cfg.window_of(i) is not None) == is_window)
         if not ls:
             continue
         kw = dict(num_layers=len(ls), kv_heads=cfg.kv_heads,
-                  head_dim=head_dim, max_slots=max_slots,
+                  head_dim=cfg.head_dim, max_slots=max_slots,
                   block_size=block_size, max_context=max_context,
                   dtype=cfg.dtype)
         if is_window:
-            window = window_of(ls[0])
+            window = cfg.window_of(ls[0])
             ring = -(-(window + write_ahead) // block_size) + 1
             n = num_blocks.get(name) or max_slots * min(per_slot, ring)
             groups[name] = WindowKVGroup(

@@ -1,15 +1,15 @@
 """Is the paged K/V pool kept in place by every program that takes it?
 
 The pool is the largest thing a serving process holds on the device, and
-six compiled programs take it: ``prefill_chunk``, ``decode``,
-``fused_decode`` at T = 1 and at T > 1, ``gather_cache`` and
-``copy_block``.  If the form the pool is stored in is not the form a
+five compiled programs take it, all of which return it: ``prefill_chunk``,
+``decode``, ``fused_decode`` at T = 1 and at T > 1 (``serve.model``, built
+as the engine builds them: ``make_programs``) and ``copy_block``.  If the form the pool is stored in is not the form a
 program computes in, XLA converts all of it on the way in and back on the
 way out, on every call — nothing fails, a decode step is just a third
 slower and a prefill chunk forty times (PERF.md §5, PR 25).  This module
 reads that off the compiled programs:
 
-- :func:`pool_programs` builds the six programs with abstract arguments
+- :func:`pool_programs` builds the five programs with abstract arguments
   (shapes only, so nothing is allocated and a *described* device will do);
 - :func:`pool_relayouts` lists the ``copy`` / ``transpose`` / ``convert``
   operations of an optimised HLO module whose result is a whole number of
@@ -35,19 +35,13 @@ import time
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPTConfig
 from . import kv_cache
-from .model import (
-    make_decode_fn,
-    make_fused_decode_fn,
-    make_gather_cache_fn,
-    make_prefill_cache,
-    make_prefill_fn,
-)
+from .model import family_of, make_programs
 
-#: The programs whose output holds the pool (``gather_cache`` only reads it).
-RETURN_POOL = ("prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
-               "copy_block")
+#: A pool among a compiled module's arguments, as the family programs take
+#: it: ``pools['full'][0]`` is the group's ``k_pool``, ``[1]`` its ``v_pool``
+#: (``copy_block`` takes them under those names).
+_POOLS_ARG = re.compile(r"^pools\[.*\]\[([01])\]$")
 
 _RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
 _INSTRUCTION = re.compile(
@@ -55,58 +49,45 @@ _INSTRUCTION = re.compile(
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def pool_programs(cfg: GPTConfig, *, max_slots: int, num_blocks: int,
-                  block_size: int, chunk: int, draft: int, sharding=None):
-    """``{name: (jitted program, abstract arguments)}`` for the six
+def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
+                  chunk: int, draft: int, sharding=None):
+    """``{name: (jitted program, abstract arguments)}`` for the five
     programs that take the pool, at the shapes an ``Engine`` with these
-    settings gives them (``cfg.max_seq`` is the serving context)."""
-    from ..models import GPTLM
-
+    settings gives them (``cfg.max_seq`` is the serving context; a model of
+    one full group, as GPT-2)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    def abstract(tree):
-        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-
     i32 = jnp.int32
-    params = abstract(jax.eval_shape(
-        lambda: GPTLM(cfg).init(jax.random.PRNGKey(0),
-                                jnp.zeros((1, 1), i32),
-                                deterministic=True)["params"]))
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: family_of(cfg).init_params(
+            cfg, jax.random.PRNGKey(0))))
     pool = sds(kv_cache.pool_shape(cfg.num_layers, num_blocks, block_size,
-                                   cfg.kv_heads,
-                                   cfg.hidden_size // cfg.num_heads),
-               cfg.dtype)
-    cache = abstract(jax.eval_shape(lambda: make_prefill_cache(cfg)))
-    table_row = sds((cfg.max_seq // block_size,), i32)
-    tables = sds((max_slots, cfg.max_seq // block_size), i32)
+                                   cfg.kv_heads, cfg.head_dim), cfg.dtype)
+    pools = {"full": (pool, pool)}
+    table_rows = {"full": sds((cfg.max_seq // block_size,), i32)}
+    tables = {"full": sds((max_slots, cfg.max_seq // block_size), i32)}
     slots_i32 = sds((max_slots,), i32)
     active = sds((max_slots,), jnp.bool_)
     scalar = sds((), i32)
+    programs = make_programs(cfg, chunk=chunk, block_size=block_size,
+                             layers={"full": tuple(range(cfg.num_layers))})
 
     def fused_args(t_width):
-        return (params, pool, pool, sds((max_slots, t_width), i32),
-                slots_i32, tables, slots_i32, active,
-                sds((max_slots, 2), jnp.uint32), slots_i32,
-                sds((max_slots,), jnp.float32), slots_i32)
+        return (params, pools, sds((max_slots, t_width), i32), slots_i32,
+                tables, slots_i32, active, sds((max_slots, 2), jnp.uint32),
+                slots_i32, sds((max_slots,), jnp.float32), slots_i32)
 
     return {
         "prefill_chunk": (
-            make_prefill_fn(cfg, chunk=chunk, block_size=block_size),
-            (params, pool, pool, cache, sds((1, chunk), i32), scalar,
-             table_row, scalar)),
+            programs.prefill_chunk,
+            (params, pools, sds((chunk,), i32), scalar, table_rows, scalar)),
         "decode": (
-            make_decode_fn(cfg, block_size=block_size),
-            (params, pool, pool, slots_i32, tables, slots_i32, active)),
-        "fused_decode": (
-            make_fused_decode_fn(cfg, block_size=block_size, draft=0),
-            fused_args(1)),
-        "fused_decode_spec": (
-            make_fused_decode_fn(cfg, block_size=block_size, draft=draft),
-            fused_args(draft + 1)),
-        "gather_cache": (
-            make_gather_cache_fn(cfg, block_size=block_size),
-            (pool, pool, cache, table_row, scalar)),
+            programs.decode,
+            (params, pools, slots_i32, tables, slots_i32, active)),
+        "fused_decode": (programs.fused(0), fused_args(1)),
+        "fused_decode_spec": (programs.fused(draft), fused_args(draft + 1)),
         "copy_block": (
             kv_cache._copy_block_fn(block_size),
             (pool, pool, scalar, scalar)),
@@ -141,12 +122,17 @@ def pool_relayouts(hlo_text: str, layer_elems: int) -> list[str]:
 
 def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
     """Parameter number -> (argument name, shape with layout) of the entry
-    computation of an HLO module's text."""
+    computation of an HLO module's text; a pool is named ``k_pool`` or
+    ``v_pool`` however the program takes it."""
     entry = hlo_text[hlo_text.index("\nENTRY "):]
     params = {}
     for m in re.finditer(
             r"= (\S+) parameter\((\d+)\)[^\n]*?op_name=\"([^\"]*)\"", entry):
-        params[int(m.group(2))] = (m.group(3), m.group(1))
+        name = m.group(3)
+        pool = _POOLS_ARG.match(name)
+        if pool:
+            name = ("k_pool", "v_pool")[int(pool.group(1))]
+        params[int(m.group(2))] = (name, m.group(1))
     return params
 
 
@@ -187,7 +173,7 @@ def failures(report: dict) -> list[str]:
     for name, r in report.items():
         for op in r["relayouts"]:
             bad.append(f"{name}: pool-sized {op}")
-        if name in RETURN_POOL and r["donated"] != ["k_pool", "v_pool"]:
+        if r["donated"] != ["k_pool", "v_pool"]:
             bad.append(f"{name}: donated in place only {r['donated']}")
     forms = {r["k_pool"] for r in report.values()}
     if len(forms) != 1:
@@ -213,8 +199,7 @@ def main(argv=None) -> int:
     cfg = dataclasses.replace(getattr(models, args.config)(),
                               max_seq=args.max_context)
     shape = kv_cache.pool_shape(cfg.num_layers, args.kv_blocks,
-                                args.block_size, cfg.kv_heads,
-                                cfg.hidden_size // cfg.num_heads)
+                                args.block_size, cfg.kv_heads, cfg.head_dim)
     report = check_pool_programs(
         pool_programs(cfg, max_slots=args.max_slots,
                       num_blocks=args.kv_blocks, block_size=args.block_size,

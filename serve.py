@@ -57,26 +57,15 @@ def build_params(args, cfg):
     seeded random init (load tests, CI)."""
     import jax
 
-    if getattr(cfg, "family", "gpt") == "afmoe":
-        from distributedtensorflow_tpu.models import afmoe
-
-        if args.checkpoint:
-            raise SystemExit(
-                f"--checkpoint: --config {args.config} has no trainer to "
-                "have written one; it serves a seeded random init")
-        logging.info("random-init params in %s (no --checkpoint)",
-                     cfg.dtype.__name__)
-        return afmoe.init_params(cfg, jax.random.PRNGKey(args.seed))
     if not args.checkpoint:
-        import numpy as np
-
-        from distributedtensorflow_tpu.models import GPTLM
+        from distributedtensorflow_tpu.serve.model import family_of
 
         logging.info("random-init params (no --checkpoint)")
-        return GPTLM(cfg).init(
-            jax.random.PRNGKey(args.seed), np.zeros((1, 1), np.int32),
-            deterministic=True,
-        )["params"]
+        return family_of(cfg).init_params(cfg, jax.random.PRNGKey(args.seed))
+    if CONFIGS[args.config][1] is None:
+        raise SystemExit(
+            f"--checkpoint: --config {args.config} has no trainer to "
+            "have written one; it serves a seeded random init")
     from distributedtensorflow_tpu.checkpoint import CheckpointManager
     from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
     from distributedtensorflow_tpu.train.state import create_sharded_state

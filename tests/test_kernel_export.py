@@ -222,7 +222,7 @@ def _gpt2m_pool_programs(one_chip, **changes):
 
 @pytest.mark.parametrize("program", [
     "prefill_chunk", "decode", "fused_decode", "fused_decode_spec",
-    "gather_cache", "copy_block"])
+    "copy_block"])
 def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program,
                                                           monkeypatch):
     """GPT-2 medium's widths and the benchmark cells' pool (2048 blocks of

@@ -206,61 +206,128 @@ _POOL_IDS = [f"bs{bs}-{jnp.dtype(dt).name}-kv{kv}" for bs, dt, kv in POOL_FORMS]
 @pytest.mark.parametrize("block_size,dtype,kv_heads", POOL_FORMS,
                          ids=_POOL_IDS)
 def test_pool_prefill_scatter_gather_round_trip(block_size, dtype, kv_heads):
-    """What ``prefill_chunk`` scatters into the pool is what
-    ``gather_cache`` reads back: the dense prefill cache's K/V, byte for
-    byte, through a shuffled page table; no row outside the slot's blocks
-    is written."""
+    """What ``prefill_chunk`` writes into the pool, through a shuffled page
+    table, is the K/V of the plain forward for those positions; no row
+    outside the slot's blocks is written; and a chunk reads its slot's
+    earlier chunks from the pool alone: the chunk at 16 gives the same
+    last-row logits whether or not another slot's chunk ran in between."""
+    from distributedtensorflow_tpu.models.generate import prefill
     from distributedtensorflow_tpu.serve.kv_cache import pool_shape
-    from distributedtensorflow_tpu.serve.model import (
-        make_gather_cache_fn,
-        make_prefill_cache,
-        make_prefill_fn,
-    )
+    from distributedtensorflow_tpu.serve.model import make_programs
 
     cfg = dataclasses.replace(gpt_tiny(), dtype=dtype, max_seq=32,
                               num_kv_heads=kv_heads)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, 24), 0,
                              cfg.vocab_size)
     params = GPTLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
-    head_dim = cfg.hidden_size // cfg.num_heads
     num_blocks, chunk = 8, 8
     shape = pool_shape(cfg.num_layers, num_blocks, block_size, cfg.kv_heads,
-                       head_dim)
+                       cfg.head_dim)
     assert shape == (cfg.num_layers, (num_blocks + 1) * block_size,
-                     cfg.kv_heads * head_dim)
-    k_pool, v_pool = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    # the slot's pages, out of order; the rest point at scratch
+                     cfg.kv_heads * cfg.head_dim)
+
+    def table_row(blocks):
+        # a slot's pages, out of order; the rest point at scratch
+        row = np.full((32 // block_size,), num_blocks, np.int32)
+        row[: len(blocks)] = blocks
+        return {"full": jnp.asarray(row)}
+
     blocks = [5, 2, 7, 0, 3, 6][: -(-24 // block_size)]
-    table_row = np.full((32 // block_size,), num_blocks, np.int32)
-    table_row[: len(blocks)] = blocks
-    table_row = jnp.asarray(table_row)
+    mine, other = table_row(blocks), table_row([1, 4][: -(-chunk // block_size)])
+    prefill_chunk = make_programs(
+        cfg, chunk=chunk, block_size=block_size,
+        layers={"full": tuple(range(cfg.num_layers))}).prefill_chunk
 
-    prefill_chunk = make_prefill_fn(cfg, chunk=chunk, block_size=block_size)
-    cache = make_prefill_cache(cfg)
-    for start in range(0, 24, chunk):
-        _, cache, k_pool, v_pool = prefill_chunk(
-            params, k_pool, v_pool, cache, ids[:, start:start + chunk],
-            jnp.int32(start), table_row, jnp.int32(chunk - 1))
-    dense = jax.tree.map(np.asarray, cache)
+    def run(pools, tokens, start, row):
+        # the pools are donated: hand the program its own copy
+        pools = jax.tree.map(jnp.array, pools)
+        return prefill_chunk(params, pools, tokens, jnp.int32(start), row,
+                             jnp.int32(chunk - 1))
 
-    k_rows = np.asarray(k_pool, np.float32)
-    written = np.zeros(shape[1], bool)
-    for j, b in enumerate(blocks):
-        n = min(block_size, 24 - j * block_size)
-        written[b * block_size: b * block_size + n] = True
-    assert np.all(k_rows[:, ~written] == 0)
-    assert np.all(np.any(k_rows[:, written] != 0, axis=-1))
+    pools = {"full": (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))}
+    for start in (0, 8):
+        _, pools = run(pools, ids[0, start:start + chunk], start, mine)
+    logits, filled = run(pools, ids[0, 16:], 16, mine)
+    _, between = run(pools, ids[0, 3:3 + chunk], 0, other)
+    again, _ = run(between, ids[0, 16:], 16, mine)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(logits))
 
-    gathered = make_gather_cache_fn(cfg, block_size=block_size)(
-        k_pool, v_pool, make_prefill_cache(cfg), table_row, jnp.int32(24))
+    _, dense = prefill(params, ids, jnp.arange(24)[None], cfg=cfg)
+    rows = np.concatenate([b * block_size + np.arange(block_size)
+                           for b in blocks])[:24]
+    tol = 1e-5 if dtype == jnp.float32 else 0.05
+    for pool, name in zip(filled["full"], ("cached_key", "cached_value")):
+        pool = np.asarray(pool, np.float32)
+        written = np.zeros(shape[1], bool)
+        written[rows] = True
+        assert np.all(pool[:, ~written] == 0)
+        assert np.all(np.any(pool[:, written] != 0, axis=-1))
+        for i in range(cfg.num_layers):
+            # the flax decode cache is (1, Hkv, max_seq, D)
+            want = np.asarray(dense[f"h{i}"]["attn"][name], np.float32)[
+                0, :, :24].transpose(1, 0, 2).reshape(24, -1)
+            np.testing.assert_allclose(pool[i, rows], want, atol=tol, rtol=0)
+
+
+def _dense_forward(family, params, ids, cfg):
+    """Logits (S, V) of one sequence through a family's layer functions
+    under plain causal attention, no cache."""
+    from distributedtensorflow_tpu.ops.attention import xla_attention
+
+    positions = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    x = family.embed(params, ids, cfg)
     for i in range(cfg.num_layers):
-        got, want = gathered[f"h{i}"]["attn"], dense[f"h{i}"]["attn"]
-        assert int(got["cache_index"]) == 24
-        for name in ("cached_key", "cached_value"):
-            assert got[name].shape == want[name].shape
-            assert got[name].dtype == want[name].dtype
-            np.testing.assert_array_equal(
-                np.asarray(got[name])[:, :, :24], want[name][:, :, :24])
+        def attend(q, k, v, window=cfg.window_of(i)):
+            return xla_attention(q[None], k[None], v[None], causal=True,
+                                 window=window)[0]
+        x, _ = family.block(params[f"h{i}"], x, cfg, i, positions, attend)
+    return family.head(params, x, cfg)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_serving_block_matches_the_training_block(dtype, kv_heads):
+    """The two definitions of the GPT block: the layer functions the serving
+    programs are built from (``models.gpt.embed`` / ``block`` / ``head``)
+    give the logits of flax ``GPTLM`` on the same parameters."""
+    from distributedtensorflow_tpu.models import gpt
+
+    cfg = dataclasses.replace(gpt_tiny(), dtype=dtype, max_seq=64,
+                              num_kv_heads=kv_heads)
+    ids = jax.random.randint(jax.random.PRNGKey(2), (1, 48), 0,
+                             cfg.vocab_size)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    # default init gives logits of ~0.02: scale the matrices up so that a
+    # wrong block would show
+    params = jax.tree.map(lambda p: p * 4 if p.ndim == 2 else p, params)
+    want = np.asarray(GPTLM(cfg).apply({"params": params}, ids)[0])
+    got = np.asarray(_dense_forward(gpt, params, ids[0], cfg))
+    assert got.dtype == want.dtype == np.float32
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+    else:
+        assert np.isfinite(got).all()
+        assert (got.argmax(-1) == want.argmax(-1)).mean() >= 0.75
+        assert np.median(np.abs(got - want)) < 0.1
+
+
+def test_one_set_of_programs_for_every_family():
+    """``make_programs`` gives a GPT-2 and an afmoe configuration the same
+    class over their family modules, and neither keeps state a slot's next
+    tenant would have to be told about."""
+    from distributedtensorflow_tpu.models import afmoe, afmoe_tiny, gpt
+    from distributedtensorflow_tpu.serve.model import make_programs
+
+    built = {
+        family: make_programs(cfg, chunk=8, block_size=4, layers=layers)
+        for family, cfg, layers in (
+            (gpt, gpt_tiny(), {"full": (0, 1)}),
+            (afmoe, afmoe_tiny(), {"window": (0, 1), "full": (2,)}))}
+    assert type(built[gpt]) is type(built[afmoe])
+    for family, programs in built.items():
+        assert programs.family is family
+        assert not hasattr(programs, "forget")
 
 
 @pytest.mark.parametrize("block_size,dtype,kv_heads", POOL_FORMS,
@@ -300,7 +367,6 @@ def test_pool_is_donated_in_place(program):
     fn, args = pool_check.pool_programs(
         cfg, max_slots=2, num_blocks=6, block_size=16, chunk=8, draft=2,
     )[program]
-    assert program in pool_check.RETURN_POOL
     text = fn.lower(*args).compile().as_text()
     assert pool_check.donated_pools(text) == {"k_pool", "v_pool"}
 
@@ -375,6 +441,12 @@ def test_engine_matches_dense_generate(served_model):
     for i, r in enumerate(reqs):
         assert r.status == "ok"
         assert r.tokens == list(dense[i, 8:])
+
+
+def test_engine_refuses_a_model_of_window_layers_only(served_model):
+    cfg, params, _ = served_model
+    with pytest.raises(ValueError, match="window layers only"):
+        _engine(dataclasses.replace(cfg, attn_window=16), params)
 
 
 def test_engine_matches_dense_generate_bf16():
