@@ -38,8 +38,11 @@ the socket stack's).  Three sweeps, selectable via ``BENCH_SERVE_MODE``
   TPOT p50/p99, draft acceptance rate, mean tokens per decode step PER
   SLOT (1.0 without speculation, up to K+1 on accepted bursts), and
   dispatches per decode step (decode program executions + host
-  sampling rounds: the per-token round-trip count each running request
-  experiences — host sampling = 2, fused = 1).
+  sampling rounds, the iterations that fetched the logits for a request
+  with temperature > 0: the per-token round-trip count each running
+  request experiences — 2 where the host samples, 1 where the token comes
+  off the device with the step: fused, or the one-token program's arg-max
+  in greedy traffic).
 
 Evidence discipline (same contract as bench_generate.py): headline
 operating points are the MEDIAN OF 3 independent trials with relative
@@ -380,8 +383,9 @@ def _spec_sweep(make_engine, *, n: int, new: int, prompt_len: int,
                     if dc["slot_steps"] else 0.0,
                     # per decode step every running slot commits >= 1
                     # token, so this is the per-token round-trip count a
-                    # request experiences: host sampling = 2 (program +
-                    # logits pull/sample/feed-back), fused = 1
+                    # request experiences: 2 where the host samples
+                    # (program + logits pull/sample/feed-back), 1 where
+                    # the token comes with the step (fused; greedy)
                     "dispatches_per_step": round(dispatches / steps, 3)
                     if steps else 0.0,
                 })

@@ -191,7 +191,9 @@ class Histogram(_Metric):
         # per label key: [bucket_counts..., +inf count], sum, count
         self._hist: dict[tuple, tuple[list[int], float, int]] = {}
 
-    def observe(self, value: float, **labels) -> None:
+    def observe(self, value: float, *, count: int = 1, **labels) -> None:
+        """``count`` observations of ``value`` (a batch that saw the same
+        value ``count`` times takes the lock once)."""
         value = float(value)
         key = _label_key(labels)
         with self._lock:
@@ -200,8 +202,8 @@ class Histogram(_Metric):
                 counts, total, n = self._hist.get(
                     key, ([0] * (len(self.buckets) + 1), 0.0, 0)
                 )
-                counts[bisect.bisect_left(self.buckets, value)] += 1
-                self._hist[key] = (counts, total + value, n + 1)
+                counts[bisect.bisect_left(self.buckets, value)] += count
+                self._hist[key] = (counts, total + value * count, n + count)
         if not ok:
             self._note_drop()
 

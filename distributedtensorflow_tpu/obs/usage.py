@@ -310,18 +310,18 @@ class UsageMeter:
             self._m_slot_s.inc(s, tenant=tenant)
             self._m_block_s.inc(b, tenant=tenant)
 
-    def on_tokens(self, req, n: int) -> None:
-        """Count ``n`` freshly committed (generated) tokens."""
-        if n <= 0:
-            return
-        flops = n * self.token_flops
+    def on_tokens(self, by_tenant) -> None:
+        """Count freshly committed (generated) tokens: ``{tenant: n}``, a
+        decode iteration's whole batch in one call."""
         with self._lock:
-            acc = self._tenant(req.tenant)
-            acc["new_tokens"] += n
-            acc["est_flops"] += flops
-        self._m_tokens.inc(n, tenant=req.tenant)
-        if flops:
-            self._m_flops.inc(flops, tenant=req.tenant)
+            for tenant, n in by_tenant.items():
+                acc = self._tenant(tenant)
+                acc["new_tokens"] += n
+                acc["est_flops"] += n * self.token_flops
+        for tenant, n in by_tenant.items():
+            self._m_tokens.inc(n, tenant=tenant)
+            if self.token_flops:
+                self._m_flops.inc(n * self.token_flops, tenant=tenant)
 
     def on_finish(self, req) -> None:
         """Terminal-state closeout: count the request under its status,

@@ -36,8 +36,16 @@ online replacement:
   whole block reservation are free (no mid-flight OOM), strictly in
   arrival order (head-of-line blocking keeps FIFO fairness — a small
   request never jumps a large one under backpressure);
-- **decode fast path** (ISSUE 15): with ``fused_sampling=True`` the
-  per-token host round-trip disappears — greedy / temperature+top-k
+- **one host pass a decode iteration**: the one-token program hands back
+  the arg-max of its logits, so an iteration in which nobody samples
+  fetches a token a slot (``slots x 4`` bytes) and leaves the logits on
+  the device; a request with ``temperature > 0`` makes that iteration
+  fetch them and takes the numpy sampler on its row.  The batch then
+  commits in one pass — counters, usage ledger and histograms once an
+  iteration, the requests' own fields in one loop — and every stream's
+  line is handed over at the end of it, in one stretch;
+- **decode fast path** (ISSUE 15): with ``fused_sampling=True`` sampling
+  itself moves onto the device — greedy / temperature+top-k
   sampling is folded INTO the compiled decode program
   (``serve.model.make_fused_decode_fn`` + ``serve.sampling``): per-slot
   PRNG keys and the last sampled tokens stay resident on device across
@@ -198,7 +206,10 @@ class GenRequest:
     _t_attr: float = 0.0
     #: streaming: newly committed tokens per iteration as ("tokens",
     #: [ids]) events plus one terminal ("done", None); None = blocking.
-    _events: queue.Queue | None = dataclasses.field(
+    #: A ``SimpleQueue``: one producer, one consumer, never full — its
+    #: ``put`` is a C call that takes no Python-level lock or condition,
+    #: and the engine makes one a stream an iteration.
+    _events: queue.SimpleQueue | None = dataclasses.field(
         default=None, repr=False
     )
     # -- chunked-prefill state (engine thread only) --
@@ -399,6 +410,8 @@ class Engine:
             maxlen=self.step_ring_size)
         self._step_id = 0
         self._step_evicted = 0     # requests finished in the current step
+        #: the current step's (device_sampled, logits_fetched)
+        self._step_sampled = (0, 0)
         #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
         #: and closes its profiler windows by iteration, so a capture
         #: armed through ``POST /profilez?steps=N`` holds N iterations.
@@ -415,10 +428,16 @@ class Engine:
             # decode fast path (ISSUE 15): tokens committed by decode /
             # verify steps, draft proposals and acceptances, and the
             # dispatch accounting the bench A/Bs — decode program
-            # executions plus host sampling rounds (the logits fetch +
-            # numpy softmax + token feed-back the fused path removes).
+            # executions plus host sampling rounds (the iterations that
+            # fetched the logits and ran the numpy sampler, for a request
+            # with temperature > 0; none in greedy traffic, none fused).
             "decode_tokens": 0, "spec_drafted": 0, "spec_accepted": 0,
             "decode_dispatches": 0, "host_sample_rounds": 0,
+            # decode iterations that fetched the logits (== the rounds
+            # above: a request with temperature > 0 was decoding), and the
+            # slot-iterations whose token came off the device with the
+            # step (all of them where nobody samples)
+            "logit_fetches": 0, "device_sampled_tokens": 0,
             # slot-steps = sum of active slots over decode steps: the
             # denominator that makes tokens-per-step PER-SLOT (1.0
             # without speculation, matching the histogram), not an
@@ -620,7 +639,7 @@ class Engine:
         if deadline_s is not None:
             req.t_deadline = req.t_submit + deadline_s
         if stream:
-            req._events = queue.Queue()
+            req._events = queue.SimpleQueue()
         req._rng = np.random.default_rng(req.seed)
         rejected = False
         with self._cond:
@@ -729,6 +748,7 @@ class Engine:
         drafted0 = self.counters["spec_drafted"]
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
+        self._step_sampled = (0, 0)
         # The iteration is one span tree (mirrored into any open profiler
         # trace): the step record's walls are its durations, and the
         # `step` attribute is the steps.jsonl `step` this iteration gets.
@@ -814,6 +834,8 @@ class Engine:
             "tokens_committed": tokens,
             "spec_drafted": drafted,
             "spec_accepted": accepted,
+            "device_sampled": self._step_sampled[0],
+            "logits_fetched": self._step_sampled[1],
             "admit_s": round(admit_s, 6),
             "prefill_s": round(prefill_s, 6),
             "decode_s": round(decode_s, 6),
@@ -1059,7 +1081,7 @@ class Engine:
         req.attr_prefill_s += max(req.t_first_token - req._t_attr, 0.0)
         req._t_attr = req.t_first_token
         req.tokens.append(tok)
-        self.usage.on_tokens(req, 1)
+        self.usage.on_tokens({req.tenant: 1})
         self._last_tokens[req.slot] = tok
         self._m_ttft.observe(req.ttft_s)
         self._stream_emit(req, [tok])
@@ -1067,21 +1089,26 @@ class Engine:
 
     def _run_decode_step(self, prefill_s: float) -> None:
         """One decode iteration for every slot whose prefill is done:
-        the host-sampling path (one token per slot, numpy fallback
-        sampler) or the fused fast path (sampling — and optionally
+        the one-token program (whose arg-max is a greedy slot's token; a
+        slot that samples takes the numpy sampler on its row of the
+        logits) or the fused fast path (sampling — and optionally
         speculative verification — inside the compiled program).  Both
         are three spans: ``engine.decode.dispatch`` (CoW guard, slot
         meta, table upload, launch), ``engine.decode.fetch`` (the wait
-        for the device) and ``engine.decode.commit`` (host sampling,
-        bookkeeping, stream emit).  ``prefill_s`` is this iteration's
-        ``engine.prefill`` wall, for the attribution split."""
+        for the device, and what the host needs of the result: a token a
+        slot, and the logits only if a live request samples) and
+        ``engine.decode.commit`` (host sampling where asked for, one pass
+        of bookkeeping over the batch, then the streams' lines).
+        ``prefill_s`` is this iteration's ``engine.prefill`` wall, for the
+        attribution split."""
         decoding = [
             (i, r) for i, r in enumerate(self._slots)
             if r is not None and r._prefill_done
         ]
         n_active = len(decoding)
+        slots = np.fromiter((i for i, _ in decoding), np.intp, n_active)
         if self.fused_sampling:
-            self._decode_step_fused(decoding, n_active, prefill_s)
+            self._decode_step_fused(decoding, slots, prefill_s)
             return
         with obs_tracing.span("engine.decode.dispatch") as s_dispatch:
             for i, _ in decoding:
@@ -1094,70 +1121,109 @@ class Engine:
             self._refresh_slot_meta()
             for i, _ in decoding:
                 self.kv.prepare_write(i, int(self.kv.seq_lens[i]) + 1)
-            logits, pools, self._routed = self.programs.decode(
+            logits, greedy, pools, self._routed = self.programs.decode(
                 self.params, self.kv.pools(),
                 jnp.asarray(self._last_tokens), self._tables_dev(),
                 jnp.asarray(self.kv.seq_lens), self._dev_active,
             )
             self.kv.set_pools(pools)
+        # what the engine sees in its input decides what it fetches: the
+        # logits (slots x vocabulary floats) stay on the device unless a
+        # live request samples from them
+        sampling = [(j, r) for j, (_, r) in enumerate(decoding)
+                    if r.temperature > 0.0]
         with obs_tracing.span("engine.decode.fetch") as s_fetch:
-            logits = np.asarray(logits)
+            tokens = np.asarray(greedy)[slots]
+            if sampling:
+                logits = np.asarray(logits)
         now = time.time()
         decode_dt = s_dispatch.dur_s + s_fetch.dur_s
         with obs_tracing.span("engine.decode.commit"):
-            self.decode_steps += 1
-            self.counters["decode_dispatches"] += 1
-            self.counters["host_sample_rounds"] += 1
-            self.counters["slot_steps"] += n_active
-            self._m_occ.observe(float(n_active))
-            self.occupancy_max = max(self.occupancy_max, n_active)
-            for slot, req in decoding:
-                self.kv.note_written(slot, int(self.kv.seq_lens[slot]) + 1)
-                tok = self._sample(req, logits[slot])
-                self._charge_decode(req, now, decode_dt, prefill_s,
-                                    spec=False)
-                self._commit_tokens(slot, req, [tok], n_active, now)
+            for j, req in sampling:
+                tokens[j] = self._sample(req, logits[req.slot])
+            self._note_sampled(n_active - len(sampling), bool(sampling))
+            self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
+            self._commit_tokens(
+                decoding, slots, [[t] for t in tokens.tolist()], now,
+                decode_dt, prefill_s, spec=False)
 
-    def _charge_decode(self, req: GenRequest, now: float, decode_dt: float,
+    def _commit_tokens(self, decoding, slots: np.ndarray,
+                       kept: list[list[int]], now: float, decode_dt: float,
                        prefill_s: float, spec: bool) -> None:
-        """Advance the request's attribution frontier to ``now``,
-        splitting the interval exclusively: this iteration's decode
-        dispatch wall to decode (or the speculative-verify component),
-        up to this iteration's prefill-phase wall to interference stall
-        (the engine ran other requests' chunks while this one had a
-        token pending), the remainder to scheduler gap (admit scans,
-        bookkeeping, idle waits between iterations)."""
-        interval = max(now - req._t_attr, 0.0)
-        d = min(interval, max(decode_dt, 0.0))
-        if spec:
-            req.attr_spec_s += d
-        else:
-            req.attr_decode_s += d
-        s = min(interval - d, max(prefill_s, 0.0))
-        req.attr_stall_s += s
-        req.attr_gap_s += interval - d - s
-        req._t_attr = now
+        """The bookkeeping of one decode iteration, for the whole batch in
+        one pass — ONE implementation for the one-token and fused paths,
+        so telemetry (occupancy, tokens/step, ITL) cannot drift between
+        them.  ``decoding`` is the iteration's ``(slot, request)`` pairs,
+        ``slots`` the same slots as an array, ``kept`` the tokens each
+        request commits (one, or a verified burst).
 
-    def _commit_tokens(self, slot: int, req: GenRequest, kept: list[int],
-                       n_active: int, now: float) -> None:
-        """Per-request bookkeeping for this iteration's committed tokens
-        — ONE implementation for the host and fused paths, so telemetry
-        (occupancy, tokens/step, ITL) cannot drift between them."""
-        req.occ_sum += n_active
-        req.occ_steps += 1
-        req.occ_max = max(req.occ_max, n_active)
-        req.tokens.extend(kept)
-        self.usage.on_tokens(req, len(kept))
-        self.counters["decode_tokens"] += len(kept)
-        self._m_tok_step.observe(float(len(kept)))
-        if req._t_last_token:
-            req.itl_max_s = max(req.itl_max_s, now - req._t_last_token)
-        req._t_last_token = now
-        self._last_tokens[slot] = kept[-1]
-        self._stream_emit(req, kept)
-        self._maybe_finish(req)
+        Engine-wide counts, the usage ledger (a tenant at a time, one
+        lock) and the histograms are taken once; a request's own fields
+        are written in one loop that takes no lock and calls no method of
+        the engine.  Each request's
+        attribution frontier advances to ``now``, the interval split
+        exclusively: this iteration's decode dispatch wall to decode (or,
+        ``spec``, the speculative-verify component), up to this
+        iteration's prefill-phase wall to interference stall (the engine
+        ran other requests' chunks while this one had a token pending),
+        the remainder to scheduler gap (admit scans, bookkeeping, idle
+        waits between iterations) — worked out once for each frontier
+        there is (one, but for requests whose prefill ended this
+        iteration).  Last of all, in one stretch, every stream gets this
+        iteration's line — so that the threads the lines wake run while
+        the engine waits for the next launch, not between two requests of
+        this loop — and the requests that ended (EOS, length) are
+        finished."""
+        n_active = len(decoding)
+        self.decode_steps += 1
+        self.counters["decode_dispatches"] += 1
+        self.counters["slot_steps"] += n_active
+        self._m_occ.observe(float(n_active))
+        self.occupancy_max = max(self.occupancy_max, n_active)
+        decode_dt, prefill_s = max(decode_dt, 0.0), max(prefill_s, 0.0)
+        splits: dict[float, tuple[float, float, float]] = {}
+        by_tenant: dict[str, int] = {}
+        by_count: dict[int, int] = {}
+        finished = []
+        for (_, req), toks in zip(decoding, kept):
+            split = splits.get(req._t_attr)
+            if split is None:
+                interval = max(now - req._t_attr, 0.0)
+                d = min(interval, decode_dt)
+                s = min(interval - d, prefill_s)
+                split = splits[req._t_attr] = (d, s, interval - d - s)
+            if spec:
+                req.attr_spec_s += split[0]
+            else:
+                req.attr_decode_s += split[0]
+            req.attr_stall_s += split[1]
+            req.attr_gap_s += split[2]
+            req._t_attr = now
+            req.occ_sum += n_active
+            req.occ_steps += 1
+            if n_active > req.occ_max:
+                req.occ_max = n_active
+            req.tokens.extend(toks)
+            n = len(toks)
+            by_tenant[req.tenant] = by_tenant.get(req.tenant, 0) + n
+            by_count[n] = by_count.get(n, 0) + 1
+            if req._t_last_token and now - req._t_last_token > req.itl_max_s:
+                req.itl_max_s = now - req._t_last_token
+            req._t_last_token = now
+            if toks[-1] == req.eos_token_id \
+                    or len(req.tokens) >= req.max_new_tokens:
+                finished.append(req)
+        self._last_tokens[slots] = [toks[-1] for toks in kept]
+        self.counters["decode_tokens"] += sum(by_tenant.values())
+        self.usage.on_tokens(by_tenant)
+        for n, requests in by_count.items():
+            self._m_tok_step.observe(float(n), count=requests)
+        for (_, req), toks in zip(decoding, kept):
+            self._stream_emit(req, toks)
+        for req in finished:
+            self._maybe_finish(req)
 
-    def _decode_step_fused(self, decoding, n_active: int,
+    def _decode_step_fused(self, decoding, slots: np.ndarray,
                            prefill_s: float) -> None:
         """One fused decode iteration: build the (optional) draft
         window, dispatch ONE program, commit the emitted bursts.
@@ -1235,21 +1301,18 @@ class Engine:
         now = time.time()
         decode_dt = s_dispatch.dur_s + s_fetch.dur_s
         with obs_tracing.span("engine.decode.commit"):
-            self.decode_steps += 1
-            self.counters["decode_dispatches"] += 1
-            self.counters["slot_steps"] += n_active
-            self._m_occ.observe(float(n_active))
-            self.occupancy_max = max(self.occupancy_max, n_active)
-            for slot, req in decoding:
+            self._note_sampled(len(decoding), False)
+            seq0 = self.kv.seq_lens[slots]
+            # Commit the last input token + every ACCEPTED draft's K/V
+            # (emitted - 1 of them); rejected drafts' K/V sits past this
+            # extent (dead, masked, overwritten by the next append).
+            self.kv.note_written(slots, seq0 + n_emit[slots])
+            bursts = []
+            for (slot, req), s in zip(decoding, seq0.tolist()):
                 n = int(n_emit[slot])
                 emitted = [int(t) for t in out[slot, :n]]
                 k_drafted = int(draft_lens[slot])
                 accepted = n - 1
-                s = int(self.kv.seq_lens[slot])
-                # Commit the last input token + every ACCEPTED draft's K/V;
-                # rejected drafts' K/V sits past this extent (dead, masked,
-                # overwritten by the next append).
-                self.kv.note_written(slot, s + 1 + accepted)
                 kept = emitted
                 if req.eos_token_id is not None \
                         and req.eos_token_id in emitted:
@@ -1272,15 +1335,28 @@ class Engine:
                     self._m_spec_drafted.inc(k_drafted)
                     if committed:
                         self._m_spec_accepted.inc(committed)
-                # a T=K+1 (verify) dispatch charges the speculation
-                # component for EVERY active slot — a mixed batch pays the
-                # window for everyone, and the attribution should say so
-                self._charge_decode(req, now, decode_dt, prefill_s,
-                                    spec=t_width > 1)
-                self._commit_tokens(slot, req, kept, n_active, now)
+                bursts.append(kept)
+            # a T=K+1 (verify) dispatch charges the speculation
+            # component for EVERY active slot — a mixed batch pays the
+            # window for everyone, and the attribution should say so
+            self._commit_tokens(decoding, slots, bursts, now, decode_dt,
+                                prefill_s, spec=t_width > 1)
+
+    def _note_sampled(self, device_sampled: int, logits_fetched: bool) -> None:
+        """This decode iteration's ``device_sampled`` / ``logits_fetched``
+        (step record) and their running totals: the slots whose token came
+        off the device with the step — the program's arg-max for a greedy
+        slot, every slot of a fused program — and whether the logits were
+        fetched for the others."""
+        self._step_sampled = (device_sampled, int(logits_fetched))
+        self.counters["device_sampled_tokens"] += device_sampled
+        self.counters["logit_fetches"] += int(logits_fetched)
+        self.counters["host_sample_rounds"] += int(logits_fetched)
 
     def _sample(self, req: GenRequest, logits: np.ndarray) -> int:
-        """Host-side sampling fallback (``fused_sampling=False``):
+        """Host-side sampler: the first token of every request (the prefill
+        program hands its last row of logits to the host) and, in decode
+        iterations, the requests with ``temperature > 0`` —
         greedy / temperature+top-k, deterministic per request seed.  The
         logits→probs math is the SHARED reference
         (:func:`serve.sampling.logits_to_probs`, fp32) — the historical
@@ -1297,7 +1373,7 @@ class Engine:
         """Push newly committed tokens to a streaming request's event
         queue (no-op for blocking requests)."""
         if req._events is not None and toks:
-            req._events.put(("tokens", list(toks)))
+            req._events.put(("tokens", toks))
 
     def _maybe_finish(self, req: GenRequest) -> None:
         last = req.tokens[-1]

@@ -291,7 +291,6 @@ class SlotPages:
 
     blocks: list[int]          # physical block ids, logical order
     capacity_tokens: int       # blocks * block_size
-    used_tokens: int = 0       # K/V positions actually written so far
     prefix_tokens: int = 0     # tokens mapped from the prefix cache at admit
 
 
@@ -336,8 +335,12 @@ class PagedKVCache:
         #: ``block_tables`` across the many decode steps between
         #: admissions instead of re-shipping it per step.
         self.tables_version = 0
+        #: K/V positions written so far, a slot
         self.seq_lens = np.zeros((max_slots,), np.int32)
         self.pages: list[SlotPages | None] = [None] * max_slots
+        #: ``pages[slot].capacity_tokens`` as an array (-1: no pages), so
+        #: that a whole batch's writes are bounded in one comparison
+        self._capacity = np.full((max_slots,), -1, np.int64)
         # prefix index: chained content hash -> (physical block, the
         # block's token tuple), + reverse map for eviction.  The tokens
         # are stored so every lookup VERIFIES them — hash() is 64-bit
@@ -465,9 +468,9 @@ class PagedKVCache:
             self.prefix_cached_tokens += prefix_tokens
         blocks = prefix_blocks + fresh
         pages = SlotPages(blocks, n * self.block_size,
-                          used_tokens=prefix_tokens,
                           prefix_tokens=prefix_tokens)
         self.pages[slot] = pages
+        self._capacity[slot] = pages.capacity_tokens
         self.block_tables[slot, :] = self.scratch_block
         self.block_tables[slot, : len(blocks)] = blocks
         self.tables_version += 1
@@ -482,6 +485,7 @@ class PagedKVCache:
             return
         self.allocator.free(pages.blocks)
         self.pages[slot] = None
+        self._capacity[slot] = -1
         self.block_tables[slot, :] = self.scratch_block
         self.tables_version += 1
         self.seq_lens[slot] = 0
@@ -567,45 +571,47 @@ class PagedKVCache:
         pages = self.pages[slot]
         if pages is None:
             raise OutOfBlocksError(f"slot {slot} has no pages")
-        if tokens > pages.used_tokens:
+        used = int(self.seq_lens[slot])
+        if tokens > used:
             raise OutOfBlocksError(
                 f"slot {slot}: rollback target {tokens} exceeds resident "
-                f"{pages.used_tokens} (rollback only retreats)"
+                f"{used} (rollback only retreats)"
             )
         if tokens < pages.prefix_tokens:
             raise OutOfBlocksError(
                 f"slot {slot}: rollback to {tokens} would retreat into the "
                 f"mapped shared prefix ({pages.prefix_tokens} tokens)"
             )
-        if tokens == pages.used_tokens:
+        if tokens == used:
             return  # empty retreat window
         bs = self.block_size
         for li in range(tokens // bs,
-                        min((pages.used_tokens - 1) // bs + 1,
-                            len(pages.blocks))):
+                        min((used - 1) // bs + 1, len(pages.blocks))):
             if self.allocator.refcount(pages.blocks[li]) > 1:
                 raise OutOfBlocksError(
                     f"slot {slot}: rollback window covers shared block "
                     f"{pages.blocks[li]} (refcount "
                     f"{self.allocator.refcount(pages.blocks[li])})"
                 )
-        pages.used_tokens = tokens
         self.seq_lens[slot] = tokens
 
-    def note_written(self, slot: int, tokens: int) -> None:
-        """Advance a slot's resident-token count (after a program wrote
-        K/V); bounded by the reservation so a scheduler bug trips here,
-        not as silent cross-slot corruption."""
-        pages = self.pages[slot]
-        if pages is None:
-            raise OutOfBlocksError(f"slot {slot} has no pages")
-        if tokens > pages.capacity_tokens:
+    def note_written(self, slots, tokens) -> None:
+        """Advance the resident-token counts of ``slots`` to ``tokens``
+        (after a program wrote K/V): two arrays of one length — a decode
+        iteration's slots in one call — or one slot and its count.  Bounded
+        by the reservations so a scheduler bug trips here, not as silent
+        cross-slot corruption."""
+        slots, tokens = np.atleast_1d(slots), np.atleast_1d(tokens)
+        over = tokens > self._capacity[slots]
+        if over.any():
+            slot, n = int(slots[over][0]), int(tokens[over][0])
+            if self.pages[slot] is None:
+                raise OutOfBlocksError(f"slot {slot} has no pages")
             raise OutOfBlocksError(
-                f"slot {slot}: {tokens} tokens exceed reserved capacity "
-                f"{pages.capacity_tokens}"
+                f"slot {slot}: {n} tokens exceed reserved capacity "
+                f"{self.pages[slot].capacity_tokens}"
             )
-        pages.used_tokens = tokens
-        self.seq_lens[slot] = tokens
+        self.seq_lens[slots] = tokens
 
     # -- introspection -------------------------------------------------------
 
@@ -635,7 +641,7 @@ class PagedKVCache:
         and the engine's metrics.jsonl rows)."""
         used = [p for p in self.pages if p is not None]
         allocated_tokens = sum(p.capacity_tokens for p in used)
-        used_tokens = sum(p.used_tokens for p in used)
+        used_tokens = int(self.seq_lens[self._capacity >= 0].sum())
         alloc = self.allocator
         return {
             "block_size": self.block_size,
@@ -708,8 +714,8 @@ class WindowKVGroup(PagedKVCache):
         self._stock: list[list[int]] = [[] for _ in range(self.max_slots)]
         #: per slot: first logical block still mapped, and the first not
         #: mapped yet (a slot's mapped blocks are [first, next))
-        self._first = [0] * self.max_slots
-        self._next = [0] * self.max_slots
+        self._first = np.zeros((self.max_slots,), np.int64)
+        self._next = np.zeros((self.max_slots,), np.int64)
         self.blocks_recycled = 0
 
     def reservation(self, tokens: int) -> int:
@@ -728,6 +734,7 @@ class WindowKVGroup(PagedKVCache):
             return None
         pages = SlotPages(blocks, self.blocks_for(tokens) * self.block_size)
         self.pages[slot] = pages
+        self._capacity[slot] = pages.capacity_tokens
         self._stock[slot] = list(reversed(blocks))
         self._first[slot] = self._next[slot] = 0
         self.block_tables[slot, :] = self.scratch_block
@@ -753,23 +760,29 @@ class WindowKVGroup(PagedKVCache):
             self._next[slot] = li + 1
             self.tables_version += 1
 
-    def note_written(self, slot: int, tokens: int) -> None:
-        """Advance the resident count, then let go of every block wholly
+    def note_written(self, slots, tokens) -> None:
+        """Advance the resident counts, then let go of every block wholly
         behind ``tokens - window``: the next query sits at ``tokens`` at
-        the earliest and attends keys ``> tokens - window``."""
-        super().note_written(slot, tokens)
-        row = self.block_tables[slot]
-        keep_from = max(tokens - self.window + 1, 0) // self.block_size
-        for li in range(self._first[slot], min(keep_from, self._next[slot])):
-            self._stock[slot].append(int(row[li]))
-            row[li] = self.scratch_block
-            self.blocks_recycled += 1
-            self.tables_version += 1
-        self._first[slot] = max(self._first[slot], keep_from)
-        self._next[slot] = max(self._next[slot], self._first[slot])
+        the earliest and attends keys ``> tokens - window``.  Only the
+        slots that crossed a block edge have a block to let go."""
+        super().note_written(slots, tokens)
+        slots = np.atleast_1d(slots)
+        keep_from = (np.maximum(np.atleast_1d(tokens) - self.window + 1, 0)
+                     // self.block_size)
+        upto = np.minimum(keep_from, self._next[slots])
+        crossed = upto > self._first[slots]
+        for slot, end in zip(slots[crossed].tolist(), upto[crossed].tolist()):
+            row = self.block_tables[slot]
+            for li in range(int(self._first[slot]), end):
+                self._stock[slot].append(int(row[li]))
+                row[li] = self.scratch_block
+                self.blocks_recycled += 1
+                self.tables_version += 1
+        self._first[slots] = np.maximum(self._first[slots], keep_from)
+        self._next[slots] = np.maximum(self._next[slots], self._first[slots])
 
     def mapped_blocks(self, slot: int) -> int:
-        return self._next[slot] - self._first[slot]
+        return int(self._next[slot] - self._first[slot])
 
 
 class GroupedKVCache:
@@ -865,9 +878,9 @@ class GroupedKVCache:
         for g in self.groups.values():
             g.prepare_write(slot, end)
 
-    def note_written(self, slot: int, tokens: int) -> None:
+    def note_written(self, slots, tokens) -> None:
         for g in self.groups.values():
-            g.note_written(slot, tokens)
+            g.note_written(slots, tokens)
 
     # -- prefix sharing: the one group's, and nothing to guard without ----
 

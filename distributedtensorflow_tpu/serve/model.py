@@ -144,12 +144,16 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
 def make_decode_fn(family, cfg, *, block_size: int,
                    layers: dict[str, tuple[int, ...]]):
     """``fn(params, pools, tokens (slots,), tables, seq_lens, active) ->
-    (logits, pools, routed)``: one token for every slot.  ``tokens`` is each
-    slot's last sampled token, ``seq_lens`` the resident token counts (the
-    new token is written at that position, then attends ``seq_len + 1``
+    (logits, greedy, pools, routed)``: one token for every slot.  ``tokens``
+    is each slot's last sampled token, ``seq_lens`` the resident token counts
+    (the new token is written at that position, then attends ``seq_len + 1``
     positions), and ``active`` masks unoccupied slots: their write lands in
     the reserved scratch block and their logits are discarded by the engine,
-    so the program shape never depends on occupancy.  ``routed`` is int32
+    so the program shape never depends on occupancy.  ``greedy`` is int32
+    ``(slots,)``, the arg-max of each row of the float32 ``logits`` (the
+    first of equal maxima, as ``np.argmax`` takes it): all the host needs of
+    an iteration in which nobody samples, so the logits can stay on the
+    device (``Engine._run_decode_step``).  ``routed`` is int32
     ``(3,)``: over the expert layers, the routed (token, choice) pairs that
     landed on held experts (sum), the held experts hit (sum) and the largest
     load of one expert (max) — active slots only; None from a model without
@@ -203,7 +207,10 @@ def make_decode_fn(family, cfg, *, block_size: int,
                 functools.reduce(jnp.maximum,
                                  [c["max_load"] for c in routed]),
             ]).astype(jnp.int32)
-        return family.head(params, x, cfg), pools, stat
+        logits = family.head(params, x, cfg)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return logits, greedy, pools, stat
 
     return decode
 
@@ -353,7 +360,8 @@ class Programs:
       table_rows, last_ix) -> (last_logits, pools)``: one prompt chunk of
       one slot (:func:`make_prefill_fn`);
     - ``decode(params, pools, tokens (slots,), tables, seq_lens, active) ->
-      (logits, pools, routed)``: one token a slot (:func:`make_decode_fn`);
+      (logits, greedy, pools, routed)``: one token a slot, and the arg-max of
+      its logits (:func:`make_decode_fn`);
     - ``fused(draft)``: the sampled (``draft`` = 0) or verify program
       (:func:`make_fused_decode_fn`), or a ``ValueError`` that says it is
       not implemented;

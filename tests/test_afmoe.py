@@ -69,24 +69,45 @@ def f32_model():
     return cfg, params
 
 
+def _record_logits(eng):
+    """``{request id: [the logits of every served position]}``, filled as
+    ``eng`` runs: a request's first row as the host sampler is handed it,
+    a decode iteration's rows as the program returns them (they stay on the
+    device for the engine, which takes the program's arg-max)."""
+    seen = {}
+    sample, decode = eng._sample, eng.programs.decode
+
+    def first(req, logits):
+        if not req.tokens:
+            seen.setdefault(req.id, []).append(np.array(logits))
+        return sample(req, logits)
+
+    def spy(*args):
+        out = decode(*args)
+        logits = np.asarray(out[0])
+        for slot, req in enumerate(eng._slots):
+            if req is not None and req._prefill_done:
+                seen.setdefault(req.id, []).append(logits[slot].copy())
+        return out
+
+    eng._sample, eng.programs.decode = first, spy
+    return seen
+
+
 def _serve(cfg, params, jobs, **engine_kw):
     """Run ``jobs`` [(prompt, n_new)] through an Engine together; returns
     per job (tokens, logits of every served position)."""
     kw = dict(max_slots=3, block_size=4, prefill_chunk=8, max_context=128)
     eng = Engine(params, cfg, **{**kw, **engine_kw})
-    seen = {}
-
-    def record(req, logits):
-        seen.setdefault(req.id, []).append(np.array(logits))
-        return int(np.argmax(logits))
-
-    eng._sample = record
+    seen = _record_logits(eng)
     reqs = [eng.submit(p, max_new_tokens=n) for p, n in jobs]
     for _ in range(2000):
         if all(r._done.is_set() for r in reqs):
             break
         eng.step()
     assert all(r.status == "ok" for r in reqs)
+    for r in reqs:      # greedy: each token the arg-max of its row
+        assert r.tokens == [int(np.argmax(row)) for row in seen[r.id]]
     return eng, [(r.tokens, np.stack(seen[r.id])) for r in reqs]
 
 
@@ -123,6 +144,43 @@ def test_dense_forward_of_the_same_block_matches_the_reference(f32_model):
     want = REF.logits(params, ids, _config_dict(cfg))
     np.testing.assert_allclose(afmoe.forward(params, ids, cfg), want,
                                atol=F32_TOL, rtol=0)
+
+
+def test_decode_program_hands_back_the_arg_max_of_its_logits(f32_model):
+    """The family's ``jit_decode`` returns the arg-max of its own float32
+    logits a slot, the first of equal maxima (a head whose every column
+    stands twice ties every row's maximum): what ``np.argmax`` gave the
+    engine when it fetched the logits."""
+    cfg, params = f32_model
+    half = cfg.vocab_size // 2
+    head = params["head"]
+    tied = {**params,
+            "head": head.at[:, half:2 * half].set(head[:, :half])}
+    eng = Engine(tied, cfg, max_slots=3, block_size=4, prefill_chunk=8,
+                 max_context=128)
+    decode, checked = eng.programs.decode, set()
+
+    def spy(*args):
+        out = decode(*args)
+        logits, greedy = np.asarray(out[0]), np.asarray(out[1])
+        assert greedy.dtype == np.int32 and greedy.shape == (3,)
+        for slot, req in enumerate(eng._slots):
+            if req is not None and req._prefill_done:
+                g = int(greedy[slot])
+                assert g == int(np.argmax(logits[slot])) < half
+                assert logits[slot, g] == logits[slot, g + half]
+                checked.add(slot)
+        return out
+
+    eng.programs.decode = spy
+    rng = np.random.default_rng(31)
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(),
+                       max_new_tokens=m) for n, m in ((40, 12), (9, 20))]
+    while not all(r._done.is_set() for r in reqs):
+        eng.step()
+    assert checked == {0, 1}        # the third slot never held a request
+    assert all(r.status == "ok" and max(r.tokens) < half for r in reqs)
+    assert eng.counters["logit_fetches"] == 0
 
 
 def test_slots_of_different_lengths_decode_together(f32_model):
@@ -318,9 +376,7 @@ def test_freed_blocks_are_never_read(f32_model):
     prompt = np.random.default_rng(1).integers(0, cfg.vocab_size, 44).tolist()
     eng = Engine(params, cfg, max_slots=2, block_size=4, prefill_chunk=8,
                  max_context=128)
-    seen = []
-    eng._sample = lambda req, lg: (seen.append(np.array(lg)),
-                                   int(np.argmax(lg)))[1]
+    seen = _record_logits(eng)
     req = eng.submit(prompt, max_new_tokens=40)
     steps = 0
     while not req._done.is_set():
@@ -337,7 +393,8 @@ def test_freed_blocks_are_never_read(f32_model):
                 g.v_pool = g.v_pool.at[:, rows].set(1e4)
     assert eng.kv.blocks_recycled > 0
     want = _reference_logits(cfg, params, prompt, req.tokens)
-    np.testing.assert_allclose(np.stack(seen), want, atol=F32_TOL, rtol=0)
+    np.testing.assert_allclose(np.stack(seen[req.id]), want, atol=F32_TOL,
+                               rtol=0)
 
 
 def test_step_log_carries_the_family_counters(f32_model):

@@ -443,6 +443,42 @@ def test_engine_matches_dense_generate(served_model):
         assert r.tokens == list(dense[i, 8:])
 
 
+def test_decode_program_hands_back_the_arg_max_of_its_logits(served_model):
+    """``jit_decode`` returns, beside the float32 logits, their arg-max a
+    slot as int32 — the token the engine takes for a greedy slot without
+    fetching the logits.  Under a head whose every column stands twice
+    each row's maximum is a tie, and the first of the two is taken, as
+    ``np.argmax`` takes it: the served tokens are the parent's."""
+    cfg, params, ids = served_model
+    half = cfg.vocab_size // 2
+    emb = params["wte"]["embedding"]
+    tied = {**params, "wte": {
+        "embedding": emb.at[half:2 * half].set(emb[:half])}}
+    eng = _engine(cfg, tied, max_slots=3)
+    decode, checked = eng.programs.decode, set()
+
+    def spy(*args):
+        out = decode(*args)
+        logits, greedy = np.asarray(out[0]), np.asarray(out[1])
+        assert greedy.dtype == np.int32 and greedy.shape == (3,)
+        for slot, req in enumerate(eng._slots):
+            if req is not None and req._prefill_done:
+                g = int(greedy[slot])
+                assert g == int(np.argmax(logits[slot])) < half
+                assert logits[slot, g] == logits[slot, g + half]
+                checked.add(slot)
+        return out
+
+    eng.programs.decode = spy
+    reqs = [eng.submit([int(t) for t in np.asarray(ids)[i]][:n],
+                       max_new_tokens=m)
+            for i, (n, m) in enumerate(((8, 9), (5, 6)))]
+    _drain(eng, reqs)
+    assert checked == {0, 1}        # the third slot never held a request
+    assert all(r.status == "ok" and max(r.tokens) < half for r in reqs)
+    assert eng.counters["logit_fetches"] == 0
+
+
 def test_engine_refuses_a_model_of_window_layers_only(served_model):
     cfg, params, _ = served_model
     with pytest.raises(ValueError, match="window layers only"):

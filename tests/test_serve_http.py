@@ -367,6 +367,62 @@ def test_streaming_tokens_then_trailer(frontend):
         trailer["drafted"] == 0 and trailer["accepted"] == 0)
 
 
+def test_concurrent_streams_one_line_a_committed_iteration(frontend):
+    """Every stream gets exactly one line for each iteration that
+    committed a token of its request — the first token's and then one a
+    decode iteration, never two iterations merged into a line — in the
+    order committed, and the trailer last; concurrent streams that share
+    decode iterations included (three streams over two slots)."""
+    server, engine, prompt = frontend
+    want = {}
+    for i in range(3):
+        status, body = _post(
+            server.port, "/generatez",
+            {"prompt": prompt[: 5 + i], "max_new_tokens": 7 + i})
+        assert status == 200
+        want[i] = body["tokens"]
+
+    def settled():
+        """The engine's newest step record, once it is the record of an
+        iteration that left nothing behind: a reply is sent from inside
+        the commit, before the iteration's record is written."""
+        for _ in range(400):
+            last = engine.step_records(1)[0]
+            if not last["active_slots"] and not last["queue_depth"] \
+                    and engine.steps_total == last["step"]:
+                return last["step"]
+            time.sleep(0.005)
+        raise AssertionError("the engine did not settle")
+
+    steps0 = settled()
+    got = {}
+
+    def client(i):
+        got[i] = _post_stream(
+            server.port, {"prompt": prompt[: 5 + i],
+                          "max_new_tokens": 7 + i, "stream": True})
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for i, (status, _, lines) in got.items():
+        assert status == 200
+        assert [l.get("done", False) for l in lines] \
+            == [False] * (7 + i) + [True]
+        assert all(len(l["tokens"]) == 1 for l in lines[:-1])
+        assert [l["tokens"][0] for l in lines[:-1]] == want[i]
+        assert lines[-1]["status"] == "ok"
+        assert lines[-1]["new_tokens"] == 7 + i
+    settled()
+    records = [r for r in engine.step_records() if r["step"] > steps0]
+    # one line a request for its first token, one a slot a decode iteration
+    assert sum(r["tokens_committed"] for r in records) + 3 \
+        == sum(7 + i for i in range(3))
+    assert max(r["occupancy"] for r in records) == 2
+
+
 def test_streaming_submit_errors_keep_real_statuses(frontend):
     """Submit-time failures must NOT be smuggled into a 200 stream:
     validation still 400s before any chunk goes out."""

@@ -82,7 +82,7 @@ def test_meter_integrals_hand_math(tmp_path):
     m.on_admit(a)
     m.on_step(101.0, 0.25, [(a, 4.0)], 1)
     m.on_step(101.5, 0.75, [(a, 2.0)], 2)
-    m.on_tokens(a, 3)
+    m.on_tokens({a.tenant: 3})
     m.on_finish(a)
     # a rejected request never admitted: queue time = submit -> done
     r = _req("b", "beta", t_submit=10.0, t_admit=0.0, t_done=10.25,
@@ -130,7 +130,7 @@ def test_meter_cardinality_guard():
     reg = Registry(max_label_sets=2)
     m = obs_usage.UsageMeter(registry=reg, token_flops=1.0, device_kind="")
     for i in range(6):  # 6 tenants through a 2-label-set registry
-        m.on_tokens(_req(f"r{i}", f"t{i}"), 1)
+        m.on_tokens({f"t{i}": 1})
     scal = reg.scalars()
     kept = [k for k in scal if k.startswith("serve_tenant_tokens_total.")]
     assert len(kept) == 2

@@ -1315,7 +1315,9 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
     ``step`` strictly increasing across the file, a ``phase`` of
     ``"idle"`` or "+"-joined tokens from :data:`STEP_PHASE_TOKENS`,
     non-negative integer count fields (:data:`STEP_COUNT_FIELDS`, with
-    ``budget_stall`` in {0, 1} and ``spec_accepted <= spec_drafted``),
+    ``budget_stall`` in {0, 1} and ``spec_accepted <= spec_drafted``;
+    where present, ``device_sampled <= occupancy`` and ``logits_fetched``
+    in {0, 1}),
     and non-negative finite wall fields whose phase split tiles the
     iteration: ``admit_s + prefill_s + decode_s <= step_s`` (up to
     rounding)."""
@@ -1415,6 +1417,23 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
             ):
                 errors.append(f"line {i}: 'kv_blocks_billed' {billed!r} is "
                               "not a non-negative finite number")
+            # where an iteration's tokens came from (PR 31; validated when
+            # present so older logs stay green): the slots whose token came
+            # off the device with the step, of the slots decoding, and
+            # whether the logits were fetched for the rest.
+            sampled = row.get("device_sampled")
+            if sampled is not None and (
+                not _nonneg_int(sampled)
+                or int(sampled) > counts.get("occupancy", int(sampled))
+            ):
+                errors.append(
+                    f"line {i}: 'device_sampled' {sampled!r} is not an "
+                    f"integer in [0, occupancy {counts.get('occupancy')}]")
+            fetched = row.get("logits_fetched")
+            if fetched is not None and (
+                    not _nonneg_int(fetched) or int(fetched) > 1):
+                errors.append(
+                    f"line {i}: 'logits_fetched' {fetched!r} is not 0/1")
             adm_t = row.get("admitted_tenants")
             if adm_t is not None:
                 if not isinstance(adm_t, dict) or not adm_t:

@@ -520,6 +520,8 @@ def serving_summary(rows: list[dict], metrics_rows: list[dict] | None
             fast["tokens_per_step"] = last["tokens_per_step"]
         steps = last.get("step")
         disp = last.get("decode_dispatches_total")
+        # iterations that fetched the logits for the host sampler (a
+        # request with temperature > 0 was decoding): 0 in greedy traffic
         rounds = last.get("host_sample_rounds_total")
         if isinstance(steps, (int, float)) and steps \
                 and isinstance(disp, (int, float)) \
