@@ -18,6 +18,11 @@ from .afmoe import (  # noqa: F401
     afmoe_tiny,
     trinity_large_ep8,
 )
+from .joyai import (  # noqa: F401
+    JoyaiConfig,
+    joyai_llm_flash,
+    joyai_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

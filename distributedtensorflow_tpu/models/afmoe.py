@@ -42,7 +42,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import xla_attention
+from ..ops.attention import KVRows, xla_attention
 from ..parallel.moe import dropless_moe
 from .gpt import rope, rope_tables
 
@@ -93,6 +93,11 @@ class AfmoeConfig:
     def window_of(self, layer: int) -> int | None:
         return (self.sliding_window if self.layer_types[layer] == SLIDING
                 else None)
+
+    @property
+    def cache_rows(self) -> KVRows:
+        """What a served layer caches a token (``ops.attention``)."""
+        return KVRows(self.num_heads, self.num_kv_heads, self.head_dim)
 
 
 def afmoe_tiny(**kw) -> AfmoeConfig:

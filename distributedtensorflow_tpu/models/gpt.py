@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import dot_product_attention
+from ..ops.attention import KVRows, dot_product_attention
 from ..ops.layernorm import layer_norm
 from ..ops.xent import tied_head_logits
 from ..parallel.sharding import LayoutMap
@@ -123,6 +123,11 @@ class GPTConfig:
 
     def window_of(self, layer: int) -> int | None:
         return self.attn_window
+
+    @property
+    def cache_rows(self) -> KVRows:
+        """What a served layer caches a token (``ops.attention``)."""
+        return KVRows(self.num_heads, self.kv_heads, self.head_dim)
 
 
 def gpt_small() -> GPTConfig:

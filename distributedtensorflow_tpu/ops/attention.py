@@ -9,6 +9,7 @@ score/value contraction, softmax in float32.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -767,3 +768,395 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, *,
         out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         interpret=interpret,
     )(tables, lens, lo, layer, qt, k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: one cached row a token that every query head shares
+# ---------------------------------------------------------------------------
+#
+# A latent (MLA) layer caches, a token, ``c_kv`` (``rank`` values, after its
+# norm) and the one rotated ``k_rope`` all heads share: a row ``[c_kv |
+# k_rope]`` of ``rank + rope`` values in ONE pool (no V pool: the values are
+# the first ``rank`` lanes of the same row).  Head ``i``'s key and value are
+# ``c_kv W_UK_i`` and ``c_kv W_UV_i``.  Two forms of the same mathematics:
+#
+# - *absorbed*: ``q_abs_i = q_nope_i W_UK_i^T`` (``rank`` wide), scores ``[q_abs
+#   | q_rope] . row``, output ``(sum_j p_j c_kv_j) W_UV_i``: every head reads
+#   the row as it lies, 2 * (rank + rope + rank) FLOPs a head a pair;
+# - *decompressed*: ``k_nope, v = c_kv W_UK, c_kv W_UV`` for a stretch of the
+#   context, then plain attention at 2 * (nope + rope + v) FLOPs a head a
+#   pair, plus the decompression of every context row once a chunk.
+#
+# Decode absorbs (a token a slot: decompressing a slot's context for one
+# query would be 2 * rank * H * (nope + v) FLOPs a key); a prefill chunk
+# decompresses (measured faster at every chunk width and context: PERF.md
+# section 4).
+
+
+def _latent_queries(q_nope, q_rope, w_uk, width):
+    """``[q_nope W_UK^T | q_rope | 0]`` (..., H, width): the query in the
+    cached row's own layout, in the stored type (the absorbed query is
+    rounded to it: the one rounding the non-absorbed form does not have)."""
+    with jax.named_scope("absorb"):
+        q_abs = jnp.einsum("...hn,rhn->...hr", q_nope, w_uk,
+                           preferred_element_type=jnp.float32
+                           ).astype(q_nope.dtype)
+        q = jnp.concatenate([q_abs, q_rope], axis=-1)
+        pad = width - q.shape[-1]
+        return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)]) if pad else q
+
+
+def _latent_values(o_lat, w_uv, dtype):
+    with jax.named_scope("v_up"):
+        return jnp.einsum("...hr,rhv->...hv", o_lat.astype(dtype), w_uv,
+                          preferred_element_type=jnp.float32).astype(dtype)
+
+
+def paged_latent_chunk_attention(
+    q_nope: jax.Array,       # (T, H, nope): one slot's chunk of queries
+    q_rope: jax.Array,       # (T, H, rope), rotated
+    start,                   # int32 scalar: position of the first query
+    pool: jax.Array,         # (L_group, rows, >= rank + rope) latent rows
+    table_row: jax.Array,    # (max_blocks,) the slot's page-table row
+    *,
+    w_uk: jax.Array,         # (rank, H, nope)
+    w_uv: jax.Array,         # (rank, H, v)
+    layer: int,
+    block_size: int,
+    scale: float,
+    kv_chunk: int = 512,
+) -> jax.Array:
+    """Chunk-prefill latent attention of one slot against its latent pages
+    (the chunk's own rows already written): a loop over ``kv_chunk``-row
+    stretches of the context up to the chunk's end with a running softmax,
+    each stretch decompressed with ``W_UK`` / ``W_UV`` inside the loop
+    (above).  Returns ``(T, H, v)``.  Scope ``paged_attn``."""
+    t, h, _ = q_nope.shape
+    rank, rope_dim = w_uk.shape[0], q_rope.shape[-1]
+    width = pool.shape[-1]
+    kv_chunk = max(block_size, kv_chunk // block_size * block_size)
+    bpc = kv_chunk // block_size
+    nb = table_row.shape[0]
+    qpos = start + jnp.arange(t, dtype=jnp.int32)
+    dtype = q_nope.dtype
+
+    def pages(c):
+        blocks = table_row[jnp.minimum(c * bpc + jnp.arange(bpc), nb - 1)]
+        return pool.reshape(pool.shape[0], -1, block_size, width)[
+            layer, blocks].reshape(kv_chunk, width)
+
+    def body(c, carry):
+        m, l, acc = carry
+        rows = pages(c)
+        kpos = c * kv_chunk + jnp.arange(kv_chunk, dtype=jnp.int32)
+        c_kv = rows[:, :rank]
+        k_nope = jnp.einsum("kr,rhn->khn", c_kv, w_uk,
+                            preferred_element_type=jnp.float32).astype(dtype)
+        values = jnp.einsum("kr,rhv->khv", c_kv, w_uv,
+                            preferred_element_type=jnp.float32).astype(dtype)
+        s = jnp.einsum("qhn,khn->hqk", q_nope, k_nope,
+                       preferred_element_type=jnp.float32) \
+            + jnp.einsum("qhr,kr->hqk", q_rope,
+                         rows[:, rank:rank + rope_dim],
+                         preferred_element_type=jnp.float32)
+        ok = (kpos[None, :] <= qpos[:, None])[None]
+        s = jnp.where(ok, s * scale, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        pv = jnp.einsum("hqk,khd->hqd", p.astype(dtype), values,
+                        preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(-1), acc * alpha[..., None] + pv
+
+    with jax.named_scope("paged_attn"):
+        init = (jnp.full((h, t), NEG_INF, jnp.float32),
+                jnp.zeros((h, t), jnp.float32),
+                jnp.zeros((h, t, w_uv.shape[-1]), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(
+            0, -(-(start + t) // kv_chunk), body, init)
+        out = (acc / jnp.maximum(l, 1e-30)[..., None]).transpose(1, 0, 2)
+    return out.astype(dtype)
+
+
+def paged_latent_formulation(block_size: int, width: int, rank: int,
+                             impl: str = "auto") -> str:
+    """Which formulation :func:`paged_latent_decode_attention` takes:
+    ``"paged_latent_attn"`` (the kernel) or ``"plain"`` (the gather of every
+    table column)."""
+    fits = (PAGED_ROWS % block_size == 0 and rank % LANES == 0
+            and width > rank)
+    return "paged_latent_attn" if use_kernel(impl) and fits else "plain"
+
+
+def _plain_latent_decode(q, pool, block_tables, attend_lens, *, layer,
+                         block_size, rank, scale):
+    """The plain formulation: every table column gathered, ``(B, H, rank)``
+    in float32.  The kernel's yardstick and the path off the TPU."""
+    b, max_blocks = block_tables.shape
+    num_layers, rows, width = pool.shape
+    x = pool.reshape(num_layers, rows // block_size, block_size,
+                     width)[layer, block_tables].reshape(b, -1, width)
+    s = jnp.einsum("bhw,bkw->bhk", q, x,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(x.shape[1])[None, :] < attend_lens[:, None]
+    w = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkr->bhr", w.astype(q.dtype), x[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
+def _paged_latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
+                         o_ref, buf, sem, m_sc, l_sc, acc_sc, *, block_size,
+                         n_steps, rank, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, c = pl.program_id(0), pl.program_id(1)
+    rows = PAGED_ROWS
+    step_rows = rows * PAGED_STRETCHES
+    bps = rows // block_size                   # blocks a stretch
+    nb = tables_ref.shape[1]
+    heads = q_ref.shape[1]
+    n, layer = lens_ref[s], layer_ref[0]
+    first = c * step_rows                      # first key row of this step
+
+    @pl.when((s == 0) & (c == 0))
+    def _():
+        # skipped blocks leave these rows as they were: keep them finite
+        buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(c == 0)
+    def _():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def copy(j):
+        """Whether block ``j`` of this step holds a row the slot attends,
+        and its copy."""
+        b0 = first + j * block_size
+        blk = tables_ref[s, jnp.minimum(b0 // block_size, nb - 1)]
+        return b0 < n, pltpu.make_async_copy(
+            pool_hbm.at[layer, pl.ds(blk * block_size, block_size)],
+            buf.at[pl.ds(j * block_size, block_size)], sem.at[j])
+
+    # only the blocks that hold a row this slot attends are read, each once
+    # for all the heads
+    for j in range(bps * PAGED_STRETCHES):
+        need, cp = copy(j)
+
+        @pl.when(need)
+        def _():
+            cp.start()
+
+    for part in range(PAGED_STRETCHES):
+        start = first + part * rows
+
+        @pl.when(start < n)
+        def _(part=part, start=start):
+            for j in range(part * bps, (part + 1) * bps):
+                need, cp = copy(j)
+
+                @pl.when(need)
+                def _():
+                    cp.wait()
+
+            here = pl.ds(part * rows, rows)
+            kpos = start + jax.lax.broadcasted_iota(
+                jnp.int32, (heads, rows), 1)
+            valid = kpos < n
+            sc = jax.lax.dot_general(
+                q_ref[0], buf[here, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (H, rows)
+            sc = jnp.where(valid, sc, NEG_INF)
+            m_prev = m_sc[...]
+            m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+            l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+            m_sc[...] = m_new
+            pv = p.astype(buf.dtype)
+            for tile in range(rank // LANES):
+                lanes = pl.ds(tile * LANES, LANES)
+                acc_sc[:, lanes] = alpha * acc_sc[:, lanes] + jnp.dot(
+                    pv, buf[here, lanes], preferred_element_type=jnp.float32)
+
+    @pl.when(c == n_steps - 1)
+    def _():
+        inv = 1.0 / jnp.maximum(l_sc[...], 1e-30)
+        for tile in range(rank // LANES):
+            lanes = pl.ds(tile * LANES, LANES)
+            o_ref[0, :, lanes] = (acc_sc[:, lanes] * inv).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "n_steps", "rank", "scale", "interpret"))
+def _paged_latent_call(tables, lens, layer, q, pool, *, block_size, n_steps,
+                       rank, scale, interpret):
+    """The kernel's call: a jitted function of its own with the layer as a
+    prefetched scalar, so the layers of a program share one lowering."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, width = q.shape
+    step_rows = PAGED_ROWS * PAGED_STRETCHES
+    return pl.pallas_call(
+        functools.partial(
+            _paged_latent_kernel, block_size=block_size, n_steps=n_steps,
+            rank=rank, scale=scale),
+        name="paged_latent_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, n_steps),
+            in_specs=[pl.BlockSpec((1, heads, width),
+                                   lambda s, c, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, heads, rank),
+                                   lambda s, c, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((step_rows, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((step_rows // block_size,)),
+                pltpu.VMEM((heads, PAGED_ROWS), jnp.float32),
+                pltpu.VMEM((heads, PAGED_ROWS), jnp.float32),
+                pltpu.VMEM((heads, rank), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, heads, rank), q.dtype),
+        interpret=interpret,
+    )(tables, lens, layer, q, pool)
+
+
+def paged_latent_decode_attention(
+    q_nope: jax.Array,        # (B, H, nope) one query a slot
+    q_rope: jax.Array,        # (B, H, rope), rotated
+    pool: jax.Array,          # (L_group, rows, >= rank + rope) latent rows
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    attend_lens: jax.Array,   # (B,) rows a slot attends, this step's included
+    *,
+    w_uk: jax.Array,          # (rank, H, nope)
+    w_uv: jax.Array,          # (rank, H, v)
+    layer: int,
+    block_size: int,
+    scale: float,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Single-token latent attention in the absorbed form, ``(B, H, v)``:
+    reads only the blocks a slot holds, each once for all the heads.
+
+    The kernel (``name="paged_latent_attn"``; page tables and lengths
+    prefetched into SMEM, the pool left in HBM; grid ``(slot, step)``) copies
+    the needed blocks of a step's 4 x 128 rows into VMEM and folds them, 128
+    rows at a time, into a running softmax: the scores of all ``H`` heads are
+    one ``(H, width) x (width, 128)`` product — the heads are the rows of the
+    left operand, so 32 heads fill the MXU's rows where grouped-query
+    attention brings 8 — and the values are the first ``rank`` lanes of the
+    same rows, a 128-lane tile at a time.  Other shapes and ``impl="xla"``
+    take the plain gather.  Scopes ``absorb``, ``paged_attn``, ``v_up``."""
+    rank, width = w_uk.shape[0], pool.shape[-1]
+    q = _latent_queries(q_nope, q_rope, w_uk, width)
+    lens = attend_lens.astype(jnp.int32)
+    with jax.named_scope("paged_attn"):
+        if paged_latent_formulation(block_size, width, rank, impl) == "plain":
+            o_lat = _plain_latent_decode(
+                q, pool, block_tables, lens, layer=layer,
+                block_size=block_size, rank=rank, scale=scale)
+        else:
+            if interpret is None:
+                interpret = not on_tpu()
+            cap = block_tables.shape[1] * block_size
+            o_lat = _paged_latent_call(
+                block_tables.astype(jnp.int32), lens,
+                jnp.full((1,), layer, jnp.int32), q, pool,
+                block_size=block_size,
+                n_steps=-(-cap // (PAGED_ROWS * PAGED_STRETCHES)),
+                rank=rank, scale=scale, interpret=interpret)
+    return _latent_values(o_lat, w_uv, q_nope.dtype)
+
+
+# ---------------------------------------------------------------------------
+# What a cached row is: the forms a layer group's pools take
+# ---------------------------------------------------------------------------
+#
+# A model's configuration says which (``cfg.cache_rows``); ``serve.kv_cache``
+# makes a group's pools ``widths`` wide (of which ``values`` are stored
+# values, the rest lane padding) and ``serve.model``'s programs write the
+# rows a block hands ``attend`` and read the pages back through the form's
+# formulations.  Each puts its page walk in scope ``paged_attn``.
+
+
+@dataclasses.dataclass(frozen=True)
+class KVRows:
+    """A token's K of all K/V heads in one pool, its V in another; a block
+    calls ``attend(q, k, v)``."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    #: each K/V head has rows of its own
+    shared_row = False
+
+    @property
+    def widths(self) -> tuple[int, ...]:
+        return (self.kv_heads * self.head_dim,) * 2
+
+    values = widths
+
+    def decode_formulation(self, block_size: int, impl: str) -> str:
+        return paged_decode_formulation(
+            self.heads, self.kv_heads, self.head_dim, block_size, impl)
+
+    def chunk(self, q, start, pools, table_row, **kw):
+        with jax.named_scope("paged_attn"):
+            return paged_chunk_attention(q, start, *pools, table_row, **kw)
+
+    def decode(self, q, pools, tables, attend_lens, **kw):
+        with jax.named_scope("paged_attn"):
+            return paged_window_decode_attention(
+                q, *pools, tables, attend_lens, **kw)
+
+    def verify(self, q, pools, tables, attend_lens, **kw):
+        with jax.named_scope("paged_attn"):
+            return paged_verify_attention(
+                q, *pools, tables, attend_lens, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentRows:
+    """One row ``[c_kv | k_rope]`` a token in one pool, shared by every head;
+    a block calls ``attend((q_nope, q_rope), row, w_uk=, w_uv=)`` and gets
+    ``(T, H, v)`` back."""
+
+    rank: int
+    rope_dim: int
+    scale: float
+    #: every head attends the one row
+    shared_row = True
+
+    @property
+    def values(self) -> tuple[int, ...]:
+        return (self.rank + self.rope_dim,)
+
+    @property
+    def widths(self) -> tuple[int, ...]:
+        """The row padded with zeros to whole 128-lane tiles: the TPU lays a
+        576-wide bf16 pool out 640 wide in HBM whatever its shape says
+        (``memref<..x640xbf16, tiled<(8,128)(2,1)>``) and Mosaic slices no
+        part of a tile, so the pad costs nothing the layout had not taken."""
+        return (-(-self.values[0] // LANES) * LANES,)
+
+    def decode_formulation(self, block_size: int, impl: str) -> str:
+        return paged_latent_formulation(
+            block_size, self.widths[0], self.rank, impl)
+
+    def _no_window(self, window):
+        if window is not None:
+            raise ValueError("latent attention over a window is not "
+                             "implemented")
+
+    def chunk(self, q, start, pools, table_row, *, window=None, **kw):
+        self._no_window(window)
+        return paged_latent_chunk_attention(
+            *q, start, *pools, table_row, scale=self.scale, **kw)
+
+    def decode(self, q, pools, tables, attend_lens, *, window=None, **kw):
+        self._no_window(window)
+        return paged_latent_decode_attention(
+            *q, *pools, tables, attend_lens, scale=self.scale, **kw)

@@ -391,6 +391,22 @@ def local_moe(
 
 #: rows of one tile of the grouped matmuls (the bf16 sublane tile)
 GROUP_TILE = 16
+#: rows of a tile where an expert expects more than ``GROUP_TILE`` rows
+#: (timed at 16 / 32 / 64 / 128 on a chunk of 1024 tokens top 8 of 256:
+#: PERF.md section 4)
+GROUP_TILE_WIDE = 64
+
+
+def group_tile(tokens: int, top_k: int, published: int) -> int:
+    """Rows of a tile for a batch of ``tokens``: the grouped kernels read an
+    expert's matrices once a tile, so where uniform routing would give an
+    expert more rows than ``GROUP_TILE`` (a prefill chunk of 1024 tokens top
+    8 of 256: 32) a tile of 16 reads it two or three times, and one of 64
+    holds all an expert's rows but a crowded one's.  Decode batches and
+    chunks that spread thinner keep the small tile, whose padding an expert
+    (``tile - 1`` rows at most) is what the row buffer is sized by."""
+    return (GROUP_TILE if tokens * top_k <= GROUP_TILE * published
+            else GROUP_TILE_WIDE)
 
 
 def sigmoid_topk_route(h: jax.Array, router_kernel: jax.Array,
@@ -503,11 +519,12 @@ def dropless_moe(
     or ``"auto"`` (the kernel on a TPU)."""
     from ..ops import grouped_matmul as gmm
 
+    tile = group_tile(h.shape[0], top_k, router_kernel.shape[-1])
     with jax.named_scope("router"):
         idx, w = sigmoid_topk_route(
             h, router_kernel, select_bias, top_k=top_k,
             route_norm=route_norm, route_scale=route_scale)
-        plan = group_plan(idx, held, token_mask)
+        plan = group_plan(idx, held, token_mask, tile)
     with jax.named_scope("experts"):
         x_rows = jnp.concatenate(
             [h, jnp.zeros((1, h.shape[-1]), h.dtype)])[plan["src"]]
@@ -515,12 +532,12 @@ def dropless_moe(
             y_rows = gmm.grouped_swiglu(
                 x_rows, experts["w_gate"], experts["w_up"],
                 experts["w_down"], plan["tile_expert"], plan["tiles_used"],
-                tile=GROUP_TILE)
+                tile=tile)
         else:
             y_rows = _grouped_swiglu_xla(
                 x_rows, experts["w_gate"], experts["w_up"],
                 experts["w_down"], plan["tile_expert"], plan["tiles_used"],
-                GROUP_TILE)
+                tile)
         y_rows = jnp.concatenate(
             [y_rows, jnp.zeros((1, y_rows.shape[-1]), y_rows.dtype)])
         picked = y_rows[plan["dest"]].astype(jnp.float32)      # (T, k, d)

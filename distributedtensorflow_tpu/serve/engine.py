@@ -340,6 +340,8 @@ class Engine:
         self.programs = make_programs(
             self.cfg, chunk=prefill_chunk, block_size=block_size,
             layers=self.kv.layers)
+        if self.prefix_cache:
+            self.programs.check_prefix_cache()
         #: the newest decode iteration's expert-routing counters (None
         #: from programs without expert layers): pairs on held experts,
         #: held experts hit (both summed over the expert layers), largest
@@ -412,6 +414,11 @@ class Engine:
         self._step_evicted = 0     # requests finished in the current step
         #: the current step's (device_sampled, logits_fetched)
         self._step_sampled = (0, 0)
+        #: the current step's [context_tokens, latent_rows_read]: the rows a
+        #: prefill chunk's queries walk, summed over the step's chunks, and
+        #: the latent rows its decode iteration read (attended tokens x
+        #: latent layers); counted only where a group stores latent rows
+        self._step_latent = [0, 0]
         #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
         #: and closes its profiler windows by iteration, so a capture
         #: armed through ``POST /profilez?steps=N`` holds N iterations.
@@ -749,6 +756,7 @@ class Engine:
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
         self._step_sampled = (0, 0)
+        self._step_latent = [0, 0]
         # The iteration is one span tree (mirrored into any open profiler
         # trace): the step record's walls are its durations, and the
         # `step` attribute is the steps.jsonl `step` this iteration gets.
@@ -862,13 +870,21 @@ class Engine:
     def _group_step_fields(self, occupancy: int) -> dict:
         """Step-log fields of the layer groups and the expert layers:
         each pool's blocks in use, the blocks a window group let go since
-        the last record, and (decode iterations of programs with expert
-        layers only) this iteration's routing counters."""
+        the last record, (decode iterations of programs with expert
+        layers only) this iteration's routing counters, and (a cache with
+        latent rows only) the rows its prefill chunks walked and its
+        decode iteration read."""
         fields = {}
         if occupancy and self._routed is not None:
             pairs, hit, load = (int(v) for v in np.asarray(self._routed))
             fields.update(moe_pairs=pairs, moe_experts_hit=hit,
                           moe_max_load=load)
+        if self.kv.latent_layers:
+            context, read = self._step_latent
+            if context:
+                fields["context_tokens"] = context
+            if occupancy:
+                fields["latent_rows_read"] = read
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
@@ -1040,6 +1056,8 @@ class Engine:
                 last_ix,
             )
             self.kv.set_pools(pools)
+            if self.kv.latent_layers:
+                self._step_latent[0] += start + c
             req._fill_next = start + c
             self.kv.note_written(
                 slot, max(min(start + c, len(req.prompt)),
@@ -1143,6 +1161,9 @@ class Engine:
                 tokens[j] = self._sample(req, logits[req.slot])
             self._note_sampled(n_active - len(sampling), bool(sampling))
             self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
+            if self.kv.latent_layers:
+                self._step_latent[1] = self.kv.latent_layers * int(
+                    self.kv.seq_lens[slots].sum())
             self._commit_tokens(
                 decoding, slots, [[t] for t in tokens.tolist()], now,
                 decode_dt, prefill_s, spec=False)
@@ -1617,8 +1638,11 @@ class Engine:
             "prefix_cache": self.prefix_cache,
             "fused_sampling": self.fused_sampling,
             "speculate": self.speculate,
-            # "paged_attn" or "plain" (serve.model): the fallback is silent
+            # "paged_attn", "paged_latent_attn" or "plain" (serve.model):
+            # the fallback is silent
             "decode_attention": self.programs.decode_attention,
+            # bytes the cache stores a token over all layers
+            "cache_row_bytes": self.kv.row_bytes,
             "spec_acceptance_rate": (
                 self.counters["spec_accepted"] / self.counters["spec_drafted"]
                 if self.counters["spec_drafted"] else 0.0

@@ -63,16 +63,23 @@ steps.  Single-writer by design: only the engine loop thread touches a
 there are no locks here.
 
 Stored form (:func:`pool_shape`): ``(num_layers, (num_blocks + 1) *
-block_size, kv_heads * head_dim)`` per pool — **token rows**, the heads
-folded into the minor dimension, one stacked array for all layers so the
-decode program indexes layers without a pytree of leaves.  Physical block
-``b`` is rows ``[b * block_size, (b + 1) * block_size)``.  This is the one
-form every program that takes the pool computes in (``serve.model``'s
-prefill chunk, decode, fused decode and cache gather; the block copy
-below), and none of them reshapes it: a K/V write scatters ``(tokens,
-kv_heads * head_dim)`` rows at ``block * block_size + offset``, the
-page-table walk gathers whole blocks of rows, and the heads are split only
-on what was gathered.
+block_size, row width)`` per pool — **token rows**, one stacked array for
+all layers so the decode program indexes layers without a pytree of leaves.
+A group states the rows it stores a token a layer (``rows``, the model's
+``cfg.cache_rows``; one pool a width of ``rows.widths``): the K/V pair, two
+pools of ``kv_heads * head_dim`` with the heads folded into the minor
+dimension; or
+one pool of latent rows ``[c_kv | k_rope]`` that every head shares
+(``models.joyai``: 512 + 64 values = 1,152 bytes in bf16, stored 640 wide —
+five lane tiles, the last 64 lanes zero — because the TPU lays a 576-wide
+bf16 array out 640 wide whatever its shape says and Mosaic slices no part of
+a tile; there is no V pool).  Physical block ``b`` is rows ``[b *
+block_size, (b + 1) * block_size)``.  This is the one form every program
+that takes a pool computes in (``serve.model``'s prefill chunk, decode and
+fused decode; the block copy below), and none of them reshapes it: a write
+scatters ``(tokens, row width)`` rows at ``block * block_size + offset``,
+the page-table walk gathers whole blocks of rows, and the heads are split
+only on what was gathered.
 
 Why rows and not ``(..., block_size, kv_heads, head_dim)``: on the TPU an
 array lives in (8, 128) tiles of its two minor dimensions, a 64-wide
@@ -115,10 +122,10 @@ class OutOfBlocksError(RuntimeError):
 
 
 def pool_shape(num_layers: int, num_blocks: int, block_size: int,
-               kv_heads: int, head_dim: int) -> tuple[int, int, int]:
-    """Shape of one K or V pool of ``num_blocks`` blocks plus the scratch
-    block: token rows of all heads (module docstring, "Stored form")."""
-    return (num_layers, (num_blocks + 1) * block_size, kv_heads * head_dim)
+               width: int) -> tuple[int, int, int]:
+    """Shape of one pool of ``num_blocks`` blocks plus the scratch block:
+    token rows ``width`` wide (module docstring, "Stored form")."""
+    return (num_layers, (num_blocks + 1) * block_size, width)
 
 
 @functools.lru_cache(maxsize=None)
@@ -136,9 +143,9 @@ def _copy_block_fn(block_size: int):
         return jax.lax.dynamic_update_slice_in_dim(pool, rows,
                                                    dst * block_size, axis=1)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def copy_block(k_pool, v_pool, src, dst):
-        return copy_rows(k_pool, src, dst), copy_rows(v_pool, src, dst)
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def copy_block(pools, src, dst):
+        return tuple(copy_rows(pool, src, dst) for pool in pools)
 
     return copy_block
 
@@ -297,17 +304,23 @@ class SlotPages:
 class PagedKVCache:
     """Block-pool KV storage for ``max_slots`` concurrent sequences.
 
-    Device arrays (``k_pool``/``v_pool``, each :func:`pool_shape`: token
-    rows of all heads) are created once and threaded functionally through
+    Device arrays (``pools``: one array a stored row, each
+    :func:`pool_shape`) are created once and threaded functionally through
     the serving programs, which donate them and update them in place; the
     engine assigns the updated arrays back after every call.  Host state
     (page tables, lengths, the prefix index) advances in lockstep on the
     engine thread.
+
+    ``rows`` states what the group stores a token a layer (``ops.attention``:
+    a ``KVRows`` or a ``LatentRows``, the model's ``cfg.cache_rows``): one
+    pool a width of ``rows.widths``, of which ``rows.values`` are stored
+    values (the rest zeros that pad a row to whole lane tiles), and whether
+    every head shares the one row (``rows.shared_row``).
     """
 
-    def __init__(self, *, num_layers: int, kv_heads: int, head_dim: int,
-                 max_slots: int, num_blocks: int, block_size: int,
-                 max_context: int, dtype=jnp.float32):
+    def __init__(self, *, num_layers: int, rows, max_slots: int,
+                 num_blocks: int, block_size: int, max_context: int,
+                 dtype=jnp.float32):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         if max_context % block_size:
@@ -321,10 +334,10 @@ class PagedKVCache:
         self.blocks_per_slot = max_context // block_size
         self.scratch_block = num_blocks  # reserved physical block
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
-        shape = pool_shape(num_layers, num_blocks, block_size, kv_heads,
-                           head_dim)
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
+        self.rows = rows
+        self.pools = tuple(
+            jnp.zeros(pool_shape(num_layers, num_blocks, block_size, width),
+                      dtype) for width in rows.widths)
         # Unallocated entries point at the scratch block (always a legal
         # physical index; reads through it are masked by seq_lens).
         self.block_tables = np.full(
@@ -359,6 +372,12 @@ class PagedKVCache:
         self.prefix_hits = 0
         self.prefix_cached_tokens = 0
         self.cow_copies = 0
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes of values the group stores a token a layer, over its pools
+        (lane padding not counted: ``rows.widths`` has it)."""
+        return sum(self.rows.values) * self.pools[0].dtype.itemsize
 
     def _on_evict(self, block: int) -> None:
         h = self._block_hash.pop(block, None)
@@ -519,9 +538,8 @@ class PagedKVCache:
                     "block but the pool is exhausted"
                 )
             dst = fresh[0]
-            self.k_pool, self.v_pool = _copy_block_fn(self.block_size)(
-                self.k_pool, self.v_pool, jnp.int32(b), jnp.int32(dst)
-            )
+            self.pools = _copy_block_fn(self.block_size)(
+                self.pools, jnp.int32(b), jnp.int32(dst))
             self.allocator.decref(b)
             pages.blocks[li] = dst
             self.block_tables[slot, li] = dst
@@ -810,6 +828,11 @@ class GroupedKVCache:
         #: the one group whose prefix index this cache shares through, or
         #: None: no block of any group is then ever shared between slots
         self._sharing = first if len(groups) == 1 else None
+        #: layers whose group stores one row a token that every head shares,
+        #: not the K/V pair
+        self.latent_layers = sum(
+            len(layers[name]) for name, g in groups.items()
+            if g.rows.shared_row)
 
     @property
     def shares_prefixes(self) -> bool:
@@ -834,15 +857,20 @@ class GroupedKVCache:
         return sum(g.cow_copies for g in self.groups.values())
 
     def pools(self) -> dict:
-        """``{group: (k_pool, v_pool)}``, as the programs take them (and
-        donate them: hand the updated ones back through ``set_pools``)."""
-        return {name: (g.k_pool, g.v_pool)
-                for name, g in self.groups.items()}
+        """``{group: its pools}`` (``(k_pool, v_pool)``, or the one pool of
+        latent rows), as the programs take them (and donate them: hand the
+        updated ones back through ``set_pools``)."""
+        return {name: g.pools for name, g in self.groups.items()}
 
     def set_pools(self, pools: dict) -> None:
-        for name, (k_pool, v_pool) in pools.items():
-            g = self.groups[name]
-            g.k_pool, g.v_pool = k_pool, v_pool
+        for name, group_pools in pools.items():
+            self.groups[name].pools = tuple(group_pools)
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes stored a token over all layers of all groups."""
+        return sum(g.row_bytes * len(self.layers[name])
+                   for name, g in self.groups.items())
 
     def check_fits(self, tokens: int) -> None:
         """Raise ``ValueError`` if no pool state could ever hold a request
@@ -927,7 +955,9 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                        write_ahead: int) -> GroupedKVCache:
     """The groups of a model: a ``"full"`` and a ``"window"`` group,
     whichever exist, by the attention kind its config names for a layer
-    (``cfg.window_of(layer)``; GPT-2 is one full group).
+    (``cfg.window_of(layer)``; GPT-2 is one full group), each storing the
+    rows the config names (``cfg.cache_rows``: the K/V pair, or one latent
+    row).
     ``num_blocks[name] = None`` provisions every slot's worst
     case (full provisioning; fewer oversubscribes — paged memory is the
     point — and admission control, not OOM, then absorbs the pressure)."""
@@ -944,10 +974,9 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                    if (cfg.window_of(i) is not None) == is_window)
         if not ls:
             continue
-        kw = dict(num_layers=len(ls), kv_heads=cfg.kv_heads,
-                  head_dim=cfg.head_dim, max_slots=max_slots,
-                  block_size=block_size, max_context=max_context,
-                  dtype=cfg.dtype)
+        kw = dict(num_layers=len(ls), rows=cfg.cache_rows,
+                  max_slots=max_slots, block_size=block_size,
+                  max_context=max_context, dtype=cfg.dtype)
         if is_window:
             window = cfg.window_of(ls[0])
             ring = -(-(window + write_ahead) // block_size) + 1

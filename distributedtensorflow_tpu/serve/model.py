@@ -9,20 +9,27 @@ batch to the same phase; splitting them lets the scheduler admit a new
 prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 
-A family is a module of layer functions (``models.gpt``, ``models.afmoe``):
-``embed(params, ids, cfg)``, ``block(p, x, cfg, layer, positions, attend,
-token_mask=None) -> (x, counters)`` and ``head(params, x, cfg)``, over
-activations ``(T, d)``, plus ``init_params(cfg, key)``.  A program is the
-family's embedding, its blocks and its head, with an ``attend(q, k, v)``
-that writes the new K/V rows into the layer's group pool and reads the
-slot's pages back — so the block is written once a family, and the three
-programs differ only in where K/V live:
+A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
+``models.joyai``): ``embed(params, ids, cfg)``, ``block(p, x, cfg, layer,
+positions, attend, token_mask=None) -> (x, counters)`` and ``head(params, x,
+cfg)``, over activations ``(T, d)``, plus ``init_params(cfg, key)``.  Its
+configuration says what a cached row is (``cfg.cache_rows``, an
+``ops.attention.KVRows`` or ``LatentRows``: the widths of the group's pools
+and the paged formulations over them), and its block calls ``attend(q,
+*rows, **weights)`` with the rows to store, one a pool: ``attend(q, k, v)``
+where a token's K and V of all heads are cached, ``attend((q_nope, q_rope),
+row, w_uk=, w_uv=)`` where one latent row is and the query comes in two
+parts.  A program is the family's embedding, its blocks and its head, with an
+``attend`` that writes the rows into the layer's group pools and reads the
+slot's pages back through the form — so the block is written once a family,
+and the three programs differ only in where the rows live:
 
 - :func:`make_prefill_fn` — one ``prefill_chunk``-wide slice of one
-  prompt; the chunk's K/V go straight to the slot's pool blocks and its
+  prompt; the chunk's rows go straight to the slot's pool blocks and its
   queries attend the slot's earlier chunks through the page-table row
-  (``ops.attention.paged_chunk_attention``, a running softmax over the
-  context up to the chunk's end).  There is no dense cache, so a chunk is
+  (``ops.attention.paged_chunk_attention`` or
+  ``paged_latent_chunk_attention``, a running softmax over the context up to
+  the chunk's end).  There is no dense cache, so a chunk is
   *stateless*: any slot's next chunk can run at any time, the scheduler can
   interleave chunks of several requests with decode steps (ISSUE 14
   budgeted prefill), and a request admitted onto a cached prefix starts
@@ -30,8 +37,9 @@ programs differ only in where K/V live:
   a Python loop of these fixed-width calls; the head is applied to the one
   row the engine wants.
 - :func:`make_decode_fn` — one token for all ``max_slots`` slots against
-  the paged pool (``ops.attention.paged_window_decode_attention``: on the
-  TPU a kernel that reads only the blocks a slot holds).
+  the paged pool (``ops.attention.paged_window_decode_attention`` or
+  ``paged_latent_decode_attention``: on the TPU a kernel that reads only the
+  blocks a slot holds).
 - :func:`make_fused_decode_fn` — the decode fast path: ``draft + 1`` tokens
   a slot, K/V append, multi-token attention
   (``ops.attention.paged_verify_attention``) and sampling in one dispatch.
@@ -39,14 +47,16 @@ programs differ only in where K/V live:
 (There is also a tiny pool-level block-copy program in ``serve.kv_cache``
 — the copy-on-write path — compiled only if a CoW ever fires.)
 
-``pools`` is ``{group: (k_pool, v_pool)}`` and ``tables`` ``{group: page
+``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, or the one pool
+of latent rows) and ``tables`` ``{group: page
 table}`` (``serve.kv_cache.GroupedKVCache``: layers in groups by attention
 kind; GPT-2 is one full group); ``layers`` maps a group to the model layers
 it holds, in pool order.  The pools are donated: steady-state serving does
 not allocate.  All of these programs take a pool in the one form
 ``serve.kv_cache`` stores it in — ``(layers, (num_blocks + 1) * block_size,
-Hkv * D)``, token rows with the heads folded into the minor dimension — and
-none reshapes it: a K/V write scatters ``(tokens, Hkv * D)`` rows at
+row width)``, token rows (``Hkv * D``: the heads folded into the minor
+dimension; or a latent row's five lane tiles) — and
+none reshapes it: a write scatters ``(tokens, row width)`` rows at
 ``block * block_size + offset``, the page-table walk gathers whole blocks
 of rows with the layer as an index of the same gather, and heads are split
 only on what was gathered.  So the donated input aliases the output and XLA
@@ -59,9 +69,11 @@ pool rows the new tokens go to, a group: the same in every layer of it, so
 computed once — a scatter that works its rows out itself costs GPT-2
 medium's ``jit_decode`` 0.23 ms of 3.24 over 24 layers; my chip run, PR 30),
 ``embed``, per layer ``h<i>/{ln,qkv,kv_write,paged_attn,proj,mlp}``
-(``kv_write`` and ``paged_attn`` are the programs' own, siblings, under
-whatever scope the family's block calls ``attend`` in: ``h<i>`` for GPT-2,
-``h<i>/window_attn`` or ``h<i>/full_attn`` for afmoe), ``head``, ``sample``,
+(``kv_write`` is the programs' own and ``paged_attn`` the form's, siblings,
+under whatever scope the family's block calls ``attend`` in: ``h<i>`` for
+GPT-2, ``h<i>/window_attn`` or ``h<i>/full_attn`` for afmoe,
+``h<i>/latent_attn`` for joyai, whose form adds ``absorb`` and ``v_up``
+beside them), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
 Metadata only, so a profiler trace can say which stage a device operation
 belongs to.
@@ -74,13 +86,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, gpt
-from ..ops.attention import (
-    paged_chunk_attention,
-    paged_decode_formulation,
-    paged_verify_attention,
-    paged_window_decode_attention,
-)
+from ..models import afmoe, gpt, joyai
 from .sampling import sample_burst
 
 __all__ = [
@@ -97,6 +103,14 @@ def _group_of(layers: dict[str, tuple[int, ...]]) -> dict[int, tuple]:
             for i, layer in enumerate(ls)}
 
 
+def _write_rows(pools: tuple, li: int, at, rows: tuple) -> tuple:
+    """The group's pools with ``rows`` (one array a pool, a row a token)
+    written at pool rows ``at`` of layer ``li``."""
+    with jax.named_scope("kv_write"):
+        return tuple(pool.at[li, at].set(r.reshape(at.shape[0], -1))
+                     for pool, r in zip(pools, rows))
+
+
 def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                     layers: dict[str, tuple[int, ...]]):
     """``fn(params, pools, tokens (chunk,), start, table_rows, last_ix) ->
@@ -105,7 +119,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     group and ``last_ix`` the in-chunk index whose logits the engine wants
     (the final prompt token's, clamped into range on non-final chunks whose
     logits are discarded).  The pools are donated."""
-    where = _group_of(layers)
+    where, form = _group_of(layers), cfg.cache_rows
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, pools, tokens, start, table_rows, last_ix):
@@ -119,18 +133,12 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
         for layer in range(cfg.num_layers):
             name, li = where[layer]
 
-            def attend(q, k, v, name=name, li=li, layer=layer):
-                k_pool, v_pool = pools[name]
-                with jax.named_scope("kv_write"):
-                    k_pool = k_pool.at[li, rows[name]].set(
-                        k.reshape(chunk, -1))
-                    v_pool = v_pool.at[li, rows[name]].set(
-                        v.reshape(chunk, -1))
-                pools[name] = (k_pool, v_pool)
-                with jax.named_scope("paged_attn"):
-                    return paged_chunk_attention(
-                        q, start, k_pool, v_pool, table_rows[name], layer=li,
-                        block_size=block_size, window=cfg.window_of(layer))
+            def attend(q, *stored, name=name, li=li, layer=layer, **weights):
+                pools[name] = _write_rows(pools[name], li, rows[name], stored)
+                return form.chunk(
+                    q, start, pools[name], table_rows[name], layer=li,
+                    block_size=block_size, window=cfg.window_of(layer),
+                    **weights)
 
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
@@ -158,7 +166,7 @@ def make_decode_fn(family, cfg, *, block_size: int,
     landed on held experts (sum), the held experts hit (sum) and the largest
     load of one expert (max) — active slots only; None from a model without
     expert layers."""
-    where = _group_of(layers)
+    where, form = _group_of(layers), cfg.cache_rows
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, pools, tokens, tables, seq_lens, active):
@@ -179,19 +187,12 @@ def make_decode_fn(family, cfg, *, block_size: int,
         for layer in range(cfg.num_layers):
             name, li = where[layer]
 
-            def attend(q, k, v, name=name, li=li, layer=layer):
-                k_pool, v_pool = pools[name]
-                with jax.named_scope("kv_write"):
-                    k_pool = k_pool.at[li, rows[name]].set(
-                        k.reshape(k.shape[0], -1))
-                    v_pool = v_pool.at[li, rows[name]].set(
-                        v.reshape(v.shape[0], -1))
-                pools[name] = (k_pool, v_pool)
-                with jax.named_scope("paged_attn"):
-                    return paged_window_decode_attention(
-                        q, k_pool, v_pool, tables[name], attend_lens,
-                        layer=li, block_size=bs,
-                        window=cfg.window_of(layer), impl=cfg.kernel_impl)
+            def attend(q, *stored, name=name, li=li, layer=layer, **weights):
+                pools[name] = _write_rows(pools[name], li, rows[name], stored)
+                return form.decode(
+                    q, pools[name], tables[name], attend_lens, layer=li,
+                    block_size=bs, window=cfg.window_of(layer),
+                    impl=cfg.kernel_impl, **weights)
 
             with jax.named_scope(f"h{layer}"):
                 x, counters = family.block(
@@ -249,7 +250,7 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
     produced (parity pinned by tests/test_serve_spec.py, incl. bf16).
     ``paged_verify_attention`` masks no window: full layers only.
     """
-    where = _group_of(layers)
+    where, form = _group_of(layers), cfg.cache_rows
     t_width = draft + 1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -283,19 +284,12 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
         for layer in range(cfg.num_layers):
             name, li = where[layer]
 
-            def attend(q, k, v, name=name, li=li):
-                k_pool, v_pool = pools[name]
-                with jax.named_scope("kv_write"):
-                    k_pool = k_pool.at[li, rows[name]].set(
-                        k.reshape(b * t_width, -1))
-                    v_pool = v_pool.at[li, rows[name]].set(
-                        v.reshape(b * t_width, -1))
-                pools[name] = (k_pool, v_pool)
-                with jax.named_scope("paged_attn"):
-                    return paged_verify_attention(
-                        q.reshape(b, t_width, *q.shape[1:]), k_pool, v_pool,
-                        tables[name], attend_lens, layer=li, block_size=bs,
-                    ).reshape(q.shape)
+            def attend(q, *stored, name=name, li=li):
+                pools[name] = _write_rows(pools[name], li, rows[name], stored)
+                return form.verify(
+                    q.reshape(b, t_width, *q.shape[1:]), pools[name],
+                    tables[name], attend_lens, layer=li, block_size=bs,
+                ).reshape(q.shape)
 
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(
@@ -335,14 +329,23 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
 PROGRAMS = {
     gpt.GPTConfig: gpt,
     afmoe.AfmoeConfig: afmoe,
+    joyai.JoyaiConfig: joyai,
 }
 
 #: the families served through the fused and verify programs: those whose
 #: tokens the parity tests of tests/test_serve_spec.py pin to the one-token
-#: path's.  Another family's would run unchecked (and afmoe's window layers
-#: not at all: ``paged_verify_attention`` masks no window), so it is refused
-#: until it has such tests and a cell of its own.
+#: path's.  Another family's would run unchecked (afmoe's window layers not
+#: at all: ``paged_verify_attention`` masks no window; joyai's draft module,
+#: which predicts several tokens for self-speculation, is not built and its
+#: latent rows have no verify formulation), so it is refused until it has
+#: such tests and a cell of its own.
 FUSED = (gpt,)
+
+#: the families a request may be admitted for onto cached prefix blocks: those
+#: with a test that holds such a request to the uncached logits
+#: (tests/test_serve.py).  (A cache of several layer groups shares no prefixes
+#: whatever the family: ``serve.engine``.)
+PREFIX = (gpt, afmoe)
 
 
 def family_of(cfg):
@@ -366,10 +369,10 @@ class Programs:
       (:func:`make_fused_decode_fn`), or a ``ValueError`` that says it is
       not implemented;
     - ``decode_attention``: the formulation the decode programs in use
-      attend the pages with, ``"paged_attn"`` (the kernel that reads only
-      the blocks a slot holds) or ``"plain"`` (the gather of every table
-      column): the fallback is silent, so the engine reports it
-      (``Engine.state()``)."""
+      attend the pages with, ``"paged_attn"`` or ``"paged_latent_attn"``
+      (the kernel that reads only the blocks a slot holds, over K/V rows or
+      latent rows) or ``"plain"`` (the gather of every table column): the
+      fallback is silent, so the engine reports it (``Engine.state()``)."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,
                  layers: dict[str, tuple[int, ...]]):
@@ -387,10 +390,8 @@ class Programs:
         # for them, and they attend with ``paged_verify_attention``
         if self._fused:
             return "plain"
-        cfg = self.cfg
-        return paged_decode_formulation(
-            cfg.num_heads, cfg.kv_heads, cfg.head_dim, self.block_size,
-            cfg.kernel_impl)
+        return self.cfg.cache_rows.decode_formulation(
+            self.block_size, self.cfg.kernel_impl)
 
     def prefill(self, params, pools, tokens, start: int, table_rows,
                 last_ix: int):
@@ -398,13 +399,23 @@ class Programs:
                              jnp.int32(start), table_rows,
                              jnp.int32(last_ix))
 
+    def _refuse(self, option: str, lacking: str):
+        raise ValueError(
+            f"{option} is not implemented for the "
+            f"{self.family.__name__.rsplit('.', 1)[-1]} family yet "
+            f"({lacking}): serve it without")
+
+    def check_prefix_cache(self) -> None:
+        """A ``ValueError`` if this family is not served with shared
+        prefixes."""
+        if self.family not in PREFIX:
+            self._refuse("prefix_cache", "no test holds a request admitted on "
+                         "cached blocks of its rows to the uncached logits")
+
     def fused(self, draft: int):
         if self.family not in FUSED:
-            raise ValueError(
-                f"{'speculate' if draft else 'fused_sampling'} is not "
-                f"implemented for the {self.family.__name__.rsplit('.', 1)[-1]}"
-                " family yet (no parity tests of its fused and verify "
-                "programs): serve it without")
+            self._refuse("speculate" if draft else "fused_sampling",
+                         "no parity tests of its fused and verify programs")
         self._fused = True
         return make_fused_decode_fn(
             self.family, self.cfg, block_size=self.block_size,

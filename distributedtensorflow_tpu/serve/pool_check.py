@@ -3,7 +3,8 @@
 The pool is the largest thing a serving process holds on the device, and
 five compiled programs take it, all of which return it: ``prefill_chunk``,
 ``decode``, ``fused_decode`` at T = 1 and at T > 1 (``serve.model``, built
-as the engine builds them: ``make_programs``) and ``copy_block``.  If the form the pool is stored in is not the form a
+as the engine builds them: ``make_programs``; a family that is not served
+through the fused programs has three) and ``copy_block``.  If the form the pool is stored in is not the form a
 program computes in, XLA converts all of it on the way in and back on the
 way out, on every call — nothing fails, a decode step is just a third
 slower and a prefill chunk forty times (PERF.md §5, PR 25).  This module
@@ -36,12 +37,14 @@ import jax
 import jax.numpy as jnp
 
 from . import kv_cache
-from .model import family_of, make_programs
+from .model import FUSED, family_of, make_programs
 
 #: A pool among a compiled module's arguments, as the family programs take
-#: it: ``pools['full'][0]`` is the group's ``k_pool``, ``[1]`` its ``v_pool``
-#: (``copy_block`` takes them under those names).
-_POOLS_ARG = re.compile(r"^pools\[.*\]\[([01])\]$")
+#: it: ``pools['full'][0]`` is the group's ``k_pool`` (or its one pool of
+#: latent rows), ``[1]`` its ``v_pool`` (``copy_block`` takes ``pools[0]``,
+#: ``pools[1]``).
+_POOLS_ARG = re.compile(r"^pools(?:\[.*\])?\[([01])\]$")
+_POOL_NAMES = ("k_pool", "v_pool")
 
 _RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
 _INSTRUCTION = re.compile(
@@ -63,9 +66,10 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda: family_of(cfg).init_params(
             cfg, jax.random.PRNGKey(0))))
-    pool = sds(kv_cache.pool_shape(cfg.num_layers, num_blocks, block_size,
-                                   cfg.kv_heads, cfg.head_dim), cfg.dtype)
-    pools = {"full": (pool, pool)}
+    pools = {"full": tuple(
+        sds(kv_cache.pool_shape(cfg.num_layers, num_blocks, block_size,
+                                width), cfg.dtype)
+        for width in cfg.cache_rows.widths)}
     table_rows = {"full": sds((cfg.max_seq // block_size,), i32)}
     tables = {"full": sds((max_slots, cfg.max_seq // block_size), i32)}
     slots_i32 = sds((max_slots,), i32)
@@ -79,19 +83,22 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
                 tables, slots_i32, active, sds((max_slots, 2), jnp.uint32),
                 slots_i32, sds((max_slots,), jnp.float32), slots_i32)
 
-    return {
+    found = {
         "prefill_chunk": (
             programs.prefill_chunk,
             (params, pools, sds((chunk,), i32), scalar, table_rows, scalar)),
         "decode": (
             programs.decode,
             (params, pools, slots_i32, tables, slots_i32, active)),
-        "fused_decode": (programs.fused(0), fused_args(1)),
-        "fused_decode_spec": (programs.fused(draft), fused_args(draft + 1)),
         "copy_block": (
             kv_cache._copy_block_fn(block_size),
-            (pool, pool, scalar, scalar)),
+            (pools["full"], scalar, scalar)),
     }
+    if programs.family in FUSED:      # the others are refused them
+        found["fused_decode"] = (programs.fused(0), fused_args(1))
+        found["fused_decode_spec"] = (programs.fused(draft),
+                                      fused_args(draft + 1))
+    return found
 
 
 def pool_relayouts(hlo_text: str, layer_elems: int) -> list[str]:
@@ -131,7 +138,7 @@ def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
         name = m.group(3)
         pool = _POOLS_ARG.match(name)
         if pool:
-            name = ("k_pool", "v_pool")[int(pool.group(1))]
+            name = _POOL_NAMES[int(pool.group(1))]
         params[int(m.group(2))] = (name, m.group(1))
     return params
 
@@ -167,13 +174,14 @@ def check_pool_programs(programs: dict, layer_elems: int) -> dict:
     return report
 
 
-def failures(report: dict) -> list[str]:
-    """What :func:`check_pool_programs` found wrong, one line each."""
+def failures(report: dict, pools: int = 2) -> list[str]:
+    """What :func:`check_pool_programs` found wrong, one line each, for a
+    group of ``pools`` pools (the K/V pair, or one pool of latent rows)."""
     bad = []
     for name, r in report.items():
         for op in r["relayouts"]:
             bad.append(f"{name}: pool-sized {op}")
-        if r["donated"] != ["k_pool", "v_pool"]:
+        if r["donated"] != list(_POOL_NAMES[:pools]):
             bad.append(f"{name}: donated in place only {r['donated']}")
     forms = {r["k_pool"] for r in report.values()}
     if len(forms) != 1:
@@ -198,8 +206,9 @@ def main(argv=None) -> int:
 
     cfg = dataclasses.replace(getattr(models, args.config)(),
                               max_seq=args.max_context)
+    widths = cfg.cache_rows.widths
     shape = kv_cache.pool_shape(cfg.num_layers, args.kv_blocks,
-                                args.block_size, cfg.kv_heads, cfg.head_dim)
+                                args.block_size, widths[0])
     report = check_pool_programs(
         pool_programs(cfg, max_slots=args.max_slots,
                       num_blocks=args.kv_blocks, block_size=args.block_size,
@@ -207,7 +216,7 @@ def main(argv=None) -> int:
         layer_elems=shape[1] * shape[2])
     # the pool as the process holds it between calls
     pool = jnp.zeros(shape, cfg.dtype)
-    bad = failures(report)
+    bad = failures(report, pools=len(widths))
     print(json.dumps({
         "device": runtime.device_summary(),
         "pool_shape": shape, "pool_dtype": str(pool.dtype),

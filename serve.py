@@ -45,6 +45,9 @@ CONFIGS = {
     # the afmoe family (models/afmoe.py): random init only, no trainer yet
     "afmoe_tiny": ("afmoe_tiny", None),
     "trinity_large_ep8": ("trinity_large_ep8", None),
+    # the joyai family (models/joyai.py): latent attention, random init only
+    "joyai_tiny": ("joyai_tiny", None),
+    "joyai_llm_flash": ("joyai_llm_flash", None),
 }
 
 
@@ -299,7 +302,8 @@ def main(argv=None) -> int:
         capture=capture,
     ).start()
     startup.mark("startup.engine_build",
-                 decode_attention=engine.programs.decode_attention)
+                 decode_attention=engine.programs.decode_attention,
+                 cache_row_bytes=engine.kv.row_bytes)
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation
     # endpoint (text / ?json / ?tenant= filter; usage.jsonl under

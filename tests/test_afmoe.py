@@ -251,26 +251,56 @@ def test_bfloat16_preset_serves_finite_logits_near_the_reference():
 
 # (b) the share
 
-def test_the_shares_sum_to_the_uncut_layer(f32_model):
-    """Every share's held-expert terms, plus the shared expert once, are
-    the reference's uncut layer (16 experts in 4 shares of 4)."""
-    _, params = f32_model
+def _joyai_layer():
+    """The joyai family's expert layer at top 8 of 16, its reference's uncut
+    layer, and the layer as that family calls it: every expert held."""
+    from distributedtensorflow_tpu.models import joyai
+
+    path = os.path.join(ROOT, "benchmark", "reference", "joyai.py")
+    spec = importlib.util.spec_from_file_location("ref_joyai", path)
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    cfg = joyai.joyai_tiny(dtype=jnp.float32, experts_per_token=8)
+    p = joyai.init_params(cfg, jax.random.PRNGKey(5), std=0.2)["h1"]["moe"]
+    config = dict(n_routed_experts=16, num_experts_per_tok=8, n_group=1,
+                  norm_topk_prob=True, routed_scaling_factor=cfg.route_scale)
+    return cfg, p, lambda h: ref._swiglu(p["shared"], h) + ref._experts(
+        p, h, config)
+
+
+def _afmoe_layer():
     cfg = afmoe.afmoe_tiny(dtype=jnp.float32, experts_held=None,
                            expert_first=0)
-    whole = afmoe.init_params(cfg, jax.random.PRNGKey(5), std=0.2)
-    p = whole["h1"]["moe"]
+    p = afmoe.init_params(cfg, jax.random.PRNGKey(5), std=0.2)["h1"]["moe"]
+    config = _config_dict(cfg)
+    return cfg, p, lambda h: (REF._swiglu(p["shared"], h[None])
+                              + REF._experts(p, h[None], config))[0]
+
+
+@pytest.mark.parametrize("layer", [_afmoe_layer, _joyai_layer],
+                         ids=["afmoe-top4", "joyai-top8"])
+def test_the_shares_sum_to_the_uncut_layer(layer):
+    """Every share's held-expert terms, plus the shared expert once, are
+    the reference's uncut layer (16 experts in 4 shares of 4), and so is
+    the layer told it holds them all (``held=(0, E)``: joyai's call)."""
+    cfg, p, uncut = layer()
+    k = cfg.experts_per_token
     h = jax.random.normal(jax.random.PRNGKey(1), (48, cfg.hidden_size))
-    total = afmoe.swiglu(p["shared"], h)
+    shared = afmoe.swiglu(p["shared"], h)
+    total = shared
     for first in range(0, 16, 4):
         share = jax.tree.map(lambda a: a[first:first + 4], p["experts"])
         out, counters = moe.dropless_moe(
-            h, p["router"], p["bias"], share, held=(first, 4), top_k=4,
+            h, p["router"], p["bias"], share, held=(first, 4), top_k=k,
             route_scale=cfg.route_scale, impl="xla")
         total = total + out
-    config = _config_dict(cfg)
-    want = REF._swiglu(p["shared"], h[None]) + REF._experts(p, h[None],
-                                                            config)
-    np.testing.assert_allclose(total, want[0], atol=1e-4, rtol=0)
+    whole, counters = moe.dropless_moe(
+        h, p["router"], p["bias"], p["experts"], held=(0, 16), top_k=k,
+        route_scale=cfg.route_scale, impl="xla")
+    assert int(counters["pairs"]) == 48 * k
+    want = uncut(h)
+    np.testing.assert_allclose(total, want, atol=1e-4, rtol=0)
+    np.testing.assert_allclose(shared + whole, want, atol=1e-4, rtol=0)
 
 
 # (c) dropless routing
@@ -316,9 +346,9 @@ def test_masked_tokens_route_nowhere():
 # (d) the window group's ring
 
 def _window_group(**kw):
-    args = dict(window=32, write_ahead=8, num_layers=2, kv_heads=2,
-                head_dim=8, max_slots=2, num_blocks=24, block_size=4,
-                max_context=128)
+    args = dict(window=32, write_ahead=8, num_layers=2,
+                rows=attention.KVRows(heads=2, kv_heads=2, head_dim=8),
+                max_slots=2, num_blocks=24, block_size=4, max_context=128)
     return WindowKVGroup(**{**args, **kw})
 
 
@@ -389,8 +419,7 @@ def test_freed_blocks_are_never_read(f32_model):
                     np.arange(b * 4, b * 4 + 4)
                     for b in range(g.allocator.num_blocks)
                     if b not in named])
-                g.k_pool = g.k_pool.at[:, rows].set(1e4)
-                g.v_pool = g.v_pool.at[:, rows].set(1e4)
+                g.pools = tuple(p.at[:, rows].set(1e4) for p in g.pools)
     assert eng.kv.blocks_recycled > 0
     want = _reference_logits(cfg, params, prompt, req.tokens)
     np.testing.assert_allclose(np.stack(seen[req.id]), want, atol=F32_TOL,
@@ -466,8 +495,13 @@ def test_chunk_attention_matches_dense_attention(window):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
-def test_grouped_matmul_kernel_matches_the_plain_loop():
-    t, d, m, e, held, k = 40, 128, 256, 16, 4, 4
+@pytest.mark.parametrize("t,tile", [(40, 16), (72, 64)],
+                         ids=["tile16", "wide-tile"])
+def test_grouped_matmul_kernel_matches_the_plain_loop(t, tile):
+    """40 tokens top 4 of 16 spread 10 rows an expert: tiles of 16; 72
+    spread 18: the wide tile (``moe.group_tile``), an expert read once."""
+    d, m, e, held, k = 128, 256, 16, 4, 4
+    assert moe.group_tile(t, k, e) == tile
     ks = jax.random.split(jax.random.PRNGKey(4), 6)
     h = jax.random.normal(ks[0], (t, d))
     router = jax.random.normal(ks[1], (d, e)) * 0.1

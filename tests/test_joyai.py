@@ -1,0 +1,421 @@
+"""The joyai family (latent attention over a latent paged cache, a wide
+sigmoid router over narrow experts) on the CPU at a tiny size, seeded
+weights, logits compared: the serving path (one latent pool, chunked prefill
+in the decompressed form, absorbed paged decode) against
+``benchmark/reference/joyai.py``'s full non-absorbed forward; the interleaved
+rotary; the router; the kernel in interpret mode at the published head shape.
+
+With float32 parameters the system and the reference do the same float32
+arithmetic in another order (absorbed products, a running softmax over key
+chunks, experts summed pair by pair): logits of size ~5 agree to 1e-4.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributedtensorflow_tpu import models
+from distributedtensorflow_tpu.models import joyai
+from distributedtensorflow_tpu.ops import attention
+from distributedtensorflow_tpu.parallel import moe
+from distributedtensorflow_tpu.serve.engine import Engine
+from distributedtensorflow_tpu.serve.kv_cache import make_grouped_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+F32_TOL = 1e-4
+
+
+def _reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "joyai.py")
+    spec = importlib.util.spec_from_file_location("ref_joyai", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+
+def _config_dict(cfg: joyai.JoyaiConfig) -> dict:
+    """What the benchmark's configuration file would say of ``cfg``."""
+    return dict(
+        hidden_size=cfg.hidden_size, num_attention_heads=cfg.num_heads,
+        q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
+        n_routed_experts=cfg.num_experts, n_group=cfg.n_group,
+        num_experts_per_tok=cfg.experts_per_token,
+        norm_topk_prob=cfg.route_norm,
+        routed_scaling_factor=cfg.route_scale,
+        num_hidden_layers=cfg.num_layers,
+        first_k_dense_replace=cfg.num_dense_layers)
+
+
+@pytest.fixture(scope="module")
+def f32_model():
+    cfg = joyai.joyai_tiny(dtype=jnp.float32)
+    # std 0.2: logits of size ~5, and a selection bias that decides picks
+    params = joyai.init_params(cfg, jax.random.PRNGKey(32), std=0.2)
+    return cfg, params
+
+
+def _record_logits(eng):
+    """``{request id: [the logits of every served position]}``, filled as
+    ``eng`` runs (``tests/test_afmoe.py`` has the same spy)."""
+    seen = {}
+    sample, decode = eng._sample, eng.programs.decode
+
+    def first(req, logits):
+        if not req.tokens:
+            seen.setdefault(req.id, []).append(np.array(logits))
+        return sample(req, logits)
+
+    def spy(*args):
+        out = decode(*args)
+        logits = np.asarray(out[0])
+        for slot, req in enumerate(eng._slots):
+            if req is not None and req._prefill_done:
+                seen.setdefault(req.id, []).append(logits[slot].copy())
+        return out
+
+    eng._sample, eng.programs.decode = first, spy
+    return seen
+
+
+def _serve(cfg, params, jobs, **engine_kw):
+    """Run ``jobs`` [(prompt, n_new)] through an Engine together; returns
+    per job (tokens, logits of every served position)."""
+    kw = dict(max_slots=3, block_size=4, prefill_chunk=8, max_context=128)
+    eng = Engine(params, cfg, **{**kw, **engine_kw})
+    seen = _record_logits(eng)
+    reqs = [eng.submit(p, max_new_tokens=n) for p, n in jobs]
+    for _ in range(2000):
+        if all(r._done.is_set() for r in reqs):
+            break
+        eng.step()
+    assert all(r.status == "ok" for r in reqs)
+    for r in reqs:      # greedy: each token the arg-max of its row
+        assert r.tokens == [int(np.argmax(row)) for row in seen[r.id]]
+    return eng, [(r.tokens, np.stack(seen[r.id])) for r in reqs]
+
+
+def _reference_logits(cfg, params, prompt, tokens):
+    ids = jnp.asarray([list(prompt) + list(tokens)])
+    full = REF.logits(params, ids, _config_dict(cfg))[0]
+    return np.asarray(full)[len(prompt) - 1:-1]
+
+
+def _prompt(seed, n, cfg):
+    return np.random.default_rng(seed).integers(0, cfg.vocab_size, n).tolist()
+
+
+# (a) chunks, then decode through the latent cache, against the reference
+
+@pytest.mark.parametrize("prompt_len,n_new", [
+    (1, 3),      # a prompt of one token
+    (3, 6),      # inside one chunk and one block
+    (8, 9),      # exactly one chunk: the first decoded token opens a block
+    (9, 25),     # a second chunk of one token
+    (16, 16),    # two whole chunks, four whole blocks
+    (21, 12),    # chunks of 8 end mid-block; decoding crosses block edges
+    (33, 5),     # a fifth chunk of one token, decoding inside its block
+    (40, 30),    # five whole chunks, ten blocks, then eight more
+    (57, 20),    # eight chunks, the last of one token
+])
+def test_served_logits_match_the_reference(f32_model, prompt_len, n_new):
+    cfg, params = f32_model
+    prompt = _prompt(prompt_len, prompt_len, cfg)
+    _, [(tokens, logits)] = _serve(cfg, params, [(prompt, n_new)])
+    want = _reference_logits(cfg, params, prompt, tokens)
+    assert len(tokens) == n_new
+    np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+
+
+def _latent_case(seed, *, t, heads, rank, rope, nope, v, blocks, bs, layers=2):
+    """Random queries, up-projections and a latent pool of ``blocks`` blocks
+    (plus scratch) in the row form, lane-padded as the family pads it."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    form = attention.LatentRows(rank=rank, rope_dim=rope,
+                                scale=(nope + rope) ** -0.5)
+    pool = jax.random.normal(ks[0], (layers, (blocks + 1) * bs,
+                                     form.widths[0]))
+    pool = pool.at[..., rank + rope:].set(0.0)
+    return dict(
+        form=form, pool=pool,
+        q_nope=jax.random.normal(ks[1], (t, heads, nope)),
+        q_rope=jax.random.normal(ks[2], (t, heads, rope)),
+        w_uk=jax.random.normal(ks[3], (rank, heads, nope)) * 0.1,
+        w_uv=jax.random.normal(ks[4], (rank, heads, v)) * 0.1)
+
+
+def _dense_latent(case, rows, qpos):
+    """Non-absorbed causal attention of queries at ``qpos`` over the latent
+    ``rows`` (S, width) at positions 0..S-1, from the definition."""
+    form = case["form"]
+    c_kv = rows[:, :form.rank]
+    k_rope = rows[:, form.rank:form.rank + form.rope_dim]
+    k_nope = jnp.einsum("kr,rhn->khn", c_kv, case["w_uk"])
+    v = jnp.einsum("kr,rhv->khv", c_kv, case["w_uv"])
+    s = (jnp.einsum("qhn,khn->hqk", case["q_nope"], k_nope)
+         + jnp.einsum("qhr,kr->hqk", case["q_rope"], k_rope)) * form.scale
+    ok = jnp.arange(rows.shape[0])[None, :] <= qpos[:, None]
+    p = jax.nn.softmax(jnp.where(ok[None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khv->qhv", p, v)
+
+
+@pytest.mark.parametrize("start", [0, 3, 24, 37, 50, 64])
+def test_chunk_formulation_matches_dense_causal_attention(start):
+    """A chunk of 16 queries from ``start`` against the slot's latent pages
+    (scattered blocks of 4, stretches of 8 rows decompressed inside the
+    loop): the running softmax against the dense definition."""
+    bs, t, nb = 4, 16, 20
+    case = _latent_case(start, t=t, heads=4, rank=32, rope=8, nope=16, v=16,
+                        blocks=nb, bs=bs)
+    row = jnp.asarray(np.random.default_rng(start).permutation(nb), jnp.int32)
+    got = attention.paged_latent_chunk_attention(
+        case["q_nope"], case["q_rope"], jnp.int32(start), case["pool"], row,
+        w_uk=case["w_uk"], w_uv=case["w_uv"], layer=1, block_size=bs,
+        scale=case["form"].scale, kv_chunk=8)
+    rows = case["pool"].reshape(2, -1, bs, case["pool"].shape[-1])[1, row]
+    rows = rows.reshape(nb * bs, -1)[:start + t]
+    want = _dense_latent(case, rows, start + jnp.arange(t))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def test_absorbed_attention_is_the_non_absorbed_on_one_block():
+    """``(q_nope W_UK^T) . c_kv = q_nope . (c_kv W_UK)`` and ``(sum p c_kv)
+    W_UV = sum p (c_kv W_UV)``: one block of 16 rows, one query a slot."""
+    bs = 16
+    case = _latent_case(5, t=3, heads=4, rank=128, rope=8, nope=16, v=16,
+                        blocks=3, bs=bs)
+    tables = jnp.asarray([[2], [0], [1]], jnp.int32)
+    lens = jnp.asarray([16, 5, 1], jnp.int32)
+    got = attention.paged_latent_decode_attention(
+        case["q_nope"], case["q_rope"], case["pool"], tables, lens,
+        w_uk=case["w_uk"], w_uv=case["w_uv"], layer=0, block_size=bs,
+        scale=case["form"].scale, impl="xla")
+    for i in range(3):
+        rows = case["pool"][0, int(tables[i, 0]) * bs:][:int(lens[i])]
+        one = {**case, "q_nope": case["q_nope"][i:i + 1],
+               "q_rope": case["q_rope"][i:i + 1]}
+        want = _dense_latent(one, rows, jnp.asarray([int(lens[i]) - 1]))
+        np.testing.assert_allclose(got[i], want[0], atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("offset", [0, 5, 1000])
+def test_interleaved_rotary_is_the_definition_at_an_offset(offset):
+    """Pairs ``(x[2i], x[2i+1])`` rotated by ``pos * theta ** (-i / 32)``,
+    the results laid out ``[firsts | seconds]``: complex multiplication,
+    written out; and the score of a rotated query and key depends on their
+    distance only."""
+    d, theta = 64, 32e6
+    x = jax.random.normal(jax.random.PRNGKey(offset), (7, 2, d))
+    pos = offset + jnp.arange(7, dtype=jnp.int32)
+    got = np.asarray(joyai.rope_interleaved(x, pos, theta), np.float64)
+    z = np.asarray(x[..., 0::2], np.float64) \
+        + 1j * np.asarray(x[..., 1::2], np.float64)
+    ang = np.asarray(pos, np.float64)[:, None, None] \
+        * theta ** (-np.arange(d // 2) / (d // 2))
+    want = z * np.exp(1j * ang)
+    # float32 angles: a thousand radians are known to 6e-5
+    tol = 2e-5 if offset < 100 else 1e-3
+    np.testing.assert_allclose(got[..., :d // 2], want.real, atol=tol)
+    np.testing.assert_allclose(got[..., d // 2:], want.imag, atol=tol)
+    far = np.asarray(joyai.rope_interleaved(x, pos + 123, theta), np.float64)
+    np.testing.assert_allclose((got[0, 0] * got[3, 1]).sum(),
+                               (far[0, 0] * far[3, 1]).sum(), atol=1e-3)
+
+
+# (b) the kernel, interpreted, against the plain formulation
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 7e-2)])
+def test_latent_decode_kernel_matches_the_plain_formulation(dtype, tol):
+    """The published head shape — 32 heads over rows of 512 + 64 (640 with
+    the lane padding), blocks of 16 — at lengths inside one block, across
+    blocks, across the kernel's 128-row stretches and 512-row steps; an
+    inactive slot attends the scratch block's one row.  (bfloat16 outputs of
+    size 4 to 8 step by 0.031: two steps.)"""
+    bs, nb, heads = 16, 80, 32
+    case = _latent_case(1, t=5, heads=heads, rank=512, rope=64, nope=128,
+                        v=128, blocks=nb, bs=bs)
+    assert case["pool"].shape[-1] == 640
+    case = {k: a.astype(dtype) if hasattr(a, "astype") else a
+            for k, a in case.items()}
+    lens = np.array([1, 16, 130, 600, 1], np.int32)
+    tables = np.full((5, 40), nb, np.int32)         # unmapped -> scratch
+    perm, o = np.random.default_rng(0).permutation(nb), 0
+    for i, n in enumerate(lens[:4]):
+        need = -(-int(n) // bs)
+        tables[i, :need] = perm[o:o + need]
+        o += need
+    kw = dict(w_uk=case["w_uk"], w_uv=case["w_uv"], layer=1, block_size=bs,
+              scale=case["form"].scale)
+    args = (case["q_nope"], case["q_rope"], case["pool"],
+            jnp.asarray(tables), jnp.asarray(lens))
+    assert attention.paged_latent_formulation(bs, 640, 512, "pallas") \
+        == "paged_latent_attn"
+    want = attention.paged_latent_decode_attention(*args, impl="xla", **kw)
+    got = attention.paged_latent_decode_attention(
+        *args, impl="pallas", interpret=True, **kw)
+    assert got.shape == (5, heads, 128) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=0)
+
+
+def test_latent_formulation_falls_back_where_the_kernel_does_not_fit():
+    assert attention.paged_latent_formulation(16, 640, 512, "xla") == "plain"
+    assert attention.paged_latent_formulation(24, 640, 512, "pallas") \
+        == "plain"                      # a block that does not divide 128
+    assert attention.paged_latent_formulation(16, 128, 32, "pallas") \
+        == "plain"                      # values of no whole lane tile
+    with pytest.raises(ValueError, match="window"):
+        joyai.joyai_tiny().cache_rows.decode(
+            None, (), None, None, window=8)
+
+
+# (c) the router
+
+def test_router_takes_the_top_8_of_score_plus_bias_and_weighs_by_score():
+    cfg = joyai.joyai_llm_flash()
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    h = jax.random.normal(ks[0], (24, 64))
+    router = jax.random.normal(ks[1], (64, cfg.num_experts)) * 0.3
+    bias = jax.random.normal(ks[2], (cfg.num_experts,)) * 0.5
+    idx, w = moe.sigmoid_topk_route(
+        h, router, bias, top_k=cfg.experts_per_token,
+        route_norm=cfg.route_norm, route_scale=cfg.route_scale)
+    s = jax.nn.sigmoid(h @ router)
+    want_idx = np.argsort(-np.asarray(s + bias), axis=-1)[:, :8]
+    assert idx.shape == (24, 8)
+    assert (np.sort(idx, -1) == np.sort(want_idx, -1)).all()
+    picked = np.take_along_axis(np.asarray(s), np.asarray(idx), -1)
+    np.testing.assert_allclose(
+        w, picked / picked.sum(-1, keepdims=True) * 2.5, rtol=1e-5)
+    np.testing.assert_allclose(w.sum(-1), 2.5, rtol=1e-5)
+    # the bias selects only: other picks without it, the same weights rule
+    no_bias, w0 = moe.sigmoid_topk_route(
+        h, router, jnp.zeros_like(bias), top_k=8, route_scale=2.5)
+    assert (np.sort(no_bias, -1) != np.sort(idx, -1)).any()
+    np.testing.assert_allclose(w0.sum(-1), 2.5, rtol=1e-5)
+
+
+def test_group_limited_routing_is_refused_not_guessed():
+    with pytest.raises(ValueError, match="n_group > 1"):
+        joyai.joyai_tiny(n_group=4, topk_group=2)
+    with pytest.raises(NotImplementedError, match="n_group > 1"):
+        REF._experts(None, None, {"n_routed_experts": 16, "n_group": 4,
+                                  "num_experts_per_tok": 4})
+
+
+# (d) many slots
+
+def test_slots_of_different_lengths_decode_together(f32_model):
+    cfg, params = f32_model
+    jobs = [(_prompt(n, n, cfg), m) for n, m in ((5, 40), (60, 20), (33, 12))]
+    _, served = _serve(cfg, params, jobs)
+    for (prompt, _), (tokens, logits) in zip(jobs, served):
+        want = _reference_logits(cfg, params, prompt, tokens)
+        np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+
+
+def test_every_slot_live_under_load(f32_model):
+    """Every slot decoding at once, several tokens on one expert an
+    iteration, a queue behind the slots and a pool that admission waits on:
+    each served logit still the reference's."""
+    cfg, params = f32_model
+    rng = np.random.default_rng(64)
+    shapes = [(70, 30), (45, 50)] + [(int(rng.integers(3, 30)),
+                                      int(rng.integers(20, 45)))
+                                     for _ in range(14)]
+    jobs = [(_prompt(i, n, cfg), m) for i, (n, m) in enumerate(shapes)]
+    eng, served = _serve(cfg, params, jobs, max_slots=8, num_blocks=140)
+    rows = [r for r in eng.step_records() if r["occupancy"]]
+    assert max(r["occupancy"] for r in rows) == 8
+    assert max(r["moe_max_load"] for r in rows) >= 3
+    assert eng.kv.stats()["blocks_free"] == 140
+    for (prompt, _), (tokens, logits) in zip(jobs, served):
+        want = _reference_logits(cfg, params, prompt, tokens)
+        np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+
+
+def test_bfloat16_preset_serves_finite_logits_near_the_reference():
+    cfg = joyai.joyai_tiny()
+    params = joyai.init_params(cfg, jax.random.PRNGKey(3), std=0.2)
+    assert params["h1"]["moe"]["experts"]["w_up"].dtype == jnp.bfloat16
+    assert params["h1"]["attn"]["w_uk"].dtype == jnp.bfloat16
+    assert params["h1"]["moe"]["router"].dtype == jnp.float32
+    prompt = list(range(1, 45))
+    eng, [(tokens, logits)] = _serve(cfg, params, [(prompt, 24)])
+    assert eng.kv.groups["full"].pools[0].dtype == jnp.bfloat16
+    want = _reference_logits(cfg, params, prompt, tokens)
+    assert np.isfinite(logits).all()
+    assert (logits.argmax(-1) == want.argmax(-1)).mean() >= 0.75
+    assert np.median(np.abs(logits - want)) < 0.1
+
+
+# (e) what is cached, logged and refused
+
+def test_cache_row_is_1152_bytes_a_layer_at_the_published_widths():
+    """One pool, no V pool: 512 + 64 bf16 values a token a layer (in rows
+    padded to five lane tiles, which the TPU's layout pads a 576-wide row
+    to in any case); 32 heads of K and V would be 16,384 bytes."""
+    cfg = joyai.joyai_llm_flash()
+    assert (cfg.num_experts, cfg.experts_per_token, cfg.vocab_size) \
+        == (256, 8, 129280)
+    kv = make_grouped_cache(cfg, max_slots=2, block_size=16, max_context=64,
+                            num_blocks={"full": 8}, write_ahead=16)
+    (pool,) = kv.groups["full"].pools
+    assert list(kv.groups) == ["full"] and kv.latent_layers == 5
+    assert pool.shape == (5, 9 * 16, 640) and pool.dtype == jnp.bfloat16
+    assert cfg.cache_rows.values == (576,)
+    assert kv.groups["full"].row_bytes == 1152
+    assert kv.row_bytes == 1152 * cfg.num_layers
+    # the K/V families' census is what it was
+    gpt = make_grouped_cache(models.gpt_tiny(), max_slots=2, block_size=16,
+                             max_context=64, num_blocks={}, write_ahead=16)
+    tiny = models.gpt_tiny()
+    assert gpt.latent_layers == 0 and gpt.row_bytes == (
+        2 * tiny.kv_heads * tiny.head_dim * tiny.num_layers
+        * jnp.dtype(tiny.dtype).itemsize)
+
+
+def test_step_log_carries_the_family_counters(f32_model):
+    cfg, params = f32_model
+    eng, [(tokens, _)] = _serve(cfg, params, [(list(range(40)), 12)])
+    state = eng.state()
+    assert state["decode_attention"] == "plain"
+    assert state["cache_row_bytes"] == (32 + 8) * 4 * cfg.num_layers
+    decodes = [r for r in eng.step_records() if r["occupancy"]]
+    assert decodes and all(
+        {"moe_pairs", "moe_experts_hit", "moe_max_load", "latent_rows_read",
+         "kv_blocks_used_full"} <= set(r) for r in decodes)
+    # two expert layers of 16 held experts; one token, 4 choices a layer
+    assert all(r["moe_pairs"] == 8 == r["moe_experts_hit"] for r in decodes)
+    # iteration i attends the prompt, the tokens before it and its own
+    assert [r["latent_rows_read"] for r in decodes] == [
+        cfg.num_layers * (40 + i + 1) for i in range(len(decodes))]
+    chunks = [r["context_tokens"] for r in eng.step_records()
+              if r["prefill_chunks"]]
+    # no prefill budget: the prompt's five chunks in one iteration, each
+    # walking the context up to its own end
+    assert chunks == [8 + 16 + 24 + 32 + 40]
+
+
+@pytest.mark.parametrize("flag,kw", [
+    ("prefix_cache", {"prefix_cache": True}),
+    ("fused_sampling", {"fused_sampling": True}),
+    ("speculate", {"fused_sampling": True, "speculate": 2}),
+])
+def test_family_refuses_what_it_cannot_run_yet(f32_model, flag, kw):
+    cfg, params = f32_model
+    want = "fused_sampling" if flag == "speculate" else flag
+    with pytest.raises(ValueError,
+                       match=f"{want} is not implemented for the joyai"):
+        Engine(params, cfg, max_slots=2, block_size=4, prefill_chunk=8,
+               max_context=128, **kw)

@@ -27,12 +27,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributedtensorflow_tpu.ops.attention import (
     _pallas_decode_attention,
+    paged_latent_decode_attention,
     paged_window_decode_attention,
 )
 from distributedtensorflow_tpu.ops.flash_attention import flash_attention
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
+from distributedtensorflow_tpu.parallel import moe
 
 # GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
 # 64, d 768, vocab 50,257, bf16 activations.
@@ -89,16 +91,34 @@ def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2):
                 _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32))
 
 
-def _grouped(x, w_gate, w_up, w_down, tile_expert, tiles_used):
-    return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert, tiles_used,
-                          tile=16, interpret=False)
+def _latent(slots=32, heads=32, rank=512, rope=64, nope=128, columns=1024):
+    # the joyai serving shapes: 32 slots, 32 heads over one latent row of
+    # 512 + 64 (stored 640 wide), blocks of 16, contexts to 16,384
+    def fn(q_nope, q_rope, pool, tables, lens, w_uk, w_uv):
+        return paged_latent_decode_attention(
+            q_nope, q_rope, pool, tables, lens, w_uk=w_uk, w_uv=w_uv,
+            layer=1, block_size=16, scale=(nope + rope) ** -0.5,
+            impl="pallas", interpret=False)
+    return fn, (_sds((slots, heads, nope), BF16),
+                _sds((slots, heads, rope), BF16),
+                _sds((2, 2049 * 16, 640), BF16),
+                _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32),
+                _sds((rank, heads, nope), BF16),
+                _sds((rank, heads, nope), BF16))
 
 
-def _grouped_args(rows=768, experts=8, d=3072, m=3072):
+def _grouped(tile):
+    def fn(x, w_gate, w_up, w_down, tile_expert, tiles_used):
+        return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert,
+                              tiles_used, tile=tile, interpret=False)
+    return fn
+
+
+def _grouped_args(rows=768, experts=8, d=3072, m=3072, tile=16):
     # a decode iteration's row buffer at the published expert widths
     return (_sds((rows, d), BF16), _sds((experts, d, m), BF16),
             _sds((experts, d, m), BF16), _sds((experts, m, d), BF16),
-            _sds((rows // 16,), jnp.int32), _sds((), jnp.int32))
+            _sds((rows // tile,), jnp.int32), _sds((), jnp.int32))
 
 
 def _qkv(seq=S, kv_heads=H, batch=B):
@@ -127,7 +147,12 @@ FAMILIES = {
     # 128-lane tile of the pool's row), contexts to 1024, 24 layers
     "paged_attn_gpt2m": _paged(None, slots=32, heads=16, d=64, columns=64,
                                layers=24),
-    "moe_grouped": (_grouped, _grouped_args()),
+    "moe_grouped": (_grouped(16), _grouped_args()),
+    # joyai's widths (d 2048, m 768) under a prefill chunk's wide tile
+    "moe_grouped_wide": (_grouped(moe.GROUP_TILE_WIDE), _grouped_args(
+        rows=16 * moe.GROUP_TILE_WIDE, experts=8, d=2048, m=768,
+        tile=moe.GROUP_TILE_WIDE)),
+    "paged_latent_attn": _latent(),
 }
 
 
@@ -238,13 +263,44 @@ def test_serving_program_keeps_the_pool_in_place_on_a_v5e(program,
     one_chip = NamedSharding(_v5e_mesh(1), P())
     _as_on_the_chip(monkeypatch)
     programs = _gpt2m_pool_programs(one_chip, num_layers=2, vocab_size=1024)
-    _, rows, width = kv_cache.pool_shape(2, 2048, 16, 16, 64)
+    _, rows, width = kv_cache.pool_shape(2, 2048, 16, 16 * 64)
     report = pool_check.check_pool_programs(
         {program: programs[program]}, layer_elems=rows * width)
     assert pool_check.failures(report) == []
     # rows of all heads, minor dimension a multiple of 128: no padding
     assert report[program]["k_pool"] == \
         "bf16[2,32784,1024]{2,1,0:T(8,128)(2,1)}"
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode",
+                                     "copy_block"])
+def test_latent_program_keeps_the_pool_in_place_on_a_v5e(program,
+                                                         monkeypatch):
+    """The joyai family at its published widths, two layers deep (one dense,
+    one of 8 experts) and with a small vocabulary: its three programs that
+    take the one pool of latent rows (it is refused the fused ones) convert
+    no layer of it outside ``paged_attn`` and hand it back in place, in the
+    row form: 512 + 64 values in five lane tiles."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import joyai_llm_flash
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        joyai_llm_flash(), max_seq=2048, num_layers=2, num_experts=8,
+        vocab_size=1024)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=8, num_blocks=1024, block_size=16, chunk=256, draft=4,
+        sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    _, rows, width = kv_cache.pool_shape(2, 1024, 16, 640)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width)
+    assert pool_check.failures(report, pools=1) == []
+    assert report[program]["k_pool"] == \
+        "bf16[2,16400,640]{2,1,0:T(8,128)(2,1)}"
 
 
 def test_decode_program_attends_through_the_kernel_on_a_v5e(monkeypatch):

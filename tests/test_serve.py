@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from distributedtensorflow_tpu.models import GPTLM, generate, gpt_tiny
+from distributedtensorflow_tpu.ops.attention import KVRows
 from distributedtensorflow_tpu.serve import (
     BlockAllocator,
     Engine,
@@ -64,7 +65,8 @@ def test_allocator_exhaustion_and_reuse():
 
 def _kv(num_blocks=8, block_size=4, max_context=16, max_slots=2):
     return PagedKVCache(
-        num_layers=1, kv_heads=2, head_dim=4, max_slots=max_slots,
+        num_layers=1, rows=KVRows(heads=2, kv_heads=2, head_dim=4),
+        max_slots=max_slots,
         num_blocks=num_blocks, block_size=block_size,
         max_context=max_context,
     )
@@ -221,8 +223,8 @@ def test_pool_prefill_scatter_gather_round_trip(block_size, dtype, kv_heads):
                              cfg.vocab_size)
     params = GPTLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
     num_blocks, chunk = 8, 8
-    shape = pool_shape(cfg.num_layers, num_blocks, block_size, cfg.kv_heads,
-                       cfg.head_dim)
+    shape = pool_shape(cfg.num_layers, num_blocks, block_size,
+                       cfg.kv_heads * cfg.head_dim)
     assert shape == (cfg.num_layers, (num_blocks + 1) * block_size,
                      cfg.kv_heads * cfg.head_dim)
 
@@ -339,13 +341,13 @@ def test_pool_copy_block_copies_one_block_of_every_layer(block_size, dtype,
         pool_shape,
     )
 
-    shape = pool_shape(3, 6, block_size, kv_heads, 32)
+    shape = pool_shape(3, 6, block_size, kv_heads * 32)
     rng = np.random.default_rng(0)
     k0 = rng.standard_normal(shape).astype(jnp.dtype(dtype))
     v0 = rng.standard_normal(shape).astype(jnp.dtype(dtype))
     src, dst = 4, 1
     k1, v1 = _copy_block_fn(block_size)(
-        jnp.asarray(k0), jnp.asarray(v0), jnp.int32(src), jnp.int32(dst))
+        (jnp.asarray(k0), jnp.asarray(v0)), jnp.int32(src), jnp.int32(dst))
     for before, after in ((k0, np.asarray(k1)), (v0, np.asarray(v1))):
         want = before.copy()
         want[:, dst * block_size:(dst + 1) * block_size] = \
@@ -915,7 +917,8 @@ def test_kv_cow_copies_shared_block_before_write():
     prompt = _tokens(rng, 8)
     kv.admit(0, tokens=8, prompt=prompt)
     # give the pool recognizable contents for the copy check
-    kv.k_pool = kv.k_pool.at[:, rows(kv.pages[0].blocks[0])].set(7.0)
+    kv.pools = (kv.pools[0].at[:, rows(kv.pages[0].blocks[0])].set(7.0),
+                kv.pools[1])
     kv.register_prefix(0, prompt)
     kv.release(0)
     a = kv.admit(0, tokens=8, prompt=prompt)
@@ -930,8 +933,8 @@ def test_kv_cow_copies_shared_block_before_write():
     assert kv.allocator.refcount(kv.pages[1].blocks[0]) == 1
     assert int(kv.block_tables[1, 0]) == kv.pages[1].blocks[0]
     np.testing.assert_array_equal(
-        np.asarray(kv.k_pool[:, rows(kv.pages[1].blocks[0])]),
-        np.asarray(kv.k_pool[:, rows(shared)]),
+        np.asarray(kv.pools[0][:, rows(kv.pages[1].blocks[0])]),
+        np.asarray(kv.pools[0][:, rows(shared)]),
     )
     assert kv.stats()["cow_copies"] == 1
     # slot 0's block is now exclusive but still INDEXED: writing it must

@@ -30,6 +30,7 @@ import numpy as np
 import pytest
 
 from distributedtensorflow_tpu.models import GPTLM, generate, gpt_tiny
+from distributedtensorflow_tpu.ops.attention import KVRows
 from distributedtensorflow_tpu.serve import Engine, OutOfBlocksError
 from distributedtensorflow_tpu.serve import draft as spec_draft
 from distributedtensorflow_tpu.serve import sampling
@@ -373,7 +374,8 @@ def test_speculate_requires_fused_sampling(served_model):
 
 def _kv(num_blocks=8, block_size=4, max_context=32, max_slots=2):
     return PagedKVCache(
-        num_layers=1, kv_heads=2, head_dim=4, max_slots=max_slots,
+        num_layers=1, rows=KVRows(heads=2, kv_heads=2, head_dim=4),
+        max_slots=max_slots,
         num_blocks=num_blocks, block_size=block_size,
         max_context=max_context,
     )

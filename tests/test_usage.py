@@ -30,6 +30,7 @@ import pytest
 from distributedtensorflow_tpu.models import GPTLM, gpt_tiny
 from distributedtensorflow_tpu.obs import usage as obs_usage
 from distributedtensorflow_tpu.obs.registry import Registry
+from distributedtensorflow_tpu.ops.attention import KVRows
 from distributedtensorflow_tpu.serve import (
     Engine,
     PagedKVCache,
@@ -143,8 +144,9 @@ def test_meter_cardinality_guard():
 
 
 def test_billed_blocks_refcount_weighted():
-    kv = PagedKVCache(num_layers=1, kv_heads=1, head_dim=4, max_slots=2,
-                      num_blocks=8, block_size=4, max_context=16)
+    kv = PagedKVCache(num_layers=1,
+                      rows=KVRows(heads=1, kv_heads=1, head_dim=4),
+                      max_slots=2, num_blocks=8, block_size=4, max_context=16)
     assert kv.billed_blocks(0) == 0.0
     prompt = list(range(8))
     assert kv.admit(0, 8) is not None       # 2 exclusive blocks

@@ -1,0 +1,128 @@
+#!/usr/bin/env python3
+"""Time the joyai family's two programs alone on the chip: a prefill chunk by
+chunk width, context and the row tile of the grouped expert matmuls, and a
+decode iteration by context.
+
+    chiprun -- python tools/latent_forms.py [--chunks 512,1024,2048]
+        [--tiles 16,32,64,128] [--starts 0,4096,8192,14336]
+        [--decode 2000,9000,15000]
+
+No engine, no HTTP: the programs of ``serve/model.py:make_programs`` over a
+pool of the cell's size, each call timed to ``block_until_ready`` (median of
+``--reps``).  One JSON row a measurement; ``PERF.md`` section 4 has the
+table this fills.  Exits non-zero without a TPU.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--config", default="joyai_llm_flash")
+    p.add_argument("--chunks", default="512,1024,2048")
+    p.add_argument("--tiles", default="",
+                   help="rows of the wide tile of the grouped matmuls to "
+                        "time (parallel.moe.GROUP_TILE_WIDE; the small tile "
+                        "times a chunk with no wide tile); default: as built")
+    p.add_argument("--starts", default="0,4096,8192,14336")
+    p.add_argument("--decode", default="2000,9000,15000")
+    p.add_argument("--slots", type=int, default=32)
+    p.add_argument("--kv-blocks", type=int, default=24576)
+    p.add_argument("--block-size", type=int, default=16)
+    p.add_argument("--max-context", type=int, default=16384)
+    p.add_argument("--reps", type=int, default=5)
+    args = p.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributedtensorflow_tpu import models, runtime
+    from distributedtensorflow_tpu.parallel import moe
+    from distributedtensorflow_tpu.serve import kv_cache
+    from distributedtensorflow_tpu.serve.model import (family_of,
+                                                       make_programs)
+
+    runtime.init_compile_cache()
+    if not runtime.on_tpu():
+        print("latent_forms: no TPU", file=sys.stderr)
+        return 1
+    base = dataclasses.replace(getattr(models, args.config)(),
+                               max_seq=args.max_context)
+    bs, cols = args.block_size, args.max_context // args.block_size
+    layers = {"full": tuple(range(base.num_layers))}
+    params = family_of(base).init_params(base, jax.random.PRNGKey(0))
+    jax.block_until_ready(params)
+    pools = {"full": tuple(
+        jnp.zeros(kv_cache.pool_shape(base.num_layers, args.kv_blocks, bs,
+                                      width), base.dtype)
+        for width in base.cache_rows.widths)}
+    rng = np.random.default_rng(0)
+
+    def timed(call):
+        nonlocal pools
+        walls = []
+        for _ in range(args.reps + 1):
+            t0 = time.perf_counter()
+            out, pools = call(pools)
+            jax.block_until_ready(out)
+            walls.append(time.perf_counter() - t0)
+        return 1e3 * statistics.median(walls[1:])
+
+    table_row = {"full": jnp.arange(cols, dtype=jnp.int32)}
+    for chunk in (int(c) for c in args.chunks.split(",")):
+        tokens = rng.integers(0, base.vocab_size, chunk)
+        for tile in [int(t) for t in args.tiles.split(",") if t] or [
+                moe.GROUP_TILE_WIDE]:
+            # read when the program is traced: a program a tile
+            moe.GROUP_TILE_WIDE = tile
+            prog = make_programs(base, chunk=chunk, block_size=bs,
+                                 layers=layers)
+            for start in (int(s) for s in args.starts.split(",")):
+                start = min(start, args.max_context - chunk)
+                ms = timed(lambda pools: prog.prefill(
+                    params, pools, tokens, start, table_row, chunk - 1))
+                print(json.dumps({
+                    "program": "prefill_chunk", "chunk": chunk,
+                    "group_tile": moe.group_tile(
+                        chunk, base.experts_per_token, base.num_experts),
+                    "start": start, "ms": round(ms, 3),
+                    "us_per_token": round(1e3 * ms / chunk, 2)}), flush=True)
+
+    prog = make_programs(base, chunk=512, block_size=bs, layers=layers)
+    per_slot = args.kv_blocks // args.slots
+    tables = {"full": jnp.asarray(
+        np.arange(args.slots)[:, None] * per_slot
+        + np.minimum(np.arange(cols), per_slot - 1)[None, :], jnp.int32)}
+    last = jnp.asarray(rng.integers(0, base.vocab_size, args.slots),
+                       jnp.int32)
+    active = jnp.ones((args.slots,), bool)
+    for n in (int(x) for x in args.decode.split(",") if x):
+        n = min(n, per_slot * bs - 1)
+        lens = jnp.full((args.slots,), n, jnp.int32)
+
+        def decode(pools):
+            logits, greedy, pools, routed = prog.decode(
+                params, pools, last, tables, lens, active)
+            return (greedy, routed), pools
+
+        ms = timed(decode)
+        print(json.dumps({
+            "program": "decode", "slots": args.slots, "context": n,
+            "decode_attention": prog.decode_attention,
+            "ms": round(ms, 3)}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
