@@ -793,6 +793,12 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, *,
 # section 4).
 
 
+def _pad_lanes(x, to: int):
+    """``x`` with zeros after its last dimension, ``to`` wide."""
+    pad = to - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
 def _latent_queries(q_nope, q_rope, w_uk, width):
     """``[q_nope W_UK^T | q_rope | 0]`` (..., H, width): the query in the
     cached row's own layout, in the stored type (the absorbed query is
@@ -801,9 +807,7 @@ def _latent_queries(q_nope, q_rope, w_uk, width):
         q_abs = jnp.einsum("...hn,rhn->...hr", q_nope, w_uk,
                            preferred_element_type=jnp.float32
                            ).astype(q_nope.dtype)
-        q = jnp.concatenate([q_abs, q_rope], axis=-1)
-        pad = width - q.shape[-1]
-        return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)]) if pad else q
+        return _pad_lanes(jnp.concatenate([q_abs, q_rope], axis=-1), width)
 
 
 def _latent_values(o_lat, w_uv, dtype):
@@ -812,29 +816,29 @@ def _latent_values(o_lat, w_uv, dtype):
                           preferred_element_type=jnp.float32).astype(dtype)
 
 
-def paged_latent_chunk_attention(
-    q_nope: jax.Array,       # (T, H, nope): one slot's chunk of queries
-    q_rope: jax.Array,       # (T, H, rope), rotated
-    start,                   # int32 scalar: position of the first query
-    pool: jax.Array,         # (L_group, rows, >= rank + rope) latent rows
-    table_row: jax.Array,    # (max_blocks,) the slot's page-table row
-    *,
-    w_uk: jax.Array,         # (rank, H, nope)
-    w_uv: jax.Array,         # (rank, H, v)
-    layer: int,
-    block_size: int,
-    scale: float,
-    kv_chunk: int = 512,
-) -> jax.Array:
-    """Chunk-prefill latent attention of one slot against its latent pages
-    (the chunk's own rows already written): a loop over ``kv_chunk``-row
-    stretches of the context up to the chunk's end with a running softmax,
-    each stretch decompressed with ``W_UK`` / ``W_UV`` inside the loop
-    (above).  Returns ``(T, H, v)``.  Scope ``paged_attn``."""
+#: context rows a step of the chunk kernel copies, decompresses and folds
+#: into the running softmax: the plain loop's stretch too
+LATENT_STRETCH = 512
+#: heads a grid step of the chunk kernel holds, all the chunk's queries of
+#: each resident: a stretch is copied once a step and decompressed once a head
+LATENT_CHUNK_HEADS = 8
+#: query rows of a head whose scores are formed at a time
+LATENT_CHUNK_QUERIES = 512
+#: VMEM the chunk kernel may take (a v5e has 128 MiB, 16 of them scoped by
+#: default): the resident queries, outputs and softmax state of its heads
+LATENT_CHUNK_VMEM = 96 << 20
+
+
+def _plain_latent_chunk(q_nope, q_rope, start, pool, table_row, *, w_uk, w_uv,
+                        layer, block_size, scale):
+    """The plain formulation: a ``fori_loop`` over the stretches, each one's
+    (H, T, stretch) float32 scores and probabilities through HBM.  The
+    kernel's yardstick, the path off the TPU and for shapes that do not
+    fit."""
     t, h, _ = q_nope.shape
     rank, rope_dim = w_uk.shape[0], q_rope.shape[-1]
     width = pool.shape[-1]
-    kv_chunk = max(block_size, kv_chunk // block_size * block_size)
+    kv_chunk = max(block_size, LATENT_STRETCH // block_size * block_size)
     bpc = kv_chunk // block_size
     nb = table_row.shape[0]
     qpos = start + jnp.arange(t, dtype=jnp.int32)
@@ -868,14 +872,294 @@ def paged_latent_chunk_attention(
                         preferred_element_type=jnp.float32)
         return m_new, l * alpha + p.sum(-1), acc * alpha[..., None] + pv
 
-    with jax.named_scope("paged_attn"):
-        init = (jnp.full((h, t), NEG_INF, jnp.float32),
-                jnp.zeros((h, t), jnp.float32),
-                jnp.zeros((h, t, w_uv.shape[-1]), jnp.float32))
-        _, l, acc = jax.lax.fori_loop(
-            0, -(-(start + t) // kv_chunk), body, init)
-        out = (acc / jnp.maximum(l, 1e-30)[..., None]).transpose(1, 0, 2)
+    init = (jnp.full((h, t), NEG_INF, jnp.float32),
+            jnp.zeros((h, t), jnp.float32),
+            jnp.zeros((h, t, w_uv.shape[-1]), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(
+        0, -(-(start + t) // kv_chunk), body, init)
+    out = (acc / jnp.maximum(l, 1e-30)[..., None]).transpose(1, 0, 2)
     return out.astype(dtype)
+
+
+def _latent_chunk_kernel(table_ref, start_ref, layer_ref, qn_ref, qr_ref,
+                         wk_ref, wv_ref, pool_hbm, o_ref, buf, sem, q_sc,
+                         wk_sc, wv_sc, k_sc, v_sc, m_sc, l_sc, acc_sc, *,
+                         block_size, rank, q_tile, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads, t, q_width = q_sc.shape
+    stretch = buf.shape[1]
+    bps = stretch // block_size                # blocks a stretch
+    nb = table_ref.shape[0]
+    nope, v = wk_sc.shape[-1], wv_sc.shape[-1]  # whole lane tiles
+    rope = qr_ref.shape[-1] // heads
+    start, layer = start_ref[0], layer_ref[0]
+    end = start + t
+    n = (end + stretch - 1) // stretch         # stretches to the chunk's end
+    dtype = q_sc.dtype
+
+    # blocks past the chunk's end are not copied and leave their rows as
+    # they were: keep them finite (their probabilities are zeros)
+    buf[...] = jnp.zeros_like(buf)
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    # the operands come as the model holds them, a head's values side by
+    # side with the next head's: a head's own, head-major, once a step (the
+    # query in the decompressed row's layout, [q_nope | q_rope | 0])
+    for h in range(heads):
+        q_sc[h, :, :nope] = qn_ref[:, h * nope:(h + 1) * nope]
+        q_sc[h, :, nope:nope + rope] = qr_ref[:, h * rope:(h + 1) * rope]
+        if nope + rope < q_width:
+            q_sc[h, :, nope + rope:] = jnp.zeros(
+                (t, q_width - nope - rope), dtype)
+        wk_sc[h] = wk_ref[:, h * nope:(h + 1) * nope]
+        wv_sc[h] = wv_ref[:, h * v:(h + 1) * v]
+
+    def copies(c, slot, go):
+        """``go`` every copy of stretch ``c`` into buffer ``slot`` whose
+        block holds a row before the chunk's end."""
+        for j in range(bps):
+            b0 = c * stretch + j * block_size
+            blk = table_ref[jnp.minimum(b0 // block_size, nb - 1)]
+            cp = pltpu.make_async_copy(
+                pool_hbm.at[layer, pl.ds(blk * block_size, block_size)],
+                buf.at[slot, pl.ds(j * block_size, block_size)],
+                sem.at[slot, j])
+            pl.when(b0 < end)(functools.partial(go, cp))
+
+    def fold(h, i, first, masked):
+        """Query tile ``i`` of head ``h`` against the decompressed stretch."""
+        rows = pl.ds(pl.multiple_of(i * q_tile, q_tile), q_tile)
+        s = jax.lax.dot_general(
+            q_sc[h, rows, :], k_sc[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (q_tile, stretch)
+        if masked:
+            qpos = start + i * q_tile + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            ok = first + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) <= qpos
+            s = jnp.where(ok, s, NEG_INF)
+        # the running maximum and sum are kept replicated across 128 lanes
+        m_prev = m_sc[h, rows, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - jnp.concatenate([m_new] * (stretch // LANES), axis=1))
+        if masked:
+            p = jnp.where(ok, p, 0.0)
+        l_sc[h, rows, :] = alpha * l_sc[h, rows, :] \
+            + p.sum(axis=1, keepdims=True)
+        m_sc[h, rows, :] = m_new
+        pv = jnp.dot(p.astype(dtype), v_sc[...],
+                     preferred_element_type=jnp.float32)
+        acc_sc[h, rows, :] = acc_sc[h, rows, :] * jnp.concatenate(
+            [alpha] * (pv.shape[1] // LANES), axis=1) + pv
+
+    def heads_of(first, slot, masked):
+        """Every head of the step against stretch ``first`` in ``slot``:
+        the stretch decompressed once a head, then its query tiles."""
+        def head(h, _):
+            c_kv = buf[slot, :, :rank]
+            k_sc[:, :nope] = jnp.dot(
+                c_kv, wk_sc[h], preferred_element_type=jnp.float32
+            ).astype(dtype)
+            v_sc[...] = jnp.dot(
+                c_kv, wv_sc[h], preferred_element_type=jnp.float32
+            ).astype(dtype)
+
+            def tile(i, _):
+                if masked:
+                    # a tile whose last query precedes the stretch attends
+                    # none of it
+                    pl.when(first <= start + (i + 1) * q_tile - 1)(
+                        lambda: fold(h, i, first, True))
+                else:
+                    fold(h, i, first, False)
+
+            jax.lax.fori_loop(0, t // q_tile, tile, None)
+
+        jax.lax.fori_loop(0, heads, head, None)
+
+    copies(0, 0, lambda cp: cp.start())
+
+    def stretch_body(c, _):
+        slot = jax.lax.rem(c, 2)
+        first = c * stretch
+
+        # the next stretch's copies run under this stretch's arithmetic
+        @pl.when(c + 1 < n)
+        def _():
+            copies(c + 1, 1 - slot, lambda cp: cp.start())
+
+        copies(c, slot, lambda cp: cp.wait())
+        # k_rope (and the row's zero lanes) is every head's
+        k_sc[:, nope:] = buf[slot, :, rank:]
+        # only a stretch that reaches past the chunk's first query is masked
+        diagonal = first + stretch - 1 > start
+        pl.when(diagonal)(lambda: heads_of(first, slot, True))
+        pl.when(jnp.logical_not(diagonal))(
+            lambda: heads_of(first, slot, False))
+
+    jax.lax.fori_loop(0, n, stretch_body, None)
+
+    for h in range(heads):
+        o_ref[:, h * v:(h + 1) * v] = (acc_sc[h] / jnp.concatenate(
+            [jnp.maximum(l_sc[h], 1e-30)] * (v // LANES), axis=1)
+        ).astype(o_ref.dtype)
+
+
+def _latent_chunk_heads(heads: int, chunk: int, nope: int, rope: int,
+                        tail: int, v: int, itemsize: int) -> int:
+    """Heads a grid step holds: the most that divide ``heads``, up to
+    ``LATENT_CHUNK_HEADS``, whose queries and outputs (two buffers each, the
+    pipeline's), head-major queries and float32 softmax state fit half the
+    kernel's VMEM; of those, one whose ``q_rope`` side by side are whole
+    lane tiles where there is one (the others have it padded)."""
+    a_head = chunk * (itemsize * (2 * (nope + tail) + nope + tail + 2 * v)
+                      + 4 * (v + 2 * LANES))
+    fit = max(1, min(LATENT_CHUNK_HEADS, (LATENT_CHUNK_VMEM // 2) // a_head))
+    steps = [g for g in range(1, fit + 1) if heads % g == 0]
+    return max([g for g in steps if g * rope % LANES == 0] or steps)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "block_size", "rank", "stretch", "heads_step", "q_tile",
+    "scale", "interpret"))
+def _latent_chunk_call(table_row, start, layer, q_nope, q_rope, wk, wv, pool,
+                       *, heads, block_size, rank, stretch, heads_step,
+                       q_tile, scale, interpret):
+    """The chunk kernel's call: a jitted function of its own with the layer
+    as a prefetched scalar, so the layers of a program share one lowering.
+    The operands are 2-D as the model holds them, ``heads`` side by side."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t = q_nope.shape[0]
+    nope, rope, v = (x.shape[1] // heads for x in (q_nope, q_rope, wv))
+    width = pool.shape[-1]
+    g, dtype = heads_step, q_nope.dtype
+    q_width = nope + width - rank
+
+    def a_step(rows, lanes):
+        return pl.BlockSpec((rows, g * lanes), lambda i, *_: (0, i))
+
+    return pl.pallas_call(
+        functools.partial(
+            _latent_chunk_kernel, block_size=block_size, rank=rank,
+            q_tile=q_tile, scale=scale),
+        name="latent_chunk_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(heads // g,),
+            in_specs=[a_step(t, nope), a_step(t, rope), a_step(rank, nope),
+                      a_step(rank, v), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=a_step(t, v),
+            scratch_shapes=[
+                pltpu.VMEM((2, stretch, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2, stretch // block_size)),
+                pltpu.VMEM((g, t, q_width), dtype),
+                pltpu.VMEM((g, rank, nope), dtype),
+                pltpu.VMEM((g, rank, v), dtype),
+                pltpu.VMEM((stretch, q_width), dtype),
+                pltpu.VMEM((stretch, v), dtype),
+                pltpu.VMEM((g, t, LANES), jnp.float32),
+                pltpu.VMEM((g, t, LANES), jnp.float32),
+                pltpu.VMEM((g, t, v), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((t, heads * v), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=LATENT_CHUNK_VMEM),
+        interpret=interpret,
+    )(table_row, start, layer, q_nope, q_rope, wk, wv, pool)
+
+
+def paged_latent_chunk_formulation(block_size: int, width: int, rank: int,
+                                   chunk: int, impl: str = "auto") -> str:
+    """Which formulation :func:`paged_latent_chunk_attention` takes:
+    ``"latent_chunk_attn"`` (the kernel) or ``"plain"`` (the loop whose
+    scores go through HBM).  The kernel wants a stretch of whole blocks and
+    whole lane tiles, ``c_kv`` and the rest of the row (``k_rope`` and its
+    zero lanes) each whole lane tiles, and a chunk of whole bf16 sublane
+    tiles that its query tiles divide."""
+    fits = (LATENT_STRETCH % block_size == 0 and LATENT_STRETCH % LANES == 0
+            and rank % LANES == 0 and width > rank
+            and (width - rank) % LANES == 0 and chunk % 16 == 0
+            and chunk % min(chunk, LATENT_CHUNK_QUERIES) == 0)
+    return "latent_chunk_attn" if use_kernel(impl) and fits else "plain"
+
+
+def paged_latent_chunk_attention(
+    q_nope: jax.Array,       # (T, H, nope): one slot's chunk of queries
+    q_rope: jax.Array,       # (T, H, rope), rotated
+    start,                   # int32 scalar: position of the first query
+    pool: jax.Array,         # (L_group, rows, >= rank + rope) latent rows
+    table_row: jax.Array,    # (max_blocks,) the slot's page-table row
+    *,
+    w_uk: jax.Array,         # (rank, H, nope)
+    w_uv: jax.Array,         # (rank, H, v)
+    layer: int,
+    block_size: int,
+    scale: float,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Chunk-prefill latent attention of one slot against its latent pages
+    (the chunk's own rows already written), ``(T, H, v)``: the context up to
+    the chunk's end in stretches of ``LATENT_STRETCH`` rows (whole blocks
+    of them), each decompressed with ``W_UK`` / ``W_UV`` (above) and
+    folded into a running softmax — bf16 operands, float32 accumulation,
+    ``k_nope``, the values and the probabilities rounded to the stored type.
+
+    The kernel (``name="latent_chunk_attn"``; the page-table row, ``start``
+    and the layer prefetched into SMEM, the pool left in HBM; grid over
+    groups of ``LATENT_CHUNK_HEADS`` heads, all ``T`` queries of each
+    resident) walks the stretches in a loop of ``ceil((start + T) /
+    stretch)`` trips: a stretch's blocks are copied into one of two VMEM
+    buffers, the next stretch's copies started before this one's arithmetic;
+    it is decompressed once a head (``c_kv W_UK_h``, ``c_kv W_UV_h``), and
+    each tile of ``LATENT_CHUNK_QUERIES`` queries forms its scores ``[q_nope
+    | q_rope] . [k_nope | k_rope]`` as one product, the running maximum, sum
+    and accumulator in VMEM scratch: no score goes to HBM.  Only the
+    stretches that reach past the chunk's first query are masked (and a
+    query tile that precedes such a stretch skips it); blocks past the
+    chunk's end are not copied.  Other shapes
+    (:func:`paged_latent_chunk_formulation`), the CPU and ``impl="xla"``
+    take the plain loop.  Scope ``paged_attn``."""
+    t, h, nope = q_nope.shape
+    rank, v = w_uk.shape[0], w_uv.shape[-1]
+    width = pool.shape[-1]
+    form = paged_latent_chunk_formulation(block_size, width, rank, t, impl)
+    with jax.named_scope("paged_attn"):
+        if form == "plain":
+            return _plain_latent_chunk(
+                q_nope, q_rope, start, pool, table_row, w_uk=w_uk, w_uv=w_uv,
+                layer=layer, block_size=block_size, scale=scale)
+        if interpret is None:
+            interpret = not on_tpu()
+        # the operands as the model holds them, the heads side by side (a
+        # reshape): nothing but the published widths' no-op pads around the
+        # call.  Heads and values of no whole lane tile are padded to one,
+        # q_rope where the step's heads side by side are not whole tiles
+        nope_p, v_p = -(-nope // LANES) * LANES, -(-v // LANES) * LANES
+        rope, tail = q_rope.shape[-1], width - rank
+        g = _latent_chunk_heads(h, t, nope_p, rope, tail, v_p,
+                                q_nope.dtype.itemsize)
+        rope_p = rope if g * rope % LANES == 0 else tail
+
+        def flat(x, to):
+            return _pad_lanes(x, to).reshape(x.shape[0], h * to)
+
+        out = _latent_chunk_call(
+            table_row.astype(jnp.int32),
+            jnp.reshape(start, (1,)).astype(jnp.int32),
+            jnp.full((1,), layer, jnp.int32), flat(q_nope, nope_p),
+            flat(q_rope, rope_p), flat(w_uk, nope_p), flat(w_uv, v_p), pool,
+            heads=h, block_size=block_size, rank=rank,
+            stretch=LATENT_STRETCH, heads_step=g,
+            q_tile=min(t, LATENT_CHUNK_QUERIES), scale=scale,
+            interpret=interpret)
+        return out.reshape(t, h, v_p)[..., :v]
 
 
 def paged_latent_formulation(block_size: int, width: int, rank: int,
@@ -1103,7 +1387,13 @@ class KVRows:
         return paged_decode_formulation(
             self.heads, self.kv_heads, self.head_dim, block_size, impl)
 
-    def chunk(self, q, start, pools, table_row, **kw):
+    def chunk_formulation(self, block_size: int, chunk: int,
+                          impl: str) -> str:
+        # K/V rows have the one chunk formulation, the plain loop
+        return "plain"
+
+    def chunk(self, q, start, pools, table_row, *, impl="auto", **kw):
+        # ``impl`` chooses nothing here: see ``chunk_formulation``
         with jax.named_scope("paged_attn"):
             return paged_chunk_attention(q, start, *pools, table_row, **kw)
 
@@ -1145,6 +1435,11 @@ class LatentRows:
     def decode_formulation(self, block_size: int, impl: str) -> str:
         return paged_latent_formulation(
             block_size, self.widths[0], self.rank, impl)
+
+    def chunk_formulation(self, block_size: int, chunk: int,
+                          impl: str) -> str:
+        return paged_latent_chunk_formulation(
+            block_size, self.widths[0], self.rank, chunk, impl)
 
     def _no_window(self, window):
         if window is not None:

@@ -1641,6 +1641,8 @@ class Engine:
             # "paged_attn", "paged_latent_attn" or "plain" (serve.model):
             # the fallback is silent
             "decode_attention": self.programs.decode_attention,
+            # the same of a prefill chunk: "latent_chunk_attn" or "plain"
+            "chunk_attention": self.programs.chunk_attention,
             # bytes the cache stores a token over all layers
             "cache_row_bytes": self.kv.row_bytes,
             "spec_acceptance_rate": (

@@ -138,7 +138,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                 return form.chunk(
                     q, start, pools[name], table_rows[name], layer=li,
                     block_size=block_size, window=cfg.window_of(layer),
-                    **weights)
+                    impl=cfg.kernel_impl, **weights)
 
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
@@ -372,12 +372,16 @@ class Programs:
       attend the pages with, ``"paged_attn"`` or ``"paged_latent_attn"``
       (the kernel that reads only the blocks a slot holds, over K/V rows or
       latent rows) or ``"plain"`` (the gather of every table column): the
-      fallback is silent, so the engine reports it (``Engine.state()``)."""
+      fallback is silent, so the engine reports it (``Engine.state()``);
+    - ``chunk_attention``: the same of ``prefill``: ``"latent_chunk_attn"``
+      (the kernel over latent rows that keeps a chunk's scores in VMEM) or
+      ``"plain"`` (the loop whose scores go through HBM; all K/V rows
+      have)."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,
                  layers: dict[str, tuple[int, ...]]):
         self.family, self.cfg = family, cfg
-        self.block_size, self.layers = block_size, layers
+        self.chunk, self.block_size, self.layers = chunk, block_size, layers
         self.prefill_chunk = make_prefill_fn(
             family, cfg, chunk=chunk, block_size=block_size, layers=layers)
         self.decode = make_decode_fn(
@@ -392,6 +396,11 @@ class Programs:
             return "plain"
         return self.cfg.cache_rows.decode_formulation(
             self.block_size, self.cfg.kernel_impl)
+
+    @property
+    def chunk_attention(self) -> str:
+        return self.cfg.cache_rows.chunk_formulation(
+            self.block_size, self.chunk, self.cfg.kernel_impl)
 
     def prefill(self, params, pools, tokens, start: int, table_rows,
                 last_ix: int):

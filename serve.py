@@ -303,6 +303,7 @@ def main(argv=None) -> int:
     ).start()
     startup.mark("startup.engine_build",
                  decode_attention=engine.programs.decode_attention,
+                 chunk_attention=engine.programs.chunk_attention,
                  cache_row_bytes=engine.kv.row_bytes)
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation

@@ -169,18 +169,19 @@ def _dense_latent(case, rows, qpos):
 
 
 @pytest.mark.parametrize("start", [0, 3, 24, 37, 50, 64])
-def test_chunk_formulation_matches_dense_causal_attention(start):
+def test_chunk_formulation_matches_dense_causal_attention(start, monkeypatch):
     """A chunk of 16 queries from ``start`` against the slot's latent pages
     (scattered blocks of 4, stretches of 8 rows decompressed inside the
     loop): the running softmax against the dense definition."""
     bs, t, nb = 4, 16, 20
+    monkeypatch.setattr(attention, "LATENT_STRETCH", 8)
     case = _latent_case(start, t=t, heads=4, rank=32, rope=8, nope=16, v=16,
                         blocks=nb, bs=bs)
     row = jnp.asarray(np.random.default_rng(start).permutation(nb), jnp.int32)
     got = attention.paged_latent_chunk_attention(
         case["q_nope"], case["q_rope"], jnp.int32(start), case["pool"], row,
         w_uk=case["w_uk"], w_uv=case["w_uv"], layer=1, block_size=bs,
-        scale=case["form"].scale, kv_chunk=8)
+        scale=case["form"].scale)
     rows = case["pool"].reshape(2, -1, bs, case["pool"].shape[-1])[1, row]
     rows = rows.reshape(nb * bs, -1)[:start + t]
     want = _dense_latent(case, rows, start + jnp.arange(t))
@@ -266,6 +267,149 @@ def test_latent_decode_kernel_matches_the_plain_formulation(dtype, tol):
     assert got.shape == (5, heads, 128) and got.dtype == dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol, rtol=0)
+
+
+def _chunk_case(seed, dtype, *, t, heads, rank, rope, nope, v, nb, start,
+                bs=16):
+    """A chunk of ``t`` queries from ``start`` over scattered blocks; every
+    block the chunk does not attend (the table's later columns, the rest of
+    the pool, the scratch block) holds NaN and inf.  Returns the arguments,
+    the keywords and the attended rows in order."""
+    case = _latent_case(seed, t=t, heads=heads, rank=rank, rope=rope,
+                        nope=nope, v=v, blocks=nb, bs=bs)
+    row = np.random.default_rng(seed).permutation(nb).astype(np.int32)
+    attended = row[:-(-(start + t) // bs)]
+    poison = np.ones(nb + 1, bool)
+    poison[attended] = False
+    bad = jnp.where(jnp.arange(case["pool"].shape[-1]) % 2, jnp.nan, jnp.inf)
+    pool = case["pool"].reshape(2, nb + 1, bs, -1)
+    pool = jnp.where(jnp.asarray(poison)[None, :, None, None], bad, pool)
+    case = {k: a.astype(dtype) if hasattr(a, "astype") else a
+            for k, a in {**case, "pool": pool.reshape(2, -1, pool.shape[-1])
+                         }.items()}
+    rows = pool[1, attended].reshape(len(attended) * bs, -1)[:start + t]
+    args = (case["q_nope"], case["q_rope"], jnp.int32(start), case["pool"],
+            jnp.asarray(row))
+    kw = dict(w_uk=case["w_uk"], w_uv=case["w_uv"], layer=1, block_size=bs,
+              scale=case["form"].scale)
+    return case, args, kw, rows.astype(dtype)
+
+
+#: stretches of 128 rows, query tiles of 16, two heads a grid step: a chunk
+#: of 32 queries of 4 heads is two tiles and two steps
+SMALL_TILES = {"LATENT_STRETCH": 128, "LATENT_CHUNK_QUERIES": 16,
+               "LATENT_CHUNK_HEADS": 2}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 4e-2)])
+@pytest.mark.parametrize("start", [
+    0,       # the chunk alone: every stretch masked
+    21,      # not a multiple of the block
+    112,     # one block short of a stretch: the chunk crosses into the next
+    300,     # several stretches, the last two masked
+    496,     # a query tile that precedes the last stretch skips it
+    608,     # the chunk ends with the table's last block
+])
+def test_latent_chunk_kernel_matches_the_plain_loop(start, dtype, tol,
+                                                   monkeypatch):
+    """The chunk kernel, interpreted, against the plain loop and (float32)
+    the dense definition: scattered page-table rows, NaN and inf in every
+    block the chunk does not attend (blocks the kernel does not copy, rows
+    of the last block it masks), heads and values padded to whole lane
+    tiles."""
+    for name, value in SMALL_TILES.items():
+        monkeypatch.setattr(attention, name, value)
+    t, nb = 32, 40
+    case, args, kw, rows = _chunk_case(
+        start, dtype, t=t, heads=4, rank=128, rope=8, nope=16, v=16, nb=nb,
+        start=start)
+    assert attention.paged_latent_chunk_formulation(
+        16, case["pool"].shape[-1], 128, t, "pallas") == "latent_chunk_attn"
+    got = attention.paged_latent_chunk_attention(
+        *args, impl="pallas", interpret=True, **kw)
+    assert got.shape == (t, 4, 16) and got.dtype == dtype
+    # the plain loop multiplies a masked row's zero weight with its values:
+    # it is the yardstick over a pool whose unattended rows are finite
+    clean = jnp.nan_to_num(args[3], nan=0.0, posinf=0.0)
+    want = attention.paged_latent_chunk_attention(
+        *args[:3], clean, args[4], impl="xla", **kw)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=0)
+    if dtype == jnp.float32:
+        dense = _dense_latent(case, rows, start + jnp.arange(t))
+        np.testing.assert_allclose(got, dense, atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 4e-2)])
+def test_latent_chunk_kernel_at_the_published_head_shape(dtype, tol):
+    """32 heads over rows of 512 + 64 (640 with the lane padding), heads of
+    128 + 64 and values of 128 — nothing padded —, the tiles as built: a
+    chunk of 64 queries from 500 crosses from the first stretch of 512 rows
+    into the second."""
+    t, nb, start = 64, 40, 500
+    case, args, kw, rows = _chunk_case(
+        7, dtype, t=t, heads=32, rank=512, rope=64, nope=128, v=128, nb=nb,
+        start=start)
+    assert case["pool"].shape[-1] == 640
+    got = attention.paged_latent_chunk_attention(
+        *args, impl="pallas", interpret=True, **kw)
+    clean = jnp.nan_to_num(args[3], nan=0.0, posinf=0.0)
+    want = attention.paged_latent_chunk_attention(
+        *args[:3], clean, args[4], impl="xla", **kw)
+    assert got.shape == (t, 32, 128) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("prompt_len,n_new", [(40, 6), (150, 4)])
+def test_served_through_the_kernels_matches_the_reference(prompt_len, n_new,
+                                                          monkeypatch):
+    """The family at a rank of one lane tile and blocks of 16, both latent
+    kernels interpreted (chunks of 32 through ``latent_chunk_attn`` in
+    stretches of 128 rows, decode through ``paged_latent_attn``): the served
+    logits against the reference's, and the engine says which formulation
+    each program was built with."""
+    for name, value in SMALL_TILES.items():
+        monkeypatch.setattr(attention, name, value)
+    cfg = joyai.joyai_tiny(dtype=jnp.float32, kv_lora_rank=128, max_seq=256,
+                           kernel_impl="pallas")
+    params = joyai.init_params(cfg, jax.random.PRNGKey(33), std=0.2)
+    prompt = _prompt(prompt_len, prompt_len, cfg)
+    eng, [(tokens, logits)] = _serve(
+        cfg, params, [(prompt, n_new)], block_size=16, prefill_chunk=32,
+        max_context=256)
+    state = eng.state()
+    assert state["chunk_attention"] == "latent_chunk_attn"
+    assert state["decode_attention"] == "paged_latent_attn"
+    want = _reference_logits(cfg, params, prompt, tokens)
+    np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+
+
+@pytest.mark.parametrize("block_size,width,rank,chunk,impl,why", [
+    (16, 640, 512, 1024, "xla", "impl says so"),
+    (24, 640, 512, 1024, "pallas", "a block that does not divide a stretch"),
+    (16, 128, 32, 1024, "pallas", "c_kv of no whole lane tile"),
+    (16, 576, 512, 1024, "pallas", "k_rope of no whole lane tile"),
+    (16, 512, 512, 1024, "pallas", "no k_rope"),
+    (16, 640, 512, 8, "pallas", "a chunk of half a bf16 sublane tile"),
+    (16, 640, 512, 768, "pallas", "a chunk its query tiles do not divide"),
+])
+def test_chunk_formulation_falls_back_where_the_kernel_does_not_fit(
+        block_size, width, rank, chunk, impl, why):
+    assert attention.paged_latent_chunk_formulation(
+        block_size, width, rank, chunk, impl) == "plain", why
+
+
+@pytest.mark.parametrize("chunk", [16, 256, 1024, 2048])
+def test_chunk_formulation_takes_the_kernel_at_the_served_shapes(chunk):
+    form = joyai.joyai_llm_flash().cache_rows
+    assert form.chunk_formulation(16, chunk, "pallas") == "latent_chunk_attn"
+    # off the TPU "auto" is the plain loop, and K/V rows have no other
+    assert form.chunk_formulation(16, chunk, "auto") == "plain"
+    assert models.gpt_tiny().cache_rows.chunk_formulation(
+        16, chunk, "pallas") == "plain"
 
 
 def test_latent_formulation_falls_back_where_the_kernel_does_not_fit():
@@ -390,6 +534,7 @@ def test_step_log_carries_the_family_counters(f32_model):
     eng, [(tokens, _)] = _serve(cfg, params, [(list(range(40)), 12)])
     state = eng.state()
     assert state["decode_attention"] == "plain"
+    assert state["chunk_attention"] == "plain"
     assert state["cache_row_bytes"] == (32 + 8) * 4 * cfg.num_layers
     decodes = [r for r in eng.step_records() if r["occupancy"]]
     assert decodes and all(

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributedtensorflow_tpu.ops.attention import (
     _pallas_decode_attention,
+    paged_latent_chunk_attention,
     paged_latent_decode_attention,
     paged_window_decode_attention,
 )
@@ -107,6 +108,23 @@ def _latent(slots=32, heads=32, rank=512, rope=64, nope=128, columns=1024):
                 _sds((rank, heads, nope), BF16))
 
 
+def _latent_chunk(chunk=1024, heads=32, rank=512, rope=64, nope=128,
+                  columns=1024):
+    # a joyai prefill chunk: 1024 queries of 32 heads against the slot's
+    # page-table row, contexts to 16,384, the cell's pool of 24,576 blocks
+    def fn(q_nope, q_rope, start, pool, table_row, w_uk, w_uv):
+        return paged_latent_chunk_attention(
+            q_nope, q_rope, start, pool, table_row, w_uk=w_uk, w_uv=w_uv,
+            layer=1, block_size=16, scale=(nope + rope) ** -0.5,
+            impl="pallas", interpret=False)
+    return fn, (_sds((chunk, heads, nope), BF16),
+                _sds((chunk, heads, rope), BF16), _sds((), jnp.int32),
+                _sds((5, 24577 * 16, 640), BF16),
+                _sds((columns,), jnp.int32),
+                _sds((rank, heads, nope), BF16),
+                _sds((rank, heads, nope), BF16))
+
+
 def _grouped(tile):
     def fn(x, w_gate, w_up, w_down, tile_expert, tiles_used):
         return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert,
@@ -153,6 +171,9 @@ FAMILIES = {
         rows=16 * moe.GROUP_TILE_WIDE, experts=8, d=2048, m=768,
         tile=moe.GROUP_TILE_WIDE)),
     "paged_latent_attn": _latent(),
+    "latent_chunk_attn": _latent_chunk(),
+    # the widest chunk the cell's sweep served: fewer heads a grid step
+    "latent_chunk_attn_2048": _latent_chunk(chunk=2048),
 }
 
 
@@ -301,6 +322,15 @@ def test_latent_program_keeps_the_pool_in_place_on_a_v5e(program,
     assert pool_check.failures(report, pools=1) == []
     assert report[program]["k_pool"] == \
         "bf16[2,16400,640]{2,1,0:T(8,128)(2,1)}"
+    if program == "prefill_chunk":
+        # both layers attend through the chunk kernel, lowered once, the
+        # layer a prefetched scalar (the fallback to the plain loop is
+        # silent: 37 of a chunk's 64 ms)
+        fn, args = programs[program]
+        text = fn.lower(*args).as_text()
+        calls = re.findall(r"call @(\w*latent_chunk\w*)\(", text)
+        assert len(calls) == 2 and len(set(calls)) == 1, calls
+        assert text.count('kernel_name = "latent_chunk_attn"') == 1
 
 
 def test_decode_program_attends_through_the_kernel_on_a_v5e(monkeypatch):

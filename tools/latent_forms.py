@@ -1,16 +1,20 @@
 #!/usr/bin/env python3
 """Time the joyai family's two programs alone on the chip: a prefill chunk by
-chunk width, context and the row tile of the grouped expert matmuls, and a
-decode iteration by context.
+chunk width, context, the formulation of its latent attention and the row
+tile of the grouped expert matmuls, and a decode iteration by context.
 
     chiprun -- python tools/latent_forms.py [--chunks 512,1024,2048]
-        [--tiles 16,32,64,128] [--starts 0,4096,8192,14336]
+        [--impl latent_chunk_attn,plain] [--kernel-tiles 8x256x512,4x512x512]
+        [--attn 1] [--tiles 16,32,64,128] [--starts 0,4096,8192,14336]
         [--decode 2000,9000,15000]
 
 No engine, no HTTP: the programs of ``serve/model.py:make_programs`` over a
 pool of the cell's size, each call timed to ``block_until_ready`` (median of
-``--reps``).  One JSON row a measurement; ``PERF.md`` section 4 has the
-table this fills.  Exits non-zero without a TPU.
+``--reps``).  ``--attn 1`` times one layer's
+``ops.attention.paged_latent_chunk_attention`` alone as well (``2``: that
+only), which is where the kernel's tiles (heads a grid step x queries a tile x rows a stretch) are
+compared.  One JSON row a measurement; ``PERF.md`` section 4 has the table
+this fills.  Exits non-zero without a TPU.
 """
 
 from __future__ import annotations
@@ -30,6 +34,18 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--config", default="joyai_llm_flash")
     p.add_argument("--chunks", default="512,1024,2048")
+    p.add_argument("--impl", default="",
+                   help="formulations of the chunk's latent attention to "
+                        "time, of ops.attention.paged_latent_chunk_"
+                        "formulation's names (latent_chunk_attn, plain); "
+                        "default: as built")
+    p.add_argument("--kernel-tiles", default="",
+                   help="HEADSxQUERIESxSTRETCH of the chunk kernel to time "
+                        "(ops.attention.LATENT_CHUNK_HEADS, _QUERIES, "
+                        "LATENT_STRETCH); default: as built")
+    p.add_argument("--attn", type=int, default=0,
+                   help="1: time one layer's chunk attention alone too; "
+                        "2: that alone, no whole chunk")
     p.add_argument("--tiles", default="",
                    help="rows of the wide tile of the grouped matmuls to "
                         "time (parallel.moe.GROUP_TILE_WIDE; the small tile "
@@ -48,6 +64,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from distributedtensorflow_tpu import models, runtime
+    from distributedtensorflow_tpu.ops import attention
     from distributedtensorflow_tpu.parallel import moe
     from distributedtensorflow_tpu.serve import kv_cache
     from distributedtensorflow_tpu.serve.model import (family_of,
@@ -80,24 +97,61 @@ def main(argv=None) -> int:
         return 1e3 * statistics.median(walls[1:])
 
     table_row = {"full": jnp.arange(cols, dtype=jnp.int32)}
+    form = base.cache_rows
+    formulation = attention.paged_latent_chunk_formulation
+    as_built = (attention.LATENT_CHUNK_HEADS, attention.LATENT_CHUNK_QUERIES,
+                attention.LATENT_STRETCH)
+    # all three are read when a program is traced: a program a setting
+    settings = [(impl or None, tiles)
+                for impl in args.impl.split(",")
+                for tiles in ([as_built] if impl == "plain" else [
+                    tuple(int(x) for x in t.split("x"))
+                    for t in args.kernel_tiles.split(",") if t] or [as_built])]
+    starts = [int(s) for s in args.starts.split(",")]
     for chunk in (int(c) for c in args.chunks.split(",")):
         tokens = rng.integers(0, base.vocab_size, chunk)
-        for tile in [int(t) for t in args.tiles.split(",") if t] or [
-                moe.GROUP_TILE_WIDE]:
-            # read when the program is traced: a program a tile
-            moe.GROUP_TILE_WIDE = tile
-            prog = make_programs(base, chunk=chunk, block_size=bs,
-                                 layers=layers)
-            for start in (int(s) for s in args.starts.split(",")):
-                start = min(start, args.max_context - chunk)
-                ms = timed(lambda pools: prog.prefill(
-                    params, pools, tokens, start, table_row, chunk - 1))
-                print(json.dumps({
-                    "program": "prefill_chunk", "chunk": chunk,
-                    "group_tile": moe.group_tile(
-                        chunk, base.experts_per_token, base.num_experts),
-                    "start": start, "ms": round(ms, 3),
-                    "us_per_token": round(1e3 * ms / chunk, 2)}), flush=True)
+        for impl, tiles in settings:
+            (attention.LATENT_CHUNK_HEADS, attention.LATENT_CHUNK_QUERIES,
+             attention.LATENT_STRETCH) = tiles
+            attention.paged_latent_chunk_formulation = (
+                formulation if impl is None else lambda *a, impl=impl: impl)
+            tag = {"chunk": chunk, "chunk_attention": form.chunk_formulation(
+                bs, chunk, base.kernel_impl), "kernel_tiles": "x".join(
+                    str(x) for x in tiles)}
+            if args.attn:
+                h = base.num_heads
+                q = [jnp.asarray(rng.standard_normal((chunk, h, d)),
+                                 base.dtype)
+                     for d in (base.qk_nope_head_dim, base.qk_rope_head_dim)]
+                w = params["h1"]["attn"]
+                one = jax.jit(lambda pools, start: form.chunk(
+                    q, start, pools["full"], table_row["full"], layer=1,
+                    block_size=bs, impl=base.kernel_impl,
+                    w_uk=w["w_uk"], w_uv=w["w_uv"]))
+                for start in starts:
+                    start = min(start, args.max_context - chunk)
+                    ms = timed(lambda pools: (
+                        one(pools, jnp.int32(start)), pools))
+                    print(json.dumps({
+                        "program": "chunk_attention_one_layer", **tag,
+                        "start": start, "ms": round(ms, 3)}), flush=True)
+            for tile in [] if args.attn == 2 else [
+                    int(t) for t in args.tiles.split(",") if t] or [
+                        moe.GROUP_TILE_WIDE]:
+                moe.GROUP_TILE_WIDE = tile
+                prog = make_programs(base, chunk=chunk, block_size=bs,
+                                     layers=layers)
+                for start in starts:
+                    start = min(start, args.max_context - chunk)
+                    ms = timed(lambda pools: prog.prefill(
+                        params, pools, tokens, start, table_row, chunk - 1))
+                    print(json.dumps({
+                        "program": "prefill_chunk", **tag,
+                        "group_tile": moe.group_tile(
+                            chunk, base.experts_per_token, base.num_experts),
+                        "start": start, "ms": round(ms, 3),
+                        "us_per_token": round(1e3 * ms / chunk, 2)}),
+                        flush=True)
 
     prog = make_programs(base, chunk=512, block_size=bs, layers=layers)
     per_slot = args.kv_blocks // args.slots
