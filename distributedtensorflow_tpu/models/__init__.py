@@ -23,6 +23,11 @@ from .joyai import (  # noqa: F401
     joyai_llm_flash,
     joyai_tiny,
 )
+from .jamba import (  # noqa: F401
+    JambaConfig,
+    jamba2_3b,
+    jamba_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

@@ -655,7 +655,7 @@ def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
     these shapes: ``"paged_attn"`` (the kernel) or ``"plain"`` (the gather
     of every table column).  A test of shapes and of ``impl`` alone, so a
     program can say what it was built with (``serve.model``)."""
-    fits = (head_dim in (64, LANES) and heads // kv_heads <= 8
+    fits = (head_dim in (64, LANES) and heads // kv_heads <= 32
             and PAGED_ROWS % block_size == 0
             and kv_heads * head_dim % LANES == 0)
     return "paged_attn" if use_kernel(impl) and fits else "plain"
@@ -690,10 +690,10 @@ def paged_window_decode_attention(
     the product over all 128 lanes is that head's scores, and its output is
     the same lanes of the same rows (the other lanes, its weights on the
     neighbour's values, are dropped).  Steps past a slot's length copy and
-    compute nothing.  Needs ``D`` of 64 or 128, ``H // Hkv <= 8``, a block
-    size that divides 128 and a pool row of whole tiles
-    (:func:`paged_decode_formulation`); other shapes, and ``impl="xla"``,
-    take the plain formulation."""
+    compute nothing.  Needs ``D`` of 64 or 128, ``H // Hkv <= 32`` (a tile's
+    query heads are its rows: 24 at 20 on 1), a block size that divides 128
+    and a pool row of whole tiles (:func:`paged_decode_formulation`); other
+    shapes, and ``impl="xla"``, take the plain formulation."""
     b, h, d = q.shape
     width = k_pool.shape[-1]
     h_kv = width // d

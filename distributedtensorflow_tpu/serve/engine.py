@@ -332,6 +332,11 @@ class Engine:
             max_context=max_context, write_ahead=prefill_chunk,
             num_blocks={"full": num_blocks, "window": window_blocks},
         )
+        if self.prefix_cache and self.kv.state is not None:
+            raise ValueError(
+                "prefix_cache is not implemented over a state group yet (a "
+                "shared prefix has no snapshot of the state at its end): "
+                "serve it without")
         if self.prefix_cache and not self.kv.shares_prefixes:
             raise ValueError(
                 "prefix_cache is not implemented for a model of several "
@@ -419,6 +424,10 @@ class Engine:
         #: the latent rows its decode iteration read (attended tokens x
         #: latent layers); counted only where a group stores latent rows
         self._step_latent = [0, 0]
+        #: the current step's tokens through the recurrence of a state
+        #: group: the real tokens of its prefill chunks and one a decoding
+        #: slot; counted only where a group keeps a state a slot
+        self._step_scan = 0
         #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
         #: and closes its profiler windows by iteration, so a capture
         #: armed through ``POST /profilez?steps=N`` holds N iterations.
@@ -731,6 +740,12 @@ class Engine:
         c = self.prefill_chunk
         return -(-prompt_len // c) * c
 
+    def _chunk_real_tokens(self, prompt_len: int, start: int) -> int:
+        """How many tokens of the chunk at ``start`` are the prompt's (the
+        rest of its ``prefill_chunk`` positions are padding): the one place
+        that says where a prompt ends inside the chunk grid."""
+        return min(max(prompt_len - start, 0), self.prefill_chunk)
+
     def _footprint(self, prompt_len: int, max_new: int) -> int:
         """Worst-case KV positions a request can touch: the padded prompt
         (the final prefill chunk writes pad K/V) or the full generation,
@@ -757,6 +772,7 @@ class Engine:
         self._step_evicted = 0
         self._step_sampled = (0, 0)
         self._step_latent = [0, 0]
+        self._step_scan = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace): the step record's walls are its durations, and the
         # `step` attribute is the steps.jsonl `step` this iteration gets.
@@ -871,9 +887,11 @@ class Engine:
         """Step-log fields of the layer groups and the expert layers:
         each pool's blocks in use, the blocks a window group let go since
         the last record, (decode iterations of programs with expert
-        layers only) this iteration's routing counters, and (a cache with
+        layers only) this iteration's routing counters, (a cache with
         latent rows only) the rows its prefill chunks walked and its
-        decode iteration read."""
+        decode iteration read, and (a cache with a state group only) the
+        slots holding live state and the tokens that went through the
+        recurrence."""
         fields = {}
         if occupancy and self._routed is not None:
             pairs, hit, load = (int(v) for v in np.asarray(self._routed))
@@ -888,8 +906,11 @@ class Engine:
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
-        for name, g in self.kv.groups.items():
+        for name, g in self.kv.paged.items():
             fields[f"kv_blocks_used_{name}"] = g.allocator.used_blocks
+        if self.kv.state is not None:
+            fields["state_slots_used"] = int(self.kv.state.live.sum())
+            fields["scan_tokens"] = self._step_scan
         return fields
 
     def step_records(self, n: int | None = None) -> list[dict]:
@@ -1035,8 +1056,9 @@ class Engine:
 
     def _run_prefill_chunk(self, req: GenRequest):
         """One fixed-width prefill chunk for one request: it reads the
-        slot's earlier chunks through its page-table rows, so chunks are
-        stateless and freely interleavable across requests."""
+        slot's earlier chunks through its page-table rows (and, over a state
+        group, continues the state they left in the slot's own row), so
+        chunks of several requests interleave freely."""
         slot = req.slot
         c = self.prefill_chunk
         start = req._fill_next
@@ -1046,18 +1068,20 @@ class Engine:
         # scans) — interference stall, not its own prefill compute
         req.attr_stall_s += max(t_chunk0 - req._t_attr, 0.0)
         with obs_tracing.span("engine.prefill_chunk"):
-            last_ix = min(max(len(req.prompt) - 1 - start, 0), c - 1)
+            real = self._chunk_real_tokens(len(req.prompt), start)
             self.kv.prepare_write(slot, start + c)
             last_logits, pools = self.programs.prefill(
                 self.params, self.kv.pools(),
                 req._fill_buf[start:start + c], start,
                 {name: jnp.asarray(g.block_tables[slot].copy())
                  for name, g in self.kv.groups.items()},
-                last_ix,
+                real,
             )
             self.kv.set_pools(pools)
             if self.kv.latent_layers:
                 self._step_latent[0] += start + c
+            if self.kv.state is not None:
+                self._step_scan += real
             req._fill_next = start + c
             self.kv.note_written(
                 slot, max(min(start + c, len(req.prompt)),
@@ -1164,6 +1188,8 @@ class Engine:
             if self.kv.latent_layers:
                 self._step_latent[1] = self.kv.latent_layers * int(
                     self.kv.seq_lens[slots].sum())
+            if self.kv.state is not None:
+                self._step_scan += n_active
             self._commit_tokens(
                 decoding, slots, [[t] for t in tokens.tolist()], now,
                 decode_dt, prefill_s, spec=False)
@@ -1643,6 +1669,9 @@ class Engine:
             "decode_attention": self.programs.decode_attention,
             # the same of a prefill chunk: "latent_chunk_attn" or "plain"
             "chunk_attention": self.programs.chunk_attention,
+            # the form a prefill chunk scans a state group's layers with:
+            # "ssm_chunk_scan" or "plain"; None where no layer keeps a state
+            "chunk_scan": self.programs.chunk_scan,
             # bytes the cache stores a token over all layers
             "cache_row_bytes": self.kv.row_bytes,
             "spec_acceptance_rate": (

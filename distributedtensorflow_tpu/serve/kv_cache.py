@@ -711,7 +711,10 @@ class PagedKVCache:
 #   ``next position - window`` un-maps it (its table entry goes back to the
 #   scratch block) and re-uses it for the slot's next block: a ring, by way
 #   of the table.  The reservation is all-or-nothing like the full group's,
-#   so there is still no mid-flight out-of-blocks.
+#   so there is still no mid-flight out-of-blocks;
+# - a *state* group (:class:`StateGroup`) holds the layers that keep a
+#   fixed-size state a slot and nothing a token (``models.jamba``'s Mamba
+#   layers): arrays ``(layers, slots, ...)``, no pages, no allocator.
 #
 # :class:`GroupedKVCache` admits against all groups at once and otherwise
 # answers the engine as one cache: it is the only cache the engine holds.  A
@@ -803,27 +806,76 @@ class WindowKVGroup(PagedKVCache):
         return int(self._next[slot] - self._first[slot])
 
 
+class StateGroup:
+    """A layer group that keeps a fixed-size state a *slot*, whatever its
+    context (``models.jamba``'s Mamba layers: the convolution tail and the
+    scan state, ``ops.ssm.SSMState``): no pages and no allocator.  Its arrays
+    are ``(layers, max_slots, ...)`` each, one a state array of ``rows``, every
+    slot's provisioned; a slot's "page table" is the one column that names
+    the slot itself, which is how a prefill chunk learns whose state it
+    scans (``serve.model``).  A slot's state is live from admission to
+    release; a new occupant's first chunk (``start == 0``) starts from zeros
+    inside the program, so release launches nothing.  There is nothing a
+    token to share, copy on write or take back: a state holds no snapshot of
+    an earlier position (``GroupedKVCache.rollback`` / ``register_prefix``
+    raise where a state group exists)."""
+
+    def __init__(self, *, num_layers: int, rows, max_slots: int,
+                 dtype=jnp.float32):
+        self.rows = rows
+        self.max_slots = max_slots
+        self.pools = tuple(
+            jnp.zeros((num_layers, max_slots, *shape), dt)
+            for shape, dt in rows.arrays(dtype))
+        self.block_tables = np.arange(max_slots, dtype=np.int32)[:, None]
+        self.tables_version = 0     # the table never changes
+        self.live = np.zeros((max_slots,), bool)
+        #: bytes a slot over the group's layers
+        self.slot_bytes = num_layers * rows.slot_bytes(dtype)
+
+    def admit(self, slot: int) -> None:
+        if self.live[slot]:
+            raise OutOfBlocksError(f"slot {slot} is already occupied")
+        self.live[slot] = True
+
+    def release(self, slot: int) -> None:
+        self.live[slot] = False
+
+    def stats(self) -> dict:
+        return {"slots_total": self.max_slots,
+                "slots_live": int(self.live.sum()),
+                "slot_bytes": self.slot_bytes,
+                "bytes_total": self.slot_bytes * self.max_slots}
+
+
 class GroupedKVCache:
     """Several layer groups behind the one interface the engine drives.
 
-    ``groups`` maps a name (``"full"``, ``"window"``) to a
-    :class:`PagedKVCache` or :class:`WindowKVGroup`; ``layers`` maps the
-    same names to the model layers each holds, in pool order.  Admission is
-    all-or-nothing over all groups; everything else fans out.  Prompt
-    prefixes are shared by a cache of one group, through that group's
-    index (several groups would need an index each, kept in step): see
+    ``groups`` maps a name (``"full"``, ``"window"``, ``"state"``) to a
+    :class:`PagedKVCache`, a :class:`WindowKVGroup` or a
+    :class:`StateGroup`; ``layers`` maps the same names to the model layers
+    each holds, in pool order.  Admission is all-or-nothing over all groups;
+    everything else fans out, the page bookkeeping to the groups that have
+    pages (``paged``).  Prompt prefixes are shared by a cache of one group,
+    through that group's index (several groups would need an index each,
+    kept in step, and a state group a snapshot a block): see
     ``shares_prefixes``."""
 
     def __init__(self, groups: dict[str, PagedKVCache],
                  layers: dict[str, tuple[int, ...]]):
         self.groups = groups
         self.layers = layers
-        first = next(iter(groups.values()))
+        #: the group of per-slot state, or None
+        self.state: StateGroup | None = groups.get("state")
+        #: the groups that hold pages
+        self.paged = {name: g for name, g in groups.items()
+                      if g is not self.state}
+        first = next(iter(self.paged.values()))
         self.block_size = first.block_size
         self.max_slots = first.max_slots
         self.max_context = first.max_context
         self.seq_lens = first.seq_lens
-        for g in groups.values():
+        for g in self.paged.values():
             g.seq_lens = self.seq_lens      # one length a slot, shared
         #: the one group whose prefix index this cache shares through, or
         #: None: no block of any group is then ever shared between slots
@@ -831,7 +883,7 @@ class GroupedKVCache:
         #: layers whose group stores one row a token that every head shares,
         #: not the K/V pair
         self.latent_layers = sum(
-            len(layers[name]) for name, g in groups.items()
+            len(layers[name]) for name, g in self.paged.items()
             if g.rows.shared_row)
 
     @property
@@ -841,8 +893,8 @@ class GroupedKVCache:
     #: the census the engine's gauges read: the full group's if there is one
     @property
     def allocator(self) -> BlockAllocator:
-        return self.groups.get(
-            "full", next(iter(self.groups.values()))).allocator
+        return self.paged.get(
+            "full", next(iter(self.paged.values()))).allocator
 
     @property
     def tables_version(self) -> int:
@@ -850,16 +902,17 @@ class GroupedKVCache:
 
     @property
     def num_blocks_total(self) -> int:
-        return sum(g.allocator.num_blocks for g in self.groups.values())
+        return sum(g.allocator.num_blocks for g in self.paged.values())
 
     @property
     def cow_copies(self) -> int:
-        return sum(g.cow_copies for g in self.groups.values())
+        return sum(g.cow_copies for g in self.paged.values())
 
     def pools(self) -> dict:
-        """``{group: its pools}`` (``(k_pool, v_pool)``, or the one pool of
-        latent rows), as the programs take them (and donate them: hand the
-        updated ones back through ``set_pools``)."""
+        """``{group: its pools}`` (``(k_pool, v_pool)``, the one pool of
+        latent rows, or a state group's arrays), as the programs take them
+        (and donate them: hand the updated ones back through
+        ``set_pools``)."""
         return {name: g.pools for name, g in self.groups.items()}
 
     def set_pools(self, pools: dict) -> None:
@@ -868,14 +921,15 @@ class GroupedKVCache:
 
     @property
     def row_bytes(self) -> int:
-        """Bytes stored a token over all layers of all groups."""
+        """Bytes stored a token over all layers of all groups (a state
+        group stores none a token: ``state.slot_bytes`` a slot)."""
         return sum(g.row_bytes * len(self.layers[name])
-                   for name, g in self.groups.items())
+                   for name, g in self.paged.items())
 
     def check_fits(self, tokens: int) -> None:
         """Raise ``ValueError`` if no pool state could ever hold a request
         of ``tokens`` positions (it would wedge the FIFO head forever)."""
-        for name, g in self.groups.items():
+        for name, g in self.paged.items():
             if g.reservation(tokens) > g.allocator.num_blocks:
                 raise ValueError(
                     f"request footprint {tokens} tokens needs "
@@ -888,14 +942,16 @@ class GroupedKVCache:
         and is not looked at where they are not."""
         if self._sharing is not None:
             return self._sharing.admit(slot, tokens, prompt)
-        for g in self.groups.values():
+        for g in self.paged.values():
             if g.reservation(tokens) > g.allocator.allocatable_blocks:
                 return None
         pages = None
-        for g in self.groups.values():
+        for g in self.paged.values():
             pages = g.admit(slot, tokens)
             if pages is None:       # unreachable: checked above
                 raise OutOfBlocksError("group admission raced its check")
+        if self.state is not None:
+            self.state.admit(slot)
         return pages
 
     def release(self, slot: int) -> None:
@@ -903,16 +959,24 @@ class GroupedKVCache:
             g.release(slot)
 
     def prepare_write(self, slot: int, end: int) -> None:
-        for g in self.groups.values():
+        for g in self.paged.values():
             g.prepare_write(slot, end)
 
     def note_written(self, slots, tokens) -> None:
-        for g in self.groups.values():
+        for g in self.paged.values():
             g.note_written(slots, tokens)
 
     # -- prefix sharing: the one group's, and nothing to guard without ----
 
+    def _no_state(self, what: str) -> None:
+        if self.state is not None:
+            raise ValueError(
+                f"{what} is not implemented over a state group: a state "
+                "keeps no snapshot of an earlier position to go back to or "
+                "to share")
+
     def register_prefix(self, slot: int, tokens) -> int:
+        self._no_state("register_prefix")
         return self._sharing.register_prefix(slot, tokens)
 
     def ensure_writable(self, slot: int, pos: int):
@@ -924,22 +988,23 @@ class GroupedKVCache:
         return self._sharing.ensure_writable_range(slot, start, end)
 
     def rollback(self, slot: int, tokens: int) -> None:
+        self._no_state("rollback")
         self._sharing.rollback(slot, tokens)
 
     def billed_blocks(self, slot: int) -> float:
         """Blocks the slot holds over all groups, a shared one at its
         share (``PagedKVCache.billed_blocks``)."""
-        return sum(g.billed_blocks(slot) for g in self.groups.values())
+        return sum(g.billed_blocks(slot) for g in self.paged.values())
 
     @property
     def blocks_recycled(self) -> int:
         return sum(getattr(g, "blocks_recycled", 0)
-                   for g in self.groups.values())
+                   for g in self.paged.values())
 
     def stats(self) -> dict:
         """The full group's census at the top level (the keys every reader
         of ``stats()`` knows), every group's under ``"groups"``."""
-        per_group = {name: g.stats() for name, g in self.groups.items()}
+        per_group = {name: g.stats() for name, g in self.paged.items()}
         top = dict(per_group.get("full", next(iter(per_group.values()))))
         top["groups"] = {
             name: {"blocks_total": s["blocks_total"],
@@ -947,37 +1012,58 @@ class GroupedKVCache:
                    "blocks_free": s["blocks_free"]}
             for name, s in per_group.items()}
         top["blocks_recycled"] = self.blocks_recycled
+        if self.state is not None:
+            top["state"] = self.state.stats()
         return top
+
+
+def layer_groups(cfg) -> dict[str, tuple[int, ...]]:
+    """``{group: the model layers it holds, in pool order}``, by what the
+    config says a layer keeps: ``"state"`` for the layers that keep a state a
+    slot (``cfg.keeps_state(layer)``, where a config has it), and of the
+    others ``"full"`` and ``"window"`` by attention kind
+    (``cfg.window_of(layer)``); a group with no layer is left out."""
+    keeps_state = getattr(cfg, "keeps_state", lambda layer: False)
+    kinds = {"full": [], "window": [], "state": []}
+    for i in range(cfg.num_layers):
+        kinds["state" if keeps_state(i) else
+              "full" if cfg.window_of(i) is None else "window"].append(i)
+    return {name: tuple(ls) for name, ls in kinds.items() if ls}
 
 
 def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                        max_context: int, num_blocks: dict[str, int | None],
                        write_ahead: int) -> GroupedKVCache:
-    """The groups of a model: a ``"full"`` and a ``"window"`` group,
-    whichever exist, by the attention kind its config names for a layer
-    (``cfg.window_of(layer)``; GPT-2 is one full group), each storing the
-    rows the config names (``cfg.cache_rows``: the K/V pair, or one latent
-    row).
+    """The groups of a model (:func:`layer_groups`): a ``"full"`` and a
+    ``"window"`` group, whichever exist (GPT-2 is one full group), each
+    storing the rows the config names (``cfg.cache_rows``: the K/V pair, or
+    one latent row), and a ``"state"`` group of what ``cfg.state_rows`` names.
     ``num_blocks[name] = None`` provisions every slot's worst
     case (full provisioning; fewer oversubscribes — paged memory is the
     point — and admission control, not OOM, then absorbs the pressure)."""
-    if all(cfg.window_of(i) is not None for i in range(cfg.num_layers)):
+    layers = layer_groups(cfg)
+    if set(layers) == {"state"}:
+        raise ValueError(
+            "serving a model of state layers only is not implemented yet: "
+            "the slots' lengths and admission live with a paged group")
+    if "full" not in layers:
         raise ValueError(
             "serving a model of window layers only is not implemented yet "
             "(a lone group is the one prefixes are shared and blocks copied "
             "on write through, and a ring is neither): serve it with full "
             "attention, attn_window=None")
     per_slot = max_context // block_size
-    groups, layers = {}, {}
-    for name, is_window in (("full", False), ("window", True)):
-        ls = tuple(i for i in range(cfg.num_layers)
-                   if (cfg.window_of(i) is not None) == is_window)
-        if not ls:
+    groups = {}
+    for name, ls in layers.items():
+        if name == "state":
+            groups[name] = StateGroup(
+                num_layers=len(ls), rows=cfg.state_rows, max_slots=max_slots,
+                dtype=cfg.dtype)
             continue
         kw = dict(num_layers=len(ls), rows=cfg.cache_rows,
                   max_slots=max_slots, block_size=block_size,
                   max_context=max_context, dtype=cfg.dtype)
-        if is_window:
+        if name == "window":
             window = cfg.window_of(ls[0])
             ring = -(-(window + write_ahead) // block_size) + 1
             n = num_blocks.get(name) or max_slots * min(per_slot, ring)
@@ -986,5 +1072,4 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
         else:
             n = num_blocks.get(name) or max_slots * per_slot
             groups[name] = PagedKVCache(num_blocks=n, **kw)
-        layers[name] = ls
     return GroupedKVCache(groups, layers)

@@ -10,9 +10,10 @@ prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
-``models.joyai``): ``embed(params, ids, cfg)``, ``block(p, x, cfg, layer,
-positions, attend, token_mask=None) -> (x, counters)`` and ``head(params, x,
-cfg)``, over activations ``(T, d)``, plus ``init_params(cfg, key)``.  Its
+``models.joyai``, ``models.jamba``): ``embed(params, ids, cfg)``, ``block(p,
+x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
+``head(params, x, cfg)``, over activations ``(T, d)``, plus
+``init_params(cfg, key)``.  Its
 configuration says what a cached row is (``cfg.cache_rows``, an
 ``ops.attention.KVRows`` or ``LatentRows``: the widths of the group's pools
 and the paged formulations over them), and its block calls ``attend(q,
@@ -29,13 +30,21 @@ and the three programs differ only in where the rows live:
   queries attend the slot's earlier chunks through the page-table row
   (``ops.attention.paged_chunk_attention`` or
   ``paged_latent_chunk_attention``, a running softmax over the context up to
-  the chunk's end).  There is no dense cache, so a chunk is
+  the chunk's end).  There is no dense cache, so for the families whose
+  layers keep only rows a token (gpt, afmoe, joyai) a chunk is
   *stateless*: any slot's next chunk can run at any time, the scheduler can
   interleave chunks of several requests with decode steps (ISSUE 14
   budgeted prefill), and a request admitted onto a cached prefix starts
   from the shared blocks without a special load path.  Any prompt length =
   a Python loop of these fixed-width calls; the head is applied to the one
-  row the engine wants.
+  row the engine wants.  For a family with a *state group* (jamba: layers
+  that keep a fixed-size state a slot, ``cfg.keeps_state(layer)``) a chunk
+  is not stateless: it scans from the state the slot's last chunk left in
+  the slot's row of the group's arrays (zeros at ``start == 0``) and stores
+  the state back, so a slot's chunks still interleave freely with other
+  slots' work, in order; the program takes the count of real tokens, since
+  a pad step must be the identity.  On such a layer the block is handed a
+  :class:`_SlotState` in ``attend``'s place.
 - :func:`make_decode_fn` — one token for all ``max_slots`` slots against
   the paged pool (``ops.attention.paged_window_decode_attention`` or
   ``paged_latent_decode_attention``: on the TPU a kernel that reads only the
@@ -47,9 +56,11 @@ and the three programs differ only in where the rows live:
 (There is also a tiny pool-level block-copy program in ``serve.kv_cache``
 — the copy-on-write path — compiled only if a CoW ever fires.)
 
-``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, or the one pool
-of latent rows) and ``tables`` ``{group: page
-table}`` (``serve.kv_cache.GroupedKVCache``: layers in groups by attention
+``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, the one pool
+of latent rows, or a state group's ``(convolution tails, scan states)``,
+``(layers, slots, ...)`` each) and ``tables`` ``{group: page
+table}`` (a state group's is the one column that names the slot;
+``serve.kv_cache.GroupedKVCache``: layers in groups by attention
 kind; GPT-2 is one full group); ``layers`` maps a group to the model layers
 it holds, in pool order.  The pools are donated: steady-state serving does
 not allocate.  All of these programs take a pool in the one form
@@ -73,7 +84,9 @@ medium's ``jit_decode`` 0.23 ms of 3.24 over 24 layers; my chip run, PR 30),
 under whatever scope the family's block calls ``attend`` in: ``h<i>`` for
 GPT-2, ``h<i>/window_attn`` or ``h<i>/full_attn`` for afmoe,
 ``h<i>/latent_attn`` for joyai, whose form adds ``absorb`` and ``v_up``
-beside them), ``head``, ``sample``,
+beside them, ``h<i>/attn`` for jamba, whose Mamba layers have
+``h<i>/{state_read,state_write}`` and ``h<i>/mamba/{in_proj,conv,x_proj,
+dt_proj,scan|ssm_step,gate,out_proj}``), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
 Metadata only, so a profiler trace can say which stage a device operation
 belongs to.
@@ -86,7 +99,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, gpt, joyai
+from ..models import afmoe, gpt, jamba, joyai
+from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
 from .sampling import sample_burst
 
 __all__ = [
@@ -111,6 +125,95 @@ def _write_rows(pools: tuple, li: int, at, rows: tuple) -> tuple:
                      for pool, r in zip(pools, rows))
 
 
+class _SlotState:
+    """The ``mixer`` hook of a state layer (``models.jamba``): the programs'
+    own, as ``attend`` is.  ``conv`` and ``scan`` read the layer's state out
+    of the group's arrays (scope ``state_read``), run the form (``mamba/conv``;
+    ``mamba/scan`` or ``mamba/ssm_step``) and store the state back
+    (``state_write``) — the convolution tail is array 0, the scan state
+    array 1 (``ops.ssm.SSMState.arrays``).  ``pools`` is the program's dict
+    of pools, updated in place."""
+
+    def __init__(self, pools: dict, li: int):
+        self.pools, self.li = pools, li
+
+    def _store(self, which: int, value) -> None:
+        arrays = list(self.pools["state"])
+        arrays[which] = self._put(arrays[which], value)
+        self.pools["state"] = tuple(arrays)
+
+    def conv(self, u, w, b):
+        with jax.named_scope("state_read"):
+            tail = self._get(self.pools["state"][0])
+        with jax.named_scope("mamba"), jax.named_scope("conv"):
+            out, tail = self._conv(u, tail, w, b)
+        with jax.named_scope("state_write"):
+            self._store(0, tail)
+        return out
+
+    def scan(self, u, delta, a, b, c, d):
+        with jax.named_scope("state_read"):
+            state = self._get(self.pools["state"][1])
+        with jax.named_scope("mamba"), jax.named_scope(self.scan_scope):
+            y, state = self._scan(u, delta, a, b, c, d, state)
+        with jax.named_scope("state_write"):
+            self._store(1, state)
+        return y
+
+
+class _ChunkState(_SlotState):
+    """One slot's state through a prefill chunk of which ``valid`` tokens are
+    real: from zeros at ``start == 0`` (a slot's new occupant needs no reset
+    launch), pad positions identity steps, the tail ending at the last real
+    token."""
+
+    scan_scope = "scan"
+
+    def __init__(self, pools, li, slot, start, valid, impl):
+        super().__init__(pools, li)
+        self.slot, self.start, self.valid, self.impl = slot, start, valid, impl
+
+    def _get(self, array):
+        mine = jax.lax.dynamic_index_in_dim(array[self.li], self.slot, 0,
+                                            keepdims=False)
+        return jnp.where(self.start == 0, jnp.zeros_like(mine), mine)
+
+    def _put(self, array, value):
+        return array.at[self.li, self.slot].set(value.astype(array.dtype))
+
+    def _conv(self, u, tail, w, b):
+        return causal_conv(u, tail, w, b, self.valid)
+
+    def _scan(self, u, delta, a, b, c, d, state):
+        return ssm_chunk_scan(u, delta, a, b, c, d, state, self.valid,
+                              impl=self.impl)
+
+
+class _StepState(_SlotState):
+    """Every slot's state through one decode step; an inactive slot's (free,
+    or between two of its prefill chunks) stays as it is, bit for bit."""
+
+    scan_scope = "ssm_step"
+
+    def __init__(self, pools, li, active):
+        super().__init__(pools, li)
+        self.active = active
+
+    def _get(self, array):
+        return array[self.li]
+
+    def _put(self, array, value):
+        keep = self.active.reshape((-1,) + (1,) * (value.ndim - 1))
+        return array.at[self.li].set(
+            jnp.where(keep, value.astype(array.dtype), array[self.li]))
+
+    def _conv(self, u, tails, w, b):
+        return conv_step(u, tails, w, b)
+
+    def _scan(self, u, delta, a, b, c, d, states):
+        return ssm_step(u, delta, a, b, c, d, states)
+
+
 def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                     layers: dict[str, tuple[int, ...]]):
     """``fn(params, pools, tokens (chunk,), start, table_rows, last_ix) ->
@@ -118,17 +221,22 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     absolute position ``start``; ``table_rows`` the slot's page-table row a
     group and ``last_ix`` the in-chunk index whose logits the engine wants
     (the final prompt token's, clamped into range on non-final chunks whose
-    logits are discarded).  The pools are donated."""
+    logits are discarded).  Over a state group the program takes one more
+    argument, ``valid``: how many of the chunk's tokens are real (pad K/V
+    rows are harmless, a pad step of a recurrence is not); the slot's state
+    is row ``table_rows["state"][0]`` of the group's arrays.  The pools are
+    donated."""
     where, form = _group_of(layers), cfg.cache_rows
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_chunk(params, pools, tokens, start, table_rows, last_ix):
+    def prefill_chunk(params, pools, tokens, start, table_rows, last_ix,
+                      *valid):
         pools = dict(pools)
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
         with jax.named_scope("kv_rows"):
             rows = {name: row[positions // block_size] * block_size
                     + positions % block_size
-                    for name, row in table_rows.items()}
+                    for name, row in table_rows.items() if name != "state"}
         x = family.embed(params, tokens, cfg)
         for layer in range(cfg.num_layers):
             name, li = where[layer]
@@ -140,9 +248,12 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                     block_size=block_size, window=cfg.window_of(layer),
                     impl=cfg.kernel_impl, **weights)
 
+            mixer = attend if name != "state" else _ChunkState(
+                pools, li, table_rows[name][0], start, *valid,
+                cfg.kernel_impl)
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
-                                   positions, attend)
+                                   positions, mixer)
         last = jax.lax.dynamic_slice_in_dim(x, last_ix, 1, 0)
         return family.head(params, last, cfg)[0], pools
 
@@ -157,7 +268,9 @@ def make_decode_fn(family, cfg, *, block_size: int,
     (the new token is written at that position, then attends ``seq_len + 1``
     positions), and ``active`` masks unoccupied slots: their write lands in
     the reserved scratch block and their logits are discarded by the engine,
-    so the program shape never depends on occupancy.  ``greedy`` is int32
+    so the program shape never depends on occupancy (a state group has no
+    scratch: an inactive slot's state is written back as it was).  ``greedy``
+    is int32
     ``(slots,)``, the arg-max of each row of the float32 ``logits`` (the
     first of equal maxima, as ``np.argmax`` takes it): all the host needs of
     an iteration in which nobody samples, so the logits can stay on the
@@ -177,6 +290,8 @@ def make_decode_fn(family, cfg, *, block_size: int,
         with jax.named_scope("kv_rows"):
             rows = {}
             for name, table in tables.items():
+                if name == "state":
+                    continue
                 blk = jnp.take_along_axis(
                     table, (positions // bs)[:, None], axis=1)[:, 0]
                 rows[name] = jnp.where(
@@ -194,9 +309,11 @@ def make_decode_fn(family, cfg, *, block_size: int,
                     block_size=bs, window=cfg.window_of(layer),
                     impl=cfg.kernel_impl, **weights)
 
+            mixer = attend if name != "state" else _StepState(
+                pools, li, active)
             with jax.named_scope(f"h{layer}"):
                 x, counters = family.block(
-                    params[f"h{layer}"], x, cfg, layer, positions, attend,
+                    params[f"h{layer}"], x, cfg, layer, positions, mixer,
                     token_mask=active)
             if counters is not None:
                 routed.append(counters)
@@ -330,6 +447,7 @@ PROGRAMS = {
     gpt.GPTConfig: gpt,
     afmoe.AfmoeConfig: afmoe,
     joyai.JoyaiConfig: joyai,
+    jamba.JambaConfig: jamba,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -338,14 +456,26 @@ PROGRAMS = {
 #: at all: ``paged_verify_attention`` masks no window; joyai's draft module,
 #: which predicts several tokens for self-speculation, is not built and its
 #: latent rows have no verify formulation), so it is refused until it has
-#: such tests and a cell of its own.
+#: such tests and a cell of its own.  Over a state group there is more in
+#: the way than tests: a rejected draft's steps cannot be rolled back out of
+#: a state, which keeps no earlier position (jamba).
 FUSED = (gpt,)
 
 #: the families a request may be admitted for onto cached prefix blocks: those
 #: with a test that holds such a request to the uncached logits
 #: (tests/test_serve.py).  (A cache of several layer groups shares no prefixes
-#: whatever the family: ``serve.engine``.)
+#: whatever the family: ``serve.engine``; a state group would need a snapshot
+#: of the state at every shared block's end.)
 PREFIX = (gpt, afmoe)
+
+
+#: what stands in an option's way over a state group, whatever tests exist
+_STATE_LACKS = {
+    "prefix_cache": "a shared prefix has no snapshot of the state at its end",
+    "fused_sampling": "its sampled program is the verify program at no "
+                      "draft, which has no state formulation",
+    "speculate": "a rejected draft cannot be rolled back out of a state",
+}
 
 
 def family_of(cfg):
@@ -376,7 +506,10 @@ class Programs:
     - ``chunk_attention``: the same of ``prefill``: ``"latent_chunk_attn"``
       (the kernel over latent rows that keeps a chunk's scores in VMEM) or
       ``"plain"`` (the loop whose scores go through HBM; all K/V rows
-      have)."""
+      have);
+    - ``chunk_scan``: the form ``prefill`` scans a state group's layers
+      with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM)
+      or ``"plain"`` (``lax.scan``); None where no layer keeps a state."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,
                  layers: dict[str, tuple[int, ...]]):
@@ -402,13 +535,26 @@ class Programs:
         return self.cfg.cache_rows.chunk_formulation(
             self.block_size, self.chunk, self.cfg.kernel_impl)
 
+    @property
+    def chunk_scan(self) -> str | None:
+        if "state" not in self.layers:
+            return None
+        return self.cfg.state_rows.chunk_formulation(
+            self.chunk, self.cfg.kernel_impl)
+
     def prefill(self, params, pools, tokens, start: int, table_rows,
-                last_ix: int):
+                real: int):
+        """The chunk at ``start`` of which the first ``real`` tokens are the
+        prompt's (the rest padding): the logits are those of the last real
+        one."""
+        valid = (jnp.int32(real),) if "state" in self.layers else ()
         return self.prefill_chunk(params, pools, jnp.asarray(tokens),
                              jnp.int32(start), table_rows,
-                             jnp.int32(last_ix))
+                             jnp.int32(max(real - 1, 0)), *valid)
 
     def _refuse(self, option: str, lacking: str):
+        if "state" in self.layers:
+            lacking = _STATE_LACKS[option]
         raise ValueError(
             f"{option} is not implemented for the "
             f"{self.family.__name__.rsplit('.', 1)[-1]} family yet "

@@ -43,8 +43,11 @@ from .model import FUSED, family_of, make_programs
 #: it: ``pools['full'][0]`` is the group's ``k_pool`` (or its one pool of
 #: latent rows), ``[1]`` its ``v_pool`` (``copy_block`` takes ``pools[0]``,
 #: ``pools[1]``).
-_POOLS_ARG = re.compile(r"^pools(?:\[.*\])?\[([01])\]$")
+_POOLS_ARG = re.compile(r"^pools(?:\[\\?'(\w+)\\?'\])?\[([01])\]$")
 _POOL_NAMES = ("k_pool", "v_pool")
+#: the arrays of a state group (``ops.ssm.SSMState.arrays``), donated like
+#: the pools
+_STATE_NAMES = ("conv_tail", "scan_state")
 
 _RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
 _INSTRUCTION = re.compile(
@@ -57,7 +60,9 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
     """``{name: (jitted program, abstract arguments)}`` for the five
     programs that take the pool, at the shapes an ``Engine`` with these
     settings gives them (``cfg.max_seq`` is the serving context; a model of
-    one full group, as GPT-2)."""
+    one full group, as GPT-2; or of a full group and a state group, whose
+    arrays are ``pools["state"]`` and whose prefill chunk takes the count of
+    real tokens)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -66,8 +71,9 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda: family_of(cfg).init_params(
             cfg, jax.random.PRNGKey(0))))
+    layers = kv_cache.layer_groups(cfg)
     pools = {"full": tuple(
-        sds(kv_cache.pool_shape(cfg.num_layers, num_blocks, block_size,
+        sds(kv_cache.pool_shape(len(layers["full"]), num_blocks, block_size,
                                 width), cfg.dtype)
         for width in cfg.cache_rows.widths)}
     table_rows = {"full": sds((cfg.max_seq // block_size,), i32)}
@@ -75,8 +81,16 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
     slots_i32 = sds((max_slots,), i32)
     active = sds((max_slots,), jnp.bool_)
     scalar = sds((), i32)
+    valid = ()
+    if "state" in layers:
+        pools["state"] = tuple(
+            sds((len(layers["state"]), max_slots, *shape), dtype)
+            for shape, dtype in cfg.state_rows.arrays(cfg.dtype))
+        table_rows["state"] = sds((1,), i32)
+        tables["state"] = sds((max_slots, 1), i32)
+        valid = (scalar,)
     programs = make_programs(cfg, chunk=chunk, block_size=block_size,
-                             layers={"full": tuple(range(cfg.num_layers))})
+                             layers=layers)
 
     def fused_args(t_width):
         return (params, pools, sds((max_slots, t_width), i32), slots_i32,
@@ -86,7 +100,8 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
     found = {
         "prefill_chunk": (
             programs.prefill_chunk,
-            (params, pools, sds((chunk,), i32), scalar, table_rows, scalar)),
+            (params, pools, sds((chunk,), i32), scalar, table_rows, scalar,
+             *valid)),
         "decode": (
             programs.decode,
             (params, pools, slots_i32, tables, slots_i32, active)),
@@ -127,10 +142,41 @@ def pool_relayouts(hlo_text: str, layer_elems: int) -> list[str]:
     return found
 
 
+_HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32", "float16": "f16"}
+
+
+def state_relayouts(hlo_text: str, slots: int, arrays) -> list[str]:
+    """The same of a state group's arrays: every ``copy`` or ``transpose``
+    whose result is, in the stored type, every slot's state of one layer or
+    of all (``(slots, *shape)`` behind at most a layer dimension, for a
+    ``(shape, dtype)`` of ``arrays``, ``ops.ssm.SSMState.arrays``): the array,
+    or a layer of it, changing form.  One slot's state, which a prefill chunk
+    reads and writes, is smaller.  Not counted: a ``convert`` (a step's
+    select and float32 arithmetic on a layer of bfloat16 tails is fused
+    arithmetic, not a change of form) and the ``copy-start`` / ``copy-done``
+    pair with which the compiler keeps a small array in its nearer memory
+    through a program and puts it back (``S(1)`` in the layout)."""
+    want = {(_HLO_DTYPES[jnp.dtype(dt).name], slots, *shape)
+            for shape, dt in arrays}
+    found = []
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(4) not in ("copy", "transpose"):
+            continue
+        dtype, dims, layout, op = m.groups()
+        dims_t = tuple(int(d) for d in dims.split(",") if d)
+        if (dtype, *dims_t) in want or (dtype, *dims_t[1:]) in want:
+            name = _OP_NAME.search(line)
+            found.append(f"{op} {dtype}[{dims}]{layout or ''} "
+                         f"{name.group(1) if name else ''}".strip())
+    return found
+
+
 def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
     """Parameter number -> (argument name, shape with layout) of the entry
     computation of an HLO module's text; a pool is named ``k_pool`` or
-    ``v_pool`` however the program takes it."""
+    ``v_pool`` however the program takes it, a state group's arrays
+    ``conv_tail`` and ``scan_state``."""
     entry = hlo_text[hlo_text.index("\nENTRY "):]
     params = {}
     for m in re.finditer(
@@ -138,27 +184,31 @@ def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
         name = m.group(3)
         pool = _POOLS_ARG.match(name)
         if pool:
-            name = _POOL_NAMES[int(pool.group(1))]
+            names = _STATE_NAMES if pool.group(1) == "state" else _POOL_NAMES
+            name = names[int(pool.group(2))]
         params[int(m.group(2))] = (name, m.group(1))
     return params
 
 
 def donated_pools(hlo_text: str) -> set[str]:
-    """The arguments among ``k_pool`` / ``v_pool`` that the module's
-    ``input_output_alias`` gives to an output: the donation took."""
+    """The arguments among ``k_pool`` / ``v_pool`` (and a state group's
+    ``conv_tail`` / ``scan_state``) that the module's ``input_output_alias``
+    gives to an output: the donation took."""
     head = hlo_text[:hlo_text.index("\n")]
     head = head.partition("input_output_alias=")[2]
     # "{ {1}: (195, {}, may-alias), {2}: (196, {}, may-alias) }, entry_..."
     aliased = {int(n) for n in re.findall(
         r"\{[\d, ]*\}: \((\d+), ", head.partition("entry_computation")[0])}
     return {name for number, (name, _) in _entry_parameters(hlo_text).items()
-            if number in aliased and name in ("k_pool", "v_pool")}
+            if number in aliased and name in _POOL_NAMES + _STATE_NAMES}
 
 
-def check_pool_programs(programs: dict, layer_elems: int) -> dict:
+def check_pool_programs(programs: dict, layer_elems: int,
+                        state: tuple | None = None) -> dict:
     """Compile each of :func:`pool_programs` and report, per program, the
-    pool-sized relayouts, the donated pools, the layout the program takes
-    ``k_pool`` in and the compile time."""
+    pool-sized relayouts (and, with ``state = (slots, arrays)``, the
+    state-sized ones: :func:`state_relayouts`), the donated pools, the layout
+    the program takes ``k_pool`` in and the compile time."""
     report = {}
     for name, (fn, args) in programs.items():
         t0 = time.monotonic()
@@ -166,7 +216,8 @@ def check_pool_programs(programs: dict, layer_elems: int) -> dict:
         layouts = {arg: shape for arg, shape
                    in _entry_parameters(text).values()}
         report[name] = {
-            "relayouts": pool_relayouts(text, layer_elems),
+            "relayouts": pool_relayouts(text, layer_elems)
+            + (state_relayouts(text, *state) if state else []),
             "donated": sorted(donated_pools(text)),
             "k_pool": layouts.get("k_pool"),
             "compile_s": round(time.monotonic() - t0, 2),
@@ -174,14 +225,19 @@ def check_pool_programs(programs: dict, layer_elems: int) -> dict:
     return report
 
 
-def failures(report: dict, pools: int = 2) -> list[str]:
+def failures(report: dict, pools: int = 2, state: bool = False) -> list[str]:
     """What :func:`check_pool_programs` found wrong, one line each, for a
-    group of ``pools`` pools (the K/V pair, or one pool of latent rows)."""
+    group of ``pools`` pools (the K/V pair, or one pool of latent rows) and,
+    with ``state``, a state group's arrays (``copy_block`` takes the pools
+    only)."""
     bad = []
     for name, r in report.items():
         for op in r["relayouts"]:
             bad.append(f"{name}: pool-sized {op}")
-        if r["donated"] != list(_POOL_NAMES[:pools]):
+        want = list(_POOL_NAMES[:pools])
+        if state and name != "copy_block":
+            want = sorted(want + list(_STATE_NAMES))
+        if r["donated"] != want:
             bad.append(f"{name}: donated in place only {r['donated']}")
     forms = {r["k_pool"] for r in report.values()}
     if len(forms) != 1:
@@ -207,16 +263,20 @@ def main(argv=None) -> int:
     cfg = dataclasses.replace(getattr(models, args.config)(),
                               max_seq=args.max_context)
     widths = cfg.cache_rows.widths
-    shape = kv_cache.pool_shape(cfg.num_layers, args.kv_blocks,
+    layers = kv_cache.layer_groups(cfg)
+    shape = kv_cache.pool_shape(len(layers["full"]), args.kv_blocks,
                                 args.block_size, widths[0])
+    state = None
+    if "state" in layers:
+        state = (args.max_slots, cfg.state_rows.arrays(cfg.dtype))
     report = check_pool_programs(
         pool_programs(cfg, max_slots=args.max_slots,
                       num_blocks=args.kv_blocks, block_size=args.block_size,
                       chunk=args.prefill_chunk, draft=args.speculate),
-        layer_elems=shape[1] * shape[2])
+        layer_elems=shape[1] * shape[2], state=state)
     # the pool as the process holds it between calls
     pool = jnp.zeros(shape, cfg.dtype)
-    bad = failures(report, pools=len(widths))
+    bad = failures(report, pools=len(widths), state=state is not None)
     print(json.dumps({
         "device": runtime.device_summary(),
         "pool_shape": shape, "pool_dtype": str(pool.dtype),

@@ -48,6 +48,9 @@ CONFIGS = {
     # the joyai family (models/joyai.py): latent attention, random init only
     "joyai_tiny": ("joyai_tiny", None),
     "joyai_llm_flash": ("joyai_llm_flash", None),
+    # the jamba family (models/jamba.py): Mamba + attention layers, random init
+    "jamba_tiny": ("jamba_tiny", None),
+    "jamba2_3b": ("jamba2_3b", None),
 }
 
 
@@ -304,6 +307,7 @@ def main(argv=None) -> int:
     startup.mark("startup.engine_build",
                  decode_attention=engine.programs.decode_attention,
                  chunk_attention=engine.programs.chunk_attention,
+                 chunk_scan=engine.programs.chunk_scan,
                  cache_row_bytes=engine.kv.row_bytes)
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation

@@ -35,6 +35,7 @@ from distributedtensorflow_tpu.ops.flash_attention import flash_attention
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
+from distributedtensorflow_tpu.ops.ssm import ssm_chunk_scan
 from distributedtensorflow_tpu.parallel import moe
 
 # GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
@@ -80,14 +81,15 @@ def _decode(q, k, v, valid):
     return _pallas_decode_attention(q, k, v, valid, interpret=False)
 
 
-def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2):
+def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2,
+           width=1024):
     # the afmoe serving shapes: 64 slots, 48 query / 8 K/V heads of 128,
     # blocks of 16, a table of 512 columns, one layer group's pool
     def fn(q, k_pool, v_pool, tables, lens):
         return paged_window_decode_attention(
             q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
             window=window, impl="pallas", interpret=False)
-    pool = _sds((layers, 2049 * 16, 1024), BF16)
+    pool = _sds((layers, 2049 * 16, width), BF16)
     return fn, (_sds((slots, heads, d), BF16), pool, pool,
                 _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32))
 
@@ -123,6 +125,18 @@ def _latent_chunk(chunk=1024, heads=32, rank=512, rope=64, nope=128,
                 _sds((columns,), jnp.int32),
                 _sds((rank, heads, nope), BF16),
                 _sds((rank, heads, nope), BF16))
+
+
+def _ssm_scan(chunk=1024, channels=5120, states=16):
+    # a jamba prefill chunk's scan of one Mamba layer at the published
+    # channel shape: u' in bf16, delta float32, the state in and out
+    def fn(u, delta, a, b, c, d, state, valid):
+        return ssm_chunk_scan(u, delta, a, b, c, d, state, valid,
+                              impl="pallas", interpret=False)
+    return fn, (_sds((chunk, channels), BF16), _sds((chunk, channels), F32),
+                _sds((states, channels), F32), _sds((chunk, states), BF16),
+                _sds((chunk, states), BF16), _sds((channels,), F32),
+                _sds((states, channels), F32), _sds((), jnp.int32))
 
 
 def _grouped(tile):
@@ -174,6 +188,12 @@ FAMILIES = {
     "latent_chunk_attn": _latent_chunk(),
     # the widest chunk the cell's sweep served: fewer heads a grid step
     "latent_chunk_attn_2048": _latent_chunk(chunk=2048),
+    # jamba2_3b's serving shapes: 32 slots, 20 query heads on ONE K/V head of
+    # 128 (24 rows a tile), a table of 2,112 columns (contexts to 33,792)
+    "paged_attn_20_on_1": _paged(None, slots=32, heads=20, d=128,
+                                 columns=2112, width=128),
+    "ssm_chunk_scan": _ssm_scan(),
+    "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
 }
 
 
@@ -331,6 +351,48 @@ def test_latent_program_keeps_the_pool_in_place_on_a_v5e(program,
         calls = re.findall(r"call @(\w*latent_chunk\w*)\(", text)
         assert len(calls) == 2 and len(set(calls)) == 1, calls
         assert text.count('kernel_name = "latent_chunk_attn"') == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
+                                                               monkeypatch):
+    """The jamba family at its published widths, four layers deep (layer 1
+    attending, three Mamba) and with a small vocabulary: the programs take
+    the K/V pools and the state group's arrays as they are stored, copy or
+    transpose no layer of either, and hand all four back in place; the
+    prefill chunk scans through ``ssm_chunk_scan``, lowered once for the
+    three layers, and decode attends 20 heads on one K/V head through
+    ``paged_attn``.  (It is refused the fused programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import jamba2_3b
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        jamba2_3b(), max_seq=2048, num_layers=4, attn_layer_period=4,
+        attn_layer_offset=1, vocab_size=1024)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=32, num_blocks=16384, block_size=16, chunk=256, draft=4,
+        sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    _, rows, width = kv_cache.pool_shape(1, 16384, 16, 128)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width,
+        state=(32, cfg.state_rows.arrays(cfg.dtype)))
+    assert pool_check.failures(report, state=True) == []
+    assert report[program]["donated"] == ["conv_tail", "k_pool", "scan_state",
+                                          "v_pool"]
+    fn, args = programs[program]
+    text = fn.lower(*args).as_text()
+    if program == "prefill_chunk":
+        assert len(args) == 7       # the count of real tokens
+        calls = re.findall(r"call @(\w*scan_call\w*)\(", text)
+        assert len(calls) == 3 and len(set(calls)) == 1, calls
+        assert text.count('kernel_name = "ssm_chunk_scan"') == 1
+    else:
+        assert text.count('kernel_name = "paged_attn"') == 1
 
 
 def test_decode_program_attends_through_the_kernel_on_a_v5e(monkeypatch):

@@ -144,7 +144,7 @@ def main(argv=None) -> int:
                 for start in starts:
                     start = min(start, args.max_context - chunk)
                     ms = timed(lambda pools: prog.prefill(
-                        params, pools, tokens, start, table_row, chunk - 1))
+                        params, pools, tokens, start, table_row, chunk))
                     print(json.dumps({
                         "program": "prefill_chunk", **tag,
                         "group_tile": moe.group_tile(
