@@ -204,9 +204,29 @@ def rope_tables(
     return cos_f.astype(dtype), sin_f.astype(dtype)
 
 
+def rope_lane_tables(
+    positions: jax.Array, d: int, theta: float
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`rope_tables` as the flash kernels that rotate in VMEM take
+    them (``ops.flash_attention.flash_attention_qkv``): float32, (B, S,
+    max(128, D)), a head's ``[cos, cos]`` / ``[-sin, sin]`` repeated
+    across a 128-lane tile.  Rows that all hold the same positions are
+    handed over as one (``positions`` of shape (1, S)): the kernel then
+    fetches the tables once, not once a row."""
+    cos, sin = rope_tables(positions, d, theta, jnp.float32)
+    reps = max(1, 128 // d)
+    return tuple(jnp.tile(t[:, :, 0, :], (1, 1, reps)) for t in (cos, sin))
+
+
 def rope(x: jax.Array, positions: jax.Array, theta: float,
          tables: tuple[jax.Array, jax.Array] | None = None) -> jax.Array:
     """Rotary embedding, (B, S, H, D) with D even.
+
+    Who rotates here, outside any kernel: every caller but the training
+    block on its ``"qkv_tiles"`` path (:func:`attention_layout`), whose
+    flash kernels rotate q and k in VMEM — the served blocks (this file's
+    serving definition, ``models.afmoe`` / ``joyai``), the decode path,
+    sequence-parallel ``attn_fn``, GQA, the XLA attention, seq2seq.
 
     Lane-friendly formulation (2026-08-01 retune): the textbook
     ``split -> 4 muls on (…, D/2) -> concat`` form cost ~31 ms/step in
@@ -241,6 +261,37 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     return x * cos_f + x_rot * sin_f
 
 
+def attention_layout(cfg: GPTConfig, seq: int, *, n_heads: int | None = None,
+                     n_kv: int | None = None) -> str:
+    """The form the training block's dense causal attention lowers to over
+    ``seq`` positions (``ops.flash_attention.qkv_layout``): ``"qkv_tiles"``
+    (the flash kernels read the fused projection as it lies and rotate in
+    VMEM), ``"bhsd"`` (split, :func:`rope`, transposes, the (B, H, S, D)
+    kernels) or ``"xla"``.  Chosen by shape and mesh, read under the
+    context mesh; ``n_heads`` / ``n_kv`` are a manual shard's counts."""
+    from ..ops.flash_attention import qkv_layout
+
+    return qkv_layout(
+        seq, n_heads or cfg.num_heads, n_kv or cfg.kv_heads, cfg.head_dim,
+        cfg.dtype, implementation=cfg.attn_impl)
+
+
+def block_rope_tables(cfg: GPTConfig, positions: jax.Array | None,
+                      shape: tuple[int, int], *, fused: bool):
+    """The rotation's tables for every block of a trunk over ``shape`` =
+    (B, S) tokens, computed once a step: lane tiles when the blocks'
+    attention rotates in its kernels (``fused``), :func:`rope`'s
+    otherwise.  ``positions`` None is ``arange(S)`` in every row."""
+    rows_alike = positions is None
+    if rows_alike:
+        positions = jnp.arange(shape[1])[None]
+    if fused:
+        return rope_lane_tables(positions, cfg.head_dim, cfg.rope_theta)
+    if rows_alike:
+        positions = jnp.broadcast_to(positions, shape)
+    return rope_tables(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
+
+
 class CausalSelfAttention(nn.Module):
     cfg: GPTConfig
     attn_fn: AttnFn | None = None  # None = dense causal (flash-capable)
@@ -272,6 +323,22 @@ class CausalSelfAttention(nn.Module):
             q_width + 2 * kv_width, dtype=cfg.dtype,
             quant=cfg.quant, use_bias=False, name="qkv",
         )(x)
+        if (
+            not self.decode and self.attn_fn is None
+            and attention_layout(cfg, x.shape[1], n_heads=nh,
+                                 n_kv=n_kv) == "qkv_tiles"
+        ):
+            # The kernels read the projection as it lies, rotate q and k
+            # in VMEM and hand back o as the output projection takes it.
+            from ..ops.flash_attention import flash_attention_qkv
+
+            if rope_tabs is None or rope_tabs[0].ndim != 3:
+                rope_tabs = rope_lane_tables(
+                    positions, head_dim, cfg.rope_theta)
+            out = flash_attention_qkv(
+                qkv, nh, rope=rope_tabs, causal=True,
+                window=cfg.attn_window)
+            return self._project(out)
         q = qkv[..., :q_width]
         k = qkv[..., q_width:q_width + kv_width]
         v = qkv[..., q_width + kv_width:]
@@ -309,8 +376,11 @@ class CausalSelfAttention(nn.Module):
                 q, k, v, causal=True, window=cfg.attn_window,
                 implementation=cfg.attn_impl,
             )
-        out = out.reshape(*x.shape[:2], q_width)
-        # Row-parallel output projection (its input dim is head-sharded).
+        return self._project(out.reshape(*x.shape[:2], q_width))
+
+    def _project(self, out):
+        """Row-parallel output projection (its input dim is head-sharded)."""
+        cfg = self.cfg
         out = dense(
             cfg.hidden_size, dtype=cfg.dtype, quant=cfg.quant,
             use_bias=False, name="proj",
@@ -407,6 +477,15 @@ class GPTLM(nn.Module):
     attn_fn: AttnFn | None = None
     decode: bool = False
 
+    def flash_layout(self, seq: int) -> str | None:
+        """:func:`attention_layout` of this model's blocks over ``seq``
+        positions; None where they do not attend through it (decode, a
+        sequence-parallel ``attn_fn``).  The trainer reports it at
+        start-up: the fall-back between the forms is silent."""
+        if self.decode or self.attn_fn is not None:
+            return None
+        return attention_layout(self.cfg, seq)
+
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
                  positions=None, return_hidden: bool = False):
@@ -421,17 +500,17 @@ class GPTLM(nn.Module):
         # remat-safe, and only when the collection is mutable (the
         # provenance re-forward), so training pays nothing.
         sow_nonfinite(self, "wte", x)
+        # One trig computation per step, shared by every layer's q and k
+        # rotation (and saved as a residual under remat instead of being
+        # recomputed per block in the backward), in the form the blocks'
+        # attention takes.
+        rope_tabs = block_rope_tables(
+            cfg, positions, input_ids.shape,
+            fused=self.flash_layout(input_ids.shape[1]) == "qkv_tiles")
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(input_ids.shape[1]), input_ids.shape
             )
-        # One trig computation per step, shared by every layer's q and k
-        # rotation (and saved as a residual under remat instead of being
-        # recomputed per block in the backward).
-        rope_tabs = rope_tables(
-            positions, cfg.hidden_size // cfg.num_heads, cfg.rope_theta,
-            cfg.dtype,
-        )
         block = GPTBlock
         if cfg.remat and not self.decode:
             # Remat each block: activations recomputed in backward — the

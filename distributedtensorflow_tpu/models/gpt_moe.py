@@ -25,8 +25,8 @@ from jax.sharding import Mesh
 
 from ..parallel.moe import local_moe
 from ..parallel.sharding import LayoutMap
-from .gpt import (CausalSelfAttention, GPTBlock, GPTConfig, gpt_layout,
-                  rope_tables)
+from .gpt import (CausalSelfAttention, GPTBlock, GPTConfig,
+                  attention_layout, block_rope_tables, gpt_layout)
 from .layers import FusedLayerNorm
 
 PyTree = Any
@@ -168,10 +168,9 @@ class GPTMoELM(nn.Module):
         positions = jnp.broadcast_to(
             jnp.arange(input_ids.shape[1]), input_ids.shape
         )
-        rope_tabs = rope_tables(
-            positions, cfg.hidden_size // cfg.num_heads, cfg.rope_theta,
-            cfg.dtype,
-        )
+        rope_tabs = block_rope_tables(
+            cfg, None, input_ids.shape,
+            fused=attention_layout(cfg, input_ids.shape[1]) == "qkv_tiles")
         aux_total = jnp.zeros((), jnp.float32)
         dense_block = GPTBlock
         moe_block = MoEGPTBlock

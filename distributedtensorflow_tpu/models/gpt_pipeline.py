@@ -46,7 +46,8 @@ from ..parallel.pipeline import (
     pipeline_apply,
     pipeline_fb_step,
 )
-from .gpt import GPTBlock, GPTConfig, rope_tables
+from .gpt import (GPTBlock, GPTConfig, attention_layout,
+                  block_rope_tables)
 from .layers import FusedLayerNorm
 
 PyTree = Any
@@ -327,16 +328,21 @@ class PipelinedGPT:
                 x.shape[:2],
             )
         else:
+            positions = None
+        # Trig once per stage, shared across the layer scan (and saved as
+        # a residual under remat) — same hoist as GPTLM's trunk, in the
+        # form the stage's attention takes (a shard's own head count).
+        cfg = self.cfg
+        block = self._apply_block
+        rope_tabs = block_rope_tables(
+            cfg, positions, x.shape[:2],
+            fused=block.attn_fn is None and attention_layout(
+                cfg, x.shape[1], n_heads=block.n_heads,
+                n_kv=block.n_kv) == "qkv_tiles")
+        if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(x.shape[1]), x.shape[:2]
             )
-        # Trig once per stage, shared across the layer scan (and saved as
-        # a residual under remat) — same hoist as GPTLM's trunk.
-        cfg = self.cfg
-        rope_tabs = rope_tables(
-            positions, cfg.hidden_size // cfg.num_heads, cfg.rope_theta,
-            cfg.dtype,
-        )
 
         def one(x, layer_params):
             # fp32 across the schedule, cfg.dtype inside the block (the

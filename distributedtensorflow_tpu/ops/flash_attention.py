@@ -18,7 +18,23 @@ LSE so no (S, S) score tile ever reaches HBM.  An XLA blockwise-recompute
 fallback (`_flash_backward_xla`) is kept as the golden reference; select
 with ``BACKWARD_IMPL``.
 
-Layout: BSHD (batch, seq, heads, head_dim) to match ``ops.attention``.
+Two entries, one set of masks and one softmax:
+
+- :func:`flash_attention` takes q, k and v as (B, S, H, D) arrays (BSHD, to
+  match ``ops.attention``) and runs the kernels on (B, H, S, D) copies of
+  them: Mosaic wants a block's trailing two dimensions tile-aligned, and
+  (S, D) are.  The transposes there and back are whole-tensor copies for
+  XLA (ten a block pass at GPT-2 medium's training shapes, 241 ms of a
+  1995 ms step: ``PERF.md`` section 6, PR 35).
+  BERT, GQA, the ring / Ulysses chunks (``parallel/ring_attention.py``),
+  the ``"xla"`` backward and ``bench_attn.py`` call it.
+- :func:`flash_attention_qkv` takes a block's fused projection (B, S,
+  3*H*D) as the matmul wrote it: the kernels pick 128-lane tiles of the q,
+  k and v thirds through their index maps (two heads of 64 a tile), rotate
+  q and k in VMEM and write o and d``qkv`` as lane tiles of (B, S, H*D) /
+  (B, S, 3*H*D).  Nothing is moved between the projection and the kernel.
+  The training block (``models/gpt.py:CausalSelfAttention``) calls it
+  where :func:`qkv_layout` says the shape fills lane tiles.
 """
 
 from __future__ import annotations
@@ -124,16 +140,22 @@ def _gqa_ok(qshape, kshape) -> bool:
     )
 
 
+def _auto_takes(seq: int, dtype) -> bool:
+    """Auto-dispatch's part that reads no operand: on the TPU, a sequence
+    past the evidenced threshold that the blocks divide, a type the
+    kernels were measured in."""
+    return (
+        on_tpu() and seq >= MIN_SEQ_FOR_PALLAS
+        and _pick_block_q(seq) is not None
+        and dtype in (jnp.bfloat16, jnp.float32)
+    )
+
+
 def supported(q, k, v, *, mask=None, segment_ids=None) -> bool:
     """True when auto-dispatch should take the Pallas kernel for this call."""
     if q.ndim != 4 or k.shape != v.shape or not _gqa_ok(q.shape, k.shape):
         return False
-    if not on_tpu():
-        return False
-    seq = q.shape[1]
-    if seq < MIN_SEQ_FOR_PALLAS or _pick_block_q(seq) is None:
-        return False
-    if q.dtype not in (jnp.bfloat16, jnp.float32):
+    if not _auto_takes(q.shape[1], q.dtype):
         return False
     if segment_ids is not None and not _is_segment_ids(segment_ids, q.shape):
         return False
@@ -173,38 +195,39 @@ def _pick_block_k(seq_len: int) -> int | None:
     return o or _default_chain(seq_len, DEFAULT_BLOCK_K)
 
 
-def _tuned_blocks(batch: int, heads: int, seq: int,
-                  depth: int, dtype) -> tuple[int, int] | None:
+def _tuned_blocks(batch: int, heads: int, seq: int, depth: int, dtype,
+                  layout: str = "bhsd") -> tuple[int, int] | None:
     """Autotune-cache consult (ops/flash_tuning.py): the (block_q,
     block_k) a sweep or XPlane analysis recorded for this (shape, dtype,
-    platform), or None.  Never raises — a broken cache must degrade to
-    the default chain, not break the kernel."""
+    platform) and kernel form, or None.  Never raises — a broken cache
+    must degrade to the default chain, not break the kernel."""
     try:
         from . import flash_tuning
 
         return flash_tuning.lookup(
             platform=jax.default_backend(),
             dtype=jnp.dtype(dtype).name,
-            seq=seq, depth=depth, batch=batch, heads=heads,
+            seq=seq, depth=depth, batch=batch, heads=heads, layout=layout,
         )
     except Exception:
         return None
 
 
 def _resolve_blocks(batch: int, heads: int, seq: int, depth: int, dtype,
-                    block_q: int | None,
-                    block_k: int | None) -> tuple[int, int]:
+                    block_q: int | None, block_k: int | None,
+                    layout: str = "bhsd") -> tuple[int, int]:
     """The kernel's block tiling, resolved: explicit argument > env
     override > autotune cache > retuned default chain.  Callers
     validated divisibility of explicit args; env/cache tiers self-skip
-    when they don't divide."""
+    when they don't divide.  ``layout`` names the kernel form: a tiling
+    recorded for one form's kernels is not taken for the other's."""
     if block_q is not None and block_k is not None:
         return block_q, block_k
     env_q = _env_divisible("DTFT_FLASH_BLOCK_Q", seq)
     env_k = _env_divisible("DTFT_FLASH_BLOCK_K", seq)
     tuned = None
     if (block_q or env_q) is None or (block_k or env_k) is None:
-        tuned = _tuned_blocks(batch, heads, seq, depth, dtype)
+        tuned = _tuned_blocks(batch, heads, seq, depth, dtype, layout)
     bq = (block_q or env_q or (tuned[0] if tuned else None)
           or _default_chain(seq, DEFAULT_BLOCK_Q))
     bk = (block_k or env_k or (tuned[1] if tuned else None)
@@ -232,7 +255,9 @@ def _masked_scores(q, k, qi, kj, *, scale, block_q, block_k, causal,
     ``(q_pos - window, q_pos]``."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    )
+    if scale != 1.0:  # 1.0: the caller folded it into q (_tiles_rope)
+        s = s * scale
     if causal or window is not None:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
@@ -483,7 +508,8 @@ def _flash_forward(q, k, v, mask, segment_ids, kv_segment_ids=None, *,
                    causal, interpret, window=None,
                    block_q=None, block_k=None):
     # Mosaic needs the trailing two block dims tile-aligned or full-size:
-    # run the kernel in BHSD so (seq, depth) are the trailing dims.
+    # this entry runs the kernels on (B, H, S, D) copies, so that (seq,
+    # depth) are the trailing dims (flash_attention_qkv moves nothing).
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
 
     o, lse, _ = _flash_forward_bhsd(qt, kt, vt, mask, segment_ids,
@@ -1102,8 +1128,8 @@ def _flash_fwd(q, k, v, mask, segment_ids, causal, interpret, backward_impl,
                window, block_q, block_k):
     # Residuals are saved in the BHSD layout the kernels consume: the
     # forward already paid for these relayouts, and saving the BSHD
-    # originals instead would make the backward re-emit all four
-    # (profiled at ~6 ms/step of pure transposes, docs/LM_PERF.md).
+    # originals instead would make the backward re-emit all four (whole-
+    # tensor copies each: PERF.md section 6, PR 35).
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     o, lse, ot = _flash_forward_bhsd(qt, kt, vt, mask, segment_ids,
                                      causal=causal, interpret=interpret,
@@ -1142,6 +1168,45 @@ def _flash_bwd(causal, interpret, backward_impl, window, block_q, block_k,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _check_rows(qshape, mask, segment_ids, causal, window, block_q,
+                block_k):
+    """The public entries' checks of what is not q, k or v, for a (B, S,
+    H, D) query shape: ``(padding mask (B, S) or None, segment_ids,
+    window)`` as the kernels take them, or a ValueError."""
+    if _pick_block_q(qshape[1]) is None:
+        raise ValueError(
+            f"sequence length {qshape[1]} not divisible by any supported "
+            "q-block size (multiple of 8 required)"
+        )
+    if mask is not None and not _is_padding_mask(mask, qshape):
+        raise ValueError(
+            f"mask shape {mask.shape} unsupported: need (B, S) or "
+            "(B, 1, 1, S) padding mask"
+        )
+    if segment_ids is not None and not _is_segment_ids(segment_ids, qshape):
+        raise ValueError(
+            f"segment_ids shape/dtype unsupported: need int (B, S), got "
+            f"{segment_ids.shape} {segment_ids.dtype}"
+        )
+    if window is not None:
+        if not causal:
+            raise ValueError(
+                "window (sliding-window attention) requires causal=True — "
+                "a lower-edge-only band has unbounded lookahead"
+            )
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+        if window >= qshape[1]:
+            window = None  # full causal attention; skip the dead masking
+    for name, b in (("block_q", block_q), ("block_k", block_k)):
+        if b is not None and (b <= 0 or qshape[1] % b):
+            raise ValueError(
+                f"{name}={b} must be a positive divisor of seq "
+                f"{qshape[1]}"
+            )
+    return _as_padding_mask(mask, qshape), segment_ids, window
+
+
 def flash_attention(q, k, v, *, mask=None, segment_ids=None, causal=False,
                     interpret=None, backward_impl=None, window=None,
                     block_q=None, block_k=None):
@@ -1176,40 +1241,10 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, causal=False,
             f"q heads a multiple of kv heads (GQA), got {q.shape} "
             f"{k.shape} {v.shape}"
         )
-    if _pick_block_q(q.shape[1]) is None:
-        raise ValueError(
-            f"sequence length {q.shape[1]} not divisible by any supported "
-            "q-block size (multiple of 8 required)"
-        )
-    if mask is not None and not _is_padding_mask(mask, q.shape):
-        raise ValueError(
-            f"mask shape {mask.shape} unsupported: need (B, S) or "
-            "(B, 1, 1, S) padding mask"
-        )
-    if segment_ids is not None and not _is_segment_ids(segment_ids, q.shape):
-        raise ValueError(
-            f"segment_ids shape/dtype unsupported: need int (B, S), got "
-            f"{segment_ids.shape} {segment_ids.dtype}"
-        )
-    if window is not None:
-        if not causal:
-            raise ValueError(
-                "window (sliding-window attention) requires causal=True — "
-                "a lower-edge-only band has unbounded lookahead"
-            )
-        if window < 1:
-            raise ValueError(f"window must be >= 1, got {window}")
-        if window >= q.shape[1]:
-            window = None  # full causal attention; skip the dead masking
-    for name, b in (("block_q", block_q), ("block_k", block_k)):
-        if b is not None and (b <= 0 or q.shape[1] % b):
-            raise ValueError(
-                f"{name}={b} must be a positive divisor of seq "
-                f"{q.shape[1]}"
-            )
+    pad, segment_ids, window = _check_rows(
+        q.shape, mask, segment_ids, causal, window, block_q, block_k)
     if interpret is None:
         interpret = not on_tpu()
-    pad = _as_padding_mask(mask, q.shape)
 
     def local(q, k, v, pad, segment_ids):
         return _flash(q, k, v, pad, segment_ids, causal, interpret,
@@ -1225,3 +1260,728 @@ def flash_attention(q, k, v, *, mask=None, segment_ids=None, causal=False,
     return shard_kernel(local, (qkv, qkv, qkv, row, row), qkv)(
         q, k, v, pad, segment_ids
     )
+
+
+# --- The fused projection read as it lies: lane tiles of qkv ----------------
+#
+# ``qkv`` is the block's one projection, (B, S, 3*H*D) with the q, k and v
+# thirds side by side in the lane dimension.  The kernels below take it three
+# times, each through an index map that picks lane tile ``p`` of one third
+# (a tile is 128 lanes: two heads of 64, four of 32, or one head of a
+# multiple of 128), do their tile's heads one after the other and write o,
+# dq, dk and dv as the same lane tiles of (B, S, H*D) arrays.  The rotary
+# embedding is applied in VMEM.  Between the projection and the kernel q, k
+# and v take no trip through HBM: no split, no reshape to heads, no
+# transpose to (B, H, S, D), no rotary product.  The softmax, the masks and
+# the order of every sum inside a head are those of the kernels above.
+
+LANES = 128
+
+#: Scoped VMEM of the tile kernels.  A grid step holds what a step of the
+#: (B, H, S, D) kernels holds (a (1024, 1024) float32 score tile and its
+#: temporaries, by Mosaic's 16 MiB default) and beside it the rotation's
+#: four table blocks, double-buffered, and the second head's running state:
+#: four heads of 32, or a window's two masks at 1024 x 1024, overflow the
+#: default (compiled for a described v5e, tests/test_kernel_export.py).
+#: A v5e core has 128 MiB; GPT-2 medium's step is no slower for the room
+#: (1561 ms against 1580 under the default, the same at 64 and 100 MiB: my
+#: chip runs, PR 35).
+TILES_VMEM_LIMIT_BYTES = 32 * 2**20
+
+
+def tile_heads(heads: int, kv_heads: int, depth: int) -> int | None:
+    """Heads a lane tile of the fused projection holds, or None where its
+    lane tiles do not hold whole heads of q, k and v alike (GQA, a head
+    width that neither divides 128 nor is a multiple of it, a head count
+    that leaves a tile half full) or the rotation has no halves to swap."""
+    if heads != kv_heads or depth % 2:
+        return None
+    if depth % LANES == 0:
+        return 1
+    if LANES % depth or heads % (LANES // depth):
+        return None
+    return LANES // depth
+
+
+def _head_lanes(shape, a, depth):
+    """(rows, tile) mask of the lanes head ``a`` of the tile holds; None
+    when the tile is one head."""
+    if shape[-1] == depth:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= a * depth) & (lane < (a + 1) * depth)
+
+
+def _only_head(x, a, depth):
+    """``x`` with the lanes of the tile's other heads zeroed: a contraction
+    over the whole tile is then head ``a``'s contraction over exact zeros
+    (the same MXU passes as a ``depth``-deep one, the same sums)."""
+    lanes = _head_lanes(x.shape, a, depth)
+    return x if lanes is None else jnp.where(lanes, x, jnp.zeros_like(x))
+
+
+def _put_head(tile, new, a, depth):
+    """``tile`` with head ``a``'s lanes taken from ``new``."""
+    lanes = _head_lanes(new.shape, a, depth)
+    return new if lanes is None else jnp.where(lanes, new, tile)
+
+
+def _half_swap(x, depth):
+    """Every head's halves swapped, (rows, tile) float32: two lane rolls
+    and a select (one roll when the tile is one head).  Its own inverse
+    and its own transpose."""
+    tile, half = x.shape[-1], depth // 2
+    if tile == depth:
+        return pltpu.roll(x, half, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane % depth < half,
+                     pltpu.roll(x, tile - half, 1), pltpu.roll(x, half, 1))
+
+
+def _rotate(x, cos_ref, sin_ref, depth):
+    """Rotary embedding of a (rows, tile) block, ``x*cos + swap(x)*sin`` in
+    float32 with ``sin`` sign-folded, rounded once to ``x``'s type; ``x``
+    itself when the call rotates nothing."""
+    if cos_ref is None:
+        return x
+    x32 = x.astype(jnp.float32)
+    out = (x32 * cos_ref[0].astype(jnp.float32)
+           + _half_swap(x32, depth) * sin_ref[0].astype(jnp.float32))
+    return out.astype(x.dtype)
+
+
+def _unrotate(dx, cos_ref, sin_ref, depth):
+    """The rotation's transpose on a float32 gradient block:
+    ``dx*cos + swap(dx*sin)``."""
+    if cos_ref is None:
+        return dx
+    return (dx * cos_ref[0].astype(jnp.float32)
+            + _half_swap(dx * sin_ref[0].astype(jnp.float32), depth))
+
+
+def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      q_scr, m_scr, l_scr, acc_scr, *, depth, scale,
+                      block_q, block_k, causal, have_mask, mask_ref=None,
+                      qseg_ref=None, kseg_ref=None, window=None,
+                      cq_ref=None, sq_ref=None, ck_ref=None, sk_ref=None):
+    """:func:`_fwd_kernel` on one lane tile of ``qkv``: grid (B, tiles, n_q,
+    n_k), the running max and sum a head, one (block_q, tile) accumulator;
+    q is rotated once a q block, k once a visit."""
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    n_k = pl.num_programs(3)
+    hp = q_ref.shape[-1] // depth
+
+    @pl.when(kj == 0)
+    def _init():
+        q_scr[:, :] = _rotate(q_ref[0], cq_ref, sq_ref, depth)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[:, :] = jnp.zeros_like(acc_scr)
+
+    run = _band_run(qi, kj, block_q, block_k, causal, window)
+
+    def _step(apply_causal, apply_window):
+        q = q_scr[:, :]
+        k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
+        v = v_ref[0]
+        for a in range(hp):
+            s = _masked_scores(
+                _only_head(q, a, depth), k, qi, kj, scale=scale,
+                block_q=block_q, block_k=block_k, causal=apply_causal,
+                have_mask=have_mask, mask_ref=mask_ref, qseg_ref=qseg_ref,
+                kseg_ref=kseg_ref, window=window if apply_window else None,
+            )
+            m_prev = m_scr[a, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_scr[a, :, :1] * alpha + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc = acc_scr[:, :]
+            acc_scr[:, :] = _put_head(acc, acc * alpha + pv, a, depth)
+            m_scr[a] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[a] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    _causal_step_split(qi, kj, run, block_q=block_q, block_k=block_k,
+                       causal=causal, step=_step, window=window)
+
+    @pl.when(kj == n_k - 1)
+    def _finalize():
+        acc = acc_scr[:, :]
+        o = acc
+        for a in range(hp):
+            o = _put_head(o, acc / l_scr[a, :, :1], a, depth)
+            lse_ref[0, a, 0, pl.ds(qi * block_q, block_q)] = (
+                m_scr[a, :, 0] + jnp.log(l_scr[a, :, 0])
+            )
+        o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _fwd_tiles_kernel_1k(q_ref, k_ref, v_ref, o_ref, lse_ref, *, depth,
+                         scale, block_q, block_k, causal, have_mask,
+                         mask_ref=None, qseg_ref=None, kseg_ref=None,
+                         window=None, cq_ref=None, sq_ref=None, ck_ref=None,
+                         sk_ref=None):
+    """:func:`_fwd_kernel_1k` on one lane tile of ``qkv``: the whole K/V
+    sequence is one k block, so each head's softmax is one pass."""
+    qi = pl.program_id(2)
+    hp = q_ref.shape[-1] // depth
+    q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
+    k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
+    v = v_ref[0]
+    o = None
+    for a in range(hp):
+        s = _masked_scores(
+            _only_head(q, a, depth), k, qi, 0, scale=scale, block_q=block_q,
+            block_k=block_k, causal=causal, have_mask=have_mask,
+            mask_ref=mask_ref, qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window,
+        )
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        o = pv / l if o is None else _put_head(o, pv / l, a, depth)
+        lse_ref[0, a, 0, pl.ds(qi * block_q, block_q)] = (
+            m[:, 0] + jnp.log(l[:, 0])
+        )
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _bwd_tiles_head(a, q, k, v, g, go, lse_ref, qi, kj, *, depth, masks):
+    """One head of a backward step on a lane tile: ``(p, ds, q_a, k_a,
+    g_a)`` with the p tile recomputed from the saved LSE as in the
+    (B, H, S, D) kernels, the operands zeroed outside the head's lanes so
+    that every product lands in them.  ``go`` is dO * O over the tile,
+    float32: a head's delta is its sum over the head's lanes."""
+    qa, ka, ga = (_only_head(x, a, depth) for x in (q, k, g))
+    s = _masked_scores(qa, k, qi, kj, **masks)
+    lse = lse_ref[0, a, 0, :]
+    p = jnp.exp(s - lse[:, None])
+    dp = jax.lax.dot_general(
+        ga, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    delta = jnp.sum(_only_head(go, a, depth), axis=-1, keepdims=True)
+    ds = p * (dp - delta)
+    if masks["scale"] != 1.0:
+        ds = ds * masks["scale"]
+    return p, ds, qa, ka, ga
+
+
+def _dot_t(x, y):
+    """``x^T @ y`` in float32."""
+    return jax.lax.dot_general(
+        x, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _dot(x, y):
+    return jax.lax.dot_general(
+        x, y, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _tile_sender(dqkv_ref):
+    """``send(value, stage, third, rows, sem)`` for this grid step: start
+    the copy of a finished (rows, tile) gradient block into lane tile ``p``
+    of ``third`` (0 q, 1 k, 2 v) of d``qkv`` in HBM, through the VMEM block
+    ``stage``; the caller waits.  d``qkv`` is one array, the projection's
+    cotangent as its backward takes it: three outputs would have to be
+    concatenated, a pass over all of it.  (The grid position is read here,
+    at the kernel's top level, which is where interpret mode has it.)"""
+    b, p, tiles = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+
+    def send(value, stage, third, rows, sem):
+        tile = stage.shape[-1]
+        stage[:, :] = value.astype(stage.dtype)
+        lanes = pl.ds(pl.multiple_of((third * tiles + p) * tile, tile), tile)
+        copy = pltpu.make_async_copy(stage, dqkv_ref.at[b, rows, lanes], sem)
+        copy.start()
+        return copy
+
+    return send
+
+
+def _bwd_tiles_fused_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                            dqkv_ref, k_scr, dq_all_scr, dk_scr, dv_scr,
+                            q_out, k_out, v_out, sems, *, depth, scale,
+                            block_q, block_k, causal, have_mask,
+                            mask_ref=None, qseg_ref=None, kseg_ref=None,
+                            window=None, cq_ref=None, sq_ref=None,
+                            ck_ref=None, sk_ref=None):
+    """:func:`_bwd_fused_kernel` on one lane tile of ``qkv``: grid (B,
+    tiles, n_k, n_q), q innermost.  The rotated q and k are recomputed from
+    the raw tiles (k once a k block), the accumulators hold the gradients
+    of the rotated q and k, and what is written, once an accumulator is
+    final, is their rotation back."""
+    j = pl.program_id(2)
+    i = pl.program_id(3)
+    n_k = pl.num_programs(2)
+    n_q = pl.num_programs(3)
+    hp = q_ref.shape[-1] // depth
+    send = _tile_sender(dqkv_ref)
+
+    @pl.when((j == 0) & (i == 0))
+    def _init_dq():
+        dq_all_scr[:, :] = jnp.zeros_like(dq_all_scr)
+
+    @pl.when(i == 0)
+    def _init_dkv():
+        k_scr[:, :] = _rotate(k_ref[0], ck_ref, sk_ref, depth)
+        dk_scr[:, :] = jnp.zeros_like(dk_scr)
+        dv_scr[:, :] = jnp.zeros_like(dv_scr)
+
+    run = _band_run(i, j, block_q, block_k, causal, window)
+    row = pl.ds(i * block_q, block_q)
+
+    def _step(apply_causal, apply_window):
+        q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
+        k, v, g = k_scr[:, :], v_ref[0], g_ref[0]
+        go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        masks = dict(
+            scale=scale, block_q=block_q, block_k=block_k,
+            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
+            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window if apply_window else None,
+        )
+        for a in range(hp):
+            p, ds, qa, ka, ga = _bwd_tiles_head(
+                a, q, k, v, g, go, lse_ref, i, j, depth=depth, masks=masks)
+            ds = ds.astype(q.dtype)
+            dv_scr[:, :] = dv_scr[:, :] + _dot_t(p.astype(ga.dtype), ga)
+            dk_scr[:, :] = dk_scr[:, :] + _dot_t(ds, qa)
+            dq_all_scr[row] = dq_all_scr[row] + _dot(ds, ka)
+
+    _causal_step_split(i, j, run, block_q=block_q, block_k=block_k,
+                       causal=causal, step=_step, window=window)
+
+    @pl.when(j == n_k - 1)
+    def _flush_dq():
+        send(_unrotate(dq_all_scr[row], cq_ref, sq_ref, depth), q_out, 0,
+             row, sems.at[0]).wait()
+
+    @pl.when(i == n_q - 1)
+    def _flush_dkv():
+        rows = pl.ds(j * block_k, block_k)
+        dk = send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1,
+                  rows, sems.at[1])
+        dv = send(dv_scr[:, :], v_out, 2, rows, sems.at[2])
+        dk.wait()
+        dv.wait()
+
+
+def _bwd_tiles_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                         dqkv_ref, q_scr, dq_scr, q_out, sems, *, depth,
+                         scale, block_q, block_k, causal, have_mask,
+                         mask_ref=None, qseg_ref=None, kseg_ref=None,
+                         window=None, cq_ref=None, sq_ref=None, ck_ref=None,
+                         sk_ref=None):
+    """:func:`_bwd_dq_kernel` on one lane tile of ``qkv`` (k innermost):
+    writes the q third of d``qkv``."""
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    n_k = pl.num_programs(3)
+    hp = q_ref.shape[-1] // depth
+    send = _tile_sender(dqkv_ref)
+
+    @pl.when(kj == 0)
+    def _init():
+        q_scr[:, :] = _rotate(q_ref[0], cq_ref, sq_ref, depth)
+        dq_scr[:, :] = jnp.zeros_like(dq_scr)
+
+    run = _band_run(qi, kj, block_q, block_k, causal, window)
+
+    def _step(apply_causal, apply_window):
+        q, v, g = q_scr[:, :], v_ref[0], g_ref[0]
+        k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
+        go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        masks = dict(
+            scale=scale, block_q=block_q, block_k=block_k,
+            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
+            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window if apply_window else None,
+        )
+        for a in range(hp):
+            _, ds, _, ka, _ = _bwd_tiles_head(
+                a, q, k, v, g, go, lse_ref, qi, kj, depth=depth, masks=masks)
+            dq_scr[:, :] = dq_scr[:, :] + _dot(ds.astype(k.dtype), ka)
+
+    _causal_step_split(qi, kj, run, block_q=block_q, block_k=block_k,
+                       causal=causal, step=_step, window=window)
+
+    @pl.when(kj == n_k - 1)
+    def _finalize():
+        send(_unrotate(dq_scr[:, :], cq_ref, sq_ref, depth), q_out, 0,
+             pl.ds(qi * block_q, block_q), sems.at[0]).wait()
+
+
+def _bwd_tiles_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                          _dq_done_ref, dqkv_ref, k_scr, dk_scr, dv_scr,
+                          k_out, v_out, sems, *, depth, scale, block_q,
+                          block_k, causal, have_mask, mask_ref=None,
+                          qseg_ref=None, kseg_ref=None, window=None,
+                          cq_ref=None, sq_ref=None, ck_ref=None,
+                          sk_ref=None):
+    """:func:`_bwd_dkv_kernel` on one lane tile of ``qkv`` (q innermost):
+    writes the k and v thirds of the d``qkv`` whose q third the dq kernel
+    wrote (``_dq_done_ref``, the same buffer)."""
+    kj = pl.program_id(2)
+    qi = pl.program_id(3)
+    n_q = pl.num_programs(3)
+    hp = q_ref.shape[-1] // depth
+    send = _tile_sender(dqkv_ref)
+
+    @pl.when(qi == 0)
+    def _init():
+        k_scr[:, :] = _rotate(k_ref[0], ck_ref, sk_ref, depth)
+        dk_scr[:, :] = jnp.zeros_like(dk_scr)
+        dv_scr[:, :] = jnp.zeros_like(dv_scr)
+
+    run = _band_run(qi, kj, block_q, block_k, causal, window)
+
+    def _step(apply_causal, apply_window):
+        q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
+        k, v, g = k_scr[:, :], v_ref[0], g_ref[0]
+        go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        masks = dict(
+            scale=scale, block_q=block_q, block_k=block_k,
+            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
+            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window if apply_window else None,
+        )
+        for a in range(hp):
+            p, ds, qa, _, ga = _bwd_tiles_head(
+                a, q, k, v, g, go, lse_ref, qi, kj, depth=depth, masks=masks)
+            dv_scr[:, :] = dv_scr[:, :] + _dot_t(p.astype(ga.dtype), ga)
+            dk_scr[:, :] = dk_scr[:, :] + _dot_t(ds.astype(q.dtype), qa)
+
+    _causal_step_split(qi, kj, run, block_q=block_q, block_k=block_k,
+                       causal=causal, step=_step, window=window)
+
+    @pl.when(qi == n_q - 1)
+    def _finalize():
+        rows = pl.ds(kj * block_k, block_k)
+        dk = send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1,
+                  rows, sems.at[0])
+        dv = send(dv_scr[:, :], v_out, 2, rows, sems.at[1])
+        dk.wait()
+        dv.wait()
+
+
+def _tile_specs(tiles, hp, tile, block_q, block_k, mem, *, swap_grid=False):
+    """The block specs of the tile kernels, by the role of the operand:
+    ``q`` / ``k`` / ``v`` pick lane tile ``p`` of their third of ``qkv``,
+    ``qside`` lane tile ``p`` of a (B, S, H*D) array by q block (o, dO; as
+    ``q`` is of the first third) and ``row`` the tile's heads of a (B, H,
+    1, S) array by q block.  ``swap_grid``: the grid is (B, tiles, n_k,
+    n_q)."""
+    def spec(shape, index):
+        if swap_grid:
+            return pl.BlockSpec(shape, lambda b, p, j, i: index(b, p, i, j),
+                                memory_space=mem)
+        return pl.BlockSpec(shape, index, memory_space=mem)
+
+    qb, kb = (1, block_q, tile), (1, block_k, tile)
+    q = spec(qb, lambda b, p, i, j: (b, i, p))
+    return {
+        "q": q,
+        "k": spec(kb, lambda b, p, i, j: (b, j, tiles + p)),
+        "v": spec(kb, lambda b, p, i, j: (b, j, 2 * tiles + p)),
+        "qside": q,
+        "row": spec((1, hp, 1, block_q), lambda b, p, i, j: (b, p, 0, i)),
+    }
+
+
+def _rope_specs_and_args(rope, block_q, block_k, mem, *, swap_grid=False):
+    """(in_specs, args, ref_names) of the rotation's tables, like
+    :func:`_extra_specs_and_args`: ``rope`` is :func:`_tiles_rope`'s
+    ``(cos_q, sin_q, cos_k, sin_k)``, each (1 or B, S, tile), q's taken by
+    q block and k's by k block."""
+    if rope is None:
+        return [], [], []
+    per_row = rope[0].shape[0] > 1
+    tile = rope[0].shape[-1]
+
+    def spec(block, by_q):
+        def index(b, p, i, j):
+            if swap_grid:
+                i, j = j, i
+            return (b if per_row else 0, i if by_q else j, 0)
+        return pl.BlockSpec((1, block, tile), index, memory_space=mem)
+
+    return (
+        [spec(block_q, True), spec(block_q, True),
+         spec(block_k, False), spec(block_k, False)],
+        list(rope),
+        ["cq_ref", "sq_ref", "ck_ref", "sk_ref"],
+    )
+
+
+def _tiles_rope(rope, depth):
+    """``(tables, scale)`` for the tile kernels: with a rotation, q's copy
+    of the tables carries the softmax scale ``1 / sqrt(depth)``, so the
+    kernels multiply no (block_q, block_k) score tile by it (and, in the
+    backward, no ds tile: dk takes it from the scaled q, dq from the
+    rotation back through q's tables) — one pass of the vector unit a tile
+    less in the forward, two in the backward.  A power of two (heads of 64,
+    16, 256) scales exactly: the scores are bit for bit what scaling them
+    afterwards gives.  Without a rotation the kernels scale the tile."""
+    scale = 1.0 / (depth ** 0.5)
+    if rope is None:
+        return None, scale
+    cos, sin = rope
+    return (cos * scale, sin * scale, cos, sin), 1.0
+
+
+def _tiles_geometry(qkv, heads):
+    batch, seq, width = qkv.shape
+    depth = width // (3 * heads)
+    hp = tile_heads(heads, heads, depth)
+    return batch, seq, depth, hp, heads // hp, hp * depth
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "interpret", "window", "block_q", "block_k"))
+def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
+                   window, block_q, block_k):
+    """Forward over the fused projection: ``(o (B, S, H*D), lse (B, H, 1,
+    S))``.  A jitted function of its own (as ``ops.attention.
+    _paged_attn_call``): the layers of a step call it at the same shapes and
+    share one trace and one lowering of the kernel's body, where every call
+    site of a bare ``pallas_call`` is lowered again (72 a step of GPT-2
+    medium; the body of two heads a tile is the longer one)."""
+    batch, seq, depth, hp, tiles, tile = _tiles_geometry(qkv, heads)
+    mem = pl.ANY if interpret else pltpu.VMEM
+    specs = _tile_specs(tiles, hp, tile, block_q, block_k, mem)
+    rope, scale = _tiles_rope(rope, depth)
+    extra_specs, extra_args, extra_names = (
+        x + y for x, y in zip(
+            _extra_specs_and_args(mask, segment_ids, batch, seq, block_q,
+                                  block_k, mem),
+            _rope_specs_and_args(rope, block_q, block_k, mem)))
+    one_k = seq // block_k == 1
+    kernel = _wrap_kernel(
+        _fwd_tiles_kernel_1k if one_k else _fwd_tiles_kernel, 3, extra_names,
+        depth=depth, scale=scale, block_q=block_q, block_k=block_k,
+        causal=causal, window=window,
+    )
+    return pl.pallas_call(
+        kernel,
+        name="flash_fwd",
+        grid=(batch, tiles, seq // block_q, seq // block_k),
+        in_specs=[specs["q"], specs["k"], specs["v"], *extra_specs],
+        out_specs=[
+            specs["qside"],
+            pl.BlockSpec((1, hp, 1, seq), lambda b, p, i, j: (b, p, 0, 0),
+                         memory_space=mem),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, seq, heads * depth), qkv.dtype),
+            jax.ShapeDtypeStruct((batch, heads, 1, seq), jnp.float32),
+        ],
+        scratch_shapes=[] if one_k else [
+            pltpu.VMEM((block_q, tile), qkv.dtype),        # rotated q
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),   # running max m
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),   # running sum l
+            pltpu.VMEM((block_q, tile), jnp.float32),      # accumulator
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=TILES_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(qkv, qkv, qkv, *extra_args)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "causal", "interpret", "force_split", "window", "block_q",
+    "block_k"))
+def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
+                    causal, interpret, force_split, window, block_q,
+                    block_k):
+    """d``qkv`` (B, S, 3*H*D) from the saved projection, o and LSE.  The
+    kernels form delta = rowsum(dO * O) themselves, from the o tile, and
+    copy each finished gradient block into its place in the one d``qkv``
+    (an output left in HBM): XLA runs nothing over an activation here.
+    Jitted for the reason :func:`_tiles_forward` is."""
+    batch, seq, depth, hp, tiles, tile = _tiles_geometry(qkv, heads)
+    mem = pl.ANY if interpret else pltpu.VMEM
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    rope, scale = _tiles_rope(rope, depth)
+    kw = dict(depth=depth, scale=scale, block_q=block_q, block_k=block_k,
+              causal=causal, window=window)
+    f32 = jnp.float32
+
+    def stage(block):
+        return pltpu.VMEM((block, tile), qkv.dtype)
+
+    def call(inner, name, swap_grid, scratch, dq_done=None):
+        specs = _tile_specs(tiles, hp, tile, block_q, block_k, mem,
+                            swap_grid=swap_grid)
+        extra_specs, extra_args, extra_names = (
+            x + y for x, y in zip(
+                _extra_specs_and_args(mask, segment_ids, batch, seq, block_q,
+                                      block_k, mem, swap_grid=swap_grid),
+                _rope_specs_and_args(rope, block_q, block_k, mem,
+                                     swap_grid=swap_grid)))
+        n = (seq // block_k, seq // block_q) if swap_grid else (
+            seq // block_q, seq // block_k)
+        done = [] if dq_done is None else [dq_done]
+        return pl.pallas_call(
+            _wrap_kernel(inner, 6 + len(done), extra_names, **kw),
+            name=name,
+            grid=(batch, tiles, *n),
+            in_specs=[specs["q"], specs["k"], specs["v"], specs["qside"],
+                      specs["qside"], specs["row"], *[hbm] * len(done),
+                      *extra_specs],
+            out_specs=hbm,
+            out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+            scratch_shapes=scratch,
+            input_output_aliases={6: 0} if done else {},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=TILES_VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )(qkv, qkv, qkv, g, o, lse, *done, *extra_args)
+
+    if not force_split and seq * tile * 4 <= FUSED_BWD_DQ_SCRATCH_BYTES:
+        return call(
+            _bwd_tiles_fused_kernel, "flash_bwd", True,
+            [stage(block_k),                      # rotated k
+             pltpu.VMEM((seq, tile), f32),        # dq, the whole tile
+             pltpu.VMEM((block_k, tile), f32),    # dk
+             pltpu.VMEM((block_k, tile), f32),    # dv
+             stage(block_q), stage(block_k), stage(block_k),
+             pltpu.SemaphoreType.DMA((3,))])
+    dq_done = call(
+        _bwd_tiles_dq_kernel, "flash_bwd_dq", False,
+        [stage(block_q), pltpu.VMEM((block_q, tile), f32), stage(block_q),
+         pltpu.SemaphoreType.DMA((1,))])
+    return call(
+        _bwd_tiles_dkv_kernel, "flash_bwd_dkv", True,
+        [stage(block_k), pltpu.VMEM((block_k, tile), f32),
+         pltpu.VMEM((block_k, tile), f32), stage(block_k), stage(block_k),
+         pltpu.SemaphoreType.DMA((2,))],
+        dq_done=dq_done)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_qkv(qkv, rope, mask, segment_ids, heads, causal, interpret,
+               backward_impl, window, block_q, block_k):
+    o, _ = _tiles_forward(qkv, rope, mask, segment_ids, heads=heads,
+                          causal=causal, interpret=interpret, window=window,
+                          block_q=block_q, block_k=block_k)
+    return o
+
+
+def _flash_qkv_fwd(qkv, rope, mask, segment_ids, heads, causal, interpret,
+                   backward_impl, window, block_q, block_k):
+    o, lse = _tiles_forward(qkv, rope, mask, segment_ids, heads=heads,
+                            causal=causal, interpret=interpret, window=window,
+                            block_q=block_q, block_k=block_k)
+    return o, (qkv, rope, mask, segment_ids, o, lse)
+
+
+def _flash_qkv_bwd(heads, causal, interpret, backward_impl, window, block_q,
+                   block_k, res, g):
+    qkv, rope, mask, segment_ids, o, lse = res
+    dqkv = _tiles_backward(
+        qkv, rope, mask, segment_ids, o, lse, g, heads=heads, causal=causal,
+        interpret=interpret,
+        force_split=(backward_impl or BACKWARD_IMPL) == "pallas_split",
+        window=window, block_q=block_q, block_k=block_k)
+    return dqkv, None, None, None
+
+
+_flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
+
+
+def qkv_layout(seq: int, heads: int, kv_heads: int, depth: int, dtype, *,
+               implementation: str = "auto",
+               backward_impl: str | None = None) -> str:
+    """The form causal self-attention over a block's fused projection
+    lowers to, by what can be observed of the call: ``"qkv_tiles"`` (the
+    kernels read the projection as it lies, :func:`flash_attention_qkv`),
+    ``"bhsd"`` (q, k and v are split, rotated and transposed to the
+    (B, H, S, D) kernels, :func:`flash_attention`) or ``"xla"`` (no
+    kernel).  Read under the context mesh, as the call itself is traced: a
+    ``model`` axis that splits heads splits the fused lane dimension into
+    shards that hold no whole q, k and v, and takes ``"bhsd"``.  The
+    fall-back is silent, so the trainer reports this at start-up."""
+    if implementation not in ("auto", "pallas") or (
+            implementation == "auto" and not _auto_takes(seq, dtype)):
+        return "xla"
+    if (
+        tile_heads(heads, kv_heads, depth) is None
+        or (backward_impl or BACKWARD_IMPL) == "xla"
+        or kernel_axes((mesh_lib.AXIS_MODEL,), kv_heads) is not None
+    ):
+        return "bhsd"
+    return "qkv_tiles"
+
+
+def flash_attention_qkv(qkv, heads: int, *, rope=None, mask=None,
+                        segment_ids=None, causal=False, interpret=None,
+                        backward_impl=None, window=None, block_q=None,
+                        block_k=None):
+    """Flash attention over a fused projection ``qkv`` (B, S, 3*H*D), as the
+    matmul wrote it: the q, k and v thirds side by side, heads major within
+    each.  Returns o as (B, S, H*D), what the output projection takes;
+    differentiable in ``qkv``.
+
+    ``rope`` is ``(cos, sin)``, the rotation's tables as lane tiles: (1 or
+    B, S, max(128, D)) with every head's ``[cos, cos]`` and sign-folded
+    ``[-sin, sin]`` repeated across a tile; q and k are rotated in VMEM
+    (float32, one rounding).  None rotates nothing.  The other arguments
+    are :func:`flash_attention`'s; ``backward_impl`` "xla" is not taken
+    here.  The shapes this form takes are :func:`tile_heads`'s; callers
+    choose between it and :func:`flash_attention` by :func:`qkv_layout`.
+    """
+    if qkv.ndim != 3 or qkv.shape[2] % (3 * heads):
+        raise ValueError(
+            f"flash_attention_qkv needs a (B, S, 3*H*D) projection, got "
+            f"{qkv.shape} for {heads} heads")
+    batch, seq, width = qkv.shape
+    depth = width // (3 * heads)
+    hp = tile_heads(heads, heads, depth)
+    if hp is None:
+        raise ValueError(
+            f"{heads} heads of {depth} do not fill lane tiles of {LANES}: "
+            "split the projection and call flash_attention")
+    if (backward_impl or BACKWARD_IMPL) not in ("pallas", "pallas_split"):
+        raise ValueError(
+            "flash_attention_qkv has the Pallas backwards only; the \"xla\" "
+            "backward takes flash_attention's (B, S, H, D) operands")
+    if rope is not None and any(
+            t.ndim != 3 or t.shape[0] not in (1, batch)
+            or t.shape[1:] != (seq, hp * depth) for t in rope):
+        raise ValueError(
+            f"rope tables must be (1 or {batch}, {seq}, {hp * depth}) lane "
+            f"tiles, got {[t.shape for t in rope]}")
+    shape = (batch, seq, heads, depth)
+    mask, segment_ids, window = _check_rows(shape, mask, segment_ids, causal,
+                                            window, block_q, block_k)
+    if interpret is None:
+        interpret = not on_tpu()
+
+    def local(qkv, rope, pad, segment_ids):
+        # resolved here, a call: the jitted kernels below are cached by
+        # their arguments and would not see the environment change
+        bq, bk = _resolve_blocks(
+            qkv.shape[0], heads, seq, depth, qkv.dtype, block_q, block_k,
+            layout="qkv_tiles")
+        return _flash_qkv(qkv, rope, pad, segment_ids, heads, causal,
+                          interpret, backward_impl, window, bq, bk)
+
+    # batch over the data axes; the lane dimension whole (qkv_layout sends
+    # a head-sharded call the other way)
+    batch_axes = kernel_axes(mesh_lib.BATCH_AXES, batch)
+    act = P(batch_axes, None, None)
+    row = P(batch_axes, None)
+    tabs = None if rope is None else tuple(
+        P(batch_axes if t.shape[0] > 1 else None, None, None) for t in rope)
+    return shard_kernel(local, (act, tabs, row, row), act)(
+        qkv, rope, mask, segment_ids)

@@ -13,8 +13,9 @@ Resolution order inside the kernel (``flash_attention._resolve_blocks``):
 
 1. explicit ``block_q=`` / ``block_k=`` arguments (the sweep driver);
 2. ``DTFT_FLASH_BLOCK_Q/K`` env overrides (the on-chip A/B knob);
-3. a cache entry matching (platform, dtype, seq, depth) — preferring an
-   exact (batch, heads) match — whose blocks divide the sequence;
+3. a cache entry matching (platform, dtype, seq, depth) and the kernel
+   form (``layout``) — preferring an exact (batch, heads) match — whose
+   blocks divide the sequence;
 4. the retuned default chain.
 
 Cache location: ``DTFT_FLASH_TUNE_CACHE`` env var, else
@@ -32,6 +33,7 @@ Schema (validated by ``tools/check_metrics_schema.py``)::
      "entries": [{"platform": "tpu", "dtype": "bfloat16",
                   "batch": 16, "heads": 12, "seq": 4096, "depth": 64,
                   "block_q": 1024, "block_k": 1024,
+                  "layout": "bhsd",            # optional; the default
                   "ms": 17.1, "source": "sweep",
                   "timestamp": "2026-08-03T00:00:00"}, ...]}
 
@@ -110,9 +112,15 @@ def load(path: str | None = None) -> dict:
     return doc
 
 
+#: The kernel form an entry without ``layout`` was recorded for: the
+#: (B, H, S, D) kernels, the only form before there were two.
+DEFAULT_LAYOUT = "bhsd"
+
+
 def _entry_key(e: dict) -> tuple:
     return (e.get("platform"), e.get("dtype"), e.get("batch"),
-            e.get("heads"), e.get("seq"), e.get("depth"))
+            e.get("heads"), e.get("seq"), e.get("depth"),
+            e.get("layout", DEFAULT_LAYOUT))
 
 
 def lookup(
@@ -123,11 +131,15 @@ def lookup(
     depth: int,
     batch: int | None = None,
     heads: int | None = None,
+    layout: str = DEFAULT_LAYOUT,
     path: str | None = None,
 ) -> tuple[int, int] | None:
     """The cached (block_q, block_k) for a shape, or None.
 
-    Matching is on (platform, dtype, seq, depth); an entry that also
+    Matching is on (platform, dtype, seq, depth) and the kernel form
+    (``layout``: ``"bhsd"``, or ``"qkv_tiles"`` for the kernels that read
+    the fused projection; they spend their VMEM differently, so one's
+    best tiling is no prior for the other); an entry that also
     matches (batch, heads) exactly beats a shape-generic one (batch and
     heads only scale the grid's embarrassingly-parallel axes, so a
     different-batch measurement of the same (seq, depth) is still the
@@ -144,7 +156,8 @@ def lookup(
         if not isinstance(e, dict):
             continue
         if (e.get("platform") != platform or e.get("dtype") != dtype
-                or e.get("seq") != seq or e.get("depth") != depth):
+                or e.get("seq") != seq or e.get("depth") != depth
+                or e.get("layout", DEFAULT_LAYOUT) != layout):
             continue
         bq, bk = e.get("block_q"), e.get("block_k")
         if not (isinstance(bq, int) and isinstance(bk, int)
@@ -162,7 +175,7 @@ def store(entry: dict[str, Any], path: str | None = None) -> str:
     Required keys: platform, dtype, seq, depth, block_q, block_k.
     ``source`` defaults to "sweep"; a timestamp is stamped when absent.
     Atomic write; an existing entry with the same
-    (platform, dtype, batch, heads, seq, depth) key is replaced.
+    (platform, dtype, batch, heads, seq, depth, layout) key is replaced.
     """
     p = cache_path(path)
     if p is None:

@@ -378,3 +378,242 @@ def test_window_requires_causal():
         flash_attention(q, q, q, window=8, interpret=True)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, causal=True, window=0, interpret=True)
+
+
+# --- the fused projection read as it lies (flash_attention_qkv) -------------
+
+
+def _fused_case(d, h, *, s=128, b=2, per_row=False, seed=0):
+    """A projection (B, S, 3*H*D), its positions, and the rotation's lane
+    tables as the trunk hands them to the blocks."""
+    from distributedtensorflow_tpu.models.gpt import rope_lane_tables
+
+    qkv = jax.random.normal(jax.random.PRNGKey(seed + d), (b, s, 3 * h * d))
+    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+    if per_row:
+        pos = pos + 7 * jnp.arange(b)[:, None]
+    return qkv, pos, rope_lane_tables(pos if per_row else pos[:1], d, 1e4)
+
+
+def _split_heads(qkv, h):
+    b, s, w = qkv.shape
+    return tuple(x.reshape(b, s, h, w // (3 * h))
+                 for x in jnp.split(qkv, 3, axis=-1))
+
+
+def _rope_then_dense(qkv, pos, h, *, causal, mask=None, segment_ids=None,
+                     window=None):
+    """What the fused entry replaces: split, ``rope`` outside, the dense
+    reference."""
+    from distributedtensorflow_tpu.models.gpt import rope
+
+    q, k, v = _split_heads(qkv, h)
+    q, k = rope(q, pos, 1e4), rope(k, pos, 1e4)
+    keep = None if mask is None else mask[:, None, None, :]
+    if segment_ids is not None:
+        seg = (segment_ids[:, :, None] == segment_ids[:, None, :])[:, None]
+        keep = seg if keep is None else keep & seg
+    o = xla_attention(q, k, v, mask=keep, causal=causal, window=window)
+    return o.reshape(qkv.shape[0], qkv.shape[1], -1)
+
+
+_PAD = np.ones((2, 128), bool)
+_PAD[0, 100:] = False
+_SEGMENTS = (np.arange(128)[None, :] >= np.array([[40], [90]])).astype(
+    np.int32)
+
+FUSED_CASES = [
+    # depth, heads, causal, rows, window, backward, per-row positions, blocks
+    (32, 4, True, None, None, "pallas", False, None),
+    (32, 8, False, None, None, "pallas_split", True, None),
+    (64, 2, True, None, None, "pallas", False, None),
+    (64, 4, False, None, None, "pallas", True, None),
+    (64, 4, True, None, None, "pallas_split", True, None),
+    (128, 2, True, None, None, "pallas", True, None),
+    (128, 1, False, None, None, "pallas_split", False, None),
+    (256, 1, True, None, None, "pallas", False, None),
+    (256, 2, False, None, None, "pallas_split", True, None),
+    (64, 2, False, "mask", None, "pallas", False, None),
+    (64, 2, True, "mask", None, "pallas_split", True, None),
+    (64, 2, True, "segments", None, "pallas", False, None),
+    (32, 4, False, "segments", None, "pallas_split", True, None),
+    (128, 1, True, "segments", None, "pallas", True, (64, 64)),
+    (64, 2, True, None, 33, "pallas", False, (32, 32)),
+    (32, 4, True, None, 70, "pallas_split", True, (64, 32)),
+    # several q and k blocks of unequal size: the running softmax, k rotated
+    # at every visit, dq accumulated over the k sweep
+    (64, 4, True, None, None, "pallas", True, (32, 64)),
+    (64, 2, False, None, None, "pallas", False, (64, 32)),
+    (64, 2, True, None, None, "pallas_split", False, (32, 64)),
+]
+
+
+@pytest.mark.parametrize(
+    "d,h,causal,rows,window,backward,per_row,blocks", FUSED_CASES,
+    ids=[f"d{c[0]}-h{c[1]}-{'causal' if c[2] else 'full'}-{c[3] or 'norows'}"
+         f"-w{c[4]}-{c[5]}-{'offsets' if c[6] else 'arange'}-"
+         f"{'x'.join(map(str, c[7])) if c[7] else 'oneblock'}"
+         for c in FUSED_CASES])
+def test_fused_projection_matches_rope_then_dense(
+        d, h, causal, rows, window, backward, per_row, blocks):
+    """``flash_attention_qkv`` reads the projection as the matmul wrote it,
+    two heads of 64 to a 128-lane tile (four of 32, one of 128 or 256),
+    and rotates q and k in VMEM: o and d``qkv`` are those of split,
+    ``rope`` and dense attention."""
+    from distributedtensorflow_tpu.ops.flash_attention import (
+        flash_attention_qkv)
+
+    qkv, pos, tabs = _fused_case(d, h, per_row=per_row)
+    kw = dict(causal=causal, window=window,
+              mask=jnp.asarray(_PAD) if rows == "mask" else None,
+              segment_ids=jnp.asarray(_SEGMENTS) if rows == "segments"
+              else None)
+    bq, bk = blocks or (None, None)
+    # a padded query row attends nothing real: compare the rows that do
+    live = jnp.asarray(_PAD if rows == "mask" else np.ones((2, 128), bool))
+    weight = jax.random.normal(jax.random.PRNGKey(9), (2, 128, h * d))
+    weight = weight * live[:, :, None]
+
+    def fused(x):
+        return flash_attention_qkv(
+            x, h, rope=tabs, interpret=True, backward_impl=backward,
+            block_q=bq, block_k=bk, **kw)
+
+    def dense(x):
+        return _rope_then_dense(x, pos, h, **kw)
+
+    o, want = fused(qkv), dense(qkv)
+    np.testing.assert_allclose(o * live[:, :, None], want * live[:, :, None],
+                               atol=2e-5, rtol=2e-5)
+    got, ref = (jax.grad(lambda x, f=f: jnp.sum(f(x) * weight))(qkv)
+                for f in (fused, dense))
+    np.testing.assert_allclose(got, ref, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("blocks", [None, (32, 64)],
+                         ids=["oneblock", "32x64"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_projection_scores_are_the_bhsd_kernels_bit_for_bit(causal,
+                                                                  blocks):
+    """With the rotation off, a head's scores out of a 128-lane tile (a
+    contraction 128 deep over exact zeros) and its softmax are the (B, H,
+    S, D) kernels': o agrees bit for bit, the gradients to rounding (delta
+    is summed in the kernel here, by XLA there)."""
+    from distributedtensorflow_tpu.ops.flash_attention import (
+        flash_attention_qkv)
+
+    h = 4
+    qkv, _, _ = _fused_case(64, h)
+    bq, bk = blocks or (None, None)
+    kw = dict(causal=causal, interpret=True, block_q=bq, block_k=bk)
+
+    def tiles(x):
+        return flash_attention_qkv(x, h, **kw)
+
+    def bhsd(x):
+        return flash_attention(*_split_heads(x, h), **kw).reshape(
+            2, 128, h * 64)
+
+    np.testing.assert_array_equal(tiles(qkv), bhsd(qkv))
+    got, ref = (jax.grad(lambda x, f=f: jnp.sum(f(x) ** 2))(qkv)
+                for f in (tiles, bhsd))
+    np.testing.assert_allclose(got, ref, atol=1e-4, rtol=1e-4)
+
+
+def test_fused_projection_in_bfloat16_rounds_the_rotation_once():
+    """bf16 operands, float32 tables and accumulation: against the float32
+    reference the fused entry is no further off than ``rope`` outside (three
+    roundings to bf16 where it has one) and the (B, H, S, D) kernels."""
+    from distributedtensorflow_tpu.models.gpt import rope
+    from distributedtensorflow_tpu.ops.flash_attention import (
+        flash_attention_qkv)
+
+    h = 2
+    qkv, pos, tabs = _fused_case(64, h)
+    want = _rope_then_dense(qkv, pos, h, causal=True)
+    x = qkv.astype(jnp.bfloat16)
+    got = flash_attention_qkv(x, h, rope=tabs, causal=True, interpret=True)
+    q, k, v = _split_heads(x, h)
+    old = flash_attention(rope(q, pos, 1e4), rope(k, pos, 1e4), v,
+                          causal=True, interpret=True).reshape(2, 128, -1)
+    assert got.dtype == jnp.bfloat16
+
+    def err(o):
+        return float(jnp.sqrt(jnp.mean((o.astype(jnp.float32) - want) ** 2)))
+
+    assert err(got) <= 1.05 * err(old) < 0.02
+
+
+FALLBACKS = {
+    # a head of 96 fills no lane tile; three heads of 64 leave one half
+    # full; GQA's k and v thirds are narrower than q's
+    "d96": dict(hidden_size=384, num_heads=4),
+    "odd_heads": dict(hidden_size=192, num_heads=3),
+    "gqa": dict(hidden_size=128, num_heads=4, num_kv_heads=2),
+}
+
+
+@pytest.mark.parametrize("case", ["tiles", *sorted(FALLBACKS)])
+def test_block_falls_back_to_the_bhsd_kernels_by_shape(case, monkeypatch):
+    """The block chooses the form by what it can observe: shapes whose lane
+    tiles hold no whole heads of q, k and v keep split + ``rope`` + the
+    (B, H, S, D) kernels — and ``attention_layout``, which the trainer
+    reports at start-up, says so."""
+    import dataclasses
+
+    import distributedtensorflow_tpu.ops.flash_attention as fa
+    from distributedtensorflow_tpu.models import gpt
+
+    cfg = dataclasses.replace(
+        gpt.gpt_tiny(), attn_impl="pallas", num_layers=1,
+        **FALLBACKS.get(case, {}))
+    taken = []
+    for name in ("flash_attention", "flash_attention_qkv"):
+        real = getattr(fa, name)
+
+        def spy(*args, _real=real, _name=name, **kw):
+            taken.append(_name)
+            return _real(*args, **kw)
+
+        monkeypatch.setattr(fa, name, spy)
+    ids = jnp.zeros((1, 64), jnp.int32)
+    model = gpt.GPTLM(cfg)
+    logits = model.apply(model.init(jax.random.PRNGKey(0), ids), ids)
+    assert np.isfinite(np.asarray(logits)).all()
+    want = "qkv_tiles" if case == "tiles" else "bhsd"
+    assert gpt.attention_layout(cfg, 64) == model.flash_layout(64) == want
+    entry = {"qkv_tiles": "flash_attention_qkv", "bhsd": "flash_attention"}
+    assert set(taken) == {entry[want]}, taken
+
+
+def test_fused_projection_layout_follows_what_it_can_observe(monkeypatch):
+    import distributedtensorflow_tpu.ops.flash_attention as fa
+
+    f32 = jnp.float32
+    assert fa.tile_heads(16, 16, 64) == 2
+    assert fa.tile_heads(12, 12, 64) == 2
+    assert fa.tile_heads(8, 8, 32) == 4
+    assert fa.tile_heads(3, 3, 128) == fa.tile_heads(2, 2, 256) == 1
+    assert fa.tile_heads(4, 4, 96) is None
+    assert fa.tile_heads(3, 3, 64) is None
+    assert fa.tile_heads(4, 2, 64) is None
+    # off the TPU "auto" keeps XLA's attention; forced, the shape decides
+    assert fa.qkv_layout(1024, 16, 16, 64, f32) == "xla"
+    assert fa.qkv_layout(1024, 16, 16, 64, f32,
+                         implementation="xla") == "xla"
+    assert fa.qkv_layout(1024, 16, 16, 64, f32,
+                         implementation="pallas") == "qkv_tiles"
+    assert fa.qkv_layout(1024, 16, 4, 64, f32,
+                         implementation="pallas") == "bhsd"
+    # the golden backward is the (B, S, H, D) operands'
+    assert fa.qkv_layout(1024, 16, 16, 64, f32, implementation="pallas",
+                         backward_impl="xla") == "bhsd"
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    assert fa.qkv_layout(1024, 16, 16, 64, jnp.bfloat16) == "qkv_tiles"
+    assert fa.qkv_layout(512, 16, 16, 64, jnp.bfloat16) == "xla"
+    with pytest.raises(ValueError, match="lane tiles"):
+        fa.flash_attention_qkv(jnp.zeros((1, 64, 3 * 3 * 64)), 3)
+    with pytest.raises(ValueError, match="lane tiles, got"):
+        fa.flash_attention_qkv(
+            jnp.zeros((1, 64, 3 * 2 * 64)), 2,
+            rope=(jnp.zeros((1, 64, 64)), jnp.zeros((1, 64, 64))))

@@ -83,6 +83,28 @@ class TestCacheRoundtrip:
             platform=jax.default_backend(), dtype="float32",
             seq=S, depth=D) is None
 
+    def test_an_entry_is_of_one_kernel_form(self, cache):
+        """A tiling recorded for the (B, H, S, D) kernels (every entry
+        written before there were two forms: no ``layout``) is no prior for
+        the kernels that read the fused projection, nor the other way
+        round; each form's entry replaces only its own."""
+        where = dict(platform=jax.default_backend(), dtype="float32",
+                     seq=S, depth=D, batch=B, heads=H)
+        flash_tuning.store(_entry())
+        assert flash_tuning.lookup(**where) == (32, 64)
+        assert flash_tuning.lookup(**where, layout="qkv_tiles") is None
+        assert _resolve_blocks(B, H, S, D, jnp.float32, None, None,
+                               layout="qkv_tiles") == (S, S)
+        flash_tuning.store(_entry(layout="qkv_tiles", block_q=64,
+                                  block_k=32))
+        assert len(json.load(open(cache))["entries"]) == 2
+        assert flash_tuning.lookup(**where) == (32, 64)
+        assert flash_tuning.lookup(**where, layout="qkv_tiles") == (64, 32)
+        assert _resolve_blocks(B, H, S, D, jnp.float32, None, None,
+                               layout="qkv_tiles") == (64, 32)
+        assert _resolve_blocks(B, H, S, D, jnp.float32, None, None) == (
+            32, 64)
+
     def test_corrupt_file_degrades_to_none(self, cache):
         with open(cache, "w") as f:
             f.write("{not json")

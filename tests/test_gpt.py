@@ -436,3 +436,65 @@ def test_sliding_window_generate_matches_full_forward():
         nxt = jnp.argmax(logits[:, -1], axis=-1)[:, None]
         cur = jnp.concatenate([cur, nxt], axis=1)
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(cur))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("window", [None, 9], ids=["full", "window9"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_block_with_the_fused_entry_matches_rope_outside(dtype, window,
+                                                         remat):
+    """The training block in its two forms on the same parameters: the
+    flash kernels reading the fused projection and rotating in VMEM
+    (``attn_impl`` "pallas", interpreted here: ``attention_layout``
+    "qkv_tiles") against split, ``rope`` and XLA's dense attention — the
+    logits and every gradient, at the tolerance that holds the served block
+    to the training block (``tests/test_serve.py``)."""
+    from distributedtensorflow_tpu.models.gpt import attention_layout
+
+    cfg = dataclasses.replace(gpt_tiny(), dtype=dtype, attn_window=window,
+                              remat=remat)
+    dense = GPTLM(dataclasses.replace(cfg, attn_impl="xla"))
+    fused = GPTLM(dataclasses.replace(cfg, attn_impl="pallas"))
+    assert attention_layout(fused.cfg, 48) == "qkv_tiles"
+    assert attention_layout(dense.cfg, 48) == "xla"
+    ids = jax.random.randint(jax.random.PRNGKey(2), (2, 48), 0,
+                             cfg.vocab_size)
+    params = dense.init(jax.random.PRNGKey(0), ids)["params"]
+    # default init gives logits of ~0.02: scale the matrices up so that a
+    # wrong block would show
+    params = jax.tree.map(lambda p: p * 4 if p.ndim == 2 else p, params)
+
+    def run(model):
+        def loss(p):
+            logits = model.apply({"params": p}, ids)
+            picked = jnp.take_along_axis(
+                jax.nn.log_softmax(logits), ids[:, :, None], axis=-1)
+            return -jnp.mean(picked), logits
+        (_, logits), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return np.asarray(logits), grads
+
+    flat = lambda g: np.concatenate(
+        [np.asarray(x, np.float32).ravel() for x in jax.tree.leaves(g)])
+    (want, g_want), (got, g_got) = run(dense), run(fused)
+    assert got.dtype == want.dtype == np.float32
+    g_want, g_got = flat(g_want), flat(g_got)
+    if dtype == jnp.float32:
+        # that test's 1e-5 is between two orders of the same sums; here a
+        # row's softmax is summed in another order, over logits of +-10
+        np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+        np.testing.assert_allclose(g_got, g_want, atol=2e-5, rtol=1e-3)
+        return
+    assert np.isfinite(got).all() and np.isfinite(g_got).all()
+    assert (got.argmax(-1) == want.argmax(-1)).mean() >= 0.75
+    assert np.median(np.abs(got - want)) < 0.1
+    # bf16 rounding moves a gradient of these scaled-up weights by a fifth:
+    # the fused entry (one rounding of the rotation, float32 tables) must
+    # be no further from the float32 block than rope outside is
+    exact, g_exact = run(GPTLM(dataclasses.replace(
+        cfg, dtype=jnp.float32, attn_impl="xla")))
+    g_exact = flat(g_exact)
+    assert (np.linalg.norm(g_got - g_exact)
+            <= 1.1 * np.linalg.norm(g_want - g_exact))
+    assert (np.median(np.abs(got - exact))
+            <= 1.1 * np.median(np.abs(want - exact)))

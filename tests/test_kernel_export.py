@@ -31,7 +31,10 @@ from distributedtensorflow_tpu.ops.attention import (
     paged_latent_decode_attention,
     paged_window_decode_attention,
 )
-from distributedtensorflow_tpu.ops.flash_attention import flash_attention
+from distributedtensorflow_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_qkv,
+)
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
@@ -58,6 +61,22 @@ def _flash(**kw):
             q, k, v, causal=True, interpret=False, **kw)),
         argnums=(0, 1, 2),
     )
+
+
+def _flash_qkv(heads=H, **kw):
+    # the training block's entry: the fused projection as the matmul wrote
+    # it, the rotation's tables as float32 lane tiles
+    return jax.value_and_grad(
+        lambda qkv, cos, sin: _sum32(flash_attention_qkv(
+            qkv, heads, rope=(cos, sin), causal=True, interpret=False,
+            **kw)))
+
+
+def _fused(seq=S, batch=B, heads=H, d=D, table_rows=1):
+    tile = max(128, d)
+    return (_sds((batch, seq, 3 * heads * d), BF16),
+            _sds((table_rows, seq, tile), F32),
+            _sds((table_rows, seq, tile), F32))
 
 
 def _xent(h, w, t):
@@ -164,6 +183,17 @@ FAMILIES = {
     "flash_8192": (_flash(), _qkv(seq=8192, batch=2)),
     "flash_window": (_flash(window=256), _qkv(seq=2048, batch=4)),
     "flash_gqa": (_flash(), _qkv(kv_heads=4)),
+    # GPT-2 medium's training block: 16 heads of 64, two a 128-lane tile,
+    # one q and one k block: a (1024, 1024) float32 score tile a head, a
+    # (1024, 128) accumulator, the whole tile's dq, the tables fetched once
+    "flash_qkv_gpt2m": (_flash_qkv(16), _fused(heads=16)),
+    # per-row positions; eight blocks a side, so the running softmax and
+    # the split backward (the dq of a whole tile no longer fits)
+    "flash_qkv_8192": (_flash_qkv(), _fused(seq=8192, batch=2,
+                                            table_rows=2)),
+    "flash_qkv_window": (_flash_qkv(window=256), _fused(seq=2048, batch=4)),
+    "flash_qkv_d128": (_flash_qkv(8), _fused(heads=8, d=128)),
+    "flash_qkv_d32": (_flash_qkv(16), _fused(heads=16, d=32)),
     "fused_xent": (_xent, (_sds((B, S, H * D), BF16),
                            _sds((V, H * D), F32),
                            _sds((B, S), jnp.int32))),
@@ -237,10 +267,13 @@ def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
     repl = NamedSharding(mesh, P())
     gb = B * n_chips
 
-    def step(q, k, v, x, g, b, w, t):
-        return _flash()(q, k, v), _ln(x, g, b), _xent(x, w, t)
+    def step(qkv, cos, sin, q, k, v, x, g, b, w, t):
+        return (_flash_qkv()(qkv, cos, sin), _flash()(q, k, v),
+                _ln(x, g, b), _xent(x, w, t))
 
     args = (
+        _sds((gb, S, 3 * H * D), BF16, batch),
+        _sds((1, S, 128), F32, repl), _sds((1, S, 128), F32, repl),
         *(_sds((gb, S, H, D), BF16, batch) for _ in range(3)),
         _sds((gb, S, H * D), BF16, batch),
         _sds((H * D,), F32, repl), _sds((H * D,), F32, repl),
@@ -260,6 +293,9 @@ def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
             "fused_xent_bwd_dw"} <= names, names
     first_dims = {int(s.split("x")[0]) for _, s in shapes}
     assert first_dims == {B, B * S}, first_dims  # per device, never global
+    # both forms of the flash kernels: (B, S, 3*H*D) and (B, H, S, D)
+    assert {s for n, s in shapes if n == "flash_fwd"} == {
+        f"{B}x{S}x{3 * H * D}", f"{B}x{H}x{S}x{D}"}, shapes
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -272,6 +308,83 @@ def _as_on_the_chip(monkeypatch):
         if name.startswith("distributedtensorflow_tpu") \
                 and hasattr(module, "on_tpu"):
             monkeypatch.setattr(module, "on_tpu", lambda: True)
+
+
+def _attention_block_text(one_chip, batch=16):
+    """GPT-2 medium's attention block (``models/gpt.py:
+    CausalSelfAttention``: qkv -> attention -> proj) as the trainer's step
+    holds it: forward and backward under ``jax.checkpoint``, the rotation's
+    tables made once outside, compiled for the described chip."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import gpt
+
+    cfg = dataclasses.replace(gpt.gpt_medium(), max_seq=1024)
+    attn = gpt.CausalSelfAttention(cfg)
+    x = _sds((batch, 1024, cfg.hidden_size), BF16, one_chip)
+    positions = jnp.broadcast_to(jnp.arange(1024), (batch, 1024))
+    params = jax.eval_shape(
+        lambda: attn.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, BF16),
+                          positions, True))
+    params = jax.tree.map(lambda p: _sds(p.shape, p.dtype, one_chip), params)
+
+    def loss(params, x):
+        tabs = gpt.block_rope_tables(
+            cfg, None, x.shape[:2],
+            fused=gpt.attention_layout(cfg, 1024) == "qkv_tiles")
+        block = jax.checkpoint(
+            lambda p, x: attn.apply(p, x, positions, True, tabs))
+        return _sum32(block(params, block(params, x)))
+
+    fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+    return fn.lower(params, x).compile().as_text()
+
+
+def _whole_tensor_moves(text, elems, head_dim):
+    """``(moves, matrices)``: the ``copy`` / ``transpose`` / ``slice`` ops
+    of the compiled program whose result holds at least ``elems`` values,
+    and the (head_dim, head_dim) arrays it holds (the rotary's half-swap
+    is a product against one)."""
+    moves, matrices = [], []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m:
+            continue
+        dims = [int(n) for n in m.group(1).split(",")]
+        size = 1
+        for n in dims:
+            size *= n
+        if dims == [head_dim, head_dim]:
+            matrices.append(line.strip()[:120])
+        elif m.group(2) in ("copy", "transpose", "slice") and size >= elems:
+            moves.append(line.strip()[:120])
+    return moves, matrices
+
+
+@pytest.mark.parametrize("form", ["qkv_tiles", "bhsd"])
+def test_attention_block_moves_no_whole_tensor_on_a_v5e(form, monkeypatch):
+    """Between the qkv product and the flash kernels, and between them and
+    the output projection, q, k, v, o and their gradients take no trip
+    through HBM: the compiled block holds no ``copy``, ``transpose`` or
+    ``slice`` of a (B, S, H*D)-sized array and no product against a (D, D)
+    matrix (the rotary's half-swap).  The (B, H, S, D) form, which a shape
+    that fills no lane tile falls back to in silence, holds both: the check
+    can see them (ten copies and four products a block pass, 241 ms of a
+    1995 ms step: ``PERF.md`` section 6, PR 35)."""
+    import distributedtensorflow_tpu.ops.flash_attention as fa
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    if form == "bhsd":
+        monkeypatch.setattr(fa, "tile_heads", lambda *a: None)
+    text = _attention_block_text(one_chip)
+    assert text.count("tpu_custom_call") >= 6   # 2 x (fwd, fwd again, bwd)
+    moves, matrices = _whole_tensor_moves(text, 16 * 1024 * 1024, 64)
+    if form == "qkv_tiles":
+        assert moves == [] and matrices == [], (moves, matrices)
+    else:
+        assert len(moves) >= 10 and matrices, (moves, matrices)
 
 
 def _gpt2m_pool_programs(one_chip, **changes):

@@ -552,6 +552,25 @@ def run_ps_cluster_task(args, cluster, task_type, task_index) -> None:
     )
 
 
+def _flash_layout(wl, mesh) -> dict:
+    """``{"flash_layout": ...}`` for the ``startup.trainer`` row and the
+    start-up log: the form the step's dense attention lowers to
+    (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla"), read
+    under the trainer's mesh as the step is traced.  The fall-back from
+    one form to the next is silent and costs a tenth of a step, so a run's
+    trace says which it got.  Empty for a model that has no such choice."""
+    ask = getattr(wl.model, "flash_layout", None)
+    ids = wl.init_batch.get("input_ids")
+    if ask is None or ids is None:
+        return {}
+    with jax.sharding.set_mesh(mesh):
+        layout = ask(ids.shape[1])
+    if layout is None:
+        return {}
+    logging.info("flash_layout: %s", layout)
+    return {"flash_layout": layout}
+
+
 def main() -> None:
     # allow_abbrev=False: apply_config_file detects explicitly-typed flags
     # by matching argv against option strings; prefix abbreviations would
@@ -1490,7 +1509,7 @@ def main() -> None:
     )
     # the input-plane services, the restore, the trainer with its metric
     # writer (TensorBoard import) and status server
-    startup.mark("startup.trainer")
+    startup.mark("startup.trainer", **_flash_layout(wl, mesh))
     if dynamics_monitor is not None and trainer.status_server is not None:
         dynamics_monitor.install(trainer.status_server)
     if elastic is not None:
