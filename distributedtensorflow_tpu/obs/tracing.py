@@ -22,7 +22,11 @@ Design constraints:
   same clock as the device lanes.  No ``capture_active()`` gate: a trace
   started outside ``obs.capture`` must see the spans too;
 - spans may complete on any thread (the Prefetcher's ``device_put`` worker);
-  roots from any thread land in the currently open step row.
+  roots from any thread land in the currently open step row;
+- where the leaves of a stretch must leave nothing of it unnamed (the
+  serving engine's iteration), :class:`tiled` is the same ``Span`` and the
+  same annotation with the boundaries shared: one clock read closes a leaf
+  and opens the next, and a parent ends with its last child.
 
 ``trace.jsonl`` row schema (one JSON object per line)::
 
@@ -30,6 +34,10 @@ Design constraints:
      "spans": [{"name": str, "dur_s": float, "children": [...]}, ...]}
     {"kind": "anomaly", "step": int, "anomaly": str, "message": str,
      "value": float}
+    {"kind": "anomaly", "anomaly": "engine_stall", "t": float, "step": int
+     (the steps.jsonl record's), "value": float (step_s + log_prev_s),
+     "median_s": float, "log_prev_s": float, "message": str,
+     "spans": [tree]}
     {"kind": "span", "name": str, "trace_id": str, "span_id": str,
      "parent_id": str?, "t0": float unix seconds, "dur_s": float,
      "proc": int, ...}
@@ -54,16 +62,17 @@ names and absolute times.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
-import uuid
 from typing import Any
 
 __all__ = [
     "Span",
     "span",
+    "tiled",
     "TraceRecorder",
     "active_recorder",
     "add_root_sink",
@@ -136,20 +145,112 @@ class span:
         self._ann.__exit__(exc_type, exc, tb)
         stack = _tls.stack
         stack.pop()
-        if stack:
-            stack[-1].children.append(s)
-        else:
-            rec = _recorder
-            if rec is not None:
-                rec._add_root(s)
-            for sink in _root_sinks:
-                # A sink raising inside __exit__ would REPLACE the body's
-                # in-flight exception (StopIteration ends the fit loop) —
-                # swallow unconditionally; sinks are telemetry, not logic.
-                try:
-                    sink(s)
-                except Exception:
-                    pass
+        _completed(stack, s)
+        return False
+
+
+def _completed(stack: list, s: Span) -> None:
+    """``s`` just closed with ``stack`` holding what is still open on this
+    thread: a child goes to its parent, a root to the recorder and the
+    sinks."""
+    if stack:
+        stack[-1].children.append(s)
+        return
+    rec = _recorder
+    if rec is not None:
+        rec._add_root(s)
+    for sink in _root_sinks:
+        # A sink raising inside __exit__ would REPLACE the body's
+        # in-flight exception (StopIteration ends the fit loop) —
+        # swallow unconditionally; sinks are telemetry, not logic.
+        try:
+            sink(s)
+        except Exception:
+            pass
+
+
+class tiled:
+    """``with tiled("engine.step", "engine.admit") as t: ...`` — a span
+    whose descendants tile it: every instant from entry to exit lies inside
+    exactly one leaf.
+
+    The names after the root's are the path it opens with, and
+    ``t.to(*path)`` names where the thread goes next, as the span names
+    below the root (``t.to("engine.decode", "engine.decode.fetch")``).  ONE
+    clock read closes what is open and not a parent on ``path``, and opens
+    the rest of ``path``; its last name is always a new span, which is
+    returned (its ``dur_s`` is final once the next ``to`` or the exit has
+    closed it).  The entry opens the root and its first path with one read
+    and the exit closes the open leaf, its parents and the root with one,
+    so a parent neither precedes its first child nor outlives its last.
+    Spans and annotations are ``span``'s: the tree, the recorder, the sinks
+    and the profiler see no difference, and a plain ``span`` may nest
+    inside a leaf."""
+
+    __slots__ = ("root", "_open")
+
+    def __init__(self, name: str, *first: str, **attrs: Any):
+        self.root = Span(name)
+        #: (span, annotation) from the root down to the open leaf
+        self._open = [(self.root, _annotation(name, attrs))]
+        self._open += [(Span(n), _annotation(n, {})) for n in first]
+
+    def __enter__(self) -> "tiled":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        for _, ann in self._open:
+            ann.__enter__()
+        t = time.perf_counter()
+        for s, _ in self._open:
+            s.t0 = t
+            stack.append(s)
+        return self
+
+    @property
+    def parent(self) -> Span:
+        """The open leaf's parent."""
+        return self._open[-2][0]
+
+    def _close(self, keep: int, t: float) -> None:
+        """Close the open spans below the first ``keep`` (>= 1), innermost
+        first, all at ``t``: each goes to its parent, which is open."""
+        open_, stack = self._open, _tls.stack
+        while len(open_) > keep:
+            s, ann = open_.pop()
+            s.dur_s = t - s.t0
+            ann.__exit__(None, None, None)
+            stack.pop()
+            open_[-1][0].children.append(s)
+
+    def to(self, *path: str) -> Span:
+        open_ = self._open
+        # the parents on `path` that are open already stay open
+        keep, last = 1, len(path) - 1
+        while keep <= last and keep < len(open_) \
+                and open_[keep][0].name == path[keep - 1]:
+            keep += 1
+        new = [(Span(n), _annotation(n, {})) for n in path[keep - 1:]]
+        t = time.perf_counter()
+        self._close(keep, t)
+        stack = _tls.stack
+        for pair in new:
+            s, ann = pair
+            ann.__enter__()
+            s.t0 = t
+            stack.append(s)
+            open_.append(pair)
+        return s
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t = time.perf_counter()
+        self._close(1, t)
+        root, ann = self._open[0]
+        root.dur_s = t - root.t0
+        ann.__exit__(exc_type, exc, tb)
+        stack = _tls.stack
+        stack.pop()
+        _completed(stack, root)
         return False
 
 
@@ -341,15 +442,29 @@ class TraceRecorder:
 _ctx_tls = threading.local()
 
 
-def new_trace_id() -> str:
-    """A fresh 16-hex-char trace id (shared across every process a
-    request touches)."""
-    return uuid.uuid4().hex[:16]
+#: Ids are 8 hex characters drawn once a process and 8 of a counter: no
+#: system call (``uuid4`` reads ``os.urandom``, which lets go of the
+#: interpreter on the engine thread) and unique within the process.
+_id_prefix = os.urandom(4).hex()
+_id_counter = itertools.count(1)
+
+
+def _redraw_id_prefix() -> None:
+    global _id_prefix
+    _id_prefix = os.urandom(4).hex()
+
+
+os.register_at_fork(after_in_child=_redraw_id_prefix)
 
 
 def new_span_id() -> str:
     """A fresh 16-hex-char span id (unique per emitted span)."""
-    return uuid.uuid4().hex[:16]
+    return f"{_id_prefix}{next(_id_counter) & 0xFFFFFFFF:08x}"
+
+
+#: A fresh 16-hex-char trace id (shared across every process a request
+#: touches): the same draw.
+new_trace_id = new_span_id
 
 
 def current_context() -> dict[str, str] | None:

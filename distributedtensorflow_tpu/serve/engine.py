@@ -67,8 +67,9 @@ online replacement:
   rows are unchanged.
 
 Observability (wired into the obs registry): ``serve_ttft_seconds``,
-``serve_tpot_seconds``, ``serve_e2e_seconds``, ``serve_batch_occupancy``
-histograms, queue/slot/block gauges, ``serve_requests_total{status=}`` /
+``serve_tpot_seconds``, ``serve_e2e_seconds``, ``serve_batch_occupancy``,
+``serve_stream_lag_seconds`` histograms, queue/slot/block gauges,
+``serve_requests_total{status=}`` /
 ``serve_tokens_generated_total`` / ``serve_admits_total{reused=}``
 counters; prefix-caching counters ``serve_prefix_hits_total`` /
 ``serve_prefix_cached_tokens_total`` / ``serve_prefill_tokens_total`` /
@@ -88,9 +89,13 @@ summing to ``e2e_s``) and periodic ``metrics.jsonl`` rows +
 ``tools/run_report.py`` and ``tools/check_metrics_schema.py`` consume).
 Every scheduler iteration that did work additionally leaves one step-log
 record — phase mix, occupancy, token/draft deltas, admissions/evictions,
-prefill chunks + budget stalls, and the admit/prefill/decode wall
-split, read off the iteration's ``engine.*`` span tree (mirrored into any
-open profiler trace; docs/OBSERVABILITY.md has the vocabulary) — in a
+prefill chunks + budget stalls, and the iteration's wall down to its
+leaves, read off its ``engine.*`` span tree (mirrored into any open
+profiler trace; the leaves tile the iteration and the records tile the
+engine thread's life), with the thread's CPU clock beside the wall
+(``offcpu_s``: it had work and did not run), its seconds inside the
+collector and the stream threads' lines and lag
+(docs/OBSERVABILITY.md has the vocabulary) — in a
 bounded ring (``GET /stepz`` via the
 frontend; :meth:`Engine.step_records`) and ``steps.jsonl``.
 
@@ -104,11 +109,13 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import itertools
 import json
 import math
 import os
 import queue
+import statistics
 import threading
 import time
 
@@ -129,6 +136,37 @@ __all__ = ["Engine", "GenRequest", "QueueFullError"]
 
 #: Terminal request states (the ``requests.jsonl`` ``status`` field).
 TERMINAL_STATES = ("ok", "rejected", "error")
+
+#: An iteration is an ``engine_stall`` (one anomaly row in ``trace.jsonl``)
+#: when ``step_s + log_prev_s`` passes both: this many seconds, and this
+#: many times the running median of the last ``STALL_HISTORY`` working
+#: iterations (``STALL_MIN_HISTORY`` of them at least: the first
+#: iterations compile).
+STALL_MIN_S = 0.25
+STALL_FACTOR = 20.0
+STALL_HISTORY = 256
+STALL_MIN_HISTORY = 8
+
+#: Seconds inside the collector, a running total by the id of each thread
+#: that has run :meth:`Engine.step`: the step record's ``gc_s`` is its
+#: growth since the previous record.  Collections are not concurrent, so
+#: one start stamp serves.
+_gc_seconds: dict[int, float] = {}
+_gc_t0 = 0.0
+
+
+#: ``json.dumps`` that raises on a non-finite float (one encoder, built once)
+_encode_finite = json.JSONEncoder(allow_nan=False).encode
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    global _gc_t0
+    tid = threading.get_ident()
+    if tid in _gc_seconds:
+        if phase == "start":
+            _gc_t0 = time.perf_counter()
+        else:
+            _gc_seconds[tid] += time.perf_counter() - _gc_t0
 
 
 class QueueFullError(RuntimeError):
@@ -204,8 +242,13 @@ class GenRequest:
     attr_spec_s: float = 0.0
     attr_gap_s: float = 0.0
     _t_attr: float = 0.0
+    #: the ``engine.prefill_chunk`` span of this request's last chunk: the
+    #: frontier stands at its start until the next charge reads its wall
+    _s_chunk: object = None
     #: streaming: newly committed tokens per iteration as ("tokens",
-    #: [ids]) events plus one terminal ("done", None); None = blocking.
+    #: [ids], stamp) events — ``stamp`` the ``time.time()`` of the commit,
+    #: which the stream thread measures its lag from — plus one terminal
+    #: ("done", None); None = blocking.
     #: A ``SimpleQueue``: one producer, one consumer, never full — its
     #: ``put`` is a C call that takes no Python-level lock or condition,
     #: and the engine makes one a stream an iteration.
@@ -416,6 +459,30 @@ class Engine:
         self._step_ring: collections.deque = collections.deque(
             maxlen=self.step_ring_size)
         self._step_id = 0
+        #: the open iteration's ``obs.tracing.tiled`` (engine thread only):
+        #: the phases below name their leaves through it
+        self._tiles = None
+        # The engine thread's account between two step records (wall on
+        # the spans' clock, CPU by ``time.thread_time``), from the start
+        # of one ``engine.log`` to the start of the next; reset when
+        # another thread takes over ``step()`` (thread CPU clocks do not
+        # compare).
+        self._tid = None
+        self._mark_wall = 0.0      # start of the previous engine.log
+        self._mark_cpu = 0.0
+        self._mark_gc = 0.0
+        self._log_prev_s = 0.0     # the previous engine.log's wall
+        self._wait_s = 0.0         # engine.wait since the previous step
+        self._cpu_blocked = 0.0    # thread CPU inside the blocking leaves
+        self._cpu_leaf0 = 0.0      # thread CPU where .fetch / .commit began
+        self._recent_walls: collections.deque = collections.deque(
+            maxlen=STALL_HISTORY)
+        #: lags of the lines the stream threads have written since the
+        #: last record (``note_stream_line`` appends, ``engine.log``
+        #: drains: no lock on either side)
+        self._stream_lags: collections.deque = collections.deque()
+        if _gc_callback not in gc.callbacks:
+            gc.callbacks.append(_gc_callback)
         self._step_evicted = 0     # requests finished in the current step
         #: the current step's (device_sampled, logits_fetched)
         self._step_sampled = (0, 0)
@@ -517,6 +584,9 @@ class Engine:
         self._m_spec_accepted = reg.counter(
             "serve_spec_accepted_total",
             "draft tokens accepted by the verifier (always <= drafted)")
+        self._m_stream_lag = reg.histogram(
+            "serve_stream_lag_seconds",
+            "a streamed line: tokens committed -> socket write returned")
         self._m_tok_step = reg.histogram(
             "serve_decode_tokens_per_step",
             "tokens committed per slot per decode step (1 without "
@@ -765,7 +835,6 @@ class Engine:
             # nothing queued, filling or decoding: no iteration to name
             # (the gauges were set when the last request left)
             return False
-        span = obs_tracing.span
         tokens0 = self.counters["decode_tokens"]
         drafted0 = self.counters["spec_drafted"]
         accepted0 = self.counters["spec_accepted"]
@@ -774,64 +843,174 @@ class Engine:
         self._step_latent = [0, 0]
         self._step_scan = 0
         # The iteration is one span tree (mirrored into any open profiler
-        # trace): the step record's walls are its durations, and the
-        # `step` attribute is the steps.jsonl `step` this iteration gets.
-        with span("engine.step", step=self._step_id + 1) as root:
-            with span("engine.admit") as s_admit:
-                admitted = self._admit_from_queue()
-            chunks, prefill_s, decode_s = 0, 0.0, 0.0
+        # trace) whose leaves tile it: a leaf begins where the one before
+        # it ended (`obs.tracing.tiled`).  The step record's walls are its
+        # durations, and the `step` attribute is the steps.jsonl `step`
+        # this iteration gets.
+        with obs_tracing.tiled("engine.step", "engine.admit",
+                               step=self._step_id + 1) as tiles:
+            self._tiles = tiles
+            root = tiles.root
+            tid = threading.get_ident()
+            if tid != self._tid:
+                self._adopt_thread(tid, root.t0)
+            admitted = self._admit_from_queue()
+            chunks = 0
             if self._filling:
-                with span("engine.prefill") as s_prefill:
-                    chunks = self._run_prefill_budget()
-                prefill_s = s_prefill.dur_s
+                chunks = self._run_prefill_budget()
             else:
                 self._prefill_stalled = False
             occupancy = sum(
                 r is not None and r._prefill_done for r in self._slots
             )
             if occupancy:
-                with span("engine.decode") as s_decode:
-                    self._run_decode_step(prefill_s)
-                decode_s = s_decode.dur_s
+                # closes the leaf that held the census (admit, or the
+                # last of engine.prefill) and engine.prefill with it
+                tiles.to("engine.decode", "engine.decode.dispatch")
+                self._run_decode_step(
+                    root.children[-1].dur_s if chunks else 0.0)
             did = bool(admitted or chunks or occupancy)
             if did:
-                with span("engine.log") as s_log:
-                    # Post-eviction census at `now` — the same instant
-                    # and slot set the step record's active_slots
-                    # reflects, so the usage ledger's per-tenant
-                    # integrals tile the step-log occupancy integrals
-                    # exactly (conservation by construction).
-                    now = time.time()
-                    step_s = s_log.t0 - root.t0  # the work, not the log
-                    held = [
-                        (r, self.kv.billed_blocks(i))
-                        for i, r in enumerate(self._slots) if r is not None
-                    ]
-                    self._log_step(
-                        now, s_admit.dur_s, prefill_s, decode_s, step_s,
-                        admitted, chunks, occupancy,
-                        self.counters["decode_tokens"] - tokens0,
-                        self.counters["spec_drafted"] - drafted0,
-                        self.counters["spec_accepted"] - accepted0,
-                        sum(b for _, b in held),
-                    )
-                    self.usage.on_step(now, step_s, held, self._step_id)
-                    if self.decode_steps % self.log_every == 0:
-                        self._log_metrics_row()
+                s_log = tiles.to("engine.log")
+                cpu_now = time.thread_time()
+                # Post-eviction census at `now` — the same instant
+                # and slot set the step record's active_slots
+                # reflects, so the usage ledger's per-tenant
+                # integrals tile the step-log occupancy integrals
+                # exactly (conservation by construction).
+                now = time.time()
+                # step_s: the work, not the log
+                step_s, walls = self._iteration_walls(root, s_log, cpu_now)
+                held = [
+                    (r, self.kv.billed_blocks(i))
+                    for i, r in enumerate(self._slots) if r is not None
+                ]
+                self._log_step(
+                    now, walls, admitted, chunks, occupancy,
+                    self.counters["decode_tokens"] - tokens0,
+                    self.counters["spec_drafted"] - drafted0,
+                    self.counters["spec_accepted"] - accepted0,
+                    sum(b for _, b in held),
+                )
+                self._note_stall(root, step_s + self._log_prev_s, now)
+                self.usage.on_step(now, step_s, held, self._step_id)
+                if self.decode_steps % self.log_every == 0:
+                    self._log_metrics_row()
+        self._tiles = None
+        if did:
+            self._log_prev_s = s_log.dur_s
         return did
 
-    def _log_step(self, now: float, admit_s: float, prefill_s: float,
-                  decode_s: float, step_s: float,
+    def _adopt_thread(self, tid: int, t0: float) -> None:
+        """Another thread runs ``step()`` from here on (the loop thread
+        after a synchronous warm-up, a test's): the account between two
+        records starts anew at ``t0``, this iteration's start."""
+        self._tid = tid
+        self._mark_wall = t0
+        self._mark_cpu = time.thread_time()
+        self._mark_gc = _gc_seconds.setdefault(tid, 0.0)
+        self._log_prev_s = self._wait_s = self._cpu_blocked = 0.0
+
+    def _iteration_walls(self, root, s_log,
+                         cpu_now: float) -> tuple[float, dict[str, float]]:
+        """``step_s`` and the step record's seconds (rounded as the record
+        holds them), read off the iteration's span tree at the start of
+        ``engine.log`` (``s_log``; ``cpu_now`` is the engine thread's CPU
+        clock there), and the account since the previous record moved on
+        to here.
+
+        The leaves tile ``step_s`` (``unnamed_s`` is what they leave:
+        rounding), and a record's ``log_prev_s + between_s + wait_s +
+        step_s`` is the wall from the previous record's ``engine.log`` to
+        this one's: the records tile the engine thread's life.
+        ``offcpu_s`` is that wall less the leaves the thread blocks in by
+        design (``engine.wait``, ``.fetch``, ``engine.first_token``), less
+        the thread's CPU seconds outside them: time it had work and did
+        not run (it waited for the interpreter, or sat in a system
+        call)."""
+        step_s = s_log.t0 - root.t0
+        admit_s = prefill_s = decode_s = first_token_s = 0.0
+        dispatch_s = fetch_s = commit_s = named = 0.0
+        for phase in root.children:     # engine.log is still open
+            name = phase.name
+            if name == "engine.admit":
+                admit_s = named = phase.dur_s
+            elif name == "engine.prefill":
+                prefill_s = phase.dur_s
+                for leaf in phase.children:
+                    named += leaf.dur_s
+                    if leaf.name == "engine.first_token":
+                        first_token_s += leaf.dur_s
+            else:
+                decode_s = phase.dur_s
+                dispatch, fetch, commit = phase.children
+                dispatch_s, fetch_s = dispatch.dur_s, fetch.dur_s
+                commit_s = commit.dur_s
+                named += dispatch_s + fetch_s + commit_s
+        wait_s, log_prev_s = self._wait_s, self._log_prev_s
+        wall = s_log.t0 - self._mark_wall
+        cpu = cpu_now - self._mark_cpu - self._cpu_blocked
+        gc_total = _gc_seconds[self._tid]
+        walls = {
+            "admit_s": round(admit_s, 6),
+            "prefill_s": round(prefill_s, 6),
+            "decode_s": round(decode_s, 6),
+            "step_s": round(step_s, 6),
+            "dispatch_s": round(dispatch_s, 6),
+            "fetch_s": round(fetch_s, 6),
+            "commit_s": round(commit_s, 6),
+            "first_token_s": round(first_token_s, 6),
+            "log_prev_s": round(log_prev_s, 6),
+            "between_s": round(wall - log_prev_s - wait_s - step_s, 6),
+            "wait_s": round(wait_s, 6),
+            "offcpu_s": round(max(
+                wall - wait_s - fetch_s - first_token_s - cpu, 0.0), 6),
+            "commit_cpu_s": round(cpu_now - self._cpu_leaf0, 6)
+            if decode_s else 0.0,
+            "gc_s": round(gc_total - self._mark_gc, 6),
+            "unnamed_s": round(max(step_s - named, 0.0), 6),
+        }
+        self._mark_wall, self._mark_cpu = s_log.t0, cpu_now
+        self._mark_gc = gc_total
+        self._wait_s = self._cpu_blocked = 0.0
+        return step_s, walls
+
+    def _note_stall(self, root, wall_s: float, now: float) -> None:
+        """One ``engine_stall`` row in ``trace.jsonl`` for an iteration
+        whose wall (with the ``engine.log`` before it) is far above the
+        recent iterations': the step id, the record's ``t`` and the span
+        tree, so the stall is found without scanning ``steps.jsonl``."""
+        recent = self._recent_walls
+        if wall_s > STALL_MIN_S and len(recent) >= STALL_MIN_HISTORY:
+            median = statistics.median(recent)
+            rec = obs_tracing.active_recorder()
+            if wall_s > STALL_FACTOR * median and rec is not None:
+                tree = root.to_dict()       # engine.log is still open
+                tree["dur_s"] = round(wall_s - self._log_prev_s, 6)
+                rec.write_event({
+                    "kind": "anomaly", "anomaly": "engine_stall", "t": now,
+                    "step": self._step_id, "value": round(wall_s, 6),
+                    "message": (
+                        f"engine iteration {self._step_id} took "
+                        f"{wall_s:.3f}s, {wall_s / median:.0f}x the "
+                        f"median of the last {len(recent)}"),
+                    "median_s": round(median, 6),
+                    "log_prev_s": round(self._log_prev_s, 6),
+                    "spans": [tree],
+                })
+        recent.append(wall_s)
+
+    def _log_step(self, now: float, walls: dict[str, float],
                   admitted: list[GenRequest], chunks: int, occupancy: int,
                   tokens: int, drafted: int, accepted: int,
                   blocks_billed: float) -> None:
         """One structured record for the iteration that just ran: phase
-        mix, occupancy, per-phase token deltas, and the wall split, read
-        off the iteration's span tree: the ``engine.admit`` /
-        ``engine.prefill`` / ``engine.decode`` durations and ``step_s``,
-        the wall from the start of ``engine.step`` to the start of
-        ``engine.log`` (host wall, all of them: device time per phase is
-        what a profiler trace holding these spans gives).
+        mix, occupancy, per-phase token deltas, the lines the stream
+        threads wrote since the previous record, and ``walls``, the
+        seconds :meth:`_iteration_walls` read off the iteration's span
+        tree, rounded (host wall and the engine thread's CPU, all of
+        them: device time per phase is what a profiler trace holding these
+        spans gives).
         ``blocks_billed`` is the pool's refcount-weighted block census at
         ``now`` (the usage ledger's conservation reference); admissions
         are additionally broken down by tenant."""
@@ -842,6 +1021,10 @@ class Engine:
             phases.append("prefill")
         if occupancy:
             phases.append("decode")
+        lags = self._stream_lags
+        lines, lag_max = len(lags), 0.0
+        if lines:
+            lag_max = round(max(lags.popleft() for _ in range(lines)), 6)
         self._step_id += 1
         rec = {
             "t": now,
@@ -860,10 +1043,9 @@ class Engine:
             "spec_accepted": accepted,
             "device_sampled": self._step_sampled[0],
             "logits_fetched": self._step_sampled[1],
-            "admit_s": round(admit_s, 6),
-            "prefill_s": round(prefill_s, 6),
-            "decode_s": round(decode_s, 6),
-            "step_s": round(step_s, 6),
+            **walls,
+            "stream_lines": lines,
+            "stream_lag_max_s": lag_max,
             "kv_blocks_billed": round(blocks_billed, 4),
         }
         rec.update(self._group_step_fields(occupancy))
@@ -880,8 +1062,23 @@ class Engine:
             self._step_ring.append(rec)
             if self._step_log is None:
                 return
-            self._step_log.write(json.dumps(json_sanitize(rec)) + "\n")
+            try:
+                # every number of a record is finite but for a fault:
+                # json_sanitize's walk (a Python call a field, each one a
+                # profiler event in a traced run) is for that case only
+                line = _encode_finite(rec)
+            except ValueError:
+                line = json.dumps(json_sanitize(rec))
+            self._step_log.write(line + "\n")
             self._step_log.flush()
+
+    def note_stream_line(self, stamp: float) -> None:
+        """A stream thread wrote the line of the tokens committed at
+        ``stamp`` (``time.time()``) to its socket: the lag goes to
+        ``serve_stream_lag_seconds`` and to the next step record."""
+        lag = max(time.time() - stamp, 0.0)
+        self._m_stream_lag.observe(lag)
+        self._stream_lags.append(lag)
 
     def _group_step_fields(self, occupancy: int) -> dict:
         """Step-log fields of the layer groups and the expert layers:
@@ -1062,34 +1259,41 @@ class Engine:
         slot = req.slot
         c = self.prefill_chunk
         start = req._fill_next
-        t_chunk0 = time.time()
-        # everything since this request's attribution frontier was spent
-        # on OTHER requests' work (their chunks, decode steps, admit
-        # scans) — interference stall, not its own prefill compute
-        req.attr_stall_s += max(t_chunk0 - req._t_attr, 0.0)
-        with obs_tracing.span("engine.prefill_chunk"):
-            real = self._chunk_real_tokens(len(req.prompt), start)
-            self.kv.prepare_write(slot, start + c)
-            last_logits, pools = self.programs.prefill(
-                self.params, self.kv.pools(),
-                req._fill_buf[start:start + c], start,
-                {name: jnp.asarray(g.block_tables[slot].copy())
-                 for name, g in self.kv.groups.items()},
-                real,
-            )
-            self.kv.set_pools(pools)
-            if self.kv.latent_layers:
-                self._step_latent[0] += start + c
-            if self.kv.state is not None:
-                self._step_scan += real
-            req._fill_next = start + c
-            self.kv.note_written(
-                slot, max(min(start + c, len(req.prompt)),
-                          int(self.kv.seq_lens[slot]))
-            )
-        t_chunk1 = time.time()
-        req.attr_prefill_s += max(t_chunk1 - t_chunk0, 0.0)
-        req._t_attr = t_chunk1
+        # the leaf before this one (another chunk, a first token,
+        # engine.admit) ends here, with whatever of the budget loop
+        # followed it
+        chunk = self._tiles.to("engine.prefill", "engine.prefill_chunk")
+        # Since this request's attribution frontier (the start of its
+        # last chunk, or its admission): its own chunk's wall, read off
+        # that chunk's span, was prefill compute; the rest was spent on
+        # OTHER requests' work (their chunks, decode steps, admit scans)
+        # — interference stall.
+        t_chunk = time.time()
+        interval = max(t_chunk - req._t_attr, 0.0)
+        own = min(req._s_chunk.dur_s, interval) if req._s_chunk else 0.0
+        req.attr_prefill_s += own
+        req.attr_stall_s += interval - own
+        req._t_attr = t_chunk
+        req._s_chunk = chunk
+        real = self._chunk_real_tokens(len(req.prompt), start)
+        self.kv.prepare_write(slot, start + c)
+        last_logits, pools = self.programs.prefill(
+            self.params, self.kv.pools(),
+            req._fill_buf[start:start + c], start,
+            {name: jnp.asarray(g.block_tables[slot].copy())
+             for name, g in self.kv.groups.items()},
+            real,
+        )
+        self.kv.set_pools(pools)
+        if self.kv.latent_layers:
+            self._step_latent[0] += start + c
+        if self.kv.state is not None:
+            self._step_scan += real
+        req._fill_next = start + c
+        self.kv.note_written(
+            slot, max(min(start + c, len(req.prompt)),
+                      int(self.kv.seq_lens[slot]))
+        )
         return last_logits
 
     def _finish_prefill(self, req: GenRequest, last_logits) -> None:
@@ -1101,21 +1305,25 @@ class Engine:
         req._prefill_done = True
         self._slot_meta_dirty = True
         # the first-token sample blocks on the last chunk's logits: the
-        # wait for the device and the host sampling are one span
-        with obs_tracing.span("engine.first_token"):
-            if self.fused_sampling:
-                # The prefill program hands logits to the host anyway
-                # (its last chunk); sampling them with the device
-                # sampler's exact math + key schedule (emitted index 0)
-                # keeps the request on ONE sampling stream across the
-                # host/device boundary.
-                tok = sampling.sample_one(
-                    np.asarray(last_logits), jax.random.PRNGKey(req.seed),
-                    0, req.temperature, req.top_k,
-                )
-                self._dev_tokens = self._dev_tokens.at[req.slot, 0].set(tok)
-            else:
-                tok = self._sample(req, np.asarray(last_logits))
+        # wait for the device and the host sampling are one span (the
+        # request's bookkeeping and its stream's line below stay in it),
+        # and the thread's CPU inside the wait is set aside (offcpu_s)
+        self._tiles.to("engine.prefill", "engine.first_token")
+        cpu0 = time.thread_time()
+        if self.fused_sampling:
+            # The prefill program hands logits to the host anyway
+            # (its last chunk); sampling them with the device
+            # sampler's exact math + key schedule (emitted index 0)
+            # keeps the request on ONE sampling stream across the
+            # host/device boundary.
+            tok = sampling.sample_one(
+                np.asarray(last_logits), jax.random.PRNGKey(req.seed),
+                0, req.temperature, req.top_k,
+            )
+            self._dev_tokens = self._dev_tokens.at[req.slot, 0].set(tok)
+        else:
+            tok = self._sample(req, np.asarray(last_logits))
+        self._cpu_blocked += time.thread_time() - cpu0
         req.t_first_token = time.time()
         req._t_last_token = req.t_first_token
         # ... and the tail of this request's prefill compute in the
@@ -1126,7 +1334,7 @@ class Engine:
         self.usage.on_tokens({req.tenant: 1})
         self._last_tokens[req.slot] = tok
         self._m_ttft.observe(req.ttft_s)
-        self._stream_emit(req, [tok])
+        self._stream_emit(req, [tok], req.t_first_token)
         self._maybe_finish(req)
 
     def _run_decode_step(self, prefill_s: float) -> None:
@@ -1135,12 +1343,16 @@ class Engine:
         slot that samples takes the numpy sampler on its row of the
         logits) or the fused fast path (sampling — and optionally
         speculative verification — inside the compiled program).  Both
-        are three spans: ``engine.decode.dispatch`` (CoW guard, slot
-        meta, table upload, launch), ``engine.decode.fetch`` (the wait
+        are three leaves that tile ``engine.decode``:
+        ``engine.decode.dispatch``, open since ``engine.decode`` began
+        (the batch's slots, CoW guard, slot meta, table upload, launch),
+        ``engine.decode.fetch`` (the wait
         for the device, and what the host needs of the result: a token a
         slot, and the logits only if a live request samples) and
         ``engine.decode.commit`` (host sampling where asked for, one pass
-        of bookkeeping over the batch, then the streams' lines).
+        of bookkeeping over the batch, then the streams' lines; it ends
+        where ``engine.decode`` ends, so whatever the engine thread waits
+        for after waking the stream threads is inside it).
         ``prefill_s`` is this iteration's ``engine.prefill`` wall, for the
         attribution split."""
         decoding = [
@@ -1152,47 +1364,65 @@ class Engine:
         if self.fused_sampling:
             self._decode_step_fused(decoding, slots, prefill_s)
             return
-        with obs_tracing.span("engine.decode.dispatch") as s_dispatch:
-            for i, _ in decoding:
-                # CoW guard: never write a shared or indexed block in
-                # place.  Steady state this is a no-op (appends land past
-                # the shared prompt blocks) — it is what makes a future
-                # scheduler bug a local copy instead of cross-request
-                # cache corruption.
-                self.kv.ensure_writable(i, int(self.kv.seq_lens[i]))
-            self._refresh_slot_meta()
-            for i, _ in decoding:
-                self.kv.prepare_write(i, int(self.kv.seq_lens[i]) + 1)
-            logits, greedy, pools, self._routed = self.programs.decode(
-                self.params, self.kv.pools(),
-                jnp.asarray(self._last_tokens), self._tables_dev(),
-                jnp.asarray(self.kv.seq_lens), self._dev_active,
-            )
-            self.kv.set_pools(pools)
+        for i, _ in decoding:
+            # CoW guard: never write a shared or indexed block in
+            # place.  Steady state this is a no-op (appends land past
+            # the shared prompt blocks) — it is what makes a future
+            # scheduler bug a local copy instead of cross-request
+            # cache corruption.
+            self.kv.ensure_writable(i, int(self.kv.seq_lens[i]))
+        self._refresh_slot_meta()
+        for i, _ in decoding:
+            self.kv.prepare_write(i, int(self.kv.seq_lens[i]) + 1)
+        logits, greedy, pools, self._routed = self.programs.decode(
+            self.params, self.kv.pools(),
+            jnp.asarray(self._last_tokens), self._tables_dev(),
+            jnp.asarray(self.kv.seq_lens), self._dev_active,
+        )
+        self.kv.set_pools(pools)
         # what the engine sees in its input decides what it fetches: the
         # logits (slots x vocabulary floats) stay on the device unless a
         # live request samples from them
         sampling = [(j, r) for j, (_, r) in enumerate(decoding)
                     if r.temperature > 0.0]
-        with obs_tracing.span("engine.decode.fetch") as s_fetch:
-            tokens = np.asarray(greedy)[slots]
-            if sampling:
-                logits = np.asarray(logits)
-        now = time.time()
-        decode_dt = s_dispatch.dur_s + s_fetch.dur_s
-        with obs_tracing.span("engine.decode.commit"):
-            for j, req in sampling:
-                tokens[j] = self._sample(req, logits[req.slot])
-            self._note_sampled(n_active - len(sampling), bool(sampling))
-            self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
-            if self.kv.latent_layers:
-                self._step_latent[1] = self.kv.latent_layers * int(
-                    self.kv.seq_lens[slots].sum())
-            if self.kv.state is not None:
-                self._step_scan += n_active
-            self._commit_tokens(
-                decoding, slots, [[t] for t in tokens.tolist()], now,
-                decode_dt, prefill_s, spec=False)
+        self._to_fetch()
+        tokens = np.asarray(greedy)[slots]
+        if sampling:
+            logits = np.asarray(logits)
+        now, decode_dt = self._to_commit()
+        for j, req in sampling:
+            tokens[j] = self._sample(req, logits[req.slot])
+        self._note_sampled(n_active - len(sampling), bool(sampling))
+        self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
+        if self.kv.latent_layers:
+            self._step_latent[1] = self.kv.latent_layers * int(
+                self.kv.seq_lens[slots].sum())
+        if self.kv.state is not None:
+            self._step_scan += n_active
+        self._commit_tokens(
+            decoding, slots, [[t] for t in tokens.tolist()], now,
+            decode_dt, prefill_s, spec=False)
+
+    def _to_fetch(self) -> None:
+        """``engine.decode.dispatch`` ends and ``.fetch`` begins: the
+        engine thread is about to wait for the device, so its CPU clock is
+        read beside the span's wall (``_to_commit`` reads it again)."""
+        self._tiles.to("engine.decode", "engine.decode.fetch")
+        self._cpu_leaf0 = time.thread_time()
+
+    def _to_commit(self) -> tuple[float, float]:
+        """``.fetch`` ends and ``.commit`` begins, to last until
+        ``engine.decode`` ends.  Returns the commit's stamp
+        (``time.time()``: the requests' token times, and what a stream
+        thread measures its line's lag from) and the wall of dispatch and
+        fetch together, this iteration's share of a request's decode
+        attribution."""
+        tiles = self._tiles
+        commit = tiles.to("engine.decode", "engine.decode.commit")
+        cpu = time.thread_time()
+        self._cpu_blocked += cpu - self._cpu_leaf0
+        self._cpu_leaf0 = cpu
+        return time.time(), commit.t0 - tiles.parent.t0
 
     def _commit_tokens(self, decoding, slots: np.ndarray,
                        kept: list[list[int]], now: float, decode_dt: float,
@@ -1266,7 +1496,7 @@ class Engine:
         for n, requests in by_count.items():
             self._m_tok_step.observe(float(n), count=requests)
         for (_, req), toks in zip(decoding, kept):
-            self._stream_emit(req, toks)
+            self._stream_emit(req, toks, now)
         for req in finished:
             self._maybe_finish(req)
 
@@ -1284,110 +1514,107 @@ class Engine:
         truncates the request's tokens AND retreats the K/V extent
         (``kv.rollback``), which by construction never crosses a
         shared (refcount > 1) prefix block."""
-        with obs_tracing.span("engine.decode.dispatch") as s_dispatch:
-            drafts: dict[int, list[int]] = {}
-            if self.speculate:
-                for i, r in decoding:
-                    cap = min(self.speculate,
-                              r.max_new_tokens - len(r.tokens) - 1)
-                    if cap > 0:
-                        # min_ngram=2: a single repeated token is mostly
-                        # coincidence on novel text, and every spurious
-                        # proposal pays the T=K+1 verify program for an
-                        # almost-surely-rejected draft — requiring a 2-gram
-                        # match keeps the low-hit-rate regression bounded
-                        # while leaving real repetition (>= 2-gram) intact.
-                        d = spec_draft.propose(
-                            r.prompt + r.tokens, cap,
-                            max_ngram=self.spec_ngram,
-                            min_ngram=min(2, self.spec_ngram),
-                        )
-                        if d:
-                            drafts[i] = d
-            # Program choice is per BATCH: one drafting slot routes every
-            # active slot through the T=K+1 program that iteration (static
-            # shapes — the non-drafting slots' extra positions are pad
-            # writes to scratch, but their forward compute still scales with
-            # T).  The draft-less fallback therefore helps exactly when NO
-            # slot drafts; a mixed batch pays the window for everyone, which
-            # is the right trade only while acceptance is healthy — the
-            # acceptance-rate telemetry is the dial to watch.
-            t_width = self.speculate + 1 if drafts else 1
+        drafts: dict[int, list[int]] = {}
+        if self.speculate:
             for i, r in decoding:
-                s = int(self.kv.seq_lens[i])
-                self.kv.ensure_writable_range(
-                    i, s, s + 1 + len(drafts.get(i, ())))
-            self._refresh_slot_meta()
-            draft_lens = np.zeros((self.max_slots,), np.int32)
-            if t_width > 1:
-                toks = np.zeros((self.max_slots, t_width), np.int32)
-                toks[:, 0] = self._last_tokens
-                for i, d in drafts.items():
-                    toks[i, 1:1 + len(d)] = d
-                    draft_lens[i] = len(d)
-                tokens_in = jnp.asarray(toks)
-                dev_draft_lens = jnp.asarray(draft_lens)
-                fn = self._fused_spec
-            else:
-                tokens_in = self._dev_tokens  # device-resident (B, 1) feed
-                dev_draft_lens = self._dev_zero_drafts
-                fn = self._fused1
-            packed, next_feed, pools = fn(
-                self.params, self.kv.pools(), tokens_in,
-                dev_draft_lens, self._tables_dev(),
-                jnp.asarray(self.kv.seq_lens), self._dev_active,
-                self._dev_keys, self._dev_prompt_lens, self._dev_temp,
-                self._dev_topk,
-            )
-            self.kv.set_pools(pools)
-            self._dev_tokens = next_feed
-        with obs_tracing.span("engine.decode.fetch") as s_fetch:
-            packed = np.asarray(packed)  # the ONE small host fetch per
-        out = packed[:, :-1]             # iteration (EOS / logging):
-        n_emit = packed[:, -1]           # emitted tokens + counts, packed
-        now = time.time()
-        decode_dt = s_dispatch.dur_s + s_fetch.dur_s
-        with obs_tracing.span("engine.decode.commit"):
-            self._note_sampled(len(decoding), False)
-            seq0 = self.kv.seq_lens[slots]
-            # Commit the last input token + every ACCEPTED draft's K/V
-            # (emitted - 1 of them); rejected drafts' K/V sits past this
-            # extent (dead, masked, overwritten by the next append).
-            self.kv.note_written(slots, seq0 + n_emit[slots])
-            bursts = []
-            for (slot, req), s in zip(decoding, seq0.tolist()):
-                n = int(n_emit[slot])
-                emitted = [int(t) for t in out[slot, :n]]
-                k_drafted = int(draft_lens[slot])
-                accepted = n - 1
-                kept = emitted
-                if req.eos_token_id is not None \
-                        and req.eos_token_id in emitted:
-                    kept = emitted[: emitted.index(req.eos_token_id) + 1]
-                    if len(kept) < n:
-                        # tokens after the EOS never happened: retreat the
-                        # K/V extent past the discarded accepted drafts too
-                        self.kv.rollback(slot, s + len(kept))
-                if k_drafted:
-                    # acceptance telemetry counts COMMITTED drafts: an
-                    # accepted draft discarded by the EOS truncation above
-                    # was rolled back as "never happened" and must not
-                    # inflate the acceptance rate.  kept == emitted keeps
-                    # `accepted`; a truncated burst is all-drafts.
-                    committed = accepted if len(kept) == n else len(kept)
-                    req.drafted += k_drafted
-                    req.accepted += committed
-                    self.counters["spec_drafted"] += k_drafted
-                    self.counters["spec_accepted"] += committed
-                    self._m_spec_drafted.inc(k_drafted)
-                    if committed:
-                        self._m_spec_accepted.inc(committed)
-                bursts.append(kept)
-            # a T=K+1 (verify) dispatch charges the speculation
-            # component for EVERY active slot — a mixed batch pays the
-            # window for everyone, and the attribution should say so
-            self._commit_tokens(decoding, slots, bursts, now, decode_dt,
-                                prefill_s, spec=t_width > 1)
+                cap = min(self.speculate,
+                          r.max_new_tokens - len(r.tokens) - 1)
+                if cap > 0:
+                    # min_ngram=2: a single repeated token is mostly
+                    # coincidence on novel text, and every spurious
+                    # proposal pays the T=K+1 verify program for an
+                    # almost-surely-rejected draft — requiring a 2-gram
+                    # match keeps the low-hit-rate regression bounded
+                    # while leaving real repetition (>= 2-gram) intact.
+                    d = spec_draft.propose(
+                        r.prompt + r.tokens, cap,
+                        max_ngram=self.spec_ngram,
+                        min_ngram=min(2, self.spec_ngram),
+                    )
+                    if d:
+                        drafts[i] = d
+        # Program choice is per BATCH: one drafting slot routes every
+        # active slot through the T=K+1 program that iteration (static
+        # shapes — the non-drafting slots' extra positions are pad
+        # writes to scratch, but their forward compute still scales with
+        # T).  The draft-less fallback therefore helps exactly when NO
+        # slot drafts; a mixed batch pays the window for everyone, which
+        # is the right trade only while acceptance is healthy — the
+        # acceptance-rate telemetry is the dial to watch.
+        t_width = self.speculate + 1 if drafts else 1
+        for i, r in decoding:
+            s = int(self.kv.seq_lens[i])
+            self.kv.ensure_writable_range(
+                i, s, s + 1 + len(drafts.get(i, ())))
+        self._refresh_slot_meta()
+        draft_lens = np.zeros((self.max_slots,), np.int32)
+        if t_width > 1:
+            toks = np.zeros((self.max_slots, t_width), np.int32)
+            toks[:, 0] = self._last_tokens
+            for i, d in drafts.items():
+                toks[i, 1:1 + len(d)] = d
+                draft_lens[i] = len(d)
+            tokens_in = jnp.asarray(toks)
+            dev_draft_lens = jnp.asarray(draft_lens)
+            fn = self._fused_spec
+        else:
+            tokens_in = self._dev_tokens  # device-resident (B, 1) feed
+            dev_draft_lens = self._dev_zero_drafts
+            fn = self._fused1
+        packed, next_feed, pools = fn(
+            self.params, self.kv.pools(), tokens_in,
+            dev_draft_lens, self._tables_dev(),
+            jnp.asarray(self.kv.seq_lens), self._dev_active,
+            self._dev_keys, self._dev_prompt_lens, self._dev_temp,
+            self._dev_topk,
+        )
+        self.kv.set_pools(pools)
+        self._dev_tokens = next_feed
+        self._to_fetch()
+        packed = np.asarray(packed)  # the ONE small host fetch per
+        out = packed[:, :-1]         # iteration (EOS / logging):
+        n_emit = packed[:, -1]       # emitted tokens + counts, packed
+        now, decode_dt = self._to_commit()
+        self._note_sampled(len(decoding), False)
+        seq0 = self.kv.seq_lens[slots]
+        # Commit the last input token + every ACCEPTED draft's K/V
+        # (emitted - 1 of them); rejected drafts' K/V sits past this
+        # extent (dead, masked, overwritten by the next append).
+        self.kv.note_written(slots, seq0 + n_emit[slots])
+        bursts = []
+        for (slot, req), s in zip(decoding, seq0.tolist()):
+            n = int(n_emit[slot])
+            emitted = [int(t) for t in out[slot, :n]]
+            k_drafted = int(draft_lens[slot])
+            accepted = n - 1
+            kept = emitted
+            if req.eos_token_id is not None \
+                    and req.eos_token_id in emitted:
+                kept = emitted[: emitted.index(req.eos_token_id) + 1]
+                if len(kept) < n:
+                    # tokens after the EOS never happened: retreat the
+                    # K/V extent past the discarded accepted drafts too
+                    self.kv.rollback(slot, s + len(kept))
+            if k_drafted:
+                # acceptance telemetry counts COMMITTED drafts: an
+                # accepted draft discarded by the EOS truncation above
+                # was rolled back as "never happened" and must not
+                # inflate the acceptance rate.  kept == emitted keeps
+                # `accepted`; a truncated burst is all-drafts.
+                committed = accepted if len(kept) == n else len(kept)
+                req.drafted += k_drafted
+                req.accepted += committed
+                self.counters["spec_drafted"] += k_drafted
+                self.counters["spec_accepted"] += committed
+                self._m_spec_drafted.inc(k_drafted)
+                if committed:
+                    self._m_spec_accepted.inc(committed)
+            bursts.append(kept)
+        # a T=K+1 (verify) dispatch charges the speculation
+        # component for EVERY active slot — a mixed batch pays the
+        # window for everyone, and the attribution should say so
+        self._commit_tokens(decoding, slots, bursts, now, decode_dt,
+                            prefill_s, spec=t_width > 1)
 
     def _note_sampled(self, device_sampled: int, logits_fetched: bool) -> None:
         """This decode iteration's ``device_sampled`` / ``logits_fetched``
@@ -1416,11 +1643,13 @@ class Engine:
         ).astype(np.float64)  # np.random requires probs summing to 1 in f64
         return int(req._rng.choice(len(probs), p=probs / probs.sum()))
 
-    def _stream_emit(self, req: GenRequest, toks: list[int]) -> None:
+    def _stream_emit(self, req: GenRequest, toks: list[int],
+                     stamp: float) -> None:
         """Push newly committed tokens to a streaming request's event
-        queue (no-op for blocking requests)."""
+        queue (no-op for blocking requests), with the ``time.time()`` of
+        their commit."""
         if req._events is not None and toks:
-            req._events.put(("tokens", toks))
+            req._events.put(("tokens", toks, stamp))
 
     def _maybe_finish(self, req: GenRequest) -> None:
         last = req.tokens[-1]
@@ -1544,24 +1773,37 @@ class Engine:
         return self._crashed is None and not self._stopped
 
     def _run(self) -> None:
+        """The engine thread: ``engine.step`` and, around it,
+        ``engine.loop`` — what the loop does between two iterations (the
+        capture engine's window by iteration, the scheduler lock, the
+        stop flag) with the idle ``Condition.wait`` inside it as
+        ``engine.wait`` — so the roots leave nothing of the thread's life
+        unnamed."""
         cap = self.capture
+        span = obs_tracing.span
+        if cap is not None:
+            cap.maybe_start(self._step_id)
         while True:
             try:
-                if cap is not None:
-                    cap.maybe_start(self._step_id)
                 did = self.step()
-                if cap is not None:
-                    cap.maybe_stop(self._step_id)
+                with span("engine.loop"):
+                    if cap is not None:
+                        cap.maybe_stop(self._step_id)
+                    with self._cond:
+                        if self._stop_flag:
+                            return
+                        if not did and not self._queue:
+                            with span("engine.wait") as s_wait:
+                                cpu0 = time.thread_time()
+                                self._cond.wait(timeout=0.05)
+                                self._cpu_blocked += time.thread_time() - cpu0
+                            self._wait_s += s_wait.dur_s
+                    if cap is not None:
+                        cap.maybe_start(self._step_id)
             except Exception as e:  # noqa: BLE001 — fail every in-flight req
                 self._crashed = repr(e)
                 self._fail_all(f"engine loop error: {e!r}")
                 raise
-            with self._cond:
-                if self._stop_flag:
-                    return
-                if not did and not self._queue:
-                    with obs_tracing.span("engine.wait"):
-                        self._cond.wait(timeout=0.05)
 
     def stop(self, drain: bool = True, timeout: float = 60.0) -> None:
         """Stop the loop.  ``drain=True`` (default) finishes in-flight and

@@ -299,17 +299,20 @@ class ServeServer:
             while True:
                 remaining = deadline - time.monotonic()
                 try:
-                    kind, payload = req._events.get(
-                        timeout=max(remaining, 0.0))
+                    event = req._events.get(timeout=max(remaining, 0.0))
                 except queue_mod.Empty:
                     yield json.dumps({
                         "done": True, "status": "timeout", "id": req.id,
                         "error": f"generation exceeded timeout_s={timeout}",
                     }) + "\n"
                     return
-                if kind != "tokens":
+                if event[0] != "tokens":
                     break
-                yield json.dumps({"tokens": payload}) + "\n"
+                _, tokens, stamp = event
+                yield json.dumps({"tokens": tokens}) + "\n"
+                # resumed once the line is on the socket
+                # (obs.server._reply_chunked writes what is yielded)
+                self.engine.note_stream_line(stamp)
             if req.status == "ok":
                 trailer = {"done": True, "status": "ok", **self._ok_stats(req)}
                 del trailer["tokens"]  # already streamed line by line

@@ -266,7 +266,7 @@ def _check_commits(eng):
                 # this iteration's one line, then (finished) the closing one
                 new = [req._events.get_nowait()
                        for _ in range(req._events.qsize())]
-                assert new[0] == ("tokens", list(toks))
+                assert new[0] == ("tokens", list(toks), now)  # commit's stamp
                 assert new[1:] == ([("done", None)] if finished else [])
         finishes.append(done)
 

@@ -196,3 +196,171 @@ def test_same_seed_same_bits_across_shardings(dp_mesh):
         )
     finally:
         jax.config.update("jax_threefry_partitionable", prior)
+
+
+# --- spans that tile their parent, and ids without a system call (ISSUE 36) --
+
+
+def _tree(span):
+    return (span.name, [_tree(c) for c in span.children])
+
+
+def test_tiled_leaves_share_their_boundaries():
+    """One clock read closes a leaf and opens the next: a leaf begins
+    where its sibling ended, the first with its parents, the last ends
+    with them."""
+    from distributedtensorflow_tpu.obs import tracing
+
+    with tracing.tiled("it", "admit", step=7) as t:
+        assert t.root.name == "it" and t.root.t0 > 0
+        chunk = t.to("prefill", "chunk")
+        assert t.parent.name == "prefill"
+        first = t.to("prefill", "first")
+        chunk2 = t.to("prefill", "chunk")
+        dispatch = t.to("decode", "decode.dispatch")
+        fetch = t.to("decode", "decode.fetch")
+        log = t.to("log")
+    root = t.root
+    assert _tree(root) == ("it", [
+        ("admit", []),
+        ("prefill", [("chunk", []), ("first", []), ("chunk", [])]),
+        ("decode", [("decode.dispatch", []), ("decode.fetch", [])]),
+        ("log", [])])
+    admit, prefill, decode, _ = root.children
+    assert admit.t0 == root.t0                      # no clock read between
+    assert prefill.t0 == chunk.t0 == admit.t0 + admit.dur_s
+    assert first.t0 == chunk.t0 + chunk.dur_s
+    assert chunk2.t0 == first.t0 + first.dur_s
+    assert decode.t0 == dispatch.t0 == prefill.t0 + prefill.dur_s
+    assert fetch.t0 + fetch.dur_s == decode.t0 + decode.dur_s == log.t0
+    assert log.t0 + log.dur_s == pytest.approx(root.t0 + root.dur_s, abs=1e-12)
+    for parent in (root, prefill, decode):
+        assert sum(c.dur_s for c in parent.children) == pytest.approx(
+            parent.dur_s, abs=1e-9)
+
+
+@pytest.mark.parametrize("case", ["sink", "recorder", "nested", "plain_inside",
+                                  "exception", "clock_reads", "annotations"])
+def test_tiled_is_a_span_to_everything_that_reads_spans(case, monkeypatch):
+    from distributedtensorflow_tpu.obs import tracing
+
+    def run():
+        with tracing.tiled("root", "a", step=1) as t:
+            t.to("b", "b1")
+            t.to("b", "b2")
+            t.to("c")
+        return t.root
+
+    want = ("root", [("a", []), ("b", [("b1", []), ("b2", [])]), ("c", [])])
+    if case == "sink":
+        got = []
+        tracing.add_root_sink(got.append)
+        try:
+            root = run()
+        finally:
+            tracing.remove_root_sink(got.append)
+        assert got == [root] and _tree(root) == want
+    elif case == "recorder":
+        with tracing.TraceRecorder() as rec:
+            rec.begin_step(1)
+            root = run()
+            assert rec._roots == [root]
+            assert rec.drain_window() == {"root": root.dur_s}
+    elif case == "nested":
+        with tracing.span("outer") as outer:
+            root = run()
+        assert _tree(outer) == ("outer", [want])
+    elif case == "plain_inside":
+        with tracing.tiled("root", "a") as t:
+            with tracing.span("inner"):
+                pass
+            t.to("b")
+        assert _tree(t.root) == ("root", [("a", [("inner", [])]), ("b", [])])
+    elif case == "exception":
+        with pytest.raises(StopIteration):
+            with tracing.tiled("root", "a") as t:
+                t.to("b", "b1")
+                raise StopIteration
+        assert _tree(t.root) == ("root", [("a", []), ("b", [("b1", [])])])
+        assert tracing._tls.stack == []
+        assert t.root.dur_s >= t.root.children[-1].dur_s > 0
+    elif case == "clock_reads":
+        reads = []
+        clock = time.perf_counter
+        monkeypatch.setattr(time, "perf_counter",
+                            lambda: reads.append(1) or clock())
+        run()
+        monkeypatch.undo()
+        assert len(reads) == 5      # enter, three `to`, exit
+    else:
+        entered = []
+
+        class Ann:
+            def __init__(self, name, **attrs):
+                self.name, self.attrs = name, attrs
+
+            def __enter__(self):
+                entered.append(("enter", self.name, self.attrs))
+
+            def __exit__(self, *exc):
+                entered.append(("exit", self.name, self.attrs))
+
+        monkeypatch.setattr(tracing, "_TraceAnnotation", Ann)
+        run()
+        assert [(e, n) for e, n, _ in entered] == [
+            ("enter", "root"), ("enter", "a"),
+            ("exit", "a"), ("enter", "b"), ("enter", "b1"),
+            ("exit", "b1"), ("enter", "b2"),
+            ("exit", "b2"), ("exit", "b"), ("enter", "c"),
+            ("exit", "c"), ("exit", "root")]
+        assert entered[0][2] == {"step": 1} and entered[1][2] == {}
+
+
+def test_span_ids_are_unique_and_take_no_system_call(monkeypatch):
+    from distributedtensorflow_tpu.obs import tracing
+
+    monkeypatch.setattr(os, "urandom", None)    # a call would raise
+    ids = {tracing.new_span_id() for _ in range(100_000)}
+    ids |= {tracing.new_trace_id() for _ in range(1000)}
+    assert len(ids) == 101_000
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    assert len({i[:8] for i in ids}) == 1       # the process's prefix
+    import inspect
+
+    assert "uuid" not in inspect.getsource(tracing).replace("uuid4", "")
+
+
+@pytest.mark.parametrize("how", ["spawn", "fork"])
+def test_two_processes_draw_different_id_prefixes(how):
+    """A process draws its prefix when it first imports the tracer, a
+    forked child again (``os.register_at_fork``).  The fork is made in a
+    fresh interpreter that has not loaded JAX: a forked copy of this
+    process, with JAX's threads, could deadlock."""
+    import subprocess
+    import sys
+
+    script = {
+        "spawn": "print(tracing.new_span_id())",
+        "fork": ("import os\n"
+                 "mine = tracing.new_span_id()\n"
+                 "if os.fork() == 0:\n"
+                 "    print(tracing.new_span_id(), flush=True)\n"
+                 "    os._exit(0)\n"
+                 "os.wait()\n"
+                 "print(mine)"),
+    }[how]
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from distributedtensorflow_tpu.obs import tracing\n" + script],
+        capture_output=True, text=True, check=True, timeout=120,
+        cwd=os.path.join(os.path.dirname(__file__), ".."),
+    ).stdout.split()
+    ids = out + [tracing_id()]
+    assert all(len(i) == 16 for i in ids) and len(ids) == len(out) + 1
+    assert len({i[:8] for i in ids}) == len(ids)
+
+
+def tracing_id():
+    from distributedtensorflow_tpu.obs import tracing
+
+    return tracing.new_span_id()

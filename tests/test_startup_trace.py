@@ -43,7 +43,22 @@ def assert_tiles(rows, phases, max_unnamed_s=0.05):
     return top
 
 
-def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch):
+@pytest.fixture
+def own_registry():
+    """A process-default metrics registry of the test's own: ``train.main()``
+    writes the default one's snapshot into ``metrics.jsonl``, and another
+    test of the same xdist worker may have left a series there that the
+    schema checker (rightly) refuses in a trainer's rows
+    (``breaker_state.endpoint_127_0_0_1:<port>``, CHANGES.md PR 27)."""
+    from distributedtensorflow_tpu.obs import registry
+
+    prev = registry.set_default_registry(registry.Registry())
+    yield
+    registry.set_default_registry(prev)
+
+
+def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
+                                                own_registry):
     import train
 
     logdir = tmp_path / "run"

@@ -339,6 +339,15 @@ STEP_COUNT_FIELDS = (
 STEP_WALL_FIELDS = (
     "admit_s", "prefill_s", "decode_s", "step_s",
 )
+#: The iteration's leaves and the engine thread's account (ISSUE 36;
+#: validated where present, so older logs stay green): seconds, all of
+#: them — ``dispatch_s + fetch_s + commit_s <= decode_s`` and the leaves
+#: leave ``unnamed_s <= 2 %`` of ``step_s`` (up to rounding).
+STEP_LEAF_FIELDS = (
+    "dispatch_s", "fetch_s", "commit_s", "first_token_s", "log_prev_s",
+    "between_s", "wait_s", "offcpu_s", "commit_cpu_s", "gc_s", "unnamed_s",
+    "stream_lag_max_s",
+)
 
 #: Per-tenant usage ledger schema (obs/usage.py ``UsageMeter``, ISSUE 19
 #: — duplicated, stdlib-only).  Tenant identities are identifier-style;
@@ -1320,7 +1329,9 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
     in {0, 1}),
     and non-negative finite wall fields whose phase split tiles the
     iteration: ``admit_s + prefill_s + decode_s <= step_s`` (up to
-    rounding)."""
+    rounding); where present, the leaves and the engine thread's account
+    (:data:`STEP_LEAF_FIELDS`), with ``dispatch_s + fetch_s + commit_s <=
+    decode_s`` and ``unnamed_s`` within 2 % of ``step_s``."""
     errors: list[str] = []
     warnings: list[str] = []
     prev_t: float | None = None
@@ -1405,6 +1416,34 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
                         f"{parts:.6f} exceeds step_s "
                         f"{walls['step_s']:.6f}"
                     )
+            for name in STEP_LEAF_FIELDS:
+                v = row.get(name)
+                if v is None:
+                    continue
+                if not _nonneg_finite(v):
+                    errors.append(f"line {i}: {name!r} {v!r} is not a "
+                                  "non-negative finite number")
+                else:
+                    walls[name] = float(v)
+            if all(k in walls for k in ("dispatch_s", "fetch_s", "commit_s",
+                                        "decode_s")):
+                parts = (walls["dispatch_s"] + walls["fetch_s"]
+                         + walls["commit_s"])
+                if parts > walls["decode_s"] + 1e-5:
+                    errors.append(
+                        f"line {i}: dispatch_s+fetch_s+commit_s "
+                        f"{parts:.6f} exceeds decode_s "
+                        f"{walls['decode_s']:.6f}")
+            if "unnamed_s" in walls and "step_s" in walls \
+                    and walls["unnamed_s"] > 0.02 * walls["step_s"] + 1e-4:
+                errors.append(
+                    f"line {i}: unnamed_s {walls['unnamed_s']:.6f} is more "
+                    f"than 2 % of step_s {walls['step_s']:.6f}: the "
+                    "iteration's leaves do not tile it")
+            lines = row.get("stream_lines")
+            if lines is not None and not _nonneg_int(lines):
+                errors.append(f"line {i}: 'stream_lines' {lines!r} is not "
+                              "a non-negative integer")
             # per-tenant usage accounting (ISSUE 19; validated when
             # present so pre-ISSUE-19 logs stay green): the pool's
             # refcount-weighted block census at the iteration boundary,

@@ -2,22 +2,38 @@
 """The untraced split of a decode iteration on the engine thread.
 
     python tools/engine_iter_split.py [ROOT] [--streams] [--profile]
-        [--config NAME] [--slots N] [--tokens N] [--sample N]
+        [--host-only] [--iterations N] [--config NAME] [--slots N]
+        [--tokens N] [--sample N]
 
 The profiler's Python tracer slows exactly the host code between two
 launches of ``jit_decode`` (PERF.md §5), so the traced ``decode_span_host_ms``
 is a direction, not a size.  This takes the size: ``--slots`` requests
 (prompt ``--prompt``) decode together in an in-process ``Engine``, no HTTP,
-and the engine's own spans are summed by name (``Span.dur_s``, read in
-``span.__exit__``: dispatch / fetch / commit / log), beside the time inside
-``_stream_emit``.  With ``--streams`` every request is a stream with a
+and the means of the step ring's seconds are printed (``step_ms``: the
+leaves of the iteration, the engine thread's CPU beside its wall, the
+streams' lag, as ``steps.jsonl`` has them; a tree from before ISSUE 36
+has four of them).  With ``--streams`` every request is a stream with a
 consumer thread doing what ``serve/server.py``'s generator does (``get``,
-``json.dumps``, a write): the interpreter lock's part shows as the
-difference.  ``--sample N`` gives N of the requests ``temperature`` 0.8 (the
+``json.dumps``, a write, the lag): the interpreter lock's part shows as
+the difference, in ``iter_ms`` and in ``offcpu_s``.  ``--sample N`` gives N
+of the requests ``temperature`` 0.8 (the
 iteration then fetches the logits).  ``--profile`` runs the engine thread
 under ``cProfile`` — inflated like a traced run: read the order, not the
 sizes.  ROOT (default: this checkout) is the tree to import the program
 from, so a parent commit unpacked beside it is measured by the same script.
+``--host-only`` hands the engine its decode program's last result in place
+of a launch, so that an iteration is the engine thread's own work and
+nothing else (the requests never end, so ``--iterations`` may pass
+``--tokens``).  Two processes on this box differ by a tenth from one run to
+the next, more than a change to the bookkeeping costs, so ``--beside ROOT2``
+(implies ``--host-only``) takes that cost in ONE process: ROOT2's
+``serve/engine.py`` is loaded as a second module of ROOT's package (same
+tracer, same cache, same everything else), one engine is built from each
+with a log directory of its own (``steps.jsonl`` is written, as under
+``serve.py``),
+and ``--pairs`` stretches of ``--iterations`` alternate; the row is the
+paired difference of the engine thread's CPU time an iteration (ISSUE 36's
+budget of 30 us is read this way).
 Defaults are the trinity cell's; a tiny one runs on the CPU:
 ``--config afmoe_tiny --slots 4 --prompt 20 --tokens 90 --block 4 --chunk 8
 --context 128 --kv-blocks 0 --kv-window-blocks 0``.  Prints one JSON row
@@ -27,17 +43,60 @@ Defaults are the trinity cell's; a tiny one runs on the CPU:
 from __future__ import annotations
 
 import argparse
-import collections
 import cProfile
+import gc
+import importlib.util
 import io
 import json
 import os
 import pstats
+import statistics
 import sys
+import tempfile
 import threading
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def beside(args, build, engine_mod) -> int:
+    """``--beside``: ROOT's engine and ROOT2's in this process, alternating
+    stretches; the paired difference of thread CPU an iteration."""
+    path = os.path.join(os.path.abspath(args.beside),
+                        "distributedtensorflow_tpu", "serve", "engine.py")
+    spec = importlib.util.spec_from_file_location(
+        "distributedtensorflow_tpu.serve._engine_beside", path)
+    other = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = other
+    spec.loader.exec_module(other)
+    engines = {args.root: build(engine_mod), args.beside: build(other)}
+    gc.collect()
+    gc.freeze()
+    n = args.iterations or 300
+
+    def stretch(eng):
+        cpu0 = time.thread_time()
+        for _ in range(n):
+            eng.step()
+        return 1e6 * (time.thread_time() - cpu0) / n
+
+    for eng in engines.values():
+        stretch(eng)
+    us = {root: [] for root in engines}
+    for _ in range(args.pairs):
+        for root, eng in engines.items():
+            us[root].append(stretch(eng))
+    diff = [a - b for a, b in zip(us[args.root], us[args.beside])]
+    print(json.dumps({
+        "root": args.root, "beside": args.beside, "pairs": args.pairs,
+        "iterations_a_stretch": n, "slots": args.slots,
+        "iter_cpu_us": {root: {"median": statistics.median(v),
+                               "min": min(v)} for root, v in us.items()},
+        "root_minus_beside_us": {
+            "median": statistics.median(diff),
+            "quartiles": statistics.quantiles(diff, n=4)},
+    }), flush=True)
+    return 0
 
 
 def main(argv=None) -> int:
@@ -45,6 +104,11 @@ def main(argv=None) -> int:
     p.add_argument("root", nargs="?", default=ROOT)
     p.add_argument("--streams", action="store_true")
     p.add_argument("--profile", action="store_true")
+    p.add_argument("--host-only", action="store_true")
+    p.add_argument("--beside", metavar="ROOT2", default=None)
+    p.add_argument("--pairs", type=int, default=40)
+    p.add_argument("--iterations", type=int, default=0,
+                   help="decode iterations to time (default: --tokens - 60)")
     p.add_argument("--config", default="trinity_large_ep8")
     p.add_argument("--slots", type=int, default=64)
     p.add_argument("--prompt", type=int, default=600)
@@ -57,13 +121,14 @@ def main(argv=None) -> int:
     p.add_argument("--sample", type=int, default=0,
                    help="how many of the requests sample (temperature 0.8)")
     args = p.parse_args(argv)
+    args.host_only = args.host_only or bool(args.beside)
     sys.path.insert(0, os.path.abspath(args.root))
 
     import jax
     import numpy as np
 
     from distributedtensorflow_tpu import models, runtime
-    from distributedtensorflow_tpu.obs import tracing
+    from distributedtensorflow_tpu.obs import registry
     from distributedtensorflow_tpu.serve import engine as engine_mod
     from distributedtensorflow_tpu.serve.model import family_of
 
@@ -72,69 +137,78 @@ def main(argv=None) -> int:
     params = family_of(cfg).init_params(cfg, jax.random.PRNGKey(3))
     jax.block_until_ready(params)
 
-    # the wall clocks: every span's duration by name, and the time inside
-    # _stream_emit (the hand-over of a line to its stream)
-    spans = collections.defaultdict(list)
-    span_exit = tracing.span.__exit__
-
-    def timed_exit(self, *exc):
-        result = span_exit(self, *exc)
-        spans[self._span.name].append(self._span.dur_s)
-        return result
-
-    tracing.span.__exit__ = timed_exit
-    emit_s = [0.0, 0]
-    stream_emit = engine_mod.Engine._stream_emit
-
-    def timed_emit(self, req, toks):
-        t = time.perf_counter()
-        stream_emit(self, req, toks)
-        emit_s[0] += time.perf_counter() - t
-        emit_s[1] += 1
-
-    engine_mod.Engine._stream_emit = timed_emit
-
-    eng = engine_mod.Engine(
-        params, cfg, max_slots=args.slots, block_size=args.block,
-        prefill_chunk=args.chunk, max_context=args.context,
-        num_blocks=args.kv_blocks, window_blocks=args.kv_window_blocks,
-        max_queue=512)
-    rng = np.random.default_rng(5)
     sink = open(os.devnull, "w")
 
-    def consume(req):
-        while True:
-            kind, payload = req._events.get()
-            if kind != "tokens":
-                return
-            sink.write(json.dumps({"tokens": payload}) + "\n")
-            sink.flush()
+    def build(mod):
+        """An engine of ``mod.Engine`` with ``--slots`` requests past their
+        prefill and every program compiled."""
+        eng = mod.Engine(
+            params, cfg, max_slots=args.slots, block_size=args.block,
+            prefill_chunk=args.chunk, max_context=args.context,
+            num_blocks=args.kv_blocks, window_blocks=args.kv_window_blocks,
+            max_queue=512, step_ring=max(args.tokens, args.iterations, 64),
+            registry=registry.Registry(),
+            # --beside: with the step log on disk, as serve.py runs
+            logdir=tempfile.mkdtemp(prefix="iter_split_")
+            if args.beside else None)
+        rng = np.random.default_rng(5)
 
-    reqs = [eng.submit(
-        rng.integers(0, cfg.vocab_size, args.prompt).tolist(),
-        max_new_tokens=args.tokens, stream=args.streams,
-        temperature=0.8 if i < args.sample else 0.0, seed=i)
-        for i in range(args.slots)]
-    if args.streams:
-        for r in reqs:
-            threading.Thread(target=consume, args=(r,), daemon=True).start()
+        def consume(req):
+            while True:
+                kind, payload, *stamp = req._events.get()
+                if kind != "tokens":
+                    return
+                sink.write(json.dumps({"tokens": payload}) + "\n")
+                sink.flush()
+                if stamp:
+                    eng.note_stream_line(stamp[0])
 
-    # every prompt prefilled and every program compiled before the stretch
-    while eng._filling or eng._queue:
-        eng.step()
-    for _ in range(20):
-        eng.step()
-    for durations in spans.values():
-        durations.clear()
-    emit_s[:] = [0.0, 0]
+        reqs = [eng.submit(
+            rng.integers(0, cfg.vocab_size, args.prompt).tolist(),
+            max_new_tokens=args.tokens, stream=args.streams,
+            temperature=0.8 if i < args.sample else 0.0, seed=i)
+            for i in range(args.slots)]
+        if args.streams:
+            for r in reqs:
+                threading.Thread(target=consume, args=(r,),
+                                 daemon=True).start()
+        while eng._filling or eng._queue:
+            eng.step()
+        for _ in range(20):
+            eng.step()
+        if args.host_only:
+            # the pools it hands back are the live ones; its token is no
+            # EOS and max_new_tokens is out of reach: the batch stays whole
+            result = eng.programs.decode(
+                eng.params, eng.kv.pools(), eng._last_tokens,
+                eng._tables_dev(), eng.kv.seq_lens, eng._dev_active)
+            eng.kv.set_pools(result[2])
+            eng.programs.decode = lambda *a: result
+            eng.kv.note_written = lambda *a: None
+            for r in reqs:
+                r.max_new_tokens = 10 ** 9
+        return eng
+
+    if args.beside:
+        return beside(args, build, engine_mod)
+    eng = build(engine_mod)
+    if args.host_only:
+        # one full collection of JAX's half a million objects is 60 ms;
+        # whether it falls inside the stretch would decide the comparison
+        gc.collect()
+        gc.freeze()
     prof = cProfile.Profile() if args.profile else None
     n0 = eng.decode_steps
+
+    cpu = [0.0]
 
     def loop():
         if prof:
             prof.enable()
-        while eng.decode_steps - n0 < args.tokens - 60:
+        cpu0 = time.thread_time()
+        while eng.decode_steps - n0 < (args.iterations or args.tokens - 60):
             eng.step()
+        cpu[0] = time.thread_time() - cpu0
         if prof:
             prof.disable()
 
@@ -144,17 +218,19 @@ def main(argv=None) -> int:
     engine_thread.join()
     wall = time.perf_counter() - t0
     n = eng.decode_steps - n0
-    recs = [r for r in eng.step_records() if r["occupancy"]]
+    recs = [r for r in eng.step_records() if r["occupancy"]][-n:]
     print(json.dumps({
         "root": args.root, "streams": args.streams, "profile": args.profile,
+        "host_only": args.host_only,
         "sample": args.sample, "device": jax.devices()[0].device_kind,
         "iterations": n,
         "occupancy_mean": sum(r["occupancy"] for r in recs) / len(recs),
         "iter_ms": 1e3 * wall / n,
-        "span_ms": {k: round(1e3 * sum(v) / n, 4)
-                    for k, v in sorted(spans.items()) if v},
-        "stream_emit_ms": round(1e3 * emit_s[0] / n, 4),
-        "stream_emit_calls_per_iter": emit_s[1] / n,
+        "iter_cpu_ms": 1e3 * cpu[0] / n,    # the engine thread's own
+        "step_ms": {k: round(1e3 * statistics.fmean(r[k] for r in recs), 4)
+                    for k in recs[-1] if k.endswith("_s")},
+        "stream_lines_per_iter": statistics.fmean(
+            r.get("stream_lines", 0) for r in recs),
         "counters": {k: v for k, v in eng.counters.items() if k in (
             "decode_tokens", "host_sample_rounds", "device_sampled_tokens",
             "logit_fetches")},
