@@ -20,6 +20,7 @@ sequences trade FLOPs for HBM.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -30,7 +31,8 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import KVRows, dot_product_attention
 from ..ops.layernorm import layer_norm
 from ..ops.xent import tied_head_logits
-from ..parallel.sharding import LayoutMap
+from ..parallel import mesh as mesh_lib
+from ..parallel.sharding import LayoutMap, kernel_axes
 from ..runtime import on_tpu
 from .layers import FusedLayerNorm, dense, sow_nonfinite
 
@@ -52,6 +54,15 @@ class GPTConfig:
     dropout_rate: float = 0.0
     rope_theta: float = 10000.0
     dtype: jnp.dtype = jnp.bfloat16
+    #: ``jax.checkpoint`` around every block (:func:`remat_block`): a layer
+    #: keeps its (B, S, d) input and the backward runs the block again.
+    #: Where the block's attention took the ``"qkv_tiles"`` form
+    #: (:func:`attention_layout`) the layer also keeps what the flash
+    #: kernel itself made, o (B, S, H*D) and the log-sum-exp (B, H, S)
+    #: float32, so the backward does not run ``flash_fwd`` a second time:
+    #: ``B*S*(H*D*itemsize + H*4)`` bytes a layer more (138 MB at 64 x
+    #: 1024 of GPT-2 medium in bf16, 3.3 GB over its 24 layers).  Every
+    #: other form keeps the input alone.
     remat: bool = True
     #: Checkpoint ONLY the attention op inside each block (meaningful when
     #: ``remat`` is False): backward recomputes the (S, S) score/softmax
@@ -292,6 +303,29 @@ def block_rope_tables(cfg: GPTConfig, positions: jax.Array | None,
     return rope_tables(positions, cfg.head_dim, cfg.rope_theta, cfg.dtype)
 
 
+def remat_block(block):
+    """``block`` — a block's Module class (``__call__(x, positions,
+    deterministic, rope_tabs)``) or a function that applies one — under
+    ``jax.checkpoint``: the backward recomputes the block from its input,
+    but for the two residuals the flash kernel of the ``"qkv_tiles"`` form
+    names (``ops.flash_attention.RESIDUAL_O`` / ``RESIDUAL_LSE``), which
+    are kept.  What a block keeps is the block's property, so every trunk
+    that remats one (``GPTLM``, ``GPTMoELM``, the pipeline stages) comes
+    here.  A block whose attention took another form holds no such name,
+    saves its input alone and compiles to the program a policy-less
+    checkpoint gives."""
+    from ..ops.flash_attention import RESIDUAL_LSE, RESIDUAL_O
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        RESIDUAL_O, RESIDUAL_LSE)
+    if isinstance(block, type) and issubclass(block, nn.Module):
+        # static_argnums counts __call__'s args INCLUDING self:
+        # deterministic is index 3 (rope_tabs at 4 is a traced array;
+        # verified by tests/test_gpt.py::test_remat_path_trains).
+        return nn.remat(block, static_argnums=(3,), policy=policy)
+    return jax.checkpoint(block, policy=policy)
+
+
 class CausalSelfAttention(nn.Module):
     cfg: GPTConfig
     attn_fn: AttnFn | None = None  # None = dense causal (flash-capable)
@@ -486,6 +520,33 @@ class GPTLM(nn.Module):
             return None
         return attention_layout(self.cfg, seq)
 
+    def attn_residuals(self, batch: int, seq: int
+                       ) -> tuple[str | None, int | None]:
+        """What the backward of a block does for the residuals of its
+        attention over ``(batch, seq)`` tokens, and the bytes a layer
+        keeps for it on a device: ``("saved", B*S*(H*D*itemsize + H*4))``
+        where :func:`remat_block` keeps the tile kernels' o and
+        log-sum-exp (B the batch a shard of the kernel holds, read under
+        the context mesh), ``("recomputed", 0)`` where a checkpoint runs
+        the attention again (another form under ``remat``; the
+        attention-only ``remat_attn`` alone, which has no policy),
+        ``(None, None)`` where nothing is rematerialised or
+        :meth:`flash_layout` is None.  Beside ``flash_layout`` in the
+        trainer's start-up row."""
+        cfg = self.cfg
+        layout = self.flash_layout(seq)
+        if layout is None or not (cfg.remat or cfg.remat_attn):
+            return None, None
+        if layout != "qkv_tiles" or not cfg.remat:
+            return "recomputed", 0
+        mesh = jax.sharding.get_abstract_mesh()
+        shards = math.prod(
+            mesh.shape[a]
+            for a in kernel_axes(mesh_lib.BATCH_AXES, batch) or ())
+        per_token = cfg.num_heads * (
+            cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
+        return "saved", batch // shards * seq * per_token
+
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
                  positions=None, return_hidden: bool = False):
@@ -514,11 +575,8 @@ class GPTLM(nn.Module):
         block = GPTBlock
         if cfg.remat and not self.decode:
             # Remat each block: activations recomputed in backward — the
-            # jax.checkpoint HBM/FLOPs trade for long sequences.  For
-            # nn.remat over a Module class, static_argnums counts
-            # __call__'s args INCLUDING self: deterministic is index 3
-            # (verified by tests/test_gpt.py::test_remat_path_trains).
-            block = nn.remat(GPTBlock, static_argnums=(3,))
+            # jax.checkpoint HBM/FLOPs trade for long sequences.
+            block = remat_block(GPTBlock)
         for i in range(cfg.num_layers):
             x = block(cfg, self.attn_fn, self.decode, name=f"h{i}")(
                 x, positions, deterministic, rope_tabs

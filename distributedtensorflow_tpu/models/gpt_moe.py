@@ -26,7 +26,8 @@ from jax.sharding import Mesh
 from ..parallel.moe import local_moe
 from ..parallel.sharding import LayoutMap
 from .gpt import (CausalSelfAttention, GPTBlock, GPTConfig,
-                  attention_layout, block_rope_tables, gpt_layout)
+                  attention_layout, block_rope_tables, gpt_layout,
+                  remat_block)
 from .layers import FusedLayerNorm
 
 PyTree = Any
@@ -175,8 +176,8 @@ class GPTMoELM(nn.Module):
         dense_block = GPTBlock
         moe_block = MoEGPTBlock
         if cfg.remat:
-            dense_block = nn.remat(GPTBlock, static_argnums=(3,))
-            moe_block = nn.remat(MoEGPTBlock, static_argnums=(3,))
+            dense_block = remat_block(GPTBlock)
+            moe_block = remat_block(MoEGPTBlock)
         for i in range(cfg.num_layers):
             # layer k-1, 2k-1, ... are MoE (last of each group of k)
             if (i + 1) % cfg.moe_every_k == 0:

@@ -47,7 +47,7 @@ from ..parallel.pipeline import (
     pipeline_fb_step,
 )
 from .gpt import (GPTBlock, GPTConfig, attention_layout,
-                  block_rope_tables)
+                  block_rope_tables, remat_block)
 from .layers import FusedLayerNorm
 
 PyTree = Any
@@ -354,7 +354,7 @@ class PipelinedGPT:
             return y.astype(jnp.float32), None
 
         if self.cfg.remat:
-            one = jax.checkpoint(one)
+            one = remat_block(one)
         x, _ = lax.scan(one, x, stage_params)
         return x
 

@@ -45,6 +45,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -1877,11 +1878,23 @@ def _flash_qkv(qkv, rope, mask, segment_ids, heads, causal, interpret,
     return o
 
 
+#: ``jax.ad_checkpoint.checkpoint_name`` of the two residuals the forward
+#: kernel itself made, o and the log-sum-exp.  A ``jax.checkpoint`` around
+#: the caller that saves them (``save_only_these_names``) runs the backward
+#: kernels on the forward pass's own bits and does not call ``flash_fwd``
+#: again; the projection, which the backward reads too, is the caller's to
+#: rebuild.  ``models.gpt.remat_block`` is that checkpoint.
+RESIDUAL_O = "flash_qkv_o"
+RESIDUAL_LSE = "flash_qkv_lse"
+
+
 def _flash_qkv_fwd(qkv, rope, mask, segment_ids, heads, causal, interpret,
                    backward_impl, window, block_q, block_k):
     o, lse = _tiles_forward(qkv, rope, mask, segment_ids, heads=heads,
                             causal=causal, interpret=interpret, window=window,
                             block_q=block_q, block_k=block_k)
+    o = checkpoint_name(o, RESIDUAL_O)
+    lse = checkpoint_name(lse, RESIDUAL_LSE)
     return o, (qkv, rope, mask, segment_ids, o, lse)
 
 
@@ -1939,6 +1952,13 @@ def flash_attention_qkv(qkv, heads: int, *, rope=None, mask=None,
     are :func:`flash_attention`'s; ``backward_impl`` "xla" is not taken
     here.  The shapes this form takes are :func:`tile_heads`'s; callers
     choose between it and :func:`flash_attention` by :func:`qkv_layout`.
+
+    The backward reads ``qkv`` again, o and the forward's log-sum-exp
+    (B, H, 1, S) float32.  The last two carry the names ``RESIDUAL_O`` /
+    ``RESIDUAL_LSE``: under a ``jax.checkpoint`` whose policy saves them
+    (``models.gpt.remat_block``) the forward kernel runs once, for
+    ``B*S*(H*D*itemsize + H*4)`` bytes kept a call; under one that does
+    not, it runs again in the backward.
     """
     if qkv.ndim != 3 or qkv.shape[2] % (3 * heads):
         raise ValueError(

@@ -1,6 +1,7 @@
 """SPMD training engine: state, train/eval step compilation, losses."""
 
-from .state import TrainState, create_sharded_state, split_variables  # noqa: F401
+from .state import (TrainState, abstract_sharded_state,  # noqa: F401
+                    create_sharded_state, split_variables)
 from .engine import (  # noqa: F401
     accumulate_gradients,
     estimate_step_flops,

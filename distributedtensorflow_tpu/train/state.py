@@ -69,33 +69,9 @@ def split_variables(variables: PyTree) -> tuple[PyTree, PyTree]:
     return variables, {}
 
 
-def create_sharded_state(
-    init_fn: Callable[[jax.Array], PyTree],
-    tx: optax.GradientTransformation,
-    mesh: Mesh,
-    rng: jax.Array,
-    *,
-    rules: shardlib.LayoutMap | Callable | None = None,
-    fsdp: bool = False,
-    zero=None,
-) -> tuple[TrainState, "TrainState"]:
-    """Initialize a TrainState directly into its target sharding.
-
-    ``init_fn(rng)`` returns a flax-style variables dict (``{"params": ...,
-    "batch_stats": ...}``) or a bare params pytree.  Params are produced by
-    ``jit`` with ``out_shardings`` so large models initialize shard-local on
-    each device — no host-side full copy (the reference initializes under
-    ``strategy.scope()`` for the same reason, SURVEY.md §3.3).
-
-    ``zero`` (a :class:`~..parallel.zero.ZeroSharder`) switches the
-    optimizer state to cross-replica weight-update sharding: slots are
-    initialized in the sharder's chunked ``(degree, chunk)`` layout and
-    sharded over the batch axes — each replica holds 1/degree of the
-    optimizer state from the first step on, never a full copy.
-
-    Returns ``(state, state_specs)`` where ``state_specs`` is a TrainState of
-    PartitionSpecs (for use as jit shardings).
-    """
+def _state_plan(init_fn, tx, mesh, rng, rules, fsdp, zero):
+    """``(build, state_specs, out_shardings)``: the function of ``rng`` that
+    makes the TrainState, its PartitionSpecs and their NamedShardings."""
     var_shapes = jax.eval_shape(init_fn, rng)
     param_shapes, mstate_shapes = split_variables(var_shapes)
     param_specs = shardlib.specs_for_tree(param_shapes, mesh, rules, fsdp=fsdp)
@@ -130,10 +106,67 @@ def create_sharded_state(
         lambda s: NamedSharding(mesh, s), state_specs,
         is_leaf=lambda x: isinstance(x, P),
     )
+    return build, state_specs, out_shardings
+
+
+def create_sharded_state(
+    init_fn: Callable[[jax.Array], PyTree],
+    tx: optax.GradientTransformation,
+    mesh: Mesh,
+    rng: jax.Array,
+    *,
+    rules: shardlib.LayoutMap | Callable | None = None,
+    fsdp: bool = False,
+    zero=None,
+) -> tuple[TrainState, "TrainState"]:
+    """Initialize a TrainState directly into its target sharding.
+
+    ``init_fn(rng)`` returns a flax-style variables dict (``{"params": ...,
+    "batch_stats": ...}``) or a bare params pytree.  Params are produced by
+    ``jit`` with ``out_shardings`` so large models initialize shard-local on
+    each device — no host-side full copy (the reference initializes under
+    ``strategy.scope()`` for the same reason, SURVEY.md §3.3).
+
+    ``zero`` (a :class:`~..parallel.zero.ZeroSharder`) switches the
+    optimizer state to cross-replica weight-update sharding: slots are
+    initialized in the sharder's chunked ``(degree, chunk)`` layout and
+    sharded over the batch axes — each replica holds 1/degree of the
+    optimizer state from the first step on, never a full copy.
+
+    Returns ``(state, state_specs)`` where ``state_specs`` is a TrainState of
+    PartitionSpecs (for use as jit shardings).
+    """
+    build, state_specs, out_shardings = _state_plan(
+        init_fn, tx, mesh, rng, rules, fsdp, zero)
     # under the mesh: init traces the model, Pallas kernels included
     # (parallel.sharding.shard_kernel reads the mesh from this context)
     with jax.sharding.set_mesh(mesh):
         state = jax.jit(build, out_shardings=out_shardings)(rng)
+    return state, state_specs
+
+
+def abstract_sharded_state(
+    init_fn: Callable[[jax.Array], PyTree],
+    tx: optax.GradientTransformation,
+    mesh: Mesh,
+    rng: jax.Array,
+    *,
+    rules: shardlib.LayoutMap | Callable | None = None,
+    fsdp: bool = False,
+    zero=None,
+) -> tuple[TrainState, "TrainState"]:
+    """:func:`create_sharded_state` with nothing placed: the state as
+    ``ShapeDtypeStruct`` leaves that carry their shardings, and its specs —
+    what a step is lowered against where the mesh's devices are described
+    and not attached (``tools/train_step_memory.py``)."""
+    build, state_specs, out_shardings = _state_plan(
+        init_fn, tx, mesh, rng, rules, fsdp, zero)
+    with jax.sharding.set_mesh(mesh):
+        shapes = jax.eval_shape(build, rng)
+    state = jax.tree.map(
+        lambda s, sharding: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding),
+        shapes, out_shardings)
     return state, state_specs
 
 

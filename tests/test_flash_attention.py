@@ -586,6 +586,62 @@ def test_block_falls_back_to_the_bhsd_kernels_by_shape(case, monkeypatch):
     assert set(taken) == {entry[want]}, taken
 
 
+_A_ROW = 64 * (128 * 2 + 4 * 4)   # o (S, H*D) bf16 + the LSE (H, S) float32
+
+
+@pytest.mark.parametrize("changes, want", [
+    pytest.param(dict(remat=True), ("saved", 2 * _A_ROW), id="tiles_remat"),
+    pytest.param(dict(remat=True, remat_attn=True), ("saved", 2 * _A_ROW),
+                 id="tiles_both_remats"),
+    pytest.param(dict(remat=True, dtype=jnp.float32),
+                 ("saved", 2 * 64 * (128 * 4 + 4 * 4)), id="tiles_float32"),
+    pytest.param(dict(remat=True, **FALLBACKS["gqa"]), ("recomputed", 0),
+                 id="bhsd_remat"),
+    pytest.param(dict(remat=True, attn_impl="xla"), ("recomputed", 0),
+                 id="xla_remat"),
+    pytest.param(dict(remat_attn=True), ("recomputed", 0),
+                 id="tiles_attn_remat_alone"),
+    pytest.param(dict(), (None, None), id="tiles_no_remat"),
+    pytest.param(dict(attn_impl="xla"), (None, None), id="xla_no_remat"),
+])
+def test_attn_residuals_says_what_the_backward_does(changes, want):
+    """``GPTLM.attn_residuals``, beside ``flash_layout`` on the trainer's
+    start-up row: "saved" with the bytes of o (B, S, H*D) and the
+    log-sum-exp (B, H, S) float32 where a remat'd block's attention took
+    the tile kernels, "recomputed" where a checkpoint runs another form
+    (or the attention-only one runs this form) again, null where nothing
+    is rematerialised."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import gpt
+
+    cfg = dataclasses.replace(
+        gpt.gpt_tiny(), **{"attn_impl": "pallas", **changes})
+    model = gpt.GPTLM(cfg)
+    assert model.attn_residuals(2, 64) == want
+    assert gpt.GPTLM(cfg, decode=True).attn_residuals(2, 64) == (None, None)
+
+
+def test_attn_residuals_counts_a_devices_rows(devices):
+    """Under a mesh the kernel runs per shard of the batch axes, and the
+    bytes are a device's: a batch the axes do not divide is replicated
+    (``kernel_axes``), every device holding all of it."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import gpt
+    from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
+
+    model = gpt.GPTLM(dataclasses.replace(
+        gpt.gpt_tiny(), attn_impl="pallas", remat=True))
+    with jax.sharding.set_mesh(build_mesh(MeshSpec(data=2, fsdp=2, model=2),
+                                          devices)):
+        assert model.attn_residuals(8, 64) == ("recomputed", 0)  # bhsd
+    with jax.sharding.set_mesh(build_mesh(MeshSpec(data=4, fsdp=2),
+                                          devices)):
+        assert model.attn_residuals(16, 64) == ("saved", 2 * _A_ROW)
+        assert model.attn_residuals(6, 64) == ("saved", 6 * _A_ROW)
+
+
 def test_fused_projection_layout_follows_what_it_can_observe(monkeypatch):
     import distributedtensorflow_tpu.ops.flash_attention as fa
 

@@ -498,3 +498,181 @@ def test_block_with_the_fused_entry_matches_rope_outside(dtype, window,
             <= 1.1 * np.linalg.norm(g_want - g_exact))
     assert (np.median(np.abs(got - exact))
             <= 1.1 * np.median(np.abs(want - exact)))
+
+
+# -- what a remat'd block keeps (PR 37) ---------------------------------------
+
+def _kernel_calls(jaxpr, out=None):
+    """{kernel name: call sites} of every ``pallas_call`` in ``jaxpr``."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            out[name] = out.get(name, 0) + 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, out)
+    return out
+
+
+def _policy_less(block):
+    """``models.gpt.remat_block`` as it was before the block kept anything:
+    ``jax.checkpoint`` with no policy."""
+    import flax.linen as nn
+
+    if isinstance(block, type):
+        return nn.remat(block, static_argnums=(3,))
+    return jax.checkpoint(block)
+
+
+def _loss_and_grads(loss, params, *args):
+    """``(jaxpr, (loss, grads))`` of ``loss(params, *args)``, traced once."""
+    traced = jax.jit(jax.value_and_grad(loss)).trace(params, *args)
+    out = traced.lower().compile()(params, *args)
+    return traced.jaxpr, jax.tree.map(np.asarray, out)
+
+
+def _both_remats(monkeypatch, build):
+    """``build()`` → ``(loss, params, *args)`` run under the block remat
+    as it is and under a policy-less one; the models are built anew each
+    time (the pipeline keeps a jitted function a model)."""
+    from distributedtensorflow_tpu.models import gpt, gpt_moe, gpt_pipeline
+
+    kept = _loss_and_grads(*build())
+    for module in (gpt, gpt_moe, gpt_pipeline):
+        monkeypatch.setattr(module, "remat_block", _policy_less)
+    return kept, _loss_and_grads(*build())
+
+
+def _assert_bitwise(got, want):
+    flat_got, tree = jax.tree.flatten(got)
+    flat_want, tree_want = jax.tree.flatten(want)
+    assert tree == tree_want
+    for a, b in zip(flat_got, flat_want):
+        np.testing.assert_array_equal(a, b)
+
+
+#: how a 3-layer tiny model is sent down each form of ``attention_layout``
+REMAT_FORMS = {
+    "qkv_tiles": dict(attn_impl="pallas"),
+    "bhsd": dict(attn_impl="pallas", num_kv_heads=2),
+    "xla": dict(attn_impl="xla"),
+}
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["one_device", "data4"])
+@pytest.mark.parametrize("form", sorted(REMAT_FORMS))
+def test_remat_block_keeps_the_flash_residuals(form, meshed, devices,
+                                               monkeypatch):
+    """A remat'd block keeps o and the log-sum-exp of its tile kernel: the
+    gradient program of a 3-layer model holds ``num_layers`` fewer
+    ``flash_fwd`` calls than under a policy-less checkpoint, as many
+    ``flash_bwd``, and gives the same bits — alone and per shard of a
+    four-way ``data`` mesh.  A block that took another form holds no such
+    name and traces to the policy-less program.
+
+    float32: the kernels are interpreted here, so XLA sees their bodies
+    and (``xla_allow_excess_precision``) drops a bf16 rounding between a
+    recomputed o and its reader that a saved o has been through; in
+    float32 there is no rounding to drop, and on the chip the kernel is a
+    custom call whose o is stored either way."""
+    import contextlib
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributedtensorflow_tpu.models.gpt import attention_layout
+
+    cfg = dataclasses.replace(gpt_tiny(), num_layers=3, remat=True,
+                              dtype=jnp.float32, **REMAT_FORMS[form])
+    ids = jax.random.randint(jax.random.PRNGKey(2), (4, 32), 0,
+                             cfg.vocab_size)
+    params = GPTLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
+    mesh = contextlib.nullcontext()
+    if meshed:
+        mesh = build_mesh(MeshSpec(data=4), devices[:4])
+        ids = jax.device_put(ids, NamedSharding(mesh, P("data")))
+        mesh = jax.sharding.set_mesh(mesh)
+
+    def build():
+        model = GPTLM(cfg)
+
+        def loss(p, ids):
+            logits = model.apply({"params": p}, ids)
+            return -jnp.mean(jnp.take_along_axis(
+                jax.nn.log_softmax(logits), ids[:, :, None], axis=-1))
+        return loss, params, ids
+
+    with mesh:
+        assert attention_layout(cfg, 32) == form
+        state, kept_bytes = GPTLM(cfg).attn_residuals(*ids.shape)
+        (jaxpr, got), (jaxpr_less, want) = _both_remats(monkeypatch, build)
+    _assert_bitwise(got, want)
+    calls, calls_less = _kernel_calls(jaxpr.jaxpr), _kernel_calls(
+        jaxpr_less.jaxpr)
+    if form == "qkv_tiles":
+        assert calls_less["flash_fwd"] == 2 * cfg.num_layers
+        assert calls["flash_fwd"] == cfg.num_layers
+        assert calls["flash_bwd"] == calls_less["flash_bwd"] == cfg.num_layers
+        # o (B, S, H*D) and the LSE (B, H, S) float32, of a device's rows
+        rows = ids.shape[0] // (4 if meshed else 1)
+        assert (state, kept_bytes) == (
+            "saved", rows * 32 * (128 * 4 + cfg.num_heads * 4))
+        return
+    assert calls == calls_less and (state, kept_bytes) == ("recomputed", 0)
+    # the two programs differ by the policy the checkpoint carries only
+    strip = lambda j: re.sub(r"policy=[^\n\]]*", "policy=", str(j))
+    assert strip(jaxpr) == strip(jaxpr_less)
+    assert "save_only_these_names" in str(jaxpr)
+
+
+def test_remat_block_is_the_moe_trunks_remat_too(monkeypatch):
+    """``GPTMoELM`` remats its dense and its expert blocks through
+    ``models.gpt.remat_block``: the same bits, a ``flash_fwd`` a layer
+    fewer."""
+    from distributedtensorflow_tpu.models.gpt_moe import (
+        GPTMoELM, gpt_moe_tiny, moe_lm_loss)
+
+    cfg = dataclasses.replace(gpt_moe_tiny(), dtype=jnp.float32, remat=True,
+                              attn_impl="pallas")
+    rng = jax.random.PRNGKey(0)
+    ids = jax.random.randint(rng, (2, 32), 0, cfg.vocab_size)
+    params = GPTMoELM(cfg).init(rng, ids)["params"]
+
+    def build():
+        loss_fn = moe_lm_loss(GPTMoELM(cfg))
+        return (lambda p: loss_fn(p, {}, {"input_ids": ids}, rng)[0]), params
+
+    (jaxpr, got), (jaxpr_less, want) = _both_remats(monkeypatch, build)
+    _assert_bitwise(got, want)
+    calls, calls_less = _kernel_calls(jaxpr.jaxpr), _kernel_calls(
+        jaxpr_less.jaxpr)
+    assert calls_less["flash_fwd"] == 2 * cfg.num_layers
+    assert calls["flash_fwd"] == cfg.num_layers
+    assert calls["flash_bwd"] == calls_less["flash_bwd"] == cfg.num_layers
+
+
+def test_remat_block_is_the_pipeline_stages_remat_too(devices, monkeypatch):
+    """The pipeline's stages scan a checkpointed block function that comes
+    from ``models.gpt.remat_block``: the same bits, and the scanned
+    backward body no longer holds a ``flash_fwd`` (one call site in the
+    program, the forward scan's, where the policy-less one has two)."""
+    from distributedtensorflow_tpu.models.gpt_pipeline import (
+        PipelinedGPT, pipelined_lm_loss)
+
+    mesh = build_mesh(MeshSpec(data=4, pipe=2), devices)
+    cfg = dataclasses.replace(gpt_tiny(), dtype=jnp.float32, remat=True,
+                              attn_impl="pallas")
+    rng = jax.random.PRNGKey(0)
+    ids = jax.random.randint(rng, (8, 32), 0, cfg.vocab_size)
+    params = PipelinedGPT(cfg, mesh, n_microbatches=2).init(rng)["params"]
+
+    def build():
+        loss_fn = pipelined_lm_loss(
+            PipelinedGPT(cfg, mesh, n_microbatches=2))
+        return (lambda p: loss_fn(p, {}, {"input_ids": ids}, rng)[0]), params
+
+    (jaxpr, got), (jaxpr_less, want) = _both_remats(monkeypatch, build)
+    _assert_bitwise(got, want)
+    assert _kernel_calls(jaxpr_less.jaxpr) == {"flash_fwd": 2, "flash_bwd": 1}
+    assert _kernel_calls(jaxpr.jaxpr) == {"flash_fwd": 1, "flash_bwd": 1}

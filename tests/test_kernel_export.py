@@ -532,3 +532,42 @@ def test_decode_program_attends_through_the_kernel_on_a_v5e(monkeypatch):
     text = plain[0].lower(*plain[1]).as_text()
     assert "paged_attn\"" not in text
     assert re.search(r"tensor<32x(?:64x16|1024)x1024xbf16>", text)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "2x2"])
+def test_gpt2_medium_step_runs_flash_fwd_once_a_layer_and_fits_a_v5e(
+        chips, monkeypatch):
+    """The benchmark's training step (``gpt_medium_lm``, 64 x 1024 tokens a
+    chip, state and step as ``train.py`` makes them) compiled for the
+    described chip, and per shard on the 2x2 mesh: a remat'd block keeps o
+    and the log-sum-exp of its flash kernel, so the step holds one
+    ``flash_fwd`` a layer — the backward's second run is gone — beside one
+    ``flash_bwd``; and what that keeps (138 MB a layer) still leaves the
+    step under 14.0 GB of the chip's 16 by ``memory_analysis`` (13.11 GB on
+    one chip, 12.75 a shard of four; 9.92 / 9.55 with nothing kept:
+    ``PERF.md`` section 4, PR 37)."""
+    import os
+    import sys
+
+    import distributedtensorflow_tpu.models  # noqa: F401 — for on_tpu
+    import distributedtensorflow_tpu.workloads  # noqa: F401
+
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    monkeypatch.syspath_prepend(tools)
+    import train_step_memory
+
+    devices = list(_v5e_mesh(chips).devices.flat)
+    _as_on_the_chip(monkeypatch)
+    compiled, mesh, wl = train_step_memory.compile_step(
+        "gpt_medium_lm", 64, 1024, devices)
+    row = train_step_memory.report(compiled, mesh, wl)
+    layers = wl.model.cfg.num_layers
+    assert layers == 24 and wl.global_batch_size == 64 * chips
+    assert row["kernels"]["flash_fwd"] == layers, row["kernels"]
+    assert row["kernels"]["flash_bwd"] == layers, row["kernels"]
+    assert row["total_bytes"] <= 14.0e9, row
+    assert row["flash_layout"] == "qkv_tiles"
+    # a device's o (64, 1024, 16 * 64) bf16 and LSE (64, 16, 1024) float32
+    assert (row["attn_residuals"], row["attn_residual_bytes_per_layer"]) == (
+        "saved", 64 * 1024 * (1024 * 2 + 16 * 4))

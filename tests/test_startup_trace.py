@@ -91,3 +91,49 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     ts = [m["t"] for m in metrics]
     assert ts == sorted(ts) and t_before <= ts[0] and ts[-1] <= t_after
     assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []
+
+
+@pytest.mark.parametrize("changes, want", [
+    pytest.param(dict(remat=True, attn_impl="pallas"),
+                 ("qkv_tiles", "saved", 4 * 64 * (128 * 2 + 4 * 4)),
+                 id="tiles_under_remat"),
+    pytest.param(dict(remat=True, attn_impl="xla"), ("xla", "recomputed", 0),
+                 id="another_form_under_remat"),
+    pytest.param(dict(remat=False, attn_impl="pallas"),
+                 ("qkv_tiles", None, None), id="remat_off"),
+])
+def test_trainer_row_says_what_a_block_keeps(changes, want, tmp_path):
+    """The ``startup.trainer`` row carries ``attn_residuals`` and
+    ``attn_residual_bytes_per_layer`` beside ``flash_layout`` (what
+    ``train._flash_layout`` reads off the model under the trainer's mesh),
+    and the schema checker takes the row, nulls included."""
+    import dataclasses
+    import types
+
+    import jax
+    import numpy as np
+    import train
+
+    from distributedtensorflow_tpu.models import GPTLM, gpt_tiny
+    from distributedtensorflow_tpu.obs import tracing
+    from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
+
+    wl = types.SimpleNamespace(
+        model=GPTLM(dataclasses.replace(gpt_tiny(), **changes)),
+        # the example batch has two rows; a step has the global batch's
+        init_batch={"input_ids": np.zeros((2, 64), np.int32)},
+        global_batch_size=4)
+    fields = train._flash_layout(
+        wl, build_mesh(MeshSpec(data=1), jax.devices()[:1]))
+    assert fields == dict(zip(
+        ("flash_layout", "attn_residuals", "attn_residual_bytes_per_layer"),
+        want))
+    path = tmp_path / "trace.jsonl"
+    with tracing.TraceRecorder(str(path), chief_only=False):
+        tracing.PhaseTrace("startup").mark("startup.trainer", **fields)
+    (row,) = startup_rows(path)
+    assert {k: row[k] for k in fields} == fields
+    assert checker.check_file(str(path)) == ([], [])
+    # a model with no such choice leaves the row as it was
+    wl.model = object()
+    assert train._flash_layout(wl, None) == {}

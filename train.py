@@ -553,22 +553,31 @@ def run_ps_cluster_task(args, cluster, task_type, task_index) -> None:
 
 
 def _flash_layout(wl, mesh) -> dict:
-    """``{"flash_layout": ...}`` for the ``startup.trainer`` row and the
-    start-up log: the form the step's dense attention lowers to
-    (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla"), read
-    under the trainer's mesh as the step is traced.  The fall-back from
-    one form to the next is silent and costs a tenth of a step, so a run's
-    trace says which it got.  Empty for a model that has no such choice."""
+    """``flash_layout``, ``attn_residuals`` and
+    ``attn_residual_bytes_per_layer`` for the ``startup.trainer`` row and
+    the start-up log: the form the step's dense attention lowers to
+    (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla") and
+    what a remat'd block does for that attention's residuals in the
+    backward (``GPTLM.attn_residuals``: "saved" with the bytes a layer
+    keeps on a device, "recomputed", or null where nothing is
+    rematerialised), read under the trainer's mesh as the step is traced.
+    The fall-back from one form to the next is silent and costs a tenth of
+    a step, and with it goes the saving, so a run's trace says which it
+    got.  Empty for a model that has no such choice."""
     ask = getattr(wl.model, "flash_layout", None)
     ids = wl.init_batch.get("input_ids")
     if ask is None or ids is None:
         return {}
     with jax.sharding.set_mesh(mesh):
         layout = ask(ids.shape[1])
+        residuals, kept = wl.model.attn_residuals(
+            wl.global_batch_size, ids.shape[1])
     if layout is None:
         return {}
     logging.info("flash_layout: %s", layout)
-    return {"flash_layout": layout}
+    logging.info("attn_residuals: %s (%s bytes a layer)", residuals, kept)
+    return {"flash_layout": layout, "attn_residuals": residuals,
+            "attn_residual_bytes_per_layer": kept}
 
 
 def main() -> None:
