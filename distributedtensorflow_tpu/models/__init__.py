@@ -20,6 +20,8 @@ from .afmoe import (  # noqa: F401
 )
 from .joyai import (  # noqa: F401
     JoyaiConfig,
+    glm5_ep16,
+    glm5_tiny,
     joyai_llm_flash,
     joyai_tiny,
 )

@@ -22,7 +22,22 @@ model width, ``H`` heads, RMSNorm everywhere, no biases, pre-norm blocks:
   top ``k`` of ``s + b`` (``b`` selects only; one group: ``n_group > 1`` is
   refused, not guessed), weights ``s[top] / (sum + 1e-20) * route_scale``,
   ``y = Shared(h) + sum_j w_j Expert_top_j(h)``: ``parallel.moe.dropless_moe``,
-  the one expert layer of both routed families, every expert held.
+  the one expert layer of both routed families, over the experts held here
+  (``experts_held`` from ``expert_first``; all of them by default: a chip's
+  share of an expert-parallel layer computes its own experts' terms of the
+  sum, the router as wide as published);
+- with ``index_topk`` > 0 (GLM-5, ``model_type glm_moe_dsa``: the published
+  DeepSeek sparse attention) each attention layer has an *indexer*: index
+  queries ``qI = c_q W_qI`` -> ``index_heads x index_head_dim``, ONE index key
+  a token ``kI = LayerNorm(h W_kI)`` (weight and bias, eps 1e-6) shared by
+  the index heads, rotary (the layer's ``rope_theta``, pairs interleaved) on
+  the first ``qk_rope_head_dim`` values of both, head weights ``w = (h W_w) *
+  index_heads ** -0.5 * index_head_dim ** -0.5`` in float32; score ``I(t, s)
+  = sum_j w_tj relu(qI_tj . kI_s)``; a query attends the ``min(index_topk, t
+  + 1)`` positions of largest score, and the softmax above runs over those
+  alone.  (The published inference code also rotates ``qI`` and ``kI`` by a
+  Hadamard matrix and stores ``kI`` in fp8: the rotation is orthonormal and
+  leaves every product as it is, so it is left out, and keys are bfloat16.)
 
 **What is cached** a token a layer is ``[c_kv | k_rope]`` (:attr:`JoyaiConfig
 .cache_rows`, an ``ops.attention.LatentRows``): ``kv_lora_rank + rope``
@@ -30,7 +45,10 @@ values, 1,152 bytes at the published widths where 32 heads of K and V would
 be 16,384.  The block hands ``attend((q_nope, q_rope), row, w_uk=, w_uv=)``
 the row to store and the queries in two parts, and gets ``(T, H, v)`` back:
 the caller owns where rows live and whether it absorbs ``W_UK`` into the
-query (``ops.attention``, "Latent attention").  Parameters are a plain tree
+query (``ops.attention``, "Latent attention").  With an indexer the cached
+rows are two, the latent row and ``kI`` after norm and rotation (an
+``ops.attention.SparseLatentRows``: a second pool of the same group), and the
+block calls ``attend((q_nope, q_rope, qI, w), row, kI, w_uk=, w_uv=)``.  Parameters are a plain tree
 of arrays created in bfloat16, the router in float32, as ``models.afmoe``'s.
 """
 
@@ -42,13 +60,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import LatentRows
+from ..ops.attention import LatentRows, SparseLatentRows
 from ..parallel.moe import dropless_moe
 from .afmoe import _uniform, rms_norm, swiglu
 from .gpt import rope
 
-__all__ = ["JoyaiConfig", "joyai_tiny", "joyai_llm_flash", "init_params",
-           "block", "embed", "head"]
+__all__ = ["JoyaiConfig", "joyai_tiny", "joyai_llm_flash", "glm5_tiny",
+           "glm5_ep16", "init_params", "block", "embed", "head"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,22 +95,38 @@ class JoyaiConfig:
     dtype: jnp.dtype = jnp.bfloat16
     #: "auto" = the Pallas kernels on a TPU, the plain formulations elsewhere
     kernel_impl: str = "auto"
+    #: the experts whose weights are held here, of the ``num_experts`` the
+    #: router scores: ``experts_held`` from ``expert_first`` (None = all)
+    experts_held: int | None = None
+    expert_first: int = 0
+    #: the indexer (module docstring): on where ``index_topk`` > 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.n_group != 1 or self.topk_group != 1:
             raise ValueError(
                 "group-limited routing (n_group > 1) is not implemented: "
                 "parallel.moe.sigmoid_topk_route selects over one group")
+        if self.index_topk and self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError("the indexer rotates the first qk_rope_head_dim "
+                             "values of an index head: index_head_dim is "
+                             "smaller")
 
     @property
     def held(self) -> tuple[int, int]:
-        return (0, self.num_experts)
+        return (self.expert_first, self.experts_held or self.num_experts)
 
     @property
     def cache_rows(self) -> LatentRows:
-        return LatentRows(
+        kw = dict(
             rank=self.kv_lora_rank, rope_dim=self.qk_rope_head_dim,
             scale=(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5)
+        if self.index_topk:
+            return SparseLatentRows(index_dim=self.index_head_dim,
+                                    topk=self.index_topk, **kw)
+        return LatentRows(**kw)
 
     def window_of(self, layer: int) -> None:
         return None
@@ -123,6 +157,32 @@ def joyai_llm_flash() -> JoyaiConfig:
         route_scale=2.5, max_seq=16384)
 
 
+def glm5_tiny(**kw) -> JoyaiConfig:
+    """CPU tests only: the family with its indexer on (``index_topk`` 24,
+    below the tests' contexts), two leading dense layers and half of the
+    experts held, from the fifth."""
+    return joyai_tiny(**{**dict(
+        num_layers=4, num_dense_layers=2, experts_held=8, expert_first=4,
+        index_heads=4, index_head_dim=16, index_topk=24,
+        rms_norm_eps=1e-5), **kw})
+
+
+def glm5_ep16() -> JoyaiConfig:
+    """GLM-5 (``model_type glm_moe_dsa``) at its published widths as one
+    chip's share of a 16-chip expert-parallel deployment: one dense layer
+    and four expert layers, 16 of the 256 experts of each (the router 256
+    wide, top 8), an eighth of the vocabulary
+    (``benchmark/configs/glm5-ep16-serve.json``)."""
+    return JoyaiConfig(
+        vocab_size=19360, hidden_size=6144, num_heads=64, q_lora_rank=2048,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64,
+        v_head_dim=256, intermediate_size=12288, moe_intermediate_size=2048,
+        num_experts=256, experts_per_token=8, experts_held=16,
+        expert_first=0, num_layers=5, num_dense_layers=1, rope_theta=1e6,
+        rms_norm_eps=1e-5, route_scale=2.5, max_seq=33792, index_heads=32,
+        index_head_dim=128, index_topk=2048)
+
+
 # -- parameters --------------------------------------------------------------
 
 def init_params(cfg: JoyaiConfig, key, std: float = 0.02):
@@ -131,7 +191,7 @@ def init_params(cfg: JoyaiConfig, key, std: float = 0.02):
     router held in float32)."""
     d, dt, h = cfg.hidden_size, cfg.dtype, cfg.num_heads
     rank, rope_dim = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    m, e = cfg.moe_intermediate_size, cfg.num_experts
+    m, e, held = cfg.moe_intermediate_size, cfg.num_experts, cfg.held[1]
     counter = iter(range(1 << 30))
 
     def draw(shape, dtype=dt, scale=std):
@@ -159,6 +219,14 @@ def init_params(cfg: JoyaiConfig, key, std: float = 0.02):
                  "w_uk": draw((rank, h, cfg.qk_nope_head_dim)),
                  "w_uv": draw((rank, h, cfg.v_head_dim)),
                  "w_o": draw((h * cfg.v_head_dim, d))}}
+        if cfg.index_topk:
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            p["attn"]["indexer"] = {
+                "w_q": draw((cfg.q_lora_rank, hi * di)),
+                "w_k": draw((d, di)),
+                "k_norm": norm(di), "k_bias": draw((di,), scale=0.05),
+                # float32 like the router: the head weights are formed in it
+                "w_w": draw((d, hi)).astype(jnp.float32)}
         if i < cfg.num_dense_layers:
             p["mlp"] = ffn(cfg.intermediate_size)
         else:
@@ -166,8 +234,9 @@ def init_params(cfg: JoyaiConfig, key, std: float = 0.02):
                 "router": draw((d, e)).astype(jnp.float32),
                 "bias": draw((e,), jnp.float32, 0.05),
                 "shared": ffn(m),
-                "experts": {"w_gate": draw((e, d, m)), "w_up": draw((e, d, m)),
-                            "w_down": draw((e, m, d))}}
+                "experts": {"w_gate": draw((held, d, m)),
+                            "w_up": draw((held, d, m)),
+                            "w_down": draw((held, m, d))}}
         params[f"h{i}"] = p
     params["ln_f"] = norm(d)
     params["head"] = draw((d, cfg.vocab_size))
@@ -188,9 +257,38 @@ def rope_interleaved(x, positions, theta: float):
     return rope(x[None], positions[None], theta)[0]
 
 
+def _rope_leading(x, positions, cfg: JoyaiConfig):
+    """Interleaved rotary on the first ``qk_rope_head_dim`` values of each
+    head of ``x`` (T, H, D), the others as they are."""
+    r = cfg.qk_rope_head_dim
+    return jnp.concatenate(
+        [rope_interleaved(x[..., :r], positions, cfg.rope_theta),
+         x[..., r:]], axis=-1)
+
+
+def index_inputs(p, h, c_q, cfg: JoyaiConfig, positions):
+    """The indexer's side of a layer: ``qI`` (T, index_heads, index_head_dim)
+    rotated, the float32 head weights (T, index_heads), and the index key to
+    cache (T, index_head_dim), after its LayerNorm and rotation."""
+    t, hi = h.shape[0], cfg.index_heads
+    with jax.named_scope("indexer"):
+        q = _rope_leading(jnp.dot(c_q, p["w_q"]).reshape(t, hi, -1),
+                          positions, cfg)
+        k = jnp.dot(h, p["w_k"]).astype(jnp.float32)
+        k = k - k.mean(-1, keepdims=True)
+        k = k * jax.lax.rsqrt((k * k).mean(-1, keepdims=True) + 1e-6)
+        k = (k * p["k_norm"].astype(jnp.float32)
+             + p["k_bias"].astype(jnp.float32)).astype(h.dtype)
+        k = _rope_leading(k[:, None], positions, cfg)[:, 0]
+        w = jnp.dot(h.astype(jnp.float32), p["w_w"]) \
+            * (hi ** -0.5 * cfg.index_head_dim ** -0.5)
+    return q, w, k
+
+
 def latent_inputs(p, h, cfg: JoyaiConfig, positions):
     """``h`` (T, d) -> ``q_nope`` (T, H, nope), ``q_rope`` (T, H, rope)
-    rotated, and the row to cache (T, rank + rope): ``[c_kv | k_rope]``."""
+    rotated, the row to cache (T, rank + rope): ``[c_kv | k_rope]``, and the
+    query latent ``c_q`` (T, q_lora_rank) after its norm."""
     t = h.shape[0]
     eps, rank = cfg.rms_norm_eps, cfg.kv_lora_rank
     nope = cfg.qk_nope_head_dim
@@ -207,7 +305,7 @@ def latent_inputs(p, h, cfg: JoyaiConfig, positions):
         pad = cfg.cache_rows.widths[0] - row.shape[-1]
         if pad:
             row = jnp.pad(row, ((0, 0), (0, pad)))
-    return q[..., :nope], q_rope, row
+    return q[..., :nope], q_rope, row, c_q
 
 
 def block(p, x, cfg: JoyaiConfig, layer: int, positions, attend,
@@ -221,8 +319,14 @@ def block(p, x, cfg: JoyaiConfig, layer: int, positions, attend,
         h = rms_norm(x, p["ln_attn"], eps)
     with jax.named_scope("latent_attn"):
         a = p["attn"]
-        q_nope, q_rope, row = latent_inputs(a, h, cfg, positions)
-        o = attend((q_nope, q_rope), row, w_uk=a["w_uk"], w_uv=a["w_uv"])
+        q_nope, q_rope, row, c_q = latent_inputs(a, h, cfg, positions)
+        if cfg.index_topk:
+            q_index, w_index, index_key = index_inputs(
+                a["indexer"], h, c_q, cfg, positions)
+            o = attend((q_nope, q_rope, q_index, w_index), row, index_key,
+                       w_uk=a["w_uk"], w_uv=a["w_uv"])
+        else:
+            o = attend((q_nope, q_rope), row, w_uk=a["w_uk"], w_uv=a["w_uv"])
         with jax.named_scope("out_proj"):
             x = x + jnp.dot(o.reshape(x.shape[0], -1).astype(x.dtype),
                             a["w_o"])

@@ -884,7 +884,10 @@ def _plain_latent_chunk(q_nope, q_rope, start, pool, table_row, *, w_uk, w_uv,
 def _latent_chunk_kernel(table_ref, start_ref, layer_ref, qn_ref, qr_ref,
                          wk_ref, wv_ref, pool_hbm, o_ref, buf, sem, q_sc,
                          wk_sc, wv_sc, k_sc, v_sc, m_sc, l_sc, acc_sc, *,
-                         block_size, rank, q_tile, scale):
+                         block_size, rank, q_tile, scale, bias=None):
+    """``bias`` = (the (T, S) float32 array in HBM, its two VMEM buffers of
+    (T, stretch), their semaphores): 0 where a query attends a row,
+    ``NEG_INF`` where it does not (a selection; the causal mask is in it)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -928,14 +931,22 @@ def _latent_chunk_kernel(table_ref, start_ref, layer_ref, qn_ref, qr_ref,
                 buf.at[slot, pl.ds(j * block_size, block_size)],
                 sem.at[slot, j])
             pl.when(b0 < end)(functools.partial(go, cp))
+        if bias is not None:
+            go(pltpu.make_async_copy(
+                bias[0].at[:, pl.ds(pl.multiple_of(c * stretch, stretch),
+                                    stretch)],
+                bias[1].at[slot], bias[2].at[slot]))
 
-    def fold(h, i, first, masked):
+    def fold(h, i, first, masked, slot=None):
         """Query tile ``i`` of head ``h`` against the decompressed stretch."""
         rows = pl.ds(pl.multiple_of(i * q_tile, q_tile), q_tile)
         s = jax.lax.dot_general(
             q_sc[h, rows, :], k_sc[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (q_tile, stretch)
-        if masked:
+        if bias is not None:
+            ok = bias[1][slot, rows, :] == 0.0
+            s = jnp.where(ok, s, NEG_INF)
+        elif masked:
             qpos = start + i * q_tile + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             ok = first + jax.lax.broadcasted_iota(
@@ -946,7 +957,7 @@ def _latent_chunk_kernel(table_ref, start_ref, layer_ref, qn_ref, qr_ref,
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - jnp.concatenate([m_new] * (stretch // LANES), axis=1))
-        if masked:
+        if masked or bias is not None:
             p = jnp.where(ok, p, 0.0)
         l_sc[h, rows, :] = alpha * l_sc[h, rows, :] \
             + p.sum(axis=1, keepdims=True)
@@ -973,9 +984,9 @@ def _latent_chunk_kernel(table_ref, start_ref, layer_ref, qn_ref, qr_ref,
                     # a tile whose last query precedes the stretch attends
                     # none of it
                     pl.when(first <= start + (i + 1) * q_tile - 1)(
-                        lambda: fold(h, i, first, True))
+                        lambda: fold(h, i, first, True, slot))
                 else:
-                    fold(h, i, first, False)
+                    fold(h, i, first, False, slot)
 
             jax.lax.fori_loop(0, t // q_tile, tile, None)
 
@@ -1027,11 +1038,14 @@ def _latent_chunk_heads(heads: int, chunk: int, nope: int, rope: int,
     "heads", "block_size", "rank", "stretch", "heads_step", "q_tile",
     "scale", "interpret"))
 def _latent_chunk_call(table_row, start, layer, q_nope, q_rope, wk, wv, pool,
-                       *, heads, block_size, rank, stretch, heads_step,
-                       q_tile, scale, interpret):
+                       bias=None, *, heads, block_size, rank, stretch,
+                       heads_step, q_tile, scale, interpret):
     """The chunk kernel's call: a jitted function of its own with the layer
     as a prefetched scalar, so the layers of a program share one lowering.
-    The operands are 2-D as the model holds them, ``heads`` side by side."""
+    The operands are 2-D as the model holds them, ``heads`` side by side.
+    With ``bias`` (T, S) float32 — 0 where a query attends a row, ``NEG_INF``
+    where not — the kernel walks the same stretches and attends under it
+    (``name="masked_latent_chunk_attn"``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1044,15 +1058,31 @@ def _latent_chunk_call(table_row, start, layer, q_nope, q_rope, wk, wv, pool,
     def a_step(rows, lanes):
         return pl.BlockSpec((rows, g * lanes), lambda i, *_: (0, i))
 
+    kernel = functools.partial(
+        _latent_chunk_kernel, block_size=block_size, rank=rank,
+        q_tile=q_tile, scale=scale)
+    operands = (q_nope, q_rope, wk, wv, pool)
+    hbm, more = [pl.BlockSpec(memory_space=pl.ANY)], []
+    if bias is not None:
+        def kernel(*refs, plain=kernel):
+            # (3 scalars, 4 blocks, the pool, the bias, the output, the ten
+            # scratch buffers, the bias's two)
+            *most, bias_buf, bias_sem = refs
+            return plain(*most[:8], *most[9:],
+                         bias=(most[8], bias_buf, bias_sem))
+
+        operands += (bias,)
+        hbm = hbm * 2
+        more = [pltpu.VMEM((2, t, stretch), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,))]
     return pl.pallas_call(
-        functools.partial(
-            _latent_chunk_kernel, block_size=block_size, rank=rank,
-            q_tile=q_tile, scale=scale),
-        name="latent_chunk_attn",
+        kernel,
+        name="latent_chunk_attn" if bias is None
+        else "masked_latent_chunk_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(heads // g,),
             in_specs=[a_step(t, nope), a_step(t, rope), a_step(rank, nope),
-                      a_step(rank, v), pl.BlockSpec(memory_space=pl.ANY)],
+                      a_step(rank, v), *hbm],
             out_specs=a_step(t, v),
             scratch_shapes=[
                 pltpu.VMEM((2, stretch, width), pool.dtype),
@@ -1065,13 +1095,14 @@ def _latent_chunk_call(table_row, start, layer, q_nope, q_rope, wk, wv, pool,
                 pltpu.VMEM((g, t, LANES), jnp.float32),
                 pltpu.VMEM((g, t, LANES), jnp.float32),
                 pltpu.VMEM((g, t, v), jnp.float32),
+                *more,
             ]),
         out_shape=jax.ShapeDtypeStruct((t, heads * v), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=LATENT_CHUNK_VMEM),
         interpret=interpret,
-    )(table_row, start, layer, q_nope, q_rope, wk, wv, pool)
+    )(table_row, start, layer, *operands)
 
 
 def paged_latent_chunk_formulation(block_size: int, width: int, rank: int,
@@ -1103,6 +1134,7 @@ def paged_latent_chunk_attention(
     scale: float,
     impl: str = "auto",
     interpret: bool | None = None,
+    bias: jax.Array | None = None,
 ) -> jax.Array:
     """Chunk-prefill latent attention of one slot against its latent pages
     (the chunk's own rows already written), ``(T, H, v)``: the context up to
@@ -1150,12 +1182,18 @@ def paged_latent_chunk_attention(
         def flat(x, to):
             return _pad_lanes(x, to).reshape(x.shape[0], h * to)
 
+        if bias is not None:
+            # whole stretches of it (nothing at the cells' context)
+            lanes = table_row.shape[0] * block_size
+            bias = jnp.pad(
+                bias, ((0, 0), (0, -lanes % LATENT_STRETCH)),
+                constant_values=NEG_INF)
         out = _latent_chunk_call(
             table_row.astype(jnp.int32),
             jnp.reshape(start, (1,)).astype(jnp.int32),
             jnp.full((1,), layer, jnp.int32), flat(q_nope, nope_p),
             flat(q_rope, rope_p), flat(w_uk, nope_p), flat(w_uv, v_p), pool,
-            heads=h, block_size=block_size, rank=rank,
+            bias, heads=h, block_size=block_size, rank=rank,
             stretch=LATENT_STRETCH, heads_step=g,
             q_tile=min(t, LATENT_CHUNK_QUERIES), scale=scale,
             interpret=interpret)
@@ -1356,6 +1394,396 @@ def paged_latent_decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# Sparse latent attention: a learned indexer picks the rows a query attends
+# ---------------------------------------------------------------------------
+#
+# A latent layer with an indexer (DeepSeek sparse attention, ``models.joyai``
+# with ``index_topk``) caches, beside the latent row, one *index key* a token
+# (``index_dim`` values, shared by the indexer's heads) in a second pool of
+# the same group.  A query at position ``t`` scores every cached key of its
+# slot, ``I(t, s) = sum_j w_tj relu(qI_tj . kI_s)`` in float32, keeps the
+# ``min(topk, t + 1)`` positions of largest score (ties to the lowest
+# position: ``lax.top_k``'s order) and attends those rows of the latent pool
+# alone, in the absorbed form (every head reads the gathered row as it lies).
+# One formulation serves a prefill chunk (``T`` queries of one slot) and a
+# decode step (one query a slot): queries ``(N, ...)``, each with the page
+# table row of its slot.  Where nothing past ``topk`` is cached the selection
+# is every row, and a prefill chunk takes the dense formulation above.
+
+#: index keys a step of the indexer kernel scores, and the plain loop's
+#: stretch: the (queries, heads, stretch) float32 products of a stretch are
+#: all the plain loop holds at a time
+INDEX_STRETCH = 512
+#: queries of a prefill chunk a step of the indexer kernel holds
+INDEX_QUERIES = 256
+#: queries whose gathered rows are held at a time (128 x 2048 rows of 1,280 B
+#: are 335 MB)
+SPARSE_QUERIES = 128
+
+
+def _plain_index_scores(q, w, keys):
+    """``sum_j w[n, t, j] * relu(q[n, t, j] . keys[n, s])`` in float32, ``(N,
+    T, S)``: a loop over stretches of ``INDEX_STRETCH`` keys."""
+    n, t, _, _ = q.shape
+    s = keys.shape[1]
+    stretch = min(s, INDEX_STRETCH)
+    parts = -(-s // stretch)
+    keys = jnp.pad(keys, ((0, 0), (0, parts * stretch - s), (0, 0)))
+
+    def one(ks):                            # (N, stretch, D)
+        dots = jnp.einsum("nthd,nsd->nths", q, ks,
+                          preferred_element_type=jnp.float32)
+        return (jnp.maximum(dots, 0.0) * w[..., None]).sum(2)
+
+    out = jax.lax.map(one, keys.reshape(n, parts, stretch, -1)
+                      .transpose(1, 0, 2, 3))   # (parts, N, T, stretch)
+    return out.transpose(1, 2, 0, 3).reshape(n, t, parts * stretch)[..., :s]
+
+
+def _index_chunk_kernel(end_ref, q_ref, w_ref, k_ref, o_ref, *, heads):
+    """A tile of a chunk's queries against a stretch of keys: a head at a
+    time, ``(queries, D) x (D, stretch)`` on the MXU, the weighted sum of the
+    rectified products in float32.  A stretch past the chunk's last query
+    scores nothing (zeros: the selection masks it)."""
+    from jax.experimental import pallas as pl
+
+    stretch = k_ref.shape[0]
+    dim = k_ref.shape[1]
+
+    @pl.when(pl.program_id(1) * stretch >= end_ref[0])
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(pl.program_id(1) * stretch < end_ref[0])
+    def _():
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
+        for h in range(heads):
+            dots = jax.lax.dot_general(
+                q_ref[:, h * dim:(h + 1) * dim], k_ref[...],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            acc = acc + w_ref[:, h:h + 1] * jnp.maximum(dots, 0.0)
+        o_ref[...] = acc
+
+
+def _index_step_kernel(lens_ref, q_ref, w_ref, k_ref, o_ref):
+    """One query a slot: its heads are the rows of one ``(heads, D) x (D,
+    stretch)`` product.  A stretch past the slot's rows scores nothing."""
+    from jax.experimental import pallas as pl
+
+    stretch = k_ref.shape[1]
+    live = pl.program_id(1) * stretch < lens_ref[pl.program_id(0)]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _():
+        dots = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (heads, stretch)
+        o_ref[0] = (w_ref[0] * jnp.maximum(dots, 0.0)).sum(
+            axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _index_scores_call(lens, q, w, keys, *, interpret):
+    """The indexer kernel's call (``name="index_scores"``): ``q`` (N, T, H,
+    D), ``w`` (N, T, H) float32, ``keys`` (N, S, D) and ``lens`` (N,), the
+    rows of ``keys`` that hold a key some query of the slot may attend."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, t, heads, dim = q.shape
+    s = keys.shape[1]
+    stretch = INDEX_STRETCH
+    if t == 1:
+        return pl.pallas_call(
+            _index_step_kernel, name="index_scores",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(n, s // stretch),
+                in_specs=[
+                    pl.BlockSpec((1, heads, dim), lambda b, c, *_: (b, 0, 0)),
+                    pl.BlockSpec((1, heads, 1), lambda b, c, *_: (b, 0, 0)),
+                    # a stretch past the slot's rows is not fetched: the
+                    # block index stays at the last live one
+                    pl.BlockSpec((1, stretch, dim), lambda b, c, lens: (
+                        b, jnp.minimum(c, (lens[b] - 1) // stretch), 0))],
+                out_specs=pl.BlockSpec((1, 1, stretch),
+                                       lambda b, c, *_: (b, 0, c))),
+            out_shape=jax.ShapeDtypeStruct((n, 1, s), jnp.float32),
+            interpret=interpret,
+        )(lens, q[:, 0], w[:, 0, :, None], keys)
+    tile = min(t, INDEX_QUERIES)
+    return pl.pallas_call(
+        functools.partial(_index_chunk_kernel, heads=heads),
+        name="index_scores",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(t // tile, s // stretch),
+            in_specs=[
+                pl.BlockSpec((tile, heads * dim), lambda i, c, *_: (i, 0)),
+                pl.BlockSpec((tile, heads), lambda i, c, *_: (i, 0)),
+                pl.BlockSpec((stretch, dim), lambda i, c, end: (
+                    jnp.minimum(c, (end[0] - 1) // stretch), 0))],
+            out_specs=pl.BlockSpec((tile, stretch), lambda i, c, *_: (i, c))),
+        out_shape=jax.ShapeDtypeStruct((t, s), jnp.float32),
+        interpret=interpret,
+    )(lens, q[0].reshape(t, heads * dim), w[0], keys[0])[None]
+
+
+def index_formulation(queries: int, slots: int, rows: int, dim: int,
+                      impl: str = "auto") -> str:
+    """Which formulation :func:`index_scores` takes: ``"index_scores"`` (the
+    kernel) or ``"plain"``.  The kernel wants keys of whole lane tiles, a
+    context of whole stretches, and either one query a slot or one slot's
+    chunk of whole query tiles."""
+    fits = (dim % LANES == 0 and rows % INDEX_STRETCH == 0
+            and (queries == 1 or (slots == 1 and queries % 16 == 0 and
+                                  queries % min(queries, INDEX_QUERIES) == 0)))
+    return "index_scores" if use_kernel(impl) and fits else "plain"
+
+
+def index_scores(q, w, keys, lens, *, impl: str = "auto",
+                 interpret: bool | None = None):
+    """The indexer's scores ``(N, T, S)`` in float32 of queries ``q`` (N, T,
+    H, D) with head weights ``w`` (N, T, H) float32 against ``keys`` (N, S,
+    D): ``sum_j w_j relu(q_j . k)``.  ``lens`` (N,) bounds the rows worth
+    scoring (the kernel leaves zeros past them; the caller masks by position
+    in any case).  Scope ``indexer``."""
+    n, t, _, dim = q.shape
+    with jax.named_scope("indexer"):
+        if index_formulation(t, n, keys.shape[1], dim, impl) == "plain":
+            return _plain_index_scores(q, w, keys)
+        if interpret is None:
+            interpret = not on_tpu()
+        return _index_scores_call(lens.astype(jnp.int32), q, w, keys,
+                                  interpret=interpret)
+
+
+def select_rows(scores, counts, k: int):
+    """The ``k`` positions of largest ``scores`` (N, S) among each query's
+    first ``counts`` (N,), best first, ties to the lowest position
+    (``lax.top_k``); a query with fewer than ``k`` candidates gets them all,
+    and the rest of its ``k`` entries lie past ``counts`` (the attention
+    masks them: its second result is how many are real).  Scope
+    ``select``."""
+    with jax.named_scope("select"):
+        s = scores.shape[-1]
+        ok = jnp.arange(s, dtype=jnp.int32)[None, :] < counts[:, None]
+        # a sum of rectified products may be -0.0, which orders below 0.0
+        # as bits and equal to it as a number: one zero
+        scores = jnp.where(scores == 0.0, 0.0, scores)
+        _, pos = jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), k)
+        return pos.astype(jnp.int32), jnp.minimum(counts, k).astype(jnp.int32)
+
+
+#: queries a step of the selection kernel holds, their scores resident
+SELECT_QUERIES = 8
+_INT_MIN = -2 ** 31
+
+
+def _plain_select_bias(scores, counts, k: int):
+    """``lax.top_k`` and a scatter: the selection kernel's yardstick."""
+    pos, real = select_rows(scores, counts, k)
+    ok = jnp.arange(k, dtype=jnp.int32)[None, :] < real[:, None]
+    n = scores.shape[0]
+    picked = jnp.zeros(scores.shape, bool).at[
+        jnp.arange(n)[:, None], pos].max(ok)
+    return jnp.where(picked, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def _select_kernel(c_ref, s_ref, o_ref, key_sc, *, k):
+    """The exact top ``k`` of each row's first ``counts`` scores without a
+    sort: the scores as order-preserving integers, the ``k``-th largest found
+    a bit at a time by counting (32 passes over the resident rows), then,
+    among the entries equal to it, the position up to which they are taken
+    (ties to the lowest position: one more bisection, over positions)."""
+    x = s_ref[...]
+    x = jnp.where(x == 0.0, 0.0, x)                     # -0.0 -> 0.0
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    counts = c_ref[...]                                 # (rows, 1)
+    key_sc[...] = jnp.where(pos < counts, key, jnp.int32(_INT_MIN))
+    want = jnp.minimum(counts, k).astype(jnp.float32)
+
+    def count(hit):
+        return jnp.where(hit, 1.0, 0.0).sum(axis=1, keepdims=True)
+
+    def score_bit(b, theta):
+        # theta: the threshold's bits in the unsigned order, built from
+        # the top; the largest value with at least ``want`` keys >= it
+        cand = theta | (jnp.int32(1) << (31 - b))
+        enough = count(key_sc[...] >= (cand ^ jnp.int32(_INT_MIN))) >= want
+        return jnp.where(enough, cand, theta)
+
+    theta = jax.lax.fori_loop(
+        0, 32, score_bit, jnp.zeros(counts.shape, jnp.int32)) \
+        ^ jnp.int32(_INT_MIN)
+    above = key_sc[...] > theta
+    left = want - count(above)          # of the equal ones, this many: >= 1
+    top = max(1, (key.shape[1] - 1).bit_length())
+
+    def position_bit(b, p):
+        # the largest p with fewer than ``left`` equal keys before it: the
+        # position of the last one taken
+        cand = p | (jnp.int32(1) << (top - 1 - b))
+        few = count((key_sc[...] == theta) & (pos < cand)) < left
+        return jnp.where(few, cand, p)
+
+    last = jax.lax.fori_loop(
+        0, top, position_bit, jnp.zeros(counts.shape, jnp.int32))
+    picked = above | ((key_sc[...] == theta) & (pos <= last))
+    o_ref[...] = jnp.where(picked, 0.0, NEG_INF)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def _select_bias_call(scores, counts, *, k, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, s = scores.shape
+    rows = SELECT_QUERIES
+    return pl.pallas_call(
+        functools.partial(_select_kernel, k=k), name="select_rows",
+        grid=(n // rows,),
+        in_specs=[pl.BlockSpec((rows, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((rows, s), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, s), lambda i: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((n, s), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(counts.reshape(n, 1).astype(jnp.int32), scores)
+
+
+def select_formulation(queries: int, impl: str = "auto") -> str:
+    """Which formulation :func:`select_bias` takes: ``"select_rows"`` (the
+    kernel: whole sublane tiles of queries) or ``"plain"`` (``lax.top_k``
+    and a scatter)."""
+    fits = queries % SELECT_QUERIES == 0
+    return "select_rows" if use_kernel(impl) and fits else "plain"
+
+
+def select_bias(scores, counts, k: int, *, impl: str = "auto",
+                interpret: bool | None = None):
+    """The selection of :func:`select_rows` as a bias ``(N, S)`` float32: 0
+    at the ``min(k, counts)`` selected positions of each query, ``NEG_INF``
+    elsewhere (at and past ``counts`` always).  Scope ``select``."""
+    with jax.named_scope("select"):
+        n, s = scores.shape
+        if select_formulation(n, impl) == "plain":
+            return _plain_select_bias(scores, counts, k)
+        if interpret is None:
+            interpret = not on_tpu()
+        # whole lane tiles of positions (nothing at the cells' context)
+        scores = jnp.pad(scores, ((0, 0), (0, -s % LANES)))
+        return _select_bias_call(scores, counts, k=k,
+                                 interpret=interpret)[:, :s]
+
+
+def _plain_sparse_latent(q, x, counts, *, rank, scale):
+    """``q`` (N, H, W) against each query's own gathered rows ``x`` (N, K,
+    W), of which the first ``counts`` are real: ``(N, H, rank)`` float32."""
+    s = jnp.einsum("nhw,nkw->nhk", q, x,
+                   preferred_element_type=jnp.float32) * scale
+    ok = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+          < counts[:, None])[:, None]
+    p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+    return jnp.einsum("nhk,nkr->nhr", p.astype(q.dtype), x[..., :rank],
+                      preferred_element_type=jnp.float32)
+
+
+def _sparse_latent_kernel(counts_ref, q_ref, x_ref, o_ref, *, rank, scale):
+    from jax.experimental import pallas as pl
+
+    x = x_ref[0]                                          # (K, W)
+    s = jax.lax.dot_general(q_ref[0], x, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    ok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+        < counts_ref[pl.program_id(0)]
+    s = jnp.where(ok, s, NEG_INF)
+    p = jnp.where(ok, jnp.exp(s - s.max(axis=1, keepdims=True)), 0.0)
+    inv = 1.0 / jnp.maximum(p.sum(axis=1, keepdims=True), 1e-30)
+    pv = p.astype(x.dtype)
+    for tile in range(rank // LANES):
+        lanes = pl.ds(tile * LANES, LANES)
+        o_ref[0, :, lanes] = (jnp.dot(
+            pv, x_ref[0, :, lanes], preferred_element_type=jnp.float32)
+            * inv).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret"))
+def _sparse_latent_call(counts, q, x, *, rank, scale, interpret):
+    """The sparse kernel's call (``name="sparse_latent_attn"``): a query a
+    grid step, its heads the rows of one ``(H, W) x (W, K)`` product against
+    its own ``K`` gathered rows, the values the first ``rank`` lanes of the
+    same rows: no score goes to HBM."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, heads, width = q.shape
+    k = x.shape[1]
+    return pl.pallas_call(
+        functools.partial(_sparse_latent_kernel, rank=rank, scale=scale),
+        name="sparse_latent_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n,),
+            in_specs=[pl.BlockSpec((1, heads, width), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec((1, k, width), lambda i, *_: (i, 0, 0))],
+            out_specs=pl.BlockSpec((1, heads, rank), lambda i, *_: (i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((n, heads, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(counts, q, x)
+
+
+def sparse_latent_formulation(width: int, rank: int, k: int,
+                              impl: str = "auto") -> str:
+    """Which formulation :func:`sparse_latent_attention` takes:
+    ``"sparse_latent_attn"`` (the kernel) or ``"plain"`` (the batched
+    products whose scores go through HBM).  The kernel wants rows and
+    ``c_kv`` of whole lane tiles and whole lane tiles of selected rows."""
+    fits = rank % LANES == 0 and width % LANES == 0 and k % LANES == 0
+    return "sparse_latent_attn" if use_kernel(impl) and fits else "plain"
+
+
+def sparse_latent_attention(q, pool, rows, counts, *, layer, rank, scale,
+                            impl: str = "auto",
+                            interpret: bool | None = None):
+    """Absorbed latent attention of queries ``q`` (N, H, W) each over its own
+    pool rows ``rows`` (N, K) (row ids of ``pool[layer]``, the first
+    ``counts`` real), ``(N, H, rank)`` float32.  The rows are gathered
+    ``SPARSE_QUERIES`` queries at a time.  Scope ``paged_attn``."""
+    n, k = rows.shape
+    form = sparse_latent_formulation(pool.shape[-1], rank, k, impl)
+    if interpret is None:
+        interpret = not on_tpu()
+
+    def some(args):
+        qs, rs, cs = args
+        x = pool[layer, rs]                               # (n, K, W)
+        if form == "plain":
+            return _plain_sparse_latent(qs, x, cs, rank=rank, scale=scale)
+        return _sparse_latent_call(cs, qs, x, rank=rank, scale=scale,
+                                   interpret=interpret)
+
+    with jax.named_scope("paged_attn"):
+        if n <= SPARSE_QUERIES or n % SPARSE_QUERIES:
+            return some((q, rows, counts))
+        parts = n // SPARSE_QUERIES
+        out = jax.lax.map(some, (
+            q.reshape(parts, SPARSE_QUERIES, *q.shape[1:]),
+            rows.reshape(parts, SPARSE_QUERIES, k),
+            counts.reshape(parts, SPARSE_QUERIES)))
+        return out.reshape(n, *out.shape[2:])
+
+
+# ---------------------------------------------------------------------------
 # What a cached row is: the forms a layer group's pools take
 # ---------------------------------------------------------------------------
 #
@@ -1455,3 +1883,118 @@ class LatentRows:
         self._no_window(window)
         return paged_latent_decode_attention(
             *q, *pools, tables, attend_lens, scale=self.scale, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseLatentRows(LatentRows):
+    """A latent row AND an index key a token, in two pools of one group; a
+    block calls ``attend((q_nope, q_rope, q_index, w_index), row, index_key,
+    w_uk=, w_uv=)``: ``q_index`` (T, index_heads, index_dim) and the float32
+    head weights ``w_index`` (T, index_heads) score the slot's cached keys,
+    the ``topk`` best positions are selected a query, and only those latent
+    rows are attended ("Sparse latent attention", above)."""
+
+    index_dim: int = 128
+    topk: int = 2048
+
+    @property
+    def values(self) -> tuple[int, ...]:
+        return (self.rank + self.rope_dim, self.index_dim)
+
+    @property
+    def widths(self) -> tuple[int, ...]:
+        return (*LatentRows.widths.fget(self), self.index_dim)
+
+    def _sparse(self, impl: str) -> str:
+        return sparse_latent_formulation(self.widths[0], self.rank,
+                                         self.topk, impl)
+
+    def decode_formulation(self, block_size: int, impl: str) -> str:
+        return self._sparse(impl)
+
+    def chunk_formulation(self, block_size: int, chunk: int,
+                          impl: str) -> str:
+        """The formulation past ``topk`` positions — the dense kernel under
+        the selection's mask where it and the selection kernel fit, else the
+        gathered rows — and, after a ``+``, the dense one that a chunk
+        ending before them takes."""
+        dense = LatentRows.chunk_formulation(self, block_size, chunk, impl)
+        if dense != "plain" and select_formulation(chunk, impl) != "plain":
+            return f"masked_{dense}+{dense}"
+        return f"{self._sparse(impl)}+{dense}"
+
+    def _gathered(self, q_nope, q_rope, scores, counts, pool, table_of, *,
+                  w_uk, w_uv, layer, block_size, impl):
+        """``N`` queries, query ``i`` selecting among the first ``counts[i]``
+        of its slot's ``scores[i]`` and attending those rows of the pool,
+        gathered by index; ``table_of(blocks)`` looks up its page table."""
+        pos, real = select_rows(scores, counts, min(self.topk,
+                                                    scores.shape[-1]))
+        rows = table_of(pos // block_size) * block_size + pos % block_size
+        o_lat = sparse_latent_attention(
+            _latent_queries(q_nope, q_rope, w_uk, pool.shape[-1]), pool,
+            rows, real, layer=layer, rank=self.rank, scale=self.scale,
+            impl=impl)
+        return _latent_values(o_lat, w_uv, q_nope.dtype)
+
+    @staticmethod
+    def _keys(index_pool, layer, tables, block_size):
+        """The index keys of every table column, ``(..., S, D)``."""
+        dim = index_pool.shape[-1]
+        with jax.named_scope("indexer"):
+            return index_pool.reshape(
+                index_pool.shape[0], -1, block_size,
+                dim)[layer, tables].reshape(*tables.shape[:-1], -1, dim)
+
+    def chunk(self, q, start, pools, table_row, *, window=None, layer,
+              block_size, impl="auto", w_uk, w_uv):
+        self._no_window(window)
+        pool, index_pool = pools
+        q_nope, q_rope, q_index, w_index = q
+        t = q_nope.shape[0]
+        kw = dict(w_uk=w_uk, w_uv=w_uv, layer=layer, block_size=block_size,
+                  impl=impl)
+
+        def dense(bias=None):
+            return paged_latent_chunk_attention(
+                q_nope, q_rope, start, pool, table_row, scale=self.scale,
+                bias=bias, **kw)
+
+        def sparse():
+            keys = self._keys(index_pool, layer, table_row, block_size)
+            scores = index_scores(
+                q_index[None], w_index[None], keys[None],
+                jnp.reshape(start + t, (1,)), impl=impl)[0]
+            counts = start + 1 + jnp.arange(t, dtype=jnp.int32)
+            if self.chunk_formulation(block_size, t, impl).startswith(
+                    "masked_"):
+                # a chunk's 2 M selected rows gathered by index cost 40 ms a
+                # layer whatever the context (PERF.md section 6, PR 39): the
+                # dense walk of the slot's pages under the selection's mask
+                # attends the same rows
+                return dense(select_bias(
+                    scores, counts, min(self.topk, scores.shape[-1]),
+                    impl=impl))
+            return self._gathered(q_nope, q_rope, scores, counts, pool,
+                                  lambda blocks: table_row[blocks], **kw)
+
+        if table_row.shape[0] * block_size <= self.topk:
+            return dense()
+        # every row is selected while none lies past topk: the same sum
+        return jax.lax.cond(start + t <= self.topk, dense, sparse)
+
+    def decode(self, q, pools, tables, attend_lens, *, window=None, layer,
+               block_size, impl="auto", w_uk, w_uv):
+        self._no_window(window)
+        pool, index_pool = pools
+        q_nope, q_rope, q_index, w_index = q
+        lens = attend_lens.astype(jnp.int32)
+        scores = index_scores(
+            q_index[:, None], w_index[:, None],
+            self._keys(index_pool, layer, tables, block_size), lens,
+            impl=impl)[:, 0]
+        return self._gathered(
+            q_nope, q_rope, scores, lens, pool,
+            lambda blocks: jnp.take_along_axis(tables, blocks, axis=1),
+            w_uk=w_uk, w_uv=w_uv, layer=layer, block_size=block_size,
+            impl=impl)

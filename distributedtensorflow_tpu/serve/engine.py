@@ -486,11 +486,14 @@ class Engine:
         self._step_evicted = 0     # requests finished in the current step
         #: the current step's (device_sampled, logits_fetched)
         self._step_sampled = (0, 0)
-        #: the current step's [context_tokens, latent_rows_read]: the rows a
-        #: prefill chunk's queries walk, summed over the step's chunks, and
-        #: the latent rows its decode iteration read (attended tokens x
-        #: latent layers); counted only where a group stores latent rows
-        self._step_latent = [0, 0]
+        #: the current step's [context_tokens, latent_rows_read,
+        #: index_rows_scored]: the rows a prefill chunk's queries walk,
+        #: summed over the step's chunks, the latent rows its decode
+        #: iteration read (attended tokens x latent layers: where an indexer
+        #: selects them, ``kv.index_topk`` a slot a layer at most) and the
+        #: index keys it scored to select them (every cached token x latent
+        #: layers); counted only where a group stores latent rows
+        self._step_latent = [0, 0, 0]
         #: the current step's tokens through the recurrence of a state
         #: group: the real tokens of its prefill chunks and one a decoding
         #: slot; counted only where a group keeps a state a slot
@@ -584,6 +587,14 @@ class Engine:
         self._m_spec_accepted = reg.counter(
             "serve_spec_accepted_total",
             "draft tokens accepted by the verifier (always <= drafted)")
+        if self.kv.index_topk:
+            self._m_latent_read = reg.counter(
+                "serve_latent_rows_read_total",
+                "latent rows the decode iterations attended: the indexer's "
+                "selection, index_topk a slot a layer at most")
+            self._m_index_scored = reg.counter(
+                "serve_index_rows_scored_total",
+                "index keys the decode iterations scored to select them")
         self._m_stream_lag = reg.histogram(
             "serve_stream_lag_seconds",
             "a streamed line: tokens committed -> socket write returned")
@@ -840,7 +851,7 @@ class Engine:
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
         self._step_sampled = (0, 0)
-        self._step_latent = [0, 0]
+        self._step_latent = [0, 0, 0]
         self._step_scan = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace) whose leaves tile it: a leaf begins where the one before
@@ -1095,11 +1106,13 @@ class Engine:
             fields.update(moe_pairs=pairs, moe_experts_hit=hit,
                           moe_max_load=load)
         if self.kv.latent_layers:
-            context, read = self._step_latent
+            context, read, scored = self._step_latent
             if context:
                 fields["context_tokens"] = context
             if occupancy:
                 fields["latent_rows_read"] = read
+                if self.kv.index_topk:
+                    fields["index_rows_scored"] = scored
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
@@ -1395,8 +1408,15 @@ class Engine:
         self._note_sampled(n_active - len(sampling), bool(sampling))
         self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
         if self.kv.latent_layers:
-            self._step_latent[1] = self.kv.latent_layers * int(
-                self.kv.seq_lens[slots].sum())
+            lens = self.kv.seq_lens[slots]
+            scored = self.kv.latent_layers * int(lens.sum())
+            self._step_latent[1] = scored
+            if self.kv.index_topk:
+                self._step_latent[1] = self.kv.latent_layers * int(
+                    np.minimum(lens, self.kv.index_topk).sum())
+                self._step_latent[2] = scored
+                self._m_latent_read.inc(self._step_latent[1])
+                self._m_index_scored.inc(scored)
         if self.kv.state is not None:
             self._step_scan += n_active
         self._commit_tokens(

@@ -885,6 +885,12 @@ class GroupedKVCache:
         self.latent_layers = sum(
             len(layers[name]) for name, g in self.paged.items()
             if g.rows.shared_row)
+        #: the rows a query of those layers attends at most, where an
+        #: indexer selects them (``ops.attention.SparseLatentRows``); None
+        #: where every cached row is attended
+        self.index_topk = next(
+            (g.rows.topk for g in self.paged.values()
+             if hasattr(g.rows, "topk")), None)
 
     @property
     def shares_prefixes(self) -> bool:

@@ -15,12 +15,15 @@ x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
 ``init_params(cfg, key)``.  Its
 configuration says what a cached row is (``cfg.cache_rows``, an
-``ops.attention.KVRows`` or ``LatentRows``: the widths of the group's pools
-and the paged formulations over them), and its block calls ``attend(q,
-*rows, **weights)`` with the rows to store, one a pool: ``attend(q, k, v)``
-where a token's K and V of all heads are cached, ``attend((q_nope, q_rope),
-row, w_uk=, w_uv=)`` where one latent row is and the query comes in two
-parts.  A program is the family's embedding, its blocks and its head, with an
+``ops.attention.KVRows``, ``LatentRows`` or ``SparseLatentRows``: the widths
+of the group's pools and the paged formulations over them), and its block
+calls ``attend(q, *rows, **weights)`` with the rows to store, one a pool:
+``attend(q, k, v)`` where a token's K and V of all heads are cached,
+``attend((q_nope, q_rope), row, w_uk=, w_uv=)`` where one latent row is and
+the query comes in two parts, ``attend((q_nope, q_rope, q_index, w_index),
+row, index_key, w_uk=, w_uv=)`` where an indexer selects the latent rows a
+query attends (joyai with ``index_topk``: GLM-5) and its key is cached in a
+second pool of the same group.  A program is the family's embedding, its blocks and its head, with an
 ``attend`` that writes the rows into the layer's group pools and reads the
 slot's pages back through the form — so the block is written once a family,
 and the three programs differ only in where the rows live:
@@ -84,7 +87,10 @@ medium's ``jit_decode`` 0.23 ms of 3.24 over 24 layers; my chip run, PR 30),
 under whatever scope the family's block calls ``attend`` in: ``h<i>`` for
 GPT-2, ``h<i>/window_attn`` or ``h<i>/full_attn`` for afmoe,
 ``h<i>/latent_attn`` for joyai, whose form adds ``absorb`` and ``v_up``
-beside them, ``h<i>/attn`` for jamba, whose Mamba layers have
+beside them and, with an indexer, ``indexer`` (the index projections in the
+block, the slot's keys gathered and scored in the form) and ``select`` (the
+top ``index_topk`` positions a query); ``kv_write`` then holds the index
+key's write too, ``h<i>/attn`` for jamba, whose Mamba layers have
 ``h<i>/{state_read,state_write}`` and ``h<i>/mamba/{in_proj,conv,x_proj,
 dt_proj,scan|ssm_step,gate,out_proj}``), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
@@ -455,7 +461,8 @@ PROGRAMS = {
 #: path's.  Another family's would run unchecked (afmoe's window layers not
 #: at all: ``paged_verify_attention`` masks no window; joyai's draft module,
 #: which predicts several tokens for self-speculation, is not built and its
-#: latent rows have no verify formulation), so it is refused until it has
+#: latent rows — with or without an index key beside them — have no verify
+#: formulation), so it is refused until it has
 #: such tests and a cell of its own.  Over a state group there is more in
 #: the way than tests: a rejected draft's steps cannot be rolled back out of
 #: a state, which keeps no earlier position (jamba).
@@ -501,12 +508,16 @@ class Programs:
     - ``decode_attention``: the formulation the decode programs in use
       attend the pages with, ``"paged_attn"`` or ``"paged_latent_attn"``
       (the kernel that reads only the blocks a slot holds, over K/V rows or
-      latent rows) or ``"plain"`` (the gather of every table column): the
-      fallback is silent, so the engine reports it (``Engine.state()``);
+      latent rows), ``"sparse_latent_attn"`` (the kernel over the rows an
+      indexer selected, gathered by index) or ``"plain"`` (the gather of
+      every table column; of the selected rows, their scores through HBM):
+      the fallback is silent, so the engine reports it (``Engine.state()``);
     - ``chunk_attention``: the same of ``prefill``: ``"latent_chunk_attn"``
-      (the kernel over latent rows that keeps a chunk's scores in VMEM) or
+      (the kernel over latent rows that keeps a chunk's scores in VMEM),
       ``"plain"`` (the loop whose scores go through HBM; all K/V rows
-      have);
+      have), or with an indexer ``"<sparse>+<dense>"``: the formulation of a
+      chunk that ends past ``index_topk`` and the one of a chunk that does
+      not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers
       with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM)
       or ``"plain"`` (``lax.scan``); None where no layer keeps a state."""

@@ -48,6 +48,9 @@ CONFIGS = {
     # the joyai family (models/joyai.py): latent attention, random init only
     "joyai_tiny": ("joyai_tiny", None),
     "joyai_llm_flash": ("joyai_llm_flash", None),
+    # the same family with its indexer on (GLM-5: learned sparse attention)
+    "glm5_tiny": ("glm5_tiny", None),
+    "glm5_ep16": ("glm5_ep16", None),
     # the jamba family (models/jamba.py): Mamba + attention layers, random init
     "jamba_tiny": ("jamba_tiny", None),
     "jamba2_3b": ("jamba2_3b", None),

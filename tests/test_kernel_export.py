@@ -26,6 +26,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributedtensorflow_tpu.ops.attention import (
+    index_scores,
+    select_bias,
+    sparse_latent_attention,
     _pallas_decode_attention,
     paged_latent_chunk_attention,
     paged_latent_decode_attention,
@@ -146,6 +149,55 @@ def _latent_chunk(chunk=1024, heads=32, rank=512, rope=64, nope=128,
                 _sds((rank, heads, nope), BF16))
 
 
+def _index(queries, slots, columns=2112, heads=32, dim=128):
+    # GLM-5's indexer: 32 index heads of 128 against one key a token,
+    # contexts to 33,792: a prefill chunk of one slot, or a query a slot
+    def fn(q, w, keys, lens):
+        return index_scores(q, w, keys, lens, impl="pallas",
+                            interpret=False)
+    return fn, (_sds((slots, queries, heads, dim), BF16),
+                _sds((slots, queries, heads), F32),
+                _sds((slots, columns * 16, dim), BF16),
+                _sds((slots,), jnp.int32))
+
+
+def _sparse_latent(queries, heads=64, k=2048, rank=512):
+    # GLM-5's sparse attention: 64 heads over each query's own 2048 rows of
+    # 640, gathered out of the cell's pool
+    def fn(q, pool, rows, counts):
+        return sparse_latent_attention(
+            q, pool, rows, counts, layer=1, rank=rank, scale=256 ** -0.5,
+            impl="pallas", interpret=False)
+    return fn, (_sds((queries, heads, 640), BF16),
+                _sds((5, 45057 * 16, 640), BF16),
+                _sds((queries, k), jnp.int32), _sds((queries,), jnp.int32))
+
+
+def _select(queries=1024, rows=33792, k=2048):
+    # GLM-5's selection: the top 2048 of a chunk's scores, as a bias
+    def fn(scores, counts):
+        return select_bias(scores, counts, k, impl="pallas", interpret=False)
+    return fn, (_sds((queries, rows), F32), _sds((queries,), jnp.int32))
+
+
+def _masked_latent_chunk(chunk=1024, heads=64, rank=512, rope=64, nope=192,
+                         v=256, columns=2112):
+    # a GLM-5 prefill chunk past index_topk: the dense walk of the slot's
+    # pages under the selection's bias, 64 heads of 192 + 64 / 256
+    def fn(q_nope, q_rope, start, pool, table_row, w_uk, w_uv, bias):
+        return paged_latent_chunk_attention(
+            q_nope, q_rope, start, pool, table_row, w_uk=w_uk, w_uv=w_uv,
+            layer=1, block_size=16, scale=(nope + rope) ** -0.5,
+            impl="pallas", interpret=False, bias=bias)
+    return fn, (_sds((chunk, heads, nope), BF16),
+                _sds((chunk, heads, rope), BF16), _sds((), jnp.int32),
+                _sds((5, 45057 * 16, 640), BF16),
+                _sds((columns,), jnp.int32),
+                _sds((rank, heads, nope), BF16),
+                _sds((rank, heads, v), BF16),
+                _sds((chunk, columns * 16), F32))
+
+
 def _ssm_scan(chunk=1024, channels=5120, states=16):
     # a jamba prefill chunk's scan of one Mamba layer at the published
     # channel shape: u' in bf16, delta float32, the state in and out
@@ -222,6 +274,12 @@ FAMILIES = {
     # 128 (24 rows a tile), a table of 2,112 columns (contexts to 33,792)
     "paged_attn_20_on_1": _paged(None, slots=32, heads=20, d=128,
                                  columns=2112, width=128),
+    "select_rows": _select(),
+    "masked_latent_chunk_attn": _masked_latent_chunk(),
+    "index_scores_chunk": _index(1024, 1),
+    "index_scores_step": _index(1, 24),
+    "sparse_latent_attn_chunk": _sparse_latent(1024),
+    "sparse_latent_attn_step": _sparse_latent(24),
     "ssm_chunk_scan": _ssm_scan(),
     "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
 }
@@ -464,6 +522,50 @@ def test_latent_program_keeps_the_pool_in_place_on_a_v5e(program,
         calls = re.findall(r"call @(\w*latent_chunk\w*)\(", text)
         assert len(calls) == 2 and len(set(calls)) == 1, calls
         assert text.count('kernel_name = "latent_chunk_attn"') == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode",
+                                     "copy_block"])
+def test_sparse_latent_program_keeps_both_pools_in_place_on_a_v5e(
+        program, monkeypatch):
+    """GLM-5 (the joyai family with its indexer on) at its published widths,
+    two layers deep with 8 experts held and a small vocabulary: its three
+    programs take the pool of latent rows AND the pool of index keys,
+    convert no layer of either outside ``paged_attn`` / ``indexer`` and hand
+    both back in place; the indexer, the selection and the sparse kernel are
+    in the lowered programs, each kernel lowered once.  (The pool is 335 MB:
+    one of 42 MB the compiler moves whole into the 128 MiB of VMEM and back,
+    which reads as a pool-sized copy.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import glm5_ep16
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        glm5_ep16(), max_seq=4096, num_layers=2, experts_held=8,
+        vocab_size=1024)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=8, num_blocks=8192, block_size=16, chunk=256, draft=4,
+        sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    for width in cfg.cache_rows.widths:         # 640, then 128
+        _, rows, _ = kv_cache.pool_shape(2, 8192, 16, width)
+        report = pool_check.check_pool_programs(
+            {program: programs[program]}, layer_elems=rows * width)
+        assert pool_check.failures(report, pools=2) == []
+    assert report[program]["k_pool"] == \
+        "bf16[2,131088,640]{2,1,0:T(8,128)(2,1)}"
+    if program != "copy_block":
+        fn, args = programs[program]
+        text = fn.lower(*args).as_text()
+        kernels = {"decode": ("index_scores", "sparse_latent_attn"),
+                   "prefill_chunk": ("index_scores", "select_rows",
+                                     "masked_latent_chunk_attn",
+                                     "latent_chunk_attn")}[program]
+        for kernel in kernels:
+            assert text.count(f'kernel_name = "{kernel}"') == 1, kernel
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
