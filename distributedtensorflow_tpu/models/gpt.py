@@ -539,13 +539,38 @@ class GPTLM(nn.Module):
             return None, None
         if layout != "qkv_tiles" or not cfg.remat:
             return "recomputed", 0
-        mesh = jax.sharding.get_abstract_mesh()
-        shards = math.prod(
-            mesh.shape[a]
-            for a in kernel_axes(mesh_lib.BATCH_AXES, batch) or ())
         per_token = cfg.num_heads * (
             cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4)
-        return "saved", batch // shards * seq * per_token
+        return "saved", self._kernel_batch(batch) * seq * per_token
+
+    @staticmethod
+    def _kernel_batch(batch: int) -> int:
+        """The rows of ``batch`` a shard of the flash kernels holds, read
+        under the context mesh (``kernel_axes``: a batch the axes do not
+        divide is replicated)."""
+        mesh = jax.sharding.get_abstract_mesh()
+        return batch // math.prod(
+            mesh.shape[a]
+            for a in kernel_axes(mesh_lib.BATCH_AXES, batch) or ())
+
+    def flash_causal_tile(self, batch: int, seq: int
+                          ) -> tuple[int | None, float | None]:
+        """``(T, share)``: the rows of the sub-tiles the tile kernels walk
+        a block on the causal diagonal in over ``(batch, seq)`` tokens, and
+        the share of such a block's square they compute
+        (``ops.flash_attention.causal_tile`` / ``causal_share`` at the
+        blocks the call resolves to: 256 and 0.625 at one 1024 x 1024
+        block).  ``(None, None)`` where the blocks are taken whole: another
+        form than ``"qkv_tiles"``, a block under two sub-tiles.  Beside
+        ``flash_layout`` in the trainer's start-up row: the mechanism is
+        static, so what says it engaged is a field."""
+        from ..ops.flash_attention import qkv_causal_tile
+
+        cfg = self.cfg
+        if self.flash_layout(seq) != "qkv_tiles":
+            return None, None
+        return qkv_causal_tile(self._kernel_batch(batch), seq, cfg.num_heads,
+                               cfg.head_dim, cfg.dtype)
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,

@@ -2,9 +2,17 @@
 
 The compiled-kernel replacement for the reference stack's fused-attention
 needs (SURVEY.md §2.4 native-code obligations): attention scores never hit
-HBM — each q-block computes its (block_q, S) score tile in VMEM, does the
-softmax in fp32, and writes only the (block_q, D) output plus the
-log-sum-exp rows needed by the backward pass.
+HBM — each (q-block, k-block) grid step computes its (block_q, block_k)
+score tile in VMEM, does the softmax in fp32, and writes only the
+(block_q, D) output plus the log-sum-exp rows needed by the backward pass.
+Block pairs wholly above the causal diagonal (or below a window's band) are
+skipped.  In the tile kernels of :func:`flash_attention_qkv` a block ON the
+diagonal is not computed as a square either: its q rows are walked in
+static sub-tiles of ``T`` = :func:`causal_tile` rows, and sub-tile ``r``
+forms scores, exponentials and products over the ``(r + 1) * T`` keys up
+to its own diagonal only — (T, (r+1)*T) tiles, :func:`causal_share` of the
+square in all (0.625 at 1024 / 256), the masks on the last ``T`` columns.
+At sequence 1024, one block a sequence, that is every grid step.
 
 Forward: one Pallas kernel, grid (batch, heads, q_blocks); K/V live in VMEM
 per (batch, head) — at BERT/long-context head dims (64..128) a full K/V head
@@ -236,11 +244,13 @@ def _resolve_blocks(batch: int, heads: int, seq: int, depth: int, dtype,
     return bq, bk
 
 
-def _segment_mask(s, qseg_ref, kseg_ref):
+def _segment_mask(s, qseg_ref, kseg_ref, rows=slice(None),
+                  cols=slice(None)):
     """Mask score tile entries whose q and k tokens are in different packed
-    segments (qseg: (block_q,), kseg: (block_k,))."""
-    qseg = qseg_ref[0, 0, :]
-    kseg = kseg_ref[0, 0, :]
+    segments (qseg: (block_q,), kseg: (block_k,); ``rows`` / ``cols``: the
+    part of the block the tile holds)."""
+    qseg = qseg_ref[0, 0, rows]
+    kseg = kseg_ref[0, 0, cols]
     return jnp.where(qseg[:, None] == kseg[None, :], s, NEG_INF)
 
 
@@ -278,6 +288,82 @@ def _masked_scores(q, k, qi, kj, *, scale, block_q, block_k, causal,
         s = jnp.where(keep[None, :], s, NEG_INF)
     if qseg_ref is not None:
         s = _segment_mask(s, qseg_ref, kseg_ref)
+    return s
+
+
+#: Rows of a sub-tile of a block on the causal diagonal (:func:`causal_tile`).
+#: One attention block of GPT-2 medium alone on the v5e (64 x 1024 tokens, 16
+#: heads of 64, one 1024 x 1024 block a grid step, forward + backward under
+#: ``jax.checkpoint``; ``tools/flash_forms.py``, PERF.md section 6, PR 40):
+#: the block whole 26.10 ms; sub-tiles of 128 rows (56 % of the square,
+#: eight a block) 23.59, of 256 (62.5 %, four) **22.45**, of 512 (75 %, two)
+#: 22.81 (the kernels alone, forward + backward: 3.29 + 8.62, 3.07 + 6.51,
+#: 2.68 + 6.16, 2.60 + 6.69).  Narrower sub-tiles skip more of the square
+#: and pay for it in unrolled products of fewer rows; 256 is the width of
+#: the table's minimum.
+#: Mutable module global, read when a call is traced (the tool and the
+#: tests set it; 0 takes every block whole).
+CAUSAL_TILE = 256
+
+
+def causal_tile(block_q: int, block_k: int, causal: bool) -> int | None:
+    """Rows of the sub-tiles the tile kernels walk a block on the causal
+    diagonal in, or None where they take the block whole: a call that is
+    not causal, q and k blocks of unequal size (the diagonal then crosses a
+    block anywhere), a block that is not at least two sub-tiles.  By what
+    the kernel can see of the call, nothing else."""
+    if (not causal or block_q != block_k or not CAUSAL_TILE
+            or block_q % CAUSAL_TILE or block_q < 2 * CAUSAL_TILE):
+        return None
+    return CAUSAL_TILE
+
+
+def causal_share(block: int, tile: int | None) -> float:
+    """The share of a diagonal block's square that is computed: row
+    sub-tile ``r`` of ``n = block / tile`` takes ``r + 1`` of the ``n``
+    column tiles, ``(n + 1) / 2n`` in all (0.625 at 1024 / 256)."""
+    if not tile:
+        return 1.0
+    n = block // tile
+    return (n + 1) / (2 * n)
+
+
+def _diagonal_scores(q, k, r, tile, *, scale, have_mask, mask_ref, qseg_ref,
+                     kseg_ref, window=None):
+    """:func:`_masked_scores` of row sub-tile ``r`` of a block ON the causal
+    diagonal (``block_q == block_k``, ``qi == kj``): ``q`` holds the block's
+    rows ``[r*tile, (r+1)*tile)`` and ``k`` its first ``(r+1)*tile`` keys,
+    every key a row of the sub-tile can see.  The keys past them are the
+    ones the whole-block tile masks to ``NEG_INF`` (an exact 0.0 after the
+    exponential), so the row maximum, the row sum and every product are the
+    whole block's up to the order of the float32 additions.  Only the last
+    ``tile`` columns (the piece the diagonal crosses) take the iota /
+    compare / select passes; the positions inside a diagonal block differ
+    by ``r*tile + i - j`` whatever the block.  The padding and segment rows
+    are read by the sub-tile's slices of the block's refs.  A row whose
+    every visible key is masked (padding on the left) has p = 1 a computed
+    key as in the whole block: finite, l > 0, the average of v over the
+    sub-tile's keys where the whole block averaged over the block's."""
+    n = (r + 1) * tile
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    if scale != 1.0:
+        s = s * scale
+    i = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    diag = jnp.where(i >= j, s[:, r * tile:], NEG_INF)
+    s = diag if r == 0 else jnp.concatenate([s[:, :r * tile], diag], axis=1)
+    if window is not None and window < n:  # the farthest key is n - 1 back
+        back = (r * tile
+                + jax.lax.broadcasted_iota(jnp.int32, (tile, n), 0)
+                - jax.lax.broadcasted_iota(jnp.int32, (tile, n), 1))
+        s = jnp.where(back < window, s, NEG_INF)
+    if have_mask:
+        s = jnp.where(mask_ref[0, 0, :n][None, :], s, NEG_INF)
+    if qseg_ref is not None:
+        s = _segment_mask(s, qseg_ref, kseg_ref,
+                          slice(r * tile, (r + 1) * tile), slice(0, n))
     return s
 
 
@@ -1339,16 +1425,73 @@ def _half_swap(x, depth):
                      pltpu.roll(x, tile - half, 1), pltpu.roll(x, half, 1))
 
 
-def _rotate(x, cos_ref, sin_ref, depth):
+def _rotate(x, cos_ref, sin_ref, depth, rows=slice(None)):
     """Rotary embedding of a (rows, tile) block, ``x*cos + swap(x)*sin`` in
     float32 with ``sin`` sign-folded, rounded once to ``x``'s type; ``x``
-    itself when the call rotates nothing."""
+    itself when the call rotates nothing.  ``rows``: the rows of the
+    tables' block that ``x`` holds."""
     if cos_ref is None:
         return x
     x32 = x.astype(jnp.float32)
-    out = (x32 * cos_ref[0].astype(jnp.float32)
-           + _half_swap(x32, depth) * sin_ref[0].astype(jnp.float32))
+    out = (x32 * cos_ref[0, rows, :].astype(jnp.float32)
+           + _half_swap(x32, depth) * sin_ref[0, rows, :].astype(jnp.float32))
     return out.astype(x.dtype)
+
+
+def _softmax_pv(s, v):
+    """One-pass softmax of a score tile whose rows see all their keys at
+    once, times ``v``: ``(o (rows, tile) float32, log-sum-exp (rows, 1))``."""
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return pv / l, m + jnp.log(l)
+
+
+def _store_lse(lse_ref, columns, rows):
+    """Write the heads' log-sum-exp ``columns`` (a (rows, 1) or (rows,
+    lanes) float32 column a head, one value a q row) into ``rows`` of the
+    (1, hp, 1, S) block, which wants them along the lanes.  The heads are
+    packed into the lanes of one (rows, 128) tile, head ``a`` in lane
+    ``a``, and turned by one transpose; its first ``hp`` rows are the
+    block's.  Storing a head's ``column[:, 0]`` leaves the turn to Mosaic's
+    relayout of a (rows,) vector, which took 1.0 ms of the forward's 3.65 a
+    layer at GPT-2 medium's shapes (my chip runs, PR 40: PERF.md section 6);
+    the same bits either way.  Rows that fill no 128-lane tile keep that
+    store."""
+    n_rows = columns[0].shape[0]
+    if n_rows % LANES:
+        for a, column in enumerate(columns):
+            lse_ref[0, a, 0, rows] = column[:, 0]
+        return
+    pack = jnp.broadcast_to(columns[0], (n_rows, LANES))
+    if len(columns) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, pack.shape, 1)
+        for a, column in enumerate(columns[1:], 1):
+            pack = jnp.where(lane == a, column, pack)
+    lse_ref[0, :, 0, rows] = pack.T[:len(columns), :]
+
+
+def _rotated_k(k_ref, k_scr, cos_ref, sin_ref, depth):
+    """The grid step's rotated k block as a ref the sub-tiles slice: the
+    scratch ``k_scr`` (a one-element tuple) filled with the rotation, or
+    the input block itself where the call rotates nothing."""
+    if cos_ref is None:
+        return k_ref.at[0]
+    (k_all,) = k_scr
+    k_all[:, :] = _rotate(k_ref[0], cos_ref, sin_ref, depth)
+    return k_all
+
+
+def _sub_tiles(block, tile):
+    """``(r, rows, cols)`` of a diagonal block's row sub-tiles: static
+    slices of the block's rows and of the keys up to the sub-tile's own
+    diagonal."""
+    return [(r, pl.ds(r * tile, tile), pl.ds(0, (r + 1) * tile))
+            for r in range(block // tile)]
 
 
 def _unrotate(dx, cos_ref, sin_ref, depth):
@@ -1361,13 +1504,17 @@ def _unrotate(dx, cos_ref, sin_ref, depth):
 
 
 def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      q_scr, m_scr, l_scr, acc_scr, *, depth, scale,
+                      q_scr, m_scr, l_scr, acc_scr, *k_scr, depth, scale,
                       block_q, block_k, causal, have_mask, mask_ref=None,
                       qseg_ref=None, kseg_ref=None, window=None,
-                      cq_ref=None, sq_ref=None, ck_ref=None, sk_ref=None):
+                      cq_ref=None, sq_ref=None, ck_ref=None, sk_ref=None,
+                      causal_tile=None):
     """:func:`_fwd_kernel` on one lane tile of ``qkv``: grid (B, tiles, n_q,
     n_k), the running max and sum a head, one (block_q, tile) accumulator;
-    q is rotated once a q block, k once a visit."""
+    q is rotated once a q block, k once a visit.  With ``causal_tile`` the
+    step on the diagonal runs the recurrence a row sub-tile at a time over
+    the keys up to the sub-tile's diagonal (``k_scr``: the visit's rotated
+    k, where the call rotates)."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     n_k = pl.num_programs(3)
@@ -1382,31 +1529,46 @@ def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     run = _band_run(qi, kj, block_q, block_k, causal, window)
 
-    def _step(apply_causal, apply_window):
-        q = q_scr[:, :]
-        k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
-        v = v_ref[0]
+    rows_kw = dict(scale=scale, have_mask=have_mask, mask_ref=mask_ref,
+                   qseg_ref=qseg_ref, kseg_ref=kseg_ref)
+
+    def _accumulate(q, k, v, rows, scores):
+        """The recurrence over the block's ``rows``, a head at a time."""
         for a in range(hp):
-            s = _masked_scores(
-                _only_head(q, a, depth), k, qi, kj, scale=scale,
-                block_q=block_q, block_k=block_k, causal=apply_causal,
-                have_mask=have_mask, mask_ref=mask_ref, qseg_ref=qseg_ref,
-                kseg_ref=kseg_ref, window=window if apply_window else None,
-            )
-            m_prev = m_scr[a, :, :1]
+            s = scores(_only_head(q, a, depth), k)
+            m_prev = m_scr[a, rows, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            l_new = l_scr[a, :, :1] * alpha + jnp.sum(p, axis=-1,
-                                                      keepdims=True)
+            l_new = l_scr[a, rows, :1] * alpha + jnp.sum(p, axis=-1,
+                                                         keepdims=True)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            acc = acc_scr[:, :]
-            acc_scr[:, :] = _put_head(acc, acc * alpha + pv, a, depth)
-            m_scr[a] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[a] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+            acc = acc_scr[rows, :]
+            acc_scr[rows, :] = _put_head(acc, acc * alpha + pv, a, depth)
+            m_scr[a, rows, :] = jnp.broadcast_to(
+                m_new, (m_new.shape[0], m_scr.shape[2]))
+            l_scr[a, rows, :] = jnp.broadcast_to(
+                l_new, (l_new.shape[0], l_scr.shape[2]))
+
+    def _step(apply_causal, apply_window):
+        win = window if apply_window else None
+        if not (apply_causal and causal_tile):
+            _accumulate(
+                q_scr[:, :], _rotate(k_ref[0], ck_ref, sk_ref, depth),
+                v_ref[0], slice(None),
+                lambda q, k: _masked_scores(
+                    q, k, qi, kj, block_q=block_q, block_k=block_k,
+                    causal=apply_causal, window=win, **rows_kw))
+            return
+        k_all = _rotated_k(k_ref, k_scr, ck_ref, sk_ref, depth)
+        for r, rows, cols in _sub_tiles(block_q, causal_tile):
+            _accumulate(
+                q_scr[rows, :], k_all[cols, :], v_ref[0, cols, :], rows,
+                lambda q, k, r=r: _diagonal_scores(
+                    q, k, r, causal_tile, window=win, **rows_kw))
 
     _causal_step_split(qi, kj, run, block_q=block_q, block_k=block_k,
                        causal=causal, step=_step, window=window)
@@ -1417,63 +1579,74 @@ def _fwd_tiles_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o = acc
         for a in range(hp):
             o = _put_head(o, acc / l_scr[a, :, :1], a, depth)
-            lse_ref[0, a, 0, pl.ds(qi * block_q, block_q)] = (
-                m_scr[a, :, 0] + jnp.log(l_scr[a, :, 0])
-            )
+        _store_lse(lse_ref, [m_scr[a] + jnp.log(l_scr[a]) for a in range(hp)],
+                   pl.ds(qi * block_q, block_q))
         o_ref[0] = o.astype(o_ref.dtype)
 
 
-def _fwd_tiles_kernel_1k(q_ref, k_ref, v_ref, o_ref, lse_ref, *, depth,
-                         scale, block_q, block_k, causal, have_mask,
+def _fwd_tiles_kernel_1k(q_ref, k_ref, v_ref, o_ref, lse_ref, *k_scr,
+                         depth, scale, block_q, block_k, causal, have_mask,
                          mask_ref=None, qseg_ref=None, kseg_ref=None,
                          window=None, cq_ref=None, sq_ref=None, ck_ref=None,
-                         sk_ref=None):
+                         sk_ref=None, causal_tile=None):
     """:func:`_fwd_kernel_1k` on one lane tile of ``qkv``: the whole K/V
-    sequence is one k block, so each head's softmax is one pass."""
+    sequence is one k block, so each head's softmax is one pass.  With
+    ``causal_tile`` (the one block is on the diagonal) the pass is made a
+    row sub-tile at a time over the keys up to the sub-tile's diagonal: a
+    row still sees all its keys at once, and the upper triangle's scores,
+    exponentials and products are never formed.  k is rotated once a grid
+    step either way (into ``k_scr``, which the sub-tiles slice)."""
     qi = pl.program_id(2)
     hp = q_ref.shape[-1] // depth
-    q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
-    k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
-    v = v_ref[0]
-    o = None
-    for a in range(hp):
-        s = _masked_scores(
-            _only_head(q, a, depth), k, qi, 0, scale=scale, block_q=block_q,
-            block_k=block_k, causal=causal, have_mask=have_mask,
-            mask_ref=mask_ref, qseg_ref=qseg_ref, kseg_ref=kseg_ref,
-            window=window,
-        )
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        o = pv / l if o is None else _put_head(o, pv / l, a, depth)
-        lse_ref[0, a, 0, pl.ds(qi * block_q, block_q)] = (
-            m[:, 0] + jnp.log(l[:, 0])
-        )
-    o_ref[0] = o.astype(o_ref.dtype)
+    rows_kw = dict(scale=scale, have_mask=have_mask, mask_ref=mask_ref,
+                   qseg_ref=qseg_ref, kseg_ref=kseg_ref, window=window)
+
+    def _heads(q, k, v, scores, lse_rows):
+        o, lses = None, []
+        for a in range(hp):
+            oa, lse = _softmax_pv(scores(_only_head(q, a, depth), k), v)
+            o = oa if o is None else _put_head(o, oa, a, depth)
+            lses.append(lse)
+        _store_lse(lse_ref, lses, lse_rows)
+        return o.astype(o_ref.dtype)
+
+    if not causal_tile:
+        o_ref[0] = _heads(
+            _rotate(q_ref[0], cq_ref, sq_ref, depth),
+            _rotate(k_ref[0], ck_ref, sk_ref, depth), v_ref[0],
+            lambda q, k: _masked_scores(
+                q, k, qi, 0, block_q=block_q, block_k=block_k, causal=causal,
+                **rows_kw),
+            pl.ds(qi * block_q, block_q))
+        return
+    k_all = _rotated_k(k_ref, k_scr, ck_ref, sk_ref, depth)
+    for r, rows, cols in _sub_tiles(block_q, causal_tile):
+        o_ref[0, rows, :] = _heads(
+            _rotate(q_ref[0, rows, :], cq_ref, sq_ref, depth, rows),
+            k_all[cols, :], v_ref[0, cols, :],
+            lambda q, k, r=r: _diagonal_scores(
+                q, k, r, causal_tile, **rows_kw),
+            rows)
 
 
-def _bwd_tiles_head(a, q, k, v, g, go, lse_ref, qi, kj, *, depth, masks):
+def _bwd_tiles_head(a, q, k, v, g, go, lse, scores, *, depth, scale):
     """One head of a backward step on a lane tile: ``(p, ds, q_a, k_a,
-    g_a)`` with the p tile recomputed from the saved LSE as in the
-    (B, H, S, D) kernels, the operands zeroed outside the head's lanes so
-    that every product lands in them.  ``go`` is dO * O over the tile,
-    float32: a head's delta is its sum over the head's lanes."""
+    g_a)`` with the p tile recomputed from the saved LSE (``lse``, the
+    head's rows) as in the (B, H, S, D) kernels, the operands zeroed
+    outside the head's lanes so that every product lands in them.
+    ``scores(q_a, k)`` is the masked score tile of the rows and keys the
+    operands hold (a block, or a diagonal block's row sub-tile); ``go`` is
+    dO * O over the tile, float32: a head's delta is its sum over the
+    head's lanes."""
     qa, ka, ga = (_only_head(x, a, depth) for x in (q, k, g))
-    s = _masked_scores(qa, k, qi, kj, **masks)
-    lse = lse_ref[0, a, 0, :]
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(scores(qa, k) - lse[:, None])
     dp = jax.lax.dot_general(
         ga, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
     )
     delta = jnp.sum(_only_head(go, a, depth), axis=-1, keepdims=True)
     ds = p * (dp - delta)
-    if masks["scale"] != 1.0:
-        ds = ds * masks["scale"]
+    if scale != 1.0:
+        ds = ds * scale
     return p, ds, qa, ka, ga
 
 
@@ -1515,12 +1688,16 @@ def _bwd_tiles_fused_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
                             block_q, block_k, causal, have_mask,
                             mask_ref=None, qseg_ref=None, kseg_ref=None,
                             window=None, cq_ref=None, sq_ref=None,
-                            ck_ref=None, sk_ref=None):
+                            ck_ref=None, sk_ref=None, causal_tile=None):
     """:func:`_bwd_fused_kernel` on one lane tile of ``qkv``: grid (B,
     tiles, n_k, n_q), q innermost.  The rotated q and k are recomputed from
     the raw tiles (k once a k block), the accumulators hold the gradients
     of the rotated q and k, and what is written, once an accumulator is
-    final, is their rotation back."""
+    final, is their rotation back.  With ``causal_tile`` the step on the
+    diagonal (at one block a sequence, every step) walks the q rows in
+    sub-tiles: p, dp and ds of sub-tile ``r`` span the keys up to its own
+    diagonal, and dv, dk and dq take its products by slices of the scratch;
+    the steps off the diagonal are the whole block's."""
     j = pl.program_id(2)
     i = pl.program_id(3)
     n_k = pl.num_programs(2)
@@ -1541,23 +1718,39 @@ def _bwd_tiles_fused_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
     run = _band_run(i, j, block_q, block_k, causal, window)
     row = pl.ds(i * block_q, block_q)
 
-    def _step(apply_causal, apply_window):
-        q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
-        k, v, g = k_scr[:, :], v_ref[0], g_ref[0]
-        go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        masks = dict(
-            scale=scale, block_q=block_q, block_k=block_k,
-            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
-            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
-            window=window if apply_window else None,
-        )
+    rows_kw = dict(scale=scale, have_mask=have_mask, mask_ref=mask_ref,
+                   qseg_ref=qseg_ref, kseg_ref=kseg_ref)
+
+    def _accumulate(rows, cols, dq_rows, scores):
+        """The block's ``rows`` against its keys ``cols``, a head at a
+        time, into the slices of the three accumulators they touch."""
+        q = _rotate(q_ref[0, rows, :], cq_ref, sq_ref, depth, rows)
+        k, v, g = k_scr[cols, :], v_ref[0, cols, :], g_ref[0, rows, :]
+        go = g.astype(jnp.float32) * o_ref[0, rows, :].astype(jnp.float32)
         for a in range(hp):
             p, ds, qa, ka, ga = _bwd_tiles_head(
-                a, q, k, v, g, go, lse_ref, i, j, depth=depth, masks=masks)
+                a, q, k, v, g, go, lse_ref[0, a, 0, rows], scores,
+                depth=depth, scale=scale)
             ds = ds.astype(q.dtype)
-            dv_scr[:, :] = dv_scr[:, :] + _dot_t(p.astype(ga.dtype), ga)
-            dk_scr[:, :] = dk_scr[:, :] + _dot_t(ds, qa)
-            dq_all_scr[row] = dq_all_scr[row] + _dot(ds, ka)
+            dv_scr[cols, :] = dv_scr[cols, :] + _dot_t(p.astype(ga.dtype), ga)
+            dk_scr[cols, :] = dk_scr[cols, :] + _dot_t(ds, qa)
+            dq_all_scr[dq_rows] = dq_all_scr[dq_rows] + _dot(ds, ka)
+
+    def _step(apply_causal, apply_window):
+        win = window if apply_window else None
+        if not (apply_causal and causal_tile):
+            _accumulate(
+                slice(None), slice(None), row,
+                lambda q, k: _masked_scores(
+                    q, k, i, j, block_q=block_q, block_k=block_k,
+                    causal=apply_causal, window=win, **rows_kw))
+            return
+        for r, rows, cols in _sub_tiles(block_q, causal_tile):
+            _accumulate(
+                rows, cols,
+                pl.ds(i * block_q + r * causal_tile, causal_tile),
+                lambda q, k, r=r: _diagonal_scores(
+                    q, k, r, causal_tile, window=win, **rows_kw))
 
     _causal_step_split(i, j, run, block_q=block_q, block_k=block_k,
                        causal=causal, step=_step, window=window)
@@ -1602,15 +1795,15 @@ def _bwd_tiles_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
         q, v, g = q_scr[:, :], v_ref[0], g_ref[0]
         k = _rotate(k_ref[0], ck_ref, sk_ref, depth)
         go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        masks = dict(
-            scale=scale, block_q=block_q, block_k=block_k,
-            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
-            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
-            window=window if apply_window else None,
-        )
+        scores = functools.partial(
+            _masked_scores, qi=qi, kj=kj, scale=scale, block_q=block_q,
+            block_k=block_k, causal=apply_causal, have_mask=have_mask,
+            mask_ref=mask_ref, qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window if apply_window else None)
         for a in range(hp):
             _, ds, _, ka, _ = _bwd_tiles_head(
-                a, q, k, v, g, go, lse_ref, qi, kj, depth=depth, masks=masks)
+                a, q, k, v, g, go, lse_ref[0, a, 0, :], scores, depth=depth,
+                scale=scale)
             dq_scr[:, :] = dq_scr[:, :] + _dot(ds.astype(k.dtype), ka)
 
     _causal_step_split(qi, kj, run, block_q=block_q, block_k=block_k,
@@ -1650,15 +1843,15 @@ def _bwd_tiles_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
         q = _rotate(q_ref[0], cq_ref, sq_ref, depth)
         k, v, g = k_scr[:, :], v_ref[0], g_ref[0]
         go = g.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        masks = dict(
-            scale=scale, block_q=block_q, block_k=block_k,
-            causal=apply_causal, have_mask=have_mask, mask_ref=mask_ref,
-            qseg_ref=qseg_ref, kseg_ref=kseg_ref,
-            window=window if apply_window else None,
-        )
+        scores = functools.partial(
+            _masked_scores, qi=qi, kj=kj, scale=scale, block_q=block_q,
+            block_k=block_k, causal=apply_causal, have_mask=have_mask,
+            mask_ref=mask_ref, qseg_ref=qseg_ref, kseg_ref=kseg_ref,
+            window=window if apply_window else None)
         for a in range(hp):
             p, ds, qa, _, ga = _bwd_tiles_head(
-                a, q, k, v, g, go, lse_ref, qi, kj, depth=depth, masks=masks)
+                a, q, k, v, g, go, lse_ref[0, a, 0, :], scores, depth=depth,
+                scale=scale)
             dv_scr[:, :] = dv_scr[:, :] + _dot_t(p.astype(ga.dtype), ga)
             dk_scr[:, :] = dk_scr[:, :] + _dot_t(ds.astype(q.dtype), qa)
 
@@ -1748,9 +1941,10 @@ def _tiles_geometry(qkv, heads):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "heads", "causal", "interpret", "window", "block_q", "block_k"))
+    "heads", "causal", "interpret", "window", "block_q", "block_k",
+    "causal_tile"))
 def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
-                   window, block_q, block_k):
+                   window, block_q, block_k, causal_tile=None):
     """Forward over the fused projection: ``(o (B, S, H*D), lse (B, H, 1,
     S))``.  A jitted function of its own (as ``ops.attention.
     _paged_attn_call``): the layers of a step call it at the same shapes and
@@ -1770,8 +1964,11 @@ def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
     kernel = _wrap_kernel(
         _fwd_tiles_kernel_1k if one_k else _fwd_tiles_kernel, 3, extra_names,
         depth=depth, scale=scale, block_q=block_q, block_k=block_k,
-        causal=causal, window=window,
+        causal=causal, window=window, causal_tile=causal_tile,
     )
+    # the sub-tiles slice the step's rotated k out of VMEM
+    k_scr = [pltpu.VMEM((block_k, tile), qkv.dtype)] if (
+        causal_tile and rope is not None) else []
     return pl.pallas_call(
         kernel,
         name="flash_fwd",
@@ -1786,11 +1983,12 @@ def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
             jax.ShapeDtypeStruct((batch, seq, heads * depth), qkv.dtype),
             jax.ShapeDtypeStruct((batch, heads, 1, seq), jnp.float32),
         ],
-        scratch_shapes=[] if one_k else [
+        scratch_shapes=k_scr if one_k else [
             pltpu.VMEM((block_q, tile), qkv.dtype),        # rotated q
             pltpu.VMEM((hp, block_q, 128), jnp.float32),   # running max m
             pltpu.VMEM((hp, block_q, 128), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, tile), jnp.float32),      # accumulator
+            *k_scr,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=TILES_VMEM_LIMIT_BYTES),
@@ -1800,10 +1998,10 @@ def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "causal", "interpret", "force_split", "window", "block_q",
-    "block_k"))
+    "block_k", "causal_tile"))
 def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
                     causal, interpret, force_split, window, block_q,
-                    block_k):
+                    block_k, causal_tile=None):
     """d``qkv`` (B, S, 3*H*D) from the saved projection, o and LSE.  The
     kernels form delta = rowsum(dO * O) themselves, from the o tile, and
     copy each finished gradient block into its place in the one d``qkv``
@@ -1820,7 +2018,7 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
     def stage(block):
         return pltpu.VMEM((block, tile), qkv.dtype)
 
-    def call(inner, name, swap_grid, scratch, dq_done=None):
+    def call(inner, name, swap_grid, scratch, dq_done=None, **more):
         specs = _tile_specs(tiles, hp, tile, block_q, block_k, mem,
                             swap_grid=swap_grid)
         extra_specs, extra_args, extra_names = (
@@ -1833,7 +2031,7 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
             seq // block_q, seq // block_k)
         done = [] if dq_done is None else [dq_done]
         return pl.pallas_call(
-            _wrap_kernel(inner, 6 + len(done), extra_names, **kw),
+            _wrap_kernel(inner, 6 + len(done), extra_names, **kw, **more),
             name=name,
             grid=(batch, tiles, *n),
             in_specs=[specs["q"], specs["k"], specs["v"], specs["qside"],
@@ -1856,7 +2054,8 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
              pltpu.VMEM((block_k, tile), f32),    # dk
              pltpu.VMEM((block_k, tile), f32),    # dv
              stage(block_q), stage(block_k), stage(block_k),
-             pltpu.SemaphoreType.DMA((3,))])
+             pltpu.SemaphoreType.DMA((3,))],
+            causal_tile=causal_tile)  # the split pair takes blocks whole
     dq_done = call(
         _bwd_tiles_dq_kernel, "flash_bwd_dq", False,
         [stage(block_q), pltpu.VMEM((block_q, tile), f32), stage(block_q),
@@ -1869,12 +2068,14 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
         dq_done=dq_done)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_qkv(qkv, rope, mask, segment_ids, heads, causal, interpret,
-               backward_impl, window, block_q, block_k):
+               backward_impl, window, block_q, block_k, causal_tile):
     o, _ = _tiles_forward(qkv, rope, mask, segment_ids, heads=heads,
                           causal=causal, interpret=interpret, window=window,
-                          block_q=block_q, block_k=block_k)
+                          block_q=block_q, block_k=block_k,
+                          causal_tile=causal_tile)
     return o
 
 
@@ -1889,23 +2090,25 @@ RESIDUAL_LSE = "flash_qkv_lse"
 
 
 def _flash_qkv_fwd(qkv, rope, mask, segment_ids, heads, causal, interpret,
-                   backward_impl, window, block_q, block_k):
+                   backward_impl, window, block_q, block_k, causal_tile):
     o, lse = _tiles_forward(qkv, rope, mask, segment_ids, heads=heads,
                             causal=causal, interpret=interpret, window=window,
-                            block_q=block_q, block_k=block_k)
+                            block_q=block_q, block_k=block_k,
+                            causal_tile=causal_tile)
     o = checkpoint_name(o, RESIDUAL_O)
     lse = checkpoint_name(lse, RESIDUAL_LSE)
     return o, (qkv, rope, mask, segment_ids, o, lse)
 
 
 def _flash_qkv_bwd(heads, causal, interpret, backward_impl, window, block_q,
-                   block_k, res, g):
+                   block_k, causal_tile, res, g):
     qkv, rope, mask, segment_ids, o, lse = res
     dqkv = _tiles_backward(
         qkv, rope, mask, segment_ids, o, lse, g, heads=heads, causal=causal,
         interpret=interpret,
         force_split=(backward_impl or BACKWARD_IMPL) == "pallas_split",
-        window=window, block_q=block_q, block_k=block_k)
+        window=window, block_q=block_q, block_k=block_k,
+        causal_tile=causal_tile)
     return dqkv, None, None, None
 
 
@@ -1934,6 +2137,20 @@ def qkv_layout(seq: int, heads: int, kv_heads: int, depth: int, dtype, *,
     ):
         return "bhsd"
     return "qkv_tiles"
+
+
+def qkv_causal_tile(batch: int, seq: int, heads: int, depth: int, dtype
+                    ) -> tuple[int | None, float | None]:
+    """``(T, share)`` of a causal :func:`flash_attention_qkv` call over a
+    shard of ``batch`` rows at the blocks it resolves to:
+    :func:`causal_tile` and :func:`causal_share` (256 and 0.625 at one
+    1024 x 1024 block), ``(None, None)`` where its blocks are taken whole.
+    The sub-tiling is static, so the trainer reports it at start-up beside
+    :func:`qkv_layout`."""
+    block_q, block_k = _resolve_blocks(
+        batch, heads, seq, depth, dtype, None, None, layout="qkv_tiles")
+    tile = causal_tile(block_q, block_k, True)
+    return tile, causal_share(block_q, tile) if tile else None
 
 
 def flash_attention_qkv(qkv, heads: int, *, rope=None, mask=None,
@@ -1994,7 +2211,8 @@ def flash_attention_qkv(qkv, heads: int, *, rope=None, mask=None,
             qkv.shape[0], heads, seq, depth, qkv.dtype, block_q, block_k,
             layout="qkv_tiles")
         return _flash_qkv(qkv, rope, pad, segment_ids, heads, causal,
-                          interpret, backward_impl, window, bq, bk)
+                          interpret, backward_impl, window, bq, bk,
+                          causal_tile(bq, bk, causal))
 
     # batch over the data axes; the lane dimension whole (qkv_layout sends
     # a head-sharded call the other way)

@@ -673,3 +673,6 @@ def test_gpt2_medium_step_runs_flash_fwd_once_a_layer_and_fits_a_v5e(
     # a device's o (64, 1024, 16 * 64) bf16 and LSE (64, 16, 1024) float32
     assert (row["attn_residuals"], row["attn_residual_bytes_per_layer"]) == (
         "saved", 64 * 1024 * (1024 * 2 + 16 * 4))
+    # the one 1024 x 1024 block a sequence is walked in row sub-tiles
+    assert (row["flash_causal_tile"], row["flash_causal_share"]) == (
+        256, 0.625)

@@ -93,20 +93,32 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []
 
 
-@pytest.mark.parametrize("changes, want", [
-    pytest.param(dict(remat=True, attn_impl="pallas"),
-                 ("qkv_tiles", "saved", 4 * 64 * (128 * 2 + 4 * 4)),
-                 id="tiles_under_remat"),
-    pytest.param(dict(remat=True, attn_impl="xla"), ("xla", "recomputed", 0),
+@pytest.mark.parametrize("changes, seq, want", [
+    pytest.param(dict(remat=True, attn_impl="pallas"), 64,
+                 ("qkv_tiles", "saved", 4 * 64 * (128 * 2 + 4 * 4),
+                  None, None), id="tiles_under_remat"),
+    pytest.param(dict(remat=True, attn_impl="xla"), 64,
+                 ("xla", "recomputed", 0, None, None),
                  id="another_form_under_remat"),
-    pytest.param(dict(remat=False, attn_impl="pallas"),
-                 ("qkv_tiles", None, None), id="remat_off"),
+    pytest.param(dict(remat=False, attn_impl="pallas"), 64,
+                 ("qkv_tiles", None, None, None, None), id="remat_off"),
+    # one 1024 x 1024 block a sequence: the diagonal block in sub-tiles
+    pytest.param(dict(remat=True, attn_impl="pallas"), 1024,
+                 ("qkv_tiles", "saved", 4 * 1024 * (128 * 2 + 4 * 4),
+                  256, 0.625), id="tiles_at_1024_sub_tiled"),
+    pytest.param(dict(remat=True, attn_impl="pallas", num_kv_heads=2), 1024,
+                 ("bhsd", "recomputed", 0, None, None),
+                 id="bhsd_at_1024_whole_blocks"),
 ])
-def test_trainer_row_says_what_a_block_keeps(changes, want, tmp_path):
+def test_trainer_row_says_what_a_block_keeps(changes, seq, want, tmp_path):
     """The ``startup.trainer`` row carries ``attn_residuals`` and
-    ``attn_residual_bytes_per_layer`` beside ``flash_layout`` (what
-    ``train._flash_layout`` reads off the model under the trainer's mesh),
-    and the schema checker takes the row, nulls included."""
+    ``attn_residual_bytes_per_layer`` beside ``flash_layout``, and
+    ``flash_causal_tile`` / ``flash_causal_share`` (the rows of the
+    sub-tiles a causal diagonal block is walked in and the share of its
+    square that is computed; null where blocks are taken whole: a block
+    under two sub-tiles, ``bhsd``, ``xla``) — what ``train._flash_layout``
+    reads off the model under the trainer's mesh —, and the schema checker
+    takes the row, nulls included."""
     import dataclasses
     import types
 
@@ -121,12 +133,13 @@ def test_trainer_row_says_what_a_block_keeps(changes, want, tmp_path):
     wl = types.SimpleNamespace(
         model=GPTLM(dataclasses.replace(gpt_tiny(), **changes)),
         # the example batch has two rows; a step has the global batch's
-        init_batch={"input_ids": np.zeros((2, 64), np.int32)},
+        init_batch={"input_ids": np.zeros((2, seq), np.int32)},
         global_batch_size=4)
     fields = train._flash_layout(
         wl, build_mesh(MeshSpec(data=1), jax.devices()[:1]))
     assert fields == dict(zip(
-        ("flash_layout", "attn_residuals", "attn_residual_bytes_per_layer"),
+        ("flash_layout", "attn_residuals", "attn_residual_bytes_per_layer",
+         "flash_causal_tile", "flash_causal_share"),
         want))
     path = tmp_path / "trace.jsonl"
     with tracing.TraceRecorder(str(path), chief_only=False):

@@ -18,7 +18,8 @@ PR 37): the step a saved residual is weighed against.
 One JSON row to stdout and ``chiprun_out/train_step_memory.jsonl``:
 ``total_bytes`` = arguments + outputs - aliased + temporaries, a device;
 ``kernels`` = custom calls in the compiled module by kernel name (per
-shard on a mesh); ``attn_residuals`` as the trainer's start-up row has it.
+shard on a mesh); ``attn_residuals`` and ``flash_causal_tile`` /
+``flash_causal_share`` as the trainer's start-up row has them.
 """
 
 from __future__ import annotations
@@ -88,6 +89,9 @@ def report(compiled, mesh, wl) -> dict:
             row["flash_layout"] = wl.model.flash_layout(ids.shape[1])
             row["attn_residuals"], row["attn_residual_bytes_per_layer"] = (
                 wl.model.attn_residuals(wl.global_batch_size, ids.shape[1]))
+            row["flash_causal_tile"], row["flash_causal_share"] = (
+                wl.model.flash_causal_tile(wl.global_batch_size,
+                                           ids.shape[1]))
     return row
 
 

@@ -553,14 +553,18 @@ def run_ps_cluster_task(args, cluster, task_type, task_index) -> None:
 
 
 def _flash_layout(wl, mesh) -> dict:
-    """``flash_layout``, ``attn_residuals`` and
-    ``attn_residual_bytes_per_layer`` for the ``startup.trainer`` row and
-    the start-up log: the form the step's dense attention lowers to
-    (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla") and
-    what a remat'd block does for that attention's residuals in the
-    backward (``GPTLM.attn_residuals``: "saved" with the bytes a layer
-    keeps on a device, "recomputed", or null where nothing is
-    rematerialised), read under the trainer's mesh as the step is traced.
+    """``flash_layout``, ``attn_residuals``,
+    ``attn_residual_bytes_per_layer``, ``flash_causal_tile`` and
+    ``flash_causal_share`` for the ``startup.trainer`` row and the start-up
+    log: the form the step's dense attention lowers to
+    (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla"), what
+    a remat'd block does for that attention's residuals in the backward
+    (``GPTLM.attn_residuals``: "saved" with the bytes a layer keeps on a
+    device, "recomputed", or null where nothing is rematerialised) and the
+    rows of the sub-tiles the tile kernels walk a causal diagonal block in
+    with the share of its square they compute
+    (``GPTLM.flash_causal_tile``; null where blocks are taken whole), read
+    under the trainer's mesh as the step is traced.
     The fall-back from one form to the next is silent and costs a tenth of
     a step, and with it goes the saving, so a run's trace says which it
     got.  Empty for a model that has no such choice."""
@@ -572,12 +576,17 @@ def _flash_layout(wl, mesh) -> dict:
         layout = ask(ids.shape[1])
         residuals, kept = wl.model.attn_residuals(
             wl.global_batch_size, ids.shape[1])
+        tile, share = wl.model.flash_causal_tile(
+            wl.global_batch_size, ids.shape[1])
     if layout is None:
         return {}
     logging.info("flash_layout: %s", layout)
     logging.info("attn_residuals: %s (%s bytes a layer)", residuals, kept)
+    logging.info("flash_causal_tile: %s (share %s of a diagonal block)",
+                 tile, share)
     return {"flash_layout": layout, "attn_residuals": residuals,
-            "attn_residual_bytes_per_layer": kept}
+            "attn_residual_bytes_per_layer": kept,
+            "flash_causal_tile": tile, "flash_causal_share": share}
 
 
 def main() -> None:
