@@ -30,6 +30,11 @@ from .jamba import (  # noqa: F401
     jamba2_3b,
     jamba_tiny,
 )
+from .mimo import (  # noqa: F401
+    MimoConfig,
+    mimo_tiny,
+    mimo_v25_ep16,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

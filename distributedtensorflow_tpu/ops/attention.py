@@ -170,8 +170,53 @@ def _gather_pages(pool, layer, block_tables, block_size, head_dim):
     num_layers, rows, width = pool.shape
     x = pool.reshape(num_layers, rows // block_size, block_size,
                      width)[layer, block_tables]
-    return x.reshape(b, max_blocks * block_size, width // head_dim,
-                     head_dim).transpose(0, 2, 1, 3)
+    return _row_heads(x, (b, max_blocks * block_size),
+                      head_dim).transpose(0, 2, 1, 3)
+
+
+def _tile_split(head_dim: int) -> int:
+    """Where a row of heads ``head_dim`` wide is split: a head wider than a
+    128-lane tile and not a whole number of them (MiMo-V2's keys: 192) is
+    stored as its whole tiles, head after head, and then its remainders, head
+    after head (``[h0[:128] | h1[:128] | ... | h0[128:] | h1[128:] | ...]``),
+    so that no tile of the row holds parts of two kinds and nothing is
+    padded; 0 where the heads simply follow each other."""
+    return head_dim // 128 * 128 if head_dim > 128 and head_dim % 128 else 0
+
+
+def lay_heads(x: jax.Array) -> jax.Array:
+    """``x`` (T, heads, D) as the row a pool stores, (T, heads * D)."""
+    t, split = x.shape[0], _tile_split(x.shape[-1])
+    if not split:
+        return x.reshape(t, -1)
+    return jnp.concatenate([x[..., :split].reshape(t, -1),
+                            x[..., split:].reshape(t, -1)], axis=-1)
+
+
+def _row_heads(x: jax.Array, lead: tuple, head_dim: int) -> jax.Array:
+    """Stored rows ``x`` (any shape that ends in the row, heads * D wide) as
+    heads, ``(*lead, heads, D)``: the inverse of :func:`lay_heads`, on
+    gathered rows only."""
+    width, split = x.shape[-1], _tile_split(head_dim)
+    heads = width // head_dim
+    if not split:
+        return x.reshape(*lead, heads, head_dim)
+    x = x.reshape(*lead, width)
+    return jnp.concatenate([
+        x[..., :heads * split].reshape(*lead, heads, split),
+        x[..., heads * split:].reshape(*lead, heads, head_dim - split)],
+        axis=-1)
+
+
+def sink_softmax(scores, sink):
+    """Softmax over the last axis of float32 ``scores`` (B, H, ..., K) with
+    one more key a head that has no value: the learned bias ``sink`` (H,)
+    joins the denominator, ``p_j = exp(s_j) / (exp(b_h) + sum_j' exp(s_j'))``."""
+    b_h = sink.astype(jnp.float32).reshape(
+        (1, -1) + (1,) * (scores.ndim - 2))
+    m = jnp.maximum(scores.max(-1, keepdims=True), b_h)
+    e = jnp.exp(scores - m)
+    return e / (e.sum(-1, keepdims=True) + jnp.exp(b_h - m))
 
 
 def paged_decode_attention(
@@ -184,6 +229,7 @@ def paged_decode_attention(
     layer: int,               # which layer of the stacked pools to attend
     block_size: int,          # rows per physical block
     window: int | None = None,  # attend only the last `window` positions
+    sink: jax.Array | None = None,  # (H,) a key without a value a head
 ) -> jax.Array:
     """Single-token decode attention against a paged (block-pool) KV cache.
 
@@ -209,12 +255,15 @@ def paged_decode_attention(
     kernel that streams only the blocks a slot holds is
     :func:`paged_window_decode_attention`, which the decode programs call;
     this is its yardstick in the tests, its path off the TPU and at shapes
-    it does not take, and what :func:`paged_verify_attention` shares.
+    it does not take, and what :func:`paged_verify_attention` shares.  The
+    V pool's heads may be narrower than K's (the output is as wide as V's);
+    ``sink`` joins each head's softmax as a key with no value.
     """
     b, h, d = q.shape
     k = _gather_pages(k_pool, layer, block_tables, block_size, d)
-    v = _gather_pages(v_pool, layer, block_tables, block_size, d)
     h_kv, cap = k.shape[1], k.shape[2]
+    v = _gather_pages(v_pool, layer, block_tables, block_size,
+                      v_pool.shape[-1] // h_kv)
     valid = jnp.arange(cap)[None, :] < seq_lens[:, None]  # (B, cap)
     if window is not None:
         valid &= jnp.arange(cap)[None, :] >= seq_lens[:, None] - window
@@ -229,12 +278,13 @@ def paged_decode_attention(
             "bhd,bhkd->bhk", q, k, preferred_element_type=jnp.float32,
         ) / (d ** 0.5)
     scores = jnp.where(valid[:, None, :], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
+    weights = (jax.nn.softmax(scores, axis=-1) if sink is None
+               else sink_softmax(scores, sink))
     if h != h_kv:
         wg = weights.astype(q.dtype).reshape(b, h_kv, g, cap)
         out = jnp.einsum(
             "bhgk,bhkd->bhgd", wg, v, preferred_element_type=jnp.float32,
-        ).reshape(b, h, d)
+        ).reshape(b, h, v.shape[-1])
     else:
         out = jnp.einsum(
             "bhk,bhkd->bhd", weights.astype(q.dtype), v,
@@ -252,6 +302,7 @@ def paged_verify_attention(
     *,
     layer: int,               # which layer of the stacked pools to attend
     block_size: int,          # rows per physical block
+    sink: jax.Array | None = None,  # (H,) a key without a value a head
 ) -> jax.Array:
     """Multi-token decode attention against the paged pool (speculative
     verification).
@@ -267,12 +318,13 @@ def paged_verify_attention(
     would have produced).  Same gather-through-page-table walk, same
     fp32-softmax scaled dot product, same GQA grouping; at ``T = 1``
     with ``attend_lens = seq_lens`` it reduces to the decode path.
-    Returns ``(B, T, H, D)``.
+    Returns ``(B, T, H, D)``, ``D`` the width of a V head.
     """
     b, t, h, d = q.shape
     k = _gather_pages(k_pool, layer, block_tables, block_size, d)
-    v = _gather_pages(v_pool, layer, block_tables, block_size, d)
     h_kv, cap = k.shape[1], k.shape[2]
+    v = _gather_pages(v_pool, layer, block_tables, block_size,
+                      v_pool.shape[-1] // h_kv)
     # (B, T, cap): query t of slot b sees positions < attend_lens[b] + t
     valid = (jnp.arange(cap)[None, None, :]
              < (attend_lens[:, None] + jnp.arange(t)[None, :])[:, :, None])
@@ -288,12 +340,13 @@ def paged_verify_attention(
             "bthd,bhkd->bhtk", q, k, preferred_element_type=jnp.float32,
         ) / (d ** 0.5)
     scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1)
+    weights = (jax.nn.softmax(scores, axis=-1) if sink is None
+               else sink_softmax(scores, sink))
     if h != h_kv:
         wg = weights.astype(q.dtype).reshape(b, h_kv, g, t, cap)
         out = jnp.einsum(
             "bhgtk,bhkd->bthgd", wg, v, preferred_element_type=jnp.float32,
-        ).reshape(b, t, h, d)
+        ).reshape(b, t, h, v.shape[-1])
     else:
         out = jnp.einsum(
             "bhtk,bhkd->bthd", weights.astype(q.dtype), v,
@@ -508,16 +561,18 @@ def paged_chunk_attention(
     block_size: int,
     window: int | None = None,
     kv_chunk: int = 512,
+    sink: jax.Array | None = None,   # (H,) a key without a value a head
 ) -> jax.Array:
     """Chunk-prefill attention of one slot against its pages, the chunk's
     own K/V already written: a loop over ``kv_chunk``-row stretches of the
     context from the first one a query of the chunk attends to the chunk's
     end, with a running softmax — ``window + T`` rows at most on a window
     layer, the rows before the chunk's end on a full one, never the table's
-    whole width."""
+    whole width.  The V pool's heads may be narrower than K's; ``sink``
+    joins each head's softmax as a key with no value."""
     t, h, d = q.shape
-    width = k_pool.shape[-1]
-    h_kv = width // d
+    h_kv = k_pool.shape[-1] // d
+    dv = v_pool.shape[-1] // h_kv
     g = h // h_kv
     kv_chunk = max(block_size, kv_chunk // block_size * block_size)
     bpc = kv_chunk // block_size
@@ -527,15 +582,16 @@ def paged_chunk_attention(
     lo = 0 if window is None else jnp.maximum(start - window + 1, 0)
     scale = d ** -0.5
 
-    def pages(pool, c):
+    def pages(pool, c, dim):
         blocks = table_row[jnp.minimum(c * bpc + jnp.arange(bpc), nb - 1)]
-        x = pool.reshape(pool.shape[0], -1, block_size, width)[layer, blocks]
-        return x.reshape(kv_chunk, h_kv, d)
+        x = pool.reshape(pool.shape[0], -1, block_size,
+                         pool.shape[-1])[layer, blocks]
+        return _row_heads(x, (kv_chunk,), dim)
 
     def body(c, carry):
         m, l, acc = carry
         kpos = c * kv_chunk + jnp.arange(kv_chunk, dtype=jnp.int32)
-        s = jnp.einsum("qhgd,khd->hgqk", qg, pages(k_pool, c),
+        s = jnp.einsum("qhgd,khd->hgqk", qg, pages(k_pool, c, d),
                        preferred_element_type=jnp.float32) * scale
         ok = kpos[None, :] <= qpos[:, None]
         if window is not None:
@@ -545,31 +601,42 @@ def paged_chunk_attention(
         alpha = jnp.exp(m - m_new)
         p = jnp.where(ok[None, None], jnp.exp(s - m_new[..., None]), 0.0)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "hgqk,khd->hgqd", p.astype(q.dtype), pages(v_pool, c),
+            "hgqk,khd->hgqd", p.astype(q.dtype), pages(v_pool, c, dv),
             preferred_element_type=jnp.float32)
         return m_new, l * alpha + p.sum(-1), acc
 
     init = (jnp.full((h_kv, g, t), NEG_INF, jnp.float32),
             jnp.zeros((h_kv, g, t), jnp.float32),
-            jnp.zeros((h_kv, g, t, d), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(
+            jnp.zeros((h_kv, g, t, dv), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(
         lo // kv_chunk, -(-(start + t) // kv_chunk), body, init)
+    if sink is not None:
+        l = l + jnp.exp(sink.astype(jnp.float32).reshape(h_kv, g, 1) - m)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(2, 0, 1, 3).reshape(t, h, d).astype(q.dtype)
+    return out.transpose(2, 0, 1, 3).reshape(t, h, dv).astype(q.dtype)
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
-                         v_hbm, o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc,
-                         *, block_size, n_steps, scale):
+                         v_hbm, *refs, block_size, n_steps, scale,
+                         rest_at=None):
+    """``rest_at``: where a K head is wider than its tile (:func:`lay_heads`),
+    the lane at which the heads' remainders start in the K row, two of 64 a
+    tile; a head's scores are then two products, one over its whole tile and
+    one over its remainder's (``q_ref`` holds the two query tiles of a head
+    one after the other).  With a sink, ``refs`` starts with its (tiles,
+    query rows, 128) float32 block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sink_ref = refs[0] if len(refs) == 8 else None
+    o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc = refs[-7:]
     s, c = pl.program_id(0), pl.program_id(1)
     rows = PAGED_ROWS
     step_rows = rows * PAGED_STRETCHES
     bps = rows // block_size                   # blocks a stretch
     nb = tables_ref.shape[1]
-    tiles, q_rows = q_ref.shape[1], q_ref.shape[2]
+    tiles, q_rows = o_ref.shape[1], o_ref.shape[2]
+    parts = q_ref.shape[1] // tiles            # query tiles a K/V tile
     n, lo, layer = lens_ref[s], lo_ref[s], layer_ref[0]
     first = (lo // step_rows + c) * step_rows  # first key row of this step
 
@@ -629,10 +696,16 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
             for hh in range(tiles):
                 lanes = pl.ds(hh * LANES, LANES)
                 sc = jax.lax.dot_general(
-                    q_ref[0, hh], kbuf[here, lanes],
+                    q_ref[0, hh * parts], kbuf[here, lanes],
                     (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale  # (8, rows)
-                sc = jnp.where(valid, sc, NEG_INF)
+                    preferred_element_type=jnp.float32)         # (8, rows)
+                if rest_at is not None:
+                    sc += jax.lax.dot_general(
+                        q_ref[0, hh * parts + 1],
+                        kbuf[here, pl.ds(rest_at + hh // 2 * LANES, LANES)],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                sc = jnp.where(valid, sc * scale, NEG_INF)
                 m_prev = m_sc[hh]
                 m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
@@ -645,19 +718,29 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
 
     @pl.when(c == n_steps - 1)
     def _():
-        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-                    ).astype(o_ref.dtype)
+        l = l_sc[...]
+        if sink_ref is not None:
+            # the key without a value: one more term of the denominator
+            l = l + jnp.exp(sink_ref[...] - m_sc[...])
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
-                             block_size: int, impl: str = "auto") -> str:
+                             block_size: int, impl: str = "auto",
+                             value_dim: int | None = None) -> str:
     """Which formulation :func:`paged_window_decode_attention` takes at
     these shapes: ``"paged_attn"`` (the kernel) or ``"plain"`` (the gather
     of every table column).  A test of shapes and of ``impl`` alone, so a
     program can say what it was built with (``serve.model``)."""
-    fits = (head_dim in (64, LANES) and heads // kv_heads <= 32
-            and PAGED_ROWS % block_size == 0
-            and kv_heads * head_dim % LANES == 0)
+    if (value_dim or head_dim) == head_dim:
+        fits = head_dim in (64, LANES) and kv_heads * head_dim % LANES == 0
+    else:
+        # keys a tile and a half wide over values of one tile: the heads'
+        # remainders lie two a tile after their whole tiles (``lay_heads``)
+        fits = (value_dim == LANES and _tile_split(head_dim) == LANES
+                and head_dim - LANES == LANES // 2 and kv_heads % 2 == 0)
+    fits = (fits and heads // kv_heads <= 32
+            and PAGED_ROWS % block_size == 0)
     return "paged_attn" if use_kernel(impl) and fits else "plain"
 
 
@@ -673,6 +756,7 @@ def paged_window_decode_attention(
     window: int | None = None,
     impl: str = "auto",
     interpret: bool | None = None,
+    sink: jax.Array | None = None,   # (H,) a key without a value a head
 ) -> jax.Array:
     """Single-token decode attention that reads only what each slot
     attends: the blocks holding rows ``[max(len - window, 0), len)``, so
@@ -693,18 +777,28 @@ def paged_window_decode_attention(
     compute nothing.  Needs ``D`` of 64 or 128, ``H // Hkv <= 32`` (a tile's
     query heads are its rows: 24 at 20 on 1), a block size that divides 128
     and a pool row of whole tiles (:func:`paged_decode_formulation`); other
-    shapes, and ``impl="xla"``, take the plain formulation."""
+    shapes, and ``impl="xla"``, take the plain formulation.
+
+    Keys of 192 over values of 128 (MiMo-V2; the K row as :func:`lay_heads`
+    stores it): a tile is one K/V head, whose scores are two products — the
+    query's first 128 values against the head's whole K tile, its last 64
+    against the head's half of a remainder tile (zeros in the neighbour's
+    lanes) — and whose output is the one V tile.  ``sink`` (H,), a learned
+    bias a query head, joins the softmax as a key with no value: ``acc / (l
+    + exp(b_h - m))`` in the kernel's last division."""
     b, h, d = q.shape
     width = k_pool.shape[-1]
     h_kv = width // d
+    dv = v_pool.shape[-1] // h_kv
     g = h // h_kv
     step_rows = PAGED_ROWS * PAGED_STRETCHES
-    if paged_decode_formulation(h, h_kv, d, block_size, impl) == "plain":
+    if paged_decode_formulation(h, h_kv, d, block_size, impl,
+                                dv) == "plain":
         # the plain formulation (gathers every table column): the tests'
         # yardstick for the kernel and the path off the TPU
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, attend_lens, layer=layer,
-            block_size=block_size, window=window)
+            block_size=block_size, window=window, sink=sink)
     if interpret is None:
         interpret = not on_tpu()
     cap = block_tables.shape[1] * block_size
@@ -713,28 +807,49 @@ def paged_window_decode_attention(
     lens = attend_lens.astype(jnp.int32)
     lo = (jnp.zeros_like(lens) if window is None
           else jnp.maximum(lens - window, 0))
-    per_tile = LANES // d             # K/V heads a tile: 1, or 2 at D = 64
-    tiles = width // LANES
+    per_tile = max(LANES // d, 1)     # K/V heads a tile: 1, or 2 at D = 64
+    tiles = v_pool.shape[-1] // LANES
     q_rows = -(-per_tile * g // 8) * 8
-    # (B, tile, head of the tile, query of the head, lanes of a head, D):
-    # each head's query in its own lanes of the tile, zeros in the others
-    own = jnp.eye(per_tile, dtype=bool)[:, None, :, None]
-    qt = jnp.where(own, q.reshape(b, tiles, per_tile, g, 1, d), 0)
-    qt = jnp.pad(qt.reshape(b, tiles, per_tile * g, LANES),
-                 ((0, 0), (0, 0), (0, q_rows - per_tile * g), (0, 0)))
+    pad_rows = ((0, 0), (0, 0), (0, q_rows - per_tile * g), (0, 0))
+    rest_at = None
+    if d > LANES:
+        # (B, head, part, query of the head, lanes): a head's first 128
+        # values, then its last 64 in its own half of the remainder tile
+        rest_at = h_kv * LANES
+        qh = q.reshape(b, h_kv, g, d)
+        own = (jnp.arange(h_kv)[:, None] % 2
+               == jnp.arange(2)[None, :])[:, None, :, None]
+        rest = jnp.where(own, qh[:, :, :, None, LANES:], 0)
+        qt = jnp.stack([qh[..., :LANES], rest.reshape(b, h_kv, g, LANES)],
+                       axis=2).reshape(b, 2 * h_kv, g, LANES)
+    else:
+        # (B, tile, head of the tile, query of the head, lanes of a head,
+        # D): each head's query in its own lanes of the tile, zeros in the
+        # others
+        own = jnp.eye(per_tile, dtype=bool)[:, None, :, None]
+        qt = jnp.where(own, q.reshape(b, tiles, per_tile, g, 1, d), 0)
+        qt = qt.reshape(b, tiles, per_tile * g, LANES)
+    qt = jnp.pad(qt, pad_rows)
+    if sink is not None:
+        # a row's bias across its lanes, as the running maximum and sum lie
+        sink = jnp.broadcast_to(jnp.pad(
+            sink.astype(jnp.float32).reshape(1, tiles, per_tile * g, 1),
+            pad_rows)[0], (tiles, q_rows, PAGED_ROWS))
     out = _paged_attn_call(
         block_tables.astype(jnp.int32), lens, lo,
-        jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool,
+        jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool, sink,
         block_size=block_size, n_steps=-(-span // step_rows),
-        scale=d ** -0.5, interpret=interpret)
+        scale=d ** -0.5, interpret=interpret, rest_at=rest_at)
+    if d > LANES:
+        return out[:, :, :g].reshape(b, h, dv)
     out = out[:, :, :per_tile * g].reshape(b, tiles, per_tile, g, per_tile, d)
     return jnp.where(own, out, 0).sum(axis=4).reshape(b, h, d)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "n_steps", "scale", "interpret"))
-def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, *,
-                     block_size, n_steps, scale, interpret):
+    "block_size", "n_steps", "scale", "interpret", "rest_at"))
+def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
+                     *, block_size, n_steps, scale, interpret, rest_at=None):
     """The kernel's call.  A jitted function of its own with the layer as a
     prefetched scalar, so that the layers of a program that call it at the
     same shapes share one trace and one lowering of the body (0.8 s a call
@@ -742,32 +857,38 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, tiles, q_rows, _ = qt.shape
-    width = k_pool.shape[-1]
+    b, q_tiles, q_rows, _ = qt.shape
+    tiles = v_pool.shape[-1] // LANES
     step_rows = PAGED_ROWS * PAGED_STRETCHES
-    blk = pl.BlockSpec((1, tiles, q_rows, LANES),
-                       lambda s, c, *_: (s, 0, 0, 0))
+
+    def blk(n):
+        return pl.BlockSpec((1, n, q_rows, LANES),
+                            lambda s, c, *_: (s, 0, 0, 0))
+
+    whole = [] if sink is None else [pl.BlockSpec(
+        sink.shape, lambda s, c, *_: (0, 0, 0))]
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, block_size=block_size, n_steps=n_steps,
-            scale=scale),
+            scale=scale, rest_at=rest_at),
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, n_steps),
-            in_specs=[blk, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=blk,
+            in_specs=[blk(q_tiles), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY), *whole],
+            out_specs=blk(tiles),
             scratch_shapes=[
-                pltpu.VMEM((step_rows, width), k_pool.dtype),
-                pltpu.VMEM((step_rows, width), v_pool.dtype),
+                pltpu.VMEM((step_rows, k_pool.shape[-1]), k_pool.dtype),
+                pltpu.VMEM((step_rows, v_pool.shape[-1]), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, step_rows // block_size)),
                 pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((tiles, q_rows, LANES), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, tiles, q_rows, LANES), qt.dtype),
         interpret=interpret,
-    )(tables, lens, lo, layer, qt, k_pool, v_pool)
+    )(tables, lens, lo, layer, qt, k_pool, v_pool,
+      *(() if sink is None else (sink,)))
 
 
 # ---------------------------------------------------------------------------
@@ -1797,23 +1918,34 @@ def sparse_latent_attention(q, pool, rows, counts, *, layer, rank, scale,
 @dataclasses.dataclass(frozen=True)
 class KVRows:
     """A token's K of all K/V heads in one pool, its V in another; a block
-    calls ``attend(q, k, v)``."""
+    calls ``attend(q, k, v)``, or ``attend(q, k, v, sink=b)`` where a learned
+    bias a query head joins the softmax as a key with no value.  A V head
+    may be narrower than a K head (``value_dim``; the output is as wide)."""
 
     heads: int
     kv_heads: int
     head_dim: int
+    value_dim: int | None = None        # None: as wide as a K head
     #: each K/V head has rows of its own
     shared_row = False
 
     @property
     def widths(self) -> tuple[int, ...]:
-        return (self.kv_heads * self.head_dim,) * 2
+        return (self.kv_heads * self.head_dim,
+                self.kv_heads * (self.value_dim or self.head_dim))
 
     values = widths
 
+    def stored(self, k, v) -> tuple:
+        """The rows as the pools hold them: K's heads split at a lane tile
+        where a head is wider than one (:func:`lay_heads`), V's as they
+        come."""
+        return lay_heads(k), v
+
     def decode_formulation(self, block_size: int, impl: str) -> str:
         return paged_decode_formulation(
-            self.heads, self.kv_heads, self.head_dim, block_size, impl)
+            self.heads, self.kv_heads, self.head_dim, block_size, impl,
+            self.value_dim)
 
     def chunk_formulation(self, block_size: int, chunk: int,
                           impl: str) -> str:
@@ -1847,6 +1979,11 @@ class LatentRows:
     scale: float
     #: every head attends the one row
     shared_row = True
+
+    @staticmethod
+    def stored(*rows) -> tuple:
+        """The rows as the pools hold them: as the block hands them."""
+        return rows
 
     @property
     def values(self) -> tuple[int, ...]:

@@ -494,6 +494,9 @@ class Engine:
         #: index keys it scored to select them (every cached token x latent
         #: layers); counted only where a group stores latent rows
         self._step_latent = [0, 0, 0]
+        #: the current step's {group: K/V rows its decode iteration
+        #: attended}; counted only over several paged groups
+        self._step_rows_read: dict[str, int] = {}
         #: the current step's tokens through the recurrence of a state
         #: group: the real tokens of its prefill chunks and one a decoding
         #: slot; counted only where a group keeps a state a slot
@@ -587,6 +590,18 @@ class Engine:
         self._m_spec_accepted = reg.counter(
             "serve_spec_accepted_total",
             "draft tokens accepted by the verifier (always <= drafted)")
+        #: whether a decode iteration's attended rows are counted a group:
+        #: where the layers lie in more than one paged group (window layers
+        #: beside full ones), whose reads differ by the window
+        self._count_rows = len(self.kv.paged) > 1
+        if self._count_rows:
+            self._m_rows_read = {
+                name: reg.counter(
+                    f"serve_{name}_rows_read_total",
+                    f"K/V rows the decode iterations attended in the {name} "
+                    "group: a slot's length (at most the window) x its "
+                    "layers")
+                for name in self.kv.paged}
         if self.kv.index_topk:
             self._m_latent_read = reg.counter(
                 "serve_latent_rows_read_total",
@@ -852,6 +867,7 @@ class Engine:
         self._step_evicted = 0
         self._step_sampled = (0, 0)
         self._step_latent = [0, 0, 0]
+        self._step_rows_read = {}
         self._step_scan = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace) whose leaves tile it: a leaf begins where the one before
@@ -1113,6 +1129,9 @@ class Engine:
                 fields["latent_rows_read"] = read
                 if self.kv.index_topk:
                     fields["index_rows_scored"] = scored
+        if occupancy:
+            for name, read in self._step_rows_read.items():
+                fields[f"{name}_rows_read"] = read
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
@@ -1417,6 +1436,15 @@ class Engine:
                 self._step_latent[2] = scored
                 self._m_latent_read.inc(self._step_latent[1])
                 self._m_index_scored.inc(scored)
+        if self._count_rows:
+            lens = self.kv.seq_lens[slots]
+            for name, g in self.kv.paged.items():
+                window = getattr(g, "window", None)
+                read = len(self.kv.layers[name]) * int(
+                    (lens if window is None
+                     else np.minimum(lens, window)).sum())
+                self._step_rows_read[name] = read
+                self._m_rows_read[name].inc(read)
         if self.kv.state is not None:
             self._step_scan += n_active
         self._commit_tokens(
@@ -1936,6 +1964,7 @@ class Engine:
             "chunk_scan": self.programs.chunk_scan,
             # bytes the cache stores a token over all layers
             "cache_row_bytes": self.kv.row_bytes,
+            "kv_groups": self.kv_groups(),
             "spec_acceptance_rate": (
                 self.counters["spec_accepted"] / self.counters["spec_drafted"]
                 if self.counters["spec_drafted"] else 0.0
@@ -1946,6 +1975,16 @@ class Engine:
             ),
             "max_context": self.kv.max_context,
         }
+
+    def kv_groups(self) -> dict:
+        """``{paged group: what it stores a token a layer (its form, K/V
+        heads, bytes of values and as laid out) and the formulations the
+        one-token program and a prefill chunk attend its pages with}``: the
+        ``startup.engine_build`` row's and ``state()``'s."""
+        forms = self.programs.formulations
+        return {name: {"layers": len(self.kv.layers[name]), **g.census,
+                       **forms[name]}
+                for name, g in self.kv.paged.items()}
 
     def _log_request(self, req: GenRequest) -> None:
         row = {

@@ -66,9 +66,12 @@ Stored form (:func:`pool_shape`): ``(num_layers, (num_blocks + 1) *
 block_size, row width)`` per pool — **token rows**, one stacked array for
 all layers so the decode program indexes layers without a pytree of leaves.
 A group states the rows it stores a token a layer (``rows``, the model's
-``cfg.cache_rows``; one pool a width of ``rows.widths``): the K/V pair, two
-pools of ``kv_heads * head_dim`` with the heads folded into the minor
-dimension; or
+``cfg.cache_rows``, or its entry for the group where the groups' rows
+differ: :func:`group_rows`; one pool a width of ``rows.widths``): the K/V
+pair, two pools of ``kv_heads * head_dim`` with the heads folded into the
+minor dimension (the V pool ``kv_heads * value_dim`` where values are
+narrower than keys, and a K head wider than a lane tile split at the tile:
+``ops.attention.lay_heads``); or
 one pool of latent rows ``[c_kv | k_rope]`` that every head shares
 (``models.joyai``: 512 + 64 values = 1,152 bytes in bf16, stored 640 wide —
 five lane tiles, the last 64 lanes zero — because the TPU lays a 576-wide
@@ -378,6 +381,18 @@ class PagedKVCache:
         """Bytes of values the group stores a token a layer, over its pools
         (lane padding not counted: ``rows.widths`` has it)."""
         return sum(self.rows.values) * self.pools[0].dtype.itemsize
+
+    @property
+    def census(self) -> dict:
+        """What the group stores, for the start-up row and ``stats()``: the
+        form's name, its K/V heads (None where every head shares the one
+        row), and the bytes a token a layer, of values and as the pools are
+        laid out (lane padding with it)."""
+        size = self.pools[0].dtype.itemsize
+        return {"form": type(self.rows).__name__,
+                "kv_heads": getattr(self.rows, "kv_heads", None),
+                "row_bytes": self.row_bytes,
+                "row_bytes_laid_out": sum(self.rows.widths) * size}
 
     def _on_evict(self, block: int) -> None:
         h = self._block_hash.pop(block, None)
@@ -1015,7 +1030,8 @@ class GroupedKVCache:
         top["groups"] = {
             name: {"blocks_total": s["blocks_total"],
                    "blocks_used": s["blocks_used"],
-                   "blocks_free": s["blocks_free"]}
+                   "blocks_free": s["blocks_free"],
+                   **self.paged[name].census}
             for name, s in per_group.items()}
         top["blocks_recycled"] = self.blocks_recycled
         if self.state is not None:
@@ -1037,13 +1053,23 @@ def layer_groups(cfg) -> dict[str, tuple[int, ...]]:
     return {name: tuple(ls) for name, ls in kinds.items() if ls}
 
 
+def group_rows(cfg, name: str):
+    """What group ``name`` of ``cfg``'s layers caches a token a layer
+    (``ops.attention``: a ``KVRows``, ``LatentRows``, ...): ``cfg.cache_rows``
+    is the one form of every group, or ``{group: form}`` where the groups'
+    rows differ (MiMo-V2: 8 K/V heads a window layer, 4 a full one)."""
+    rows = cfg.cache_rows
+    return rows[name] if isinstance(rows, dict) else rows
+
+
 def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                        max_context: int, num_blocks: dict[str, int | None],
                        write_ahead: int) -> GroupedKVCache:
     """The groups of a model (:func:`layer_groups`): a ``"full"`` and a
     ``"window"`` group, whichever exist (GPT-2 is one full group), each
-    storing the rows the config names (``cfg.cache_rows``: the K/V pair, or
-    one latent row), and a ``"state"`` group of what ``cfg.state_rows`` names.
+    storing the rows the config names for it (:func:`group_rows`: the K/V
+    pair, or one latent row), and a ``"state"`` group of what
+    ``cfg.state_rows`` names.
     ``num_blocks[name] = None`` provisions every slot's worst
     case (full provisioning; fewer oversubscribes — paged memory is the
     point — and admission control, not OOM, then absorbs the pressure)."""
@@ -1066,7 +1092,7 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                 num_layers=len(ls), rows=cfg.state_rows, max_slots=max_slots,
                 dtype=cfg.dtype)
             continue
-        kw = dict(num_layers=len(ls), rows=cfg.cache_rows,
+        kw = dict(num_layers=len(ls), rows=group_rows(cfg, name),
                   max_slots=max_slots, block_size=block_size,
                   max_context=max_context, dtype=cfg.dtype)
         if name == "window":

@@ -10,15 +10,20 @@ prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
-``models.joyai``, ``models.jamba``): ``embed(params, ids, cfg)``, ``block(p,
+``models.joyai``, ``models.jamba``, ``models.mimo``): ``embed(params, ids,
+cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
 ``init_params(cfg, key)``.  Its
 configuration says what a cached row is (``cfg.cache_rows``, an
 ``ops.attention.KVRows``, ``LatentRows`` or ``SparseLatentRows``: the widths
-of the group's pools and the paged formulations over them), and its block
-calls ``attend(q, *rows, **weights)`` with the rows to store, one a pool:
-``attend(q, k, v)`` where a token's K and V of all heads are cached,
+of the group's pools and the paged formulations over them; ``{group:
+form}`` where the groups' rows differ — mimo's window layers cache 8 K/V
+heads a token and its full layers 4 —, ``serve.kv_cache.group_rows``), and
+its block calls ``attend(q, *rows, **weights)`` with the rows to store, one a
+pool: ``attend(q, k, v)`` where a token's K and V of all heads are cached
+(``attend(q, k, v, sink=b)`` on a layer whose heads have a learned sink; the
+form lays a head wider than a lane tile out as it stores it, ``stored``),
 ``attend((q_nope, q_rope), row, w_uk=, w_uv=)`` where one latent row is and
 the query comes in two parts, ``attend((q_nope, q_rope, q_index, w_index),
 row, index_key, w_uk=, w_uv=)`` where an indexer selects the latent rows a
@@ -105,8 +110,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, gpt, jamba, joyai
+from ..models import afmoe, gpt, jamba, joyai, mimo
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
+from .kv_cache import group_rows
 from .sampling import sample_burst
 
 __all__ = [
@@ -123,12 +129,19 @@ def _group_of(layers: dict[str, tuple[int, ...]]) -> dict[int, tuple]:
             for i, layer in enumerate(ls)}
 
 
-def _write_rows(pools: tuple, li: int, at, rows: tuple) -> tuple:
-    """The group's pools with ``rows`` (one array a pool, a row a token)
-    written at pool rows ``at`` of layer ``li``."""
+def _forms_of(cfg, layers: dict[str, tuple[int, ...]]) -> dict:
+    """``{group: the form of its rows}`` over the paged groups."""
+    return {name: group_rows(cfg, name) for name in layers
+            if name != "state"}
+
+
+def _write_rows(form, pools: tuple, li: int, at, rows: tuple) -> tuple:
+    """The group's pools with ``rows`` (one array a pool, a row a token, as
+    the block handed them to ``attend``) written at pool rows ``at`` of
+    layer ``li``, laid out as the form stores them."""
     with jax.named_scope("kv_write"):
         return tuple(pool.at[li, at].set(r.reshape(at.shape[0], -1))
-                     for pool, r in zip(pools, rows))
+                     for pool, r in zip(pools, form.stored(*rows)))
 
 
 class _SlotState:
@@ -232,7 +245,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     rows are harmless, a pad step of a recurrence is not); the slot's state
     is row ``table_rows["state"][0]`` of the group's arrays.  The pools are
     donated."""
-    where, form = _group_of(layers), cfg.cache_rows
+    where, forms = _group_of(layers), _forms_of(cfg, layers)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, pools, tokens, start, table_rows, last_ix,
@@ -248,7 +261,9 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
             name, li = where[layer]
 
             def attend(q, *stored, name=name, li=li, layer=layer, **weights):
-                pools[name] = _write_rows(pools[name], li, rows[name], stored)
+                form = forms[name]
+                pools[name] = _write_rows(form, pools[name], li, rows[name],
+                                          stored)
                 return form.chunk(
                     q, start, pools[name], table_rows[name], layer=li,
                     block_size=block_size, window=cfg.window_of(layer),
@@ -285,7 +300,7 @@ def make_decode_fn(family, cfg, *, block_size: int,
     landed on held experts (sum), the held experts hit (sum) and the largest
     load of one expert (max) — active slots only; None from a model without
     expert layers."""
-    where, form = _group_of(layers), cfg.cache_rows
+    where, forms = _group_of(layers), _forms_of(cfg, layers)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, pools, tokens, tables, seq_lens, active):
@@ -309,7 +324,9 @@ def make_decode_fn(family, cfg, *, block_size: int,
             name, li = where[layer]
 
             def attend(q, *stored, name=name, li=li, layer=layer, **weights):
-                pools[name] = _write_rows(pools[name], li, rows[name], stored)
+                form = forms[name]
+                pools[name] = _write_rows(form, pools[name], li, rows[name],
+                                          stored)
                 return form.decode(
                     q, pools[name], tables[name], attend_lens, layer=li,
                     block_size=bs, window=cfg.window_of(layer),
@@ -373,7 +390,7 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
     produced (parity pinned by tests/test_serve_spec.py, incl. bf16).
     ``paged_verify_attention`` masks no window: full layers only.
     """
-    where, form = _group_of(layers), cfg.cache_rows
+    where, forms = _group_of(layers), _forms_of(cfg, layers)
     t_width = draft + 1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -407,12 +424,15 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
         for layer in range(cfg.num_layers):
             name, li = where[layer]
 
-            def attend(q, *stored, name=name, li=li):
-                pools[name] = _write_rows(pools[name], li, rows[name], stored)
-                return form.verify(
+            def attend(q, *stored, name=name, li=li, **weights):
+                form = forms[name]
+                pools[name] = _write_rows(form, pools[name], li, rows[name],
+                                          stored)
+                out = form.verify(
                     q.reshape(b, t_width, *q.shape[1:]), pools[name],
                     tables[name], attend_lens, layer=li, block_size=bs,
-                ).reshape(q.shape)
+                    **weights)
+                return out.reshape(b * t_width, *out.shape[2:])
 
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(
@@ -454,6 +474,7 @@ PROGRAMS = {
     afmoe.AfmoeConfig: afmoe,
     joyai.JoyaiConfig: joyai,
     jamba.JambaConfig: jamba,
+    mimo.MimoConfig: mimo,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -483,6 +504,12 @@ _STATE_LACKS = {
                       "draft, which has no state formulation",
     "speculate": "a rejected draft cannot be rolled back out of a state",
 }
+
+
+def _one_or_each(names) -> str:
+    """The formulation every group takes, or ``"a|b"`` in group order where
+    the groups' differ."""
+    return "|".join(dict.fromkeys(names))
 
 
 def family_of(cfg):
@@ -538,13 +565,22 @@ class Programs:
         # for them, and they attend with ``paged_verify_attention``
         if self._fused:
             return "plain"
-        return self.cfg.cache_rows.decode_formulation(
-            self.block_size, self.cfg.kernel_impl)
+        return _one_or_each(f["decode"] for f in self.formulations.values())
 
     @property
     def chunk_attention(self) -> str:
-        return self.cfg.cache_rows.chunk_formulation(
-            self.block_size, self.chunk, self.cfg.kernel_impl)
+        return _one_or_each(f["chunk"] for f in self.formulations.values())
+
+    @property
+    def formulations(self) -> dict[str, dict[str, str]]:
+        """``{group: {"decode": ..., "chunk": ...}}``: what the one-token
+        program and a prefill chunk attend each paged group's pages with."""
+        impl = self.cfg.kernel_impl
+        return {name: {
+            "decode": form.decode_formulation(self.block_size, impl),
+            "chunk": form.chunk_formulation(self.block_size, self.chunk,
+                                            impl)}
+            for name, form in _forms_of(self.cfg, self.layers).items()}
 
     @property
     def chunk_scan(self) -> str | None:

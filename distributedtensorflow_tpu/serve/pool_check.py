@@ -56,13 +56,15 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
-                  chunk: int, draft: int, sharding=None):
+                  chunk: int, draft: int, sharding=None,
+                  window_blocks: int | None = None):
     """``{name: (jitted program, abstract arguments)}`` for the five
     programs that take the pool, at the shapes an ``Engine`` with these
     settings gives them (``cfg.max_seq`` is the serving context; a model of
-    one full group, as GPT-2; or of a full group and a state group, whose
-    arrays are ``pools["state"]`` and whose prefill chunk takes the count of
-    real tokens)."""
+    one full group, as GPT-2; of a full group and a window group of
+    ``window_blocks`` blocks, each of its own rows; or of a full group and a
+    state group, whose arrays are ``pools["state"]`` and whose prefill chunk
+    takes the count of real tokens)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -72,12 +74,17 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
         jax.eval_shape(lambda: family_of(cfg).init_params(
             cfg, jax.random.PRNGKey(0))))
     layers = kv_cache.layer_groups(cfg)
-    pools = {"full": tuple(
-        sds(kv_cache.pool_shape(len(layers["full"]), num_blocks, block_size,
+    paged = [name for name in layers if name != "state"]
+    blocks = {"full": num_blocks, "window": window_blocks or num_blocks}
+    pools = {name: tuple(
+        sds(kv_cache.pool_shape(len(layers[name]), blocks[name], block_size,
                                 width), cfg.dtype)
-        for width in cfg.cache_rows.widths)}
-    table_rows = {"full": sds((cfg.max_seq // block_size,), i32)}
-    tables = {"full": sds((max_slots, cfg.max_seq // block_size), i32)}
+        for width in kv_cache.group_rows(cfg, name).widths)
+        for name in paged}
+    table_rows = {name: sds((cfg.max_seq // block_size,), i32)
+                  for name in paged}
+    tables = {name: sds((max_slots, cfg.max_seq // block_size), i32)
+              for name in paged}
     slots_i32 = sds((max_slots,), i32)
     active = sds((max_slots,), jnp.bool_)
     scalar = sds((), i32)
@@ -184,8 +191,11 @@ def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
         name = m.group(3)
         pool = _POOLS_ARG.match(name)
         if pool:
-            names = _STATE_NAMES if pool.group(1) == "state" else _POOL_NAMES
+            group = pool.group(1)
+            names = _STATE_NAMES if group == "state" else _POOL_NAMES
             name = names[int(pool.group(2))]
+            if group not in (None, "full", "state"):
+                name = f"{group}.{name}"        # "window.k_pool"
         params[int(m.group(2))] = (name, m.group(1))
     return params
 
@@ -200,7 +210,8 @@ def donated_pools(hlo_text: str) -> set[str]:
     aliased = {int(n) for n in re.findall(
         r"\{[\d, ]*\}: \((\d+), ", head.partition("entry_computation")[0])}
     return {name for number, (name, _) in _entry_parameters(hlo_text).items()
-            if number in aliased and name in _POOL_NAMES + _STATE_NAMES}
+            if number in aliased
+            and name.rpartition(".")[2] in _POOL_NAMES + _STATE_NAMES}
 
 
 def check_pool_programs(programs: dict, layer_elems: int,
@@ -225,16 +236,20 @@ def check_pool_programs(programs: dict, layer_elems: int,
     return report
 
 
-def failures(report: dict, pools: int = 2, state: bool = False) -> list[str]:
+def failures(report: dict, pools: int = 2, state: bool = False,
+             window: bool = False) -> list[str]:
     """What :func:`check_pool_programs` found wrong, one line each, for a
-    group of ``pools`` pools (the K/V pair, or one pool of latent rows) and,
-    with ``state``, a state group's arrays (``copy_block`` takes the pools
+    group of ``pools`` pools (the K/V pair, or one pool of latent rows), with
+    ``window`` a window group's beside the full group's, and, with ``state``,
+    a state group's arrays (``copy_block`` takes the full group's pools
     only)."""
     bad = []
     for name, r in report.items():
         for op in r["relayouts"]:
             bad.append(f"{name}: pool-sized {op}")
         want = list(_POOL_NAMES[:pools])
+        if window and name != "copy_block":
+            want = sorted(want + [f"window.{n}" for n in want])
         if state and name != "copy_block":
             want = sorted(want + list(_STATE_NAMES))
         if r["donated"] != want:
@@ -262,7 +277,7 @@ def main(argv=None) -> int:
 
     cfg = dataclasses.replace(getattr(models, args.config)(),
                               max_seq=args.max_context)
-    widths = cfg.cache_rows.widths
+    widths = kv_cache.group_rows(cfg, "full").widths
     layers = kv_cache.layer_groups(cfg)
     shape = kv_cache.pool_shape(len(layers["full"]), args.kv_blocks,
                                 args.block_size, widths[0])

@@ -54,6 +54,10 @@ CONFIGS = {
     # the jamba family (models/jamba.py): Mamba + attention layers, random init
     "jamba_tiny": ("jamba_tiny", None),
     "jamba2_3b": ("jamba2_3b", None),
+    # the mimo family (models/mimo.py): window layers with a sink beside full
+    # ones, a K/V head count a kind, keys wider than values; random init
+    "mimo_tiny": ("mimo_tiny", None),
+    "mimo_v25_ep16": ("mimo_v25_ep16", None),
 }
 
 
@@ -311,7 +315,8 @@ def main(argv=None) -> int:
                  decode_attention=engine.programs.decode_attention,
                  chunk_attention=engine.programs.chunk_attention,
                  chunk_scan=engine.programs.chunk_scan,
-                 cache_row_bytes=engine.kv.row_bytes)
+                 cache_row_bytes=engine.kv.row_bytes,
+                 kv_groups=engine.kv_groups())
     server = ServeServer(engine, args.port, host=args.host).start()
     # Per-tenant usage ledger: GET /usagez next to the generation
     # endpoint (text / ?json / ?tenant= filter; usage.jsonl under

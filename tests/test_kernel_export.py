@@ -116,6 +116,23 @@ def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2,
                 _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32))
 
 
+def _paged_wide(window, kv_heads, slots=32, heads=64, columns=4224):
+    # the mimo serving shapes: 32 slots, 64 query heads on 4 (full) or 8
+    # (window) K/V heads, keys 192 wide over values 128, a table of 4,224
+    # columns (contexts to 67,584); the window layers' heads have a sink
+    def fn(q, k_pool, v_pool, tables, lens, *sink):
+        return paged_window_decode_attention(
+            q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
+            window=window, impl="pallas", interpret=False,
+            sink=sink[0] if sink else None)
+    sink = (_sds((heads,), F32),) if window else ()
+    return fn, (_sds((slots, heads, 192), BF16),
+                _sds((2, 2049 * 16, kv_heads * 192), BF16),
+                _sds((2, 2049 * 16, kv_heads * 128), BF16),
+                _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32),
+                *sink)
+
+
 def _latent(slots=32, heads=32, rank=512, rope=64, nope=128, columns=1024):
     # the joyai serving shapes: 32 slots, 32 heads over one latent row of
     # 512 + 64 (stored 640 wide), blocks of 16, contexts to 16,384
@@ -280,6 +297,10 @@ FAMILIES = {
     "index_scores_step": _index(1, 24),
     "sparse_latent_attn_chunk": _sparse_latent(1024),
     "sparse_latent_attn_step": _sparse_latent(24),
+    # mimo_v25_ep16: 16 query heads a K/V head, a K head a tile and a half
+    "paged_attn_wide_full": _paged_wide(None, 4),
+    # ... 8 a K/V head, a window of 128 and a sink a head
+    "paged_attn_wide_window_sink": _paged_wide(128, 8),
     "ssm_chunk_scan": _ssm_scan(),
     "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
 }
@@ -566,6 +587,54 @@ def test_sparse_latent_program_keeps_both_pools_in_place_on_a_v5e(
                                      "latent_chunk_attn")}[program]
         for kernel in kernels:
             assert text.count(f'kernel_name = "{kernel}"') == 1, kernel
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode",
+                                     "copy_block"])
+def test_two_form_program_keeps_both_groups_pools_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The mimo family at its published widths, three layers deep (full,
+    window, window) with 8 experts held and a small vocabulary: its programs
+    take the full group's pools (4 K/V heads: rows of 768 and 512) AND the
+    window group's (8: 1536 and 1024), copy or convert no layer of any
+    outside ``paged_attn`` and hand all four back in place; decode attends
+    both groups through the ``paged_attn`` kernel, lowered once a group (the
+    window group's with the sink), and the prefill chunk through the plain
+    loop.  (It is refused the fused programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import mimo_v25_ep16
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+    from distributedtensorflow_tpu.serve.model import make_programs
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        mimo_v25_ep16(), max_seq=4096, layer_pattern=(0, 1, 1),
+        moe_layers=(0, 1, 1), experts_held=8, vocab_size=1024)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=8, num_blocks=8192, window_blocks=2048, block_size=16,
+        chunk=256, draft=4, sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    window = program != "copy_block"        # which takes the full group's
+    for name, blocks in (("full", 8192), ("window", 2048)):
+        for width in kv_cache.group_rows(cfg, name).widths:
+            _, rows, _ = kv_cache.pool_shape(1, blocks, 16, width)
+            report = pool_check.check_pool_programs(
+                {program: programs[program]}, layer_elems=rows * width)
+            assert pool_check.failures(report, window=window) == []
+    assert report[program]["k_pool"] == \
+        "bf16[1,131088,768]{2,1,0:T(8,128)(2,1)}"
+    if window:
+        fn, args = programs[program]
+        text = fn.lower(*args).as_text()
+        kernels = text.count('kernel_name = "paged_attn"')
+        assert kernels == (2 if program == "decode" else 0)
+        assert make_programs(
+            cfg, chunk=256, block_size=16,
+            layers=kv_cache.layer_groups(cfg)).formulations == {
+            "full": {"decode": "paged_attn", "chunk": "plain"},
+            "window": {"decode": "paged_attn", "chunk": "plain"}}
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
