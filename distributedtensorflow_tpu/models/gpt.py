@@ -572,6 +572,32 @@ class GPTLM(nn.Module):
         return qkv_causal_tile(self._kernel_batch(batch), seq, cfg.num_heads,
                                cfg.head_dim, cfg.dtype)
 
+    def xent_products(self, batch: int, seq: int
+                      ) -> tuple[int | None, int | None]:
+        """``(products, chunk_tokens)`` of the loss head over ``(batch,
+        seq)`` tokens: the ``tokens x d x V`` MXU products a step's head
+        runs, forward and backward (``ops.fused_xent.PRODUCTS_PER_STEP``:
+        the backward forms its logits tile and its dlogits once), and the
+        tokens a chunk of that backward holds dlogits for on a device
+        (``ops.fused_xent.dlog_chunk_tokens`` of a shard's ``batch x (seq
+        - 1)`` rows, read under the context mesh).  ``(None, None)`` where
+        the head is not the fused one (``xent_impl``; "auto" off the TPU).
+        Beside ``flash_layout`` in the trainer's start-up row."""
+        from ..ops import fused_xent
+
+        cfg = self.cfg
+        if _xent_impl(cfg) != "fused":
+            return None, None
+        # a shard's rows, dealt out as ``parallel.sharding.token_spec``
+        # deals the head's (batch, seq - 1) tokens
+        mesh = jax.sharding.get_abstract_mesh()
+        rows = self._kernel_batch(batch) * ((seq - 1) // math.prod(
+            mesh.shape[a]
+            for a in kernel_axes((mesh_lib.AXIS_SEQ,), seq - 1) or ()))
+        return fused_xent.PRODUCTS_PER_STEP, fused_xent.dlog_chunk_tokens(
+            rows, cfg.hidden_size, cfg.vocab_size,
+            jnp.dtype(cfg.dtype).itemsize)
+
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True,
                  positions=None, return_hidden: bool = False):
@@ -761,14 +787,20 @@ def lm_loss(model: GPTLM):
     return loss_fn
 
 
+def _xent_impl(cfg: GPTConfig) -> str:
+    """``cfg.xent_impl`` with "auto" resolved: fused on the TPU, chunked
+    elsewhere."""
+    if cfg.xent_impl == "auto":
+        return "fused" if on_tpu() else "chunked"
+    return cfg.xent_impl
+
+
 def _pick_xent(cfg: GPTConfig):
     """Head-loss kernel for ``cfg.xent_impl``: "auto" (fused on TPU,
     chunked elsewhere), "chunked" (fp32 logits tiles), "chunked_bf16"
     (bf16 tiles — half the head HBM traffic, ~1e-2 NLL tolerance), or
     "fused" (Pallas, logits never leave VMEM)."""
-    impl = cfg.xent_impl
-    if impl == "auto":
-        impl = "fused" if on_tpu() else "chunked"
+    impl = _xent_impl(cfg)
     if impl == "fused":
         from ..ops.fused_xent import fused_softmax_xent
 

@@ -38,6 +38,7 @@ from distributedtensorflow_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_qkv,
 )
+from distributedtensorflow_tpu.ops import fused_xent
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
@@ -371,7 +372,11 @@ def test_training_kernels_compile_per_shard_on_a_2x2_mesh():
             "fused_xent_fwd", "fused_xent_bwd_dx",
             "fused_xent_bwd_dw"} <= names, names
     first_dims = {int(s.split("x")[0]) for _, s in shapes}
-    assert first_dims == {B, B * S}, first_dims  # per device, never global
+    # per device, never global; the head's backward a dlog chunk of a
+    # shard's tokens (dx) and the padded vocabulary's dlog rows (dw)
+    chunk = fused_xent.dlog_chunk_tokens(B * S, H * D, V)
+    assert chunk == B * S // 4
+    assert first_dims == {B, B * S, chunk, V + (-V) % 512}, first_dims
     # both forms of the flash kernels: (B, S, 3*H*D) and (B, H, S, D)
     assert {s for n, s in shapes if n == "flash_fwd"} == {
         f"{B}x{S}x{3 * H * D}", f"{B}x{H}x{S}x{D}"}, shapes
@@ -745,3 +750,9 @@ def test_gpt2_medium_step_runs_flash_fwd_once_a_layer_and_fits_a_v5e(
     # the one 1024 x 1024 block a sequence is walked in row sub-tiles
     assert (row["flash_causal_tile"], row["flash_causal_share"]) == (
         256, 0.625)
+    # the head's backward forms its dlogits once, a chunk of 4,096 of a
+    # device's 64 x 1023 tokens at a time: one lowering of each kernel
+    assert (row["xent_products_per_step"], row["xent_dlog_chunk_tokens"]
+            ) == (4, 4096)
+    assert {k: n for k, n in row["kernels"].items() if "xent" in k} == {
+        "fused_xent_fwd": 2, "fused_xent_bwd_dx": 1, "fused_xent_bwd_dw": 1}

@@ -47,7 +47,8 @@ def _loss(a, targets):
     h = y + o.reshape(B, S, H * D)
     return fused_softmax_xent(h, a["wte"], targets, interpret=True,
                               block_tokens=128, block_vocab=128,
-                              block_tokens_dx=128, block_vocab_dx=128)
+                              block_tokens_dx=128, block_vocab_dx=128,
+                              block_tokens_dw=128, block_vocab_dw=128)
 
 
 def _pallas_operand_shapes(jaxpr, inside_shard_map=False, out=None):

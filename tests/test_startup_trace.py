@@ -109,6 +109,11 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     pytest.param(dict(remat=True, attn_impl="pallas", num_kv_heads=2), 1024,
                  ("bhsd", "recomputed", 0, None, None),
                  id="bhsd_at_1024_whole_blocks"),
+    # the fused head: four products a step, and 4 x 63 tokens make one
+    # chunk of one 2048-token block (the dw kernel's token tile)
+    pytest.param(dict(remat=False, attn_impl="xla", xent_impl="fused"), 64,
+                 ("xla", None, None, None, None, 4, 2048),
+                 id="fused_head_four_products"),
 ])
 def test_trainer_row_says_what_a_block_keeps(changes, seq, want, tmp_path):
     """The ``startup.trainer`` row carries ``attn_residuals`` and
@@ -117,8 +122,12 @@ def test_trainer_row_says_what_a_block_keeps(changes, seq, want, tmp_path):
     sub-tiles a causal diagonal block is walked in and the share of its
     square that is computed; null where blocks are taken whole: a block
     under two sub-tiles, ``bhsd``, ``xla``) — what ``train._flash_layout``
-    reads off the model under the trainer's mesh —, and the schema checker
-    takes the row, nulls included."""
+    reads off the model under the trainer's mesh —, and
+    ``xent_products_per_step`` / ``xent_dlog_chunk_tokens`` (the head's
+    ``tokens x d x V`` products a step and the tokens a chunk of its
+    backward holds dlogits for; null where the head is not the fused one,
+    as "auto" is not on this CPU), and the schema checker takes the row,
+    nulls included."""
     import dataclasses
     import types
 
@@ -139,8 +148,9 @@ def test_trainer_row_says_what_a_block_keeps(changes, seq, want, tmp_path):
         wl, build_mesh(MeshSpec(data=1), jax.devices()[:1]))
     assert fields == dict(zip(
         ("flash_layout", "attn_residuals", "attn_residual_bytes_per_layer",
-         "flash_causal_tile", "flash_causal_share"),
-        want))
+         "flash_causal_tile", "flash_causal_share",
+         "xent_products_per_step", "xent_dlog_chunk_tokens"),
+        want + (None, None)[len(want) - 5:]))
     path = tmp_path / "trace.jsonl"
     with tracing.TraceRecorder(str(path), chief_only=False):
         tracing.PhaseTrace("startup").mark("startup.trainer", **fields)

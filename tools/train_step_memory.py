@@ -18,8 +18,9 @@ PR 37): the step a saved residual is weighed against.
 One JSON row to stdout and ``chiprun_out/train_step_memory.jsonl``:
 ``total_bytes`` = arguments + outputs - aliased + temporaries, a device;
 ``kernels`` = custom calls in the compiled module by kernel name (per
-shard on a mesh); ``attn_residuals`` and ``flash_causal_tile`` /
-``flash_causal_share`` as the trainer's start-up row has them.
+shard on a mesh); ``attn_residuals``, ``flash_causal_tile`` /
+``flash_causal_share`` and ``xent_products_per_step`` /
+``xent_dlog_chunk_tokens`` as the trainer's start-up row has them.
 """
 
 from __future__ import annotations
@@ -92,6 +93,8 @@ def report(compiled, mesh, wl) -> dict:
             row["flash_causal_tile"], row["flash_causal_share"] = (
                 wl.model.flash_causal_tile(wl.global_batch_size,
                                            ids.shape[1]))
+            row["xent_products_per_step"], row["xent_dlog_chunk_tokens"] = (
+                wl.model.xent_products(wl.global_batch_size, ids.shape[1]))
     return row
 
 

@@ -554,17 +554,21 @@ def run_ps_cluster_task(args, cluster, task_type, task_index) -> None:
 
 def _flash_layout(wl, mesh) -> dict:
     """``flash_layout``, ``attn_residuals``,
-    ``attn_residual_bytes_per_layer``, ``flash_causal_tile`` and
-    ``flash_causal_share`` for the ``startup.trainer`` row and the start-up
-    log: the form the step's dense attention lowers to
+    ``attn_residual_bytes_per_layer``, ``flash_causal_tile``,
+    ``flash_causal_share``, ``xent_products_per_step`` and
+    ``xent_dlog_chunk_tokens`` for the ``startup.trainer`` row and the
+    start-up log: the form the step's dense attention lowers to
     (``models.gpt.attention_layout``: "qkv_tiles", "bhsd" or "xla"), what
     a remat'd block does for that attention's residuals in the backward
     (``GPTLM.attn_residuals``: "saved" with the bytes a layer keeps on a
     device, "recomputed", or null where nothing is rematerialised) and the
     rows of the sub-tiles the tile kernels walk a causal diagonal block in
     with the share of its square they compute
-    (``GPTLM.flash_causal_tile``; null where blocks are taken whole), read
-    under the trainer's mesh as the step is traced.
+    (``GPTLM.flash_causal_tile``; null where blocks are taken whole), and
+    the ``tokens x d x V`` products a step's loss head runs with the tokens
+    a chunk of its backward holds dlogits for (``GPTLM.xent_products``: 4;
+    null where the head is not the fused one), read under the trainer's
+    mesh as the step is traced.
     The fall-back from one form to the next is silent and costs a tenth of
     a step, and with it goes the saving, so a run's trace says which it
     got.  Empty for a model that has no such choice."""
@@ -578,15 +582,21 @@ def _flash_layout(wl, mesh) -> dict:
             wl.global_batch_size, ids.shape[1])
         tile, share = wl.model.flash_causal_tile(
             wl.global_batch_size, ids.shape[1])
+        products, chunk = wl.model.xent_products(
+            wl.global_batch_size, ids.shape[1])
     if layout is None:
         return {}
     logging.info("flash_layout: %s", layout)
     logging.info("attn_residuals: %s (%s bytes a layer)", residuals, kept)
     logging.info("flash_causal_tile: %s (share %s of a diagonal block)",
                  tile, share)
+    logging.info("xent_products_per_step: %s (dlog chunks of %s tokens)",
+                 products, chunk)
     return {"flash_layout": layout, "attn_residuals": residuals,
             "attn_residual_bytes_per_layer": kept,
-            "flash_causal_tile": tile, "flash_causal_share": share}
+            "flash_causal_tile": tile, "flash_causal_share": share,
+            "xent_products_per_step": products,
+            "xent_dlog_chunk_tokens": chunk}
 
 
 def main() -> None:
