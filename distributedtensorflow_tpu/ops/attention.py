@@ -1949,13 +1949,13 @@ class KVRows:
 
     def chunk_formulation(self, block_size: int, chunk: int,
                           impl: str) -> str:
-        # K/V rows have the one chunk formulation, the plain loop
-        return "plain"
+        return paged_chunk_formulation(self.heads, self.kv_heads, self.head_dim,
+                                       self.value_dim, block_size, chunk, impl)
 
-    def chunk(self, q, start, pools, table_row, *, impl="auto", **kw):
-        # ``impl`` chooses nothing here: see ``chunk_formulation``
+    def chunk(self, q, start, pools, table_row, **kw):
         with jax.named_scope("paged_attn"):
-            return paged_chunk_attention(q, start, *pools, table_row, **kw)
+            return paged_window_chunk_attention(
+                q, start, *pools, table_row, **kw)
 
     def decode(self, q, pools, tables, attend_lens, **kw):
         with jax.named_scope("paged_attn"):
@@ -2135,3 +2135,343 @@ class SparseLatentRows(LatentRows):
             lambda blocks: jnp.take_along_axis(tables, blocks, axis=1),
             w_uk=w_uk, w_uv=w_uv, layer=layer, block_size=block_size,
             impl=impl)
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk over K/V rows: the page walk in a kernel
+# ---------------------------------------------------------------------------
+#
+# What ``paged_chunk_attention`` (above, the plain loop) computes, with a
+# chunk's scores kept in VMEM: the K/V rows' counterpart of the latent rows'
+# ``latent_chunk_attn``.  (It stands below the row forms that call it: a
+# Mosaic body carries its source lines, so the kernels above lower byte for
+# byte as they did before it was added.)
+
+#: context rows a step of the K/V chunk kernel copies and folds into the
+#: running softmax (the plain loop's ``kv_chunk`` is as many)
+KV_CHUNK_STRETCH = 512
+#: query heads of one K/V head a grid step holds at most, all the chunk's
+#: queries of each resident: a stretch is copied once a step
+KV_CHUNK_HEADS = 8
+#: query rows of a head whose scores are formed at a time
+KV_CHUNK_QUERIES = 512
+#: query rows a K/V head (the heads that read it x the chunk) under which a
+#: chunk stays on the plain loop: not an MXU pass of them
+KV_CHUNK_MIN_ROWS = 128
+#: VMEM the chunk kernel may take (a v5e has 128 MiB, 16 of them scoped by
+#: default): the resident queries, outputs and softmax state of its heads
+KV_CHUNK_VMEM = 96 << 20
+
+
+def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
+                     v_hbm, *refs, block_size, q_tile, scale, window,
+                     rest_at):
+    """``rest_at``: where a K head is wider than its whole tiles
+    (:func:`lay_heads`), the lane at which the heads' remainders start in
+    the K row, two of 64 a tile: the head's whole tiles and the remainder
+    tile it shares are copied side by side, and the query holds zeros in
+    the neighbour's lanes.  With a sink, ``refs`` starts with its (1,
+    heads, 128) float32 block."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    sink_ref = refs[0] if len(refs) == 9 else None
+    o_ref, kbuf, vbuf, sem, q_sc, m_sc, l_sc, acc_sc = refs[-8:]
+    heads, t, dq = q_sc.shape
+    stretch, dv = vbuf.shape[1], vbuf.shape[2]
+    whole = dq if rest_at is None else dq - LANES   # lanes of whole K tiles
+    bps = stretch // block_size                # blocks a stretch
+    nb = table_ref.shape[0]
+    kv = pl.program_id(0)
+    start, lo, layer = start_ref[0], lo_ref[0], layer_ref[0]
+    end = start + t
+    n = (end + stretch - 1) // stretch         # stretches to the chunk's end
+    dtype = q_sc.dtype
+
+    # blocks the chunk does not attend are not copied and leave their rows
+    # as they were: keep them finite (their probabilities are zeros)
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+    # the queries come as the model holds them, a head's values side by side
+    # with the next head's: a head's own, head-major, once a step
+    for h in range(heads):
+        q_sc[h] = q_ref[:, h * dq:(h + 1) * dq]
+
+    def copies(c, slot, go):
+        """``go`` every copy of stretch ``c`` into buffer ``slot`` whose
+        block holds a row the chunk attends: this K/V head's lanes only."""
+        for j in range(bps):
+            b0 = c * stretch + j * block_size
+            blk = table_ref[jnp.minimum(b0 // block_size, nb - 1)]
+            src = pl.ds(blk * block_size, block_size)
+            dst = pl.ds(j * block_size, block_size)
+            parts = [
+                (k_hbm.at[layer, src,
+                          pl.ds(pl.multiple_of(kv * whole, LANES), whole)],
+                 kbuf.at[slot, dst, pl.ds(0, whole)]),
+                (v_hbm.at[layer, src,
+                          pl.ds(pl.multiple_of(kv * dv, LANES), dv)],
+                 vbuf.at[slot, dst])]
+            if rest_at is not None:
+                parts.append((
+                    k_hbm.at[layer, src, pl.ds(pl.multiple_of(
+                        rest_at + kv // 2 * LANES, LANES), LANES)],
+                    kbuf.at[slot, dst, pl.ds(whole, LANES)]))
+
+            @pl.when((b0 < end) & (b0 + block_size > lo))
+            def _():
+                for i, (src_ref, dst_ref) in enumerate(parts):
+                    go(pltpu.make_async_copy(src_ref, dst_ref,
+                                             sem.at[slot, i, j]))
+
+    def fold(h, i, first, masked, slot):
+        """Query tile ``i`` of head ``h`` against the stretch in ``slot``."""
+        rows = pl.ds(pl.multiple_of(i * q_tile, q_tile), q_tile)
+        s = jax.lax.dot_general(
+            q_sc[h, rows, :], kbuf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (q_tile, stretch)
+        if masked:
+            qpos = start + i * q_tile + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            kpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            ok = kpos <= qpos
+            if window is not None:
+                ok &= kpos > qpos - window
+            s = jnp.where(ok, s, NEG_INF)
+        # the running maximum and sum are kept replicated across 128 lanes
+        m_prev = m_sc[h, rows, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - jnp.concatenate([m_new] * (stretch // LANES), axis=1))
+        if masked:
+            p = jnp.where(ok, p, 0.0)
+        l_sc[h, rows, :] = alpha * l_sc[h, rows, :] \
+            + p.sum(axis=1, keepdims=True)
+        m_sc[h, rows, :] = m_new
+        pv = jnp.dot(p.astype(dtype), vbuf[slot],
+                     preferred_element_type=jnp.float32)
+        acc_sc[h, rows, :] = acc_sc[h, rows, :] * jnp.concatenate(
+            [alpha] * (dv // LANES), axis=1) + pv
+
+    def heads_of(first, slot, masked):
+        """Every head of the step against stretch ``first`` in ``slot``."""
+        def head(h, _):
+            def tile(i, _):
+                if not masked:
+                    return fold(h, i, first, False, slot)
+                # a tile whose last query precedes the stretch, or whose
+                # first query's window starts past it, attends none of it
+                some = first <= start + (i + 1) * q_tile - 1
+                if window is not None:
+                    some &= first + stretch - 1 > start + i * q_tile - window
+                pl.when(some)(lambda: fold(h, i, first, True, slot))
+
+            jax.lax.fori_loop(0, t // q_tile, tile, None)
+
+        jax.lax.fori_loop(0, heads, head, None)
+
+    c0 = lo // stretch                         # the first attended stretch
+    copies(c0, jax.lax.rem(c0, 2), lambda cp: cp.start())
+
+    def stretch_body(c, _):
+        slot = jax.lax.rem(c, 2)
+        first = c * stretch
+
+        # the next stretch's copies run under this stretch's arithmetic
+        @pl.when(c + 1 < n)
+        def _():
+            copies(c + 1, 1 - slot, lambda cp: cp.start())
+
+        copies(c, slot, lambda cp: cp.wait())
+        # only a stretch that reaches past the chunk's first query, or
+        # before the window of its last, is masked
+        masked = first + stretch - 1 > start
+        if window is not None:
+            masked |= first < end - window
+        pl.when(masked)(lambda: heads_of(first, slot, True))
+        pl.when(jnp.logical_not(masked))(
+            lambda: heads_of(first, slot, False))
+
+    jax.lax.fori_loop(c0, n, stretch_body, None)
+
+    for h in range(heads):
+        l = l_sc[h]
+        if sink_ref is not None:
+            # the key without a value: one more term of the denominator
+            l = l + jnp.exp(sink_ref[0, pl.ds(h, 1), :] - m_sc[h])
+        o_ref[:, h * dv:(h + 1) * dv] = (acc_sc[h] / jnp.concatenate(
+            [jnp.maximum(l, 1e-30)] * (dv // LANES), axis=1)
+        ).astype(o_ref.dtype)
+
+
+def _kv_chunk_heads(group: int, chunk: int, dq: int, dv: int,
+                    itemsize: int) -> int:
+    """Query heads a grid step holds: the most that divide the ``group``
+    that reads a K/V head, up to ``KV_CHUNK_HEADS``, whose queries and
+    outputs (two buffers each, the pipeline's), head-major queries and
+    float32 softmax state fit half the kernel's VMEM."""
+    a_head = chunk * (itemsize * (3 * dq + 2 * dv) + 4 * (dv + 2 * LANES))
+    fit = max(1, min(KV_CHUNK_HEADS, (KV_CHUNK_VMEM // 2) // a_head))
+    return max(g for g in range(1, fit + 1) if group % g == 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kv_heads", "block_size", "stretch", "heads_step", "q_tile", "scale",
+    "window", "rest_at", "interpret"))
+def _kv_chunk_call(table_row, start, lo, layer, q, k_pool, v_pool, sink=None,
+                   *, kv_heads, block_size, stretch, heads_step, q_tile,
+                   scale, window, rest_at, interpret):
+    """The chunk kernel's call: a jitted function of its own with the layer
+    as a prefetched scalar, so the layers of a group share one lowering.
+    ``q`` is 2-D, (T, H * dq): the heads side by side, each as wide as the
+    lanes of its K/V head that the kernel copies."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t = q.shape[0]
+    dv = v_pool.shape[-1] // kv_heads
+    whole = k_pool.shape[-1] // kv_heads // LANES * LANES
+    dq = whole if rest_at is None else whole + LANES
+    heads = q.shape[1] // dq
+    g, steps = heads_step, heads // kv_heads // heads_step
+
+    def a_step(lanes):
+        return pl.BlockSpec((t, g * lanes),
+                            lambda kv, j, *_: (0, kv * steps + j))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    sink_spec = [] if sink is None else [pl.BlockSpec(
+        (1, g, LANES), lambda kv, j, *_: (kv * steps + j, 0, 0))]
+    return pl.pallas_call(
+        functools.partial(
+            _kv_chunk_kernel, block_size=block_size, q_tile=q_tile,
+            scale=scale, window=window, rest_at=rest_at),
+        name="kv_chunk_attn",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(kv_heads, steps),
+            in_specs=[a_step(dq), hbm, hbm, *sink_spec],
+            out_specs=a_step(dv),
+            scratch_shapes=[
+                pltpu.VMEM((2, stretch, dq), k_pool.dtype),
+                pltpu.VMEM((2, stretch, dv), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 3, stretch // block_size)),
+                pltpu.VMEM((g, t, dq), q.dtype),
+                pltpu.VMEM((g, t, LANES), jnp.float32),
+                pltpu.VMEM((g, t, LANES), jnp.float32),
+                pltpu.VMEM((g, t, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((t, heads * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=KV_CHUNK_VMEM),
+        interpret=interpret,
+    )(table_row, start, lo, layer, q, k_pool, v_pool,
+      *(() if sink is None else (sink,)))
+
+
+def paged_chunk_formulation(heads: int, kv_heads: int, head_dim: int,
+                            value_dim: int | None, block_size: int,
+                            chunk: int, impl: str = "auto") -> str:
+    """Which formulation :func:`paged_window_chunk_attention` takes at these
+    shapes: ``"kv_chunk_attn"`` (the kernel) or ``"plain"`` (the loop whose
+    scores go through HBM).  The kernel wants a stretch of whole blocks and
+    whole lane tiles; a K head of whole lane tiles, or of whole tiles and
+    half a tile more with the remainders two a tile (:func:`lay_heads`); a V
+    head of whole tiles; a chunk of whole bf16 sublane tiles that its query
+    tiles divide; and an MXU pass of query rows a K/V head.  A test of
+    shapes and of ``impl`` alone, so a program can say what it was built
+    with (``serve.model``)."""
+    rest = head_dim % LANES
+    fits = (KV_CHUNK_STRETCH % block_size == 0
+            and KV_CHUNK_STRETCH % LANES == 0
+            and heads % kv_heads == 0 and head_dim >= LANES
+            and (rest == 0 or (rest == LANES // 2 and kv_heads % 2 == 0))
+            and (value_dim or head_dim) % LANES == 0
+            and chunk % 16 == 0
+            and chunk % min(chunk, KV_CHUNK_QUERIES) == 0
+            and heads // kv_heads * chunk >= KV_CHUNK_MIN_ROWS)
+    return "kv_chunk_attn" if use_kernel(impl) and fits else "plain"
+
+
+def paged_window_chunk_attention(
+    q: jax.Array,            # (T, H, D): one slot's chunk of queries
+    start,                   # int32 scalar: position of q[0]
+    k_pool: jax.Array,       # (L_group, rows, Hkv * D)
+    v_pool: jax.Array,
+    table_row: jax.Array,    # (max_blocks,) the slot's page-table row
+    *,
+    layer: int,
+    block_size: int,
+    window: int | None = None,
+    sink: jax.Array | None = None,   # (H,) a key without a value a head
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Chunk-prefill attention of one slot against its pages that keeps a
+    chunk's scores in VMEM: what :func:`paged_chunk_attention` computes (its
+    loop is the plain formulation here: the tests' yardstick, the path off
+    the TPU, for ``impl="xla"`` and at shapes that do not fit,
+    :func:`paged_chunk_formulation`), bf16 operands, float32 scores and
+    softmax state, the probabilities rounded to the stored type.
+
+    The kernel (``name="kv_chunk_attn"``; the page-table row, ``start``, the
+    first attended row and the layer prefetched into SMEM, the pools left in
+    HBM; grid over K/V heads and groups of ``KV_CHUNK_HEADS`` of the query
+    heads that read one, all ``T`` queries of each resident) walks the
+    stretches of ``KV_CHUNK_STRETCH`` rows from the first one a query
+    attends (``max(start - window + 1, 0)``, 0 on a full layer) to the
+    chunk's end: a stretch's blocks — this K/V head's lanes of them: its
+    whole K tiles, the remainder tile it shares where a K head is a tile
+    and a half (the query holds zeros in the neighbour's lanes, as
+    ``paged_attn``'s does) and its V tiles — are copied into one of two
+    VMEM buffers, the next stretch's copies started before this one's
+    arithmetic, and each tile of ``KV_CHUNK_QUERIES`` queries of each head
+    folds it into the running maximum, sum and accumulator in VMEM scratch:
+    no score goes to HBM.  Only a stretch that reaches past the chunk's
+    first query, or before the window of its last, is masked, and a query
+    tile that attends none of such a stretch skips it; blocks past the
+    chunk's end or before the first attended row are not copied.  ``sink``
+    joins each head's denominator once, at the end."""
+    t, h, d = q.shape
+    h_kv = k_pool.shape[-1] // d
+    dv = v_pool.shape[-1] // h_kv
+    if paged_chunk_formulation(h, h_kv, d, dv, block_size, t,
+                               impl) == "plain":
+        return paged_chunk_attention(
+            q, start, k_pool, v_pool, table_row, layer=layer,
+            block_size=block_size, window=window, sink=sink)
+    if interpret is None:
+        interpret = not on_tpu()
+    g = h // h_kv
+    whole, rest_at = d // LANES * LANES, None
+    if d > whole:
+        # a head's whole tiles, then its last 64 values in its own half of
+        # the remainder tile
+        rest_at = h_kv * whole
+        own = (jnp.arange(h_kv)[:, None] % 2
+               == jnp.arange(2)[None, :])[:, None, :, None]
+        qh = q.reshape(t, h_kv, g, d)
+        rest = jnp.where(own, qh[:, :, :, None, whole:], 0)
+        q = jnp.concatenate(
+            [qh[..., :whole], rest.reshape(t, h_kv, g, LANES)], axis=-1)
+    dq = whole + (LANES if rest_at is not None else 0)
+    heads_step = _kv_chunk_heads(g, t, dq, dv, q.dtype.itemsize)
+    start = jnp.reshape(start, (1,)).astype(jnp.int32)
+    lo = (jnp.zeros_like(start) if window is None
+          else jnp.maximum(start - window + 1, 0))
+    if sink is not None:
+        # a head's bias across the lanes, as the running maximum lies
+        sink = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(h // heads_step, heads_step, 1),
+            (h // heads_step, heads_step, LANES))
+    out = _kv_chunk_call(
+        table_row.astype(jnp.int32), start, lo,
+        jnp.full((1,), layer, jnp.int32), q.reshape(t, h * dq), k_pool,
+        v_pool, sink, kv_heads=h_kv, block_size=block_size,
+        stretch=KV_CHUNK_STRETCH, heads_step=heads_step,
+        q_tile=min(t, KV_CHUNK_QUERIES), scale=d ** -0.5, window=window,
+        rest_at=rest_at, interpret=interpret)
+    return out.reshape(t, h, dv)

@@ -36,9 +36,9 @@ and the three programs differ only in where the rows live:
 - :func:`make_prefill_fn` — one ``prefill_chunk``-wide slice of one
   prompt; the chunk's rows go straight to the slot's pool blocks and its
   queries attend the slot's earlier chunks through the page-table row
-  (``ops.attention.paged_chunk_attention`` or
+  (``ops.attention.paged_window_chunk_attention`` or
   ``paged_latent_chunk_attention``, a running softmax over the context up to
-  the chunk's end).  There is no dense cache, so for the families whose
+  the chunk's end, in VMEM on the TPU).  There is no dense cache, so for the families whose
   layers keep only rows a token (gpt, afmoe, joyai) a chunk is
   *stateless*: any slot's next chunk can run at any time, the scheduler can
   interleave chunks of several requests with decode steps (ISSUE 14
@@ -539,10 +539,10 @@ class Programs:
       indexer selected, gathered by index) or ``"plain"`` (the gather of
       every table column; of the selected rows, their scores through HBM):
       the fallback is silent, so the engine reports it (``Engine.state()``);
-    - ``chunk_attention``: the same of ``prefill``: ``"latent_chunk_attn"``
-      (the kernel over latent rows that keeps a chunk's scores in VMEM),
-      ``"plain"`` (the loop whose scores go through HBM; all K/V rows
-      have), or with an indexer ``"<sparse>+<dense>"``: the formulation of a
+    - ``chunk_attention``: the same of ``prefill``: ``"kv_chunk_attn"`` /
+      ``"latent_chunk_attn"`` (the kernel over K/V / latent rows that keeps a
+      chunk's scores in VMEM), ``"plain"`` (the loop whose scores go through
+      HBM: the CPU, ``"xla"``, GPT-2's heads of 64), or with an indexer ``"<sparse>+<dense>"``: the formulation of a
       chunk that ends past ``index_topk`` and the one of a chunk that does
       not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers

@@ -105,3 +105,87 @@ def dp_mesh(devices):
     from distributedtensorflow_tpu.parallel import MeshSpec, build_mesh
 
     return build_mesh(MeshSpec(data=-1), devices)
+
+
+#: the K/V chunk kernel's tiles in the tests: stretches of 128 rows, query
+#: tiles of 16, eight query heads a grid step
+KV_CHUNK_SMALL_TILES = {"KV_CHUNK_STRETCH": 128, "KV_CHUNK_QUERIES": 16,
+                        "KV_CHUNK_HEADS": 8}
+
+
+@pytest.fixture()
+def check_kv_chunk_kernel(monkeypatch):
+    """``check(heads=, kv_heads=, d=, dv=, window=, sink=, start=)``: a chunk
+    of 32 queries from ``start`` through the kernel ``kv_chunk_attn``
+    (``ops.attention.paged_window_chunk_attention``, interpreted, at small
+    tiles) against the plain loop and, in float32, against each query's
+    dense sum over the rows it attends.  The table row is scattered; every
+    block the chunk does not attend — the table's later columns, the rest of
+    the pool, and the scratch block that the columns of a window layer's
+    freed early blocks name — holds NaN and inf."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributedtensorflow_tpu.ops import attention
+
+    def check(*, heads, kv_heads, d, dv, window, sink, start, t=32, bs=16,
+              dtype=jnp.float32, tol=2e-5, tiles=KV_CHUNK_SMALL_TILES):
+        for name, value in tiles.items():
+            monkeypatch.setattr(attention, name, value)
+        rng = np.random.default_rng(start + heads)
+        used = -(-(start + t) // bs)
+        nb = used + 3
+        first = 0 if window is None else max(start - window + 1, 0) // bs
+        k = rng.standard_normal((2, (nb + 1) * bs, kv_heads, d))
+        v = rng.standard_normal((2, (nb + 1) * bs, kv_heads, dv))
+        row = rng.permutation(nb).astype(np.int32)
+        poison = np.ones(nb + 1, bool)
+        poison[row[first:used]] = False
+        row[:first] = nb                    # freed: the scratch block
+        bad = np.where(np.arange(d) % 2, np.nan, np.inf)
+        k[:, np.repeat(poison, bs)] = bad
+        v[:, np.repeat(poison, bs)] = bad[:dv]
+        q = rng.standard_normal((t, heads, d))
+        bias = rng.standard_normal(heads) + 2.0 if sink else None
+        pools = (jax.vmap(attention.lay_heads)(jnp.asarray(k, dtype)),
+                 jnp.asarray(v.reshape(2, -1, kv_heads * dv), dtype))
+        args = (jnp.asarray(q, dtype), jnp.int32(start))
+        kw = dict(layer=1, block_size=bs, window=window,
+                  sink=None if bias is None else jnp.asarray(
+                      bias, jnp.float32))
+        assert attention.paged_chunk_formulation(
+            heads, kv_heads, d, dv, bs, t, "pallas") == "kv_chunk_attn"
+        got = attention.paged_window_chunk_attention(
+            *args, *pools, jnp.asarray(row), impl="pallas", interpret=True,
+            **kw)
+        assert got.shape == (t, heads, dv) and got.dtype == dtype
+        # the plain loop multiplies a masked row's zero weight with its
+        # values: it is the yardstick over pools whose unattended rows are
+        # finite
+        clean = [jnp.nan_to_num(p, nan=0.0, posinf=0.0) for p in pools]
+        for impl in ("xla", "auto"):        # off the TPU "auto" is the loop
+            want = attention.paged_window_chunk_attention(
+                *args, *clean, jnp.asarray(row), impl=impl, **kw)
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                atol=tol, rtol=0)
+        if dtype != jnp.float32:
+            return
+        g = heads // kv_heads
+        for i in range(t):
+            pos = start + i
+            at = np.arange(0 if window is None
+                           else max(pos - window + 1, 0), pos + 1)
+            at = row[at // bs] * bs + at % bs
+            sc = np.einsum("hgd,khd->hgk", q[i].reshape(kv_heads, g, d),
+                           k[1, at]) * d ** -0.5
+            if bias is not None:
+                sc = np.concatenate(
+                    [sc, bias.reshape(kv_heads, g, 1)], axis=-1)
+            p = np.exp(sc - sc.max(-1, keepdims=True))
+            p = (p / p.sum(-1, keepdims=True))[..., :len(at)]
+            dense = np.einsum("hgk,khd->hgd", p, v[1, at])
+            np.testing.assert_allclose(
+                got[i], dense.reshape(heads, dv), atol=tol, rtol=0)
+
+    return check

@@ -495,6 +495,25 @@ def test_chunk_attention_matches_dense_attention(window):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("window,start", [
+    (None, 0),       # a full layer, the chunk alone
+    (None, 300),     # ... several stretches in, the last two on the diagonal
+    (4096, 100),     # a window layer inside its window: every row attended
+    (4096, 4200),    # ... past it: on no stretch's boundary, early blocks
+                     # freed, stretches inside every query's window unmasked
+])
+def test_chunk_kernel_matches_the_loop_at_heads_of_128(window, start,
+                                                       check_kv_chunk_kernel):
+    """``kv_chunk_attn``, interpreted, at trinity's head shape: heads of
+    128, 6 a K/V head, a window of 4,096 on the window layers."""
+    form = afmoe.trinity_large_ep8().cache_rows
+    assert form.chunk_formulation(16, 512, "pallas") == "kv_chunk_attn"
+    assert form.chunk_formulation(16, 512, "xla") == "plain"
+    assert form.chunk_formulation(16, 512, "auto") == "plain"    # the CPU
+    check_kv_chunk_kernel(heads=12, kv_heads=2, d=128, dv=128,
+                          window=window, sink=False, start=start)
+
+
 @pytest.mark.parametrize("t,tile", [(40, 16), (72, 64)],
                          ids=["tile16", "wide-tile"])
 def test_grouped_matmul_kernel_matches_the_plain_loop(t, tile):

@@ -336,12 +336,18 @@ def test_chunk_scan_formulation_says_what_is_taken(channels, d_state, chunk,
     assert ssm.chunk_scan_formulation(channels, d_state, chunk, impl) == want
 
 
-def test_served_through_the_kernels_matches_the_reference():
-    """The whole path with the kernels interpreted: ``ssm_chunk_scan`` in
+@pytest.mark.parametrize("heads,chunk_attention", [
+    (1, "plain"),             # 64 query rows a K/V head: the loop
+    (2, "kv_chunk_attn"),     # two heads on the one K/V head: the kernel
+])
+def test_served_through_the_kernels_matches_the_reference(heads,
+                                                          chunk_attention):
+    """The whole path with the kernels interpreted: ``ssm_chunk_scan`` and
+    (where a chunk has an MXU pass of query rows) ``kv_chunk_attn`` in
     prefill, ``paged_attn`` in decode (a head of 128 needs the width)."""
     cfg = jamba.jamba_tiny(dtype=jnp.float32, kernel_impl="pallas",
-                           hidden_size=128, num_heads=1, head_dim=128,
-                           mamba_dt_rank=8)
+                           hidden_size=128 * heads, num_heads=heads,
+                           head_dim=128, mamba_dt_rank=8)
     params = jamba.init_params(cfg, jax.random.PRNGKey(7), std=0.1)
     prompt = _prompt(70, 70, cfg)
     eng, [(tokens, logits)] = _serve(
@@ -349,6 +355,7 @@ def test_served_through_the_kernels_matches_the_reference():
         max_slots=2)
     state = eng.state()
     assert state["chunk_scan"] == "ssm_chunk_scan"
+    assert state["chunk_attention"] == chunk_attention
     assert state["decode_attention"] == "paged_attn"
     want = _reference_logits(cfg, params, prompt, tokens)
     np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
@@ -374,6 +381,20 @@ def test_paged_attn_takes_20_heads_on_one_kv_head():
     want = attention.paged_decode_attention(
         q, k_pool, v_pool, tables, lens, **kw)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("start", [0, 300, 608])
+def test_chunk_kernel_takes_20_heads_on_one_kv_head(start,
+                                                    check_kv_chunk_kernel):
+    """``kv_chunk_attn``, interpreted, at the served head shape: the 20
+    query heads in four grid steps of five, all reading the one K/V head."""
+    assert attention.paged_chunk_formulation(
+        20, 1, 128, None, 16, 1024, "pallas") == "kv_chunk_attn"
+    form = models.jamba2_3b().cache_rows
+    assert form.chunk_formulation(16, 1024, "pallas") == "kv_chunk_attn"
+    assert form.chunk_formulation(16, 1024, "auto") == "plain"   # the CPU
+    check_kv_chunk_kernel(heads=20, kv_heads=1, d=128, dv=128, window=None,
+                          sink=False, start=start)
 
 
 # (e) under load; what is kept, logged and refused

@@ -406,9 +406,12 @@ def test_chunk_formulation_falls_back_where_the_kernel_does_not_fit(
 def test_chunk_formulation_takes_the_kernel_at_the_served_shapes(chunk):
     form = joyai.joyai_llm_flash().cache_rows
     assert form.chunk_formulation(16, chunk, "pallas") == "latent_chunk_attn"
-    # off the TPU "auto" is the plain loop, and K/V rows have no other
+    # off the TPU "auto" is the plain loop; K/V rows have a kernel of their
+    # own (``kv_chunk_attn``), which GPT-2's heads of 64 do not fit
     assert form.chunk_formulation(16, chunk, "auto") == "plain"
     assert models.gpt_tiny().cache_rows.chunk_formulation(
+        16, chunk, "pallas") == "plain"
+    assert models.gpt_medium().cache_rows.chunk_formulation(
         16, chunk, "pallas") == "plain"
 
 

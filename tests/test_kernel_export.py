@@ -32,6 +32,7 @@ from distributedtensorflow_tpu.ops.attention import (
     _pallas_decode_attention,
     paged_latent_chunk_attention,
     paged_latent_decode_attention,
+    paged_window_chunk_attention,
     paged_window_decode_attention,
 )
 from distributedtensorflow_tpu.ops.flash_attention import (
@@ -132,6 +133,23 @@ def _paged_wide(window, kv_heads, slots=32, heads=64, columns=4224):
                 _sds((2, 2049 * 16, kv_heads * 128), BF16),
                 _sds((slots, columns), jnp.int32), _sds((slots,), jnp.int32),
                 *sink)
+
+
+def _kv_chunk(chunk, heads, kv_heads, d, dv, window, columns, blocks,
+              sink=False):
+    # a prefill chunk of a K/V-row family at its cell's shapes: the chunk's
+    # queries against the slot's page-table row, blocks of 16, one layer
+    # group's pools (K rows as ``lay_heads`` stores them)
+    def fn(q, start, k_pool, v_pool, table_row, *sink):
+        return paged_window_chunk_attention(
+            q, start, k_pool, v_pool, table_row, layer=1, block_size=16,
+            window=window, impl="pallas", interpret=False,
+            sink=sink[0] if sink else None)
+    return fn, (_sds((chunk, heads, d), BF16), _sds((), jnp.int32),
+                _sds((2, (blocks + 1) * 16, kv_heads * d), BF16),
+                _sds((2, (blocks + 1) * 16, kv_heads * dv), BF16),
+                _sds((columns,), jnp.int32),
+                *((_sds((heads,), F32),) if sink else ()))
 
 
 def _latent(slots=32, heads=32, rank=512, rope=64, nope=128, columns=1024):
@@ -304,6 +322,21 @@ FAMILIES = {
     "paged_attn_wide_window_sink": _paged_wide(128, 8),
     "ssm_chunk_scan": _ssm_scan(),
     "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
+    # a prefill chunk's attention over K/V rows.  mimo_v25_ep16: 1024
+    # queries of 64 heads, 16 a K/V head, keys 192 over values 128, a table
+    # of 4,224 columns over the cell's pool of 65,536 blocks
+    "kv_chunk_attn_wide_full": _kv_chunk(1024, 64, 4, 192, 128, None, 4224,
+                                         65536),
+    # ... 8 a K/V head, a window of 128 and a sink a head
+    "kv_chunk_attn_wide_window_sink": _kv_chunk(1024, 64, 8, 192, 128, 128,
+                                                4224, 2336, sink=True),
+    # trinity_large_ep8: 512 queries of 48 heads of 128, 6 a K/V head, a
+    # window of 4,096 in a table of 512 columns
+    "kv_chunk_attn_d128_window": _kv_chunk(512, 48, 8, 128, 128, 4096, 512,
+                                           11264),
+    # jamba2_3b: 1024 queries of 20 heads on ONE K/V head of 128
+    "kv_chunk_attn_20_on_1": _kv_chunk(1024, 20, 1, 128, 128, None, 2112,
+                                       67584),
 }
 
 
@@ -604,8 +637,9 @@ def test_two_form_program_keeps_both_groups_pools_in_place_on_a_v5e(
     window group's (8: 1536 and 1024), copy or convert no layer of any
     outside ``paged_attn`` and hand all four back in place; decode attends
     both groups through the ``paged_attn`` kernel, lowered once a group (the
-    window group's with the sink), and the prefill chunk through the plain
-    loop.  (It is refused the fused programs.)"""
+    window group's with the sink), and the prefill chunk through
+    ``kv_chunk_attn``, lowered once a group too (the window group's two
+    layers share one body).  (It is refused the fused programs.)"""
     import dataclasses
 
     from distributedtensorflow_tpu.models import mimo_v25_ep16
@@ -633,13 +667,16 @@ def test_two_form_program_keeps_both_groups_pools_in_place_on_a_v5e(
     if window:
         fn, args = programs[program]
         text = fn.lower(*args).as_text()
-        kernels = text.count('kernel_name = "paged_attn"')
-        assert kernels == (2 if program == "decode" else 0)
+        kernels = {name: text.count(f'kernel_name = "{name}"')
+                   for name in ("paged_attn", "kv_chunk_attn")}
+        assert kernels == ({"paged_attn": 2, "kv_chunk_attn": 0}
+                           if program == "decode"
+                           else {"paged_attn": 0, "kv_chunk_attn": 2})
         assert make_programs(
             cfg, chunk=256, block_size=16,
             layers=kv_cache.layer_groups(cfg)).formulations == {
-            "full": {"decode": "paged_attn", "chunk": "plain"},
-            "window": {"decode": "paged_attn", "chunk": "plain"}}
+            "full": {"decode": "paged_attn", "chunk": "kv_chunk_attn"},
+            "window": {"decode": "paged_attn", "chunk": "kv_chunk_attn"}}
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
@@ -650,8 +687,9 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
     the K/V pools and the state group's arrays as they are stored, copy or
     transpose no layer of either, and hand all four back in place; the
     prefill chunk scans through ``ssm_chunk_scan``, lowered once for the
-    three layers, and decode attends 20 heads on one K/V head through
-    ``paged_attn``.  (It is refused the fused programs.)"""
+    three layers, and attends 20 heads on one K/V head through
+    ``kv_chunk_attn``; decode attends them through ``paged_attn``.  (It is
+    refused the fused programs.)"""
     import dataclasses
 
     from distributedtensorflow_tpu.models import jamba2_3b
@@ -680,6 +718,7 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
         calls = re.findall(r"call @(\w*scan_call\w*)\(", text)
         assert len(calls) == 3 and len(set(calls)) == 1, calls
         assert text.count('kernel_name = "ssm_chunk_scan"') == 1
+        assert text.count('kernel_name = "kv_chunk_attn"') == 1
     else:
         assert text.count('kernel_name = "paged_attn"') == 1
 

@@ -281,6 +281,57 @@ def test_chunk_loop_takes_wide_keys_and_the_sink(window):
         np.testing.assert_allclose(got[i], want[0], atol=2e-5, rtol=0)
 
 
+#: a chunk of 32 queries from here: alone (every stretch on the diagonal);
+#: inside the window and the first stretch; several stretches in, on no
+#: stretch's boundary, the window layers' early blocks freed (their table
+#: columns name the scratch block, which holds NaN); ending with a stretch
+CHUNK_STARTS = [0, 100, 300, 608]
+
+
+@pytest.mark.parametrize("start", CHUNK_STARTS)
+@pytest.mark.parametrize("kv_heads,window", [(4, None), (8, 128)],
+                         ids=["full-16-on-1", "window-sink-8-on-1"])
+def test_chunk_kernel_takes_wide_keys_and_the_sink(kv_heads, window, start,
+                                                   check_kv_chunk_kernel):
+    """``kv_chunk_attn``, interpreted, at the published head shapes: 64
+    heads, keys 192 wide (a head's whole tile and its half of a remainder
+    tile) over values of 128; the window layers' heads have a sink."""
+    check_kv_chunk_kernel(heads=64, kv_heads=kv_heads, d=192, dv=128,
+                          window=window, sink=window is not None,
+                          start=start)
+
+
+@pytest.mark.parametrize("kv_heads,window", [(4, None), (8, 128)],
+                         ids=["full", "window-sink"])
+def test_chunk_kernel_in_bfloat16_matches_the_loop(kv_heads, window,
+                                                   check_kv_chunk_kernel):
+    """The stored type: the kernel rounds the probabilities where the loop
+    does, so the two agree to bf16's own rounding of the output."""
+    check_kv_chunk_kernel(heads=64, kv_heads=kv_heads, d=192, dv=128,
+                          window=window, sink=window is not None, start=300,
+                          dtype=jnp.bfloat16, tol=4e-2)
+
+
+@pytest.mark.parametrize("why,args", [
+    ("impl says so", (64, 4, 192, 128, 16, 1024, "xla")),
+    ("the CPU under auto", (64, 4, 192, 128, 16, 1024, "auto")),
+    ("values of no whole tile", (64, 4, 192, 192, 16, 1024, "pallas")),
+    ("remainders of an odd count of heads", (63, 3, 192, 128, 16, 1024,
+                                             "pallas")),
+    ("a block that does not divide a stretch", (64, 4, 192, 128, 48, 1024,
+                                                "pallas")),
+    ("a chunk of half a bf16 sublane tile", (64, 4, 192, 128, 16, 8,
+                                             "pallas")),
+    ("a chunk its query tiles do not divide", (64, 4, 192, 128, 16, 768,
+                                               "pallas")),
+    ("GPT-2: heads of 64, two a lane tile", (16, 16, 64, 64, 16, 16,
+                                             "pallas")),
+    ("16 query rows a K/V head", (16, 16, 128, 128, 16, 16, "pallas")),
+])
+def test_chunk_kernel_shapes_that_do_not_fit_fall_back(why, args):
+    assert attention.paged_chunk_formulation(*args) == "plain", why
+
+
 def test_kernel_shapes_that_do_not_fit_fall_back():
     # values as wide as keys at 192, an odd count of K/V heads, a block of
     # 48: the plain formulation, silently (the programs report it)
@@ -302,7 +353,9 @@ def test_a_form_a_group_reaches_the_pools_and_the_programs():
     assert sum(window.widths) * 2 == 5120
     for form in (full, window):
         assert form.decode_formulation(16, "pallas") == "paged_attn"
-        assert form.chunk_formulation(16, 1024, "pallas") == "plain"
+        assert form.chunk_formulation(16, 1024, "pallas") == "kv_chunk_attn"
+        assert form.chunk_formulation(16, 1024, "xla") == "plain"
+        assert form.chunk_formulation(16, 1024, "auto") == "plain"  # the CPU
     k = jnp.arange(2 * 4 * 192, dtype=jnp.float32).reshape(2, 4, 192)
     row, v = full.stored(k, k[..., :128])
     assert row.shape == (2, 768) and v.shape == (2, 4, 128)
