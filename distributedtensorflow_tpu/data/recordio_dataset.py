@@ -101,7 +101,7 @@ def record_dataset(
 
     ``num_threads=None`` (the default) gates reader threads on the host:
     ``min(4, cpu_count)`` — on a 1-core host extra reader threads only add
-    contention (measured: 4t slower than 1t, bench_input.py).  Pass an
+    contention.  Pass an
     explicit value to force it (e.g. for file interleaving semantics).
 
     Yields dicts of stacked arrays with a leading ``batch_size`` dim (the

@@ -1195,8 +1195,7 @@ class DataServiceClient:
     EOF.  ``protocol="streaming"`` (default) keeps one persistent
     connection + fetcher thread per split with a pipelined credit window;
     ``protocol="per_connection"`` is the v1 blocking round-robin (one TCP
-    connection and one full round-trip per batch) kept as the measurable
-    baseline (bench_input.py) and for v1 workers.
+    connection and one full round-trip per batch) kept for v1 workers.
 
     Fault policy on mid-epoch worker death:
 

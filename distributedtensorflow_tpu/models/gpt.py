@@ -84,13 +84,12 @@ class GPTConfig:
     #: ``num_heads // num_kv_heads`` query heads shares one K/V head.
     #: None = num_heads (MHA — every existing preset, param-tree
     #: unchanged).  Shrinks the decode KV cache and its per-step HBM
-    #: stream by the group factor — the binding constraint of the serving
-    #: decode step (ops.attention decode-perf history).  New capability
+    #: stream by the group factor — the binding constraint of a decode
+    #: step.  New capability
     #: beyond the reference stack (tf-classic predates GQA entirely).
     num_kv_heads: int | None = None
-    #: LM-head loss kernel: "auto" (Pallas fused head on TPU — the fastest
-    #: measured path, 111.3k vs 108.4k tok/s against chunked_bf16 at the
-    #: 2026-08-01 headline A/B — and "chunked" elsewhere, keeping CPU
+    #: LM-head loss kernel: "auto" (Pallas fused head on TPU — ``PERF.md``
+    #: section 6, PR 42 — and "chunked" elsewhere, keeping CPU
     #: tests on the fp32 golden path), "chunked" (lax.scan over token
     #: chunks, ops/xent.py), "chunked_bf16" (bf16 logits tiles), or
     #: "fused" (Pallas ops/fused_xent.py unconditionally — logits never
@@ -239,14 +238,12 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
     serving definition, ``models.afmoe`` / ``joyai``), the decode path,
     sequence-parallel ``attn_fn``, GQA, the XLA attention, seq2seq.
 
-    Lane-friendly formulation (2026-08-01 retune): the textbook
-    ``split -> 4 muls on (…, D/2) -> concat`` form cost ~31 ms/step in
-    the GPT-2-small profile — every elementwise op ran on D/2=32-wide
-    tensors (a quarter of the 128-lane VPU tile) and XLA materialized
-    half-width copies around them (profile_lm_flash, fusions at
-    (16,1024,12,32)).  Folding the signs into a full-width sin pattern
-    turns it into ONE half-swap relayout plus two muls and an add at
-    full D width; per-element arithmetic is bit-identical
+    Lane-friendly formulation: in the textbook ``split -> 4 muls on
+    (…, D/2) -> concat`` form every elementwise op runs on D/2=32-wide
+    tensors (a quarter of the 128-lane VPU tile) and XLA materializes
+    half-width copies around them.  Folding the signs into a full-width
+    sin pattern turns it into ONE half-swap relayout plus two muls and an
+    add at full D width; per-element arithmetic is bit-identical
     (x1*cos + x2*(-sin) == x1*cos - x2*sin in IEEE fp).
 
     The combine runs in ``x.dtype`` (round-4 retune): upcasting the
@@ -758,8 +755,7 @@ def lm_loss(model: GPTLM):
     Uses the vocab-chunked head (``ops/xent.py``): the model returns final
     hidden states and the tied-embedding logits are built and reduced one
     token chunk at a time, so the fp32 ``(B, S, V)`` logits tensor never
-    exists — measured +19% tokens/sec like-for-like on the v5e chip for
-    GPT-2-small (BENCH_RESULTS/lm_*.json).
+    exists.
     """
     xent = _pick_xent(model.cfg)
 

@@ -100,10 +100,9 @@ class CifarResNet(nn.Module):
 class ImageNetResNet(nn.Module):
     """Bottleneck ResNet for ImageNet; stage_sizes (3,4,6,3) -> ResNet-50.
 
-    ``space_to_depth`` re-expresses the stem conv the MLPerf-TPU way
-    (docs/RESNET_PERF.md §3 L2): the C=3 minor dim of the 224x224x3 input
-    defeats the TPU's (8,128) register tiling (conv1 fwd measured at 480
-    GB/s vs 758+ elsewhere).  Packing 2x2 spatial blocks into channels
+    ``space_to_depth`` re-expresses the stem conv the MLPerf-TPU way: the
+    C=3 minor dim of the 224x224x3 input defeats the TPU's (8,128) register
+    tiling.  Packing 2x2 spatial blocks into channels
     gives a 112x112x12 input, and the 7x7/s2 stem is equivalent to a
     4x4/s1 conv on it: output(i,j) = sum_{di,dj} W[di,dj] x[2i+di-3,
     2j+dj-3]; writing di-3 = 2p+a (a in {0,1}) maps every tap onto kernel

@@ -129,8 +129,8 @@ class RecordReader:
         if not self._h:
             raise OSError(f"cannot open record files {list(paths)!r}")
         # Batched pulls: one FFI round-trip per ~batch of records (the
-        # per-record ctypes path was ~5x slower than plain Python file
-        # reads — bench_input.py).  _pending holds sliced-out records.
+        # per-record ctypes path is slower than plain Python file reads).
+        # _pending holds sliced-out records.
         self._pending: list[bytes] = []
         self._pending_ix = 0
         # GC safety net: a dropped, unexhausted reader still joins its C++
@@ -193,7 +193,7 @@ class RecordReader:
         round-trip per producer batch (~256 records) and **no per-record
         Python object creation** — on a single core the per-record
         ``bytes`` construction is what pins the iterator API at
-        pure-Python speed (bench_input.py), so fixed-shape/tokenized
+        pure-Python speed, so fixed-shape/tokenized
         consumers that can slice numpy views should use this.
 
         Both views alias memory that is FREED when the generator advances

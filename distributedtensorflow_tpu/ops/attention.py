@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,15 +18,6 @@ import jax.numpy as jnp
 from ..runtime import on_tpu, use_kernel
 
 NEG_INF = -1e9  # large-negative in bf16-safe range (bf16 max ~3.4e38; 1e9 fine)
-
-#: Decode-step kernel selection: "auto" (Pallas single-token kernel where
-#: platform/VMEM allow) or "xla" (force the einsum lowering).  Seeded from
-#: the env; deliberately a MUTABLE module global, re-read at every trace:
-#: bench_generate._xla_relative swaps it between back-to-back compiles for
-#: the XLA-relative A/B (the decode claim hierarchy's primary axis), and
-#: tests monkeypatch it.  Do not cache or freeze it at import time.
-DECODE_IMPL = os.environ.get("DTF_DECODE_IMPL", "auto")
-
 
 def dot_product_attention(
     q: jax.Array,  # (B, S, H, D)
@@ -76,7 +66,12 @@ def cached_decode_attention(
     cache_index: jax.Array,  # () int32 — next write slot
     window: int | None = None,  # sliding window (matches training masking)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One KV-cache decode step, shared by every serving path.
+    """One decode step over a dense ``(B, Hkv, max_seq, D)`` K/V cache.
+
+    The dense-cache reference: ``models.generate`` and the seq2seq decoder
+    step through it, and the paged server's tests hold the served tokens to
+    what it produces.  It is on no served path (``serve/`` attends pages:
+    :func:`paged_decode_attention` and below).
 
     Pure function (caller owns the cache state, e.g. a flax "cache"
     collection): writes the new K/V at ``cache_index``, attends the new
@@ -84,21 +79,9 @@ def cached_decode_attention(
     a query at absolute position ``ix+i`` sees keys at positions
     ``<= ix+i``, which is also correct for multi-token chunked prefill —
     and returns ``(out, cached_k, cached_v, cache_index)`` updated.
-
-    Decode perf history (2026-08-01, GPT-2-small bs16 max_seq 1024, all
-    measured in BENCH_RESULTS/generate_20260801_*.json): XLA's gemv
-    lowering costs ~9.6 ms/step INVARIANT to cache layout and operand
-    dtype (three formulations tied); a per-(b, h) Pallas kernel cut it
-    to 7.1 ms but paid ~2.2 ms of strided cache WRITES in (B, H, D, S)
-    plus DMA latency on 192 tiny tiles; the shipped form — cache
-    (B, H, S, D) so the per-step write is a contiguous row, single-token
-    steps dispatched to the head-blocked Pallas kernel — measures
-    **7.3 ms/step (1.34x the XLA lowering)**.  The remaining gap to the
-    ~1 ms memory floor is kernel-internal (half-empty lanes at D=64 and
-    per-head softmax passes); further cuts need Mosaic-level work, not
-    layout changes.  Softmax runs fp32 (matching :func:`xla_attention`);
-    the multi-token (prefill) path keeps the XLA einsums with native
-    operand dtype + fp32 accumulation.
+    Plain XLA einsums on every platform, MHA and grouped (the cache is
+    never broadcast to H); scores accumulate and softmax runs in float32,
+    matching :func:`xla_attention`.
     """
     b, s_new, h, d = q.shape
     max_seq = cached_k.shape[2]
@@ -115,26 +98,16 @@ def cached_decode_attention(
     if window is not None:
         # sliding window: only the last `window` positions stay visible
         valid &= k_idx[None, :] > q_pos[:, None] - window
-    # Kernel blocks are whole-axis in (S, D) (always tile-legal); the
-    # head-block picker bounds VMEM, so the only fallback case is a
-    # single head's (S, D) temporaries exceeding the budget.  Compiled
-    # kernel on TPU; interpret-mode kernel elsewhere so the CPU tests
-    # exercise the same code path.
-    if (DECODE_IMPL != "xla" and s_new == 1
-            and max_seq * d * _decode_bytes_per_elem(cached_k.dtype.itemsize)
-            <= _DECODE_VMEM_BUDGET):
-        out = _pallas_decode_attention(
-            q, cached_k, cached_v, valid.astype(jnp.int32),
-            interpret=not on_tpu(),
-        )
-        return out, cached_k, cached_v, ix + s_new
     h_kv = cached_k.shape[1]
+    # The grouped einsums take float32 operands: the CPU backend has no
+    # bf16 x bf16 -> f32 product for them, and the cast changes no number
+    # (a bf16 product is exact in float32; the sum was float32 already).
+    f32 = jnp.float32
     if h != h_kv:  # GQA: grouped einsums, cache never broadcast to H
         g = h // h_kv
         qg = q.reshape(b, s_new, h_kv, g, d)
         scores = jnp.einsum(
-            "bqhgd,bhkd->bhgqk", qg, cached_k,
-            preferred_element_type=jnp.float32,
+            "bqhgd,bhkd->bhgqk", qg.astype(f32), cached_k.astype(f32),
         ).reshape(b, h, s_new, max_seq) / (d ** 0.5)
     else:
         scores = jnp.einsum(
@@ -146,8 +119,7 @@ def cached_decode_attention(
     if h != h_kv:
         wg = weights.astype(q.dtype).reshape(b, h_kv, g, s_new, max_seq)
         out = jnp.einsum(
-            "bhgqk,bhkd->bqhgd", wg, cached_v,
-            preferred_element_type=jnp.float32,
+            "bhgqk,bhkd->bqhgd", wg.astype(f32), cached_v.astype(f32),
         ).reshape(b, s_new, h, d).astype(q.dtype)
     else:
         out = jnp.einsum(
@@ -353,137 +325,6 @@ def paged_verify_attention(
             preferred_element_type=jnp.float32,
         )
     return out.astype(q.dtype)
-
-
-def _decode_attn_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, *, scale):
-    """A block of heads of one batch row's single-token decode attention.
-
-    XLA lowers the decode gemv as separate multiply-reduce fusions that
-    measured ~9.6 ms/step at GPT-2-small bs16 regardless of cache layout
-    (see :func:`cached_decode_attention`).  This kernel fuses
-    scores -> masked softmax -> weighted-V for ``hb`` heads per grid
-    step over (hb, S, D) K/V tiles: the only HBM traffic is one read of
-    each.
-
-    Lane-major formulation (round-4 rework of the first measured kernel):
-    the original computed per-head scores as an (S, 1) COLUMN — every
-    softmax/mask pass used 1 of 128 lanes, and the score and weighted-V
-    contractions ran as VPU multiply+lane-reduce over fp32-cast (S, D)
-    tiles, which is exactly the "half-empty lanes and per-head softmax
-    passes" gap its 7.3 ms measurement recorded.  Here both contractions
-    are MXU dot_generals on the native-dtype tiles (fp32 accumulation)
-    and every elementwise temporary is a lane-major (8, S) row tile —
-    the 8 sublanes carry the q broadcast the block layout ships anyway,
-    so each pass is 8 full vregs instead of 128 nearly-empty ones, and
-    the (S, D) fp32 cast passes disappear entirely.
-    """
-    # q/out ride with an 8-deep broadcast sublane dim — (1, hb, 8, d)
-    # blocks keep the head block on an UNTILED leading dim, so any hb is
-    # tile-legal (a (hb, d) trailing block is only legal for hb % 8 == 0
-    # or hb == H, and Mosaic cannot reshape lanes to sublanes in-kernel;
-    # both found on-chip at hb=4).  Same trick as fused_xent's _SUB
-    # scratch.  The head loop is a STATIC unroll.
-    hb = q_ref.shape[1]
-    # GQA: the kv block carries hb // group heads; q head hi reads kv
-    # head hi // group — the group shares one streamed (S, D) tile, so
-    # the cache read (the decode step's binding HBM cost) shrinks by
-    # the group factor.
-    group = hb // k_ref.shape[1]
-    valid_row = valid_ref[...] != 0                     # (1, S)
-    for hi in range(hb):
-        q_h = q_ref[0, hi, :, :]                        # (8, D), rows equal
-        k_h = k_ref[0, hi // group, :, :]               # (S, D)
-        s = jax.lax.dot_general(
-            q_h, k_h, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # (8, S)
-        s = jnp.where(valid_row, s, NEG_INF)
-        m = jnp.max(s, axis=1, keepdims=True)
-        p = jnp.exp(s - m)
-        w = (p / jnp.sum(p, axis=1, keepdims=True)).astype(v_ref.dtype)
-        o_ref[0, hi] = jax.lax.dot_general(
-            w, v_ref[0, hi // group, :, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)                           # (8, D), rows equal
-
-
-def _decode_bytes_per_elem(kv_itemsize: int) -> int:
-    """VMEM bytes per cache element in the decode kernel: the
-    double-buffered K and V blocks (2 operands x 2 buffers x itemsize)
-    plus slack for the small lane-major temporaries.  Scales with the
-    cache dtype — a flat bf16 constant under-counted fp32 caches ~2x
-    and could pick a block over the 16 MB VMEM limit.  The lane-major
-    kernel holds no fp32 (S, D) casts (the old formulation's flat
-    24 B/elem), so more heads fit one grid step."""
-    return 4 * kv_itemsize + 2
-
-
-_DECODE_VMEM_BUDGET = 12 * 2**20
-
-
-def _pick_decode_head_block(h: int, s: int, d: int, kv_itemsize: int,
-                            group: int = 1) -> int:
-    """q-heads per grid step: a multiple of ``group`` (so every step's
-    kv block holds whole GQA groups) whose kv-side tile fits the VMEM
-    budget.  At group=1 this is the original picker."""
-    import os
-
-    o = os.environ.get("DTFT_DECODE_HEAD_BLOCK")  # on-chip sweep override
-    if o:
-        n = int(o)
-        if n > 0 and h % n == 0 and n % group == 0:
-            return n
-        import sys
-
-        print(f"decode_attention: DTFT_DECODE_HEAD_BLOCK={o} invalid for "
-              f"{h} heads / group {group}; using the auto-picked block",
-              file=sys.stderr)
-    for hb_kv in (16, 12, 8, 6, 4, 3, 2, 1):
-        hb = hb_kv * group
-        if h % hb == 0 and hb_kv * s * d * _decode_bytes_per_elem(kv_itemsize) \
-                <= _DECODE_VMEM_BUDGET:
-            return hb
-    return group
-
-
-def _pallas_decode_attention(q, cached_k, cached_v, valid, *, interpret):
-    """Single-token decode attention over the (B, Hkv, S, D) cache.
-
-    ``q`` (B, 1, H, D); ``valid`` (1, S) int32 (1 = attend).  Returns
-    (B, 1, H, D).  Grid (B, H/hb): each step streams hb/group kv heads'
-    K/V (GQA shares each kv tile across its query-head group).
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, _, h, d = q.shape
-    h_kv, s = cached_k.shape[1], cached_k.shape[2]
-    group = h // h_kv
-    hb = _pick_decode_head_block(h, s, d, cached_k.dtype.itemsize, group)
-    hb_kv = hb // group
-    mem = pl.ANY if interpret else pltpu.VMEM
-    q8 = jnp.broadcast_to(
-        q.transpose(0, 2, 1, 3), (b, h, 8, d)
-    )  # (B, H, 8, D): 8-deep sublane broadcast (see kernel note)
-    out = pl.pallas_call(
-        functools.partial(_decode_attn_kernel, scale=1.0 / (d ** 0.5)),
-        name="decode_attention",
-        grid=(b, h // hb),
-        in_specs=[
-            pl.BlockSpec((1, hb, 8, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=mem),
-            pl.BlockSpec((1, hb_kv, s, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=mem),
-            pl.BlockSpec((1, hb_kv, s, d), lambda i, j: (i, j, 0, 0),
-                         memory_space=mem),
-            pl.BlockSpec((1, s), lambda i, j: (0, 0), memory_space=mem),
-        ],
-        out_specs=pl.BlockSpec((1, hb, 8, d), lambda i, j: (i, j, 0, 0),
-                               memory_space=mem),
-        out_shape=jax.ShapeDtypeStruct((b, h, 8, d), q.dtype),
-        interpret=interpret,
-    )(q8, cached_k, cached_v, valid)
-    return out[:, :, 0, :][:, None, :, :]  # (B, 1, H, D)
 
 
 def xla_attention(q, k, v, *, mask=None, causal=False, window=None):

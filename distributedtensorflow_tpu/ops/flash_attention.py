@@ -35,7 +35,7 @@ Two entries, one set of masks and one softmax:
   XLA (ten a block pass at GPT-2 medium's training shapes, 241 ms of a
   1995 ms step: ``PERF.md`` section 6, PR 35).
   BERT, GQA, the ring / Ulysses chunks (``parallel/ring_attention.py``),
-  the ``"xla"`` backward and ``bench_attn.py`` call it.
+  and the ``"xla"`` backward call it.
 - :func:`flash_attention_qkv` takes a block's fused projection (B, S,
   3*H*D) as the matmul wrote it: the kernels pick 128-lane tiles of the q,
   k and v thirds through their index maps (two heads of 64 a tile), rotate
@@ -64,20 +64,16 @@ from ..runtime import on_tpu
 
 NEG_INF = -1e9
 
-#: Block tiling.  Retuned on the v5e 2026-08-01 (tools/sweep_flash_blocks.py,
-#: artifacts BENCH_RESULTS/flashsweep_20260801_*.json): 1024x1024 q/k blocks
-#: win at EVERY swept length — fwd+bwd vs the old 128x512 default:
-#: 9.11 vs 12.57 ms at seq 1024 (B16 H12 D64), 17.1 vs 28.3 ms at 4k,
-#: 25.8 vs 49.0 ms at 8k.  The kernel is VPU/softmax-bound, not matmul-
-#: bound, so fewer+bigger grid steps amortize per-step scalar/DMA overhead;
-#: (1024, 1024) fp32 score tiles (+temps) still fit Mosaic's 16 MB stack
-#: (1024x2048 does not — compile-checked on chip).
+#: Block tiling: the first of the default chain.  The kernel is VPU/softmax-
+#: bound, not matmul-bound, so fewer and bigger grid steps amortize the
+#: per-step scalar/DMA overhead; (1024, 1024) fp32 score tiles (+temps) are
+#: the largest that fit Mosaic's 16 MB stack (1024x2048 does not compile).
 DEFAULT_BLOCK_Q = 1024
 
 
 def _env_block(name: str) -> int | None:
     """On-chip sweep override for a block size (read per call so one
-    process can A/B several tilings; see tools/sweep_flash_blocks.py)."""
+    process can A/B several tilings; ``tools/flash_forms.py`` reports it)."""
     import os
 
     v = os.environ.get(name)
@@ -95,8 +91,8 @@ def _env_block(name: str) -> int | None:
 def _env_divisible(name: str, seq_len: int) -> int | None:
     """The env-override block when set AND it divides the sequence; a
     non-dividing override warns (``warnings.warn`` — NOT a bare print:
-    bench JSON consumers parse this process's stdout/stderr) and falls
-    through to the next resolution tier."""
+    tools parse this process's stdout/stderr as JSON) and falls through to
+    the next resolution tier."""
     o = _env_block(name)
     if not o:
         return None
@@ -122,21 +118,10 @@ def _pick_block_q(seq_len: int) -> int | None:
     return o or _default_chain(seq_len, DEFAULT_BLOCK_Q)
 
 
-#: Auto-dispatch threshold.  Re-measured on the real v5e 2026-08-01 after
-#: the 1024x1024 block retune (tools/sweep_flash_blocks.py, artifact
-#: flashsweep_20260801_023237.json, B=16 H=12 D=64 bf16 causal — the GPT
-#: headline shapes): at seq 1024 the kernel now beats XLA's fused dense
-#: attention 1.22x fwd / 1.60x fwd+full-bwd (6.46/9.11 ms vs 7.94/14.61),
-#: where the OLD 128x512 tiling only managed 1.16x fwd+bwd — which is why
-#: this threshold used to sit at 4096.  At 4k the win is 3.3x, at 8k the
-#: dense path OOMs (attn_20260801_014350.json).  Below 1024 the dense
-#: path keeps the job: score tensors are small enough that XLA's fusion
-#: is competitive and the kernel's fixed overhead dominates — pending the
-#: seq-512 probe (VERDICT r4 #5): the env seed lets the watcher A/B BERT
-#: with the threshold at 512 (`DTF_MIN_SEQ_FOR_PALLAS=512 bench_bert.py`)
-#: in the same window as the attn_512 kernel probe, so the decision and
-#: its end-to-end consequence land together.  Mutable module global,
-#: re-read at each trace (tests monkeypatch it).
+#: Auto-dispatch threshold: the shortest sequence ``supported`` hands to the
+#: kernels.  Below it the score tensors are small enough that XLA's fused
+#: dense attention is competitive and the kernels' fixed overhead dominates.
+#: Mutable module global, re-read at each trace (tests monkeypatch it).
 MIN_SEQ_FOR_PALLAS = int(os.environ.get("DTF_MIN_SEQ_FOR_PALLAS", "1024"))
 
 
@@ -508,9 +493,9 @@ def _fwd_kernel_1k(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_q,
                    qseg_ref=None, kseg_ref=None, window=None):
     """Single-k-block forward: the softmax in one pass, no online state.
 
-    When the whole K/V sequence fits one k block (the seq<=1024 headline
-    regime under the 1024x1024 retune, where the kernel is VPU-bound —
-    docs/LM_PERF.md), the online-softmax recurrence degenerates to a
+    When the whole K/V sequence fits one k block (seq <= 1024 under the
+    1024x1024 tiling, where the kernel is VPU-bound), the online-softmax
+    recurrence degenerates to a
     plain row softmax: the m/l/acc scratch buffers, their init pass, the
     alpha rescale of the accumulator, and the (block_q, 128) broadcast
     writes are all dead work this kernel simply does not emit.  Same
@@ -690,8 +675,7 @@ BACKWARD_IMPL = "pallas"
 #: instead (at D=64 the cutoff is seq 8192).  2 MiB, not 4: the scratch
 #: shares the 16 MB VMEM with the (1024, 1024) fp32 score/p/dp/ds tiles,
 #: and a 4 MiB scratch compiled but OOM'd AT RUN TIME on the v5e at
-#: seq 16384 (measured 2026-08-01; 8192 runs and is 11% faster than
-#: split end-to-end).
+#: seq 16384 (8192 runs).
 FUSED_BWD_DQ_SCRATCH_BYTES = 2 * 2**20
 
 

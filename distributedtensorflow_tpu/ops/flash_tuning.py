@@ -1,10 +1,8 @@
 """On-disk autotune cache for the flash-attention block tiling.
 
-``ops/flash_attention.py`` historically picked its (block_q, block_k)
-tiling from a hand-retuned constant plus a divide-the-sequence fallback
-chain — one number for every shape, refreshed only when someone re-ran
-``tools/sweep_flash_blocks.py`` on a live chip and edited the source.
-This module replaces that with a **runtime-consulted cache**: a JSON file
+``ops/flash_attention.py`` picks its (block_q, block_k) tiling from a
+constant plus a divide-the-sequence fallback chain — one number for every
+shape.  This module puts a **runtime-consulted cache** before it: a JSON file
 keyed on (shape, dtype, platform) whose entries are produced either by
 ``tools/autotune_flash.py``'s timing microbench sweep or from a
 CaptureEngine XPlane, and looked up by the kernel at trace time.

@@ -3,9 +3,8 @@
 :func:`ops.xent.chunked_softmax_xent` already keeps the full ``(B*S, V)``
 logits out of the *residual* set, but every chunk's ``(C, V)`` logits tile
 still round-trips HBM — materialized by the matmul, re-read by logsumexp,
-re-materialized and re-read twice more in the checkpointed backward.  On
-the v5e that is ~20 GB of HBM traffic per GPT-2-small step (B=16, S=1024:
-the single largest non-matmul cost of the step — see docs/LM_PERF.md).
+re-materialized and re-read twice more in the checkpointed backward: ~20 GB
+of HBM traffic per GPT-2-small step at B=16, S=1024 (``estimate_hbm_bytes``).
 
 This module fuses the head end-to-end in Pallas so the fp32 logits live
 only in VMEM, tile by tile, and HBM sees ``x``, ``wte``, the O(N) outputs
@@ -71,7 +70,7 @@ from ..runtime import on_tpu
 from .flash_attention import NEG_INF
 
 def _env_int(name: str, default: int) -> int:
-    """Bench/debug override for a tile size (read once at import).
+    """Debug override for a tile size (read once at import).
 
     The defaults below are VMEM-budget reasoning, not measurements; the
     ``DTFT_XENT_*`` envs let an on-chip sweep retune them without code
@@ -84,15 +83,13 @@ def _env_int(name: str, default: int) -> int:
 #: Default tile sizes.  The binding constraint is Mosaic's 16 MB scoped-
 #: VMEM stack: the (block_v, block_n) fp32 logits tile plus its
 #: elementwise temporaries (iota/mask/exp) dominate, alongside the
-#: double-buffered operand blocks.  Measured on the v5e 2026-08-01:
-#: block_v=2048 x block_n=512 compiled to a 16.71 MB stack — 724 KB OVER
-#: the limit; 1024 x 512 fits with ~2x headroom.  The trade is NOT free:
-#: the w table streams once per token chunk regardless of block_v, but x
-#: restreams once PER VOCAB BLOCK (vocab-outer sweep), so halving block_v
-#: doubles the fwd x-restream (and dw's, which shared these tiles until
-#: PR 42) — estimate_hbm_bytes put the move at 2.92 -> 4.18 GB/step at
-#: the headline config, ~1.5 ms @ 819 GB/s, against a kernel that
-#: otherwise does not compile at all.
+#: double-buffered operand blocks.  block_v=2048 x block_n=512 compiles to
+#: a 16.71 MB stack — 724 KB OVER the limit; 1024 x 512 fits with ~2x
+#: headroom.  The trade is NOT free: the w table streams once per token
+#: chunk regardless of block_v, but x restreams once PER VOCAB BLOCK
+#: (vocab-outer sweep), so halving block_v doubles the fwd x-restream
+#: (``estimate_hbm_bytes``), against a kernel that otherwise does not
+#: compile at all.
 BLOCK_TOKENS = _env_int("DTFT_XENT_BLOCK_TOKENS", 512)
 BLOCK_VOCAB = _env_int("DTFT_XENT_BLOCK_VOCAB", 1024)
 #: dx backward uses a bigger token tile: its dominant HBM cost is the full
@@ -100,14 +97,11 @@ BLOCK_VOCAB = _env_int("DTFT_XENT_BLOCK_VOCAB", 1024)
 #: Its vocab tile is the smallest: the dx kernel carries the most live
 #: fp32 temporaries (p, dlog, the fp32-cast weight tile, the fp32 dx
 #: accumulator), so it hits the same 16 MB stack wall soonest.
-#: On-chip sweep 2026-08-01 (bs16 seq1024 headline): token tile 2048
-#: first measured 118.7k tok/s vs 116.8k at 1024, but (a) 2048's ~18 MB
-#: Mosaic stack only fits in SOME surrounding programs — it compiled
-#: inside the seq-1024 train step yet fails in isolation AND inside the
-#: seq-8192 step with the SAME padded (16384, 768) operands (scoped-
-#: stack accounting is context-dependent), and (b) a re-measure of the
-#: 1024 default landed 118.6k: the apparent tile win was mostly run
-#: variance.  1024 is robust everywhere and costs nothing measurable.
+#: A token tile of 2048 has a ~18 MB Mosaic stack that only fits in SOME
+#: surrounding programs — it compiled inside a seq-1024 train step yet
+#: fails in isolation AND inside a seq-8192 step with the SAME padded
+#: (16384, 768) operands (scoped-stack accounting is context-dependent);
+#: 1024 compiles everywhere.
 BLOCK_TOKENS_DX = _env_int("DTFT_XENT_BLOCK_TOKENS_DX", 1024)
 BLOCK_VOCAB_DX = _env_int("DTFT_XENT_BLOCK_VOCAB_DX", 512)
 
@@ -133,9 +127,8 @@ def _blocks_for_dim(d: int) -> tuple[int, int, int, int, int, int]:
 
     Every kernel tile is (block, d)- or (block_v, block_n)-shaped, so the
     VMEM stack scales with d: the d<=768 defaults above (on-chip-tuned at
-    GPT-2-small) VMEM-OOM at d=1024 (GPT-2-medium), where the measured
-    fitting set is 512 across the board (46.0k tok/s, MFU 0.566 —
-    still ahead of the chunked_bf16 head's 44.1k).  Env overrides win
+    GPT-2-small) VMEM-OOM at d=1024 (GPT-2-medium), where the fitting set
+    is 512 across the board.  Env overrides win
     unconditionally at every d; the dw kernel's tiles do not depend on d."""
     if d <= 768:
         # The module constants above ARE the d<=768 defaults (env already
@@ -234,13 +227,12 @@ def _bwd_dx_kernel(x_ref, w_ref, t_ref, lse_ref, c_ref, dx_ref, dlog_ref,
     match = row == t_ref[...]
     # dlog drops to the operand compute dtype (bf16 in training) so the
     # two products it feeds run native MXU passes instead of the
-    # ~4x-slower fp32 emulation — profiled at 46% MXU with the old fp32
-    # operands (docs/LM_PERF.md round-4 anatomy); accumulation stays
-    # fp32.  This matches standard mixed-precision (dlogits are bf16
-    # wherever logits are), and bf16's fp32-sized exponent keeps the tiny
-    # c*(p-match) magnitudes exact in scale.  fp32 operands are left
-    # untouched.  The rounded tile is formed HERE ONLY: it goes to HBM for
-    # the dw kernel as it is handed to this kernel's own product.
+    # ~4x-slower fp32 emulation; accumulation stays fp32.  This matches
+    # standard mixed-precision (dlogits are bf16 wherever logits are), and
+    # bf16's fp32-sized exponent keeps the tiny c*(p-match) magnitudes exact
+    # in scale.  fp32 operands are left untouched.  The rounded tile is
+    # formed HERE ONLY: it goes to HBM for the dw kernel as it is handed to
+    # this kernel's own product.
     dlog = (c_ref[...] * (p - match.astype(jnp.float32))).astype(w_ref.dtype)
     dlog_ref[...] = dlog
     # dx_i += sum_j dlogits_ji * wte_j : contract the vocab sublanes.
@@ -332,7 +324,7 @@ def _fused_fwd_arrays(x, w, t, *, block_n, block_v, v_true, interpret):
         # a (1, block_n) block over an (n_i, block_n) array would put a
         # sublane block of 1 over an array dim > 1, which the real Mosaic
         # lowering rejects ("block shape ... divisible by 8 and 128") even
-        # though interpret mode accepts it — found on-chip 2026-08-01.
+        # though interpret mode accepts it.
         lse, tgt = pl.pallas_call(
             functools.partial(_fwd_kernel, block_v=block_v, v_true=v_true),
             name="fused_xent_fwd",

@@ -5,10 +5,8 @@ The reference stack's LayerNorm is Keras ``LayerNormalization``
 as separate reduce + apply fusions.  Our models' pre-LN trunks ran the
 same way (flax ``nn.LayerNorm(dtype=float32)``): the input is read once
 for the statistics reduce and again for the normalize, with an fp32
-promotion in between — profiled at ~16.6 ms/step of the GPT-2-small
-headline (the multiply_reduce/convert_reduce fusion families,
-``BENCH_RESULTS/profile_lm_tpu`` 2026-08-01), second only to the
-attention and head kernels.
+promotion in between (the multiply_reduce / convert_reduce fusion
+families of a profile).
 
 These kernels read each ``(block_n, D)`` tile ONCE: mean/var/normalize
 happen VMEM-resident in fp32 and only the normalized output returns to

@@ -3,8 +3,7 @@
 The naive LM loss materializes fp32 logits ``(B, S, V)`` plus a
 ``log_softmax`` copy — for GPT-2-small at B=16, S=1024, V=50257 that is
 ~3.3 GB *per copy*, and the train step becomes HBM-bandwidth-bound on
-tensors that are immediately reduced away (measured on the v5e chip:
-see BENCH_RESULTS/lm_*.json before/after).  The reference stack has no
+tensors that are immediately reduced away.  The reference stack has no
 equivalent (Keras ``SparseCategoricalCrossentropy`` materializes logits
 the same way); this is TPU-first design, not a port.
 

@@ -67,7 +67,7 @@ def ring_attention(
         # hardware (off-TPU the interpret-mode kernel is orders of magnitude
         # slower than the einsum ring), and only once the PER-DEVICE chunk
         # is long enough that the kernel beats XLA's fused attention
-        # (MIN_SEQ_FOR_PALLAS — the bench_attn.py-evidenced threshold).
+        # (``flash_attention.MIN_SEQ_FOR_PALLAS``).
         # Callers can always force impl="flash".
         ok = (
             on_tpu()

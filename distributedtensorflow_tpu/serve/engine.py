@@ -1,10 +1,10 @@
 """Continuous-batching generation engine: queue → slots → paged decode.
 
-The batch serving path (``models.generate``) decodes a whole batch in one
-``lax.scan``: every sequence pays ``max_new_tokens`` steps, a finished
-sequence squats its slot emitting EOS, and nothing can join mid-flight —
-fine for offline eval, fatal for request serving.  This engine is the
-online replacement:
+The dense-cache reference (``models.generate``, what this engine's tests
+hold its tokens to) decodes a whole batch in one ``lax.scan``: every
+sequence pays ``max_new_tokens`` steps, a finished sequence squats its slot
+emitting EOS, and nothing can join mid-flight — fine for offline eval and
+as a reference, fatal for request serving.  This engine is what serves:
 
 - **thread-safe FIFO queue** (bounded; a full queue rejects loudly so the
   frontend can return 429 instead of letting latency grow unboundedly);

@@ -1,9 +1,9 @@
 """Paged KV cache: refcounted block pool + prefix index + page tables.
 
-The dense serving cache (``models.generate``) pins ``max_seq`` tokens of
-K/V per batch slot for the whole request lifetime — a 16-token reply in a
-slot sized for 2048 tokens wastes 99% of the slot's HBM.  This module is
-the vLLM-style fix, built on the same sequence-chunking idiom as
+The dense cache of the reference (``models.generate``) pins ``max_seq``
+tokens of K/V per batch slot for the whole request lifetime — a 16-token
+reply in a slot sized for 2048 tokens wastes 99% of the slot's HBM.  This
+module is the vLLM-style fix, built on the same sequence-chunking idiom as
 ``ops/blockwise.py``: K/V live in a pool of fixed-size **blocks** shared
 by every slot, each slot's **page table** row names the blocks holding
 its sequence, and a refcounted **allocator** hands blocks out per request

@@ -4,8 +4,8 @@ the fused decode / verify fast path — one set for every model family.
 Prefill/decode disaggregation: a serving step is either (a) teacher-forced
 ingestion of a prompt chunk — big matmuls, compute-bound — or (b) one
 token for every active slot — cache streaming, memory-bound.  Fusing them
-(the ``models.generate`` whole-batch scan) forces every request in the
-batch to the same phase; splitting them lets the scheduler admit a new
+(the reference's whole-batch scan, ``models.generate``) forces every request
+in the batch to the same phase; splitting them lets the scheduler admit a new
 prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 

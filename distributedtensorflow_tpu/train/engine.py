@@ -126,7 +126,8 @@ class _InstrumentedStep:
     Counts dispatches into the obs registry and records the first dispatch
     (which pays tracing + XLA compile) as a gauge — without touching the
     per-dispatch hot path beyond one counter increment.  ``lower`` is
-    forwarded so the AOT path (`bench.py`'s ``step.lower(...).compile()``)
+    forwarded so the AOT path (``step.lower(...).compile()``, as
+    ``tools/train_step_memory.py`` calls it)
     keeps working on the wrapped object.
 
     Every call and ``lower`` runs under ``jax.sharding.set_mesh(mesh)``:

@@ -32,8 +32,8 @@ const Tables& tables() {
 // Hardware path: the SSE4.2 crc32 instruction computes exactly the
 // Castagnoli polynomial.  Compiled with a per-function target attribute so
 // the binary stays runnable on pre-SSE4.2 CPUs; dispatched once at startup
-// via __builtin_cpu_supports.  ~8-10x the slice-by-8 table path, which
-// made CRC verification ~40% of record-reader time (bench_input.py).
+// via __builtin_cpu_supports.  Several times the slice-by-8 table path,
+// under which CRC verification is a large share of record-reader time.
 __attribute__((target("sse4.2")))
 uint32_t crc32c_hw(uint32_t crc, const void* data, size_t n) {
   const uint8_t* p = static_cast<const uint8_t*>(data);

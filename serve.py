@@ -6,7 +6,7 @@ serving engine, and fronts it with the ``/generatez`` HTTP endpoint plus
 the whole ``/statusz`` introspection family (including the per-tenant
 usage ledger at ``GET /usagez``).  One process per host; the
 model may be mesh-sharded (GSPMD partitions both serving programs the
-same way it partitions ``models.generate``).
+same way it partitions the dense-cache reference, ``models.generate``).
 
 Examples:
 

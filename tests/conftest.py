@@ -45,7 +45,6 @@ _PROCESS_TEST_FILES = {
     "test_coordinator_process.py",
     "test_data_service.py",
     "test_pipeline_mpmd.py",
-    "test_bench_smoke.py",
     "test_examples.py",
     "test_sidecar.py",
     "test_combined_axes.py",

@@ -24,9 +24,8 @@ def make_qkv(b=2, s=256, h=4, d=32, dtype=jnp.float32, seed=0):
 
 
 def test_pick_block_q(monkeypatch):
-    # 1024-first chain (2026-08-01 on-chip retune; see DEFAULT_BLOCK_Q).
-    # A leaked sweep override (tools/sweep_flash_blocks.py sets this var)
-    # would change the chain — pin the default environment.
+    # 1024-first chain (see DEFAULT_BLOCK_Q).  A leaked override would
+    # change the chain — pin the default environment.
     monkeypatch.delenv("DTFT_FLASH_BLOCK_Q", raising=False)
     assert _pick_block_q(2048) == 1024
     assert _pick_block_q(1024) == 1024

@@ -324,8 +324,7 @@ def test_chunked_xent_bf16_compute_dtype_close_to_fp32():
 def test_workload_trains_with_fused_xent(devices):
     """gpt_lm with xent_impl="fused" (Pallas head, interpret mode on CPU)
     trains through the full engine path and the loss falls — the
-    integration guard for the BENCH_LM_XENT=fused / --xent-impl=fused
-    on-chip A/B."""
+    integration guard for ``--xent-impl=fused``."""
     wl = get_workload("gpt_lm", test_size=True, global_batch_size=8,
                       xent_impl="fused")
     assert wl.model.cfg.xent_impl == "fused"

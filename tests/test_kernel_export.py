@@ -29,7 +29,6 @@ from distributedtensorflow_tpu.ops.attention import (
     index_scores,
     select_bias,
     sparse_latent_attention,
-    _pallas_decode_attention,
     paged_latent_chunk_attention,
     paged_latent_decode_attention,
     paged_window_chunk_attention,
@@ -99,10 +98,6 @@ def _ln(x, g, b):
             x, g, b, impl="pallas", interpret=False)),
         argnums=(0, 1, 2),
     )(x, g, b)
-
-
-def _decode(q, k, v, valid):
-    return _pallas_decode_attention(q, k, v, valid, interpret=False)
 
 
 def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2,
@@ -287,10 +282,6 @@ FAMILIES = {
                            _sds((B, S), jnp.int32))),
     "layer_norm": (_ln, (_sds((B, S, H * D), BF16),
                          _sds((H * D,), F32), _sds((H * D,), F32))),
-    "decode": (_decode, (_sds((B, 1, H, D), BF16),
-                         _sds((B, H, S, D), BF16),
-                         _sds((B, H, S, D), BF16),
-                         _sds((1, S), jnp.int32))),
     "paged_attn_full": _paged(None),
     "paged_attn_window": _paged(4096),
     # GPT-2 medium's serving shapes: 32 slots, 16 heads of 64 (two a

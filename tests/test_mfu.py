@@ -1,14 +1,13 @@
 """MFU estimator reconciliation (ISSUE 7 satellite).
 
-BENCH_r02 showed the analytic and xla-cost MFU paths disagreeing 2x on
-ResNet-50 (0.16 vs 0.32): the analytic constant passed a MAC count where
-a MACs x 2 FLOP count was owed.  These tests PIN both estimator paths to
-the same convention on a known matmul — XLA's ``cost_analysis()`` counts
-an ``(M,K) @ (K,N)`` matmul as exactly ``2*M*N*K`` FLOPs, and the
-analytic side (:func:`obs.mfu.matmul_flops`, bench.py's per-image
-constants) must use the same arithmetic — so the two numbers can only
-diverge for the documented structural reason (scan bodies counted once;
-``xla_flops_scale``), never by a units mismatch.
+The analytic and xla-cost MFU paths once disagreed 2x on ResNet-50: the
+analytic constant passed a MAC count where a MACs x 2 FLOP count was owed.
+These tests PIN both estimator paths to the same convention on a known
+matmul — XLA's ``cost_analysis()`` counts an ``(M,K) @ (K,N)`` matmul as
+exactly ``2*M*N*K`` FLOPs, and the analytic side
+(:func:`obs.mfu.matmul_flops`) must use the same arithmetic — so the two
+numbers can only diverge for the documented structural reason (scan bodies
+counted once; ``xla_flops_scale``), never by a units mismatch.
 """
 
 import jax
@@ -59,17 +58,30 @@ def test_mfu_fields_agree_on_known_matmul(compiled_matmul):
     )
 
 
-def test_resnet_constant_uses_macs_times_two():
-    """Change-detector for the BENCH_r02 2x bug: the ResNet-50 analytic
-    constant must be the MACs x 2 figure (fwd 4.1 GMACs = 8.2 GF, train
-    ~3x fwd = 24.6 GF/image), not the bare MAC count."""
-    import bench
+def test_mfu_xla_cost_scales_with_steps_per_call():
+    """XLA cost analysis counts a lax.scan body once, so a k-steps-per-
+    dispatch executable under-reports executed FLOPs by ~k.  mfu_fields
+    must honour xla_flops_scale=k."""
 
-    assert bench.RESNET50_TRAIN_FLOPS_PER_IMAGE == pytest.approx(24.6e9)
+    class FakeCompiled:
+        def cost_analysis(self):
+            return {"flops": 1e12}
+
+    base = mfu_lib.mfu_fields(FakeCompiled(), dt=1.0, n_steps=10,
+                              device_kind="TPU v5 lite",
+                              analytic_flops_per_step=2e12,
+                              analytic_source="test")
+    scaled = mfu_lib.mfu_fields(FakeCompiled(), dt=1.0, n_steps=10,
+                                device_kind="TPU v5 lite",
+                                analytic_flops_per_step=2e12,
+                                analytic_source="test", xla_flops_scale=20.0)
+    assert scaled["mfu_xla_cost"] == pytest.approx(
+        20.0 * base["mfu_xla_cost"], rel=1e-2)  # fields round to 4 places
+    assert scaled["mfu_analytic"] == base["mfu_analytic"]
 
 
 def test_unknown_device_kind_has_no_peak():
-    """No default chip: the bench accounting raises on a kind without
+    """No default chip: the accounting raises on a kind without
     published peaks, and the Trainer's record carries no mfu field."""
     for kind in ("cpu", "TPU v9 imaginary", ""):
         with pytest.raises(KeyError, match="no published peaks"):

@@ -1,45 +1,10 @@
-"""Profile-tool hardening: analyze_trace / profile_summary exit non-zero
-with a one-line diagnostic on missing/empty/corrupt profile dirs (they
-used to traceback or print a silent empty table), and the
-captures.jsonl schema gate in check_metrics_schema."""
+"""Profile-tool hardening: profile_summary exits non-zero with a one-line
+diagnostic on a missing or empty profile dir, and the captures.jsonl schema
+gate in check_metrics_schema."""
 
-import gzip
 import json
 
-import pytest
-
-from tools import analyze_trace, check_metrics_schema, profile_summary
-
-
-# -- analyze_trace -----------------------------------------------------------
-
-def test_analyze_trace_missing_dir_one_line_exit(tmp_path):
-    with pytest.raises(SystemExit) as e:
-        analyze_trace.main([str(tmp_path / "nope")])
-    assert "no such profile dir" in str(e.value)
-
-
-def test_analyze_trace_empty_dir_one_line_exit(tmp_path):
-    with pytest.raises(SystemExit) as e:
-        analyze_trace.main([str(tmp_path)])
-    assert "no *.trace.json.gz" in str(e.value)
-
-
-def test_analyze_trace_corrupt_gz_one_line_exit(tmp_path):
-    bad = tmp_path / "x.trace.json.gz"
-    bad.write_bytes(b"not gzip at all")
-    with pytest.raises(SystemExit) as e:
-        analyze_trace.main([str(bad)])
-    assert "unreadable trace" in str(e.value)
-
-
-def test_analyze_trace_empty_capture_one_line_exit(tmp_path):
-    empty = tmp_path / "x.trace.json.gz"
-    with gzip.open(empty, "wt") as f:
-        json.dump({"traceEvents": []}, f)
-    with pytest.raises(SystemExit) as e:
-        analyze_trace.main([str(empty)])
-    assert "no traceEvents" in str(e.value)
+from tools import check_metrics_schema, profile_summary
 
 
 # -- profile_summary ---------------------------------------------------------

@@ -4,7 +4,6 @@ Reference model: SURVEY.md §2.3 — ``AutoShardPolicy`` {OFF,AUTO,FILE,DATA}
 (`options.py:89`), `auto_shard_dataset` (`input_ops.py:28`).
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -288,47 +287,3 @@ def test_seq2seq_trains_from_record_files(tmp_path, devices):
         state, metrics = step(state, next(it), rng)
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0] - 0.2, losses[::8]
-
-
-def test_raw_u8_image_records_roundtrip(tmp_path):
-    """bench.py's records-input evidence path (VERDICT r4 #3): raw-u8
-    fixed-shape records written shard-wise, read back through the native
-    reader + custom decode_fn, batch content bit-exact vs the seeded
-    generator, and the .done integrity marker gates reuse/regeneration."""
-    import bench
-
-    root = str(tmp_path / "imgrec")
-    paths = bench._ensure_imagenet_records(root, n_images=24, image_size=16,
-                                           num_shards=3)
-    assert len(paths) == 3
-    decode = bench._decode_raw_image(16)
-    # num_threads=1: the first-record bit-exact assertion below needs
-    # deterministic shard order (multi-thread readers interleave files).
-    batches = list(record_dataset(paths, batch_size=8, decode_fn=decode,
-                                  policy="OFF", num_threads=1))
-    assert len(batches) == 3
-    for b in batches:
-        assert b["image"].shape == (8, 16, 16, 3)
-        assert b["image"].dtype == np.uint8
-        assert b["label"].shape == (8,)
-        assert b["label"].dtype == np.int32
-        assert (0 <= b["label"]).all() and (b["label"] < 1000).all()
-    # content matches the seeded generator (first record of shard 0 is
-    # image index 0: round-robin i % num_shards)
-    rng = np.random.default_rng(0)
-    img0 = rng.integers(0, 256, (16, 16, 3), dtype=np.uint8)
-    lab0 = np.int32(rng.integers(0, 1000))
-    np.testing.assert_array_equal(batches[0]["image"][0], img0)
-    assert batches[0]["label"][0] == lab0
-    # reuse: second call returns without rewriting (same mtimes)
-    mtimes = [os.path.getmtime(p) for p in paths]
-    assert bench._ensure_imagenet_records(root, n_images=24, image_size=16,
-                                          num_shards=3) == paths
-    assert [os.path.getmtime(p) for p in paths] == mtimes
-    # changed spec (n_images) regenerates instead of silently reusing
-    paths2 = bench._ensure_imagenet_records(root, n_images=27, image_size=16,
-                                            num_shards=3)
-    total = sum(
-        1 for _ in record_dataset(paths2, batch_size=None, decode_fn=decode,
-                                  policy="OFF"))
-    assert total == 27

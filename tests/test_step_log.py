@@ -560,16 +560,19 @@ def _after_commit(eng, fn):
 
 
 def _busy(seconds):
-    t0 = time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
+    """Burn ``seconds`` of this thread's own CPU time, however long the box
+    takes to grant them."""
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
         pass
 
 
 @pytest.mark.parametrize("what", ["sleep", "busy"])
 def test_offcpu_tells_waiting_from_working(served_model, what):
     """20 ms inside the commit: asleep, the engine thread was off the CPU
-    for them and ``offcpu_s`` says so; in a busy loop it was working, and
-    ``commit_cpu_s`` holds them instead."""
+    for them and ``offcpu_s`` says so; in a busy loop that burns 20 ms of
+    the thread's CPU it was working, and ``commit_cpu_s`` holds them instead
+    (how long the loop was descheduled meanwhile is the box's business)."""
     cfg, params, _ = served_model
     eng = _decoding_engine(cfg, params)
     rows = []
@@ -584,9 +587,7 @@ def test_offcpu_tells_waiting_from_working(served_model, what):
         assert max(r["offcpu_s"] for r in rows) >= 0.8 * 0.02
         assert min(r["commit_cpu_s"] for r in rows) < 0.01
     else:
-        best = min(rows, key=lambda r: r["offcpu_s"])
-        assert best["offcpu_s"] < 0.5 * best["commit_s"]
-        assert best["commit_cpu_s"] > 0.5 * 0.02
+        assert all(r["commit_cpu_s"] >= 0.8 * 0.02 for r in rows)
     eng.stop(drain=False)
 
 

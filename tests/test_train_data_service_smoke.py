@@ -88,26 +88,3 @@ def test_train_data_service_end_to_end(tmp_path):
     assert ip["data_prefetch_depth"] >= 1
     assert len(ip["workers"]) == 2
     assert 0.0 <= ip["data_wait_share"] <= 1.0
-
-
-def test_bench_input_service_rows_smoke(tmp_path):
-    """bench_input's service rows measure all four protocol/wire combos
-    over identical batch streams (BENCH_INPUT_TEST size)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_INPUT_TEST="1")
-    res = subprocess.run(
-        [
-            sys.executable, "-c",
-            "import bench_input, json; "
-            "print(json.dumps(bench_input.bench_service()))",
-        ],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
-    )
-    assert res.returncode == 0, res.stderr[-4000:]
-    doc = json.loads(res.stdout.strip().splitlines()[-1])
-    rows = doc["rows"]
-    assert set(rows) == {
-        "service_per_conn_npz", "service_per_conn_raw",
-        "service_stream_npz", "service_stream_raw",
-    }
-    assert all(v > 0 for v in rows.values())
-    assert doc["speedup_stream_raw_vs_per_conn_npz"] > 1.0

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Autotune the flash-attention block tiling and persist the winner.
 
-Replaces the static ``DEFAULT_BLOCK_Q/K`` + hand-run
-``tools/sweep_flash_blocks.py`` loop with a cache the kernel consults at
-trace time (``ops/flash_tuning.py``): run this tool once per
+Puts a cache the kernel consults at trace time (``ops/flash_tuning.py``)
+before the static ``DEFAULT_BLOCK_Q/K``: run this tool once per
 (shape, dtype, platform) of interest and every subsequent
 ``flash_attention`` call on that shape picks the measured-best tiling
 automatically (env overrides still win; see
@@ -39,7 +38,7 @@ with ``source: "xplane"`` — certifying the production tiling's measured
 cost so a later sweep has a baseline to beat.
 
 Cache: ``--cache`` path, else ``DTFT_FLASH_TUNE_CACHE``, else
-``~/.cache/distributedtensorflow_tpu/flash_blocks.json``.  Exactly one
+``flash_blocks.json`` beside ``ops/flash_tuning.py``.  Exactly one
 JSON line is printed with the stored entry.
 """
 

@@ -4,13 +4,12 @@ xplane proto.
 
 Usage::
 
-    python tools/profile_summary.py BENCH_RESULTS/profile_lm_tpu [--top 30]
+    python tools/profile_summary.py <logdir>/captures/0 [--top 30]
 
 Reads the ``*.xplane.pb`` a ``jax.profiler.start_trace`` /
 ``train.py --profile-dir`` window writes and prints, per device plane, the
 top event names by summed duration with their share of the plane's busy
-time.  This is the instrument for VERDICT r2 #1's "profile a real step,
-then attack the top costs": the installed ``tensorboard_plugin_profile``
+time.  The installed ``tensorboard_plugin_profile``
 (2.13) cannot parse TF 2.21's pywrap output, so this goes straight at the
 proto (schema: ``tensorflow/tsl/profiler/protobuf/xplane.proto`` in the
 installed wheel — the XSpace → planes → lines → events tree with

@@ -427,7 +427,7 @@ def serving_summary(rows: list[dict], metrics_rows: list[dict] | None
         if name == "tpot_s":
             # single-token completions have no per-output-token interval
             # (the engine writes tpot_s=0.0) — including them would
-            # deflate the tail; bench_serve applies the same filter.
+            # deflate the tail.
             rows_for = [r for r in ok if r.get("new_tokens", 0) > 1]
         vals = sorted(
             r[name] for r in rows_for
