@@ -35,6 +35,11 @@ from .mimo import (  # noqa: F401
     mimo_tiny,
     mimo_v25_ep16,
 )
+from .lfm2 import (  # noqa: F401
+    Lfm2Config,
+    lfm2_24b_a2b,
+    lfm2_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

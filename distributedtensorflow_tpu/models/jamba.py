@@ -78,7 +78,8 @@ class JambaConfig:
         if self.num_experts != 1:
             raise ValueError(
                 "routed experts inside the jamba family (num_experts > 1) "
-                "are not implemented: every layer's FFN is the dense SwiGLU")
+                "are not implemented: every layer's FFN is the dense SwiGLU "
+                "(models.lfm2 is the state-group family that routes)")
 
     @property
     def channels(self) -> int:

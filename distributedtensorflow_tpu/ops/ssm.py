@@ -5,7 +5,9 @@ A Mamba layer keeps, a sequence, a *fixed-size* state whatever the context:
 the scan state ``s`` of ``d_state`` values a channel, and the last ``d_conv -
 1`` inputs of its causal depthwise convolution (the *convolution tail*).
 :class:`SSMState` states both (a model's ``cfg.state_rows``, beside
-``ops.attention.KVRows`` / ``LatentRows`` for what a layer caches a token).
+``ops.attention.KVRows`` / ``LatentRows`` for what a layer caches a token);
+:class:`ConvTail` is the state of a layer that keeps the tail and has no scan
+(``models.lfm2``'s gated short convolution).
 
 The recurrence, a token ``t``, channels ``c`` and states ``n``, in float32::
 
@@ -56,32 +58,64 @@ SCAN_ROWS = 64
 SCAN_LANES = 512
 
 
-@dataclasses.dataclass(frozen=True)
-class SSMState:
-    """What a Mamba layer keeps a slot: the convolution tail, ``d_conv - 1``
-    inputs of ``channels`` in the activations' type, stored as one row (the
-    oldest input first: with the slots of a layer on the sublanes every tile
-    is full, where ``(d_conv - 1, channels)`` a slot would pad 3 rows to a
-    tile's 16), and the scan state ``(d_state, channels)`` in float32."""
+def _tail_array(channels: int, d_conv: int, dtype):
+    """The convolution tail a slot a layer: ``d_conv - 1`` inputs of
+    ``channels`` in the activations' type, stored as one row (the oldest
+    input first: with the slots of a layer on the sublanes every tile is
+    full, where ``(d_conv - 1, channels)`` a slot would pad 3 rows to a
+    tile's 16)."""
+    return (((d_conv - 1) * channels,), jnp.dtype(dtype))
 
-    channels: int
-    d_state: int
-    d_conv: int
 
-    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
-        """(shape a slot a layer, dtype) of each state array, in pool order:
-        the convolution tail, the scan state."""
-        return ((((self.d_conv - 1) * self.channels,), jnp.dtype(dtype)),
-                ((self.d_state, self.channels), jnp.dtype(jnp.float32)))
+class _SlotArrays:
+    """What the two state forms share: ``arrays(dtype)`` lists a slot's
+    arrays a layer in pool order, ``names`` names them in the same order."""
 
     def slot_bytes(self, dtype) -> int:
         """Bytes a slot a layer."""
         return sum(math.prod(shape) * dt.itemsize
                    for shape, dt in self.arrays(dtype))
 
+
+@dataclasses.dataclass(frozen=True)
+class SSMState(_SlotArrays):
+    """What a Mamba layer keeps a slot: the convolution tail
+    (:func:`_tail_array`) and the scan state ``(d_state, channels)`` in
+    float32."""
+
+    channels: int
+    d_state: int
+    d_conv: int
+
+    names = ("conv_tail", "scan_state")
+
+    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
+        """(shape a slot a layer, dtype) of each state array, in pool order:
+        the convolution tail, the scan state."""
+        return (_tail_array(self.channels, self.d_conv, dtype),
+                ((self.d_state, self.channels), jnp.dtype(jnp.float32)))
+
     def chunk_formulation(self, chunk: int, impl: str) -> str:
         return chunk_scan_formulation(self.channels, self.d_state, chunk,
                                       impl)
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvTail(_SlotArrays):
+    """What a layer that is a short causal convolution and nothing else
+    keeps a slot (``models.lfm2``): the convolution tail alone, one array.
+    There is no scan, so a chunk has no scan formulation."""
+
+    channels: int
+    d_conv: int
+
+    names = ("conv_tail",)
+
+    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
+        return (_tail_array(self.channels, self.d_conv, dtype),)
+
+    def chunk_formulation(self, chunk: int, impl: str) -> None:
+        return None
 
 
 # -- the convolution and its tail --------------------------------------------

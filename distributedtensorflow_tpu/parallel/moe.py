@@ -411,18 +411,20 @@ def group_tile(tokens: int, top_k: int, published: int) -> int:
 
 def sigmoid_topk_route(h: jax.Array, router_kernel: jax.Array,
                        select_bias: jax.Array, *, top_k: int,
-                       route_norm: bool = True, route_scale: float = 1.0):
+                       route_norm: bool = True, route_scale: float = 1.0,
+                       route_norm_eps: float = 1e-20):
     """``(idx, weight)`` of shape ``(T, top_k)``: float32 sigmoid scores
     over the router's full width, the top ``top_k`` of ``score + bias``
-    (the bias selects only), weights ``score[top] / (sum + 1e-20) *
-    route_scale``."""
+    (the bias selects only), weights ``score[top] / (sum +
+    route_norm_eps) * route_scale`` (the families publish 1e-20; lfm2
+    1e-6)."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
     _, idx = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if route_norm:
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(-1, keepdims=True) + route_norm_eps)
     return idx, w * route_scale
 
 
@@ -505,6 +507,7 @@ def dropless_moe(
     top_k: int,
     route_norm: bool = True,
     route_scale: float = 1.0,
+    route_norm_eps: float = 1e-20,
     token_mask: jax.Array | None = None,
     impl: str = "auto",
 ) -> tuple[jax.Array, dict]:
@@ -523,7 +526,8 @@ def dropless_moe(
     with jax.named_scope("router"):
         idx, w = sigmoid_topk_route(
             h, router_kernel, select_bias, top_k=top_k,
-            route_norm=route_norm, route_scale=route_scale)
+            route_norm=route_norm, route_scale=route_scale,
+            route_norm_eps=route_norm_eps)
         plan = group_plan(idx, held, token_mask, tile)
     with jax.named_scope("experts"):
         x_rows = jnp.concatenate(

@@ -1962,6 +1962,9 @@ class Engine:
             # the form a prefill chunk scans a state group's layers with:
             # "ssm_chunk_scan" or "plain"; None where no layer keeps a state
             "chunk_scan": self.programs.chunk_scan,
+            # what a state layer keeps a slot: "conv_tail+scan_state"
+            # (jamba), "conv_tail" (lfm2); None where no layer keeps a state
+            "state_form": self.programs.state_form,
             # bytes the cache stores a token over all layers
             "cache_row_bytes": self.kv.row_bytes,
             "kv_groups": self.kv_groups(),

@@ -729,7 +729,8 @@ class PagedKVCache:
 #   so there is still no mid-flight out-of-blocks;
 # - a *state* group (:class:`StateGroup`) holds the layers that keep a
 #   fixed-size state a slot and nothing a token (``models.jamba``'s Mamba
-#   layers): arrays ``(layers, slots, ...)``, no pages, no allocator.
+#   layers, ``models.lfm2``'s conv layers): arrays ``(layers, slots, ...)``,
+#   as many as the family's state form lists, no pages, no allocator.
 #
 # :class:`GroupedKVCache` admits against all groups at once and otherwise
 # answers the engine as one cache: it is the only cache the engine holds.  A
@@ -824,7 +825,8 @@ class WindowKVGroup(PagedKVCache):
 class StateGroup:
     """A layer group that keeps a fixed-size state a *slot*, whatever its
     context (``models.jamba``'s Mamba layers: the convolution tail and the
-    scan state, ``ops.ssm.SSMState``): no pages and no allocator.  Its arrays
+    scan state, ``ops.ssm.SSMState``; ``models.lfm2``'s conv layers: the
+    tail alone, ``ops.ssm.ConvTail``): no pages and no allocator.  Its arrays
     are ``(layers, max_slots, ...)`` each, one a state array of ``rows``, every
     slot's provisioned; a slot's "page table" is the one column that names
     the slot itself, which is how a prefill chunk learns whose state it

@@ -10,8 +10,8 @@ prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
-``models.joyai``, ``models.jamba``, ``models.mimo``): ``embed(params, ids,
-cfg)``, ``block(p,
+``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``):
+``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
 ``init_params(cfg, key)``.  Its
@@ -45,14 +45,15 @@ and the three programs differ only in where the rows live:
   budgeted prefill), and a request admitted onto a cached prefix starts
   from the shared blocks without a special load path.  Any prompt length =
   a Python loop of these fixed-width calls; the head is applied to the one
-  row the engine wants.  For a family with a *state group* (jamba: layers
-  that keep a fixed-size state a slot, ``cfg.keeps_state(layer)``) a chunk
-  is not stateless: it scans from the state the slot's last chunk left in
-  the slot's row of the group's arrays (zeros at ``start == 0``) and stores
-  the state back, so a slot's chunks still interleave freely with other
-  slots' work, in order; the program takes the count of real tokens, since
-  a pad step must be the identity.  On such a layer the block is handed a
-  :class:`_SlotState` in ``attend``'s place.
+  row the engine wants.  For a family with a *state group* (jamba, lfm2:
+  layers that keep a fixed-size state a slot, ``cfg.keeps_state(layer)``) a
+  chunk is not stateless: it scans from the state the slot's last chunk left
+  in the slot's row of the group's arrays (zeros at ``start == 0``) and
+  stores the state back, so a slot's chunks still interleave freely with
+  other slots' work, in order; the program takes the count of real tokens,
+  since a pad step must be the identity, and hands every block the mask of
+  them (``token_mask``: a pad token reaches no expert, lfm2).  On such a
+  layer the block is handed a :class:`_SlotState` in ``attend``'s place.
 - :func:`make_decode_fn` — one token for all ``max_slots`` slots against
   the paged pool (``ops.attention.paged_window_decode_attention`` or
   ``paged_latent_decode_attention``: on the TPU a kernel that reads only the
@@ -65,8 +66,9 @@ and the three programs differ only in where the rows live:
 — the copy-on-write path — compiled only if a CoW ever fires.)
 
 ``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, the one pool
-of latent rows, or a state group's ``(convolution tails, scan states)``,
-``(layers, slots, ...)`` each) and ``tables`` ``{group: page
+of latent rows, or a state group's arrays — ``(convolution tails, scan
+states)`` for jamba, ``(convolution tails,)`` for lfm2: what
+``cfg.state_rows.arrays`` lists —, ``(layers, slots, ...)`` each) and ``tables`` ``{group: page
 table}`` (a state group's is the one column that names the slot;
 ``serve.kv_cache.GroupedKVCache``: layers in groups by attention
 kind; GPT-2 is one full group); ``layers`` maps a group to the model layers
@@ -97,7 +99,11 @@ block, the slot's keys gathered and scored in the form) and ``select`` (the
 top ``index_topk`` positions a query); ``kv_write`` then holds the index
 key's write too, ``h<i>/attn`` for jamba, whose Mamba layers have
 ``h<i>/{state_read,state_write}`` and ``h<i>/mamba/{in_proj,conv,x_proj,
-dt_proj,scan|ssm_step,gate,out_proj}``), ``head``, ``sample``,
+dt_proj,scan|ssm_step,gate,out_proj}``, and for lfm2, whose attention layers
+add ``h<i>/attn/{qk_norm,rope}`` and whose conv layers have
+``h<i>/{state_read,state_write}`` and ``h<i>/conv/{in_proj,gate_in,conv,
+gate_out,out_proj}``; an expert layer's FFN is ``h<i>/{router,experts}``, a
+dense one's ``h<i>/mlp``), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
 Metadata only, so a profiler trace can say which stage a device operation
 belongs to.
@@ -110,7 +116,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, gpt, jamba, joyai, mimo
+from ..models import afmoe, gpt, jamba, joyai, lfm2, mimo
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
 from .kv_cache import group_rows
 from .sampling import sample_burst
@@ -145,13 +151,15 @@ def _write_rows(form, pools: tuple, li: int, at, rows: tuple) -> tuple:
 
 
 class _SlotState:
-    """The ``mixer`` hook of a state layer (``models.jamba``): the programs'
-    own, as ``attend`` is.  ``conv`` and ``scan`` read the layer's state out
-    of the group's arrays (scope ``state_read``), run the form (``mamba/conv``;
-    ``mamba/scan`` or ``mamba/ssm_step``) and store the state back
-    (``state_write``) — the convolution tail is array 0, the scan state
-    array 1 (``ops.ssm.SSMState.arrays``).  ``pools`` is the program's dict
-    of pools, updated in place."""
+    """The ``mixer`` hook of a state layer (``models.jamba``,
+    ``models.lfm2``): the programs' own, as ``attend`` is.  ``conv`` and
+    ``scan`` read the layer's state out of the group's arrays (scope
+    ``state_read``), run the form (``<scope>/conv``; ``mamba/scan`` or
+    ``mamba/ssm_step``) and store the state back (``state_write``) — the
+    convolution tail is array 0 and, where the family scans, the scan state
+    array 1 (``cfg.state_rows.arrays``: a family that keeps the tail alone
+    never calls ``scan``).  ``pools`` is the program's dict of pools, updated
+    in place."""
 
     def __init__(self, pools: dict, li: int):
         self.pools, self.li = pools, li
@@ -161,10 +169,12 @@ class _SlotState:
         arrays[which] = self._put(arrays[which], value)
         self.pools["state"] = tuple(arrays)
 
-    def conv(self, u, w, b):
+    def conv(self, u, w, b, scope: str = "mamba"):
+        """The causal convolution of ``u`` after the layer's tail, under the
+        family's own scope ``<scope>/conv``."""
         with jax.named_scope("state_read"):
             tail = self._get(self.pools["state"][0])
-        with jax.named_scope("mamba"), jax.named_scope("conv"):
+        with jax.named_scope(scope), jax.named_scope("conv"):
             out, tail = self._conv(u, tail, w, b)
         with jax.named_scope("state_write"):
             self._store(0, tail)
@@ -242,9 +252,10 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     (the final prompt token's, clamped into range on non-final chunks whose
     logits are discarded).  Over a state group the program takes one more
     argument, ``valid``: how many of the chunk's tokens are real (pad K/V
-    rows are harmless, a pad step of a recurrence is not); the slot's state
-    is row ``table_rows["state"][0]`` of the group's arrays.  The pools are
-    donated."""
+    rows are harmless, a pad step of a recurrence is not, and a pad token
+    routed to an expert is work and a count: every block gets the mask of
+    the real ones); the slot's state is row ``table_rows["state"][0]`` of the
+    group's arrays.  The pools are donated."""
     where, forms = _group_of(layers), _forms_of(cfg, layers)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -252,6 +263,8 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                       *valid):
         pools = dict(pools)
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
+        real = (jnp.arange(chunk, dtype=jnp.int32) < valid[0]
+                if valid else None)
         with jax.named_scope("kv_rows"):
             rows = {name: row[positions // block_size] * block_size
                     + positions % block_size
@@ -274,7 +287,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                 cfg.kernel_impl)
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
-                                   positions, mixer)
+                                   positions, mixer, token_mask=real)
         last = jax.lax.dynamic_slice_in_dim(x, last_ix, 1, 0)
         return family.head(params, last, cfg)[0], pools
 
@@ -475,6 +488,7 @@ PROGRAMS = {
     joyai.JoyaiConfig: joyai,
     jamba.JambaConfig: jamba,
     mimo.MimoConfig: mimo,
+    lfm2.Lfm2Config: lfm2,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -486,7 +500,7 @@ PROGRAMS = {
 #: formulation), so it is refused until it has
 #: such tests and a cell of its own.  Over a state group there is more in
 #: the way than tests: a rejected draft's steps cannot be rolled back out of
-#: a state, which keeps no earlier position (jamba).
+#: a state, which keeps no earlier position (jamba, lfm2).
 FUSED = (gpt,)
 
 #: the families a request may be admitted for onto cached prefix blocks: those
@@ -547,7 +561,11 @@ class Programs:
       not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers
       with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM)
-      or ``"plain"`` (``lax.scan``); None where no layer keeps a state."""
+      or ``"plain"`` (``lax.scan``); None where no layer keeps a state, or
+      the state has no scan (lfm2);
+    - ``state_form``: what a state layer keeps a slot, the names of the
+      group's arrays joined by ``+``: ``"conv_tail+scan_state"`` (jamba),
+      ``"conv_tail"`` (lfm2); None where no layer keeps a state."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,
                  layers: dict[str, tuple[int, ...]]):
@@ -588,6 +606,12 @@ class Programs:
             return None
         return self.cfg.state_rows.chunk_formulation(
             self.chunk, self.cfg.kernel_impl)
+
+    @property
+    def state_form(self) -> str | None:
+        if "state" not in self.layers:
+            return None
+        return "+".join(self.cfg.state_rows.names)
 
     def prefill(self, params, pools, tokens, start: int, table_rows,
                 real: int):

@@ -36,6 +36,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from ..ops.ssm import SSMState
 from . import kv_cache
 from .model import FUSED, family_of, make_programs
 
@@ -45,9 +46,9 @@ from .model import FUSED, family_of, make_programs
 #: ``pools[1]``).
 _POOLS_ARG = re.compile(r"^pools(?:\[\\?'(\w+)\\?'\])?\[([01])\]$")
 _POOL_NAMES = ("k_pool", "v_pool")
-#: the arrays of a state group (``ops.ssm.SSMState.arrays``), donated like
-#: the pools
-_STATE_NAMES = ("conv_tail", "scan_state")
+#: the arrays of a state group by their place in it, donated like the pools
+#: (``ops.ssm``: an ``SSMState`` has both, a ``ConvTail`` the first alone)
+_STATE_NAMES = SSMState.names
 
 _RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
 _INSTRUCTION = re.compile(
@@ -236,13 +237,13 @@ def check_pool_programs(programs: dict, layer_elems: int,
     return report
 
 
-def failures(report: dict, pools: int = 2, state: bool = False,
+def failures(report: dict, pools: int = 2, state: tuple[str, ...] = (),
              window: bool = False) -> list[str]:
     """What :func:`check_pool_programs` found wrong, one line each, for a
     group of ``pools`` pools (the K/V pair, or one pool of latent rows), with
-    ``window`` a window group's beside the full group's, and, with ``state``,
-    a state group's arrays (``copy_block`` takes the full group's pools
-    only)."""
+    ``window`` a window group's beside the full group's, and, with ``state``
+    the names of a state group's arrays (``cfg.state_rows.names``), those
+    (``copy_block`` takes the full group's pools only)."""
     bad = []
     for name, r in report.items():
         for op in r["relayouts"]:
@@ -251,7 +252,7 @@ def failures(report: dict, pools: int = 2, state: bool = False,
         if window and name != "copy_block":
             want = sorted(want + [f"window.{n}" for n in want])
         if state and name != "copy_block":
-            want = sorted(want + list(_STATE_NAMES))
+            want = sorted(want + list(state))
         if r["donated"] != want:
             bad.append(f"{name}: donated in place only {r['donated']}")
     forms = {r["k_pool"] for r in report.values()}
@@ -291,7 +292,8 @@ def main(argv=None) -> int:
         layer_elems=shape[1] * shape[2], state=state)
     # the pool as the process holds it between calls
     pool = jnp.zeros(shape, cfg.dtype)
-    bad = failures(report, pools=len(widths), state=state is not None)
+    bad = failures(report, pools=len(widths),
+                   state=cfg.state_rows.names if state else ())
     print(json.dumps({
         "device": runtime.device_summary(),
         "pool_shape": shape, "pool_dtype": str(pool.dtype),

@@ -58,6 +58,10 @@ CONFIGS = {
     # ones, a K/V head count a kind, keys wider than values; random init
     "mimo_tiny": ("mimo_tiny", None),
     "mimo_v25_ep16": ("mimo_v25_ep16", None),
+    # the lfm2 family (models/lfm2.py): gated short-convolution layers that
+    # keep a tail a slot beside attention layers, routed experts; random init
+    "lfm2_tiny": ("lfm2_tiny", None),
+    "lfm2_24b_a2b": ("lfm2_24b_a2b", None),
 }
 
 
@@ -315,6 +319,7 @@ def main(argv=None) -> int:
                  decode_attention=engine.programs.decode_attention,
                  chunk_attention=engine.programs.chunk_attention,
                  chunk_scan=engine.programs.chunk_scan,
+                 state_form=engine.programs.state_form,
                  cache_row_bytes=engine.kv.row_bytes,
                  kv_groups=engine.kv_groups())
     server = ServeServer(engine, args.port, host=args.host).start()

@@ -699,7 +699,8 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
     report = pool_check.check_pool_programs(
         {program: programs[program]}, layer_elems=rows * width,
         state=(32, cfg.state_rows.arrays(cfg.dtype)))
-    assert pool_check.failures(report, state=True) == []
+    assert pool_check.failures(
+        report, state=cfg.state_rows.names) == []
     assert report[program]["donated"] == ["conv_tail", "k_pool", "scan_state",
                                           "v_pool"]
     fn, args = programs[program]
@@ -710,6 +711,50 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
         assert len(calls) == 3 and len(set(calls)) == 1, calls
         assert text.count('kernel_name = "ssm_chunk_scan"') == 1
         assert text.count('kernel_name = "kv_chunk_attn"') == 1
+    else:
+        assert text.count('kernel_name = "paged_attn"') == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+def test_conv_tail_program_keeps_pools_and_tails_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The lfm2 family at its published widths, four layers deep (a dense
+    conv layer, an attention layer and two conv layers with all 64 experts)
+    and with a small vocabulary: the programs take the K/V pools and the
+    state group's one array, the convolution tails, as they are stored, copy
+    or transpose no layer of either, and hand all three back in place; decode
+    attends heads of 64 (four query heads a K/V head) through ``paged_attn``
+    and routes through the grouped kernels; a prefill chunk attends through
+    the plain loop (the chunk kernel wants a head of 128) and takes the count
+    of real tokens.  (It is refused the fused programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import lfm2_24b_a2b
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        lfm2_24b_a2b(), max_seq=2048, vocab_size=1024,
+        layer_types=("conv", "full_attention", "conv", "conv"))
+    assert cfg.state_rows.names == ("conv_tail",)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=96, num_blocks=4096, block_size=16, chunk=256, draft=4,
+        sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    _, rows, width = kv_cache.pool_shape(1, 4096, 16, 8 * 64)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width,
+        state=(96, cfg.state_rows.arrays(cfg.dtype)))
+    assert pool_check.failures(report, state=cfg.state_rows.names) == []
+    assert report[program]["donated"] == ["conv_tail", "k_pool", "v_pool"]
+    fn, args = programs[program]
+    text = fn.lower(*args).as_text()
+    # one call an expert layer
+    assert text.count('kernel_name = "moe_grouped_up"') == 3
+    if program == "prefill_chunk":
+        assert len(args) == 7       # the count of real tokens
+        assert text.count('kernel_name = "kv_chunk_attn"') == 0
     else:
         assert text.count('kernel_name = "paged_attn"') == 1
 

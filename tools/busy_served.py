@@ -60,12 +60,19 @@ def _requests(config: dict, seed: int):
 
 def serve(config: dict, seed: int, quant: str) -> dict:
     """Both token sets, from weights that never leave the chip."""
+    import functools
+
     import jax
 
     from distributedtensorflow_tpu import models
+    from distributedtensorflow_tpu.serve import engine
     from distributedtensorflow_tpu.serve.model import family_of
 
     _, tool, prompts, fillers = _requests(config, seed)
+    # every request is submitted before the first step: the engine's queue
+    # (64 unless told) must hold a configuration of more slots than that
+    engine.Engine = functools.partial(
+        engine.Engine, max_queue=max(64, config["max_slots"]))
     cfg = getattr(models, config["system_config"])()
     params = family_of(cfg).init_params(
         cfg, jax.random.PRNGKey(seed % (2 ** 31 - 1)))
