@@ -112,54 +112,49 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _reply_routed(self, result) -> None:
         """Render an extra-route handler's ``(status, payload)`` result:
-        dict/list payloads as JSON, strings as plain text, and any other
-        iterable (a generator of str/bytes chunks) as a chunked-transfer
-        stream — the serving frontend's token streaming rides this."""
+        dict/list payloads as JSON, strings as plain text, and a callable
+        as a chunked-transfer stream the route writes itself
+        (:meth:`_reply_stream`) — the serving frontend's token streaming
+        rides this."""
         status, payload = result
         if isinstance(payload, str):
             self._reply(payload, status=status)
-        elif hasattr(payload, "__next__"):
-            # an ITERATOR (generator) streams; concrete containers
-            # (dict/list/tuple/set) keep rendering as JSON bodies
-            self._reply_chunked(payload, status=status)
+        elif callable(payload):
+            self._reply_stream(payload, status=status)
         else:
             self._reply_json(payload, status=status)
 
-    def _reply_chunked(self, chunks, *, status: int = 200,
-                       content_type: str = "application/x-ndjson") -> None:
-        """Stream an iterable of str/bytes as HTTP/1.1 chunked transfer.
+    def _reply_stream(self, take, *, status: int = 200,
+                      content_type: str = "application/x-ndjson") -> None:
+        """Send the headers of an HTTP/1.1 chunked transfer and give the
+        connection to ``take``.
 
-        Headers go out before the first chunk, so the producer must
-        already have validated the request (the status is committed).  A
-        client that disconnects mid-stream closes the producer (its
-        ``GeneratorExit`` runs) and drops the connection; a producer
-        exception after headers cannot be turned into an error status
-        any more, so the stream is terminated and the connection closed
-        — the outer handler's 500 path never runs after bytes went out."""
-        self.send_response(status)
-        self.send_header("Content-Type", content_type)
-        self.send_header("Transfer-Encoding", "chunked")
-        self.end_headers()
+        Headers go out before the first chunk, so the route must already
+        have validated the request (the status is committed).
+        ``take(sock)`` has the chunks and the terminating chunk written to
+        ``sock`` — by whatever thread: this one only waits — and returns
+        once the stream is over: whether the connection may serve another
+        request.  It is called exactly once: ``take(None)`` says the
+        client went away before the headers.  A failure after headers
+        cannot be turned into an error status any more, so the connection
+        is closed — the outer handler's 500 path never runs after bytes
+        went out."""
         try:
-            for chunk in chunks:
-                data = (chunk.encode("utf-8") if isinstance(chunk, str)
-                        else bytes(chunk))
-                if not data:
-                    continue
-                self.wfile.write(
-                    f"{len(data):X}\r\n".encode("ascii") + data + b"\r\n"
-                )
-                self.wfile.flush()
-            self.wfile.write(b"0\r\n\r\n")
+            self.send_response(status)
+            self.send_header("Content-Type", content_type)
+            self.send_header("Transfer-Encoding", "chunked")
+            self.end_headers()
         except OSError:
-            self.close_connection = True  # client went away mid-stream
-        except Exception:
-            logger.exception("streaming route producer failed mid-stream")
             self.close_connection = True
-        finally:
-            close = getattr(chunks, "close", None)
-            if close is not None:
-                close()
+            take(None)
+            return
+        try:
+            keep = take(self.connection)
+        except Exception:
+            logger.exception("streaming route failed mid-stream")
+            keep = False
+        if not keep:
+            self.close_connection = True
 
     def do_GET(self) -> None:  # noqa: N802 — http.server contract
         srv = self.server_ref
@@ -345,7 +340,9 @@ class StatusServer:
         #: Extra application endpoints: ``{("GET"|"POST", path): handler}``
         #: where a GET handler is ``fn(query) -> (status, payload)`` and a
         #: POST handler ``fn(query, body_bytes) -> (status, payload)``
-        #: (payload: dict/list → JSON, str → text/plain).  Handlers run on
+        #: (payload: dict/list → JSON, str → text/plain, a callable → a
+        #: chunked stream it writes itself, ``_Handler._reply_stream``).
+        #: Handlers run on
         #: HTTP threads — same thread-safety contract as status_fn; unlike
         #: the built-ins they MAY block (the serving frontend's POST
         #: /generatez waits for generation), each request has its own

@@ -61,10 +61,13 @@ as a reference, fatal for request serving.  This engine is what serves:
   dispatch.  Iterations where no slot has a draft fall back to the
   one-token fused program, so a low-hit-rate workload pays only the
   (microsecond) lookup;
-- **streaming**: a request submitted with ``stream=True`` exposes each
-  iteration's newly committed tokens through a per-request event queue
-  (the HTTP frontend's chunked ``/generatez`` transfer) — requests.jsonl
-  rows are unchanged.
+- **streaming**: the newly committed tokens of the requests submitted
+  with ``stream=True`` go to :attr:`Engine.stream_sink` in ONE call an
+  iteration, whatever the number of streams — ``(request, tokens,
+  stamp)`` triples, ``tokens`` None for a request that ended (the HTTP
+  frontend's one writer thread, ``serve.server.StreamWriter``, turns
+  them into the chunked ``/generatez`` transfer) — requests.jsonl rows
+  are unchanged.
 
 Observability (wired into the obs registry): ``serve_ttft_seconds``,
 ``serve_tpot_seconds``, ``serve_e2e_seconds``, ``serve_batch_occupancy``,
@@ -94,7 +97,7 @@ leaves, read off its ``engine.*`` span tree (mirrored into any open
 profiler trace; the leaves tile the iteration and the records tile the
 engine thread's life), with the thread's CPU clock beside the wall
 (``offcpu_s``: it had work and did not run), its seconds inside the
-collector and the stream threads' lines and lag
+collector and the stream writer's lines and lag
 (docs/OBSERVABILITY.md has the vocabulary) — in a
 bounded ring (``GET /stepz`` via the
 frontend; :meth:`Engine.step_records`) and ``steps.jsonl``.
@@ -114,7 +117,6 @@ import itertools
 import json
 import math
 import os
-import queue
 import statistics
 import threading
 import time
@@ -245,16 +247,11 @@ class GenRequest:
     #: the ``engine.prefill_chunk`` span of this request's last chunk: the
     #: frontier stands at its start until the next charge reads its wall
     _s_chunk: object = None
-    #: streaming: newly committed tokens per iteration as ("tokens",
-    #: [ids], stamp) events — ``stamp`` the ``time.time()`` of the commit,
-    #: which the stream thread measures its lag from — plus one terminal
-    #: ("done", None); None = blocking.
-    #: A ``SimpleQueue``: one producer, one consumer, never full — its
-    #: ``put`` is a C call that takes no Python-level lock or condition,
-    #: and the engine makes one a stream an iteration.
-    _events: queue.SimpleQueue | None = dataclasses.field(
-        default=None, repr=False
-    )
+    #: streaming: each iteration's newly committed tokens, and the end
+    #: of the request, go to ``Engine.stream_sink``; False = blocking.
+    #: ``submit(stream=...)`` as given: True, or the sink's own object for
+    #: the request (the frontend's writer keeps the stream's state here).
+    stream: object = False
     # -- chunked-prefill state (engine thread only) --
     _fill_buf: np.ndarray | None = dataclasses.field(
         default=None, repr=False
@@ -477,10 +474,22 @@ class Engine:
         self._cpu_leaf0 = 0.0      # thread CPU where .fetch / .commit began
         self._recent_walls: collections.deque = collections.deque(
             maxlen=STALL_HISTORY)
-        #: lags of the lines the stream threads have written since the
+        #: lags of the lines the stream writer has written since the
         #: last record (``note_stream_line`` appends, ``engine.log``
         #: drains: no lock on either side)
         self._stream_lags: collections.deque = collections.deque()
+        #: where the streaming requests' lines go: ``sink(batch)``, called
+        #: on the engine thread once for all the streams an iteration
+        #: committed tokens for (once more if requests ended in it, with
+        #: their ends; and once a first token), ``batch`` a list
+        #: of ``(request, tokens, stamp)`` — ``stamp`` the ``time.time()``
+        #: of the commit, which the sink measures its lag from
+        #: (:meth:`note_stream_line`); ``tokens`` None says the request
+        #: reached its end (its status is final).  It must not block:
+        #: ``ServeServer`` sets it to its writer thread's ``put``.  None
+        #: drops the lines (the tokens stay on the requests).
+        self.stream_sink = None
+        self._stream_out: list = []    # the batch being gathered
         if _gc_callback not in gc.callbacks:
             gc.callbacks.append(_gc_callback)
         self._step_evicted = 0     # requests finished in the current step
@@ -660,7 +669,7 @@ class Engine:
         trace_id: str | None = None,
         tenant: str | None = None,
         deadline_s: float | None = None,
-        stream: bool = False,
+        stream: object = False,
     ) -> GenRequest:
         """Validate + enqueue; returns the live :class:`GenRequest`.
 
@@ -746,12 +755,10 @@ class Engine:
             eos_token_id=eos_token_id, seed=int(seed),
             trace_id=trace_id or obs_tracing.new_trace_id(),
             tenant=tenant,
-            t_submit=time.time(),
+            t_submit=time.time(), stream=stream,
         )
         if deadline_s is not None:
             req.t_deadline = req.t_submit + deadline_s
-        if stream:
-            req._events = queue.SimpleQueue()
         req._rng = np.random.default_rng(req.seed)
         rejected = False
         with self._cond:
@@ -1100,7 +1107,7 @@ class Engine:
             self._step_log.flush()
 
     def note_stream_line(self, stamp: float) -> None:
-        """A stream thread wrote the line of the tokens committed at
+        """The stream writer handed the line of the tokens committed at
         ``stamp`` (``time.time()``) to its socket: the lag goes to
         ``serve_stream_lag_seconds`` and to the next step record."""
         lag = max(time.time() - stamp, 0.0)
@@ -1235,6 +1242,7 @@ class Engine:
         for req in expired:
             # Finished OUTSIDE the scheduler lock (log I/O, metrics).
             self._finish(req, None, status="error")
+        self._stream_flush()
         self._m_active.set(sum(r is not None for r in self._slots))
         self._update_kv_metrics()
         for req in admitted:
@@ -1366,8 +1374,10 @@ class Engine:
         self.usage.on_tokens({req.tenant: 1})
         self._last_tokens[req.slot] = tok
         self._m_ttft.observe(req.ttft_s)
-        self._stream_emit(req, [tok], req.t_first_token)
+        if req.stream:
+            self._stream_out.append((req, [tok], req.t_first_token))
         self._maybe_finish(req)
+        self._stream_flush()    # the first token does not wait for a decode
 
     def _run_decode_step(self, prefill_s: float) -> None:
         """One decode iteration for every slot whose prefill is done:
@@ -1382,9 +1392,10 @@ class Engine:
         for the device, and what the host needs of the result: a token a
         slot, and the logits only if a live request samples) and
         ``engine.decode.commit`` (host sampling where asked for, one pass
-        of bookkeeping over the batch, then the streams' lines; it ends
-        where ``engine.decode`` ends, so whatever the engine thread waits
-        for after waking the stream threads is inside it).
+        of bookkeeping over the batch, then the streams' lines in one
+        hand-over; it ends where ``engine.decode`` ends, so whatever the
+        engine thread waits for after waking the stream writer is inside
+        it).
         ``prefill_s`` is this iteration's ``engine.prefill`` wall, for the
         attribution split."""
         decoding = [
@@ -1494,11 +1505,11 @@ class Engine:
         the remainder to scheduler gap (admit scans, bookkeeping, idle
         waits between iterations) — worked out once for each frontier
         there is (one, but for requests whose prefill ended this
-        iteration).  Last of all, in one stretch, every stream gets this
-        iteration's line — so that the threads the lines wake run while
-        the engine waits for the next launch, not between two requests of
-        this loop — and the requests that ended (EOS, length) are
-        finished."""
+        iteration).  Last of all the streams' lines of this iteration go
+        to ``stream_sink`` in one call — one thread wakes for them, however
+        many streams there are, and it runs while the engine waits for the
+        next launch — then the requests that ended (EOS, length) are
+        finished, and their ends follow in a call of their own."""
         n_active = len(decoding)
         self.decode_steps += 1
         self.counters["decode_dispatches"] += 1
@@ -1543,10 +1554,13 @@ class Engine:
         self.usage.on_tokens(by_tenant)
         for n, requests in by_count.items():
             self._m_tok_step.observe(float(n), count=requests)
-        for (_, req), toks in zip(decoding, kept):
-            self._stream_emit(req, toks, now)
+        self._stream_out += [(req, toks, now)
+                             for (_, req), toks in zip(decoding, kept)
+                             if req.stream]
+        self._stream_flush()    # no line waits for the finishes
         for req in finished:
             self._maybe_finish(req)
+        self._stream_flush()    # their ends
 
     def _decode_step_fused(self, decoding, slots: np.ndarray,
                            prefill_s: float) -> None:
@@ -1691,13 +1705,14 @@ class Engine:
         ).astype(np.float64)  # np.random requires probs summing to 1 in f64
         return int(req._rng.choice(len(probs), p=probs / probs.sum()))
 
-    def _stream_emit(self, req: GenRequest, toks: list[int],
-                     stamp: float) -> None:
-        """Push newly committed tokens to a streaming request's event
-        queue (no-op for blocking requests), with the ``time.time()`` of
-        their commit."""
-        if req._events is not None and toks:
-            req._events.put(("tokens", toks, stamp))
+    def _stream_flush(self) -> None:
+        """Hand the lines gathered so far (and the ends: ``_finish``
+        appends them) to ``stream_sink``: one call for all of them."""
+        batch = self._stream_out
+        if batch:
+            self._stream_out = []
+            if self.stream_sink is not None:
+                self.stream_sink(batch)
 
     def _maybe_finish(self, req: GenRequest) -> None:
         last = req.tokens[-1]
@@ -1739,8 +1754,8 @@ class Engine:
         self._update_kv_metrics()
         self._log_request(req)
         self.usage.on_finish(req)
-        if req._events is not None:
-            req._events.put(("done", None))
+        if req.stream:
+            self._stream_out.append((req, None, req.t_done))
         req._done.set()
 
     def _update_kv_metrics(self) -> None:
@@ -1913,6 +1928,7 @@ class Engine:
         for req in doomed:
             req.error = message
             self._finish(req, "error", status="error")
+        self._stream_flush()
 
     # -- introspection / logs ------------------------------------------------
 

@@ -2,7 +2,8 @@
 
 A thin blocking-JSON frontend over :class:`serve.engine.Engine`, riding
 ``obs.server.StatusServer`` (stdlib ``http.server`` background thread, one
-handler thread per request) so a serving process exposes the whole
+handler thread per request; the streams' lines are all written by ONE
+thread, :class:`StreamWriter`) so a serving process exposes the whole
 introspection family — ``/healthz``, ``/statusz``, ``/varz`` (live
 Prometheus incl. the ``serve_*`` SLO histograms), ``/threadz``, ``/memz``
 — next to the generation endpoint, no third-party deps.
@@ -33,10 +34,16 @@ Endpoint contract (docs/API.md "Serving"):
   reply would (or the error).  Because headers go out before the first
   token, submit-time failures still map to real 4xx/5xx statuses —
   only post-admission failures land in the trailer.  requests.jsonl
-  rows are identical to blocking requests.
+  rows are identical to blocking requests.  Between a stream's headers
+  and its trailer no thread wakes for it but the engine's and the one
+  :class:`StreamWriter` thread: the engine hands it every stream's line
+  of an iteration in one call, the request's handler thread is parked
+  until the terminating chunk is out.
 - ``GET /generatez`` — engine state JSON: queue depth, slot occupancy
   (with each slot's ``prefill``/``decode`` phase), paged-KV budget,
-  admission/eviction counters, and the prefix-cache census (``kv``:
+  admission/eviction counters, the stream writer's census (``streams``:
+  open streams, bytes waiting for a full socket, lines, wakes), and the
+  prefix-cache census (``kv``:
   blocks free/used/cached, fragmentation, prefix occupancy, hit rate,
   evictions, CoW copies; ``prefill_budget``/``prefix_cache`` config) —
   the scheduler's live control surface.  The same census rides ``/varz``
@@ -50,10 +57,15 @@ Endpoint contract (docs/API.md "Serving"):
 
 from __future__ import annotations
 
+import collections
+import functools
+import heapq
+import itertools
 import json
 import logging
 import math
-import queue as queue_mod
+import selectors
+import socket
 import threading
 import time
 
@@ -62,7 +74,7 @@ from .engine import Engine, GenRequest, QueueFullError
 
 logger = logging.getLogger("distributedtensorflow_tpu")
 
-__all__ = ["ServeServer"]
+__all__ = ["ServeServer", "StreamWriter"]
 
 #: Cap on how long one POST handler thread blocks awaiting generation.
 DEFAULT_TIMEOUT_S = 300.0
@@ -79,6 +91,361 @@ def _as_float(v) -> float:
     if isinstance(v, bool) or not isinstance(v, (int, float)):
         raise ValueError(f"not a number: {v!r}")
     return float(v)
+
+
+def _ok_stats(req: GenRequest) -> dict:
+    """The completed-request stat block: the blocking 200 body, and
+    (minus ``tokens``, already streamed) the streaming trailer."""
+    return {
+        "id": req.id,
+        "tokens": req.tokens,
+        "trace_id": req.trace_id,
+        "tenant": req.tenant,
+        "finish_reason": req.finish_reason,
+        "prompt_tokens": len(req.prompt),
+        "new_tokens": len(req.tokens),
+        "ttft_s": round(req.ttft_s, 6),
+        "tpot_s": round(req.tpot_s, 6),
+        "e2e_s": round(req.e2e_s, 6),
+        "drafted": req.drafted,
+        "accepted": req.accepted,
+    }
+
+
+def _error_trailer(status: str, req: GenRequest, error: str) -> dict:
+    return {"done": True, "status": status, "id": req.id, "error": error}
+
+
+def _trailer(req: GenRequest) -> dict:
+    """The last line of the stream of a request that reached its end."""
+    if req.status == "ok":
+        trailer = {"done": True, "status": "ok", **_ok_stats(req)}
+        del trailer["tokens"]  # already streamed line by line
+        return trailer
+    if req.deadline_exceeded:
+        # engine-side deadline abandonment is the SAME condition the
+        # writer's own expiry reports (and the blocking path maps to
+        # 504): one status class, not a race
+        return _error_trailer("timeout", req,
+                              req.error or "deadline exceeded")
+    return _error_trailer(req.status, req,
+                          req.error or f"request {req.status}")
+
+
+def _chunk(doc: dict) -> bytes:
+    """One ndjson line as one HTTP/1.1 chunk."""
+    data = (json.dumps(doc) + "\n").encode("utf-8")
+    return b"%X\r\n%b\r\n" % (len(data), data)
+
+
+_LAST_CHUNK = b"0\r\n\r\n"
+
+
+class _Stream:
+    """One streaming request as its writer sees it.  It rides the request
+    (``GenRequest.stream``), so the engine's hand-over finds it without a
+    look-up; but for ``timeout_s``, ``released`` and ``keep`` it is the
+    writer thread's alone."""
+
+    __slots__ = ("timeout_s", "req", "sock", "buf", "flushed", "marks",
+                 "watched", "over", "released", "keep")
+
+    def __init__(self, timeout_s: float):
+        self.timeout_s = timeout_s
+        self.req: GenRequest | None = None
+        self.sock: socket.socket | None = None   # None: not attached (yet)
+        self.buf = bytearray()      # bytes the socket has not taken
+        self.flushed = 0            # bytes that have left ``buf``
+        #: (``flushed`` once the line has left ``buf``, the line's commit
+        #: stamp): the lines in ``buf``, oldest first
+        self.marks: collections.deque = collections.deque()
+        self.watched = False        # in the selector, for writability
+        self.over = False           # takes no further line
+        self.released = threading.Event()   # the handler thread parks here
+        self.keep = False           # the connection may serve another request
+
+
+class StreamWriter:
+    """The one thread that writes every open ``/generatez`` stream.
+
+    The engine calls :meth:`put` once for all the streams an iteration
+    committed tokens for (``Engine.stream_sink``); the thread sleeps in a
+    selector on a socketpair that ``put`` writes a byte to (no polling
+    interval), and on waking formats every line of the batch and sends
+    each to its socket without blocking.  A socket that would block keeps
+    its unsent bytes in its stream's own buffer and is watched for
+    writability: it delays no other stream and never the engine.  The
+    thread also owns each stream's ``timeout_s`` deadline (it wakes at
+    the earliest), writes the trailers and drops a stream whose client
+    went away, while that request keeps running server-side.  A
+    request's handler thread sends the headers, then parks in
+    :meth:`serve` until its stream is over."""
+
+    def __init__(self, engine: Engine, registry):
+        self._note_line = engine.note_stream_line
+        self._inbox: collections.deque = collections.deque()
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._woken = False         # a byte is on its way to ``_wake_r``
+        self._sel = selectors.DefaultSelector()
+        self._sel.register(self._wake_r, selectors.EVENT_READ)
+        self._open: set[_Stream] = set()
+        self._deadlines: list = []  # heap of (deadline, tie-break, stream)
+        self._tie = itertools.count()
+        self._pending = 0           # bytes in the streams' buffers
+        self._lines = self._wakes = self._backlogged = 0
+        self._lock = threading.Lock()   # ``_closed`` against ``serve``
+        self._closed = False
+        self._thread: threading.Thread | None = None
+        self._m_lines = registry.counter(
+            "serve_stream_lines_total",
+            "token lines the stream writer took from the engine")
+        self._m_wakes = registry.counter(
+            "serve_stream_writer_wakes_total",
+            "times the stream writer woke to lines of the engine's: lines "
+            "a wake is about the decoding slots")
+        self._m_backlogged = registry.counter(
+            "serve_stream_backlogged_total",
+            "token lines that met a full socket and waited in their "
+            "stream's buffer")
+        self._m_open = registry.gauge(
+            "serve_streams_open", "streams a client is attached to")
+
+    # -- any thread ----------------------------------------------------------
+
+    def put(self, item) -> None:
+        """``Engine.stream_sink``: a batch of ``(request, tokens,
+        stamp)``.  An append and, if the thread may be asleep, one byte."""
+        if self._closed:
+            return      # nobody reads it any more
+        self._inbox.append(item)
+        if not self._woken:
+            self._woken = True
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:     # full: it wakes anyway
+                pass
+
+    def serve(self, req: GenRequest, sock) -> bool:
+        """A handler thread, with the chunked headers sent on ``sock``:
+        give the connection to the writer and park until the stream is
+        over.  True if the connection may serve another request.  ``sock``
+        None (the headers met a dead client): the stream's lines go
+        nowhere."""
+        st = req.stream
+        st.req = req
+        if sock is None:
+            self.put(("detach", st))
+            return False
+        timeout = sock.gettimeout()
+        sock.setblocking(False)
+        with self._lock:    # not beside ``_run``'s last look at the inbox
+            if self._closed:
+                return False
+            self.put(("attach", st, sock))
+        st.released.wait()
+        sock.settimeout(timeout)
+        return st.keep
+
+    def start(self) -> None:
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._run, name="dtf-serve-streams", daemon=True)
+            self._thread.start()
+
+    def stop(self) -> None:
+        """End every open stream (an ``error`` trailer where none was
+        due yet) and join the thread."""
+        if self._thread is not None:
+            self.put(("stop",))
+            self._thread.join(timeout=5)
+            self._thread = None
+        if not self._closed:    # never started
+            self._closed = True
+            self._close()
+
+    def state(self) -> dict:
+        """``GET /generatez``'s ``streams``."""
+        return {"open": len(self._open), "pending_bytes": self._pending,
+                "lines": self._lines, "wakes": self._wakes,
+                "backlogged": self._backlogged}
+
+    # -- the writer thread ---------------------------------------------------
+
+    def _run(self) -> None:
+        try:
+            while self._turn():
+                pass
+        except Exception:
+            logger.exception("the stream writer died")
+            raise
+        finally:
+            with self._lock:
+                self._closed = True
+            for item in self._inbox:    # handlers that came too late
+                if item[0] == "attach":
+                    item[1].released.set()
+            for st in list(self._open):
+                if not st.over:
+                    self._finish(st, _error_trailer(
+                        "error", st.req, "server stopped"))
+                if not st.released.is_set():    # what is unsent stays so
+                    self._release(st, keep=False)
+            self._close()
+
+    def _close(self) -> None:
+        self._sel.close()
+        self._wake_r.close()
+        self._wake_w.close()
+
+    def _turn(self) -> bool:
+        """Sleep until there is something to do, and do all of it.
+        False once told to stop."""
+        deadlines = self._deadlines
+        while deadlines and deadlines[0][2].over:
+            heapq.heappop(deadlines)
+        timeout = None
+        if deadlines:
+            timeout = max(deadlines[0][0] - time.monotonic(), 0.0)
+        for key, _ in self._sel.select(timeout):
+            if key.data is None:
+                self._wake_r.recv(4096)
+            else:
+                self._flush(key.data)   # a full socket took bytes again
+        # before the inbox is read: a put that finds it set was read
+        self._woken = False
+        inbox, lines0, go_on = self._inbox, self._lines, True
+        while inbox:
+            item = inbox.popleft()
+            if isinstance(item, list):
+                self._batch(item)
+            elif item[0] == "attach":
+                self._attach(item[1], item[2])
+            elif item[0] == "detach":
+                self._lose(item[1])
+            else:
+                go_on = False
+        if self._lines > lines0:
+            self._wakes += 1
+            self._m_wakes.inc()
+            self._m_lines.inc(self._lines - lines0)
+        now = time.monotonic()
+        while deadlines and deadlines[0][0] <= now:
+            st = heapq.heappop(deadlines)[2]
+            if not st.over:
+                self._finish(st, _error_trailer(
+                    "timeout", st.req,
+                    f"generation exceeded timeout_s={st.timeout_s}"))
+        return go_on
+
+    def _batch(self, batch: list) -> None:
+        backlogged0 = self._backlogged
+        for req, tokens, stamp in batch:
+            st = req.stream
+            if not isinstance(st, _Stream):
+                continue    # submitted past the frontend: nobody reads it
+            if tokens is None:
+                if not st.over:
+                    self._finish(st, _trailer(req))
+            elif not st.over:
+                self._lines += 1
+                self._send(st, _chunk({"tokens": tokens}), stamp)
+        if self._backlogged > backlogged0:
+            self._m_backlogged.inc(self._backlogged - backlogged0)
+
+    def _send(self, st: _Stream, data: bytes, stamp: float | None) -> None:
+        """``data`` to the stream's socket, or as much as it takes now
+        and the rest to the stream's buffer; ``stamp`` is a token line's."""
+        sock = st.sock
+        if sock is not None:
+            sent = 0
+            if not st.buf:
+                try:
+                    sent = sock.send(data)
+                except BlockingIOError:
+                    pass
+                except OSError:
+                    return self._lose(st)
+                if sent == len(data):
+                    if stamp is not None:
+                        self._note_line(stamp)
+                    return
+                data = data[sent:]
+                self._watch(st)
+            if stamp is not None:
+                self._backlogged += 1
+        st.buf += data
+        self._pending += len(data)
+        if stamp is not None:
+            st.marks.append((st.flushed + len(st.buf), stamp))
+
+    def _flush(self, st: _Stream) -> None:
+        """Send what the stream's buffer holds, as far as the socket
+        takes it; a stream that is over and has nothing left lets its
+        handler go."""
+        if st.buf:
+            try:
+                sent = st.sock.send(st.buf)
+            except BlockingIOError:
+                sent = 0
+            except OSError:
+                return self._lose(st)
+            del st.buf[:sent]
+            self._pending -= sent
+            st.flushed += sent
+            marks = st.marks
+            while marks and marks[0][0] <= st.flushed:
+                self._note_line(marks.popleft()[1])
+        if st.buf:
+            self._watch(st)
+        elif st.over:
+            self._release(st, keep=True)
+        elif st.watched:
+            st.watched = False
+            self._sel.unregister(st.sock)
+
+    def _watch(self, st: _Stream) -> None:
+        if not st.watched:
+            st.watched = True
+            self._sel.register(st.sock, selectors.EVENT_WRITE, st)
+
+    def _attach(self, st: _Stream, sock) -> None:
+        st.sock = sock
+        self._open.add(st)
+        self._m_open.set(len(self._open))
+        if not st.over:
+            heapq.heappush(self._deadlines, (
+                time.monotonic() + st.timeout_s, next(self._tie), st))
+        self._flush(st)     # the lines committed before the headers went
+
+    def _finish(self, st: _Stream, trailer: dict) -> None:
+        """The trailer and the terminating chunk; the handler goes once
+        the socket has them."""
+        st.over = True
+        self._send(st, _chunk(trailer) + _LAST_CHUNK, None)
+        if st.sock is not None and not st.buf:
+            self._release(st, keep=True)
+
+    def _lose(self, st: _Stream) -> None:
+        """The client went away (or never saw the headers): its lines go
+        nowhere from here on, the request runs on."""
+        st.over = True
+        self._pending -= len(st.buf)
+        st.buf.clear()
+        st.marks.clear()
+        if st.sock is not None:
+            self._release(st, keep=False)
+
+    def _release(self, st: _Stream, keep: bool) -> None:
+        if st.watched:
+            st.watched = False
+            self._sel.unregister(st.sock)
+        st.sock = None
+        self._open.discard(st)
+        self._m_open.set(len(self._open))
+        st.keep = keep
+        st.released.set()
 
 
 class ServeServer:
@@ -104,6 +471,8 @@ class ServeServer:
                 ("GET", "/stepz"): self._stepz,
             },
         )
+        self._streams = StreamWriter(engine, self._srv.registry)
+        engine.stream_sink = self._streams.put
 
     @property
     def port(self) -> int:
@@ -130,7 +499,7 @@ class ServeServer:
     # -- handlers (HTTP threads) ---------------------------------------------
 
     def _get_state(self, query: str):
-        return 200, self.engine.state()
+        return 200, {**self.engine.state(), "streams": self._streams.state()}
 
     def _stepz(self, query: str):
         """``GET /stepz`` — live tail of the engine step log: the newest
@@ -238,7 +607,7 @@ class ServeServer:
             # already gave up.
             req = self.engine.submit(
                 prompt, deadline_s=timeout if timeout > 0 else None,
-                stream=stream, **kwargs,
+                stream=_Stream(timeout) if stream else False, **kwargs,
             )
         except QueueFullError as e:
             return 429, {"error": str(e)}
@@ -247,11 +616,18 @@ class ServeServer:
         except RuntimeError as e:  # dead scheduler loop
             return 503, {"error": str(e)}
         if stream:
-            # Chunked transfer: the StatusServer streams this generator
-            # (obs.server._reply_chunked); submit-time errors above kept
-            # their real statuses — from here on failures ride the
-            # trailer line, since headers are already committed.
-            return 200, self._stream_response(req, timeout)
+            # Chunked transfer: the StatusServer sends the headers and
+            # calls this with the connection (obs.server._reply_stream);
+            # the writer thread sends the lines, this thread parks.
+            # Submit-time errors above kept their real statuses — from
+            # here on failures ride the trailer line, since headers are
+            # already committed.  The engine always terminates requests
+            # (crash/stop included), so the trailer is guaranteed; the
+            # timeout guards the stream the same way ``req.wait(timeout)``
+            # guards the blocking path — on expiry the trailer reports it
+            # and the request keeps running server-side (the engine-side
+            # deadline already abandons requests still QUEUED past it).
+            return 200, functools.partial(self._streams.serve, req)
         if not req.wait(timeout):
             return 504, {"error": f"generation exceeded timeout_s="
                                   f"{timeout}", "id": req.id}
@@ -263,85 +639,22 @@ class ServeServer:
         if req.status != "ok":
             return 500, {"error": req.error or f"request {req.status}",
                          "id": req.id}
-        return 200, self._ok_stats(req)
-
-    @staticmethod
-    def _ok_stats(req: GenRequest) -> dict:
-        """The completed-request stat block: the blocking 200 body, and
-        (minus ``tokens``, already streamed) the streaming trailer."""
-        return {
-            "id": req.id,
-            "tokens": req.tokens,
-            "trace_id": req.trace_id,
-            "tenant": req.tenant,
-            "finish_reason": req.finish_reason,
-            "prompt_tokens": len(req.prompt),
-            "new_tokens": len(req.tokens),
-            "ttft_s": round(req.ttft_s, 6),
-            "tpot_s": round(req.tpot_s, 6),
-            "e2e_s": round(req.e2e_s, 6),
-            "drafted": req.drafted,
-            "accepted": req.accepted,
-        }
-
-    def _stream_response(self, req: GenRequest, timeout: float):
-        """Generator of ndjson lines for one streaming request: token
-        lines as iterations commit, then one trailer with the stats.
-        The engine always terminates requests (crash/stop included), so
-        the ``done`` event is guaranteed; the timeout guards the stream
-        the same way ``req.wait(timeout)`` guards the blocking path —
-        on expiry the trailer reports it and the request keeps running
-        server-side (the engine-side deadline already abandons requests
-        still QUEUED past it)."""
-        deadline = time.monotonic() + timeout
-
-        def gen():
-            while True:
-                remaining = deadline - time.monotonic()
-                try:
-                    event = req._events.get(timeout=max(remaining, 0.0))
-                except queue_mod.Empty:
-                    yield json.dumps({
-                        "done": True, "status": "timeout", "id": req.id,
-                        "error": f"generation exceeded timeout_s={timeout}",
-                    }) + "\n"
-                    return
-                if event[0] != "tokens":
-                    break
-                _, tokens, stamp = event
-                yield json.dumps({"tokens": tokens}) + "\n"
-                # resumed once the line is on the socket
-                # (obs.server._reply_chunked writes what is yielded)
-                self.engine.note_stream_line(stamp)
-            if req.status == "ok":
-                trailer = {"done": True, "status": "ok", **self._ok_stats(req)}
-                del trailer["tokens"]  # already streamed line by line
-            elif req.deadline_exceeded:
-                # engine-side deadline abandonment is the SAME condition
-                # the generator's own expiry reports (and the blocking
-                # path maps to 504): one status class, not a race
-                trailer = {
-                    "done": True, "status": "timeout", "id": req.id,
-                    "error": req.error or "deadline exceeded",
-                }
-            else:
-                trailer = {
-                    "done": True, "status": req.status, "id": req.id,
-                    "error": req.error or f"request {req.status}",
-                }
-            yield json.dumps(trailer) + "\n"
-
-        return gen()
+        return 200, _ok_stats(req)
 
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> "ServeServer":
+        self._streams.start()
         self._srv.start()
         logger.info("serving frontend on port %d (POST /generatez)",
                     self.port)
         return self
 
     def stop(self) -> None:
+        """Ends every open stream, joins the writer, then the server."""
+        if self.engine.stream_sink == self._streams.put:
+            self.engine.stream_sink = None
+        self._streams.stop()
         self._srv.stop()
 
     close = stop

@@ -220,6 +220,8 @@ def _check_commits(eng):
     the list the finishes seen are appended to, an iteration a row."""
     commit, finishes = eng._commit_tokens, []
     tok_hist = eng._m_tok_step
+    handed = []
+    eng.stream_sink = handed.append
 
     def checked(decoding, slots, kept, now, decode_dt, prefill_s, spec):
         assert [i for i, _ in decoding] == slots.tolist()
@@ -230,9 +232,7 @@ def _check_commits(eng):
         hist0 = tok_hist.stats()
         usage0 = {t: acc["new_tokens"]
                   for t, acc in eng.usage._tenants.items()}
-        for _, r in decoding:       # the lines of earlier iterations
-            while r._events is not None and not r._events.empty():
-                r._events.get_nowait()
+        del handed[:]               # the first tokens' lines
         commit(decoding, slots, kept, now, decode_dt, prefill_s, spec)
         n_tokens = sum(len(t) for t in kept)
         assert eng.counters["decode_tokens"] - tokens0 == n_tokens
@@ -246,7 +246,7 @@ def _check_commits(eng):
         for tenant, n in by_tenant.items():
             assert eng.usage._tenants[tenant]["new_tokens"] \
                 - usage0.get(tenant, 0) == n
-        done = []
+        done, lines, ends = [], [], []
         for (slot, req), toks in zip(decoding, kept):
             st = want[req.id]
             assert req.tokens == st["tokens"]
@@ -262,12 +262,13 @@ def _check_commits(eng):
                 done.append(st["finish"])
             else:
                 assert eng._last_tokens[slot] == toks[-1]
-            if req._events is not None:
-                # this iteration's one line, then (finished) the closing one
-                new = [req._events.get_nowait()
-                       for _ in range(req._events.qsize())]
-                assert new[0] == ("tokens", list(toks), now)  # commit's stamp
-                assert new[1:] == ([("done", None)] if finished else [])
+            if req.stream:
+                lines.append((req, list(toks), now))    # commit's stamp
+                if finished:
+                    ends.append((req, None, req.t_done))
+        # one hand-over an iteration for every stream's line, then one
+        # for the ends of the requests that finished in it
+        assert handed == [batch for batch in (lines, ends) if batch]
         finishes.append(done)
 
     eng._commit_tokens = checked
@@ -343,30 +344,44 @@ def test_batched_commit_of_bursts_is_the_slot_at_a_time_commit(families):
                and r["logits_fetched"] == 0 for r in decoded)
 
 
-def test_one_line_an_iteration_is_put_after_the_bookkeeping(families):
-    """A stream's line is handed over once every request of the batch is
-    up to date: whoever the line wakes sees the iteration whole."""
+def test_one_hand_over_an_iteration_after_the_bookkeeping(families):
+    """The streams' lines are handed over in one call an iteration, once
+    every request of the batch is up to date: whoever the call wakes sees
+    the iteration whole."""
     cfg, params, kw = families["gpt"]
     eng = Engine(params, cfg, max_slots=3, **kw)
     reqs = [eng.submit([2, 7, 1, 8], max_new_tokens=5, stream=True)
             for _ in range(3)]
     seen = []
-
-    class Probe(queue.SimpleQueue):
-        def put(self, item, *a, **k):
-            if item[0] == "tokens":
-                seen.append([len(r.tokens) for r in reqs])
-            super().put(item, *a, **k)
-
-    for r in reqs:
-        r._events = Probe()
+    eng.stream_sink = lambda batch: seen.append(
+        ([len(r.tokens) for r in reqs], batch))
     _drain(eng, reqs)
     # 3 first tokens (one request at a time, in prefill), then 4 decode
-    # iterations of 3 lines each: all three requests already hold the
-    # iteration's token when its first line is put
-    assert len(seen) == 3 + 4 * 3
-    for i, lens in enumerate(seen[3:]):
-        assert lens == [2 + i // 3] * 3
+    # iterations of one call each: all three requests already hold the
+    # iteration's token when its lines are handed over; the ends follow
+    # the last iteration's lines in a call of their own
+    assert len(seen) == 3 + 4 + 1
+    for i, (lens, batch) in enumerate(seen[:3]):
+        assert batch == [(reqs[i], [reqs[i].tokens[0]],
+                          reqs[i].t_first_token)]
+    for i, (lens, batch) in enumerate(seen[3:7]):
+        assert lens == [2 + i] * 3
+        assert [(r, t) for r, t, _ in batch] \
+            == [(r, [r.tokens[1 + i]]) for r in reqs]
+        assert len({stamp for _, _, stamp in batch}) == 1
+    assert seen[7] == ([5] * 3, [(r, None, r.t_done) for r in reqs])
+
+
+def test_lines_without_a_sink_are_dropped(families):
+    """``stream=True`` with nobody to read the lines: the requests run to
+    their end and nothing is kept for a reader that never comes."""
+    cfg, params, kw = families["gpt"]
+    eng = Engine(params, cfg, max_slots=2, **kw)
+    reqs = [eng.submit([2, 7, 1, 8], max_new_tokens=4, stream=True)
+            for _ in range(2)]
+    _drain(eng, reqs)
+    assert [len(r.tokens) for r in reqs] == [4, 4]
+    assert eng._stream_out == []
 
 
 # ---------------------------------------------------------------- the cache

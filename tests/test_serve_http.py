@@ -4,6 +4,10 @@ all in-process on CPU (same idiom as test_status_server.py)."""
 
 import dataclasses
 import json
+import os
+import socket
+import struct
+import sys
 import threading
 import time
 import urllib.error
@@ -457,4 +461,362 @@ def test_streaming_timeout_lands_in_trailer(served_model):
         assert "timeout" in lines[-1]["error"]
     finally:
         server.stop()
+        engine.stop(drain=False)
+
+
+# ------------------------------- one writer for all streams (ISSUE 46)
+
+
+def _open_stream(port, payload, *, rcvbuf=None):
+    """POST a streaming request on a raw socket; the reply is left on it."""
+    sock = socket.socket()
+    if rcvbuf is not None:      # before connect: it sizes the window
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    sock.settimeout(60)
+    sock.connect(("127.0.0.1", port))
+    body = json.dumps({"stream": True, **payload}).encode()
+    sock.sendall((f"POST /generatez HTTP/1.1\r\nHost: 127.0.0.1:{port}\r\n"
+                  "Content-Type: application/json\r\n"
+                  f"Content-Length: {len(body)}\r\n\r\n").encode() + body)
+    return sock
+
+
+def _read_stream(sock):
+    """The reply's bytes up to and with the terminating chunk."""
+    raw = b""
+    while not raw.endswith(b"\r\n0\r\n\r\n"):
+        data = sock.recv(1 << 16)
+        assert data, f"closed early after {raw[-80:]!r}"
+        raw += data
+    return raw
+
+
+def _framed(raw):
+    """``(header lines, documents)`` of a chunked ndjson reply, held byte
+    for byte to the framing: a chunk a line, ``{len:X}\\r\\n`` + the line
+    as ``json.dumps`` writes it + ``\\r\\n``, then ``0\\r\\n\\r\\n``."""
+    head, _, body = raw.partition(b"\r\n\r\n")
+    head = head.decode("latin-1").split("\r\n")
+    assert head[0] == "HTTP/1.1 200 OK"
+    assert "Transfer-Encoding: chunked" in head
+    assert "Content-Type: application/x-ndjson" in head
+    assert body.endswith(b"0\r\n\r\n")
+    docs, rest = [], body[:-5]
+    while rest:
+        size, _, rest = rest.partition(b"\r\n")
+        n = int(size, 16)
+        assert size == b"%X" % n and rest[n:n + 2] == b"\r\n"
+        line, rest = rest[:n], rest[n + 2:]
+        assert line.endswith(b"\n") and line.count(b"\n") == 1
+        docs.append(json.loads(line))
+        assert (json.dumps(docs[-1]) + "\n").encode() == line
+    return head, docs
+
+
+def _stream_counts(reg):
+    return {k: reg.counter(f"serve_stream_{k}_total").value()
+            for k in ("lines", "writer_wakes", "backlogged")}
+
+
+def _until(cond, what, seconds=20):
+    end = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < end, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
+def _parked_handlers():
+    """The handler threads inside ``StreamWriter.serve``, by whether they
+    are parked on their stream's event."""
+    parked = running = 0
+    for frame in sys._current_frames().values():
+        inner, names = frame, []
+        while frame is not None:
+            names.append(frame.f_code.co_name)
+            frame = frame.f_back
+        if "serve" in names and "_reply_stream" in names:
+            if inner.f_code.co_filename.endswith("threading.py") \
+                    and names[:2] == ["wait", "wait"]:
+                parked += 1
+            else:
+                running += 1
+    return parked, running
+
+
+def test_sixteen_streams_one_writer_wake_an_iteration(served_model):
+    """16 concurrent streams of different lengths, the engine driven from
+    here an iteration at a time: every client gets one line a committed
+    iteration, its trailer and the terminating chunk in today's framing;
+    the writer wakes once for all the lines of an iteration, and no
+    thread but it runs for a line (the handlers are parked)."""
+    cfg, params, prompt = served_model
+    reg = Registry()
+    engine = Engine(params, cfg, max_slots=8, max_queue=16, block_size=4,
+                    prefill_chunk=4, max_context=64, registry=reg)
+    want = [engine.submit(prompt[: 3 + i % 5], max_new_tokens=4 + i)
+            for i in range(16)]
+    while not all(r._done.is_set() for r in want):
+        engine.step()
+    steps0, threads0 = engine.steps_total, threading.active_count()
+    ok0 = engine.counters["ok"]
+    server = ServeServer(engine, 0, registry=reg).start()
+    try:
+        socks = []
+        for i in range(16):     # one at a time: the queue's order is i's
+            socks.append(_open_stream(server.port, {
+                "prompt": prompt[: 3 + i % 5], "max_new_tokens": 4 + i}))
+            _until(lambda: engine.state()["queue_depth"] == i + 1, "submit")
+        _until(lambda: _parked_handlers() == (16, 0), "the handlers to park")
+        total = sum(4 + i for i in range(16))
+        while engine.step():
+            # the server's thread, the writer and 16 handlers: no more, and
+            # the handler of every request still running is parked (one
+            # whose request ended runs once more, to leave)
+            assert threading.active_count() <= threads0 + 18
+            assert _parked_handlers()[0] >= 16 - (engine.counters["ok"] - ok0)
+        _until(lambda: _stream_counts(reg)["lines"] == total, "the writer")
+        counts = _stream_counts(reg)
+        assert counts["writer_wakes"] <= (engine.steps_total - steps0) + 16
+        decode_iterations = sum(
+            r["occupancy"] > 0 for r in engine.step_records()
+            if r["step"] > steps0)
+        # (a wake a first token, a wake a decode iteration: never a line)
+        assert counts["writer_wakes"] <= decode_iterations + 16
+        assert counts["lines"] > 4 * decode_iterations
+        for i, sock in enumerate(socks):
+            _, docs = _framed(_read_stream(sock))
+            assert [d["tokens"] for d in docs[:-1]] \
+                == [[t] for t in want[i].tokens]
+            assert docs[-1]["done"] is True and docs[-1]["status"] == "ok"
+            assert docs[-1]["new_tokens"] == 4 + i
+            assert list(docs[-1])[:3] == ["done", "status", "id"]
+            sock.close()
+        _until(lambda: threading.active_count() <= threads0 + 2,
+               "the handlers to leave")
+        st = json.loads(_get(server.port, "/generatez")[1])["streams"]
+        assert st == {"open": 0, "pending_bytes": 0,
+                      "lines": counts["lines"],
+                      "wakes": counts["writer_wakes"], "backlogged": 0}
+        assert reg.gauge("serve_streams_open").value() == 0
+        rows = [r for r in engine.step_records() if r["step"] > steps0]
+        assert sum(r["stream_lines"] for r in rows) <= counts["lines"]
+    finally:
+        server.stop()
+        engine.stop(drain=False)
+
+
+def test_a_client_that_stops_reading_delays_nobody():
+    """Two long streams, one client reading and one not (small buffers on
+    both sides of its connection): the reader gets every line, the engine
+    finishes both requests, the stalled stream's lines wait in its own
+    buffer — and are all there, in order, when its client reads at last."""
+    cfg = dataclasses.replace(gpt_tiny(), dtype=jnp.float32, max_seq=512)
+    rng = jax.random.PRNGKey(0)
+    ids = jax.random.randint(rng, (1, 8), 0, cfg.vocab_size)
+    params = GPTLM(cfg).init(rng, ids)["params"]
+    prompt = [int(t) for t in np.asarray(ids)[0]]
+    reg = Registry()
+    engine = Engine(params, cfg, max_slots=2, max_queue=4, block_size=16,
+                    prefill_chunk=8, max_context=512, registry=reg)
+    server = ServeServer(engine, 0, registry=reg)
+    # accepted connections inherit it: a send buffer of the kernel's least
+    server.status_server._httpd.socket.setsockopt(
+        socket.SOL_SOCKET, socket.SO_SNDBUF, 1)
+    server.start()
+    n = 480
+    try:
+        stalled = _open_stream(server.port, {
+            "prompt": prompt, "max_new_tokens": n}, rcvbuf=1)
+        reader = _open_stream(server.port, {
+            "prompt": prompt[:5], "max_new_tokens": n})
+        _until(lambda: engine.state()["queue_depth"] == 2, "both submits")
+        engine.start()
+        _, docs = _framed(_read_stream(reader))
+        assert len(docs) == n + 1 and docs[-1]["status"] == "ok"
+        # the engine went on too: both requests are done server-side
+        _until(lambda: engine.counters["ok"] == 2, "the stalled request")
+        _until(lambda: server._streams.state()["lines"] == 2 * n
+               and server._streams.state()["open"] == 1, "the writer")
+        st = server._streams.state()
+        assert st["pending_bytes"] > 0 and st["backlogged"] > 0
+        assert _stream_counts(reg)["backlogged"] == st["backlogged"]
+        assert reg.gauge("serve_streams_open").value() == 1
+        # the reader's lines did not wait for the stalled stream's
+        lag = reg.histogram("serve_stream_lag_seconds", "").stats()
+        assert lag["count"] < 2 * n
+        _, docs = _framed(_read_stream(stalled))
+        assert len(docs) == n + 1 and docs[-1]["new_tokens"] == n
+        assert all(len(d["tokens"]) == 1 for d in docs[:-1])
+        _until(lambda: server._streams.state()["open"] == 0, "the release")
+        assert server._streams.state()["pending_bytes"] == 0
+        lag = reg.histogram("serve_stream_lag_seconds", "").stats()
+        assert lag["count"] == 2 * n    # each line once its bytes had gone
+        reader.close()
+        stalled.close()
+    finally:
+        server.stop()
+        engine.stop(drain=False)
+
+
+def test_a_client_that_disconnects_is_dropped(served_model):
+    """One of three clients resets its connection mid-stream: the writer
+    drops it, its request finishes server-side, the other streams are
+    whole, no stream stays open and no descriptor is left behind."""
+    cfg, params, prompt = served_model
+    reg = Registry()
+    engine = Engine(params, cfg, max_slots=3, max_queue=4, block_size=4,
+                    prefill_chunk=4, max_context=64, registry=reg)
+    server = ServeServer(engine, 0, registry=reg).start()
+    fds0 = len(os.listdir("/proc/self/fd"))
+    try:
+        socks = [_open_stream(server.port, {
+            "prompt": prompt[: 4 + i], "max_new_tokens": 30})
+            for i in range(3)]
+        _until(lambda: engine.state()["queue_depth"] == 3, "the submits")
+        for _ in range(8):
+            engine.step()
+        _until(lambda: server._streams.state()["open"] == 3, "three streams")
+        # SO_LINGER 0: close() sends a reset, the next send fails
+        socks[0].setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                            struct.pack("ii", 1, 0))
+        socks[0].close()
+        while engine.step():
+            time.sleep(0.002)   # the reset is back before the next line
+        assert engine.counters["ok"] == 3 and engine.counters["error"] == 0
+        for sock in socks[1:]:
+            _, docs = _framed(_read_stream(sock))
+            assert len(docs) == 31 and docs[-1]["status"] == "ok"
+            sock.close()
+        _until(lambda: server._streams.state()["open"] == 0, "the drop")
+        st = server._streams.state()
+        assert st["pending_bytes"] == 0 and st["lines"] < 90
+        assert reg.gauge("serve_streams_open").value() == 0
+        _until(lambda: len(os.listdir("/proc/self/fd")) <= fds0,
+               "the descriptors to close")
+    finally:
+        server.stop()
+        engine.stop(drain=False)
+
+
+def test_timeout_trailer_is_the_writers(served_model):
+    """``timeout_s`` runs out with the engine standing still after two
+    iterations: the writer wakes at the deadline and writes today's
+    trailer; the request's later lines go nowhere, and the connection
+    serves the next request."""
+    cfg, params, prompt = served_model
+    engine = Engine(params, cfg, max_slots=1, max_queue=8, block_size=4,
+                    prefill_chunk=4, max_context=64)
+    engine.submit(prompt[:4], max_new_tokens=3)     # compiles the programs
+    while engine.step():
+        pass
+    server = ServeServer(engine, 0).start()
+    try:
+        t0 = time.monotonic()
+        sock = _open_stream(server.port, {
+            "prompt": prompt[:4], "max_new_tokens": 6, "timeout_s": 0.3})
+        _until(lambda: engine.state()["queue_depth"] == 1, "the submit")
+        engine.step()       # the prompt's one chunk: two tokens, two lines
+        engine.step()
+        _, docs = _framed(_read_stream(sock))
+        assert 0.3 <= time.monotonic() - t0 < 5
+        assert [list(d) for d in docs[:-1]] == [["tokens"]] * 3
+        assert docs[-1] == {"done": True, "status": "timeout", "id": "r1",
+                            "error": "generation exceeded timeout_s=0.3"}
+        while engine.step():
+            pass
+        assert engine.counters["ok"] == 2       # it ran on server-side
+        st = server._streams.state()
+        assert st["open"] == 0 and st["pending_bytes"] == 0
+        assert st["lines"] == 3
+        sock.sendall(b"GET /healthz HTTP/1.1\r\nHost: x\r\n\r\n")
+        assert sock.recv(1 << 16).startswith(b"HTTP/1.1 200 OK")
+        sock.close()
+    finally:
+        server.stop()
+        engine.stop(drain=False)
+
+
+def test_drain_lets_streams_finish_and_stop_ends_them(served_model):
+    """A stream open at ``begin_drain()`` runs to its trailer while new
+    submits get 503; ``stop()`` ends the stream still open with an error
+    trailer, lets its handler go and joins the writer."""
+    cfg, params, prompt = served_model
+    engine = Engine(params, cfg, max_slots=2, max_queue=8, block_size=4,
+                    prefill_chunk=4, max_context=64)
+    server = ServeServer(engine, 0).start()
+    threads0 = threading.active_count()
+    try:
+        short = _open_stream(server.port, {
+            "prompt": prompt, "max_new_tokens": 3})
+        long = _open_stream(server.port, {
+            "prompt": prompt[:5], "max_new_tokens": 50})
+        _until(lambda: engine.state()["queue_depth"] == 2, "the submits")
+        server.begin_drain()
+        status, body = _post(server.port, "/generatez",
+                             {"prompt": prompt, "max_new_tokens": 2,
+                              "stream": True})
+        assert status == 503 and "draining" in body["error"]
+        for _ in range(12):
+            engine.step()
+        _, docs = _framed(_read_stream(short))
+        assert len(docs) == 4 and docs[-1]["status"] == "ok"
+        long_id = engine.state()["slots"][1]["id"]
+        _until(lambda: server._streams.state()["open"] == 1, "one stream")
+        server.stop()
+        _, docs = _framed(_read_stream(long))
+        assert docs[-1] == {"done": True, "status": "error", "id": long_id,
+                            "error": "server stopped"}
+        assert 1 < len(docs) < 51
+        assert all(t.name != "dtf-serve-streams"
+                   for t in threading.enumerate())
+        short.close()       # its handler was waiting for a next request
+        long.close()
+        _until(lambda: threading.active_count() <= threads0 - 2,
+               "the handlers to leave")
+        assert engine.stream_sink is None
+    finally:
+        server.stop()
+        engine.stop(drain=False)
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_dead_engine_ends_every_stream_with_the_error_trailer(served_model):
+    """The engine's loop dies mid-stream (``test_dead_engine_loop_visible_
+    and_503``'s sibling): every open stream, decoding or still queued,
+    ends with the ``error`` trailer and the terminating chunk."""
+    cfg, params, prompt = served_model
+    engine = Engine(params, cfg, max_slots=2, max_queue=8, block_size=4,
+                    prefill_chunk=4, max_context=64)
+    server = ServeServer(engine, 0).start()
+    decode, calls = engine._run_decode_step, []
+
+    def dying(prefill_s):
+        calls.append(prefill_s)
+        if len(calls) == 5:
+            raise RuntimeError("XLA exploded (simulated)")
+        decode(prefill_s)
+
+    engine._run_decode_step = dying
+    try:
+        socks = [_open_stream(server.port, {
+            "prompt": prompt[: 4 + i], "max_new_tokens": 40})
+            for i in range(3)]
+        _until(lambda: engine.state()["queue_depth"] == 3, "the submits")
+        engine.start()
+        lines = []
+        for sock in socks:
+            _, docs = _framed(_read_stream(sock))
+            assert docs[-1]["done"] is True
+            assert docs[-1]["status"] == "error"
+            assert "XLA exploded" in docs[-1]["error"]
+            assert list(docs[-1]) == ["done", "status", "id", "error"]
+            lines.append(len(docs) - 1)
+            sock.close()
+        assert sorted(lines)[0] == 0 and 0 < sorted(lines)[-1] <= 5
+        assert _get(server.port, "/healthz")[0] == 503
+        _until(lambda: server._streams.state()["open"] == 0, "the release")
+    finally:
+        server.stop()
+        engine._thread = None   # it died: nothing to join
         engine.stop(drain=False)

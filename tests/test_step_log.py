@@ -705,22 +705,22 @@ def test_one_stalled_iteration_leaves_one_engine_stall_row(served_model,
 
 def test_stream_lag_reaches_the_step_log_and_the_registry(served_model,
                                                           monkeypatch):
-    """A handler whose write takes 10 ms: the stream thread measures the
-    lag of a line once the write has returned, the registry histogram and
-    the next step record hold it."""
+    """A writer that takes 10 ms to put a line on its socket: the lag of
+    a line is measured once its bytes have been handed to the socket, the
+    registry histogram and the next step record hold it."""
     from distributedtensorflow_tpu.obs import registry as obs_registry
+    from distributedtensorflow_tpu.serve import server as server_mod
 
     cfg, params, _ = served_model
     reg = obs_registry.Registry()
     eng = _engine(cfg, params, registry=reg)
-    stream = ServeServer._stream_response
+    chunk = server_mod._chunk
 
-    def slow_write(self, req, timeout):
-        for line in stream(self, req, timeout):
-            yield line              # the handler writes it ...
-            time.sleep(0.01)        # ... and that took 10 ms more
+    def slow_chunk(doc):
+        time.sleep(0.01)            # the writer is 10 ms late with the line
+        return chunk(doc)
 
-    monkeypatch.setattr(ServeServer, "_stream_response", slow_write)
+    monkeypatch.setattr(server_mod, "_chunk", slow_chunk)
     with ServeServer(eng, port=0, registry=reg) as srv, eng:
         body = json.dumps({"prompt": [5, 6, 7], "max_new_tokens": 6,
                            "stream": True}).encode()

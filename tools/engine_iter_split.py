@@ -12,10 +12,12 @@ is a direction, not a size.  This takes the size: ``--slots`` requests
 and the means of the step ring's seconds are printed (``step_ms``: the
 leaves of the iteration, the engine thread's CPU beside its wall, the
 streams' lag, as ``steps.jsonl`` has them; a tree from before ISSUE 36
-has four of them).  With ``--streams`` every request is a stream with a
-consumer thread doing what ``serve/server.py``'s generator does (``get``,
-``json.dumps``, a write, the lag): the interpreter lock's part shows as
-the difference, in ``iter_ms`` and in ``offcpu_s``.  ``--sample N`` gives N
+has four of them).  With ``--streams`` every request is a stream, and one
+consumer thread does for all of them what ``serve/server.py``'s writer does
+(a ``get`` an iteration; ``json.dumps``, a write and the lag a line) — in a
+tree from before ISSUE 46, a consumer thread a stream, as its frontend had:
+the interpreter lock's part shows as the difference, in ``iter_ms`` and in
+``offcpu_s``.  ``--sample N`` gives N
 of the requests ``temperature`` 0.8 (the
 iteration then fetches the logits).  ``--profile`` runs the engine thread
 under ``cProfile`` — inflated like a traced run: read the order, not the
@@ -50,6 +52,7 @@ import io
 import json
 import os
 import pstats
+import queue
 import statistics
 import sys
 import tempfile
@@ -153,22 +156,36 @@ def main(argv=None) -> int:
             if args.beside else None)
         rng = np.random.default_rng(5)
 
-        def consume(req):
+        def line(tokens, stamp=None):
+            sink.write(json.dumps({"tokens": tokens}) + "\n")
+            sink.flush()
+            if stamp is not None:
+                eng.note_stream_line(stamp)
+
+        def consume_all(batches):
+            while True:
+                for _, tokens, stamp in batches.get():
+                    if tokens is not None:
+                        line(tokens, stamp)
+
+        def consume(req):       # a tree from before ISSUE 46
             while True:
                 kind, payload, *stamp = req._events.get()
                 if kind != "tokens":
                     return
-                sink.write(json.dumps({"tokens": payload}) + "\n")
-                sink.flush()
-                if stamp:
-                    eng.note_stream_line(stamp[0])
+                line(payload, *stamp)
 
         reqs = [eng.submit(
             rng.integers(0, cfg.vocab_size, args.prompt).tolist(),
             max_new_tokens=args.tokens, stream=args.streams,
             temperature=0.8 if i < args.sample else 0.0, seed=i)
             for i in range(args.slots)]
-        if args.streams:
+        if args.streams and hasattr(eng, "stream_sink"):
+            batches = queue.SimpleQueue()
+            eng.stream_sink = batches.put
+            threading.Thread(target=consume_all, args=(batches,),
+                             daemon=True).start()
+        elif args.streams:
             for r in reqs:
                 threading.Thread(target=consume, args=(r,),
                                  daemon=True).start()
