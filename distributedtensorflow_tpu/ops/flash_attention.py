@@ -1646,22 +1646,49 @@ def _dot(x, y):
 
 
 def _tile_sender(dqkv_ref):
-    """``send(value, stage, third, rows, sem)`` for this grid step: start
-    the copy of a finished (rows, tile) gradient block into lane tile ``p``
-    of ``third`` (0 q, 1 k, 2 v) of d``qkv`` in HBM, through the VMEM block
-    ``stage``; the caller waits.  d``qkv`` is one array, the projection's
-    cotangent as its backward takes it: three outputs would have to be
-    concatenated, a pass over all of it.  (The grid position is read here,
-    at the kernel's top level, which is where interpret mode has it.)"""
-    b, p, tiles = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    """``send(value, stage, third, block, sem)`` for this grid step: copy a
+    finished (rows, tile) gradient block into rows ``block * rows`` of lane
+    tile ``p`` of ``third`` (0 q, 1 k, 2 v) of d``qkv`` in HBM, through the
+    VMEM block ``stage``.  d``qkv`` is one array, the projection's cotangent
+    as its backward takes it: three outputs would have to be concatenated, a
+    pass over all of it.
 
-    def send(value, stage, third, rows, sem):
-        tile = stage.shape[-1]
-        stage[:, :] = value.astype(stage.dtype)
+    **A copy is waited for where its staging block is next needed, not where
+    it was started**: ``send`` first waits for the copy that last left
+    ``stage`` (on ``sem``, which only that stage's copies signal), then
+    stages and starts its own, and returns with it in flight.  The rows land
+    256 bytes at a stride of the projection's width, with the MXU idle where
+    the step waits for them; the next flush of the same output is a grid
+    step of products later at least, so the copy runs under them (the
+    backward alone 6.12 -> 5.48 ms a layer at GPT-2 medium's shapes, 5.41
+    with no copy at all; a second staging block an output, swapped by the
+    flush's parity, reads 5.49: my chip runs, PR 47, PERF.md section 6).
+    One was started unless this is the output's first flush of the grid:
+    sequence 0, lane tile 0, ``block`` 0 — every kernel flushes an output
+    once a block, in the order of the blocks.  **The grid's last step waits
+    for its own copies too**: each output's last block is flushed there,
+    nothing runs after it, and the projection's backward reads d``qkv`` as
+    soon as the kernel returns.  The carry is sound only while the grid's
+    steps run in order on one core (``dimension_semantics`` all
+    ``arbitrary`` in :func:`_tiles_backward`).  (The grid position is read
+    here, at the kernel's top level, which is where interpret mode has
+    it.)"""
+    ids = [pl.program_id(axis) for axis in range(4)]
+    b, p, tiles = ids[0], ids[1], pl.num_programs(1)
+    first_tile = (b == 0) & (p == 0)
+    last_step = functools.reduce(
+        jnp.logical_and,
+        [i == pl.num_programs(axis) - 1 for axis, i in enumerate(ids)])
+
+    def send(value, stage, third, block, sem):
+        rows, tile = stage.shape
         lanes = pl.ds(pl.multiple_of((third * tiles + p) * tile, tile), tile)
-        copy = pltpu.make_async_copy(stage, dqkv_ref.at[b, rows, lanes], sem)
+        copy = pltpu.make_async_copy(
+            stage, dqkv_ref.at[b, pl.ds(block * rows, rows), lanes], sem)
+        pl.when(jnp.logical_not(first_tile & (block == 0)))(copy.wait)
+        stage[:, :] = value.astype(stage.dtype)
         copy.start()
-        return copy
+        pl.when(last_step)(copy.wait)
 
     return send
 
@@ -1741,17 +1768,14 @@ def _bwd_tiles_fused_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
 
     @pl.when(j == n_k - 1)
     def _flush_dq():
-        send(_unrotate(dq_all_scr[row], cq_ref, sq_ref, depth), q_out, 0,
-             row, sems.at[0]).wait()
+        send(_unrotate(dq_all_scr[row], cq_ref, sq_ref, depth), q_out, 0, i,
+             sems.at[0])
 
     @pl.when(i == n_q - 1)
     def _flush_dkv():
-        rows = pl.ds(j * block_k, block_k)
-        dk = send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1,
-                  rows, sems.at[1])
-        dv = send(dv_scr[:, :], v_out, 2, rows, sems.at[2])
-        dk.wait()
-        dv.wait()
+        send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1, j,
+             sems.at[1])
+        send(dv_scr[:, :], v_out, 2, j, sems.at[2])
 
 
 def _bwd_tiles_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
@@ -1795,8 +1819,8 @@ def _bwd_tiles_dq_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
 
     @pl.when(kj == n_k - 1)
     def _finalize():
-        send(_unrotate(dq_scr[:, :], cq_ref, sq_ref, depth), q_out, 0,
-             pl.ds(qi * block_q, block_q), sems.at[0]).wait()
+        send(_unrotate(dq_scr[:, :], cq_ref, sq_ref, depth), q_out, 0, qi,
+             sems.at[0])
 
 
 def _bwd_tiles_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
@@ -1844,12 +1868,9 @@ def _bwd_tiles_dkv_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
 
     @pl.when(qi == n_q - 1)
     def _finalize():
-        rows = pl.ds(kj * block_k, block_k)
-        dk = send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1,
-                  rows, sems.at[0])
-        dv = send(dv_scr[:, :], v_out, 2, rows, sems.at[1])
-        dk.wait()
-        dv.wait()
+        send(_unrotate(dk_scr[:, :], ck_ref, sk_ref, depth), k_out, 1, kj,
+             sems.at[0])
+        send(dv_scr[:, :], v_out, 2, kj, sems.at[1])
 
 
 def _tile_specs(tiles, hp, tile, block_q, block_k, mem, *, swap_grid=False):
@@ -1917,6 +1938,14 @@ def _tiles_rope(rope, depth):
     return (cos * scale, sin * scale, cos, sin), 1.0
 
 
+def _tiles_block_memory(interpret):
+    """Where the tile kernels' blocks live: VMEM on the chip and under the
+    TPU interpreter (``interpret`` a ``pltpu.InterpretParams``, which models
+    the memory spaces and performs a copy at its wait), anywhere under the
+    plain one (``True``)."""
+    return pl.ANY if interpret is True else pltpu.VMEM
+
+
 def _tiles_geometry(qkv, heads):
     batch, seq, width = qkv.shape
     depth = width // (3 * heads)
@@ -1936,7 +1965,7 @@ def _tiles_forward(qkv, rope, mask, segment_ids, *, heads, causal, interpret,
     site of a bare ``pallas_call`` is lowered again (72 a step of GPT-2
     medium; the body of two heads a tile is the longer one)."""
     batch, seq, depth, hp, tiles, tile = _tiles_geometry(qkv, heads)
-    mem = pl.ANY if interpret else pltpu.VMEM
+    mem = _tiles_block_memory(interpret)
     specs = _tile_specs(tiles, hp, tile, block_q, block_k, mem)
     rope, scale = _tiles_rope(rope, depth)
     extra_specs, extra_args, extra_names = (
@@ -1990,9 +2019,14 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
     kernels form delta = rowsum(dO * O) themselves, from the o tile, and
     copy each finished gradient block into its place in the one d``qkv``
     (an output left in HBM): XLA runs nothing over an activation here.
-    Jitted for the reason :func:`_tiles_forward` is."""
+    A block's copy is in flight while the next grid steps run and is waited
+    for when its staging block (one an output) is written again; the grid's
+    last step waits for what it started, so d``qkv`` is whole when the call
+    returns (:func:`_tile_sender`).  That carry of a semaphore from step to
+    step needs the steps in order on one core: every grid dimension is
+    ``arbitrary``.  Jitted for the reason :func:`_tiles_forward` is."""
     batch, seq, depth, hp, tiles, tile = _tiles_geometry(qkv, heads)
-    mem = pl.ANY if interpret else pltpu.VMEM
+    mem = _tiles_block_memory(interpret)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     rope, scale = _tiles_rope(rope, depth)
     kw = dict(depth=depth, scale=scale, block_q=block_q, block_k=block_k,
@@ -2026,6 +2060,7 @@ def _tiles_backward(qkv, rope, mask, segment_ids, o, lse, g, *, heads,
             scratch_shapes=scratch,
             input_output_aliases={6: 0} if done else {},
             compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 4,
                 vmem_limit_bytes=TILES_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(qkv, qkv, qkv, g, o, lse, *done, *extra_args)

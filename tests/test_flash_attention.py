@@ -891,3 +891,73 @@ def test_causal_tile_is_chosen_by_what_the_call_shows():
     assert fa.causal_share(512, 256) == 0.75
     assert fa.qkv_causal_tile(64, 1024, 16, 64, jnp.bfloat16) == (256, 0.625)
     assert fa.qkv_causal_tile(8, 128, 4, 64, jnp.float32) == (None, None)
+
+
+# --- the backward's copies in flight (the TPU interpreter) ------------------
+
+_IN_FLIGHT_PAD = np.arange(128)[None, :] < np.array([[100], [128], [57]])
+
+IN_FLIGHT_CASES = [
+    # depth, heads (two lane tiles), backward, blocks, causal, sub-tile,
+    # window, padding mask
+    (64, 4, "pallas", None, True, 32, None, False),
+    (64, 4, "pallas", (32, 64), True, 0, None, False),
+    (64, 4, "pallas", (64, 32), False, 0, None, False),
+    (64, 4, "pallas", None, False, 0, None, True),
+    (128, 2, "pallas", (64, 64), True, 32, 40, False),
+    (128, 2, "pallas", (32, 64), True, 0, 33, True),
+    (64, 4, "pallas_split", None, True, 0, None, False),
+    (64, 4, "pallas_split", (32, 64), True, 0, None, True),
+    (128, 2, "pallas_split", (64, 32), False, 0, None, False),
+    (128, 2, "pallas_split", (64, 32), True, 0, 70, False),
+]
+
+
+@pytest.mark.parametrize(
+    "d,h,backward,blocks,causal,tile,window,pad", IN_FLIGHT_CASES,
+    ids=[f"d{c[0]}-{c[2]}-{'x'.join(map(str, c[3])) if c[3] else 'oneblock'}"
+         f"-{'causal' if c[4] else 'full'}-t{c[5]}-w{c[6]}-"
+         f"{'mask' if c[7] else 'nomask'}" for c in IN_FLIGHT_CASES])
+def test_backward_copies_in_flight_land_before_the_kernel_returns(
+        d, h, backward, blocks, causal, tile, window, pad, monkeypatch):
+    """The tile backward leaves a finished block's copy into d``qkv`` in
+    flight and waits for it where the staging block is written again; the
+    grid's last step waits for what is left (``_tile_sender``).  The plain
+    interpreter finishes a copy at its start and cannot tell a kernel that
+    waits from one that never does.  The TPU interpreter performs a copy
+    at its wait, fills memory nobody wrote with NaN and follows reads and
+    writes for races: a block that was never waited for is NaN in d``qkv``,
+    one re-staged under its copy lands in the wrong place.  Three
+    sequences of two lane tiles: the carry crosses a tile and a sequence,
+    and nothing but the last step's wait lands the last blocks.  o and
+    d``qkv`` are the plain interpreter's bit for bit."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    import distributedtensorflow_tpu.ops.flash_attention as fa
+
+    monkeypatch.setattr(fa, "CAUSAL_TILE", tile)
+    bq, bk = blocks or (128, 128)
+    assert fa.causal_tile(bq, bk, causal) == (tile or None)
+    qkv, _, tabs = _fused_case(d, h, b=3)
+    mask = jnp.asarray(_IN_FLIGHT_PAD) if pad else None
+    g = jax.random.normal(jax.random.PRNGKey(9), (3, 128, h * d))
+    if pad:
+        g = g * mask[:, :, None]
+
+    def o_and_dqkv(interpret):
+        o, vjp = jax.vjp(lambda x: fa.flash_attention_qkv(
+            x, h, rope=tabs, mask=mask, causal=causal, window=window,
+            interpret=interpret, backward_impl=backward, block_q=bq,
+            block_k=bk), qkv)
+        return np.asarray(o), np.asarray(vjp(g)[0])
+
+    o, dqkv = o_and_dqkv(pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True,
+        uninitialized_memory="nan"))
+    races = interpret_pallas_call.races   # the last kernel's: the backward
+    assert races is None or not races.races_found
+    assert not np.isnan(dqkv).any() and not np.isnan(o).any()
+    o_plain, dqkv_plain = o_and_dqkv(True)
+    np.testing.assert_array_equal(o, o_plain)
+    np.testing.assert_array_equal(dqkv, dqkv_plain)
