@@ -40,6 +40,11 @@ from .lfm2 import (  # noqa: F401
     lfm2_24b_a2b,
     lfm2_tiny,
 )
+from .evabyte import (  # noqa: F401
+    EvaByteConfig,
+    evabyte_6_5b,
+    evabyte_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

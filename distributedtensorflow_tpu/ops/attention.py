@@ -459,18 +459,22 @@ def paged_chunk_attention(
 
 def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
                          v_hbm, *refs, block_size, n_steps, scale,
-                         rest_at=None):
+                         rest_at=None, with_lse=False):
     """``rest_at``: where a K head is wider than its tile (:func:`lay_heads`),
     the lane at which the heads' remainders start in the K row, two of 64 a
     tile; a head's scores are then two products, one over its whole tile and
     one over its remainder's (``q_ref`` holds the two query tiles of a head
     one after the other).  With a sink, ``refs`` starts with its (tiles,
-    query rows, 128) float32 block."""
+    query rows, 128) float32 block.  ``with_lse``: a second output, the log
+    of each row's denominator across its lanes (a walk over no key leaves
+    it under ``NEG_INF``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    sink_ref = refs[0] if len(refs) == 8 else None
-    o_ref, kbuf, vbuf, sem, m_sc, l_sc, acc_sc = refs[-7:]
+    lse_ref = refs[-7] if with_lse else None
+    sink_ref = refs[0] if len(refs) == 8 + with_lse else None
+    o_ref = refs[-7 - with_lse]
+    kbuf, vbuf, sem, m_sc, l_sc, acc_sc = refs[-6:]
     s, c = pl.program_id(0), pl.program_id(1)
     rows = PAGED_ROWS
     step_rows = rows * PAGED_STRETCHES
@@ -564,6 +568,8 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
             # the key without a value: one more term of the denominator
             l = l + jnp.exp(sink_ref[...] - m_sc[...])
         o_ref[0] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0] = m_sc[...] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
@@ -598,11 +604,18 @@ def paged_window_decode_attention(
     impl: str = "auto",
     interpret: bool | None = None,
     sink: jax.Array | None = None,   # (H,) a key without a value a head
+    lo: jax.Array | None = None,     # (B,) the first row a slot attends
+    with_lse: bool = False,
 ) -> jax.Array:
     """Single-token decode attention that reads only what each slot
     attends: the blocks holding rows ``[max(len - window, 0), len)``, so
     ``window`` + one block at most on a window layer and the slot's resident
-    blocks on a full one.
+    blocks on a full one.  ``lo`` names each slot's first attended row
+    itself (a tumbling window's start: ``window`` then only bounds the
+    walk); ``with_lse`` returns ``(o, lse)``, the output in float32 and the
+    log of each head's denominator (B, H), for a caller that merges several
+    walks under one softmax (:func:`merge_softmax_parts`) — both are the
+    kernel's alone.
 
     The kernel (``name="paged_attn"``) has the page tables and lengths
     prefetched into SMEM and the pools left in HBM; grid ``(slot, step)``,
@@ -637,6 +650,8 @@ def paged_window_decode_attention(
                                 dv) == "plain":
         # the plain formulation (gathers every table column): the tests'
         # yardstick for the kernel and the path off the TPU
+        if lo is not None or with_lse:
+            raise ValueError("lo= and with_lse= are the paged_attn kernel's")
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, attend_lens, layer=layer,
             block_size=block_size, window=window, sink=sink)
@@ -646,8 +661,9 @@ def paged_window_decode_attention(
     # a window starts anywhere inside its first step
     span = cap if window is None else min(cap, window + step_rows - 1)
     lens = attend_lens.astype(jnp.int32)
-    lo = (jnp.zeros_like(lens) if window is None
-          else jnp.maximum(lens - window, 0))
+    if lo is None:
+        lo = (jnp.zeros_like(lens) if window is None
+              else jnp.maximum(lens - window, 0))
     per_tile = max(LANES // d, 1)     # K/V heads a tile: 1, or 2 at D = 64
     tiles = v_pool.shape[-1] // LANES
     q_rows = -(-per_tile * g // 8) * 8
@@ -680,17 +696,27 @@ def paged_window_decode_attention(
         block_tables.astype(jnp.int32), lens, lo,
         jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool, sink,
         block_size=block_size, n_steps=-(-span // step_rows),
-        scale=d ** -0.5, interpret=interpret, rest_at=rest_at)
+        scale=d ** -0.5, interpret=interpret, rest_at=rest_at,
+        with_lse=with_lse)
+    lse = None
+    if with_lse:
+        # a row's log-denominator lies across its lanes: one lane of it
+        out, lse = out
+        lse = lse[:, :, :per_tile * g, 0].reshape(b, h)
     if d > LANES:
-        return out[:, :, :g].reshape(b, h, dv)
-    out = out[:, :, :per_tile * g].reshape(b, tiles, per_tile, g, per_tile, d)
-    return jnp.where(own, out, 0).sum(axis=4).reshape(b, h, d)
+        out = out[:, :, :g].reshape(b, h, dv)
+    else:
+        out = out[:, :, :per_tile * g].reshape(
+            b, tiles, per_tile, g, per_tile, d)
+        out = jnp.where(own, out, 0).sum(axis=4).reshape(b, h, d)
+    return (out, lse) if with_lse else out
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "n_steps", "scale", "interpret", "rest_at"))
+    "block_size", "n_steps", "scale", "interpret", "rest_at", "with_lse"))
 def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
-                     *, block_size, n_steps, scale, interpret, rest_at=None):
+                     *, block_size, n_steps, scale, interpret, rest_at=None,
+                     with_lse=False):
     """The kernel's call.  A jitted function of its own with the layer as a
     prefetched scalar, so that the layers of a program that call it at the
     same shapes share one trace and one lowering of the body (0.8 s a call
@@ -708,16 +734,22 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
 
     whole = [] if sink is None else [pl.BlockSpec(
         sink.shape, lambda s, c, *_: (0, 0, 0))]
+    out_specs = blk(tiles)
+    out_shape = jax.ShapeDtypeStruct((b, tiles, q_rows, LANES), qt.dtype)
+    if with_lse:
+        # float32 out beside the log-denominators: the caller merges walks
+        out_shape = [jax.ShapeDtypeStruct(out_shape.shape, jnp.float32)] * 2
+        out_specs = [out_specs] * 2
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, block_size=block_size, n_steps=n_steps,
-            scale=scale, rest_at=rest_at),
+            scale=scale, rest_at=rest_at, with_lse=with_lse),
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, n_steps),
             in_specs=[blk(q_tiles), pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY), *whole],
-            out_specs=blk(tiles),
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((step_rows, k_pool.shape[-1]), k_pool.dtype),
                 pltpu.VMEM((step_rows, v_pool.shape[-1]), v_pool.dtype),
@@ -726,7 +758,7 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
                 pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((tiles, q_rows, LANES), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, tiles, q_rows, LANES), qt.dtype),
+        out_shape=out_shape,
         interpret=interpret,
     )(tables, lens, lo, layer, qt, k_pool, v_pool,
       *(() if sink is None else (sink,)))
@@ -2006,18 +2038,25 @@ KV_CHUNK_VMEM = 96 << 20
 
 def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
                      v_hbm, *refs, block_size, q_tile, scale, window,
-                     rest_at):
+                     rest_at, from_lo=False, causal=True, with_lse=False):
     """``rest_at``: where a K head is wider than its whole tiles
     (:func:`lay_heads`), the lane at which the heads' remainders start in
     the K row, two of 64 a tile: the head's whole tiles and the remainder
     tile it shares are copied side by side, and the query holds zeros in
     the neighbour's lanes.  With a sink, ``refs`` starts with its (1,
-    heads, 128) float32 block."""
+    heads, 128) float32 block.  ``from_lo``: rows before ``lo`` are masked
+    too (a tumbling window starts anywhere in its first stretch; a sliding
+    one's rows before ``lo`` fall to the window's mask).  ``causal`` false:
+    every query attends every row before ``start + T`` (the rows are not the
+    queries' own positions: chunk summaries).  ``with_lse``: a second output,
+    the log of each query's denominator across a head's 128 lanes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    sink_ref = refs[0] if len(refs) == 9 else None
-    o_ref, kbuf, vbuf, sem, q_sc, m_sc, l_sc, acc_sc = refs[-8:]
+    lse_ref = refs[-8] if with_lse else None
+    sink_ref = refs[0] if len(refs) == 9 + with_lse else None
+    o_ref = refs[-8 - with_lse]
+    kbuf, vbuf, sem, q_sc, m_sc, l_sc, acc_sc = refs[-7:]
     heads, t, dq = q_sc.shape
     stretch, dv = vbuf.shape[1], vbuf.shape[2]
     whole = dq if rest_at is None else dq - LANES   # lanes of whole K tiles
@@ -2078,9 +2117,11 @@ def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
             qpos = start + i * q_tile + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             kpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            ok = kpos <= qpos
+            ok = kpos <= qpos if causal else kpos < end
             if window is not None:
                 ok &= kpos > qpos - window
+            if from_lo:
+                ok &= kpos >= lo
             s = jnp.where(ok, s, NEG_INF)
         # the running maximum and sum are kept replicated across 128 lanes
         m_prev = m_sc[h, rows, :]
@@ -2101,8 +2142,8 @@ def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
         """Every head of the step against stretch ``first`` in ``slot``."""
         def head(h, _):
             def tile(i, _):
-                if not masked:
-                    return fold(h, i, first, False, slot)
+                if not masked or not causal:
+                    return fold(h, i, first, masked, slot)
                 # a tile whose last query precedes the stretch, or whose
                 # first query's window starts past it, attends none of it
                 some = first <= start + (i + 1) * q_tile - 1
@@ -2129,9 +2170,12 @@ def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
         copies(c, slot, lambda cp: cp.wait())
         # only a stretch that reaches past the chunk's first query, or
         # before the window of its last, is masked
-        masked = first + stretch - 1 > start
+        masked = (first + stretch - 1 > start if causal
+                  else first + stretch > end)
         if window is not None:
             masked |= first < end - window
+        if from_lo:
+            masked |= first < lo
         pl.when(masked)(lambda: heads_of(first, slot, True))
         pl.when(jnp.logical_not(masked))(
             lambda: heads_of(first, slot, False))
@@ -2146,25 +2190,31 @@ def _kv_chunk_kernel(table_ref, start_ref, lo_ref, layer_ref, q_ref, k_hbm,
         o_ref[:, h * dv:(h + 1) * dv] = (acc_sc[h] / jnp.concatenate(
             [jnp.maximum(l, 1e-30)] * (dv // LANES), axis=1)
         ).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[:, h * LANES:(h + 1) * LANES] = m_sc[h] + jnp.log(
+                jnp.maximum(l, 1e-30))
 
 
 def _kv_chunk_heads(group: int, chunk: int, dq: int, dv: int,
-                    itemsize: int) -> int:
+                    itemsize: int, with_lse: bool = False) -> int:
     """Query heads a grid step holds: the most that divide the ``group``
     that reads a K/V head, up to ``KV_CHUNK_HEADS``, whose queries and
-    outputs (two buffers each, the pipeline's), head-major queries and
-    float32 softmax state fit half the kernel's VMEM."""
-    a_head = chunk * (itemsize * (3 * dq + 2 * dv) + 4 * (dv + 2 * LANES))
+    outputs (two buffers each, the pipeline's; the log-denominators' too
+    where they go out), head-major queries and float32 softmax state fit
+    half the kernel's VMEM."""
+    a_head = chunk * (itemsize * (3 * dq + 2 * dv) + 4 * (dv + 2 * LANES)
+                      + 8 * LANES * with_lse)
     fit = max(1, min(KV_CHUNK_HEADS, (KV_CHUNK_VMEM // 2) // a_head))
     return max(g for g in range(1, fit + 1) if group % g == 0)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "kv_heads", "block_size", "stretch", "heads_step", "q_tile", "scale",
-    "window", "rest_at", "interpret"))
+    "window", "rest_at", "interpret", "from_lo", "causal", "with_lse"))
 def _kv_chunk_call(table_row, start, lo, layer, q, k_pool, v_pool, sink=None,
                    *, kv_heads, block_size, stretch, heads_step, q_tile,
-                   scale, window, rest_at, interpret):
+                   scale, window, rest_at, interpret, from_lo=False,
+                   causal=True, with_lse=False):
     """The chunk kernel's call: a jitted function of its own with the layer
     as a prefetched scalar, so the layers of a group share one lowering.
     ``q`` is 2-D, (T, H * dq): the heads side by side, each as wide as the
@@ -2186,15 +2236,22 @@ def _kv_chunk_call(table_row, start, lo, layer, q, k_pool, v_pool, sink=None,
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     sink_spec = [] if sink is None else [pl.BlockSpec(
         (1, g, LANES), lambda kv, j, *_: (kv * steps + j, 0, 0))]
+    out_specs = a_step(dv)
+    out_shape = jax.ShapeDtypeStruct((t, heads * dv), q.dtype)
+    if with_lse:
+        out_specs = [out_specs, a_step(LANES)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((t, heads * LANES),
+                                                     jnp.float32)]
     return pl.pallas_call(
         functools.partial(
             _kv_chunk_kernel, block_size=block_size, q_tile=q_tile,
-            scale=scale, window=window, rest_at=rest_at),
+            scale=scale, window=window, rest_at=rest_at, from_lo=from_lo,
+            causal=causal, with_lse=with_lse),
         name="kv_chunk_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(kv_heads, steps),
             in_specs=[a_step(dq), hbm, hbm, *sink_spec],
-            out_specs=a_step(dv),
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, stretch, dq), k_pool.dtype),
                 pltpu.VMEM((2, stretch, dv), v_pool.dtype),
@@ -2204,7 +2261,7 @@ def _kv_chunk_call(table_row, start, lo, layer, q, k_pool, v_pool, sink=None,
                 pltpu.VMEM((g, t, LANES), jnp.float32),
                 pltpu.VMEM((g, t, dv), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((t, heads * dv), q.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=KV_CHUNK_VMEM),
@@ -2250,9 +2307,13 @@ def paged_window_chunk_attention(
     sink: jax.Array | None = None,   # (H,) a key without a value a head
     impl: str = "auto",
     interpret: bool | None = None,
+    lo=None,                 # int32 scalar: the first row a query attends
+    causal: bool = True,
+    with_lse: bool = False,
 ) -> jax.Array:
     """Chunk-prefill attention of one slot against its pages that keeps a
-    chunk's scores in VMEM: what :func:`paged_chunk_attention` computes (its
+    chunk's scores in VMEM (``lo``, ``causal`` and ``with_lse`` are at the
+    end of this text): what :func:`paged_chunk_attention` computes (its
     loop is the plain formulation here: the tests' yardstick, the path off
     the TPU, for ``impl="xla"`` and at shapes that do not fit,
     :func:`paged_chunk_formulation`), bf16 operands, float32 scores and
@@ -2275,12 +2336,23 @@ def paged_window_chunk_attention(
     first query, or before the window of its last, is masked, and a query
     tile that attends none of such a stretch skips it; blocks past the
     chunk's end or before the first attended row are not copied.  ``sink``
-    joins each head's denominator once, at the end."""
+    joins each head's denominator once, at the end.
+
+    The kernel's alone, for a caller that merges several walks under one
+    softmax (:func:`eva_chunk_attention`): ``lo`` names the first attended
+    row itself (a tumbling window's start, masked where it lies inside a
+    stretch); ``causal=False`` has every query attend every row before
+    ``start + T`` (rows that are not the queries' own positions); ``with_lse``
+    returns ``(o, lse)``, the log of each query's denominator (T, H)."""
     t, h, d = q.shape
     h_kv = k_pool.shape[-1] // d
     dv = v_pool.shape[-1] // h_kv
     if paged_chunk_formulation(h, h_kv, d, dv, block_size, t,
                                impl) == "plain":
+        if lo is not None or not causal or with_lse:
+            raise ValueError(
+                "lo=, causal=False and with_lse= are the kv_chunk_attn "
+                "kernel's")
         return paged_chunk_attention(
             q, start, k_pool, v_pool, table_row, layer=layer,
             block_size=block_size, window=window, sink=sink)
@@ -2299,10 +2371,14 @@ def paged_window_chunk_attention(
         q = jnp.concatenate(
             [qh[..., :whole], rest.reshape(t, h_kv, g, LANES)], axis=-1)
     dq = whole + (LANES if rest_at is not None else 0)
-    heads_step = _kv_chunk_heads(g, t, dq, dv, q.dtype.itemsize)
+    heads_step = _kv_chunk_heads(g, t, dq, dv, q.dtype.itemsize, with_lse)
     start = jnp.reshape(start, (1,)).astype(jnp.int32)
-    lo = (jnp.zeros_like(start) if window is None
-          else jnp.maximum(start - window + 1, 0))
+    from_lo = lo is not None
+    if from_lo:
+        lo = jnp.reshape(lo, (1,)).astype(jnp.int32)
+    else:
+        lo = (jnp.zeros_like(start) if window is None
+              else jnp.maximum(start - window + 1, 0))
     if sink is not None:
         # a head's bias across the lanes, as the running maximum lies
         sink = jnp.broadcast_to(
@@ -2314,5 +2390,278 @@ def paged_window_chunk_attention(
         v_pool, sink, kv_heads=h_kv, block_size=block_size,
         stretch=KV_CHUNK_STRETCH, heads_step=heads_step,
         q_tile=min(t, KV_CHUNK_QUERIES), scale=d ** -0.5, window=window,
-        rest_at=rest_at, interpret=interpret)
+        rest_at=rest_at, interpret=interpret, from_lo=from_lo, causal=causal,
+        with_lse=with_lse)
+    if with_lse:
+        # a query's log-denominator lies across a head's lanes: one of them
+        out, lse = out
+        return out.reshape(t, h, dv), lse.reshape(t, h, LANES)[..., 0]
     return out.reshape(t, h, dv)
+
+
+# ---------------------------------------------------------------------------
+# EVA: an exact tumbling window and chunk summaries under one softmax
+# ---------------------------------------------------------------------------
+#
+# An EVA layer (Zheng et al., "Efficient Attention via Control Variates", as
+# EvaByte simplifies it; ``models.evabyte``) attends, under ONE softmax, the
+# exact keys of the query's own window — windows tumble: ``[w i, w (i + 1))``
+# — and one summary key/value for every chunk of ``c`` tokens of every
+# *earlier* window.  So a layer's rows live in TWO pools that advance at two
+# rates: token rows in a ring that is reused in place when a window closes
+# (group ``"window"``), and summary rows, one a ``c`` tokens, that are kept
+# for ever (group ``"full"``).  Both pools hold a K/V pair a row, so each
+# walk is the K/V rows' walk (``paged_attn`` / ``kv_chunk_attn``, or their
+# plain formulations) and the two are merged by their log-sum-exp.
+
+
+def chunk_summaries(k, v, mu, phi, chunk_size: int):
+    """The summary key and value of every whole chunk of ``chunk_size`` rows
+    of ``k``, ``v`` (..., n * chunk_size, H, D): ``k~ = sum_m softmax_m(s
+    <k_m, mu_h>) k_m`` and ``v~ = sum_m softmax_m(s <k_m, phi_h>) v_m`` over
+    the chunk's rows ``m``, ``s = D ** -0.5``, with learned ``mu``, ``phi``
+    (H, D).  Float32 scores, softmax and sums on the stored rows; back in
+    the rows' type, (..., n, H, D) each."""
+    *lead, t, h, d = k.shape
+    f32 = jnp.float32
+    kc = k.reshape(*lead, t // chunk_size, chunk_size, h, d).astype(f32)
+    vc = v.reshape(*lead, t // chunk_size, chunk_size, h, d).astype(f32)
+    scale = d ** -0.5
+
+    def pooled(rows, by, w):
+        p = jax.nn.softmax((by * w.astype(f32)).sum(-1) * scale, axis=-2)
+        return (p[..., None] * rows).sum(-3)
+
+    return (pooled(kc, kc, mu).astype(k.dtype),
+            pooled(vc, kc, phi).astype(v.dtype))
+
+
+def merge_softmax_parts(parts, dtype):
+    """Outputs of one query over disjoint sets of keys, each normalised over
+    its own set with the log of its denominator (``lse``, ``(...,)`` beside
+    ``o`` ``(..., D)``), as the output over all the sets: each part weighs
+    ``exp(lse - lse_all)``.  A part over no key has ``lse`` at ``NEG_INF``
+    or under and weighs nothing."""
+    lse = jnp.stack([l for _, l in parts])
+    top = lse.max(0)
+    w = jnp.exp(lse - top)
+    w = w / w.sum(0)
+    return sum(o.astype(jnp.float32) * wi[..., None]
+               for (o, _), wi in zip(parts, w)).astype(dtype)
+
+
+def _eva_span(pos, window: int, chunk_size: int):
+    """Of a query at ``pos``: the first position of its window, and the
+    summary rows it sees (every chunk of every earlier window)."""
+    first = pos // window * window
+    return first, first // chunk_size
+
+
+def _plain_eva_scores(q, k, ok):
+    """Masked float32 scores of queries (..., T, H, D) on keys (..., K, H,
+    D) under ``ok`` (..., T, K), (..., H, T, K)."""
+    s = jnp.einsum("...qhd,...khd->...hqk", q, k,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
+    return jnp.where(ok[..., None, :, :], s, NEG_INF)
+
+
+def softmax_over_parts(q, parts):
+    """One softmax of queries (..., T, H, D) over several sets of keys:
+    ``parts`` is ``[(k, v, ok)]``, rows (..., K, H, D) and the mask (..., T,
+    K) of what each query attends."""
+    s = jnp.concatenate([_plain_eva_scores(q, k, ok) for k, _, ok in parts],
+                        axis=-1)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    v = jnp.concatenate([v for _, v, _ in parts], axis=-3)
+    return jnp.einsum("...hqk,...khd->...qhd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _window_blocks(table, first, window: int, block_size: int):
+    """The table's columns (last axis) that hold the window from ``first``
+    (a scalar, or one a row of ``table``): ``window // block_size`` of
+    them, past the table's width its last column (masked by position)."""
+    cols = jnp.asarray(first)[..., None] // block_size + jnp.arange(
+        -(-window // block_size), dtype=jnp.int32)
+    cols = jnp.minimum(cols, table.shape[-1] - 1)
+    return jnp.take_along_axis(table, cols, axis=-1)
+
+
+def _block_rows(pool, layer, blocks, block_size: int, head_dim: int):
+    """Rows of ``blocks`` (..., n) of layer ``layer`` as heads, (..., n *
+    block_size, H, D): whole blocks gathered, the heads split on what was
+    gathered only."""
+    x = pool.reshape(pool.shape[0], -1, block_size,
+                     pool.shape[-1])[layer, blocks]
+    return _row_heads(x, (*blocks.shape[:-1], blocks.shape[-1] * block_size),
+                      head_dim)
+
+
+def eva_decode_attention(
+    q: jax.Array,              # (B, H, D) one query a slot
+    ring_pools: tuple,         # (k_pool, v_pool) of token rows, the ring
+    summary_pools: tuple,      # (k_pool, v_pool) of summary rows
+    ring_tables: jax.Array,    # (B, columns a token block) int32
+    summary_tables: jax.Array,  # (B, columns a block of summary rows)
+    attend_lens: jax.Array,    # (B,) tokens a slot holds, this step's too
+    *,
+    layer: int,
+    block_size: int,
+    window: int,
+    chunk_size: int,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Decode attention of an EVA layer: the query at ``attend_lens - 1``
+    attends the token rows from its window's start to itself and the summary
+    rows of every earlier window's chunks, one softmax over both.
+
+    On the TPU two walks of the ``paged_attn`` kernel — the ring's blocks of
+    the open window, then the summary pool's blocks that hold a visible row
+    — each of which also returns the log of its denominator, merged
+    (:func:`merge_softmax_parts`); the plain formulation gathers the open
+    window's columns of the ring's table and every column of the summary
+    table, and takes one softmax over both."""
+    b, h, d = q.shape
+    pos = attend_lens.astype(jnp.int32) - 1
+    first, seen = _eva_span(pos, window, chunk_size)
+    if paged_decode_formulation(h, h, d, block_size, impl) == "paged_attn":
+        walk = functools.partial(
+            paged_window_decode_attention, q, layer=layer,
+            block_size=block_size, impl=impl, interpret=interpret,
+            with_lse=True)
+        # ``window`` bounds the ring's walk: ``lo`` says where it starts
+        return merge_softmax_parts(
+            [walk(*ring_pools, ring_tables, pos + 1, lo=first,
+                  window=window),
+             walk(*summary_pools, summary_tables, seen)], q.dtype)
+    blocks = _window_blocks(ring_tables, first, window, block_size)
+    kpos = first[:, None] + jnp.arange(blocks.shape[1] * block_size)
+    local = tuple(_block_rows(p, layer, blocks, block_size, d)
+                  for p in ring_pools)
+    summ = tuple(_block_rows(p, layer, summary_tables, block_size, d)
+                 for p in summary_pools)
+    return softmax_over_parts(q[:, None], [
+        (*local, (kpos <= pos[:, None])[:, None]),
+        (*summ, (jnp.arange(summ[0].shape[1])[None] < seen[:, None])[:, None]),
+    ])[:, 0]
+
+
+def eva_chunk_attention(
+    q: jax.Array,              # (T, H, D): one slot's chunk of queries
+    start,                     # int32 scalar: position of q[0]
+    ring_pools: tuple,
+    summary_pools: tuple,
+    ring_row: jax.Array,       # the slot's row of the ring's table
+    summary_row: jax.Array,    # the slot's row of the summary table
+    *,
+    layer: int,
+    block_size: int,
+    window: int,
+    chunk_size: int,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Chunk-prefill attention of an EVA layer, the chunk's own token rows
+    already written: a chunk lies inside one window (the chunk grid divides
+    the window grid), so its queries attend the window's rows up to
+    themselves, causally, and — every one the same — the summary rows of the
+    earlier windows.  On the TPU two walks of ``kv_chunk_attn`` (the causal
+    one from the window's first row; one without a mask over the visible
+    summary rows), merged by their log-sum-exp; the plain formulation gathers
+    the window's blocks and the whole summary table under one softmax."""
+    t, h, d = q.shape
+    start = jnp.asarray(start, jnp.int32)
+    first, seen = _eva_span(start, window, chunk_size)
+    if paged_chunk_formulation(h, h, d, d, block_size, t,
+                               impl) == "kv_chunk_attn":
+        walk = functools.partial(
+            paged_window_chunk_attention, q, layer=layer,
+            block_size=block_size, impl=impl, interpret=interpret,
+            with_lse=True)
+        return merge_softmax_parts(
+            [walk(start, *ring_pools, ring_row, lo=first),
+             walk(seen - t, *summary_pools, summary_row, causal=False)],
+            q.dtype)
+    blocks = _window_blocks(ring_row, first, window, block_size)
+    kpos = first + jnp.arange(blocks.shape[0] * block_size)
+    qpos = start + jnp.arange(t)
+    local = tuple(_block_rows(p, layer, blocks, block_size, d)
+                  for p in ring_pools)
+    summ = tuple(_block_rows(p, layer, summary_row, block_size, d)
+                 for p in summary_pools)
+    return softmax_over_parts(q, [
+        (*local, kpos[None, :] <= qpos[:, None]),
+        (*summ, jnp.broadcast_to(
+            jnp.arange(summ[0].shape[0])[None] < seen, (t, summ[0].shape[0]))),
+    ])
+
+
+@dataclasses.dataclass(frozen=True)
+class TumblingKVRows(KVRows):
+    """The K/V pair a token of a layer that attends its own *tumbling*
+    window exactly: a ring that lets a whole window go when it closes
+    (``serve.kv_cache.WindowKVGroup``)."""
+
+    tumbling = True
+
+
+@dataclasses.dataclass(frozen=True)
+class SummaryKVRows(KVRows):
+    """One K/V pair a chunk of ``tokens_per_row`` tokens, written when the
+    chunk is complete and kept for ever; a query sees the rows of the
+    windows before its own (``window`` tokens each)."""
+
+    tokens_per_row: int = 16
+    window: int = 2048
+
+    def visible_rows(self, positions):
+        """Summary rows a query at each of ``positions`` attends."""
+        return positions // self.window * (self.window // self.tokens_per_row)
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaRows:
+    """What an EVA layer caches, in two groups at two rates: the K/V pair a
+    token in group ``token_group`` (a tumbling ring of ``window`` rows) and
+    one summary K/V pair a ``chunk_size`` tokens in group ``summary_group``.
+    A block calls ``attend(q, k, v, mu=, phi=)``; the programs
+    (``serve.model``) write the token rows, form and write the summaries of
+    the chunks they complete, and read both pools back through ``chunk`` and
+    ``decode`` here."""
+
+    heads: int
+    head_dim: int
+    chunk_size: int
+    window: int
+    token_group = "window"
+    summary_group = "full"
+
+    @property
+    def groups(self) -> dict:
+        """``{group: the form of the rows it stores}``."""
+        return {
+            self.token_group: TumblingKVRows(
+                self.heads, self.heads, self.head_dim),
+            self.summary_group: SummaryKVRows(
+                self.heads, self.heads, self.head_dim,
+                tokens_per_row=self.chunk_size, window=self.window)}
+
+    def summarise(self, k, v, mu, phi):
+        with jax.named_scope("summarise"):
+            return chunk_summaries(k, v, mu, phi, self.chunk_size)
+
+    def chunk(self, q, start, pools: dict, table_rows: dict, **kw):
+        with jax.named_scope("paged_attn"):
+            return eva_chunk_attention(
+                q, start, pools[self.token_group], pools[self.summary_group],
+                table_rows[self.token_group], table_rows[self.summary_group],
+                window=self.window, chunk_size=self.chunk_size, **kw)
+
+    def decode(self, q, pools: dict, tables: dict, attend_lens, **kw):
+        with jax.named_scope("paged_attn"):
+            return eva_decode_attention(
+                q, pools[self.token_group], pools[self.summary_group],
+                tables[self.token_group], tables[self.summary_group],
+                attend_lens, window=self.window, chunk_size=self.chunk_size,
+                **kw)

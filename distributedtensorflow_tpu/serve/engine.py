@@ -393,6 +393,14 @@ class Engine:
         #: load of one expert
         self._routed = None
         self._blocks_recycled0 = 0
+        #: the group of chunk summaries (rows that stand for several tokens
+        #: each), or None; its counters at the last step record, and the
+        #: summary rows this iteration's prefill chunks attended (None: no
+        #: chunk ran)
+        self._summaries = next(
+            (g for g in self.kv.paged.values() if g.tokens_per_row > 1), None)
+        self._closed0 = (0, 0)
+        self._step_chunk_summaries = None
         self.fused_sampling = bool(fused_sampling)
         self.speculate = speculate
         self.spec_ngram = int(spec_ngram)
@@ -875,6 +883,7 @@ class Engine:
         self._step_sampled = (0, 0)
         self._step_latent = [0, 0, 0]
         self._step_rows_read = {}
+        self._step_chunk_summaries = None
         self._step_scan = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace) whose leaves tile it: a leaf begins where the one before
@@ -1142,6 +1151,17 @@ class Engine:
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
+        if self._summaries is not None:
+            # a cache with chunk summaries beside a tumbling ring only: the
+            # summary rows the programs wrote and the windows that closed
+            # since the last record, and the summary rows this iteration's
+            # prefill chunks attended (a layer)
+            closed = (self.kv.summary_rows_written, self.kv.windows_closed)
+            fields["summary_rows_written"] = closed[0] - self._closed0[0]
+            fields["windows_closed"] = closed[1] - self._closed0[1]
+            self._closed0 = closed
+            if self._step_chunk_summaries is not None:
+                fields["chunk_summary_rows_read"] = self._step_chunk_summaries
         for name, g in self.kv.paged.items():
             fields[f"kv_blocks_used_{name}"] = g.allocator.used_blocks
         if self.kv.state is not None:
@@ -1329,6 +1349,9 @@ class Engine:
             self._step_latent[0] += start + c
         if self.kv.state is not None:
             self._step_scan += real
+        if self._summaries is not None:
+            self._step_chunk_summaries = (self._step_chunk_summaries or 0) \
+                + int(self._summaries.rows_attended(start))
         req._fill_next = start + c
         self.kv.note_written(
             slot, max(min(start + c, len(req.prompt)),
@@ -1448,12 +1471,10 @@ class Engine:
                 self._m_latent_read.inc(self._step_latent[1])
                 self._m_index_scored.inc(scored)
         if self._count_rows:
-            lens = self.kv.seq_lens[slots]
+            positions = self.kv.seq_lens[slots] - 1    # the queries'
             for name, g in self.kv.paged.items():
-                window = getattr(g, "window", None)
                 read = len(self.kv.layers[name]) * int(
-                    (lens if window is None
-                     else np.minimum(lens, window)).sum())
+                    g.rows_attended(positions).sum())
                 self._step_rows_read[name] = read
                 self._m_rows_read[name].inc(read)
         if self.kv.state is not None:

@@ -300,7 +300,7 @@ class SlotPages:
     """One slot's page-table bookkeeping (host side)."""
 
     blocks: list[int]          # physical block ids, logical order
-    capacity_tokens: int       # blocks * block_size
+    capacity_tokens: int       # positions whose rows the blocks hold
     prefix_tokens: int = 0     # tokens mapped from the prefix cache at admit
 
 
@@ -334,7 +334,9 @@ class PagedKVCache:
         self.block_size = block_size
         self.max_slots = max_slots
         self.max_context = max_context
-        self.blocks_per_slot = max_context // block_size
+        #: tokens that make one row: 1, or the chunk a summary row stands for
+        self.tokens_per_row = getattr(rows, "tokens_per_row", 1)
+        self.blocks_per_slot = self.blocks_for(max_context)
         self.scratch_block = num_blocks  # reserved physical block
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
         self.rows = rows
@@ -453,8 +455,19 @@ class PagedKVCache:
     # -- admission / eviction (engine thread only) ---------------------------
 
     def blocks_for(self, tokens: int) -> int:
-        """Physical blocks needed to hold ``tokens`` K/V positions."""
-        return -(-tokens // self.block_size)
+        """Physical blocks needed to hold the rows of ``tokens`` positions:
+        a row a token, or one a whole ``tokens_per_row`` of them."""
+        return -(-(tokens // self.tokens_per_row) // self.block_size)
+
+    def _capacity_tokens(self, blocks: int) -> int:
+        """Positions ``blocks`` blocks hold the rows of."""
+        per = self.tokens_per_row
+        return (blocks * self.block_size + 1) * per - 1
+
+    def rows_attended(self, positions):
+        """Rows of this group a query at each of ``positions`` attends."""
+        seen = getattr(self.rows, "visible_rows", None)
+        return positions + 1 if seen is None else seen(positions)
 
     def reservation(self, tokens: int) -> int:
         """Blocks admission grants a slot of ``tokens`` positions."""
@@ -501,7 +514,7 @@ class PagedKVCache:
             self.prefix_hits += 1
             self.prefix_cached_tokens += prefix_tokens
         blocks = prefix_blocks + fresh
-        pages = SlotPages(blocks, n * self.block_size,
+        pages = SlotPages(blocks, self._capacity_tokens(n),
                           prefix_tokens=prefix_tokens)
         self.pages[slot] = pages
         self._capacity[slot] = pages.capacity_tokens
@@ -732,6 +745,21 @@ class PagedKVCache:
 #   layers, ``models.lfm2``'s conv layers): arrays ``(layers, slots, ...)``,
 #   as many as the family's state form lists, no pages, no allocator.
 #
+# A layer may live in TWO groups (``cfg.groups_of(layer)``; ``models.evabyte``:
+# every layer keeps token rows in a window group AND chunk summaries in the
+# full group), and a paged group's rows may advance once a ``tokens_per_row``
+# tokens (``rows.tokens_per_row``: a summary row stands for a chunk of 16):
+# its lengths, ``blocks_for``, admission and ``note_written`` then count rows,
+# not tokens, under the one length a slot that all groups share, and its page
+# table has a column a block of rows.  A window group whose rows say
+# ``tumbling`` keeps ``[w i, w (i + 1))`` and not the last ``w``: ``keep_from =
+# tokens // window * window``, a closed window's blocks all go at once, and —
+# the prefill chunk grid lying on the window grid, which
+# :func:`make_grouped_cache` insists on — nothing is written ahead, so the
+# ring is one window + one block and is overwritten in place.  Such a cache keeps
+# nothing of an earlier position: ``rollback`` / ``register_prefix`` raise, as
+# over a state group.
+#
 # :class:`GroupedKVCache` admits against all groups at once and otherwise
 # answers the engine as one cache: it is the only cache the engine holds.  A
 # GPT-2 configuration is one full group — the :class:`PagedKVCache` the
@@ -745,6 +773,15 @@ class WindowKVGroup(PagedKVCache):
     def __init__(self, *, window: int, write_ahead: int, **kw):
         super().__init__(**kw)
         self.window = window
+        #: whether windows tumble (``[w i, w (i + 1))``: a closed window's
+        #: rows are read by no one, so all its blocks go at once and the
+        #: ring is one window, overwritten in place) or slide
+        self.tumbling = getattr(self.rows, "tumbling", False)
+        if self.tumbling and window % self.block_size:
+            raise ValueError(
+                f"a tumbling window of {window} must be whole blocks of "
+                f"{self.block_size}: a closed window's blocks go whole")
+        self.windows_closed = 0
         #: most tokens one program writes past the resident ones (a
         #: prefill chunk), which the ring must hold beside the window
         self.write_ahead = write_ahead
@@ -769,7 +806,8 @@ class WindowKVGroup(PagedKVCache):
         blocks = self.allocator.alloc(self.reservation(tokens))
         if blocks is None:
             return None
-        pages = SlotPages(blocks, self.blocks_for(tokens) * self.block_size)
+        pages = SlotPages(blocks,
+                          self._capacity_tokens(self.blocks_for(tokens)))
         self.pages[slot] = pages
         self._capacity[slot] = pages.capacity_tokens
         self._stock[slot] = list(reversed(blocks))
@@ -803,9 +841,15 @@ class WindowKVGroup(PagedKVCache):
         the earliest and attends keys ``> tokens - window``.  Only the
         slots that crossed a block edge have a block to let go."""
         super().note_written(slots, tokens)
-        slots = np.atleast_1d(slots)
-        keep_from = (np.maximum(np.atleast_1d(tokens) - self.window + 1, 0)
-                     // self.block_size)
+        slots, tokens = np.atleast_1d(slots), np.atleast_1d(tokens)
+        if self.tumbling:
+            # the next query sits in the window that holds ``tokens``
+            keep_from = tokens // self.window * self.window // self.block_size
+            self.windows_closed += int(
+                (keep_from > self._first[slots]).sum())
+        else:
+            keep_from = (np.maximum(tokens - self.window + 1, 0)
+                         // self.block_size)
         upto = np.minimum(keep_from, self._next[slots])
         crossed = upto > self._first[slots]
         for slot, end in zip(slots[crossed].tolist(), upto[crossed].tolist()):
@@ -820,6 +864,11 @@ class WindowKVGroup(PagedKVCache):
 
     def mapped_blocks(self, slot: int) -> int:
         return int(self._next[slot] - self._first[slot])
+
+    def rows_attended(self, positions):
+        if self.tumbling:
+            return positions % self.window + 1
+        return np.minimum(positions + 1, self.window)
 
 
 class StateGroup:
@@ -897,6 +946,11 @@ class GroupedKVCache:
         #: the one group whose prefix index this cache shares through, or
         #: None: no block of any group is then ever shared between slots
         self._sharing = first if len(groups) == 1 else None
+        #: the groups whose rows stand for several tokens each (a chunk's
+        #: summary), and the rows they have gained since the start
+        self._chunked = [g for g in self.paged.values()
+                         if g.tokens_per_row > 1]
+        self.summary_rows_written = 0
         #: layers whose group stores one row a token that every head shares,
         #: not the K/V pair
         self.latent_layers = sum(
@@ -946,7 +1000,7 @@ class GroupedKVCache:
     def row_bytes(self) -> int:
         """Bytes stored a token over all layers of all groups (a state
         group stores none a token: ``state.slot_bytes`` a slot)."""
-        return sum(g.row_bytes * len(self.layers[name])
+        return sum(g.row_bytes * len(self.layers[name]) // g.tokens_per_row
                    for name, g in self.paged.items())
 
     def check_fits(self, tokens: int) -> None:
@@ -986,6 +1040,12 @@ class GroupedKVCache:
             g.prepare_write(slot, end)
 
     def note_written(self, slots, tokens) -> None:
+        if self._chunked:
+            before = self.seq_lens[np.atleast_1d(slots)]
+            for g in self._chunked:
+                self.summary_rows_written += int(
+                    (np.atleast_1d(tokens) // g.tokens_per_row
+                     - before // g.tokens_per_row).sum())
         for g in self.paged.values():
             g.note_written(slots, tokens)
 
@@ -997,6 +1057,12 @@ class GroupedKVCache:
                 f"{what} is not implemented over a state group: a state "
                 "keeps no snapshot of an earlier position to go back to or "
                 "to share")
+        if self._chunked:
+            raise ValueError(
+                f"{what} is not implemented over a group of chunk summaries "
+                "beside a tumbling ring: a summary once written and a ring "
+                "row once reused keep nothing of an earlier position to go "
+                "back to or to share")
 
     def register_prefix(self, slot: int, tokens) -> int:
         self._no_state("register_prefix")
@@ -1024,6 +1090,11 @@ class GroupedKVCache:
         return sum(getattr(g, "blocks_recycled", 0)
                    for g in self.paged.values())
 
+    @property
+    def windows_closed(self) -> int:
+        return sum(getattr(g, "windows_closed", 0)
+                   for g in self.paged.values())
+
     def stats(self) -> dict:
         """The full group's census at the top level (the keys every reader
         of ``stats()`` knows), every group's under ``"groups"``."""
@@ -1046,12 +1117,18 @@ def layer_groups(cfg) -> dict[str, tuple[int, ...]]:
     config says a layer keeps: ``"state"`` for the layers that keep a state a
     slot (``cfg.keeps_state(layer)``, where a config has it), and of the
     others ``"full"`` and ``"window"`` by attention kind
-    (``cfg.window_of(layer)``); a group with no layer is left out."""
+    (``cfg.window_of(layer)``); a group with no layer is left out.  A layer
+    whose rows live in several groups (``cfg.groups_of(layer)``, where a
+    config has it: ``models.evabyte`` keeps token rows in a ring and chunk
+    summaries in a pool that grows) is listed in each."""
     keeps_state = getattr(cfg, "keeps_state", lambda layer: False)
+    groups_of = getattr(cfg, "groups_of", lambda layer: (
+        "state" if keeps_state(layer) else
+        "full" if cfg.window_of(layer) is None else "window",))
     kinds = {"full": [], "window": [], "state": []}
     for i in range(cfg.num_layers):
-        kinds["state" if keeps_state(i) else
-              "full" if cfg.window_of(i) is None else "window"].append(i)
+        for name in groups_of(i):
+            kinds[name].append(i)
     return {name: tuple(ls) for name, ls in kinds.items() if ls}
 
 
@@ -1059,9 +1136,27 @@ def group_rows(cfg, name: str):
     """What group ``name`` of ``cfg``'s layers caches a token a layer
     (``ops.attention``: a ``KVRows``, ``LatentRows``, ...): ``cfg.cache_rows``
     is the one form of every group, or ``{group: form}`` where the groups'
-    rows differ (MiMo-V2: 8 K/V heads a window layer, 4 a full one)."""
+    rows differ (MiMo-V2: 8 K/V heads a window layer, 4 a full one), or a
+    form of rows in several groups that names each group's (``rows.groups``:
+    ``ops.attention.EvaRows``)."""
     rows = cfg.cache_rows
+    rows = getattr(rows, "groups", rows)
     return rows[name] if isinstance(rows, dict) else rows
+
+
+def _check_tumbling_chunk(window: int, chunk: int, summaries) -> None:
+    """Refuse, with the reason, a prefill chunk off the grid of a tumbling
+    window or one that is not whole summary chunks.  On the grid a chunk
+    never reaches past the window it starts in and a closed window's rows
+    are overwritten in place: the ring holds nothing beside the window."""
+    per = getattr(summaries, "tokens_per_row", 1)
+    if window % chunk or chunk % per:
+        raise ValueError(
+            f"prefill_chunk={chunk} does not fit a tumbling window of "
+            f"{window} summarised in chunks of {per}: a prefill chunk must "
+            f"be a multiple of {per} that divides {window} or equals it, so "
+            "that no chunk crosses a window's end (its queries would see two "
+            "windows' rows) or completes part of a summary chunk")
 
 
 def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
@@ -1099,6 +1194,10 @@ def make_grouped_cache(cfg, *, max_slots: int, block_size: int,
                   max_context=max_context, dtype=cfg.dtype)
         if name == "window":
             window = cfg.window_of(ls[0])
+            if getattr(kw["rows"], "tumbling", False):
+                _check_tumbling_chunk(window, write_ahead,
+                                      group_rows(cfg, "full"))
+                write_ahead = 0
             ring = -(-(window + write_ahead) // block_size) + 1
             n = num_blocks.get(name) or max_slots * min(per_slot, ring)
             groups[name] = WindowKVGroup(

@@ -10,7 +10,8 @@ prompt while other slots keep decoding.  All programs have fully static
 shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
-``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``):
+``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``,
+``models.evabyte``):
 ``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
@@ -28,7 +29,17 @@ form lays a head wider than a lane tile out as it stores it, ``stored``),
 the query comes in two parts, ``attend((q_nope, q_rope, q_index, w_index),
 row, index_key, w_uk=, w_uv=)`` where an indexer selects the latent rows a
 query attends (joyai with ``index_topk``: GLM-5) and its key is cached in a
-second pool of the same group.  A program is the family's embedding, its blocks and its head, with an
+second pool of the same group, ``attend(q, k, v, mu=, phi=)`` where a layer's
+rows live in TWO groups at two rates (evabyte's EVA layers,
+``ops.attention.EvaRows``: the K/V pair a token in a tumbling ring, group
+``"window"``, and one summary pair a ``chunk_size`` tokens in group ``"full"``,
+a row a ``tokens_per_row`` tokens; :class:`_TwoPools` is that hook: it writes
+the token rows, forms and writes the summaries of the chunks the program
+completes — a decode step's for the slots at ``t % chunk_size == chunk_size
+- 1`` only, the others' to the scratch block — and reads a prefix of the
+summary pool shorter than what is written, the chunks of *closed* windows;
+such a family's prefill chunk takes the count of real tokens too, since a
+padded chunk gets no summary).  A program is the family's embedding, its blocks and its head, with an
 ``attend`` that writes the rows into the layer's group pools and reads the
 slot's pages back through the form — so the block is written once a family,
 and the three programs differ only in where the rows live:
@@ -102,8 +113,10 @@ key's write too, ``h<i>/attn`` for jamba, whose Mamba layers have
 dt_proj,scan|ssm_step,gate,out_proj}``, and for lfm2, whose attention layers
 add ``h<i>/attn/{qk_norm,rope}`` and whose conv layers have
 ``h<i>/{state_read,state_write}`` and ``h<i>/conv/{in_proj,gate_in,conv,
-gate_out,out_proj}``; an expert layer's FFN is ``h<i>/{router,experts}``, a
-dense one's ``h<i>/mlp``), ``head``, ``sample``,
+gate_out,out_proj}``, ``h<i>/eva_attn`` for evabyte, whose hook adds
+``summarise`` and ``summary_write`` beside ``kv_write`` and ``paged_attn``
+(the block's own are ``qkv``, ``rope`` and ``proj``); an expert layer's FFN is
+``h<i>/{router,experts}``, a dense one's ``h<i>/mlp``), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
 Metadata only, so a profiler trace can say which stage a device operation
 belongs to.
@@ -116,7 +129,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, gpt, jamba, joyai, lfm2, mimo
+from ..models import afmoe, evabyte, gpt, jamba, joyai, lfm2, mimo
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
 from .kv_cache import group_rows
 from .sampling import sample_burst
@@ -131,8 +144,20 @@ __all__ = [
 
 
 def _group_of(layers: dict[str, tuple[int, ...]]) -> dict[int, tuple]:
-    return {layer: (name, i) for name, ls in layers.items()
-            for i, layer in enumerate(ls)}
+    """``{layer: (its group, its index in the group's pools)}``; of a layer
+    in several groups (:class:`_TwoPools`) the first that lists it."""
+    where = {}
+    for name, ls in layers.items():
+        for i, layer in enumerate(ls):
+            where.setdefault(layer, (name, i))
+    return where
+
+
+def _two_pool_form(cfg):
+    """``cfg.cache_rows`` where a layer's rows live in two groups at two
+    rates (``ops.attention.EvaRows``), else None."""
+    form = cfg.cache_rows
+    return form if hasattr(form, "summary_group") else None
 
 
 def _forms_of(cfg, layers: dict[str, tuple[int, ...]]) -> dict:
@@ -141,13 +166,55 @@ def _forms_of(cfg, layers: dict[str, tuple[int, ...]]) -> dict:
             if name != "state"}
 
 
-def _write_rows(form, pools: tuple, li: int, at, rows: tuple) -> tuple:
+def _write_rows(form, pools: tuple, li: int, at, rows: tuple,
+                scope: str = "kv_write") -> tuple:
     """The group's pools with ``rows`` (one array a pool, a row a token, as
     the block handed them to ``attend``) written at pool rows ``at`` of
     layer ``li``, laid out as the form stores them."""
-    with jax.named_scope("kv_write"):
+    with jax.named_scope(scope):
         return tuple(pool.at[li, at].set(r.reshape(at.shape[0], -1))
                      for pool, r in zip(pools, form.stored(*rows)))
+
+
+class _TwoPools:
+    """The ``attend`` hook of a layer whose rows live in two groups at two
+    rates (``models.evabyte``): the programs' own.  It writes the token rows
+    to the ring (``kv_write``), forms the summaries of the chunks this
+    program completes (``summarise``: in a prefill chunk from the chunk's own
+    rows, in a decode step from the slot's last ``chunk_size`` ring rows,
+    this step's among them) and writes them to the summary pool
+    (``summary_write``) — a chunk the program does not complete, a pad
+    position's and an inactive slot's, goes to the scratch block —, then
+    reads both pools back through the form (``paged_attn``).  The summary is
+    written in the program that wrote the chunk's last row, so before the
+    ring row it came from can be reused.  ``pools`` is the program's dict of
+    pools, updated in place; ``rows`` the pool rows the writes go to, a group,
+    and ``last`` (decode) the ring rows of each slot's last chunk."""
+
+    def __init__(self, form, pools: dict, layers: dict, layer: int,
+                 rows: dict, read, last=None):
+        self.form, self.pools, self.rows = form, pools, rows
+        self.read, self.last = read, last
+        self.li = {name: layers[name].index(layer)
+                   for name in (form.token_group, form.summary_group)}
+
+    def __call__(self, q, k, v, *, mu, phi):
+        form, pools = self.form, self.pools
+        tok, summ = form.token_group, form.summary_group
+        forms = form.groups
+        pools[tok] = _write_rows(forms[tok], pools[tok], self.li[tok],
+                                 self.rows[tok], (k, v))
+        if self.last is not None:
+            with jax.named_scope("summarise"):
+                heads = (*self.last.shape, *k.shape[1:])
+                k, v = (pool[self.li[tok], self.last].reshape(heads)
+                        for pool in pools[tok])
+        pools[summ] = _write_rows(
+            forms[summ], pools[summ], self.li[summ], self.rows[summ],
+            tuple(r.reshape(-1, *r.shape[-2:])       # a row a chunk
+                  for r in form.summarise(k, v, mu, phi)),
+            scope="summary_write")
+        return self.read(q, pools, self.li[tok])
 
 
 class _SlotState:
@@ -257,6 +324,9 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     the real ones); the slot's state is row ``table_rows["state"][0]`` of the
     group's arrays.  The pools are donated."""
     where, forms = _group_of(layers), _forms_of(cfg, layers)
+    two = _two_pool_form(cfg)
+    #: the groups whose rows are not one a token: a state, chunk summaries
+    by_chunk = {"state"} | ({two.summary_group} if two else set())
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, pools, tokens, start, table_rows, last_ix,
@@ -265,10 +335,25 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
         positions = start + jnp.arange(chunk, dtype=jnp.int32)
         real = (jnp.arange(chunk, dtype=jnp.int32) < valid[0]
                 if valid else None)
+        bs = block_size
         with jax.named_scope("kv_rows"):
-            rows = {name: row[positions // block_size] * block_size
-                    + positions % block_size
-                    for name, row in table_rows.items() if name != "state"}
+            rows = {name: row[positions // bs] * bs + positions % bs
+                    for name, row in table_rows.items()
+                    if name not in by_chunk}
+            if two is not None:
+                # a summary row a chunk the prompt fills: the others (the
+                # pad positions' and the one the prompt ends in, which a
+                # decode step completes) go to the scratch block
+                per, row = two.chunk_size, table_rows[two.summary_group]
+                at = start // per + jnp.arange(chunk // per, dtype=jnp.int32)
+                whole = (jnp.arange(1, chunk // per + 1) * per) <= valid[0]
+                rows[two.summary_group] = jnp.where(
+                    whole, row[jnp.minimum(at // bs, row.shape[0] - 1)] * bs
+                    + at % bs, pools[two.summary_group][0].shape[1] - bs)
+
+            def read(q, pools, li):
+                return two.chunk(q, start, pools, table_rows, layer=li,
+                                 block_size=bs, impl=cfg.kernel_impl)
         x = family.embed(params, tokens, cfg)
         for layer in range(cfg.num_layers):
             name, li = where[layer]
@@ -285,6 +370,8 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
             mixer = attend if name != "state" else _ChunkState(
                 pools, li, table_rows[name][0], start, *valid,
                 cfg.kernel_impl)
+            if two is not None:
+                mixer = _TwoPools(two, pools, layers, layer, rows, read)
             with jax.named_scope(f"h{layer}"):
                 x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
                                    positions, mixer, token_mask=real)
@@ -314,6 +401,9 @@ def make_decode_fn(family, cfg, *, block_size: int,
     load of one expert (max) — active slots only; None from a model without
     expert layers."""
     where, forms = _group_of(layers), _forms_of(cfg, layers)
+    two = _two_pool_form(cfg)
+    #: the groups whose rows are not one a token: a state, chunk summaries
+    by_chunk = {"state"} | ({two.summary_group} if two else set())
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, pools, tokens, tables, seq_lens, active):
@@ -324,13 +414,37 @@ def make_decode_fn(family, cfg, *, block_size: int,
         with jax.named_scope("kv_rows"):
             rows = {}
             for name, table in tables.items():
-                if name == "state":
+                if name in by_chunk:
                     continue
                 blk = jnp.take_along_axis(
                     table, (positions // bs)[:, None], axis=1)[:, 0]
                 rows[name] = jnp.where(
                     active, blk * bs + positions % bs,
                     pools[name][0].shape[1] - bs)           # else: scratch
+            last = None
+            if two is not None:
+                # the slots whose token completes a chunk write its summary,
+                # formed from the chunk's rows in the ring (a chunk lies in
+                # one window: all of them are still mapped); the others'
+                # goes to the scratch block
+                per, tok, summ = (two.chunk_size, two.token_group,
+                                  two.summary_group)
+                closes = active & (positions % per == per - 1)
+                rows[summ] = jnp.where(
+                    closes, jnp.take_along_axis(
+                        tables[summ], (positions // per // bs)[:, None],
+                        axis=1)[:, 0] * bs + positions // per % bs,
+                    pools[summ][0].shape[1] - bs)
+                back = jnp.maximum(
+                    positions[:, None] - (per - 1) + jnp.arange(per), 0)
+                last = jnp.where(
+                    closes[:, None], jnp.take_along_axis(
+                        tables[tok], back // bs, axis=1) * bs + back % bs,
+                    pools[tok][0].shape[1] - bs)
+
+            def read(q, pools, li):
+                return two.decode(q, pools, tables, attend_lens, layer=li,
+                                  block_size=bs, impl=cfg.kernel_impl)
         x = family.embed(params, tokens, cfg)
         routed = []
         for layer in range(cfg.num_layers):
@@ -347,6 +461,8 @@ def make_decode_fn(family, cfg, *, block_size: int,
 
             mixer = attend if name != "state" else _StepState(
                 pools, li, active)
+            if two is not None:
+                mixer = _TwoPools(two, pools, layers, layer, rows, read, last)
             with jax.named_scope(f"h{layer}"):
                 x, counters = family.block(
                     params[f"h{layer}"], x, cfg, layer, positions, mixer,
@@ -489,6 +605,7 @@ PROGRAMS = {
     jamba.JambaConfig: jamba,
     mimo.MimoConfig: mimo,
     lfm2.Lfm2Config: lfm2,
+    evabyte.EvaByteConfig: evabyte,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -517,6 +634,21 @@ _STATE_LACKS = {
     "fused_sampling": "its sampled program is the verify program at no "
                       "draft, which has no state formulation",
     "speculate": "a rejected draft cannot be rolled back out of a state",
+}
+
+
+#: what stands in an option's way where a layer keeps a ring that is reused
+#: in place beside chunk summaries (``ops.attention.EvaRows``)
+_TWO_POOL_LACKS = {
+    "prefix_cache": "a prefix shared at a block's end would need the ring's "
+                    "rows of its open window beside its summaries, and a "
+                    "ring is reused in place",
+    "fused_sampling": "its sampled program is the verify program at no "
+                      "draft, which has no formulation over a ring and a "
+                      "summary pool",
+    "speculate": "a rejected draft that completed a chunk has written its "
+                 "summary, and one past a window's end has reused a ring "
+                 "row: neither can be rolled back",
 }
 
 
@@ -618,7 +750,8 @@ class Programs:
         """The chunk at ``start`` of which the first ``real`` tokens are the
         prompt's (the rest padding): the logits are those of the last real
         one."""
-        valid = (jnp.int32(real),) if "state" in self.layers else ()
+        counted = "state" in self.layers or _two_pool_form(self.cfg)
+        valid = (jnp.int32(real),) if counted else ()
         return self.prefill_chunk(params, pools, jnp.asarray(tokens),
                              jnp.int32(start), table_rows,
                              jnp.int32(max(real - 1, 0)), *valid)
@@ -626,6 +759,8 @@ class Programs:
     def _refuse(self, option: str, lacking: str):
         if "state" in self.layers:
             lacking = _STATE_LACKS[option]
+        elif _two_pool_form(self.cfg) is not None:
+            lacking = _TWO_POOL_LACKS[option]
         raise ValueError(
             f"{option} is not implemented for the "
             f"{self.family.__name__.rsplit('.', 1)[-1]} family yet "

@@ -63,7 +63,9 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
     programs that take the pool, at the shapes an ``Engine`` with these
     settings gives them (``cfg.max_seq`` is the serving context; a model of
     one full group, as GPT-2; of a full group and a window group of
-    ``window_blocks`` blocks, each of its own rows; or of a full group and a
+    ``window_blocks`` blocks, each of its own rows — different layers', or
+    the same layers' token rows and chunk summaries (evabyte, whose prefill
+    chunk takes the count of real tokens too); or of a full group and a
     state group, whose arrays are ``pools["state"]`` and whose prefill chunk
     takes the count of real tokens)."""
     def sds(shape, dtype):
@@ -82,10 +84,13 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
                                 width), cfg.dtype)
         for width in kv_cache.group_rows(cfg, name).widths)
         for name in paged}
-    table_rows = {name: sds((cfg.max_seq // block_size,), i32)
-                  for name in paged}
-    tables = {name: sds((max_slots, cfg.max_seq // block_size), i32)
-              for name in paged}
+    # a table column a block of rows: a row a token, or (a group of chunk
+    # summaries) one a ``tokens_per_row`` of them
+    columns = {name: cfg.max_seq // getattr(kv_cache.group_rows(cfg, name),
+                                            "tokens_per_row", 1) // block_size
+               for name in paged}
+    table_rows = {name: sds((columns[name],), i32) for name in paged}
+    tables = {name: sds((max_slots, columns[name]), i32) for name in paged}
     slots_i32 = sds((max_slots,), i32)
     active = sds((max_slots,), jnp.bool_)
     scalar = sds((), i32)
@@ -97,6 +102,8 @@ def pool_programs(cfg, *, max_slots: int, num_blocks: int, block_size: int,
         table_rows["state"] = sds((1,), i32)
         tables["state"] = sds((max_slots, 1), i32)
         valid = (scalar,)
+    if hasattr(cfg.cache_rows, "summary_group"):
+        valid = (scalar,)       # a summary a chunk the prompt fills
     programs = make_programs(cfg, chunk=chunk, block_size=block_size,
                              layers=layers)
 

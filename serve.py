@@ -62,6 +62,10 @@ CONFIGS = {
     # keep a tail a slot beside attention layers, routed experts; random init
     "lfm2_tiny": ("lfm2_tiny", None),
     "lfm2_24b_a2b": ("lfm2_24b_a2b", None),
+    # the evabyte family (models/evabyte.py): EVA layers that keep token rows
+    # in a tumbling ring AND chunk summaries in a pool that grows; random init
+    "evabyte_tiny": ("evabyte_tiny", None),
+    "evabyte_6_5b": ("evabyte_6_5b", None),
 }
 
 

@@ -671,6 +671,51 @@ def test_two_form_program_keeps_both_groups_pools_in_place_on_a_v5e(
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+def test_two_rate_program_keeps_ring_and_summary_pools_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The evabyte family at its published widths (32 heads of 128, chunks
+    of 16 in windows of 2,048, contexts to 32,768), two layers deep: its
+    programs take the ring's pools AND the summary pool's — the same layers'
+    rows in two groups at two rates —, copy or convert no layer of either
+    outside ``paged_attn`` and hand all four back in place; decode walks both
+    through the ``paged_attn`` kernel and a chunk of 2,048 through
+    ``kv_chunk_attn`` (one body a group: the ring's walk starts at the
+    window's first row, the summaries' is unmasked), each walk handing out
+    its log-sum-exp; a pool row of 4,096 lanes fits the decode kernel's
+    VMEM."""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import evabyte_6_5b
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+    from distributedtensorflow_tpu.serve.model import make_programs
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(evabyte_6_5b(), num_layers=2)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=8, num_blocks=512, window_blocks=8 * 129,
+        block_size=16, chunk=2048, draft=0, sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    for blocks in (512, 8 * 129):
+        _, rows, width = kv_cache.pool_shape(2, blocks, 16, 4096)
+        report = pool_check.check_pool_programs(
+            {program: programs[program]}, layer_elems=rows * width)
+        assert pool_check.failures(report, window=True) == []
+    fn, args = programs[program]
+    text = fn.lower(*args).as_text()
+    kernels = {name: text.count(f'kernel_name = "{name}"')
+               for name in ("paged_attn", "kv_chunk_attn")}
+    assert kernels == ({"paged_attn": 2, "kv_chunk_attn": 0}
+                       if program == "decode"
+                       else {"paged_attn": 0, "kv_chunk_attn": 2})
+    assert make_programs(
+        cfg, chunk=2048, block_size=16,
+        layers=kv_cache.layer_groups(cfg)).formulations == {
+        "full": {"decode": "paged_attn", "chunk": "kv_chunk_attn"},
+        "window": {"decode": "paged_attn", "chunk": "kv_chunk_attn"}}
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
 def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
                                                                monkeypatch):
     """The jamba family at its published widths, four layers deep (layer 1

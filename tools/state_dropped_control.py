@@ -36,17 +36,17 @@ for path in (ROOT, BENCH, os.path.join(BENCH, "reference")):
 import harness  # noqa: E402
 
 
-def main(argv: list[str]) -> int:
+def run_control(argv: list[str], control: str) -> int:
+    """The configuration's check (``argv[0]``) served by its engine as the
+    process now stands — the caller has already taken ``control`` away from
+    the programs — and scored as a benchmark run's is, a JSON row a seed
+    (``argv[1:]``); 1 if a control passed."""
     import jax
-    import jax.numpy as jnp
 
     import serve_check
     from distributedtensorflow_tpu import models
-    from distributedtensorflow_tpu.serve import model
     from distributedtensorflow_tpu.serve.model import family_of
 
-    decode_too = "--decode-too" in argv
-    argv = [a for a in argv if a != "--decode-too"]
     config = harness.load_json(argv[0])
     tool = harness.load_module(os.path.join(BENCH, "tools",
                                             "control_served.py"))
@@ -57,14 +57,6 @@ def main(argv: list[str]) -> int:
     cfg = getattr(models, config["system_config"])()
     check = config["correctness"]
     n_new = check["new_tokens"]
-
-    def dropped(self, array):       # the state is not carried
-        return jnp.zeros_like(array[self.li, 0])
-
-    model._ChunkState._get = dropped
-    if decode_too:
-        model._StepState._get = lambda self, array: jnp.zeros_like(
-            array[self.li])
     passed = 0
     for seed in (int(s) for s in argv[1:]):
         prompts = [r["prompt"] for r in kind._check_requests(
@@ -83,9 +75,7 @@ def main(argv: list[str]) -> int:
         verdict = kind._compare(served, scored, check)
         first = [[round(r, 3) for _, _, r in steps[:16]] for steps in scored]
         print(json.dumps({
-            "seed": seed, "control": "state dropped at " + (
-                "every program boundary" if decode_too
-                else "chunk boundaries"),
+            "seed": seed, "control": control,
             "limit": check["mean_regret_limit"], "control_ok": verdict["ok"],
             **{k: verdict[k] for k in ("mean_regret", "largest_regret",
                                        "positions_differing",
@@ -93,6 +83,25 @@ def main(argv: list[str]) -> int:
             "regret_first_16": first}), flush=True)
         passed += verdict["ok"]
     return 1 if passed else 0
+
+
+def main(argv: list[str]) -> int:
+    import jax.numpy as jnp
+
+    from distributedtensorflow_tpu.serve import model
+
+    decode_too = "--decode-too" in argv
+
+    def dropped(self, array):       # the state is not carried
+        return jnp.zeros_like(array[self.li, 0])
+
+    model._ChunkState._get = dropped
+    if decode_too:
+        model._StepState._get = lambda self, array: jnp.zeros_like(
+            array[self.li])
+    return run_control(
+        [a for a in argv if a != "--decode-too"], "state dropped at " + (
+            "every program boundary" if decode_too else "chunk boundaries"))
 
 
 if __name__ == "__main__":
