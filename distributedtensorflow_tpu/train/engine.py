@@ -120,6 +120,38 @@ def accumulate_gradients(
     return grads, metrics, new_mstate
 
 
+def separate_update(grads: PyTree) -> PyTree:
+    """``grads`` behind one ``lax.optimization_barrier`` over the whole
+    tree: the identity, which XLA may neither fuse nor schedule across.
+
+    An element-wise optimizer's update of a leaf depends on that leaf's
+    gradient alone, so on one chip XLA fuses the AdamW arithmetic into the
+    output of the weight-gradient product that feeds it, and the fused
+    product runs slower than the product and the update apart (GPT-2
+    medium, a layer: ``fc_in``'s weights + AdamW 3.99 ms where 2.96 is the
+    product alone; PERF.md section 6, PR 49).  Behind the barrier each
+    product writes its gradient and the update runs as element-wise fusions
+    under the ``optimizer`` scope once the backward has ended: the program
+    a data-parallel mesh already runs, where the gradient all-reduce stands
+    in the same place.  One barrier over the tree, not one a leaf: a leaf's
+    own barrier forbids the fusion too, but lets the scheduler start that
+    leaf's update inside the backward, where the update's f32 operands take
+    the fast memory the neighbouring products' operands had (31 ms a step
+    of 1280, same section); every gradient alive until the backward ends
+    costs no memory the step did not already hold.
+    """
+    return lax.optimization_barrier(grads)
+
+
+def optimizer_update(params: PyTree) -> tuple[str, int]:
+    """``(optimizer_update, optimizer_update_leaves)`` of the trainer's
+    start-up row: ``"separate"`` — the update is a region of the step of
+    its own (:func:`separate_update`) — and the gradient leaves that pass a
+    barrier, one a parameter leaf.  The mechanism is static, so the field,
+    not a rate, says it engaged; ``train_optimizer_ms`` is what it costs."""
+    return "separate", len(jax.tree.leaves(params))
+
+
 class _InstrumentedStep:
     """Thin telemetry shim over a jitted step executable.
 
@@ -259,7 +291,8 @@ def _step_body(loss_fn: LossFn, accum_steps: int, overlap=None,
 
     Folds the step counter into the rng (dropout etc. differs per step
     without threading a new key from the host), accumulates gradients over
-    microbatches, applies the update.  Shared so the single-step and
+    microbatches, applies the update as a region of its own
+    (:func:`separate_update`).  Shared so the single-step and
     multi-step (scanned) engines can never drift apart semantically.
     ``overlap`` wraps the loss so parameter cotangents flow through the
     plan's bucket tags (see :func:`make_train_step`).  ``dynamics_every``
@@ -276,6 +309,7 @@ def _step_body(loss_fn: LossFn, accum_steps: int, overlap=None,
         grads, metrics, new_mstate = accumulate_gradients(
             loss_fn, state.params, state.model_state, batch, r, accum_steps
         )
+        grads = separate_update(grads)
         with jax.named_scope("optimizer"):  # a name for the trace
             new_state = state.apply_gradients(grads).replace(
                 model_state=new_mstate)

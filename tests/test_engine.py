@@ -187,3 +187,104 @@ def test_multi_step_one_is_single(devices):
     )
     state, metrics = step(state, synthetic_batch(0), jax.random.PRNGKey(0))
     assert int(state.step) == 1 and np.isfinite(float(metrics["loss"]))
+
+
+def _plain_step(loss_fn, accum_steps):
+    """A train step as the engine's was before the update became a region
+    of its own: ``accumulate_gradients`` + ``state.apply_gradients``, no
+    barrier between them."""
+
+    def step(state, batch, rng):
+        r = jax.random.fold_in(rng, state.step)
+        grads, metrics, mstate = accumulate_gradients(
+            loss_fn, state.params, state.model_state, batch, r, accum_steps)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads).replace(
+                model_state=mstate)
+        return new_state, metrics
+
+    return step
+
+
+@pytest.mark.parametrize(
+    "tx,ndev,accum_steps,steps_per_call,zero",
+    [
+        (optax.adamw(1e-3, weight_decay=0.1), 1, 1, 1, False),
+        (optax.adamw(1e-3, weight_decay=0.1), 1, 2, 1, False),
+        (optax.adamw(1e-3, weight_decay=0.1), 1, 1, 2, False),
+        (optax.adamw(1e-3, weight_decay=0.1), 2, 1, 1, True),
+        (optax.sgd(0.1, momentum=0.9), 1, 1, 1, False),
+    ],
+    ids=["adamw", "accum2", "multi_step2", "zero_2dev", "sgd_momentum"],
+)
+def test_update_is_a_region_of_its_own_and_the_identity(
+        devices, tx, ndev, accum_steps, steps_per_call, zero):
+    """The gradients pass one ``optimization_barrier``, all leaves of the
+    tree together, between the backward and the ``optimizer`` scope
+    (``engine.separate_update``), and the barrier is the identity:
+    parameters, optimizer state and the logged loss equal, bit for bit,
+    those of a step with no barrier."""
+    from jax import lax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributedtensorflow_tpu.parallel import sharding as shardlib
+    from distributedtensorflow_tpu.parallel.zero import ZeroSharder
+    from distributedtensorflow_tpu.train import engine, make_multi_train_step
+
+    mesh = build_mesh(MeshSpec(data=ndev), devices[:ndev])
+    model = LeNet5()
+    state0, specs = create_sharded_state(
+        lambda r: model.init(r, jnp.zeros((1, 28, 28, 1))), tx, mesh,
+        jax.random.PRNGKey(0), zero=ZeroSharder(mesh) if zero else None)
+    loss_fn = classification_loss(model)
+    rng = jax.random.PRNGKey(3)
+    leaves = len(jax.tree.leaves(state0.params))
+
+    step = make_multi_train_step(
+        loss_fn, mesh, specs, steps_per_call=steps_per_call,
+        accum_steps=accum_steps, donate=False)
+    plain = _plain_step(loss_fn, accum_steps)
+    if steps_per_call > 1:
+        one = plain
+        plain = lambda s, bs, r: lax.scan(  # noqa: E731
+            lambda c, b: one(c, b, r), s, bs)
+    shardings = shardlib.named_shardings(mesh, specs)
+    repl = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, shardlib.batch_spec(
+        mesh, leading_unsharded=int(steps_per_call > 1)))
+    plain = jax.jit(plain, in_shardings=(shardings, rows, repl),
+                    out_shardings=(shardings, repl))
+
+    def batch(call):
+        if steps_per_call == 1:
+            return synthetic_batch(call)
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *(
+            synthetic_batch(call * steps_per_call + i)
+            for i in range(steps_per_call)))
+
+    state_a = state_b = state0
+    for call in range(-(-3 // steps_per_call)):  # three steps or more
+        state_a, m_a = step(state_a, batch(call), rng)
+        state_b, m_b = plain(state_b, batch(call), rng)
+        np.testing.assert_array_equal(
+            np.asarray(m_a["loss"]), np.asarray(m_b["loss"]))
+    assert int(state_a.step) == int(state_b.step) >= 3
+    for a, b in zip(
+            jax.tree.leaves((state_a.params, state_a.opt_state)),
+            jax.tree.leaves((state_b.params, state_b.opt_state)),
+            strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # one barrier in the lowered step, over every gradient leaf ...
+    text = step.lower(state0, batch(0), rng).as_text()
+    assert text.count("optimization_barrier") == 1
+    # ... and it stands where the backward ends and the update begins
+    with jax.sharding.set_mesh(mesh):
+        eqns = jax.make_jaxpr(engine._step_body(loss_fn, accum_steps))(
+            state0, synthetic_batch(0), rng).eqns
+    (at,) = [i for i, e in enumerate(eqns)
+             if e.primitive.name == "optimization_barrier"]
+    assert len(eqns[at].invars) == leaves
+    scoped = ["optimizer" in str(e.source_info.name_stack) for e in eqns]
+    assert not any(scoped[:at]) and all(scoped[at + 1:])
+    assert engine.optimizer_update(state0.params) == ("separate", leaves)

@@ -876,3 +876,14 @@ def test_gpt2_medium_step_runs_flash_fwd_once_a_layer_and_fits_a_v5e(
             ) == (4, 4096)
     assert {k: n for k, n in row["kernels"].items() if "xent" in k} == {
         "fused_xent_fwd": 2, "fused_xent_bwd_dx": 1, "fused_xent_bwd_dw": 1}
+    # the update is a region of the step of its own
+    # (``train.engine.separate_update``, PR 49): no fusion of the optimized
+    # module holds both a product and an op of scope ``optimizer`` (96 did,
+    # ``qkv``, ``proj``, ``fc_in`` and ``fc_out`` of 24 layers, and ran a
+    # fifth slower than product and update apart)
+    fusions = re.findall(r"^%?fused_computation[\w.]* [^\n]*\{\n(.*?)^\}",
+                         compiled.as_text(), re.S | re.M)
+    products = [f for f in fusions if " convolution(" in f]
+    updates = [f for f in fusions if "/optimizer/" in f]
+    assert len(products) >= 4 * layers and len(updates) >= 4 * layers
+    assert not [f for f in products if "/optimizer/" in f]

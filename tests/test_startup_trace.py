@@ -93,6 +93,37 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []
 
 
+def test_trainer_row_says_the_update_is_separate(tmp_path, monkeypatch,
+                                                 own_registry):
+    """``gpt_medium_lm`` at test size through ``train.main()``: the
+    ``startup.trainer`` row carries ``optimizer_update`` ("separate": the
+    step's gradients pass a barrier before ``apply_gradients``,
+    ``train.engine.separate_update``) and ``optimizer_update_leaves`` (one
+    a parameter leaf) beside ``flash_layout``, and the schema checker takes
+    the row."""
+    import jax
+    import train
+
+    from distributedtensorflow_tpu.workloads import get_workload
+
+    logdir = tmp_path / "run"
+    monkeypatch.setattr(sys, "argv", [
+        "train.py", "--workload", "gpt_medium_lm", "--test-size", "--steps",
+        "2", "--log-every", "1", "--batch-size", "4", "--device", "cpu",
+        "--mesh", "data=1", "--logdir", str(logdir)])
+    train.main()
+    (row,) = [r for r in startup_rows(logdir / "trace.jsonl")
+              if r["name"] == "startup.trainer"]
+    wl = get_workload("gpt_medium_lm", test_size=True)
+    leaves = len(jax.tree.leaves(
+        jax.eval_shape(wl.init_fn, jax.random.PRNGKey(0))["params"]))
+    assert leaves > 1
+    assert (row["optimizer_update"], row["optimizer_update_leaves"]) == (
+        "separate", leaves)
+    assert row["flash_layout"] == "xla"  # this CPU, 64 positions
+    assert checker.check_file(str(logdir / "trace.jsonl")) == ([], [])
+
+
 @pytest.mark.parametrize("changes, seq, want", [
     pytest.param(dict(remat=True, attn_impl="pallas"), 64,
                  ("qkv_tiles", "saved", 4 * 64 * (128 * 2 + 4 * 4),

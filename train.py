@@ -599,6 +599,21 @@ def _flash_layout(wl, mesh) -> dict:
             "xent_dlog_chunk_tokens": chunk}
 
 
+def _optimizer_update(params) -> dict:
+    """``optimizer_update`` and ``optimizer_update_leaves`` for the
+    ``startup.trainer`` row and the start-up log, beside ``flash_layout``:
+    how the step's update stands to the backward that feeds it
+    (``train.engine.optimizer_update``: "separate" — the gradients pass a
+    ``lax.optimization_barrier`` before ``apply_gradients``, so the update
+    is a region of its own and cannot fuse into the weight-gradient
+    products — with the number of gradient leaves behind it)."""
+    from distributedtensorflow_tpu.train import engine
+
+    update, leaves = engine.optimizer_update(params)
+    logging.info("optimizer_update: %s (%d gradient leaves)", update, leaves)
+    return {"optimizer_update": update, "optimizer_update_leaves": leaves}
+
+
 def main() -> None:
     # allow_abbrev=False: apply_config_file detects explicitly-typed flags
     # by matching argv against option strings; prefix abbreviations would
@@ -1537,7 +1552,8 @@ def main() -> None:
     )
     # the input-plane services, the restore, the trainer with its metric
     # writer (TensorBoard import) and status server
-    startup.mark("startup.trainer", **_flash_layout(wl, mesh))
+    startup.mark("startup.trainer", **_flash_layout(wl, mesh),
+                 **_optimizer_update(state.params))
     if dynamics_monitor is not None and trainer.status_server is not None:
         dynamics_monitor.install(trainer.status_server)
     if elastic is not None:
