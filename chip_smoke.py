@@ -239,7 +239,9 @@ def train_leg(name: str, out: str, args: list[str], *, steps: int,
           "failed to write beside the chip?)")
     result = {
         "wall_s": wall,
-        "compile_s": rows[-1]["engine_first_dispatch_s.kind_train_step"],
+        # the first window's row: what JAX traced, lowered and compiled
+        # (or loaded) inside its steps, by the program's own compile log
+        "compile_s": rows[0]["compile_s"],
         "steady_step_s": rows[-1]["t_step"],
         "losses": {r["step"]: r["loss"] for r in rows},
         **cache_counts(log),

@@ -114,8 +114,10 @@ from .tracing import (  # noqa: F401
     TraceRecorder,
     active_recorder,
     current_context,
+    install_compile_log,
     new_trace_id,
     record_remote_span,
     remote_span,
     span,
+    take_compiled,
 )

@@ -16,8 +16,9 @@ bucket               meaning
 ==================== =======================================================
 ``init``             process setup: mesh build, state creation, everything
                      before the fit loop that no span claims
-``compile``          XLA compilation (the engine's first-dispatch
-                     ``compile_*`` spans, wherever they nest)
+``compile``          what JAX traced, lowered, compiled or loaded from its
+                     cache (the compile log's ``compile.*`` spans,
+                     ``obs.tracing``, wherever they nest)
 ``train_step``       productive training: step dispatch + the host metric
                      fetch that syncs it (device is computing either way)
 ``data_wait``        the fit loop blocking on the input pipeline
@@ -43,7 +44,7 @@ Accounting model — no new timers on the hot path:
 - **Spans feed the buckets.**  Completed *root* spans are forwarded here by
   ``tracing`` (:func:`tracing.add_root_sink`) whether or not a
   ``TraceRecorder`` is installed, so pre-fit spans (``checkpoint_restore``,
-  the ``--estimate-flops`` AOT compile) are captured too.  ``compile_*``
+  the ``--estimate-flops`` AOT compile) are captured too.  ``compile*``
   child spans are carved out of their parent's bucket.
 - **Flight events feed the markers.**  ``FlightRecorder.record`` forwards
   every event kind here: a ``preemption`` event stamps the drain window,
@@ -311,7 +312,7 @@ class GoodputLedger:
 
     def observe_span(self, span) -> None:
         """Root-span sink: attribute a completed span tree to its bucket,
-        carving ``compile_*`` descendants out into ``compile``."""
+        carving ``compile*`` descendants out into ``compile``."""
         name = span.name
         bucket = _SPAN_BUCKETS.get(name)
         if bucket is None and name.startswith("compile"):

@@ -58,6 +58,20 @@ its back-to-back phases (``startup.imports``, ``startup.backend``, ... —
 docs/OBSERVABILITY.md has the list) as ``kind: "span"`` rows under the
 one ``trace_id`` ``"startup"``, so what happens before the first step has
 names and absolute times.
+
+What JAX traces, lowers, compiles or loads from its cache is a row too
+(:func:`install_compile_log`): ``compile.trace`` | ``compile.lower`` |
+``compile.backend`` | ``compile.cache_load`` with the ``program`` (JAX's
+``fun_name``), absolute ``t0`` and, on ``compile.backend``, ``cache``
+(``"hit"`` | ``"miss"`` | ``"off"``) and ``cache_load_s``.  An event that
+begins inside another on the same thread (a jitted helper traced inside
+``jit_decode``) is its child (``parent_id``); sums and counters count
+roots only.  Until the start-up trace says :meth:`PhaseTrace.ready` the
+roots are children of the phase they ended in (``trace_id``
+``"startup"``), after it they carry ``trace_id`` ``"compile"``; either
+way the span open on the thread gets them as ``compile.*`` children, and
+:func:`take_compiled` hands the iteration they happened in their seconds
+and names.
 """
 
 from __future__ import annotations
@@ -83,6 +97,9 @@ __all__ = [
     "record_remote_span",
     "remote_span",
     "PhaseTrace",
+    "install_compile_log",
+    "uninstall_compile_log",
+    "take_compiled",
 ]
 
 _tls = threading.local()
@@ -572,6 +589,31 @@ class remote_span:
         return False
 
 
+#: The fields a phase row (and ``startup.ready``) sums its compile roots
+#: into: seconds of the roots of each name, the part of ``backend_s`` that
+#: was the persistent cache's read, and the ``compile.backend`` roots
+#: counted (programs compiled or loaded).
+_COMPILE_SUMS = ("trace_s", "lower_s", "backend_s", "cache_load_s",
+                 "programs")
+
+
+def _add_compile_root(sums: dict[str, Any], row: dict[str, Any]) -> None:
+    """Add one root row of the compile log to ``sums``."""
+    phase = row["name"][len("compile."):]
+    sums[phase + "_s"] = sums.get(phase + "_s", 0.0) + row["dur_s"]
+    if phase == "backend":
+        sums["programs"] = sums.get("programs", 0) + 1
+        sums["cache_load_s"] = sums.get("cache_load_s", 0.0) \
+            + row["cache_load_s"]
+        if row["cache"] != "off":
+            key = "cache_hits" if row["cache"] == "hit" else "cache_misses"
+            sums[key] = sums.get(key, 0) + 1
+
+
+def _rounded_sums(sums: dict[str, Any], keys=_COMPILE_SUMS) -> dict[str, Any]:
+    return {k: round(sums.get(k, 0), 6) for k in keys}
+
+
 class PhaseTrace:
     """Back-to-back phases of one stage of a process (its start-up) as
     ``kind: "span"`` rows under one ``trace_id``.
@@ -582,34 +624,291 @@ class PhaseTrace:
     made with ``parent=name`` until ``close(name)`` writes its row.  Rows
     made while no recorder is installed (imports and the backend come
     before a recorder can exist) wait and are written at the next call.
+
+    A trace that owns the compile log (``install_compile_log(phases=
+    trace)``) holds the log's rows until the stretch they ended in gets
+    its name: they are written just before that phase's row, the roots as
+    its children, and the row carries their sums (``trace_s``, ``lower_s``,
+    ``backend_s``, ``cache_load_s``, ``programs``), as every open phase
+    around it does.  ``ready()`` ends the trace with the summary row
+    ``startup.ready`` and gives the compile log back.  Marks may come from
+    another thread than the one that opened the trace (the engine's, the
+    trainer's), one thread at a time; the compile log's rows from any.
     """
 
     def __init__(self, trace_id: str, t0: float | None = None):
         self.trace_id = trace_id
-        self._t = time.time() if t0 is None else t0
-        self._open: dict[str, tuple[str, float]] = {}  # name -> (id, t0)
+        self._t0 = self._t = time.time() if t0 is None else t0
+        #: name -> [span id, t0, compile sums of the rows written inside]
+        self._open: dict[str, list] = {}
         self._pending: list[dict[str, Any]] = []
+        self._lock = threading.Lock()
+        self._compiles: list[dict[str, Any]] | None = None
+        self._total: dict[str, Any] = {}
+        self._top_s = 0.0
 
     def mark(self, name: str, *, parent: str | None = None,
              **fields: Any) -> None:
         if parent is not None:
             fields["parent_id"] = self._open[parent][0]
-        self._row(name, self._t, **fields)
+        self._row(name, self._t, new_span_id(), **fields)
 
     def open(self, name: str) -> None:
-        self._open[name] = (new_span_id(), self._t)
+        self._open[name] = [new_span_id(), self._t, {}]
 
     def close(self, name: str, **fields: Any) -> None:
-        span_id, t0 = self._open.pop(name)
-        self._row(name, t0, span_id=span_id, **fields)
+        span_id, t0, sums = self._open.pop(name)
+        self._row(name, t0, span_id, sums, **fields)
 
-    def _row(self, name: str, t0: float, **fields: Any) -> None:
+    def ready(self) -> None:
+        """Start-up ended with the last row: one summary row
+        ``startup.ready`` from the trace's ``t0`` to there.  ``total_s`` is
+        what the process spent on the way, the compile sums are the whole
+        trace's, and ``unnamed_s`` is ``total_s`` less the top-level
+        phases: they tile it, so 0 up to rounding."""
+        _release_compile_log(self)
+        total = self._t - self._t0
+        self._row_out(dict(
+            name=self.trace_id + ".ready", t0=self._t0, dur_s=total,
+            trace_id=self.trace_id, total_s=round(total, 6),
+            **_rounded_sums(self._total, _COMPILE_SUMS + (
+                "cache_hits", "cache_misses")),
+            unnamed_s=round(max(total - self._top_s, 0.0), 6)))
+
+    def _follow_compiles(self, row: dict[str, Any]) -> None:
+        """The compile log's next row, to wait for its phase's name."""
+        with self._lock:
+            self._compiles.append(row)
+
+    def _row(self, name: str, t0: float, span_id: str,
+             sums: dict[str, Any] | None = None, **fields: Any) -> None:
         """The phase from ``t0`` to now; the clock moves to now."""
         self._t = time.time()
-        self._pending.append(dict(
+        rows: list[dict[str, Any]] = []
+        if self._compiles is not None:
+            with self._lock:
+                rows, self._compiles = self._compiles, []
+            inside: dict[str, Any] = {}
+            for row in rows:
+                if "parent_id" not in row:     # a root: this phase's child
+                    row["parent_id"] = span_id
+                    _add_compile_root(inside, row)
+                row["trace_id"] = self.trace_id
+            for acc in [self._total, *(o[2] for o in self._open.values())]:
+                for k, v in inside.items():
+                    acc[k] = acc.get(k, 0) + v
+            if sums is not None:
+                for k, v in sums.items():
+                    inside[k] = inside.get(k, 0) + v
+            fields.update(_rounded_sums(inside))
+        if "parent_id" not in fields:
+            self._top_s += self._t - t0
+        self._row_out(*rows, dict(
             name=name, t0=t0, dur_s=self._t - t0, trace_id=self.trace_id,
-            **fields))
+            span_id=span_id, **fields))
+
+    def _row_out(self, *rows: dict[str, Any]) -> None:
+        self._pending += rows
         if _recorder is not None:
             pending, self._pending = self._pending, []
             for row in pending:
                 record_remote_span(**row)
+
+
+# -- the compile log ---------------------------------------------------------
+
+#: ``jax.monitoring``'s time spans, by the row they become.  JAX says when
+#: each begins (a scalar event, with the ``fun_name``) and when it ended
+#: (the time span), on the thread that did the work.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: Inside a ``backend`` span: the persistent cache's read (a duration, and
+#: only on a hit) and what the cache said.
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+#: A child shorter than this is left out of ``trace.jsonl``: every
+#: ``jnp`` call of a traced function is a trace event of its own, a few
+#: thousand a program and well under a millisecond each, and they explain
+#: nothing their root does not.  A lowering, a compilation or a cache read
+#: inside another event is always written.
+COMPILE_CHILD_MIN_S = 0.01
+
+_compile_tls = threading.local()
+
+
+class _CompileLog:
+    """The listeners' state: who gets the rows, and what compiled since
+    the last :func:`take_compiled`.  A thread's open spans are a stack of
+    ``[span id (drawn when a row needs it), program, cache, seconds of
+    cache read]``."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        #: the start-up trace that holds the rows until ``ready()``
+        self.phases: PhaseTrace | None = None
+        #: ``[seconds, names]`` of the roots ended since the last take
+        self.since: list | None = None
+
+    def on_open(self, event: str, value: float, **kw: Any) -> None:
+        if event in _COMPILE_SPANS:
+            stack = getattr(_compile_tls, "stack", None)
+            if stack is None:
+                stack = _compile_tls.stack = []
+            stack.append([None, str(kw.get("fun_name", "")), "off", 0.0])
+
+    def on_event(self, event: str, **kw: Any) -> None:
+        cache = _CACHE_EVENTS.get(event)
+        stack = getattr(_compile_tls, "stack", None)
+        if cache is not None and stack:
+            stack[-1][2] = cache
+
+    def on_duration(self, event: str, secs: float, **kw: Any) -> None:
+        stack = getattr(_compile_tls, "stack", None)
+        if event != _CACHE_LOAD or not stack:
+            return
+        top = stack[-1]
+        top[0] = top[0] or new_span_id()
+        top[3] += secs
+        self.write(dict(name="compile.cache_load", program=top[1],
+                        t0=time.time() - secs, dur_s=secs,
+                        span_id=new_span_id(), parent_id=top[0]))
+
+    def on_span(self, event: str, t0: float, t1: float, **kw: Any) -> None:
+        phase = _COMPILE_SPANS.get(event)
+        if phase is None:
+            return
+        stack = getattr(_compile_tls, "stack", None)
+        # (a span that was open when the listeners came has no entry)
+        span_id, program, cache, load_s = stack.pop() if stack else (
+            None, str(kw.get("fun_name", "")), "off", 0.0)
+        dur = max(t1 - t0, 0.0)
+        row: dict[str, Any] = dict(name="compile." + phase, program=program,
+                                   t0=t0, dur_s=dur)
+        if phase == "backend":
+            row["cache"] = cache
+            row["cache_load_s"] = round(load_s, 6)
+        if stack:
+            if phase == "trace" and dur < COMPILE_CHILD_MIN_S \
+                    and span_id is None:
+                return
+            parent = stack[-1]
+            parent[0] = parent[0] or new_span_id()
+            self.write(dict(row, span_id=span_id or new_span_id(),
+                            parent_id=parent[0]))
+            return
+        self.write(dict(row, span_id=span_id or new_span_id()))
+        self.count_root(row)
+
+    def write(self, row: dict[str, Any]) -> None:
+        phases = self.phases
+        if phases is not None:
+            phases._follow_compiles(row)
+        else:
+            record_remote_span(trace_id="compile", **row)
+
+    def count_root(self, row: dict[str, Any]) -> None:
+        """Sums count roots only: the registry's two counters, the
+        iteration's account, and a ``compile.*`` child of the span open on
+        this thread (a root span where none is: the goodput ledger books
+        either as ``compile``)."""
+        from .registry import counter  # noqa: PLC0415
+
+        name, program, dur = row["name"], row["program"], row["dur_s"]
+        seconds = counter(
+            "jit_compile_seconds_total",
+            "seconds of the compile log's roots by phase (trace, lower, "
+            "backend; cache_load is the part of backend that read the "
+            "persistent cache)")
+        seconds.inc(dur, phase=name[len("compile."):])
+        if name == "compile.backend":
+            seconds.inc(row["cache_load_s"], phase="cache_load")
+            counter(
+                "jit_compiles_total",
+                "programs compiled or loaded (compile.backend roots) by "
+                "program and what the persistent cache said",
+            ).inc(program=program, cache=row["cache"])
+        # the function's own name, as the trace has it: not "jit(f)"
+        if program.endswith(")"):
+            program = program[program.find("(") + 1:-1]
+        with self.lock:
+            since = self.since
+            if since is None:
+                since = self.since = [0.0, []]
+            since[0] += dur
+            if program not in since[1]:
+                since[1].append(program)
+        s = Span(name)
+        s.dur_s = dur
+        _completed(getattr(_tls, "stack", None) or [], s)
+
+
+_compile_log: _CompileLog | None = None
+
+
+def install_compile_log(phases: PhaseTrace | None = None) -> None:
+    """Listen to what JAX traces, lowers, compiles and loads, once a
+    process: a second call registers nothing.  ``phases`` is the start-up
+    trace that takes the rows from here until its ``ready()``."""
+    global _compile_log
+    with _recorder_lock:
+        log = _compile_log
+        if log is None:
+            import jax.monitoring as monitoring  # noqa: PLC0415
+
+            log = _compile_log = _CompileLog()
+            monitoring.register_scalar_listener(log.on_open)
+            monitoring.register_event_listener(log.on_event)
+            monitoring.register_event_duration_secs_listener(log.on_duration)
+            monitoring.register_event_time_span_listener(log.on_span)
+    if phases is not None:
+        phases._compiles = []
+        log.phases = phases
+
+
+def uninstall_compile_log() -> None:
+    """Stop listening (tests)."""
+    global _compile_log
+    with _recorder_lock:
+        log, _compile_log = _compile_log, None
+    if log is not None:
+        import jax.monitoring as monitoring  # noqa: PLC0415
+
+        monitoring.unregister_scalar_listener(log.on_open)
+        monitoring.unregister_event_listener(log.on_event)
+        monitoring.unregister_event_duration_listener(log.on_duration)
+        monitoring.unregister_event_time_span_listener(log.on_span)
+
+
+def _release_compile_log(phases: PhaseTrace) -> None:
+    """``phases`` is ready: the log's rows go straight to the recorder
+    from here on, those ``phases`` still holds first."""
+    log = _compile_log
+    if log is not None and log.phases is phases:
+        log.phases = None
+    if phases._compiles:
+        with phases._lock:
+            rows, phases._compiles = phases._compiles, []
+        for row in rows:
+            record_remote_span(trace_id="compile", **row)
+
+
+#: What an iteration with no compilation in it takes.
+_NOTHING_COMPILED = (0.0, "")
+
+
+def take_compiled() -> tuple[float, str]:
+    """``(seconds, names)`` of the compile log's roots that ended since the
+    last call, the programs' names joined by commas; ``(0.0, "")``, at the
+    cost of one attribute read, where nothing did (or nothing listens)."""
+    log = _compile_log
+    if log is None or log.since is None:
+        return _NOTHING_COMPILED
+    with log.lock:
+        (seconds, names), log.since = log.since, None
+    return round(seconds, 6), ",".join(names)

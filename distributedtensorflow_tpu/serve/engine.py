@@ -482,6 +482,19 @@ class Engine:
         self._cpu_leaf0 = 0.0      # thread CPU where .fetch / .commit began
         self._recent_walls: collections.deque = collections.deque(
             maxlen=STALL_HISTORY)
+        # What JAX traced, lowered, compiled or loaded (the compile log,
+        # obs.tracing): an iteration takes what ended inside it into its
+        # step record, and ``state()`` keeps the account.
+        obs_tracing.install_compile_log()
+        self._compiles = {"count": 0, "seconds": 0.0, "last_program": None,
+                          "last_t": None}
+        #: The entry point's start-up phases (obs.PhaseTrace) with
+        #: ``startup.first_request`` open, or None: the engine names the
+        #: children below as they end, in this order, and the last ends
+        #: start-up.
+        self.startup_trace: obs_tracing.PhaseTrace | None = None
+        self._startup_next = ["startup.first_wait", "startup.first_chunk",
+                              "startup.first_decode"]
         #: lags of the lines the stream writer has written since the
         #: last record (``note_stream_line`` appends, ``engine.log``
         #: drains: no lock on either side)
@@ -876,6 +889,8 @@ class Engine:
             # nothing queued, filling or decoding: no iteration to name
             # (the gauges were set when the last request left)
             return False
+        if self.startup_trace is not None:
+            self._startup_mark("startup.first_wait")
         tokens0 = self.counters["decode_tokens"]
         drafted0 = self.counters["spec_drafted"]
         accepted0 = self.counters["spec_accepted"]
@@ -924,6 +939,11 @@ class Engine:
                 now = time.time()
                 # step_s: the work, not the log
                 step_s, walls = self._iteration_walls(root, s_log, cpu_now)
+                compile_s, compiled = obs_tracing.take_compiled()
+                walls["compile_s"] = compile_s
+                if compile_s:
+                    walls["compiled"] = compiled
+                    self._note_compiled(compile_s, compiled, now)
                 held = [
                     (r, self.kv.billed_blocks(i))
                     for i, r in enumerate(self._slots) if r is not None
@@ -935,20 +955,52 @@ class Engine:
                     self.counters["spec_accepted"] - accepted0,
                     sum(b for _, b in held),
                 )
-                self._note_stall(root, step_s + self._log_prev_s, now)
+                self._note_stall(root, step_s + self._log_prev_s, now,
+                                 compile_s)
                 self.usage.on_step(now, step_s, held, self._step_id)
                 if self.decode_steps % self.log_every == 0:
                     self._log_metrics_row()
         self._tiles = None
         if did:
             self._log_prev_s = s_log.dur_s
+            if occupancy and self.startup_trace is not None:
+                self._startup_mark("startup.first_decode")
         return did
+
+    def _startup_mark(self, name: str) -> None:
+        """Child ``name`` of ``startup.first_request`` ends here, if it has
+        not yet: ``startup.first_wait`` where the first iteration with work
+        begins, ``startup.first_chunk`` with the first request's first
+        token (its prefill program compiled, or loaded, and ran), and
+        ``startup.first_decode`` with the first iteration that decoded
+        (the one-token program likewise).  That one closes
+        ``startup.first_request`` and start-up: ``startup.ready``."""
+        if name not in self._startup_next:
+            return
+        startup = self.startup_trace
+        while True:     # (a trace handed over mid-request names all to here)
+            child = self._startup_next.pop(0)
+            startup.mark(child, parent="startup.first_request")
+            if child == name:
+                break
+        if not self._startup_next:
+            self.startup_trace = None
+            startup.close("startup.first_request", step=self._step_id)
+            startup.ready()
+
+    def _note_compiled(self, seconds: float, names: str, now: float) -> None:
+        c = self._compiles
+        c["count"] += names.count(",") + 1
+        c["seconds"] = round(c["seconds"] + seconds, 6)
+        c["last_program"] = names.rsplit(",", 1)[-1]
+        c["last_t"] = now
 
     def _adopt_thread(self, tid: int, t0: float) -> None:
         """Another thread runs ``step()`` from here on (the loop thread
         after a synchronous warm-up, a test's): the account between two
         records starts anew at ``t0``, this iteration's start."""
         self._tid = tid
+        obs_tracing.take_compiled()    # what compiled before is not its
         self._mark_wall = t0
         self._mark_cpu = time.thread_time()
         self._mark_gc = _gc_seconds.setdefault(tid, 0.0)
@@ -1018,11 +1070,14 @@ class Engine:
         self._wait_s = self._cpu_blocked = 0.0
         return step_s, walls
 
-    def _note_stall(self, root, wall_s: float, now: float) -> None:
+    def _note_stall(self, root, wall_s: float, now: float,
+                    compile_s: float) -> None:
         """One ``engine_stall`` row in ``trace.jsonl`` for an iteration
         whose wall (with the ``engine.log`` before it) is far above the
-        recent iterations': the step id, the record's ``t`` and the span
-        tree, so the stall is found without scanning ``steps.jsonl``."""
+        recent iterations': the step id, the record's ``t``, the span
+        tree and ``compile_s``, the seconds of it JAX spent on a program
+        (the tree's ``compile.*`` spans), so the stall is found without
+        scanning ``steps.jsonl``."""
         recent = self._recent_walls
         if wall_s > STALL_MIN_S and len(recent) >= STALL_MIN_HISTORY:
             median = statistics.median(recent)
@@ -1039,6 +1094,7 @@ class Engine:
                         f"median of the last {len(recent)}"),
                     "median_s": round(median, 6),
                     "log_prev_s": round(self._log_prev_s, 6),
+                    "compile_s": compile_s,
                     "spans": [tree],
                 })
         recent.append(wall_s)
@@ -1389,6 +1445,8 @@ class Engine:
         self._cpu_blocked += time.thread_time() - cpu0
         req.t_first_token = time.time()
         req._t_last_token = req.t_first_token
+        if self.startup_trace is not None:
+            self._startup_mark("startup.first_chunk")
         # ... and the tail of this request's prefill compute in the
         # attribution ledger
         req.attr_prefill_s += max(req.t_first_token - req._t_attr, 0.0)
@@ -1984,6 +2042,9 @@ class Engine:
             "prefill_budget_stalls": self.prefill_budget_stalls,
             "steps_total": self._step_id,
             "step_ring_size": self.step_ring_size,
+            # programs JAX compiled or loaded inside an iteration: how
+            # many, their seconds, the last one's name and when
+            "compiles": dict(self._compiles),
             "kv": self.kv.stats(),
             "counters": dict(self.counters),
             "prefill_chunk": self.prefill_chunk,

@@ -155,8 +155,9 @@ def optimizer_update(params: PyTree) -> tuple[str, int]:
 class _InstrumentedStep:
     """Thin telemetry shim over a jitted step executable.
 
-    Counts dispatches into the obs registry and records the first dispatch
-    (which pays tracing + XLA compile) as a gauge — without touching the
+    Counts dispatches into the obs registry and brackets the first dispatch
+    (which pays tracing + XLA compile: the compile log's rows,
+    ``obs.tracing``) with two flight events — without touching the
     per-dispatch hot path beyond one counter increment.  ``lower`` is
     forwarded so the AOT path (``step.lower(...).compile()``, as
     ``tools/train_step_memory.py`` calls it)
@@ -168,8 +169,7 @@ class _InstrumentedStep:
     GSPMD cannot partition a Mosaic call on its own.
     """
 
-    __slots__ = ("_jitted", "_mesh", "_label", "_first", "_dispatches",
-                 "_first_gauge")
+    __slots__ = ("_jitted", "_mesh", "_label", "_first", "_dispatches")
 
     def __init__(self, jitted, mesh: Mesh, label: str):
         self._jitted = jitted
@@ -179,10 +179,6 @@ class _InstrumentedStep:
         self._dispatches = obs.counter(
             "engine_dispatches_total",
             "train/eval step dispatches by executable kind",
-        )
-        self._first_gauge = obs.gauge(
-            "engine_first_dispatch_s",
-            "wall seconds of the first dispatch (trace + XLA compile + run)",
         )
 
     def __call__(self, *args):
@@ -198,13 +194,11 @@ class _InstrumentedStep:
             # post-mortem signature — so the begin marker must land BEFORE
             # the potentially-wedging call.
             obs.record_event("compile_begin", label=self._label)
-            with obs.span(f"compile_{self._label}"):
-                t0 = time.perf_counter()
-                out = self._jitted(*args)
-                dur = time.perf_counter() - t0
-                self._first_gauge.set(dur, kind=self._label)
+            t0 = time.perf_counter()
+            out = self._jitted(*args)
             obs.record_event(
-                "compile", label=self._label, seconds=round(dur, 3)
+                "compile", label=self._label,
+                seconds=round(time.perf_counter() - t0, 3),
             )
             self._dispatches.inc(kind=self._label)
             return out
@@ -234,10 +228,10 @@ def estimate_step_flops(step, state, batch_abstract, rng) -> float | None:
     Costs one extra compile — the persistent compilation cache absorbs it
     on reruns.
     """
-    # Span name keeps this AOT compile in the goodput `compile` bucket
-    # (it runs pre-fit, where unattributed time would read as `init`).
-    with obs.span("compile_cost_estimate"):
-        compiled = step.lower(state, batch_abstract, rng).compile()
+    # (the compile log's root spans keep this AOT compile in the goodput
+    # `compile` bucket: it runs pre-fit, where unattributed time would
+    # read as `init`)
+    compiled = step.lower(state, batch_abstract, rng).compile()
     return obs.mfu.xla_cost_flops(compiled)
 
 

@@ -228,9 +228,12 @@ class Trainer:
         self.meter = ThroughputMeter(config.global_batch_size)
         #: The entry point's start-up phases (obs.PhaseTrace) with
         #: ``startup.first_step`` open, or None: the first dispatch of the
-        #: first fit names its batch wait and compile-or-load, waits for
-        #: the step once, and closes it.
+        #: first fit names its batch wait, its compile-or-load and the
+        #: step's run, waited for once, closes it, and ends start-up
+        #: (``startup.ready``).
         self.startup_trace: obs.PhaseTrace | None = None
+        # a logged row says what JAX compiled since the row before it
+        obs.install_compile_log()
         #: Span recorder for the current fit (obs.TraceRecorder); feeds the
         #: step-time breakdown and writes <logdir>/trace.jsonl.
         self.tracer: obs.TraceRecorder | None = None
@@ -349,6 +352,7 @@ class Trainer:
         self.meter.start()
         self._window_t0 = time.perf_counter()
         self._window_step0 = int(state.step)
+        obs.take_compiled()     # what compiled before the fit is not a row's
         self._last_step = int(state.step)
         self._fit_t0 = time.time()
         if self.flight is not None:
@@ -665,7 +669,10 @@ class Trainer:
                     startup.mark("startup.compile_or_load",
                                  parent="startup.first_step")
                     jax.block_until_ready(metrics)
+                    startup.mark("startup.first_step_run",
+                                 parent="startup.first_step")
                     startup.close("startup.first_step", step=step_next)
+                    startup.ready()
                 if k > 1:  # stacked (k_eff, ...) metrics; report the last
                     metrics = jax.tree.map(lambda v: v[-1], metrics)
                 self.meter.update(k_eff)
@@ -707,6 +714,10 @@ class Trainer:
                     obs.memory.update_registry(snapshot=mem_snap)
                     breakdown = self._window_breakdown(step_next)
                     last_metrics.update(breakdown)
+                    compile_s, compiled = obs.take_compiled()
+                    last_metrics["compile_s"] = compile_s
+                    if compile_s:
+                        last_metrics["compiled"] = compiled
                     if jax.process_count() > 1:
                         # Every host reaches this branch, so the allgather
                         # is globally consistent; chief-only would hang it.

@@ -44,6 +44,20 @@ def json_sanitize(value: Any) -> Any:
     return value
 
 
+def import_tensorflow(logdir: str):
+    """TensorFlow, whose event writer is the TensorBoard sink of a
+    :class:`MetricWriter` on ``logdir``, or None where it is not installed.
+    The first import is seconds of a process's start-up: an entry point
+    that names its phases makes it here, under a name of its own."""
+    try:
+        import tensorflow as tf  # noqa: PLC0415
+    except ImportError:  # no TF installed -> JSONL only
+        logger.info("tensorflow not importable: %s gets metrics.jsonl "
+                    "only, no TensorBoard events", logdir)
+        return None
+    return tf
+
+
 class MetricWriter:
     """Writes scalars; only the chief process actually emits (SURVEY.md §5.5)."""
 
@@ -56,13 +70,8 @@ class MetricWriter:
             return
         os.makedirs(logdir, exist_ok=True)
         if use_tensorboard:
-            try:
-                import tensorflow as tf  # noqa: PLC0415
-            except ImportError:  # no TF installed -> JSONL only, said once
-                logger.info("tensorflow not importable: %s gets "
-                            "metrics.jsonl only, no TensorBoard events",
-                            logdir)
-            else:
+            tf = import_tensorflow(logdir)
+            if tf is not None:
                 # a TF that imports but cannot write is an error, not a
                 # quiet downgrade
                 self._tb = tf.summary.create_file_writer(logdir)

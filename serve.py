@@ -251,12 +251,19 @@ def main(argv=None) -> int:
         CaptureEngine,
         install_engine,
     )
-    from distributedtensorflow_tpu.obs.tracing import PhaseTrace, TraceRecorder
+    from distributedtensorflow_tpu.obs.tracing import (
+        PhaseTrace,
+        TraceRecorder,
+        install_compile_log,
+    )
     from distributedtensorflow_tpu.serve import Engine, ServeServer
 
     # Start-up as spans with absolute time (trace_id "startup" in
-    # <logdir>/trace.jsonl): each mark names the stretch since the last.
+    # <logdir>/trace.jsonl): each mark names the stretch since the last,
+    # and what JAX traces, lowers, compiles or loads inside it is its
+    # child.  It ends in the engine, with the first decode step.
     startup = PhaseTrace("startup", T_PROCESS_START)
+    install_compile_log(startup)
     startup.mark("startup.imports")
     runtime.init_compile_cache()
     device = runtime.device_summary()  # also initialises the backend
@@ -420,6 +427,8 @@ def main(argv=None) -> int:
         "device": device,
     }), flush=True)
     startup.mark("startup.listen", port=server.port)
+    startup.open("startup.first_request")
+    engine.startup_trace = startup
     logging.info(
         "serving %s on %s:%d (slots=%d queue=%d block=%d prefix_cache=%s "
         "prefill_budget=%s fused_sampling=%s speculate=%d)",

@@ -587,4 +587,10 @@ def test_trainer_real_model_end_to_end(tmp_path, dp_mesh):
     assert math.isfinite(row["loss"])
     assert row["t_dispatch"] > 0
     assert row["engine_dispatches_total.kind_train_step"] >= 2
-    assert row["engine_first_dispatch_s.kind_train_step"] > 0
+    # the first dispatch's tracing and compilation: the compile log's
+    # roots, by phase and by program (ISSUE 50; they took the place of
+    # the gauge engine_first_dispatch_s), and the row that held them
+    assert row["jit_compile_seconds_total.phase_trace"] > 0
+    assert row["jit_compile_seconds_total.phase_backend"] > 0
+    assert row["jit_compiles_total.cache_off.program_jit_step_"] >= 1
+    assert row["compile_s"] > 0 and "step" in row["compiled"].split(",")

@@ -30,13 +30,35 @@ def startup_rows(path):
             and r["name"].startswith("startup.")]
 
 
+#: The children that tile ``startup.trainer`` and ``startup.first_step``
+#: (ISSUE 50), in order.
+TRAIN_CHILDREN = {
+    "startup.trainer": [
+        "startup.trainer.services", "startup.trainer.tensorflow_import",
+        "startup.trainer.construct"],
+    "startup.first_step": [
+        "startup.first_batch", "startup.compile_or_load",
+        "startup.first_step_run"],
+}
+COMPILE_SUMS = ("trace_s", "lower_s", "backend_s", "cache_load_s",
+                "programs")
+
+
 def assert_tiles(rows, phases, max_unnamed_s=0.05):
     """Top-level start-up rows are exactly ``phases``, in order, each
-    starting where the one before ends."""
+    starting where the one before ends, and ``startup.ready``, the summary
+    of them all, last."""
     assert {r["trace_id"] for r in rows} == {"startup"}
     assert len({r["proc"] for r in rows}) == 1
     top = [r for r in rows if "parent_id" not in r]
-    assert [r["name"] for r in top] == phases
+    assert [r["name"] for r in top] == phases + ["startup.ready"]
+    ready = top.pop()
+    assert ready["unnamed_s"] == 0.0
+    assert ready["total_s"] == ready["dur_s"] == pytest.approx(
+        sum(r["dur_s"] for r in top), abs=1e-4)
+    assert ready["t0"] == top[0]["t0"]
+    for key in COMPILE_SUMS:
+        assert ready[key] == pytest.approx(sum(r[key] for r in top), abs=1e-4)
     for a, b in zip(top, top[1:]):
         gap = b["t0"] - (a["t0"] + a["dur_s"])
         assert -2e-6 <= gap <= max_unnamed_s, (a["name"], b["name"], gap)
@@ -76,18 +98,49 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     first_step = top[-1]
     assert t_before <= first_step["t0"] + first_step["dur_s"] <= t_after
     assert first_step["step"] == 1
-    kids = [r for r in rows if "parent_id" in r]
-    assert [r["name"] for r in kids] == [
-        "startup.first_batch", "startup.compile_or_load"]
-    assert {r["parent_id"] for r in kids} == {first_step["span_id"]}
-    assert sum(r["dur_s"] for r in kids) <= first_step["dur_s"] + 2e-6
+    for parent in top:
+        kids = [r for r in rows if r.get("parent_id") == parent["span_id"]]
+        assert [r["name"] for r in kids] == TRAIN_CHILDREN.get(
+            parent["name"], [])
+        if kids:    # they tile it, and their compile sums are its own
+            assert kids[0]["t0"] == parent["t0"]
+            assert sum(r["dur_s"] for r in kids) == pytest.approx(
+                parent["dur_s"], abs=1e-3)
+            for key in COMPILE_SUMS:
+                assert parent[key] == pytest.approx(
+                    sum(r[key] for r in kids), abs=1e-4)
     # rows written at their phase's end: file order is time order
-    ends = [r["t0"] + r["dur_s"] for r in rows]
+    ends = [r["t0"] + r["dur_s"] for r in rows if r["name"] != "startup.ready"]
     assert ends == sorted(ends)
+    # what JAX compiled on the way: every root a child of the phase it
+    # ended in, the phase's sums theirs; the step's own program is the
+    # first step's, and nothing compiles after it
+    with open(logdir / "trace.jsonl") as f:
+        spans = [json.loads(line) for line in f if line.strip()]
+    compiles = [r for r in spans if r.get("kind") == "span"
+                and r["name"].startswith("compile.")]
+    assert compiles and {r["trace_id"] for r in compiles} == {"startup"}
+    phases = {r["span_id"]: r for r in rows}
+    roots = [r for r in compiles if r["parent_id"] in phases]
+    for key, name in (("trace_s", "compile.trace"), ("lower_s",
+                      "compile.lower"), ("backend_s", "compile.backend")):
+        assert sum(r["dur_s"] for r in roots if r["name"] == name) == \
+            pytest.approx(sum(r[key] for r in top), abs=1e-4)
+    # (on the CPU --estimate-flops compiles the step once before, ahead
+    # of time, inside startup.state_init)
+    step_rows = [r for r in roots if phases[r["parent_id"]]["name"]
+                 == "startup.compile_or_load"]
+    assert [(r["name"], r["program"]) for r in step_rows] == [
+        ("compile.trace", "step"), ("compile.lower", "jit(step)"),
+        ("compile.backend", "jit(step)")]
     assert checker.check_file(str(logdir / "trace.jsonl")) == ([], [])
     with open(logdir / "metrics.jsonl") as f:
         metrics = [json.loads(line) for line in f if line.strip()]
     assert [m["step"] for m in metrics] == [2, 4]
+    # the first row's steps held the compilation, and the row says so
+    assert metrics[0]["compile_s"] > 0
+    assert "step" in metrics[0]["compiled"].split(",")
+    assert metrics[1]["compile_s"] == 0.0 and "compiled" not in metrics[1]
     ts = [m["t"] for m in metrics]
     assert ts == sorted(ts) and t_before <= ts[0] and ts[-1] <= t_after
     assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []

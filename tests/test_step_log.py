@@ -434,7 +434,7 @@ def test_profilez_captures_engine_iterations(served_model, tmp_path,
 LEAF_FIELDS = ("dispatch_s", "fetch_s", "commit_s", "first_token_s",
                "log_prev_s", "between_s", "wait_s", "offcpu_s",
                "commit_cpu_s", "gc_s", "unnamed_s", "stream_lines",
-               "stream_lag_max_s")
+               "stream_lag_max_s", "compile_s")
 
 
 def _mixed_traffic(eng, cfg, n_requests=6):
@@ -509,6 +509,9 @@ def test_leaves_tile_the_iteration_and_rows_tile_the_thread(
     ("gc_s", "x", "'gc_s'"),
     ("stream_lines", 1.5, "'stream_lines'"),
     ("stream_lag_max_s", float("nan"), "'stream_lag_max_s'"),
+    ("compile_s", -1.0, "'compile_s'"),
+    ("compile_s", 0.25, "names no 'compiled' program"),
+    ("compiled", "decode", "nothing took any time"),
 ])
 def test_schema_checker_holds_the_leaf_fields(served_model, tmp_path, field,
                                               value, message):
@@ -529,7 +532,8 @@ def test_schema_checker_holds_the_leaf_fields(served_model, tmp_path, field,
     with open(path, "w") as f:
         for r in rows:
             f.write(json.dumps({k: v for k, v in r.items()
-                                if k not in LEAF_FIELDS}) + "\n")
+                                if k not in LEAF_FIELDS + ("compiled",)})
+                    + "\n")
     assert checker.check_steps_file(path)[0] == []
 
 
@@ -663,11 +667,73 @@ def test_decode_iteration_costs_a_counted_number_of_clock_reads(
     eng.stop(drain=False)
 
 
-def test_one_stalled_iteration_leaves_one_engine_stall_row(served_model,
-                                                           tmp_path):
+def test_an_iteration_that_compiles_says_so(served_model, tmp_path):
+    """A second shape once every program of the engine exists (ISSUE 50):
+    the iteration it compiled in carries ``compile_s`` and the program's
+    name in its step record, the rows around it read 0.0, ``state()``
+    keeps the account, and the compile log's rows are ``trace_id``
+    ``"compile"`` rows of ``trace.jsonl``."""
     from distributedtensorflow_tpu.obs.tracing import TraceRecorder
 
     cfg, params, _ = served_model
+
+    @jax.jit
+    def second_shape(x):
+        return x + 1
+
+    x = jnp.ones(7)
+    with TraceRecorder(str(tmp_path / "trace.jsonl"), step_rows=False):
+        eng = _decoding_engine(cfg, params, logdir=str(tmp_path))
+        account = dict(eng.state()["compiles"])     # its own programs'
+        assert account["count"] >= 2 and account["seconds"] > 0
+        _after_commit(eng, lambda: second_shape(x))
+        compiled_at = eng.steps_total + 1
+        for _ in range(3):
+            assert eng.step()
+        compiles = eng.state()["compiles"]
+        eng.stop(drain=False)
+    rows = {r["step"]: r for r in _load_jsonl(tmp_path / "steps.jsonl")}
+    row = rows[compiled_at]
+    assert row["compile_s"] > 0 and row["compiled"] == "second_shape"
+    assert row["compile_s"] <= row["commit_s"] <= row["step_s"]
+    for around in (compiled_at - 1, compiled_at + 1, compiled_at + 2):
+        assert rows[around]["compile_s"] == 0.0
+        assert "compiled" not in rows[around]
+    assert compiles["count"] == account["count"] + 1
+    assert compiles["seconds"] == pytest.approx(
+        account["seconds"] + row["compile_s"], abs=1e-5)
+    assert compiles["last_program"] == "second_shape"
+    assert compiles["last_t"] == row["t"]
+    mine = [r for r in _load_jsonl(tmp_path / "trace.jsonl")
+            if r["name"].startswith("compile.")
+            and "second_shape" in r["program"]]
+    assert [r["name"] for r in mine] == [
+        "compile.trace", "compile.lower", "compile.backend"]
+    assert {r["trace_id"] for r in mine} == {"compile"}
+    assert sum(r["dur_s"] for r in mine) == pytest.approx(
+        row["compile_s"], abs=1e-5)
+    for name in ("steps.jsonl", "trace.jsonl"):
+        assert checker.check_file(str(tmp_path / name)) == ([], [])
+
+
+@pytest.mark.parametrize("what", ["sleep", "compile"])
+def test_one_stalled_iteration_leaves_one_engine_stall_row(served_model,
+                                                           tmp_path, what):
+    """... and a stall that is a compilation names its cause: the row's
+    ``compile_s`` and the ``compile.*`` spans under the leaf that held
+    it."""
+    from distributedtensorflow_tpu.obs.tracing import TraceRecorder
+
+    cfg, params, _ = served_model
+
+    @jax.jit
+    def slow_to_trace(x):
+        time.sleep(0.5)
+        return x + 1
+
+    x = jnp.ones(3)
+    stall = {"sleep": lambda: time.sleep(0.5),
+             "compile": lambda: slow_to_trace(x)}[what]
     path = tmp_path / "trace.jsonl"
     rec = TraceRecorder(str(path), step_rows=False).install()
     try:
@@ -678,7 +744,7 @@ def test_one_stalled_iteration_leaves_one_engine_stall_row(served_model,
                     eng.submit([3, 1, 4, 1, 5], max_new_tokens=50)
             if i == 200:
                 eng.step()          # (a decode iteration follows)
-                _after_commit(eng, lambda: time.sleep(0.5))
+                _after_commit(eng, stall)
                 stalled = eng.steps_total + 1
             assert eng.step()
         eng.stop(drain=False)
@@ -698,6 +764,14 @@ def test_one_stalled_iteration_leaves_one_engine_stall_row(served_model,
     assert commit["dur_s"] >= 0.5           # the leaf that held the time
     record = next(r for r in eng.step_records() if r["step"] == stalled)
     assert record["commit_s"] == commit["dur_s"]
+    assert row["compile_s"] == record["compile_s"]
+    if what == "compile":
+        assert row["compile_s"] >= 0.5
+        assert record["compiled"] == "slow_to_trace"
+        assert [c["name"] for c in commit["children"]] == [
+            "compile.trace", "compile.lower", "compile.backend"]
+    else:
+        assert row["compile_s"] == 0.0 and "children" not in commit
     assert record["offcpu_s"] >= 0.4        # ... and the thread slept
     errors, _ = checker.check_trace_file(str(path))
     assert errors == []

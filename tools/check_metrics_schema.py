@@ -346,8 +346,31 @@ STEP_WALL_FIELDS = (
 STEP_LEAF_FIELDS = (
     "dispatch_s", "fetch_s", "commit_s", "first_token_s", "log_prev_s",
     "between_s", "wait_s", "offcpu_s", "commit_cpu_s", "gc_s", "unnamed_s",
-    "stream_lag_max_s",
+    "stream_lag_max_s", "compile_s",
 )
+#: What JAX compiled or loaded inside the iteration, or the log row's
+#: steps (ISSUE 50, the compile log of obs/tracing.py): ``compile_s`` its
+#: seconds (a step-log leaf field above, a metric-row field too), and,
+#: only beside a ``compile_s`` above 0, ``compiled``: the programs' names,
+#: joined by commas.
+COMPILED_FIELD = "compiled"
+
+
+def _check_compiled(row: dict, where: str) -> list[str]:
+    """``compiled`` is a non-empty string, there exactly where
+    ``compile_s`` is above 0 (both absent in older logs)."""
+    names, secs = row.get(COMPILED_FIELD), row.get("compile_s")
+    if names is None:
+        if _nonneg_finite(secs) and secs > 0:
+            return [f"{where}: 'compile_s' {secs!r} names no 'compiled' "
+                    "program"]
+        return []
+    if not isinstance(names, str) or not names:
+        return [f"{where}: 'compiled' {names!r} is not a non-empty string"]
+    if not (_nonneg_finite(secs) and secs > 0):
+        return [f"{where}: 'compiled' {names!r} beside 'compile_s' "
+                f"{secs!r}: nothing took any time"]
+    return []
 
 #: Per-tenant usage ledger schema (obs/usage.py ``UsageMeter``, ISSUE 19
 #: — duplicated, stdlib-only).  Tenant identities are identifier-style;
@@ -597,6 +620,9 @@ def check_row(row, lineno: int) -> tuple[list[str], list[str]]:
                     f"line {lineno}: 'quant_mode' {v!r} not in "
                     f"{QUANT_MODES}"
                 )
+            continue
+        if k == COMPILED_FIELD:
+            errors.extend(_check_compiled(row, f"line {lineno}"))
             continue
         if k == "pipeline_schedule":
             # the pipeline-schedule stamp (TrainerConfig.pipeline_schedule
@@ -1241,6 +1267,79 @@ def _check_trace_span(row: dict, i: int) -> list[str]:
     return errors
 
 
+#: The compile log's rows (obs/tracing.py ``install_compile_log``, ISSUE
+#: 50 — duplicated, stdlib-only) and what ``cache`` says on a
+#: ``compile.backend`` row.
+COMPILE_SPAN_NAMES = ("compile.trace", "compile.lower", "compile.backend",
+                      "compile.cache_load")
+COMPILE_CACHE_STATES = ("hit", "miss", "off")
+#: The sums a start-up phase row carries of the compile roots that ended
+#: inside it, and ``startup.ready`` of the whole trace beside its own.
+COMPILE_SUM_FIELDS = ("trace_s", "lower_s", "backend_s", "cache_load_s",
+                      "programs")
+STARTUP_READY_FIELDS = COMPILE_SUM_FIELDS + (
+    "total_s", "cache_hits", "cache_misses", "unnamed_s")
+
+
+def _check_compile_span(row: dict, i: int) -> list[str]:
+    """A ``compile.*`` row names its ``program``; it is a child (of the
+    start-up phase it ended in, or of the compile event it began in) or
+    carries ``trace_id`` ``"compile"``; ``compile.backend`` says what the
+    persistent cache said."""
+    name, errors = row["name"], []
+    if name not in COMPILE_SPAN_NAMES:
+        errors.append(f"line {i}: unknown compile row {name!r} (known: "
+                      f"{COMPILE_SPAN_NAMES})")
+    if not isinstance(row.get("program"), str):
+        errors.append(f"line {i}: {name!r} names no 'program'")
+    if row["trace_id"] not in ("startup", "compile"):
+        errors.append(f"line {i}: {name!r} has trace_id "
+                      f"{row['trace_id']!r}, neither 'startup' nor "
+                      "'compile'")
+    elif "parent_id" not in row and row["trace_id"] != "compile":
+        errors.append(f"line {i}: {name!r} under trace_id 'startup' is "
+                      "no phase's child")
+    if name == "compile.backend":
+        if row.get("cache") not in COMPILE_CACHE_STATES:
+            errors.append(f"line {i}: 'cache' {row.get('cache')!r} not in "
+                          f"{COMPILE_CACHE_STATES}")
+        if not _nonneg_finite(row.get("cache_load_s")) \
+                or row["cache_load_s"] > row["dur_s"] + 1e-5:
+            errors.append(f"line {i}: 'cache_load_s' "
+                          f"{row.get('cache_load_s')!r} is not a part of "
+                          f"dur_s {row['dur_s']!r}")
+    return errors
+
+
+def _check_startup_ready(row: dict, i: int, top: dict) -> list[str]:
+    """``startup.ready`` against ``top``, the sums of the top-level phases
+    before it: every field there and a non-negative finite number,
+    ``total_s`` their seconds with ``unnamed_s`` between them, the
+    compile sums theirs (each row rounds to a microsecond)."""
+    errors = []
+    for key in STARTUP_READY_FIELDS:
+        if not _nonneg_finite(row.get(key)):
+            errors.append(f"line {i}: 'startup.ready' {key!r} "
+                          f"{row.get(key)!r} is not a non-negative finite "
+                          "number")
+    if errors:
+        return errors
+    if row["unnamed_s"] > 1e-3:
+        errors.append(f"line {i}: 'startup.ready' leaves unnamed_s "
+                      f"{row['unnamed_s']!r} of start-up to no phase")
+    if abs(row["total_s"] - row["unnamed_s"] - top.get("dur_s", 0.0)) > 1e-3:
+        errors.append(
+            f"line {i}: 'startup.ready' total_s {row['total_s']!r} less "
+            f"unnamed_s is not the top-level phases' "
+            f"{top.get('dur_s', 0.0):.6f}")
+    for key in COMPILE_SUM_FIELDS:
+        if abs(row[key] - top.get(key, 0.0)) > 1e-3:
+            errors.append(
+                f"line {i}: 'startup.ready' {key!r} {row[key]!r} is not "
+                f"the top-level phases' {top.get(key, 0.0):.6f}")
+    return errors
+
+
 def check_trace_file(path: str) -> tuple[list[str], list[str]]:
     """Validate a ``trace.jsonl`` (obs/tracing.py): per-step span-tree
     rows (``step`` an integer or null, ``spans`` a list of
@@ -1250,7 +1349,12 @@ def check_trace_file(path: str) -> tuple[list[str], list[str]]:
     absolute ``t0``, ``dur_s >= 0``, integer ``proc``).  The start-up
     phases (``startup.*`` spans, one process's ``PhaseTrace``) all carry
     ``trace_id`` ``"startup"``, and those with no ``parent_id`` tile time
-    in file order: none starts before the one before it ends."""
+    in file order: none starts before the one before it ends
+    (``startup.first_request`` after ``startup.listen`` like any other).
+    ``startup.ready`` is the summary and no tile: its ``total_s`` is the
+    top-level phases' seconds (``unnamed_s`` what they leave: rounding)
+    and its compile sums are theirs.  ``compile.*`` rows are
+    :func:`_check_compile_span`'s."""
     errors: list[str] = []
 
     def tree_errors(node, i) -> list[str]:
@@ -1267,6 +1371,8 @@ def check_trace_file(path: str) -> tuple[list[str], list[str]]:
         return out
 
     startup_end: dict[int, float] = {}   # proc -> end of its last phase
+    # proc -> what its top-level phases sum to, for startup.ready
+    startup_sums: dict[int, dict[str, float]] = {}
     with open(path) as f:
         for i, line in enumerate(f, start=1):
             line = line.strip()
@@ -1285,12 +1391,27 @@ def check_trace_file(path: str) -> tuple[list[str], list[str]]:
                 errs = _check_trace_span(row, i)
                 errors.extend(errs)
                 name = row.get("name")
+                if not errs and name.startswith("compile."):
+                    errors.extend(_check_compile_span(row, i))
                 if errs or not name.startswith("startup."):
                     continue
                 if row["trace_id"] != "startup":
                     errors.append(f"line {i}: {name!r} has trace_id "
                                   f"{row['trace_id']!r}, not 'startup'")
-                if "parent_id" not in row:
+                for key in COMPILE_SUM_FIELDS:
+                    if key in row and not _nonneg_finite(row[key]):
+                        errors.append(f"line {i}: {name!r} {key!r} "
+                                      f"{row[key]!r} is not a non-negative "
+                                      "finite number")
+                if name == "startup.ready":
+                    # (a restarted run appends its own start-up)
+                    errors.extend(_check_startup_ready(
+                        row, i, startup_sums.pop(row["proc"], {})))
+                elif "parent_id" not in row:
+                    sums = startup_sums.setdefault(row["proc"], {})
+                    for key in ("dur_s",) + COMPILE_SUM_FIELDS:
+                        if _nonneg_finite(row.get(key)):
+                            sums[key] = sums.get(key, 0.0) + row[key]
                     end = startup_end.get(row["proc"])
                     if end is not None and row["t0"] < end - 1e-5:
                         errors.append(
@@ -1434,6 +1555,7 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
                         f"line {i}: dispatch_s+fetch_s+commit_s "
                         f"{parts:.6f} exceeds decode_s "
                         f"{walls['decode_s']:.6f}")
+            errors.extend(_check_compiled(row, f"line {i}"))
             if "unnamed_s" in walls and "step_s" in walls \
                     and walls["unnamed_s"] > 0.02 * walls["step_s"] + 1e-4:
                 errors.append(

@@ -1036,14 +1036,19 @@ def main() -> None:
         make_eval_step,
         make_train_step,
     )
-    from distributedtensorflow_tpu.obs.tracing import PhaseTrace
+    from distributedtensorflow_tpu.obs.tracing import (
+        PhaseTrace,
+        install_compile_log,
+    )
     from distributedtensorflow_tpu.train.trainer import Trainer, TrainerConfig
     from distributedtensorflow_tpu.workloads import get_workload
 
     # Start-up as spans with absolute time (trace_id "startup" in
-    # <logdir>/trace.jsonl): each mark names the stretch since the last;
-    # rows wait until the pre-fit recorder below exists.
+    # <logdir>/trace.jsonl): each mark names the stretch since the last,
+    # and what JAX traces, lowers, compiles or loads inside it is its
+    # child; rows wait until the pre-fit recorder below exists.
     startup = PhaseTrace("startup", T_PROCESS_START)
+    install_compile_log(startup)
     startup.mark("startup.imports")
 
     # Goodput ledger FIRST (before mesh/state/restore) so setup time is
@@ -1240,6 +1245,7 @@ def main() -> None:
 
     jax.block_until_ready(state)  # init is asynchronous: charge it here
     startup.mark("startup.state_init")
+    startup.open("startup.trainer")
     ctx = current_input_context(wl.global_batch_size)
 
     # Disaggregated input (--data-service N): a loopback dispatcher + N
@@ -1487,6 +1493,18 @@ def main() -> None:
             logdir=args.logdir,
         )
 
+    # the input-plane services, the fault plan, the checkpoint manager and
+    # the restore, the monitors: 8 ms on the chip where none is asked for
+    startup.mark("startup.trainer.services", parent="startup.trainer")
+    if args.logdir and jax.process_index() == 0:
+        from distributedtensorflow_tpu.utils.metrics import import_tensorflow
+
+        # The metric writer's TensorBoard sink, imported here under its
+        # own name: all but 0.05 s of startup.trainer's 14.4 on the chip
+        # (PERF.md §5).
+        import_tensorflow(args.logdir)
+    startup.mark("startup.trainer.tensorflow_import",
+                 parent="startup.trainer")
     trainer = Trainer(
         train_step,
         TrainerConfig(
@@ -1550,10 +1568,11 @@ def main() -> None:
         callbacks=[cb for cb in (chaos, dynamics_monitor, elastic)
                    if cb is not None] or None,
     )
-    # the input-plane services, the restore, the trainer with its metric
-    # writer (TensorBoard import) and status server
-    startup.mark("startup.trainer", **_flash_layout(wl, mesh),
-                 **_optimizer_update(state.params))
+    layout = {**_flash_layout(wl, mesh), **_optimizer_update(state.params)}
+    # the trainer with its metric writer, status server and flight
+    # recorder, and what the step will lower to
+    startup.mark("startup.trainer.construct", parent="startup.trainer")
+    startup.close("startup.trainer", **layout)
     if dynamics_monitor is not None and trainer.status_server is not None:
         dynamics_monitor.install(trainer.status_server)
     if elastic is not None:
