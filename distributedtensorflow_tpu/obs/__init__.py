@@ -2,7 +2,9 @@
 aggregation, anomaly detection.
 
 The reference harness's observability floor is ``tf.summary`` scalars plus
-chief-only logging; this subsystem answers the questions that floor cannot:
+chief-only logging (``utils.metrics.MetricWriter`` keeps that floor: the
+same event files, written without TensorFlow); this subsystem answers the
+questions that floor cannot:
 *where did the step time go* (span tracing → per-step breakdown), *which
 host is slow* (cross-host gauge aggregation), *is the run healthy*
 (streaming anomaly detection), and *what is every layer doing* (the

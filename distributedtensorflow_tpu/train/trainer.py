@@ -773,7 +773,8 @@ class Trainer:
                             loss=last_metrics.get("loss"),
                             step_time=breakdown.get("t_step"),
                         )
-                    self.writer.write(step_i + 1, last_metrics)
+                    with obs.span("metric_write"):
+                        self.writer.write(step_i + 1, last_metrics)
                     self._export_prometheus()
                     ledger = obs.goodput.default_ledger()
                     if ledger is not None:

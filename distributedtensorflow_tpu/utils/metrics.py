@@ -2,8 +2,10 @@
 
 Reference: ``tf.summary`` event files + Keras callbacks + chief-only
 convention (SURVEY.md §5.5).  A ``metrics.jsonl`` record is always written
-(the human/tool-greppable artifact); TensorBoard-compatible event output is
-layered on top through ``tf.summary`` when TF is importable.
+(the human/tool-greppable artifact); the TensorBoard event file beside it
+is :class:`EventFileWriter`'s, which needs no TensorFlow: the ``tensorboard``
+package's ``Event`` message and ``google_crc32c``, both imported in a tenth
+of a second where ``import tensorflow`` was 14 of a trainer's start-up.
 
 Lifecycle contract: ``MetricWriter`` is a context manager, ``close()`` is
 idempotent and flushes, and every owner (``Trainer``, ``SidecarEvaluator``,
@@ -15,10 +17,13 @@ callback must not crash teardown).
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import math
 import os
+import socket
+import struct
 import time
 from typing import Any, Mapping
 
@@ -44,18 +49,64 @@ def json_sanitize(value: Any) -> Any:
     return value
 
 
-def import_tensorflow(logdir: str):
-    """TensorFlow, whose event writer is the TensorBoard sink of a
-    :class:`MetricWriter` on ``logdir``, or None where it is not installed.
-    The first import is seconds of a process's start-up: an entry point
-    that names its phases makes it here, under a name of its own."""
-    try:
-        import tensorflow as tf  # noqa: PLC0415
-    except ImportError:  # no TF installed -> JSONL only
-        logger.info("tensorflow not importable: %s gets metrics.jsonl "
-                    "only, no TensorBoard events", logdir)
-        return None
-    return tf
+def mask_crc(c: int) -> int:
+    """A CRC-32C as a TFRecord stores it: rotated right by 15 bits and
+    offset, so that a checksum of bytes which themselves hold checksums
+    stays well distributed."""
+    return ((c >> 15 | c << 17) + 0xA282EAD8) & 0xFFFFFFFF
+
+
+class EventFileWriter:
+    """One TensorBoard event file in ``logdir``,
+    ``events.out.tfevents.<unix seconds>.<host>.<pid>.<n>``: TFRecords
+    (u64 length, u32 masked CRC-32C of the length, the payload, u32 masked
+    CRC-32C of the payload; little-endian) that each hold one ``Event``,
+    the first the file's version.  Raises ImportError where the
+    ``tensorboard`` package or ``google_crc32c`` is not installed.
+    (``native.recordio`` frames the same records in C++, but builds its
+    library at first use: a compiler inside the start-up that this writer
+    exists to shorten.)"""
+
+    def __init__(self, logdir: str):
+        # both before the file is made: a machine without one leaves none
+        from google_crc32c import value as crc32c  # noqa: PLC0415
+        from tensorboard.compat.proto.event_pb2 import Event  # noqa: PLC0415
+
+        self._crc32c = crc32c
+        self._event = Event
+        stem = os.path.join(logdir, "events.out.tfevents.%d.%s.%d." % (
+            time.time(), socket.gethostname(), os.getpid()))
+        for n in itertools.count():
+            try:  # the first n no writer of this process and second took
+                self._file = open(stem + str(n), "xb")
+                break
+            except FileExistsError:
+                continue
+        self._record(Event(wall_time=time.time(),
+                           file_version="brain.Event:2"))
+        self._file.flush()
+
+    def _record(self, event) -> None:
+        data = event.SerializeToString()
+        header = struct.pack("<Q", len(data))
+        self._file.write(
+            header + struct.pack("<I", mask_crc(self._crc32c(header)))
+            + data + struct.pack("<I", mask_crc(self._crc32c(data))))
+
+    def write(self, step: int, scalars: Mapping[str, float]) -> None:
+        """One ``Event`` at ``step`` with every scalar a ``simple_value``
+        (float32, as ``tf.summary.scalar`` stored it), flushed."""
+        event = self._event(wall_time=time.time(), step=step)
+        for tag, value in scalars.items():
+            event.summary.value.add(tag=tag, simple_value=value)
+        self._record(event)
+        self._file.flush()
+
+    def flush(self) -> None:
+        self._file.flush()
+
+    def close(self) -> None:
+        self._file.close()
 
 
 class MetricWriter:
@@ -70,11 +121,14 @@ class MetricWriter:
             return
         os.makedirs(logdir, exist_ok=True)
         if use_tensorboard:
-            tf = import_tensorflow(logdir)
-            if tf is not None:
-                # a TF that imports but cannot write is an error, not a
-                # quiet downgrade
-                self._tb = tf.summary.create_file_writer(logdir)
+            # (a writer that imports but cannot write is an error, not a
+            # quiet downgrade)
+            try:
+                self._tb = EventFileWriter(logdir)
+            except ImportError:
+                logger.info("tensorboard or google_crc32c not importable: "
+                            "%s gets metrics.jsonl only, no TensorBoard "
+                            "events", logdir)
         # JSONL is always written: a human/tool-greppable record of the run
         # (TensorBoard events are the reference-parity surface on top).
         self._jsonl = open(os.path.join(logdir, "metrics.jsonl"), "a")
@@ -90,13 +144,8 @@ class MetricWriter:
             for k, v in scalars.items() if v is not None
         }
         if self._tb is not None:
-            import tensorflow as tf  # noqa: PLC0415
-
-            with self._tb.as_default(step=step):
-                for k, v in scalars.items():
-                    if not isinstance(v, str):
-                        tf.summary.scalar(k, v)
-            self._tb.flush()
+            self._tb.write(step, {k: v for k, v in scalars.items()
+                                  if not isinstance(v, str)})
         if self._jsonl is not None:
             # `t`: when the row was written, as steps.jsonl and
             # requests.jsonl rows carry (jsonl only: not a TB scalar)
@@ -141,11 +190,8 @@ class MetricWriter:
                 self._jsonl = None
         if self._tb is not None:
             try:
-                self._tb.flush()
-                close = getattr(self._tb, "close", None)
-                if close is not None:
-                    close()
-            except Exception:  # a broken TB writer must not mask teardown
+                self._tb.close()
+            except OSError:  # a broken TB writer must not mask teardown
                 logger.exception("tensorboard writer close failed")
             self._tb = None
 

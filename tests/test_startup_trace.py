@@ -10,6 +10,7 @@ import os
 import sys
 import time
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -83,6 +84,9 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
                                                 own_registry):
     import train
 
+    # the run needs no TensorFlow (and TensorBoard's loader, below, then
+    # reads with its own record reader)
+    monkeypatch.setitem(sys.modules, "tensorflow", None)
     logdir = tmp_path / "run"
     monkeypatch.setattr(sys, "argv", [
         "train.py", "--workload", "mnist_lenet", "--test-size", "--steps",
@@ -109,6 +113,11 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
             for key in COMPILE_SUMS:
                 assert parent[key] == pytest.approx(
                     sum(r[key] for r in kids), abs=1e-4)
+    # the mark that timed `import tensorflow` (14.3 s on the chip) times
+    # nothing since the metric writer writes its event files itself
+    (tf_import,) = [r for r in rows
+                    if r["name"] == "startup.trainer.tensorflow_import"]
+    assert tf_import["dur_s"] < 0.5
     # rows written at their phase's end: file order is time order
     ends = [r["t0"] + r["dur_s"] for r in rows if r["name"] != "startup.ready"]
     assert ends == sorted(ends)
@@ -144,6 +153,26 @@ def test_train_py_startup_rows_and_metrics_time(tmp_path, monkeypatch,
     ts = [m["t"] for m in metrics]
     assert ts == sorted(ts) and t_before <= ts[0] and ts[-1] <= t_after
     assert checker.check_file(str(logdir / "metrics.jsonl"))[0] == []
+    # every logged row's write is a span of its step's trace row, and the
+    # one event file holds the rows' numbers as TensorBoard's loader
+    # reads them: same tags, same steps, at float32
+    step_rows = [r for r in spans if r.get("step") in (2, 4)]
+    assert [[s["name"] for s in r["spans"]].count("metric_write")
+            for r in step_rows] == [1, 1]
+    loader = pytest.importorskip(
+        "tensorboard.backend.event_processing.event_file_loader")
+    from tensorboard.util import tensor_util
+
+    (events,) = logdir.glob("events.out.tfevents.*")
+    got = {}
+    for event in loader.EventFileLoader(str(events)).Load():
+        for value in event.summary.value:
+            got.setdefault(event.step, {})[value.tag] = (
+                tensor_util.make_ndarray(value.tensor)[()])
+    want = {m["step"]: {k: np.float32(v) for k, v in m.items()
+                        if k not in ("step", "t") and not isinstance(v, str)}
+            for m in metrics}
+    assert got == want and all(len(row) > 20 for row in got.values())
 
 
 def test_trainer_row_says_the_update_is_separate(tmp_path, monkeypatch,

@@ -1496,13 +1496,11 @@ def main() -> None:
     # the input-plane services, the fault plan, the checkpoint manager and
     # the restore, the monitors: 8 ms on the chip where none is asked for
     startup.mark("startup.trainer.services", parent="startup.trainer")
-    if args.logdir and jax.process_index() == 0:
-        from distributedtensorflow_tpu.utils.metrics import import_tensorflow
-
-        # The metric writer's TensorBoard sink, imported here under its
-        # own name: all but 0.05 s of startup.trainer's 14.4 on the chip
-        # (PERF.md §5).
-        import_tensorflow(args.logdir)
+    # Times nothing and reads ~0: `import tensorflow` stood here, 14.3 of
+    # startup.trainer's 14.4 s on the chip, until the metric writer wrote
+    # its event files itself (PERF.md §6, PR 51).  The mark stays because
+    # benchmark/layer_metrics/setup_trainer_tensorflow_import_s.json reads
+    # it; it goes with that file (ROADMAP S0 g).
     startup.mark("startup.trainer.tensorflow_import",
                  parent="startup.trainer")
     trainer = Trainer(
