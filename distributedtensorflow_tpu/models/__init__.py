@@ -45,6 +45,11 @@ from .evabyte import (  # noqa: F401
     evabyte_6_5b,
     evabyte_tiny,
 )
+from .ling import (  # noqa: F401
+    LingConfig,
+    ling3_flash_ep8,
+    ling_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

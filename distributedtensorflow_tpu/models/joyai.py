@@ -19,8 +19,9 @@ model width, ``H`` heads, RMSNorm everywhere, no biases, pre-norm blocks:
   softmax, output ``concat_i(sum_j p_ij v_j) W_o``;
 - FFN of the first ``num_dense_layers`` layers: SwiGLU of
   ``intermediate_size``; of the others: ``s = sigmoid(h W_r)`` in float32, the
-  top ``k`` of ``s + b`` (``b`` selects only; one group: ``n_group > 1`` is
-  refused, not guessed), weights ``s[top] / (sum + 1e-20) * route_scale``,
+  top ``k`` of ``s + b`` (``b`` selects only; with ``n_group`` > 1 inside the
+  ``topk_group`` best groups of consecutive experts, a group scoring the sum
+  of its two largest), weights ``s[top] / (sum + 1e-20) * route_scale``,
   ``y = Shared(h) + sum_j w_j Expert_top_j(h)``: ``parallel.moe.dropless_moe``,
   the one expert layer of both routed families, over the experts held here
   (``experts_held`` from ``expert_first``; all of them by default: a chip's
@@ -66,7 +67,8 @@ from .afmoe import _uniform, rms_norm, swiglu
 from .gpt import rope
 
 __all__ = ["JoyaiConfig", "joyai_tiny", "joyai_llm_flash", "glm5_tiny",
-           "glm5_ep16", "init_params", "block", "embed", "head"]
+           "glm5_ep16", "init_params", "block", "embed", "head",
+           "latent_attention"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,10 +107,11 @@ class JoyaiConfig:
     index_topk: int = 0
 
     def __post_init__(self):
-        if self.n_group != 1 or self.topk_group != 1:
+        if self.num_experts % self.n_group or self.topk_group > self.n_group:
             raise ValueError(
-                "group-limited routing (n_group > 1) is not implemented: "
-                "parallel.moe.sigmoid_topk_route selects over one group")
+                f"group-limited routing takes topk_group of n_group equal "
+                f"groups of the experts: {self.num_experts} experts, n_group "
+                f"{self.n_group}, topk_group {self.topk_group}")
         if self.index_topk and self.index_head_dim < self.qk_rope_head_dim:
             raise ValueError("the indexer rotates the first qk_rope_head_dim "
                              "values of an index head: index_head_dim is "
@@ -288,13 +291,23 @@ def index_inputs(p, h, c_q, cfg: JoyaiConfig, positions):
 def latent_inputs(p, h, cfg: JoyaiConfig, positions):
     """``h`` (T, d) -> ``q_nope`` (T, H, nope), ``q_rope`` (T, H, rope)
     rotated, the row to cache (T, rank + rope): ``[c_kv | k_rope]``, and the
-    query latent ``c_q`` (T, q_lora_rank) after its norm."""
+    query latent ``c_q`` (T, q_lora_rank) after its norm.  Options by what the
+    config and the parameters hold: ``q_lora_rank`` None is one query
+    projection ``w_q`` (no latent, ``c_q`` None), and ``q_head_norm`` an
+    RMSNorm with a learned scale over each head's ``nope + rope`` values
+    before rotary (``models.ling``)."""
     t = h.shape[0]
     eps, rank = cfg.rms_norm_eps, cfg.kv_lora_rank
     nope = cfg.qk_nope_head_dim
     with jax.named_scope("q_proj"):
-        c_q = rms_norm(jnp.dot(h, p["w_qa"]), p["q_norm"], eps)
-        q = jnp.dot(c_q, p["w_qb"]).reshape(t, cfg.num_heads, -1)
+        if cfg.q_lora_rank:
+            c_q = rms_norm(jnp.dot(h, p["w_qa"]), p["q_norm"], eps)
+            q = jnp.dot(c_q, p["w_qb"])
+        else:       # no query rank: one projection, no query-latent norm
+            c_q, q = None, jnp.dot(h, p["w_q"])
+        q = q.reshape(t, cfg.num_heads, -1)
+        if "q_head_norm" in p:      # each head's values, before rotary
+            q = rms_norm(q, p["q_head_norm"], eps)
         q_rope = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
     with jax.named_scope("kv_down"):
         ckr = jnp.dot(h, p["w_kva"])
@@ -308,6 +321,30 @@ def latent_inputs(p, h, cfg: JoyaiConfig, positions):
     return q[..., :nope], q_rope, row, c_q
 
 
+def latent_attention(a, x, h, cfg, positions, attend):
+    """``x`` (T, d) plus the latent attention layer on its normed ``h``,
+    under scope ``latent_attn``: the inputs, ``attend``, the output projection.
+    With ``w_gate`` (d, H) among the parameters each head's output is scaled
+    by ``sigmoid(h w_gate)`` before the projection (``models.ling``)."""
+    with jax.named_scope("latent_attn"):
+        q_nope, q_rope, row, c_q = latent_inputs(a, h, cfg, positions)
+        if cfg.index_topk:
+            q_index, w_index, index_key = index_inputs(
+                a["indexer"], h, c_q, cfg, positions)
+            o = attend((q_nope, q_rope, q_index, w_index), row, index_key,
+                       w_uk=a["w_uk"], w_uv=a["w_uv"])
+        else:
+            o = attend((q_nope, q_rope), row, w_uk=a["w_uk"], w_uv=a["w_uv"])
+        if "w_gate" in a:
+            with jax.named_scope("out_gate"):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h, a["w_gate"], preferred_element_type=jnp.float32))
+                o = o.astype(jnp.float32) * gate[:, :, None]
+        with jax.named_scope("out_proj"):
+            return x + jnp.dot(o.reshape(x.shape[0], -1).astype(x.dtype),
+                               a["w_o"])
+
+
 def block(p, x, cfg: JoyaiConfig, layer: int, positions, attend,
           token_mask=None):
     """One decoder layer on ``x`` (T, d).  ``attend((q_nope, q_rope), row,
@@ -317,19 +354,7 @@ def block(p, x, cfg: JoyaiConfig, layer: int, positions, attend,
     eps = cfg.rms_norm_eps
     with jax.named_scope("ln"):
         h = rms_norm(x, p["ln_attn"], eps)
-    with jax.named_scope("latent_attn"):
-        a = p["attn"]
-        q_nope, q_rope, row, c_q = latent_inputs(a, h, cfg, positions)
-        if cfg.index_topk:
-            q_index, w_index, index_key = index_inputs(
-                a["indexer"], h, c_q, cfg, positions)
-            o = attend((q_nope, q_rope, q_index, w_index), row, index_key,
-                       w_uk=a["w_uk"], w_uv=a["w_uv"])
-        else:
-            o = attend((q_nope, q_rope), row, w_uk=a["w_uk"], w_uv=a["w_uv"])
-        with jax.named_scope("out_proj"):
-            x = x + jnp.dot(o.reshape(x.shape[0], -1).astype(x.dtype),
-                            a["w_o"])
+    x = latent_attention(p["attn"], x, h, cfg, positions, attend)
     with jax.named_scope("ln"):
         h = rms_norm(x, p["ln_mlp"], eps)
     if layer < cfg.num_dense_layers:
@@ -339,7 +364,8 @@ def block(p, x, cfg: JoyaiConfig, layer: int, positions, attend,
     routed, counters = dropless_moe(
         h, moe["router"], moe["bias"], moe["experts"], held=cfg.held,
         top_k=cfg.experts_per_token, route_norm=cfg.route_norm,
-        route_scale=cfg.route_scale, token_mask=token_mask,
+        route_scale=cfg.route_scale, n_group=cfg.n_group,
+        topk_group=cfg.topk_group, token_mask=token_mask,
         impl=cfg.kernel_impl)
     with jax.named_scope("shared_expert"):
         return x + swiglu(moe["shared"], h) + routed, counters

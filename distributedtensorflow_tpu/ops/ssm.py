@@ -7,7 +7,9 @@ the scan state ``s`` of ``d_state`` values a channel, and the last ``d_conv -
 :class:`SSMState` states both (a model's ``cfg.state_rows``, beside
 ``ops.attention.KVRows`` / ``LatentRows`` for what a layer caches a token);
 :class:`ConvTail` is the state of a layer that keeps the tail and has no scan
-(``models.lfm2``'s gated short convolution).
+(``models.lfm2``'s gated short convolution); :class:`DeltaState` that of a
+Kimi-Delta-Attention layer (``models.ling``): three tails and a matrix a head,
+whose forms are ``ops.kda``'s.
 
 The recurrence, a token ``t``, channels ``c`` and states ``n``, in float32::
 
@@ -50,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime import on_tpu, use_kernel
+from . import kda
 
 LANES = 128
 #: tokens a grid step of the kernel (a multiple of 8)
@@ -68,7 +71,7 @@ def _tail_array(channels: int, d_conv: int, dtype):
 
 
 class _SlotArrays:
-    """What the two state forms share: ``arrays(dtype)`` lists a slot's
+    """What the state forms share: ``arrays(dtype)`` lists a slot's
     arrays a layer in pool order, ``names`` names them in the same order."""
 
     def slot_bytes(self, dtype) -> int:
@@ -116,6 +119,36 @@ class ConvTail(_SlotArrays):
 
     def chunk_formulation(self, chunk: int, impl: str) -> None:
         return None
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaState(_SlotArrays):
+    """What a Kimi-Delta-Attention layer keeps a slot (``ops.kda``): the
+    convolution tails of its q, k and v (``heads * key_dim`` channels for q
+    and k, ``heads * value_dim`` for v) and the matrix state a head in
+    float32, stored transposed: ``(heads, value_dim, key_dim)``, the key's
+    channels across lanes."""
+
+    heads: int
+    key_dim: int
+    value_dim: int
+    d_conv: int
+
+    names = ("q_tail", "k_tail", "v_tail", "delta_state")
+
+    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
+        qk = _tail_array(self.heads * self.key_dim, self.d_conv, dtype)
+        return (qk, qk,
+                _tail_array(self.heads * self.value_dim, self.d_conv, dtype),
+                ((self.heads, self.value_dim, self.key_dim),
+                 jnp.dtype(jnp.float32)))
+
+    def chunk_formulation(self, chunk: int, impl: str) -> str:
+        return kda.chunk_scan_formulation(chunk)
+
+    def step_formulation(self, impl: str) -> str:
+        return kda.step_formulation(self.heads, self.key_dim, self.value_dim,
+                                    impl)
 
 
 # -- the convolution and its tail --------------------------------------------

@@ -412,16 +412,29 @@ def group_tile(tokens: int, top_k: int, published: int) -> int:
 def sigmoid_topk_route(h: jax.Array, router_kernel: jax.Array,
                        select_bias: jax.Array, *, top_k: int,
                        route_norm: bool = True, route_scale: float = 1.0,
-                       route_norm_eps: float = 1e-20):
+                       route_norm_eps: float = 1e-20, n_group: int = 1,
+                       topk_group: int = 1):
     """``(idx, weight)`` of shape ``(T, top_k)``: float32 sigmoid scores
     over the router's full width, the top ``top_k`` of ``score + bias``
     (the bias selects only), weights ``score[top] / (sum +
     route_norm_eps) * route_scale`` (the families publish 1e-20; lfm2
-    1e-6)."""
+    1e-6).  With ``n_group`` > 1 the selection is group-limited
+    (DeepSeek-V3's ``noaux_tc``): the experts lie in ``n_group`` groups of
+    consecutive ids, a group scores the sum of its two largest ``score +
+    bias``, and the ``top_k`` are taken inside the ``topk_group`` best
+    groups; one group is the plain selection, bit for bit."""
     scores = jax.nn.sigmoid(jnp.dot(
         h.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    choice = scores + select_bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = choice.shape
+        best_two, _ = lax.top_k(choice.reshape(t, n_group, e // n_group), 2)
+        _, keep = lax.top_k(best_two.sum(-1), topk_group)
+        kept = (keep[:, :, None] == jnp.arange(n_group)).any(1)
+        choice = jnp.where(jnp.repeat(kept, e // n_group, axis=1), choice,
+                           -jnp.inf)
+    _, idx = lax.top_k(choice, top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if route_norm:
         w = w / (w.sum(-1, keepdims=True) + route_norm_eps)
@@ -508,6 +521,8 @@ def dropless_moe(
     route_norm: bool = True,
     route_scale: float = 1.0,
     route_norm_eps: float = 1e-20,
+    n_group: int = 1,
+    topk_group: int = 1,
     token_mask: jax.Array | None = None,
     impl: str = "auto",
 ) -> tuple[jax.Array, dict]:
@@ -516,7 +531,9 @@ def dropless_moe(
     Routes every token over the router's full width, computes ``sum_j w_j
     * Expert_{top_j}(h)`` over the choices that are held here, and returns
     it with the counters of :func:`group_plan` (``pairs``, ``experts_hit``,
-    ``max_load``).  The shared expert is the caller's, added once.  On
+    ``max_load``; under group-limited routing, ``n_group`` > 1, also
+    ``groups_hit``: the groups the real tokens' choices fall in, summed over
+    the tokens).  The shared expert is the caller's, added once.  On
     one chip nothing is exchanged and nothing stands in for the absent
     experts.  ``impl``: ``"pallas"`` (``ops.grouped_matmul``), ``"xla"``,
     or ``"auto"`` (the kernel on a TPU)."""
@@ -527,7 +544,8 @@ def dropless_moe(
         idx, w = sigmoid_topk_route(
             h, router_kernel, select_bias, top_k=top_k,
             route_norm=route_norm, route_scale=route_scale,
-            route_norm_eps=route_norm_eps)
+            route_norm_eps=route_norm_eps, n_group=n_group,
+            topk_group=topk_group)
         plan = group_plan(idx, held, token_mask, tile)
     with jax.named_scope("experts"):
         x_rows = jnp.concatenate(
@@ -547,5 +565,11 @@ def dropless_moe(
         picked = y_rows[plan["dest"]].astype(jnp.float32)      # (T, k, d)
         out = (picked * w[..., None]).sum(1).astype(h.dtype)
     counters = {k: plan[k] for k in ("pairs", "experts_hit", "max_load")}
+    if n_group > 1:
+        group = idx // (router_kernel.shape[-1] // n_group)
+        hit = (group[:, :, None] == jnp.arange(n_group)).any(1)  # (T, G)
+        if token_mask is not None:
+            hit &= token_mask[:, None]
+        counters["groups_hit"] = hit.sum()
     return out, counters
 

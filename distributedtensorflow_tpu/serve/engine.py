@@ -1190,9 +1190,12 @@ class Engine:
         recurrence."""
         fields = {}
         if occupancy and self._routed is not None:
-            pairs, hit, load = (int(v) for v in np.asarray(self._routed))
+            pairs, hit, load, *groups = (
+                int(v) for v in np.asarray(self._routed))
             fields.update(moe_pairs=pairs, moe_experts_hit=hit,
                           moe_max_load=load)
+            if groups:      # group-limited routing only
+                fields["moe_groups_hit"] = groups[0]
         if self.kv.latent_layers:
             context, read, scored = self._step_latent
             if context:

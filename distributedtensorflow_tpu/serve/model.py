@@ -11,7 +11,7 @@ shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
 ``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``,
-``models.evabyte``):
+``models.evabyte``, ``models.ling``):
 ``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
@@ -78,8 +78,9 @@ and the three programs differ only in where the rows live:
 
 ``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, the one pool
 of latent rows, or a state group's arrays — ``(convolution tails, scan
-states)`` for jamba, ``(convolution tails,)`` for lfm2: what
-``cfg.state_rows.arrays`` lists —, ``(layers, slots, ...)`` each) and ``tables`` ``{group: page
+states)`` for jamba, ``(convolution tails,)`` for lfm2, ``(q tails, k tails,
+v tails, matrix states)`` for ling: what ``cfg.state_rows.arrays`` lists —,
+``(layers, slots, ...)`` each) and ``tables`` ``{group: page
 table}`` (a state group's is the one column that names the slot;
 ``serve.kv_cache.GroupedKVCache``: layers in groups by attention
 kind; GPT-2 is one full group); ``layers`` maps a group to the model layers
@@ -113,7 +114,9 @@ key's write too, ``h<i>/attn`` for jamba, whose Mamba layers have
 dt_proj,scan|ssm_step,gate,out_proj}``, and for lfm2, whose attention layers
 add ``h<i>/attn/{qk_norm,rope}`` and whose conv layers have
 ``h<i>/{state_read,state_write}`` and ``h<i>/conv/{in_proj,gate_in,conv,
-gate_out,out_proj}``, ``h<i>/eva_attn`` for evabyte, whose hook adds
+gate_out,out_proj}``, ``h<i>/latent_attn`` again for ling's MLA layers (with
+``out_gate``), whose KDA layers have ``h<i>/{state_read,state_write}`` and
+``h<i>/kda/{proj,conv,gate,scan|step,out_proj}``, ``h<i>/eva_attn`` for evabyte, whose hook adds
 ``summarise`` and ``summary_write`` beside ``kv_write`` and ``paged_attn``
 (the block's own are ``qkv``, ``rope`` and ``proj``); an expert layer's FFN is
 ``h<i>/{router,experts}``, a dense one's ``h<i>/mlp``), ``head``, ``sample``,
@@ -129,7 +132,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, evabyte, gpt, jamba, joyai, lfm2, mimo
+from ..models import afmoe, evabyte, gpt, jamba, joyai, lfm2, ling, mimo
+from ..ops.kda import kda_chunk_scan, kda_step
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
 from .kv_cache import group_rows
 from .sampling import sample_burst
@@ -219,32 +223,34 @@ class _TwoPools:
 
 class _SlotState:
     """The ``mixer`` hook of a state layer (``models.jamba``,
-    ``models.lfm2``): the programs' own, as ``attend`` is.  ``conv`` and
-    ``scan`` read the layer's state out of the group's arrays (scope
-    ``state_read``), run the form (``<scope>/conv``; ``mamba/scan`` or
-    ``mamba/ssm_step``) and store the state back (``state_write``) — the
-    convolution tail is array 0 and, where the family scans, the scan state
-    array 1 (``cfg.state_rows.arrays``: a family that keeps the tail alone
-    never calls ``scan``).  ``pools`` is the program's dict of pools, updated
-    in place."""
+    ``models.lfm2``, ``models.ling``): the programs' own, as ``attend`` is.
+    ``conv``, ``scan`` and ``delta`` read the layer's state out of the group's
+    arrays (scope ``state_read``), run the form (``<scope>/conv``;
+    ``mamba/scan`` or ``mamba/ssm_step``; ``kda/scan`` or ``kda/step``) and
+    store the state back (``state_write``) — a convolution tail is array
+    ``which`` (0 where the layer has one convolution; ling's q, k and v are 0,
+    1, 2) and the scan or matrix state the last of ``cfg.state_rows.arrays``
+    (a family that keeps the tail alone calls neither ``scan`` nor
+    ``delta``).  ``pools`` is the program's dict of pools, updated in
+    place."""
 
-    def __init__(self, pools: dict, li: int):
-        self.pools, self.li = pools, li
+    def __init__(self, pools: dict, li: int, impl: str = "auto"):
+        self.pools, self.li, self.impl = pools, li, impl
 
     def _store(self, which: int, value) -> None:
         arrays = list(self.pools["state"])
         arrays[which] = self._put(arrays[which], value)
         self.pools["state"] = tuple(arrays)
 
-    def conv(self, u, w, b, scope: str = "mamba"):
-        """The causal convolution of ``u`` after the layer's tail, under the
-        family's own scope ``<scope>/conv``."""
+    def conv(self, u, w, b, scope: str = "mamba", which: int = 0):
+        """The causal convolution of ``u`` after the layer's tail ``which``,
+        under the family's own scope ``<scope>/conv``."""
         with jax.named_scope("state_read"):
-            tail = self._get(self.pools["state"][0])
+            tail = self._get(self.pools["state"][which])
         with jax.named_scope(scope), jax.named_scope("conv"):
             out, tail = self._conv(u, tail, w, b)
         with jax.named_scope("state_write"):
-            self._store(0, tail)
+            self._store(which, tail)
         return out
 
     def scan(self, u, delta, a, b, c, d):
@@ -266,8 +272,8 @@ class _ChunkState(_SlotState):
     scan_scope = "scan"
 
     def __init__(self, pools, li, slot, start, valid, impl):
-        super().__init__(pools, li)
-        self.slot, self.start, self.valid, self.impl = slot, start, valid, impl
+        super().__init__(pools, li, impl)
+        self.slot, self.start, self.valid = slot, start, valid
 
     def _get(self, array):
         mine = jax.lax.dynamic_index_in_dim(array[self.li], self.slot, 0,
@@ -284,6 +290,17 @@ class _ChunkState(_SlotState):
         return ssm_chunk_scan(u, delta, a, b, c, d, state, self.valid,
                               impl=self.impl)
 
+    def delta(self, q, k, v, g, beta):
+        """The gated delta rule through the chunk, from the slot's matrix
+        state (``ops.kda``)."""
+        with jax.named_scope("state_read"):
+            state = self._get(self.pools["state"][-1])
+        with jax.named_scope("kda"), jax.named_scope("scan"):
+            o, state = kda_chunk_scan(q, k, v, g, beta, state, self.valid)
+        with jax.named_scope("state_write"):
+            self._store(-1, state)
+        return o
+
 
 class _StepState(_SlotState):
     """Every slot's state through one decode step; an inactive slot's (free,
@@ -291,8 +308,8 @@ class _StepState(_SlotState):
 
     scan_scope = "ssm_step"
 
-    def __init__(self, pools, li, active):
-        super().__init__(pools, li)
+    def __init__(self, pools, li, active, impl):
+        super().__init__(pools, li, impl)
         self.active = active
 
     def _get(self, array):
@@ -308,6 +325,20 @@ class _StepState(_SlotState):
 
     def _scan(self, u, delta, a, b, c, d, states):
         return ssm_step(u, delta, a, b, c, d, states)
+
+    def delta(self, q, k, v, g, beta):
+        """One token of the gated delta rule a slot, in place in the group's
+        array of matrix states: an inactive slot's step is made the identity
+        (no decay, no correction), so no pass over the array selects after
+        it."""
+        with jax.named_scope("kda"), jax.named_scope("step"):
+            arrays = list(self.pools["state"])
+            o, arrays[-1] = kda_step(
+                q, k, v, jnp.where(self.active[:, None, None], g, 0.0),
+                jnp.where(self.active[:, None], beta, 0.0), arrays[-1],
+                self.li, impl=self.impl)
+            self.pools["state"] = tuple(arrays)
+        return o
 
 
 def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
@@ -460,7 +491,7 @@ def make_decode_fn(family, cfg, *, block_size: int,
                     impl=cfg.kernel_impl, **weights)
 
             mixer = attend if name != "state" else _StepState(
-                pools, li, active)
+                pools, li, active, cfg.kernel_impl)
             if two is not None:
                 mixer = _TwoPools(two, pools, layers, layer, rows, read, last)
             with jax.named_scope(f"h{layer}"):
@@ -471,12 +502,13 @@ def make_decode_fn(family, cfg, *, block_size: int,
                 routed.append(counters)
         stat = None
         if routed:
-            stat = jnp.stack([
-                sum(c["pairs"] for c in routed),
-                sum(c["experts_hit"] for c in routed),
-                functools.reduce(jnp.maximum,
-                                 [c["max_load"] for c in routed]),
-            ]).astype(jnp.int32)
+            stat = [sum(c["pairs"] for c in routed),
+                    sum(c["experts_hit"] for c in routed),
+                    functools.reduce(jnp.maximum,
+                                     [c["max_load"] for c in routed])]
+            if "groups_hit" in routed[0]:
+                stat.append(sum(c["groups_hit"] for c in routed))
+            stat = jnp.stack(stat).astype(jnp.int32)
         logits = family.head(params, x, cfg)
         with jax.named_scope("sample"):
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -606,6 +638,7 @@ PROGRAMS = {
     mimo.MimoConfig: mimo,
     lfm2.Lfm2Config: lfm2,
     evabyte.EvaByteConfig: evabyte,
+    ling.LingConfig: ling,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -692,12 +725,14 @@ class Programs:
       chunk that ends past ``index_topk`` and the one of a chunk that does
       not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers
-      with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM)
-      or ``"plain"`` (``lax.scan``); None where no layer keeps a state, or
-      the state has no scan (lfm2);
+      with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM),
+      ``"chunked"`` (ling: the delta rule's chunked mathematics in plain
+      ``jax.numpy``) or ``"plain"`` (``lax.scan``); None where no
+      layer keeps a state, or the state has no scan (lfm2);
     - ``state_form``: what a state layer keeps a slot, the names of the
       group's arrays joined by ``+``: ``"conv_tail+scan_state"`` (jamba),
-      ``"conv_tail"`` (lfm2); None where no layer keeps a state."""
+      ``"conv_tail"`` (lfm2), ``"q_tail+k_tail+v_tail+delta_state"`` (ling);
+      None where no layer keeps a state."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,
                  layers: dict[str, tuple[int, ...]]):

@@ -44,10 +44,11 @@ from .model import FUSED, family_of, make_programs
 #: it: ``pools['full'][0]`` is the group's ``k_pool`` (or its one pool of
 #: latent rows), ``[1]`` its ``v_pool`` (``copy_block`` takes ``pools[0]``,
 #: ``pools[1]``).
-_POOLS_ARG = re.compile(r"^pools(?:\[\\?'(\w+)\\?'\])?\[([01])\]$")
+_POOLS_ARG = re.compile(r"^pools(?:\[\\?'(\w+)\\?'\])?\[(\d)\]$")
 _POOL_NAMES = ("k_pool", "v_pool")
 #: the arrays of a state group by their place in it, donated like the pools
-#: (``ops.ssm``: an ``SSMState`` has both, a ``ConvTail`` the first alone)
+#: (``ops.ssm``: an ``SSMState`` has both, a ``ConvTail`` the first alone; a
+#: ``DeltaState``'s four are handed in by name, ``state_names``)
 _STATE_NAMES = SSMState.names
 
 _RELAYOUT_OPS = {"copy", "copy-start", "copy-done", "transpose", "convert"}
@@ -187,11 +188,13 @@ def state_relayouts(hlo_text: str, slots: int, arrays) -> list[str]:
     return found
 
 
-def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
+def _entry_parameters(hlo_text: str, state_names=_STATE_NAMES
+                      ) -> dict[int, tuple[str, str]]:
     """Parameter number -> (argument name, shape with layout) of the entry
     computation of an HLO module's text; a pool is named ``k_pool`` or
     ``v_pool`` however the program takes it, a state group's arrays
-    ``conv_tail`` and ``scan_state``."""
+    ``conv_tail`` and ``scan_state`` (``state_names``: what the group's
+    form calls them, ``cfg.state_rows.names``)."""
     entry = hlo_text[hlo_text.index("\nENTRY "):]
     params = {}
     for m in re.finditer(
@@ -200,7 +203,7 @@ def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
         pool = _POOLS_ARG.match(name)
         if pool:
             group = pool.group(1)
-            names = _STATE_NAMES if group == "state" else _POOL_NAMES
+            names = state_names if group == "state" else _POOL_NAMES
             name = names[int(pool.group(2))]
             if group not in (None, "full", "state"):
                 name = f"{group}.{name}"        # "window.k_pool"
@@ -208,7 +211,7 @@ def _entry_parameters(hlo_text: str) -> dict[int, tuple[str, str]]:
     return params
 
 
-def donated_pools(hlo_text: str) -> set[str]:
+def donated_pools(hlo_text: str, state_names=_STATE_NAMES) -> set[str]:
     """The arguments among ``k_pool`` / ``v_pool`` (and a state group's
     ``conv_tail`` / ``scan_state``) that the module's ``input_output_alias``
     gives to an output: the donation took."""
@@ -217,27 +220,30 @@ def donated_pools(hlo_text: str) -> set[str]:
     # "{ {1}: (195, {}, may-alias), {2}: (196, {}, may-alias) }, entry_..."
     aliased = {int(n) for n in re.findall(
         r"\{[\d, ]*\}: \((\d+), ", head.partition("entry_computation")[0])}
-    return {name for number, (name, _) in _entry_parameters(hlo_text).items()
+    return {name for number, (name, _)
+            in _entry_parameters(hlo_text, state_names).items()
             if number in aliased
-            and name.rpartition(".")[2] in _POOL_NAMES + _STATE_NAMES}
+            and name.rpartition(".")[2] in _POOL_NAMES + tuple(state_names)}
 
 
 def check_pool_programs(programs: dict, layer_elems: int,
-                        state: tuple | None = None) -> dict:
+                        state: tuple | None = None,
+                        state_names=_STATE_NAMES) -> dict:
     """Compile each of :func:`pool_programs` and report, per program, the
     pool-sized relayouts (and, with ``state = (slots, arrays)``, the
     state-sized ones: :func:`state_relayouts`), the donated pools, the layout
-    the program takes ``k_pool`` in and the compile time."""
+    the program takes ``k_pool`` in and the compile time.  ``state_names``
+    names the state group's arrays where they are not an ``SSMState``'s."""
     report = {}
     for name, (fn, args) in programs.items():
         t0 = time.monotonic()
         text = fn.lower(*args).compile().as_text()
         layouts = {arg: shape for arg, shape
-                   in _entry_parameters(text).values()}
+                   in _entry_parameters(text, state_names).values()}
         report[name] = {
             "relayouts": pool_relayouts(text, layer_elems)
             + (state_relayouts(text, *state) if state else []),
-            "donated": sorted(donated_pools(text)),
+            "donated": sorted(donated_pools(text, state_names)),
             "k_pool": layouts.get("k_pool"),
             "compile_s": round(time.monotonic() - t0, 2),
         }
@@ -296,7 +302,8 @@ def main(argv=None) -> int:
         pool_programs(cfg, max_slots=args.max_slots,
                       num_blocks=args.kv_blocks, block_size=args.block_size,
                       chunk=args.prefill_chunk, draft=args.speculate),
-        layer_elems=shape[1] * shape[2], state=state)
+        layer_elems=shape[1] * shape[2], state=state,
+        state_names=cfg.state_rows.names if state else _STATE_NAMES)
     # the pool as the process holds it between calls
     pool = jnp.zeros(shape, cfg.dtype)
     bad = failures(report, pools=len(widths),

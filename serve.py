@@ -66,6 +66,11 @@ CONFIGS = {
     # in a tumbling ring AND chunk summaries in a pool that grows; random init
     "evabyte_tiny": ("evabyte_tiny", None),
     "evabyte_6_5b": ("evabyte_6_5b", None),
+    # the ling family (models/ling.py): Kimi-Delta-Attention layers that keep
+    # a matrix state a head a slot beside latent-attention layers,
+    # group-limited experts; random init
+    "ling_tiny": ("ling_tiny", None),
+    "ling3_flash_ep8": ("ling3_flash_ep8", None),
 }
 
 

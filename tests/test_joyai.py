@@ -452,9 +452,17 @@ def test_router_takes_the_top_8_of_score_plus_bias_and_weighs_by_score():
     np.testing.assert_allclose(w0.sum(-1), 2.5, rtol=1e-5)
 
 
-def test_group_limited_routing_is_refused_not_guessed():
-    with pytest.raises(ValueError, match="n_group > 1"):
-        joyai.joyai_tiny(n_group=4, topk_group=2)
+def test_group_limited_routing_is_taken_by_the_config_refused_by_its_reference():
+    """The family routes in groups since PR 52 (``tests/test_ling.py`` holds
+    the selection to a brute-force one); groups that do not divide the
+    experts are refused, and the joyai cell's own reference, whose published
+    config has one group, still refuses rather than guess."""
+    cfg = joyai.joyai_tiny(n_group=4, topk_group=2)
+    assert (cfg.n_group, cfg.topk_group) == (4, 2)
+    with pytest.raises(ValueError, match="equal groups"):
+        joyai.joyai_tiny(n_group=3)
+    with pytest.raises(ValueError, match="equal groups"):
+        joyai.joyai_tiny(n_group=2, topk_group=3)
     with pytest.raises(NotImplementedError, match="n_group > 1"):
         REF._experts(None, None, {"n_routed_experts": 16, "n_group": 4,
                                   "num_experts_per_tok": 4})

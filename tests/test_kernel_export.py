@@ -43,6 +43,7 @@ from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
 from distributedtensorflow_tpu.ops.ssm import ssm_chunk_scan
+from distributedtensorflow_tpu.ops.kda import kda_step
 from distributedtensorflow_tpu.parallel import moe
 
 # GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
@@ -241,6 +242,17 @@ def _ssm_scan(chunk=1024, channels=5120, states=16):
                 _sds((states, channels), F32), _sds((), jnp.int32))
 
 
+def _kda_step(slots=128, heads=32, d=128, layers=2):
+    # a ling decode step of one KDA layer: every slot's state of the layer
+    # through VMEM once, the group's array aliased in and out
+    def fn(q, k, v, g, beta, pool):
+        return kda_step(q, k, v, g, beta, pool, 1, impl="pallas",
+                        interpret=False)
+    rows = _sds((slots, heads, d), F32)
+    return fn, (rows, rows, rows, rows, _sds((slots, heads), F32),
+                _sds((layers, slots, heads, d, d), F32))
+
+
 def _grouped(tile):
     def fn(x, w_gate, w_up, w_down, tile_expert, tiles_used):
         return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert,
@@ -313,6 +325,7 @@ FAMILIES = {
     "paged_attn_wide_window_sink": _paged_wide(128, 8),
     "ssm_chunk_scan": _ssm_scan(),
     "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
+    "kda_step": _kda_step(),
     # a prefill chunk's attention over K/V rows.  mimo_v25_ep16: 1024
     # queries of 64 heads, 16 a K/V head, keys 192 over values 128, a table
     # of 4,224 columns over the cell's pool of 65,536 blocks
@@ -758,6 +771,57 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(program,
         assert text.count('kernel_name = "kv_chunk_attn"') == 1
     else:
         assert text.count('kernel_name = "paged_attn"') == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+def test_delta_state_program_keeps_pool_and_state_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The ling family at its published widths, three layers deep (a dense
+    KDA layer, an MLA layer and a KDA layer with 16 of 128 experts held) and
+    with a small vocabulary: a state group beside a latent full group.  The
+    programs take the latent pool and the state group's four arrays (three
+    tails, the matrix states) as they are stored, copy or transpose no layer
+    of either, and hand all five back in place; a prefill chunk scans
+    in plain ``jax.numpy`` (the chunked form: no kernel), decode
+    steps through ``kda_step`` over the group's whole array, a layer an
+    index; the latent rows go through joyai's kernels.  (It is refused the
+    fused programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import ling3_flash_ep8
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        ling3_flash_ep8(), max_seq=2048, vocab_size=1024, num_experts=128,
+        experts_held=16, layer_types=("kda", "mla", "kda"),
+        swiglu_limits=())
+    names = cfg.state_rows.names
+    assert names == ("q_tail", "k_tail", "v_tail", "delta_state")
+    # the cell's 128 slots: at 32 the compiler leaves the tails as they lie;
+    # at 128 it wrote the q, k, v product with the slots across lanes and
+    # re-laid all three tail arrays out, a copy in and a copy out, until
+    # models/ling.py pinned the product's rows
+    programs = pool_check.pool_programs(
+        cfg, max_slots=128, num_blocks=4096, block_size=16, chunk=256,
+        draft=4, sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    _, rows, width = kv_cache.pool_shape(1, 4096, 16, 640)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width,
+        state=(128, cfg.state_rows.arrays(cfg.dtype)), state_names=names)
+    assert pool_check.failures(report, pools=1, state=names) == []
+    assert report[program]["donated"] == sorted(("k_pool",) + names)
+    fn, args = programs[program]
+    text = fn.lower(*args).as_text()
+    if program == "prefill_chunk":
+        assert len(args) == 7       # the count of real tokens
+        assert "kda_chunk_scan" not in text     # plain jax.numpy: no kernel
+        assert text.count('kernel_name = "latent_chunk_attn"') == 1
+    else:
+        assert text.count('kernel_name = "kda_step"') == 2  # a layer an index
+        assert text.count('kernel_name = "paged_latent_attn"') == 1
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
