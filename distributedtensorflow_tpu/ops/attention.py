@@ -1571,8 +1571,20 @@ def select_rows(scores, counts, k: int):
         return pos.astype(jnp.int32), jnp.minimum(counts, k).astype(jnp.int32)
 
 
-#: queries a step of the selection kernel holds, their scores resident
-SELECT_QUERIES = 8
+#: queries a step of the selection kernel holds at most, their scores
+#: resident.  A pass ends in a chain of ~180 ns a step whatever it counted
+#: (the sum across lanes, the threshold's next bit, its spread back over the
+#: lanes), which 32 queries share where 8 did
+SELECT_QUERIES = 32
+#: positions by which the selection kernel's walks differ in length: a step
+#: takes the walk that ends with the stretch holding its last candidate.  A
+#: walk is straight-line code over its positions (a loop over stretches with
+#: a trip count read at run time pays ~85 ns an iteration on a v5e, the
+#: work of 512 positions 5), and every walk is compiled: 4 at the cells'
+#: 33,792 (17 of 2,048 ran three times slower than 8 of 4,224 or these 4,
+#: and under 8,448 positions a step waits for its copies anyway: PERF.md
+#: section 6, PR 53)
+SELECT_STRETCH = 8448
 _INT_MIN = -2 ** 31
 
 
@@ -1586,78 +1598,114 @@ def _plain_select_bias(scores, counts, k: int):
     return jnp.where(picked, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _select_kernel(c_ref, s_ref, o_ref, key_sc, *, k):
+def select_walk(extent: int, rows: int) -> int:
+    """The positions of a query's ``rows`` scores that the selection kernel
+    walks in a step whose largest ``counts`` is ``extent``."""
+    return min(-(-extent // SELECT_STRETCH) * SELECT_STRETCH, rows)
+
+
+def _select_kernel(ext_ref, c_ref, s_ref, o_ref, key_sc, *, k, stretch):
     """The exact top ``k`` of each row's first ``counts`` scores without a
     sort: the scores as order-preserving integers, the ``k``-th largest found
-    a bit at a time by counting (32 passes over the resident rows), then,
-    among the entries equal to it, the position up to which they are taken
-    (ties to the lowest position: one more bisection, over positions)."""
-    x = s_ref[...]
-    x = jnp.where(x == 0.0, 0.0, x)                     # -0.0 -> 0.0
-    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
-    pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    a bit at a time by counting (32 passes), then, among the entries equal to
+    it, the position up to which they are taken (ties to the lowest
+    position: one more bisection, over positions).
+
+    ``ext_ref[step]``, the largest ``counts`` of the tile's rows, bounds
+    every pass: the tile takes the walk that ends with the stretch of
+    ``stretch`` positions holding position ``extent - 1``.  What lies past
+    that stretch is neither read nor counted, and is ``NEG_INF`` in the
+    result.  The second bisection (``bit_length(end - 1)`` passes) runs only
+    where some row of the tile has more scores equal to its threshold than
+    it still wants: a row with exactly as many takes them all."""
+    from jax.experimental import pallas as pl
+
+    s = key_sc.shape[1]
     counts = c_ref[...]                                 # (rows, 1)
-    key_sc[...] = jnp.where(pos < counts, key, jnp.int32(_INT_MIN))
     want = jnp.minimum(counts, k).astype(jnp.float32)
 
     def count(hit):
         return jnp.where(hit, 1.0, 0.0).sum(axis=1, keepdims=True)
 
-    def score_bit(b, theta):
-        # theta: the threshold's bits in the unsigned order, built from
-        # the top; the largest value with at least ``want`` keys >= it
-        cand = theta | (jnp.int32(1) << (31 - b))
-        enough = count(key_sc[...] >= (cand ^ jnp.int32(_INT_MIN))) >= want
-        return jnp.where(enough, cand, theta)
+    def walk(end):
+        """The selection among the first ``end`` (static) positions."""
+        x = s_ref[:, :end]
+        x = jnp.where(x == 0.0, 0.0, x)                 # -0.0 -> 0.0
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+        key_sc[:, :end] = jnp.where(pos < counts, key, jnp.int32(_INT_MIN))
 
-    theta = jax.lax.fori_loop(
-        0, 32, score_bit, jnp.zeros(counts.shape, jnp.int32)) \
-        ^ jnp.int32(_INT_MIN)
-    above = key_sc[...] > theta
-    left = want - count(above)          # of the equal ones, this many: >= 1
-    top = max(1, (key.shape[1] - 1).bit_length())
+        def score_bit(b, theta):
+            # theta: the threshold's bits in the unsigned order, built from
+            # the top; the largest value with at least ``want`` keys >= it
+            cand = theta | (jnp.int32(1) << (31 - b))
+            enough = count(
+                key_sc[:, :end] >= (cand ^ jnp.int32(_INT_MIN))) >= want
+            return jnp.where(enough, cand, theta)
 
-    def position_bit(b, p):
-        # the largest p with fewer than ``left`` equal keys before it: the
-        # position of the last one taken
-        cand = p | (jnp.int32(1) << (top - 1 - b))
-        few = count((key_sc[...] == theta) & (pos < cand)) < left
-        return jnp.where(few, cand, p)
+        theta = jax.lax.fori_loop(
+            0, 32, score_bit, jnp.zeros(counts.shape, jnp.int32)) \
+            ^ jnp.int32(_INT_MIN)
+        above = key_sc[:, :end] > theta
+        left = want - count(above)      # of the equal ones, this many: >= 1
+        top = max(1, (end - 1).bit_length())
 
-    last = jax.lax.fori_loop(
-        0, top, position_bit, jnp.zeros(counts.shape, jnp.int32))
-    picked = above | ((key_sc[...] == theta) & (pos <= last))
-    o_ref[...] = jnp.where(picked, 0.0, NEG_INF)
+        def position_bit(b, p):
+            # the largest p with fewer than ``left`` equal keys before it:
+            # the position of the last one taken
+            cand = p | (jnp.int32(1) << (top - 1 - b))
+            few = count((key_sc[:, :end] == theta) & (pos < cand)) < left
+            return jnp.where(few, cand, p)
+
+        last = jax.lax.cond(
+            jnp.max(count(key_sc[:, :end] == theta) - left) > 0.0,
+            lambda: jax.lax.fori_loop(0, top, position_bit,
+                                      jnp.zeros(counts.shape, jnp.int32)),
+            lambda: jnp.full(counts.shape, end, jnp.int32))
+        picked = above | ((key_sc[:, :end] == theta) & (pos <= last))
+        o_ref[:, :end] = jnp.where(picked, 0.0, NEG_INF)
+        if end < s:
+            o_ref[:, end:] = jnp.full((o_ref.shape[0], s - end), NEG_INF,
+                                      o_ref.dtype)
+
+    which = jnp.maximum(ext_ref[pl.program_id(0)] - 1, 0) // stretch
+    for i in range(-(-s // stretch)):
+        pl.when(which == i)(functools.partial(
+            walk, min((i + 1) * stretch, s)))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _select_bias_call(scores, counts, *, k, interpret):
+@functools.partial(jax.jit, static_argnames=("k", "stretch", "interpret"))
+def _select_bias_call(scores, counts, *, k, stretch, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, s = scores.shape
-    rows = SELECT_QUERIES
+    rows = min(n, SELECT_QUERIES)
+    counts = jnp.minimum(counts.astype(jnp.int32), s)
     return pl.pallas_call(
-        functools.partial(_select_kernel, k=k), name="select_rows",
-        grid=(n // rows,),
-        in_specs=[pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((rows, s), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((rows, s), lambda i: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)],
+        functools.partial(_select_kernel, k=k, stretch=stretch),
+        name="select_rows",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n // rows,),
+            in_specs=[pl.BlockSpec((rows, 1), lambda i, *_: (i, 0)),
+                      pl.BlockSpec((rows, s), lambda i, *_: (i, 0))],
+            out_specs=pl.BlockSpec((rows, s), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, s), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((n, s), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 << 20),
         interpret=interpret,
-    )(counts.reshape(n, 1).astype(jnp.int32), scores)
+    )(counts.reshape(n // rows, rows).max(axis=1), counts.reshape(n, 1),
+      scores)
 
 
 def select_formulation(queries: int, impl: str = "auto") -> str:
     """Which formulation :func:`select_bias` takes: ``"select_rows"`` (the
-    kernel: whole sublane tiles of queries) or ``"plain"`` (``lax.top_k``
-    and a scatter)."""
-    fits = queries % SELECT_QUERIES == 0
+    kernel: whole sublane tiles of queries that its tiles divide) or
+    ``"plain"`` (``lax.top_k`` and a scatter)."""
+    fits = queries % 8 == 0 and queries % min(queries, SELECT_QUERIES) == 0
     return "select_rows" if use_kernel(impl) and fits else "plain"
 
 
@@ -1665,7 +1713,14 @@ def select_bias(scores, counts, k: int, *, impl: str = "auto",
                 interpret: bool | None = None):
     """The selection of :func:`select_rows` as a bias ``(N, S)`` float32: 0
     at the ``min(k, counts)`` selected positions of each query, ``NEG_INF``
-    elsewhere (at and past ``counts`` always).  Scope ``select``."""
+    elsewhere (at and past ``counts`` always).  Scope ``select``.
+
+    The kernel (``name="select_rows"``) walks a tile of ``SELECT_QUERIES``
+    queries' scores up to the stretch of ``SELECT_STRETCH`` positions that
+    holds the tile's largest ``counts`` and no further — a score at or past
+    a query's ``counts`` may be anything, NaN too — and breaks ties by
+    position only in a tile that has one across some query's cut
+    (:func:`_select_kernel`)."""
     with jax.named_scope("select"):
         n, s = scores.shape
         if select_formulation(n, impl) == "plain":
@@ -1674,7 +1729,7 @@ def select_bias(scores, counts, k: int, *, impl: str = "auto",
             interpret = not on_tpu()
         # whole lane tiles of positions (nothing at the cells' context)
         scores = jnp.pad(scores, ((0, 0), (0, -s % LANES)))
-        return _select_bias_call(scores, counts, k=k,
+        return _select_bias_call(scores, counts, k=k, stretch=SELECT_STRETCH,
                                  interpret=interpret)[:, :s]
 
 

@@ -128,6 +128,7 @@ import numpy as np
 from ..obs import registry as obs_registry
 from ..obs import tracing as obs_tracing
 from ..obs import usage as obs_usage
+from ..ops.attention import select_walk
 from ..utils.metrics import json_sanitize
 from . import draft as spec_draft
 from . import sampling
@@ -517,13 +518,16 @@ class Engine:
         #: the current step's (device_sampled, logits_fetched)
         self._step_sampled = (0, 0)
         #: the current step's [context_tokens, latent_rows_read,
-        #: index_rows_scored]: the rows a prefill chunk's queries walk,
-        #: summed over the step's chunks, the latent rows its decode
-        #: iteration read (attended tokens x latent layers: where an indexer
-        #: selects them, ``kv.index_topk`` a slot a layer at most) and the
-        #: index keys it scored to select them (every cached token x latent
-        #: layers); counted only where a group stores latent rows
-        self._step_latent = [0, 0, 0]
+        #: index_rows_scored, select_positions_walked]: the rows a prefill
+        #: chunk's queries walk, summed over the step's chunks, the latent
+        #: rows its decode iteration read (attended tokens x latent layers:
+        #: where an indexer selects them, ``kv.index_topk`` a slot a layer
+        #: at most), the index keys it scored to select them (every cached
+        #: token x latent layers) and the positions of a query's scores the
+        #: selection of its chunks past ``kv.index_topk`` walked (the
+        #: kernel's longest walk x latent layers); counted only where a
+        #: group stores latent rows
+        self._step_latent = [0, 0, 0, 0]
         #: the current step's {group: K/V rows its decode iteration
         #: attended}; counted only over several paged groups
         self._step_rows_read: dict[str, int] = {}
@@ -896,7 +900,7 @@ class Engine:
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
         self._step_sampled = (0, 0)
-        self._step_latent = [0, 0, 0]
+        self._step_latent = [0, 0, 0, 0]
         self._step_rows_read = {}
         self._step_chunk_summaries = None
         self._step_scan = 0
@@ -1197,9 +1201,11 @@ class Engine:
             if groups:      # group-limited routing only
                 fields["moe_groups_hit"] = groups[0]
         if self.kv.latent_layers:
-            context, read, scored = self._step_latent
+            context, read, scored, walked = self._step_latent
             if context:
                 fields["context_tokens"] = context
+            if walked:
+                fields["select_positions_walked"] = walked
             if occupancy:
                 fields["latent_rows_read"] = read
                 if self.kv.index_topk:
@@ -1406,6 +1412,10 @@ class Engine:
         self.kv.set_pools(pools)
         if self.kv.latent_layers:
             self._step_latent[0] += start + c
+            if (self.kv.index_topk and start + c > self.kv.index_topk
+                    and self.programs.chunk_attention.startswith("masked_")):
+                self._step_latent[3] += self.kv.latent_layers * select_walk(
+                    start + c, self.kv.max_context)
         if self.kv.state is not None:
             self._step_scan += real
         if self._summaries is not None:

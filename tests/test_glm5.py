@@ -221,38 +221,114 @@ def test_selection_is_the_top_k_set_with_ties_to_the_lowest_position():
         assert (pos[i, real[i]:] >= n).all()
 
 
-@pytest.mark.parametrize("rows,k", [(256, 24), (384, 128), (1024, 2048)])
-def test_selection_kernel_is_the_top_k_set_with_ties(rows, k):
-    """The kernel finds the k-th largest score a bit at a time and takes the
-    equal ones by position: the same set as ``lax.top_k``'s, as a bias."""
+def _few_values(rows, k):
+    """Few distinct values, so every row has ties across its cut; a row of
+    one value, and one that alternates -0.0 with numbers."""
     rng = np.random.default_rng(rows)
     scores = jnp.asarray(rng.integers(-3, 4, (16, rows))
                          * rng.choice([0.5, 1.0], (16, rows)), jnp.float32)
-    # a row of one value, and one that alternates -0.0 with numbers
     scores = scores.at[3].set(-0.0).at[4, ::2].set(-0.0)
     counts = jnp.asarray(rng.integers(1, rows + 1, 16), jnp.int32).at[
         0].set(1).at[1].set(rows).at[2].set(min(k, rows))
-    assert attention.select_formulation(16, "pallas") == "select_rows"
-    got = attention.select_bias(scores, counts, min(k, rows), impl="pallas")
-    want = attention.select_bias(scores, counts, min(k, rows), impl="xla")
+    return scores, counts, min(k, rows)
+
+
+def _distinct(queries=8, rows=1024, seed=7):
+    return jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (queries, rows)), jnp.float32)
+
+
+def _with_a_tie_across_the_cut(scores, counts, k, row):
+    """``scores`` with row ``row``'s ``k``-th largest candidate value also at
+    the two ranks above it and the two below."""
+    x = np.array(scores)
+    order = np.argsort(-x[row, :int(counts[row])], kind="stable")
+    x[row, order[k - 3:k + 2]] = x[row, order[k - 1]]
+    return jnp.asarray(x)
+
+
+def _dead_positions_set_to(value, queries=8, rows=1024):
+    """Distinct scores with ``value`` at and past every row's ``counts``."""
+    counts = jnp.asarray(np.random.default_rng(11).integers(
+        1, rows, queries), jnp.int32).at[0].set(256).at[1].set(257)
+    dead = jnp.arange(rows)[None, :] >= counts[:, None]
+    return jnp.where(dead, value, _distinct(queries, rows)), counts, 64
+
+
+#: scores, counts, k; the kernel's walks differ by 256 positions here
+_SELECTIONS = {
+    "256-24": lambda: _few_values(256, 24),
+    "384-128": lambda: _few_values(384, 128),
+    "1024-2048": lambda: _few_values(1024, 2048),
+    "distinct": lambda: (_distinct(), jnp.asarray(
+        np.random.default_rng(8).integers(1, 1025, 8), jnp.int32), 64),
+    "counts_on_a_stretch_boundary": lambda: (
+        _distinct(), jnp.full((8,), 512, jnp.int32), 64),
+    "counts_one_under_a_boundary": lambda: (
+        _distinct(), jnp.full((8,), 511, jnp.int32), 64),
+    "counts_one_over_a_boundary": lambda: (
+        _distinct(), jnp.full((8,), 513, jnp.int32), 64),
+    "an_extent_of_one_stretch": lambda: (_distinct(), jnp.asarray(
+        [1, 7, 64, 65, 128, 200, 255, 256], jnp.int32), 64),
+    "an_extent_of_the_whole_row": lambda: (_distinct(), jnp.asarray(
+        [3, 100, 300, 1024, 513, 64, 900, 1], jnp.int32), 64),
+    "a_chunk_of_consecutive_counts_over_three_tiles": lambda: (
+        _distinct(96), 200 + jnp.arange(96, dtype=jnp.int32) * 8, 64),
+    "a_tie_across_the_cut_in_one_row_of_eight": lambda: (
+        _with_a_tie_across_the_cut(
+            _distinct(), np.full(8, 700), 64, row=5),
+        jnp.full((8,), 700, jnp.int32), 64),
+    "a_row_of_one_value": lambda: (
+        _distinct().at[2].set(1.5), jnp.asarray(
+            [600, 600, 600, 10, 64, 65, 1000, 300], jnp.int32), 64),
+    "nan_at_and_past_counts": lambda: _dead_positions_set_to(jnp.nan),
+    "inf_at_and_past_counts": lambda: _dead_positions_set_to(jnp.inf),
+    "minus_inf_at_and_past_counts": lambda: _dead_positions_set_to(-jnp.inf),
+    "3e38_at_and_past_counts": lambda: _dead_positions_set_to(3e38),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SELECTIONS))
+def test_selection_kernel_is_the_top_k_set_with_ties(case, monkeypatch):
+    """The kernel finds the k-th largest score a bit at a time, over the
+    stretches up to its tile's last candidate alone, and takes the equal ones
+    by position where a tile has more of them than it wants: the same set as
+    ``lax.top_k``'s, as a bias, whatever lies at and past ``counts``."""
+    monkeypatch.setattr(attention, "SELECT_STRETCH", 256)
+    scores, counts, k = _SELECTIONS[case]()
+    n, rows = scores.shape
+    assert attention.select_formulation(n, "pallas") == "select_rows"
+    got = attention.select_bias(scores, counts, k, impl="pallas")
+    want = attention.select_bias(scores, counts, k, impl="xla")
     assert got.dtype == jnp.float32 and set(np.unique(got).tolist()) <= {
         0.0, attention.NEG_INF}
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(
         (np.asarray(got) == 0).sum(-1), np.minimum(counts, k))
-    # distinct random scores
-    x = jnp.asarray(rng.standard_normal((8, rows)), jnp.float32)
-    c = jnp.asarray(rng.integers(1, rows + 1, 8), jnp.int32)
-    np.testing.assert_array_equal(
-        attention.select_bias(x, c, min(k, rows), impl="pallas"),
-        attention.select_bias(x, c, min(k, rows), impl="xla"))
+    # what a dead position holds decides nothing
+    live = jnp.arange(rows)[None, :] < counts[:, None]
+    np.testing.assert_array_equal(want, attention.select_bias(
+        jnp.where(live, scores, 0.0), counts, k, impl="xla"))
 
 
-@pytest.mark.parametrize("start", [112, 600, 1008])
-def test_masked_chunk_kernels_match_the_gathered_plain_formulation(start):
+def test_selection_walk_ends_with_the_stretch_of_the_last_candidate():
+    assert attention.SELECT_STRETCH % attention.LANES == 0
+    walks = [attention.select_walk(e, 33792) for e in (
+        1, attention.SELECT_STRETCH, attention.SELECT_STRETCH + 1, 33792)]
+    assert walks == [attention.SELECT_STRETCH, attention.SELECT_STRETCH,
+                     2 * attention.SELECT_STRETCH, 33792]
+    assert attention.select_walk(500, 384) == 384
+
+
+@pytest.mark.parametrize("start", [112, 360, 600, 1008])
+def test_masked_chunk_kernels_match_the_gathered_plain_formulation(
+        start, monkeypatch):
     """A chunk past ``topk`` through the kernels (the indexer, the selection
     as a bias, the dense walk under it) against the plain formulation (the
-    selected rows gathered by index): the same rows attended."""
+    selected rows gathered by index): the same rows attended.  The chunks
+    from 360 and 600 end inside a stretch of the selection's, the last on
+    one."""
+    monkeypatch.setattr(attention, "SELECT_STRETCH", 128)
     bs, t = 16, 16
     form = attention.SparseLatentRows(rank=128, rope_dim=8,
                                       scale=24 ** -0.5, index_dim=128,
@@ -332,6 +408,7 @@ def test_served_through_the_kernels_matches_the_reference(monkeypatch):
     """The whole path with every kernel interpreted: rows and index keys of
     whole lane tiles, ``index_topk`` 128 of contexts to 640."""
     monkeypatch.setattr(attention, "INDEX_STRETCH", 128)
+    monkeypatch.setattr(attention, "SELECT_STRETCH", 256)
     cfg = joyai.glm5_tiny(
         dtype=jnp.float32, kernel_impl="pallas", kv_lora_rank=128,
         qk_rope_head_dim=8, index_head_dim=128, index_topk=128, max_seq=640,
@@ -347,6 +424,12 @@ def test_served_through_the_kernels_matches_the_reference(monkeypatch):
         == "masked_latent_chunk_attn+latent_chunk_attn"
     want = _reference_logits(cfg, params, prompt, tokens)
     np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+    # a chunk past index_topk records how far its selection walked: the
+    # stretch its end lies in, of the table's 640 positions, x 3 layers.
+    # One iteration prefilled the ten chunks: two end past 128, at 144, 160
+    [chunks] = [r for r in eng.step_records() if "context_tokens" in r]
+    assert chunks["context_tokens"] == sum(range(16, 161, 16))
+    assert chunks["select_positions_walked"] == 2 * 3 * 256
 
 
 def test_formulations_fall_back_where_the_kernels_do_not_fit():
@@ -512,6 +595,9 @@ def test_step_log_carries_the_indexer_counters(f32_model):
     assert decodes and all(
         {"index_rows_scored", "latent_rows_read", "moe_pairs"} <= set(r)
         for r in decodes)
+    # the plain formulation selects with lax.top_k: no kernel walked
+    assert not any("select_positions_walked" in r
+                   for r in eng.step_records())
     # iteration i scores the prompt, the tokens before it and its own, and
     # attends index_topk of them at most
     assert [r["index_rows_scored"] for r in decodes] == [

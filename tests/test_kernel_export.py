@@ -314,6 +314,8 @@ FAMILIES = {
     "paged_attn_20_on_1": _paged(None, slots=32, heads=20, d=128,
                                  columns=2112, width=128),
     "select_rows": _select(),
+    # a chunk of no whole tile of 32 queries: one tile of 24
+    "select_rows_24": _select(queries=24, rows=8448),
     "masked_latent_chunk_attn": _masked_latent_chunk(),
     "index_scores_chunk": _index(1024, 1),
     "index_scores_step": _index(1, 24),

@@ -2,7 +2,8 @@
 """Time GLM-5's sparse latent attention alone on the chip, piece by piece and
 against the dense formulation, then its two programs.
 
-    chiprun -- python tools/sparse_forms.py [--contexts 2048,8192,32768]
+    chiprun -- python tools/sparse_forms.py
+        [--contexts 2048,4096,8192,16384,32768]
         [--pieces 1] [--programs 1] [--decode 2000,15000,30000]
 
 No engine, no HTTP.  One layer's pieces at a prefill chunk's shape (``--chunk``
@@ -10,7 +11,8 @@ queries of one slot whose chunk ends at each of ``--contexts``) and at a
 decode step's (``--slots`` queries, each slot ``--decode`` tokens long):
 ``index_scores`` (kernel and plain loop), ``select_rows`` (``lax.top_k`` of
 ``index_topk``) and ``select_bias`` (the same set by the bisection kernel,
-as a mask), ``sparse_latent_attention`` (gather + kernel, gather + plain
+as a mask: on distinct scores and with a tie planted across every query's
+cut, ``variant``), ``sparse_latent_attention`` (gather + kernel, gather + plain
 products), the whole sparse form and the dense form (``LatentRows.chunk``:
 every cached row, decompressed) over the same pool; then
 ``serve/model.py:make_programs``'s prefill chunk and decode iteration.  The
@@ -37,7 +39,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--config", default="glm5_ep16")
     p.add_argument("--chunk", type=int, default=1024)
-    p.add_argument("--contexts", default="2048,8192,32768")
+    p.add_argument("--contexts", default="2048,4096,8192,16384,32768")
     p.add_argument("--decode", default="2000,15000,30000")
     p.add_argument("--pieces", type=int, default=1)
     p.add_argument("--programs", type=int, default=1)
@@ -93,6 +95,19 @@ def main(argv=None) -> int:
     def draw(*shape, dtype=dt):
         return jnp.asarray(rng.standard_normal(shape), dtype)
 
+    def tied(scores, counts):
+        """``scores`` with each row's ``k``-th largest candidate value also
+        at the two ranks above and the two below it: a tie across the cut
+        (on the device before anything is timed)."""
+        x, n = np.array(scores), np.asarray(counts)[:, None]
+        live = np.arange(x.shape[1])[None, :] < n
+        near = np.argsort(np.where(live, -x, np.inf), axis=1,
+                          kind="stable")[:, k - 3:k + 2]
+        np.put_along_axis(x, near, np.where(
+            n > k + 2, np.take_along_axis(x, near[:, 2:3], 1),
+            np.take_along_axis(x, near, 1)), 1)
+        return jax.block_until_ready(jnp.asarray(x))
+
     contexts = [int(c) for c in args.contexts.split(",")]
     decodes = [int(c) for c in args.decode.split(",") if c]
     table_row = jnp.asarray(rng.permutation(cols), jnp.int32)
@@ -129,9 +144,11 @@ def main(argv=None) -> int:
             s, n, k, impl=cfg.kernel_impl))
         for end in contexts:
             counts = end - t + 1 + jnp.arange(t, dtype=jnp.int32)
-            row(piece="select_bias", shape="chunk",
-                name=attention.select_formulation(t, cfg.kernel_impl),
-                context=end, ms=timed(bias, scores, counts))
+            for variant, x in (("distinct", scores),
+                               ("tie", tied(scores, counts))):
+                row(piece="select_bias", shape="chunk", variant=variant,
+                    name=attention.select_formulation(t, cfg.kernel_impl),
+                    context=end, ms=timed(bias, x, counts))
         q_abs = draw(t, h, width)
         for impl in ("pallas", "xla"):
             f = jax.jit(lambda q, pl_, r, c, impl=impl:
