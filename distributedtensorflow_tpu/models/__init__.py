@@ -50,6 +50,11 @@ from .ling import (  # noqa: F401
     ling3_flash_ep8,
     ling_tiny,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    nemotron3_super_ep4,
+    nemotron_h_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

@@ -9,7 +9,9 @@ the scan state ``s`` of ``d_state`` values a channel, and the last ``d_conv -
 :class:`ConvTail` is the state of a layer that keeps the tail and has no scan
 (``models.lfm2``'s gated short convolution); :class:`DeltaState` that of a
 Kimi-Delta-Attention layer (``models.ling``): three tails and a matrix a head,
-whose forms are ``ops.kda``'s.
+whose forms are ``ops.kda``'s; :class:`SSDState` that of a Mamba-2 layer
+(``models.nemotron_h``): one tail and a matrix a head, whose forms are
+``ops.ssd``'s.
 
 The recurrence, a token ``t``, channels ``c`` and states ``n``, in float32::
 
@@ -18,7 +20,7 @@ The recurrence, a token ``t``, channels ``c`` and states ``n``, in float32::
     y_t[c]    = sum_n s_t[n, c] * C_t[n] + D[c] * u_t[c]
 
 The decay is per (channel, state), so there is no matmul form (the chunked
-products of later state-space models need a scalar decay a head); a
+products of Mamba-2 need a scalar decay a head, and ``ops.ssd`` has them); a
 parallel form materialises ``(T, N, C)`` float32 operands in HBM.  The state
 is laid out ``(N, C)``: **channels across lanes, states across sublanes**
 (``A`` is stored so too, the published ``A_log`` transposed).  A step with
@@ -52,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime import on_tpu, use_kernel
-from . import kda
+from . import kda, ssd
 
 LANES = 128
 #: tokens a grid step of the kernel (a multiple of 8)
@@ -149,6 +151,38 @@ class DeltaState(_SlotArrays):
     def step_formulation(self, impl: str) -> str:
         return kda.step_formulation(self.heads, self.key_dim, self.value_dim,
                                     impl)
+
+
+@dataclasses.dataclass(frozen=True)
+class SSDState(_SlotArrays):
+    """What a Mamba-2 layer keeps a slot (``ops.ssd``): the convolution tail
+    of its ``[x | B | C]`` (``heads * head_dim + 2 * groups * d_state``
+    channels) and the matrix state a head in float32, ``(heads, head_dim,
+    d_state)``: the states across lanes."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    d_state: int
+    d_conv: int
+
+    names = ("conv_tail", "ssd_state")
+
+    @property
+    def conv_channels(self) -> int:
+        return self.heads * self.head_dim + 2 * self.groups * self.d_state
+
+    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
+        return (_tail_array(self.conv_channels, self.d_conv, dtype),
+                ((self.heads, self.head_dim, self.d_state),
+                 jnp.dtype(jnp.float32)))
+
+    def chunk_formulation(self, chunk: int, impl: str) -> str:
+        return ssd.chunk_scan_formulation(chunk)
+
+    def step_formulation(self, impl: str) -> str:
+        return ssd.step_formulation(self.heads, self.head_dim, self.groups,
+                                    self.d_state, impl)
 
 
 # -- the convolution and its tail --------------------------------------------

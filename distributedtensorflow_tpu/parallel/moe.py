@@ -489,19 +489,24 @@ def group_plan(idx: jax.Array, held: tuple[int, int], token_mask=None,
             "max_load": counts.max()}
 
 
-def _grouped_swiglu_xla(x_rows, w_gate, w_up, w_down, tile_expert,
-                        tiles_used, tile):
-    """Plain formulation of the grouped expert SwiGLU: one loop turn a
-    used tile, the tile's expert sliced out of the stacked matrices."""
+def _grouped_ffn_xla(x_rows, weights, tile_expert, tiles_used, tile):
+    """Plain formulation of the grouped expert feed-forward: one loop turn a
+    used tile, the tile's expert sliced out of the stacked matrices.
+    ``weights`` is ``(w_gate, w_up, w_down)``, the SwiGLU ``silu(x Wg) * (x
+    Wu)``, or ``(w_up, w_down)``, the ungated ``relu(x Wu)^2``."""
+    *w_ups, w_down = weights
+
     def body(i, out):
         e = tile_expert[i]
         x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile, 0)
-        g = jnp.dot(x, lax.dynamic_index_in_dim(w_gate, e, 0, False),
-                    preferred_element_type=jnp.float32)
-        u = jnp.dot(x, lax.dynamic_index_in_dim(w_up, e, 0, False),
-                    preferred_element_type=jnp.float32)
-        hid = (jax.nn.silu(g) * u).astype(x_rows.dtype)
-        y = jnp.dot(hid, lax.dynamic_index_in_dim(w_down, e, 0, False),
+        ups = [jnp.dot(x, lax.dynamic_index_in_dim(w, e, 0, False),
+                       preferred_element_type=jnp.float32) for w in w_ups]
+        if len(ups) == 2:
+            hid = jax.nn.silu(ups[0]) * ups[1]
+        else:
+            hid = jnp.square(jnp.maximum(ups[0], 0.0))
+        y = jnp.dot(hid.astype(x_rows.dtype),
+                    lax.dynamic_index_in_dim(w_down, e, 0, False),
                     preferred_element_type=jnp.float32)
         return lax.dynamic_update_slice_in_dim(
             out, y.astype(out.dtype), i * tile, 0)
@@ -514,7 +519,7 @@ def dropless_moe(
     h: jax.Array,                 # (T, d)
     router_kernel: jax.Array,     # (d, E_published)
     select_bias: jax.Array,       # (E_published,)
-    experts: dict,                # w_gate, w_up (E_held, d, m), w_down (E_held, m, d)
+    experts: dict,                # w_gate, w_up (E_held, d_in, m), w_down (E_held, m, d_in)
     *,
     held: tuple[int, int],
     top_k: int,
@@ -525,15 +530,22 @@ def dropless_moe(
     topk_group: int = 1,
     token_mask: jax.Array | None = None,
     impl: str = "auto",
+    experts_in: jax.Array | None = None,    # (T, d_in)
 ) -> tuple[jax.Array, dict]:
     """The held experts' share of a token-choice MoE layer, dropless.
 
     Routes every token over the router's full width, computes ``sum_j w_j
-    * Expert_{top_j}(h)`` over the choices that are held here, and returns
+    * Expert_{top_j}(x)`` over the choices that are held here, and returns
     it with the counters of :func:`group_plan` (``pairs``, ``experts_hit``,
     ``max_load``; under group-limited routing, ``n_group`` > 1, also
     ``groups_hit``: the groups the real tokens' choices fall in, summed over
-    the tokens).  The shared expert is the caller's, added once.  On
+    the tokens).  The experts read ``x = h``, the router's input, or
+    ``experts_in`` where the caller gives one (a latent expert layer: the
+    router reads the token, the experts a projection of it ``d_in`` wide, and
+    the sum comes back ``d_in`` wide).  ``experts`` holds a SwiGLU expert's
+    ``w_gate``, ``w_up`` (E_held, d_in, m) and ``w_down`` (E_held, m, d_in),
+    or without a ``w_gate`` the ungated ``relu(x W_up)^2 W_down``.  The
+    shared expert is the caller's, added once.  On
     one chip nothing is exchanged and nothing stands in for the absent
     experts.  ``impl``: ``"pallas"`` (``ops.grouped_matmul``), ``"xla"``,
     or ``"auto"`` (the kernel on a TPU)."""
@@ -547,23 +559,24 @@ def dropless_moe(
             route_norm_eps=route_norm_eps, n_group=n_group,
             topk_group=topk_group)
         plan = group_plan(idx, held, token_mask, tile)
+    x = h if experts_in is None else experts_in
     with jax.named_scope("experts"):
         x_rows = jnp.concatenate(
-            [h, jnp.zeros((1, h.shape[-1]), h.dtype)])[plan["src"]]
+            [x, jnp.zeros((1, x.shape[-1]), x.dtype)])[plan["src"]]
+        weights = [experts[name] for name in ("w_gate", "w_up", "w_down")
+                   if name in experts]
         if runtime.use_kernel(impl):
-            y_rows = gmm.grouped_swiglu(
-                x_rows, experts["w_gate"], experts["w_up"],
-                experts["w_down"], plan["tile_expert"], plan["tiles_used"],
-                tile=tile)
+            grouped = gmm.grouped_swiglu if len(weights) == 3 \
+                else gmm.grouped_relu2
+            y_rows = grouped(x_rows, *weights, plan["tile_expert"],
+                             plan["tiles_used"], tile=tile)
         else:
-            y_rows = _grouped_swiglu_xla(
-                x_rows, experts["w_gate"], experts["w_up"],
-                experts["w_down"], plan["tile_expert"], plan["tiles_used"],
-                tile)
+            y_rows = _grouped_ffn_xla(x_rows, weights, plan["tile_expert"],
+                                      plan["tiles_used"], tile)
         y_rows = jnp.concatenate(
             [y_rows, jnp.zeros((1, y_rows.shape[-1]), y_rows.dtype)])
         picked = y_rows[plan["dest"]].astype(jnp.float32)      # (T, k, d)
-        out = (picked * w[..., None]).sum(1).astype(h.dtype)
+        out = (picked * w[..., None]).sum(1).astype(x.dtype)
     counters = {k: plan[k] for k in ("pairs", "experts_hit", "max_load")}
     if n_group > 1:
         group = idx // (router_kernel.shape[-1] // n_group)

@@ -11,7 +11,7 @@ shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
 ``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``,
-``models.evabyte``, ``models.ling``):
+``models.evabyte``, ``models.ling``, ``models.nemotron_h``):
 ``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
@@ -79,7 +79,8 @@ and the three programs differ only in where the rows live:
 ``pools`` is ``{group: its pools}`` (``(k_pool, v_pool)``, the one pool
 of latent rows, or a state group's arrays — ``(convolution tails, scan
 states)`` for jamba, ``(convolution tails,)`` for lfm2, ``(q tails, k tails,
-v tails, matrix states)`` for ling: what ``cfg.state_rows.arrays`` lists —,
+v tails, matrix states)`` for ling, ``(convolution tails, matrix states)``
+for nemotron_h: what ``cfg.state_rows.arrays`` lists —,
 ``(layers, slots, ...)`` each) and ``tables`` ``{group: page
 table}`` (a state group's is the one column that names the slot;
 ``serve.kv_cache.GroupedKVCache``: layers in groups by attention
@@ -116,7 +117,12 @@ add ``h<i>/attn/{qk_norm,rope}`` and whose conv layers have
 ``h<i>/{state_read,state_write}`` and ``h<i>/conv/{in_proj,gate_in,conv,
 gate_out,out_proj}``, ``h<i>/latent_attn`` again for ling's MLA layers (with
 ``out_gate``), whose KDA layers have ``h<i>/{state_read,state_write}`` and
-``h<i>/kda/{proj,conv,gate,scan|step,out_proj}``, ``h<i>/eva_attn`` for evabyte, whose hook adds
+``h<i>/kda/{proj,conv,gate,scan|step,out_proj}``, ``h<i>/attn`` for
+nemotron_h, whose Mamba-2 layers have ``h<i>/{state_read,state_write}`` and
+``h<i>/mamba2/{in_proj,conv,scan|step,gated_norm,out_proj}`` and whose expert
+layers ``h<i>/moe/{latent_down,latent_up}`` around the experts (a layer of
+that family is one part, and an expert layer is in no cache group: it calls
+no hook), ``h<i>/eva_attn`` for evabyte, whose hook adds
 ``summarise`` and ``summary_write`` beside ``kv_write`` and ``paged_attn``
 (the block's own are ``qkv``, ``rope`` and ``proj``); an expert layer's FFN is
 ``h<i>/{router,experts}``, a dense one's ``h<i>/mlp``), ``head``, ``sample``,
@@ -132,8 +138,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..models import afmoe, evabyte, gpt, jamba, joyai, lfm2, ling, mimo
+from ..models import (afmoe, evabyte, gpt, jamba, joyai, lfm2, ling, mimo,
+                      nemotron_h)
 from ..ops.kda import kda_chunk_scan, kda_step
+from ..ops.ssd import ssd_chunk_scan, ssd_step
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
 from .kv_cache import group_rows
 from .sampling import sample_burst
@@ -155,6 +163,11 @@ def _group_of(layers: dict[str, tuple[int, ...]]) -> dict[int, tuple]:
         for i, layer in enumerate(ls):
             where.setdefault(layer, (name, i))
     return where
+
+
+#: where a layer in no cache group lives (``models.nemotron_h``'s expert
+#: layers: a part that mixes no tokens keeps nothing, and calls no hook)
+_NO_GROUP = (None, 0)
 
 
 def _two_pool_form(cfg):
@@ -223,16 +236,16 @@ class _TwoPools:
 
 class _SlotState:
     """The ``mixer`` hook of a state layer (``models.jamba``,
-    ``models.lfm2``, ``models.ling``): the programs' own, as ``attend`` is.
-    ``conv``, ``scan`` and ``delta`` read the layer's state out of the group's
-    arrays (scope ``state_read``), run the form (``<scope>/conv``;
-    ``mamba/scan`` or ``mamba/ssm_step``; ``kda/scan`` or ``kda/step``) and
-    store the state back (``state_write``) — a convolution tail is array
-    ``which`` (0 where the layer has one convolution; ling's q, k and v are 0,
-    1, 2) and the scan or matrix state the last of ``cfg.state_rows.arrays``
-    (a family that keeps the tail alone calls neither ``scan`` nor
-    ``delta``).  ``pools`` is the program's dict of pools, updated in
-    place."""
+    ``models.lfm2``, ``models.ling``, ``models.nemotron_h``): the programs'
+    own, as ``attend`` is.  ``conv``, ``scan``, ``delta`` and ``ssd`` read the
+    layer's state out of the group's arrays (scope ``state_read``), run the
+    form (``<scope>/conv``; ``mamba/scan`` or ``mamba/ssm_step``; ``kda/scan``
+    or ``kda/step``; ``mamba2/scan`` or ``mamba2/step``) and store the state
+    back (``state_write``) — a convolution tail is array ``which`` (0 where
+    the layer has one convolution; ling's q, k and v are 0, 1, 2) and the scan
+    or matrix state the last of ``cfg.state_rows.arrays`` (a family that keeps
+    the tail alone calls none of the three).  ``pools`` is the program's dict
+    of pools, updated in place."""
 
     def __init__(self, pools: dict, li: int, impl: str = "auto"):
         self.pools, self.li, self.impl = pools, li, impl
@@ -255,11 +268,11 @@ class _SlotState:
 
     def scan(self, u, delta, a, b, c, d):
         with jax.named_scope("state_read"):
-            state = self._get(self.pools["state"][1])
+            state = self._get(self.pools["state"][-1])
         with jax.named_scope("mamba"), jax.named_scope(self.scan_scope):
             y, state = self._scan(u, delta, a, b, c, d, state)
         with jax.named_scope("state_write"):
-            self._store(1, state)
+            self._store(-1, state)
         return y
 
 
@@ -301,6 +314,28 @@ class _ChunkState(_SlotState):
             self._store(-1, state)
         return o
 
+    def _get_slot(self, array):
+        """``_get`` with layer and slot sliced in one step: ``array[layer]``
+        first is a copy of every slot's rows (537 MB a layer of
+        nemotron3_super_ep4's matrices at 128 slots: 1.6 ms a layer a chunk,
+        and the compiler re-computing other work to make room for it; my chip
+        run, PR 54)."""
+        mine = jax.lax.dynamic_slice(
+            array, (self.li, self.slot) + (0,) * (array.ndim - 2),
+            (1, 1) + array.shape[2:])[0, 0]
+        return jnp.where(self.start == 0, jnp.zeros_like(mine), mine)
+
+    def ssd(self, x, dt, a, b, c, d):
+        """Mamba-2's scalar-decay recurrence through the chunk, from the
+        slot's matrix state (``ops.ssd``)."""
+        with jax.named_scope("state_read"):
+            state = self._get_slot(self.pools["state"][-1])
+        with jax.named_scope("mamba2"), jax.named_scope("scan"):
+            y, state = ssd_chunk_scan(x, dt, a, b, c, d, state, self.valid)
+        with jax.named_scope("state_write"):
+            self._store(-1, state)
+        return y
+
 
 class _StepState(_SlotState):
     """Every slot's state through one decode step; an inactive slot's (free,
@@ -339,6 +374,18 @@ class _StepState(_SlotState):
                 self.li, impl=self.impl)
             self.pools["state"] = tuple(arrays)
         return o
+
+    def ssd(self, x, dt, a, b, c, d):
+        """One token of Mamba-2's recurrence a slot, in place in the group's
+        array of matrix states: an inactive slot's ``dt`` is 0, the identity
+        (``ops.ssd.ssd_step``), so no pass over the array selects after it."""
+        with jax.named_scope("mamba2"), jax.named_scope("step"):
+            arrays = list(self.pools["state"])
+            y, arrays[-1] = ssd_step(
+                x, jnp.where(self.active[:, None], dt, 0.0), a, b, c, d,
+                arrays[-1], self.li, impl=self.impl)
+            self.pools["state"] = tuple(arrays)
+        return y
 
 
 def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
@@ -387,7 +434,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                                  block_size=bs, impl=cfg.kernel_impl)
         x = family.embed(params, tokens, cfg)
         for layer in range(cfg.num_layers):
-            name, li = where[layer]
+            name, li = where.get(layer, _NO_GROUP)
 
             def attend(q, *stored, name=name, li=li, layer=layer, **weights):
                 form = forms[name]
@@ -479,7 +526,7 @@ def make_decode_fn(family, cfg, *, block_size: int,
         x = family.embed(params, tokens, cfg)
         routed = []
         for layer in range(cfg.num_layers):
-            name, li = where[layer]
+            name, li = where.get(layer, _NO_GROUP)
 
             def attend(q, *stored, name=name, li=li, layer=layer, **weights):
                 form = forms[name]
@@ -639,6 +686,7 @@ PROGRAMS = {
     lfm2.Lfm2Config: lfm2,
     evabyte.EvaByteConfig: evabyte,
     ling.LingConfig: ling,
+    nemotron_h.NemotronHConfig: nemotron_h,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -726,12 +774,14 @@ class Programs:
       not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers
       with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM),
-      ``"chunked"`` (ling: the delta rule's chunked mathematics in plain
-      ``jax.numpy``) or ``"plain"`` (``lax.scan``); None where no
+      ``"chunked"`` (ling, nemotron_h: the delta rule's, or the scalar
+      decay's, chunked mathematics in plain ``jax.numpy``) or ``"plain"``
+      (``lax.scan``); None where no
       layer keeps a state, or the state has no scan (lfm2);
     - ``state_form``: what a state layer keeps a slot, the names of the
       group's arrays joined by ``+``: ``"conv_tail+scan_state"`` (jamba),
-      ``"conv_tail"`` (lfm2), ``"q_tail+k_tail+v_tail+delta_state"`` (ling);
+      ``"conv_tail"`` (lfm2), ``"q_tail+k_tail+v_tail+delta_state"`` (ling),
+      ``"conv_tail+ssd_state"`` (nemotron_h);
       None where no layer keeps a state."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,

@@ -71,6 +71,11 @@ CONFIGS = {
     # group-limited experts; random init
     "ling_tiny": ("ling_tiny", None),
     "ling3_flash_ep8": ("ling3_flash_ep8", None),
+    # the nemotron_h family (models/nemotron_h.py): one part a layer — Mamba-2
+    # layers that keep a matrix state a head a slot, latent expert layers,
+    # attention layers without rotary; random init
+    "nemotron_h_tiny": ("nemotron_h_tiny", None),
+    "nemotron3_super_ep4": ("nemotron3_super_ep4", None),
 }
 
 

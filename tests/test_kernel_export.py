@@ -40,10 +40,14 @@ from distributedtensorflow_tpu.ops.flash_attention import (
 )
 from distributedtensorflow_tpu.ops import fused_xent
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
-from distributedtensorflow_tpu.ops.grouped_matmul import grouped_swiglu
+from distributedtensorflow_tpu.ops.grouped_matmul import (
+    grouped_relu2,
+    grouped_swiglu,
+)
 from distributedtensorflow_tpu.ops.layernorm import layer_norm
 from distributedtensorflow_tpu.ops.ssm import ssm_chunk_scan
 from distributedtensorflow_tpu.ops.kda import kda_step
+from distributedtensorflow_tpu.ops.ssd import ssd_step
 from distributedtensorflow_tpu.parallel import moe
 
 # GPT-2 small at the trainer leg's shapes: batch 16, seq 1024, 12 heads of
@@ -253,6 +257,31 @@ def _kda_step(slots=128, heads=32, d=128, layers=2):
                 _sds((layers, slots, heads, d, d), F32))
 
 
+def _ssd_step(slots=128, heads=128, dim=64, groups=8, states=128, layers=2):
+    # a nemotron_h decode step of one Mamba-2 layer at the published widths:
+    # every slot's state of the layer through VMEM once, a group's 16 heads a
+    # grid step, the group's array aliased in and out
+    def fn(x, dt, a, b, c, d, pool):
+        return ssd_step(x, dt, a, b, c, d, pool, 1, impl="pallas",
+                        interpret=False)
+    bc = _sds((slots, groups, states), BF16)
+    return fn, (_sds((slots, heads, dim), BF16), _sds((slots, heads), F32),
+                _sds((heads,), F32), bc, bc, _sds((heads,), F32),
+                _sds((layers, slots, heads, dim, states), F32))
+
+
+def _grouped_ungated(tile, rows):
+    # nemotron_h's latent experts at the published widths (1,024 -> 2,688 ->
+    # 1,024, relu^2, no gate): the up kernel takes its weight block whole
+    # (2,688 is no multiple of 256), 16 of the 128 held experts
+    def fn(x, w_up, w_down, tile_expert, tiles_used):
+        return grouped_relu2(x, w_up, w_down, tile_expert, tiles_used,
+                             tile=tile, interpret=False)
+    return fn, (_sds((rows, 1024), BF16), _sds((16, 1024, 2688), BF16),
+                _sds((16, 2688, 1024), BF16),
+                _sds((rows // tile,), jnp.int32), _sds((), jnp.int32))
+
+
 def _grouped(tile):
     def fn(x, w_gate, w_up, w_down, tile_expert, tiles_used):
         return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert,
@@ -328,6 +357,12 @@ FAMILIES = {
     "ssm_chunk_scan": _ssm_scan(),
     "ssm_chunk_scan_2048": _ssm_scan(chunk=2048),
     "kda_step": _kda_step(),
+    "ssd_step": _ssd_step(),
+    # a decode batch of 128 slots top 22 of 512 (the small tile), and a
+    # prefill chunk's wide tile
+    "moe_grouped_ungated": _grouped_ungated(16, 128 * 22 + 16 * 16),
+    "moe_grouped_ungated_wide": _grouped_ungated(
+        moe.GROUP_TILE_WIDE, 24 * moe.GROUP_TILE_WIDE),
     # a prefill chunk's attention over K/V rows.  mimo_v25_ep16: 1024
     # queries of 64 heads, 16 a K/V head, keys 192 over values 128, a table
     # of 4,224 columns over the cell's pool of 65,536 blocks
@@ -824,6 +859,57 @@ def test_delta_state_program_keeps_pool_and_state_in_place_on_a_v5e(
     else:
         assert text.count('kernel_name = "kda_step"') == 2  # a layer an index
         assert text.count('kernel_name = "paged_latent_attn"') == 1
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
+def test_ssd_state_program_keeps_pools_and_state_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The nemotron_h family at its published widths, four layers deep (a
+    Mamba-2 layer, an expert layer with 16 of 64 experts held, an attention
+    layer, a Mamba-2 layer) and with a small vocabulary: a state group beside
+    a K/V full group, the expert layer in neither.  The programs take the K/V
+    pools and the state group's two arrays (the tail, the matrix states) as
+    they are stored, copy or transpose no layer of either, and hand all four
+    back in place at the cell's 128 slots; a prefill chunk scans in plain
+    ``jax.numpy`` (the chunked form: no kernel), decode steps through
+    ``ssd_step`` over the group's whole array, a layer an index; 16 query
+    heads a K/V head go through ``paged_attn`` and ``kv_chunk_attn``, the
+    ungated experts through the grouped kernels.  (It is refused the fused
+    programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import nemotron3_super_ep4
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(_v5e_mesh(1), P())
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(
+        nemotron3_super_ep4(), max_seq=2048, vocab_size=1024, num_experts=64,
+        experts_held=16, pattern="ME*M")
+    names = cfg.state_rows.names
+    assert names == ("conv_tail", "ssd_state")
+    programs = pool_check.pool_programs(
+        cfg, max_slots=128, num_blocks=16384, block_size=16, chunk=256,
+        draft=4, sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    # a pool the compiler does not move whole into faster memory (at 4,096
+    # blocks of 2 K/V heads it does: a prefetch, not a re-layout)
+    _, rows, width = kv_cache.pool_shape(1, 16384, 16, 256)
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width,
+        state=(128, cfg.state_rows.arrays(cfg.dtype)), state_names=names)
+    assert pool_check.failures(report, pools=2, state=names) == []
+    assert report[program]["donated"] == sorted(("k_pool", "v_pool") + names)
+    fn, args = programs[program]
+    text = fn.lower(*args).as_text()
+    assert text.count('kernel_name = "moe_grouped_up"') == 1
+    if program == "prefill_chunk":
+        assert len(args) == 7       # the count of real tokens
+        assert "ssd_step" not in text       # plain jax.numpy: no kernel
+        assert text.count('kernel_name = "kv_chunk_attn"') == 1
+    else:
+        assert text.count('kernel_name = "ssd_step"') == 2  # a layer an index
+        assert text.count('kernel_name = "paged_attn"') == 1
 
 
 @pytest.mark.parametrize("program", ["prefill_chunk", "decode"])
