@@ -389,6 +389,9 @@ PAGED_ROWS = 128
 #: the top of the step and each stretch waits only for its own, so the later
 #: stretches' copies run under the earlier ones' arithmetic
 PAGED_STRETCHES = 4
+#: rows a trip of the latent decode kernel's walk holds, in one of its two
+#: buffers: 256, 512 and 1,024 read the same on the chip (PERF.md, PR 55)
+PAGED_LATENT_STRETCH = PAGED_ROWS * PAGED_STRETCHES
 
 
 def paged_chunk_attention(
@@ -1221,120 +1224,150 @@ def _plain_latent_decode(q, pool, block_tables, attend_lens, *, layer,
 
 
 def _paged_latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
-                         o_ref, buf, sem, m_sc, l_sc, acc_sc, *, block_size,
-                         n_steps, rank, scale):
+                         o_ref, buf, sem, walked, m_sc, l_sc, acc_sc, *,
+                         block_size, rank, scale):
+    """A grid step is a slot; its rows are walked in a loop of ``ceil(rows /
+    stretch)`` trips, read from the prefetched lengths, over two buffers: a
+    trip starts the next stretch's copies — this slot's, or the first of the
+    next slot's — before it waits for its own, so they run under its
+    arithmetic.  The buffers, the semaphores and ``walked`` (stretches of the
+    slots before: the buffer's parity) outlive a grid step; every copy
+    started is waited for by the trip that folds it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s, c = pl.program_id(0), pl.program_id(1)
-    rows = PAGED_ROWS
-    step_rows = rows * PAGED_STRETCHES
-    bps = rows // block_size                   # blocks a stretch
-    nb = tables_ref.shape[1]
+    s = pl.program_id(0)
+    slots = tables_ref.shape[0]
+    stretch, rows = buf.shape[1], PAGED_ROWS
+    parts = stretch // rows
     heads = q_ref.shape[1]
     n, layer = lens_ref[s], layer_ref[0]
-    first = c * step_rows                      # first key row of this step
+    trips = (n + stretch - 1) // stretch
 
-    @pl.when((s == 0) & (c == 0))
+    @pl.when(s == 0)
     def _():
-        # skipped blocks leave these rows as they were: keep them finite
+        # rows past a slot's last are masked, not skipped: keep them finite
         buf[...] = jnp.zeros_like(buf)
+        walked[0] = 0
 
-    @pl.when(c == 0)
-    def _():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+    before = walked[0]
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def copy(j):
-        """Whether block ``j`` of this step holds a row the slot attends,
-        and its copy."""
-        b0 = first + j * block_size
-        blk = tables_ref[s, jnp.minimum(b0 // block_size, nb - 1)]
-        return b0 < n, pltpu.make_async_copy(
-            pool_hbm.at[layer, pl.ds(blk * block_size, block_size)],
-            buf.at[pl.ds(j * block_size, block_size)], sem.at[j])
+    def copies(of, c, into, start):
+        """Start, or wait for, the copies of stretch ``c`` of slot ``of``
+        into buffer ``into``: the blocks of a part (``rows`` rows) together
+        and without a branch each, if the part holds a row the slot attends;
+        past the slot's last column its last block is copied again (the mask
+        discards the rows).  A slot past the last has no rows."""
+        row = jnp.minimum(of, slots - 1)
+        live = jnp.where(of < slots, lens_ref[row], 0)
+        last = jnp.maximum(live - 1, 0) // block_size
 
-    # only the blocks that hold a row this slot attends are read, each once
-    # for all the heads
-    for j in range(bps * PAGED_STRETCHES):
-        need, cp = copy(j)
-
-        @pl.when(need)
-        def _():
-            cp.start()
-
-    for part in range(PAGED_STRETCHES):
-        start = first + part * rows
-
-        @pl.when(start < n)
-        def _(part=part, start=start):
-            for j in range(part * bps, (part + 1) * bps):
-                need, cp = copy(j)
-
-                @pl.when(need)
-                def _():
+        def a_part(part):
+            for j in range(rows // block_size):
+                at = part * rows + j * block_size
+                blk = tables_ref[row, jnp.minimum(
+                    (c * stretch + at) // block_size, last)] if start else 0
+                cp = pltpu.make_async_copy(
+                    pool_hbm.at[layer, pl.ds(blk * block_size, block_size)],
+                    buf.at[into, pl.ds(at, block_size)], sem.at[into, part])
+                if start:
+                    cp.start()
+                else:
                     cp.wait()
 
-            here = pl.ds(part * rows, rows)
-            kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (heads, rows), 1)
-            valid = kpos < n
-            sc = jax.lax.dot_general(
-                q_ref[0], buf[here, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (H, rows)
-            sc = jnp.where(valid, sc, NEG_INF)
-            m_prev = m_sc[...]
-            m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-            l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
-            m_sc[...] = m_new
-            pv = p.astype(buf.dtype)
-            for tile in range(rank // LANES):
-                lanes = pl.ds(tile * LANES, LANES)
-                acc_sc[:, lanes] = alpha * acc_sc[:, lanes] + jnp.dot(
-                    pv, buf[here, lanes], preferred_element_type=jnp.float32)
+        for part in range(parts):
+            pl.when(c * stretch + part * rows < live)(
+                functools.partial(a_part, part))
 
-    @pl.when(c == n_steps - 1)
+    # the walk's first stretch is slot 0's; a slot that holds nothing starts
+    # the next slot's, which its last trip would have
+    @pl.when((s == 0) | (trips == 0))
     def _():
-        inv = 1.0 / jnp.maximum(l_sc[...], 1e-30)
-        for tile in range(rank // LANES):
-            lanes = pl.ds(tile * LANES, LANES)
-            o_ref[0, :, lanes] = (acc_sc[:, lanes] * inv).astype(o_ref.dtype)
+        copies(s + (trips == 0).astype(jnp.int32), 0, jax.lax.rem(before, 2),
+               True)
+
+    def a_trip(c, into):
+        more = c + 1 < trips
+        copies(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
+               1 - into, True)
+        copies(s, c, into, False)
+        for part in range(parts):
+            first = c * stretch + part * rows
+
+            @pl.when(first < n)
+            def _(part=part, first=first):
+                here = pl.ds(part * rows, rows)
+                kpos = first + jax.lax.broadcasted_iota(
+                    jnp.int32, (heads, rows), 1)
+                valid = kpos < n
+                sc = jax.lax.dot_general(
+                    q_ref[0], buf[into, here, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # (H, rows)
+                sc = jnp.where(valid, sc, NEG_INF)
+                m_prev = m_sc[...]
+                m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+                l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+                m_sc[...] = m_new
+                pv = p.astype(buf.dtype)
+                for tile in range(rank // LANES):
+                    lanes = pl.ds(tile * LANES, LANES)
+                    acc_sc[:, lanes] = alpha * acc_sc[:, lanes] + jnp.dot(
+                        pv, buf[into, here, lanes],
+                        preferred_element_type=jnp.float32)
+
+    def trip(c, _):
+        # a trip's code once a buffer: a copy's VMEM and semaphore addresses
+        # are then constants (6 % of the kernel at both cells' shapes)
+        parity = jax.lax.rem(before + c, 2)
+        for into in range(2):
+            pl.when(parity == into)(functools.partial(a_trip, c, into))
+
+    jax.lax.fori_loop(0, trips, trip, None)
+    walked[0] = before + trips
+
+    inv = 1.0 / jnp.maximum(l_sc[...], 1e-30)
+    for tile in range(rank // LANES):
+        lanes = pl.ds(tile * LANES, LANES)
+        o_ref[0, :, lanes] = (acc_sc[:, lanes] * inv).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "n_steps", "rank", "scale", "interpret"))
-def _paged_latent_call(tables, lens, layer, q, pool, *, block_size, n_steps,
-                       rank, scale, interpret):
+    "block_size", "rank", "scale", "interpret"))
+def _paged_latent_call(tables, lens, layer, q, pool, *, block_size, rank,
+                       scale, interpret):
     """The kernel's call: a jitted function of its own with the layer as a
     prefetched scalar, so the layers of a program share one lowering."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, heads, width = q.shape
-    step_rows = PAGED_ROWS * PAGED_STRETCHES
+    stretch = PAGED_LATENT_STRETCH
     return pl.pallas_call(
         functools.partial(
-            _paged_latent_kernel, block_size=block_size, n_steps=n_steps,
-            rank=rank, scale=scale),
+            _paged_latent_kernel, block_size=block_size, rank=rank,
+            scale=scale),
         name="paged_latent_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(b, n_steps),
-            in_specs=[pl.BlockSpec((1, heads, width),
-                                   lambda s, c, *_: (s, 0, 0)),
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, heads, width), lambda s, *_: (s, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, heads, rank),
-                                   lambda s, c, *_: (s, 0, 0)),
+            out_specs=pl.BlockSpec((1, heads, rank), lambda s, *_: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((step_rows, width), pool.dtype),
-                pltpu.SemaphoreType.DMA((step_rows // block_size,)),
+                pltpu.VMEM((2, stretch, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((2, stretch // PAGED_ROWS)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((heads, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((heads, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((heads, rank), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((b, heads, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables, lens, layer, q, pool)
 
@@ -1358,14 +1391,18 @@ def paged_latent_decode_attention(
     reads only the blocks a slot holds, each once for all the heads.
 
     The kernel (``name="paged_latent_attn"``; page tables and lengths
-    prefetched into SMEM, the pool left in HBM; grid ``(slot, step)``) copies
-    the needed blocks of a step's 4 x 128 rows into VMEM and folds them, 128
-    rows at a time, into a running softmax: the scores of all ``H`` heads are
-    one ``(H, width) x (width, 128)`` product — the heads are the rows of the
-    left operand, so 32 heads fill the MXU's rows where grouped-query
-    attention brings 8 — and the values are the first ``rank`` lanes of the
-    same rows, a 128-lane tile at a time.  Other shapes and ``impl="xla"``
-    take the plain gather.  Scopes ``absorb``, ``paged_attn``, ``v_up``."""
+    prefetched into SMEM, the pool left in HBM; grid ``(slot,)``) walks a
+    slot's rows in a loop of ``ceil(rows / PAGED_LATENT_STRETCH)`` trips read
+    from its length — a table column no slot holds costs nothing — over two
+    VMEM buffers: a trip starts the next stretch's block copies, this slot's
+    or the next slot's first, before it waits for its own, and folds its 4 x
+    128 rows, 128 at a time, into a running softmax: the scores of all ``H``
+    heads are one ``(H, width) x (width, 128)`` product — the heads are the
+    rows of the left operand, so 32 heads fill the MXU's rows where
+    grouped-query attention brings 8 — and the values are the first ``rank``
+    lanes of the same rows, a 128-lane tile at a time.  A slot that attends
+    nothing returns zeros.  Other shapes and ``impl="xla"`` take the plain
+    gather.  Scopes ``absorb``, ``paged_attn``, ``v_up``."""
     rank, width = w_uk.shape[0], pool.shape[-1]
     q = _latent_queries(q_nope, q_rope, w_uk, width)
     lens = attend_lens.astype(jnp.int32)
@@ -1377,13 +1414,11 @@ def paged_latent_decode_attention(
         else:
             if interpret is None:
                 interpret = not on_tpu()
-            cap = block_tables.shape[1] * block_size
             o_lat = _paged_latent_call(
                 block_tables.astype(jnp.int32), lens,
                 jnp.full((1,), layer, jnp.int32), q, pool,
-                block_size=block_size,
-                n_steps=-(-cap // (PAGED_ROWS * PAGED_STRETCHES)),
-                rank=rank, scale=scale, interpret=interpret)
+                block_size=block_size, rank=rank, scale=scale,
+                interpret=interpret)
     return _latent_values(o_lat, w_uv, q_nope.dtype)
 
 

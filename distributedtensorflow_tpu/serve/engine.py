@@ -128,7 +128,7 @@ import numpy as np
 from ..obs import registry as obs_registry
 from ..obs import tracing as obs_tracing
 from ..obs import usage as obs_usage
-from ..ops.attention import select_walk
+from ..ops.attention import PAGED_LATENT_STRETCH, select_walk
 from ..utils.metrics import json_sanitize
 from . import draft as spec_draft
 from . import sampling
@@ -528,6 +528,18 @@ class Engine:
         #: kernel's longest walk x latent layers); counted only where a
         #: group stores latent rows
         self._step_latent = [0, 0, 0, 0]
+        #: the stretches the latent decode kernel walked in the current
+        #: step, and the stretches the slots' table rows can hold, both x
+        #: latent layers: a slot's walk is ``ceil(rows it attends /
+        #: PAGED_LATENT_STRETCH)`` trips (an idle slot attends the scratch
+        #: block's one row), so the pair says what share of the table held
+        #: rows; counted only where the decode program attends through
+        #: ``paged_latent_attn`` (a capacity of 0 elsewhere)
+        self._step_walk = None
+        self._walk_capacity = (
+            self.kv.latent_layers * self.kv.max_slots
+            * -(-self.kv.max_context // PAGED_LATENT_STRETCH)
+            if self.programs.decode_attention == "paged_latent_attn" else 0)
         #: the current step's {group: K/V rows its decode iteration
         #: attended}; counted only over several paged groups
         self._step_rows_read: dict[str, int] = {}
@@ -901,6 +913,7 @@ class Engine:
         self._step_evicted = 0
         self._step_sampled = (0, 0)
         self._step_latent = [0, 0, 0, 0]
+        self._step_walk = None
         self._step_rows_read = {}
         self._step_chunk_summaries = None
         self._step_scan = 0
@@ -1210,6 +1223,9 @@ class Engine:
                 fields["latent_rows_read"] = read
                 if self.kv.index_topk:
                     fields["index_rows_scored"] = scored
+                if self._step_walk:
+                    fields["latent_stretches_walked"] = self._step_walk
+                    fields["latent_stretches_capacity"] = self._walk_capacity
         if occupancy:
             for name, read in self._step_rows_read.items():
                 fields[f"{name}_rows_read"] = read
@@ -1541,6 +1557,10 @@ class Engine:
                 self._step_latent[2] = scored
                 self._m_latent_read.inc(self._step_latent[1])
                 self._m_index_scored.inc(scored)
+            if self._walk_capacity:
+                idle = self.kv.max_slots - len(slots)
+                self._step_walk = self.kv.latent_layers * (idle + int(
+                    (-(-lens // PAGED_LATENT_STRETCH)).sum()))
         if self._count_rows:
             positions = self.kv.seq_lens[slots] - 1    # the queries'
             for name, g in self.kv.paged.items():

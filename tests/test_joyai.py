@@ -269,6 +269,116 @@ def test_latent_decode_kernel_matches_the_plain_formulation(dtype, tol):
                                np.asarray(want, np.float32), atol=tol, rtol=0)
 
 
+#: rows a trip of the decode kernel's walk holds
+WALK = attention.PAGED_LATENT_STRETCH
+#: table columns a slot: 2.5 trips (joyai's cell in small: the long slots fill
+#: over half of it) and 10 (ling's: a table far wider than any slot uses)
+WALK_COLS = {"table_of_2.5_trips": 5 * WALK // 2 // 16,
+             "table_of_10_trips": 10 * WALK // 16}
+
+#: rows a slot attends; None is the table's whole capacity
+RAGGED = {
+    "nothing": [0],
+    "one_row": [1],
+    "a_row_short_of_a_stretch": [WALK - 1],
+    "a_stretch": [WALK],
+    "a_row_into_the_next": [WALK + 1],
+    "capacity": [None],
+    # the first stretch of the slot after an empty one is started by the
+    # empty slot's step, not under a last trip; the walk ends on empties
+    "mixed": [2 * WALK + 5, 0, WALK + 200, 0, 0, 17, None, 0],
+    "empty_first": [0, 0, WALK + 1, 3],
+}
+
+
+def _ragged_case(lens, cols, shared=()):
+    """Both cells' head shape — 32 heads over rows of 512 + 64, 640 with the
+    lane padding — blocks of 16, scattered tables of ``cols`` columns; every
+    block no slot attends holds NaN (the kernel must not let one into a
+    product), unmapped columns point at the scratch block.  ``shared``:
+    pairs of slots (a, b), b taking a's table row."""
+    bs, heads = 16, 32
+    lens = [cols * bs if n is None else n for n in lens]
+    need = [-(-n // bs) for n in lens]
+    nb = sum(need) + 3
+    case = _latent_case(len(lens), t=len(lens), heads=heads, rank=512,
+                        rope=64, nope=128, v=128, blocks=nb, bs=bs)
+    tables = np.full((len(lens), cols), nb, np.int32)
+    perm, o = np.random.default_rng(len(lens)).permutation(nb), 0
+    for i, k in enumerate(need):
+        tables[i, :k] = perm[o:o + k]
+        o += k
+    for a, b in shared:
+        tables[b] = tables[a]
+    attended = np.zeros(nb + 1, bool)
+    for i, k in enumerate(need):
+        attended[tables[i, :k]] = True
+    pool = case["pool"].reshape(2, nb + 1, bs, -1)
+    pool = jnp.where(jnp.asarray(attended)[None, :, None, None], pool,
+                     jnp.nan).reshape(case["pool"].shape)
+    kw = dict(w_uk=case["w_uk"], w_uv=case["w_uv"], layer=1, block_size=bs,
+              scale=case["form"].scale)
+    args = (case["q_nope"], case["q_rope"], pool, jnp.asarray(tables),
+            jnp.asarray(lens, jnp.int32))
+    return args, kw
+
+
+@pytest.mark.parametrize("cols", list(WALK_COLS.values()), ids=list(WALK_COLS))
+@pytest.mark.parametrize("lens", list(RAGGED.values()), ids=list(RAGGED))
+def test_latent_decode_walk_over_ragged_slots(lens, cols):
+    """The walk, interpreted, against the plain gather: a slot's trips are
+    counted from its length, the next slot's first stretch is started under
+    this slot's last one, and a slot that attends nothing returns zeros."""
+    args, kw = _ragged_case(lens, cols)
+    got = np.asarray(attention.paged_latent_decode_attention(
+        *args, impl="pallas", interpret=True, **kw))
+    # the gather reads every column: give it the attended rows alone
+    want = np.asarray(attention.paged_latent_decode_attention(
+        *args[:2], jnp.nan_to_num(args[2]), *args[3:], impl="xla", **kw))
+    live = np.asarray(args[4]) > 0
+    assert not np.isnan(got).any()
+    np.testing.assert_array_equal(got[~live], 0.0)
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=0)
+
+
+def test_latent_decode_slots_that_share_blocks_read_the_same_rows():
+    """Two slots whose tables are one shared prefix: the same query over the
+    same length gives the same output, bit for bit, whatever slot walked
+    before it; a shorter length over the same blocks reads its rows."""
+    lens = [WALK + 40, 300, WALK + 40, WALK - 7]
+    args, kw = _ragged_case(lens, WALK_COLS["table_of_2.5_trips"],
+                            shared=[(0, 2), (0, 3)])
+    q_nope, q_rope = (x.at[2].set(x[0]) for x in args[:2])
+    args = (q_nope, q_rope, *args[2:])
+    got = np.asarray(attention.paged_latent_decode_attention(
+        *args, impl="pallas", interpret=True, **kw))
+    want = np.asarray(attention.paged_latent_decode_attention(
+        q_nope, q_rope, jnp.nan_to_num(args[2]), *args[3:], impl="xla", **kw))
+    np.testing.assert_array_equal(got[0], got[2])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def test_latent_decode_walk_waits_for_every_copy_it_starts():
+    """Under the TPU interpreter a copy lands when it is waited for, memory
+    starts as NaN and races are looked for: the walk's result is the plain
+    interpreter's, bit for bit (which finishes a copy at its start and cannot
+    see a missing wait)."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    args, kw = _ragged_case(RAGGED["mixed"], WALK_COLS["table_of_10_trips"])
+    got = np.asarray(attention.paged_latent_decode_attention(
+        *args, impl="pallas", interpret=pltpu.InterpretParams(
+            dma_execution_mode="on_wait", detect_races=True,
+            uninitialized_memory="nan"), **kw))
+    races = interpret_pallas_call.races
+    assert races is None or not races.races_found
+    plain = np.asarray(attention.paged_latent_decode_attention(
+        *args, impl="pallas", interpret=True, **kw))
+    assert not np.isnan(got).any()
+    np.testing.assert_array_equal(got, plain)
+
+
 def _chunk_case(seed, dtype, *, t, heads, rank, rope, nope, v, nb, start,
                 bs=16):
     """A chunk of ``t`` queries from ``start`` over scattered blocks; every
@@ -385,6 +495,16 @@ def test_served_through_the_kernels_matches_the_reference(prompt_len, n_new,
     assert state["decode_attention"] == "paged_latent_attn"
     want = _reference_logits(cfg, params, prompt, tokens)
     np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
+    # the walk's census: iteration i's one live slot attends prompt + i + 1
+    # rows, the two idle slots the scratch block's one row; a table row of
+    # 256 tokens holds one stretch
+    stretch = attention.PAGED_LATENT_STRETCH
+    decodes = [r for r in eng.step_records() if r["occupancy"]]
+    assert [r["latent_stretches_walked"] for r in decodes] == [
+        cfg.num_layers * (-(-(prompt_len + i + 1) // stretch) + 2)
+        for i in range(len(decodes))]
+    assert {r["latent_stretches_capacity"] for r in decodes} == {
+        cfg.num_layers * 3 * -(-256 // stretch)}
 
 
 @pytest.mark.parametrize("block_size,width,rank,chunk,impl,why", [
@@ -553,6 +673,8 @@ def test_step_log_carries_the_family_counters(f32_model):
          "kv_blocks_used_full"} <= set(r) for r in decodes)
     # two expert layers of 16 held experts; one token, 4 choices a layer
     assert all(r["moe_pairs"] == 8 == r["moe_experts_hit"] for r in decodes)
+    # the plain gather walks nothing
+    assert not any("latent_stretches_walked" in r for r in decodes)
     # iteration i attends the prompt, the tokens before it and its own
     assert [r["latent_rows_read"] for r in decodes] == [
         cfg.num_layers * (40 + i + 1) for i in range(len(decodes))]

@@ -335,6 +335,9 @@ FAMILIES = {
         rows=16 * moe.GROUP_TILE_WIDE, experts=8, d=2048, m=768,
         tile=moe.GROUP_TILE_WIDE)),
     "paged_latent_attn": _latent(),
+    # ling's serving shapes: 128 slots, a table of 1,280 columns (contexts to
+    # 20,480: 655 KB of page tables in SMEM)
+    "paged_latent_attn_128_slots": _latent(slots=128, columns=1280),
     "latent_chunk_attn": _latent_chunk(),
     # the widest chunk the cell's sweep served: fewer heads a grid step
     "latent_chunk_attn_2048": _latent_chunk(chunk=2048),

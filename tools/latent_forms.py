@@ -7,6 +7,18 @@ tile of the grouped expert matmuls, and a decode iteration by context.
         [--impl latent_chunk_attn,plain] [--kernel-tiles 8x256x512,4x512x512]
         [--attn 1] [--tiles 16,32,64,128] [--starts 0,4096,8192,14336]
         [--decode 2000,9000,15000]
+    chiprun -- python tools/latent_forms.py --decode-attn 2700 \
+        --config ling3_flash_ep8 --slots 128 --kv-blocks 98304 \
+        --max-context 20480
+
+``--decode-attn CONTEXTS`` times one layer's
+``ops.attention.paged_latent_decode_attention`` alone and nothing else (any
+configuration whose ``cache_rows`` are latent rows: joyai's, ling's): ragged
+slots of ``context / 2`` to ``3 context / 2`` rows over scattered blocks, a
+table of ``--max-context`` (cut it to the contexts to see what the table's
+empty columns cost), ``--calls`` calls launched back to back and timed
+together — a single call's wall carries ~0.5 ms of dispatch (PERF.md, PRs
+40, 53).
 
 No engine, no HTTP: the programs of ``serve/model.py:make_programs`` over a
 pool of the cell's size, each call timed to ``block_until_ready`` (median of
@@ -57,6 +69,12 @@ def main(argv=None) -> int:
     p.add_argument("--block-size", type=int, default=16)
     p.add_argument("--max-context", type=int, default=16384)
     p.add_argument("--reps", type=int, default=5)
+    p.add_argument("--decode-attn", default="",
+                   help="mean contexts at which to time one layer's decode "
+                        "attention alone; nothing else is timed")
+    p.add_argument("--calls", type=int, default=8,
+                   help="calls a timed run of --decode-attn launches back "
+                        "to back")
     args = p.parse_args(argv)
 
     import jax
@@ -77,6 +95,8 @@ def main(argv=None) -> int:
     base = dataclasses.replace(getattr(models, args.config)(),
                                max_seq=args.max_context)
     bs, cols = args.block_size, args.max_context // args.block_size
+    if args.decode_attn:
+        return _decode_attention_alone(args, base)
     layers = {"full": tuple(range(base.num_layers))}
     params = family_of(base).init_params(base, jax.random.PRNGKey(0))
     jax.block_until_ready(params)
@@ -175,6 +195,68 @@ def main(argv=None) -> int:
             "program": "decode", "slots": args.slots, "context": n,
             "decode_attention": prog.decode_attention,
             "ms": round(ms, 3)}), flush=True)
+    return 0
+
+
+def _decode_attention_alone(args, base) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributedtensorflow_tpu.ops import attention
+    from distributedtensorflow_tpu.serve import kv_cache
+
+    form, bs = base.cache_rows, args.block_size
+    cols, slots, heads = args.max_context // bs, args.slots, base.num_heads
+    stretch = attention.PAGED_LATENT_STRETCH
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    pool = jax.random.normal(keys[0], kv_cache.pool_shape(
+        1, args.kv_blocks, bs, form.widths[0]), base.dtype)
+    pool = pool.at[..., form.values[0]:].set(0)
+    q_nope, q_rope, w_uk, w_uv = (
+        jax.random.normal(k, shape, base.dtype) for k, shape in zip(keys[1:], (
+            (slots, heads, base.qk_nope_head_dim),
+            (slots, heads, form.rope_dim),
+            (form.rank, heads, base.qk_nope_head_dim),
+            (form.rank, heads, base.v_head_dim))))
+    one = jax.jit(lambda pool, q_nope, tables, lens: form.decode(
+        (q_nope, q_rope), (pool,), tables, lens, layer=0, block_size=bs,
+        impl=base.kernel_impl, w_uk=w_uk, w_uv=w_uv))
+    queries = [q_nope * (1 + 0.001 * i) for i in range(args.calls)]
+    for context in (int(x) for x in args.decode_attn.split(",")):
+        lens = np.minimum(rng.integers(
+            context // 2, 3 * context // 2 + 1, slots), args.max_context)
+        need = -(-lens // bs)
+        if need.sum() > args.kv_blocks:
+            print(f"latent_forms: {need.sum()} blocks at context {context}, "
+                  f"--kv-blocks {args.kv_blocks}", file=sys.stderr)
+            return 2
+        tables = np.full((slots, cols), args.kv_blocks, np.int32)
+        scattered = rng.permutation(args.kv_blocks)
+        for i, end in enumerate(np.cumsum(need)):
+            tables[i, :need[i]] = scattered[end - need[i]:end]
+        tables, lens_dev = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+        jax.block_until_ready(one(pool, q_nope, tables, lens_dev))
+        walls = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            for q in queries:
+                out = one(pool, q, tables, lens_dev)
+            jax.block_until_ready(out)
+            walls.append((time.perf_counter() - t0) / args.calls)
+        ms = 1e3 * statistics.median(walls)
+        moved = int(lens.sum()) * form.widths[0] * jnp.dtype(
+            base.dtype).itemsize
+        print(json.dumps({
+            "program": "decode_attention_one_layer", "slots": slots,
+            "context": context, "max_context": args.max_context,
+            "decode_attention": form.decode_formulation(bs, base.kernel_impl),
+            "rows": int(lens.sum()),
+            "stretches_walked": int((-(-lens // stretch)).sum()),
+            "stretches_capacity": slots * -(-args.max_context // stretch),
+            "ms": round(ms, 4),
+            "row_gb_per_s": round(moved / ms / 1e6, 1)}), flush=True)
     return 0
 
 
