@@ -271,7 +271,8 @@ def _kda(p, h, cfg: LingConfig, state):
         # writes this product with the slots across lanes, the convolutions
         # inherit that form, and every layer of the group's three tail arrays
         # is then re-laid on the way in and out of the program
-        # (serve.pool_check; tests/test_kernel_export.py -k delta_state)
+        # (serve.pool_check;
+        # tests/test_kernel_export_families.py -k delta_state)
         qkv = with_layout_constraint(jnp.dot(h, p["w_qkv"]),
                                      Layout(major_to_minor=(0, 1)))
         gates = jnp.dot(h, p["w_gates"], preferred_element_type=f32)

@@ -41,9 +41,9 @@ as a reference, fatal for request serving.  This engine is what serves:
   fetches a token a slot (``slots x 4`` bytes) and leaves the logits on
   the device; a request with ``temperature > 0`` makes that iteration
   fetch them and takes the numpy sampler on its row.  The batch then
-  commits in one pass — counters, usage ledger and histograms once an
-  iteration, the requests' own fields in one loop — and every stream's
-  line is handed over at the end of it, in one stretch;
+  commits in one pass — counters and histograms once an iteration, the
+  requests' own fields in one loop — and every stream's line is handed
+  over at the end of it, in one stretch;
 - **decode fast path** (ISSUE 15): with ``fused_sampling=True`` sampling
   itself moves onto the device — greedy / temperature+top-k
   sampling is folded INTO the compiled decode program
@@ -117,6 +117,7 @@ import itertools
 import json
 import math
 import os
+import re
 import statistics
 import threading
 import time
@@ -127,7 +128,6 @@ import numpy as np
 
 from ..obs import registry as obs_registry
 from ..obs import tracing as obs_tracing
-from ..obs import usage as obs_usage
 from ..ops.attention import PAGED_LATENT_STRETCH, select_walk
 from ..utils.metrics import json_sanitize
 from . import draft as spec_draft
@@ -139,6 +139,26 @@ __all__ = ["Engine", "GenRequest", "QueueFullError"]
 
 #: Terminal request states (the ``requests.jsonl`` ``status`` field).
 TERMINAL_STATES = ("ok", "rejected", "error")
+
+#: Tenant identities are identifier-style so they stay greppable in every
+#: stream that carries one.
+TENANT_RE = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
+DEFAULT_TENANT = "default"
+
+
+def validate_tenant(tenant) -> str:
+    """Normalize + validate a tenant identity: ``None``/empty defaults to
+    :data:`DEFAULT_TENANT`; anything else must match :data:`TENANT_RE`
+    (raises ``ValueError`` — the serving frontend maps it to 400)."""
+    if tenant is None or tenant == "":
+        return DEFAULT_TENANT
+    tenant = str(tenant)
+    if not TENANT_RE.match(tenant):
+        raise ValueError(
+            f"tenant must match {TENANT_RE.pattern} "
+            f"(identifier-style, <= 64 chars), got {tenant!r}"
+        )
+    return tenant
 
 #: An iteration is an ``engine_stall`` (one anomaly row in ``trace.jsonl``)
 #: when ``step_s + log_prev_s`` passes both: this many seconds, and this
@@ -195,10 +215,9 @@ class GenRequest:
     #: the queue/prefill/decode spans the engine emits into trace.jsonl
     #: carry it, so a slow request's time is attributable end to end.
     trace_id: str = ""
-    #: Validated tenant identity (``obs.usage.validate_tenant``): the
-    #: unit of resource attribution — every requests.jsonl row, step-log
-    #: admission, and usage-ledger integral is keyed by it.
-    tenant: str = obs_usage.DEFAULT_TENANT
+    #: Validated tenant identity (:func:`validate_tenant`): every
+    #: requests.jsonl row and step-log admission is keyed by it.
+    tenant: str = DEFAULT_TENANT
     #: Absolute wall deadline (0 = none): a request still QUEUED past it
     #: is abandoned at admission instead of decoded for a client that
     #: already stopped listening (net-layer deadline honored end to end).
@@ -681,17 +700,6 @@ class Engine:
             self._met_log = open(os.path.join(logdir, "metrics.jsonl"), "a")
             self._step_log = open(os.path.join(logdir, "steps.jsonl"), "a")
 
-        # Per-tenant usage ledger (ISSUE 19): fed from the loop thread
-        # with the SAME step wall + post-eviction census the step log
-        # records, so its integrals tile steps.jsonl by construction.
-        self.usage = obs_usage.UsageMeter(
-            registry=reg, logdir=logdir,
-            token_flops=obs_usage.estimate_token_flops(self.cfg),
-            max_slots=max_slots,
-            kv_blocks_total=self.kv.num_blocks_total,
-            flush_every=log_every,
-        )
-
     # -- submission (any thread) ---------------------------------------------
 
     def submit(
@@ -762,7 +770,7 @@ class Engine:
                 )
         # Validated BEFORE GenRequest construction so even the rejected
         # path's requests.jsonl row carries a well-formed identity.
-        tenant = obs_usage.validate_tenant(tenant)
+        tenant = validate_tenant(tenant)
         if deadline_s is not None:
             deadline_s = float(deadline_s)
             if not math.isfinite(deadline_s) or deadline_s <= 0:
@@ -819,7 +827,6 @@ class Engine:
             # The disk write happens OUTSIDE the scheduler lock: a 429
             # storm must not stall the decode loop on log I/O.
             self._log_request(req)
-            self.usage.on_finish(req)
             raise QueueFullError(
                 f"queue full ({self.max_queue} requests waiting)"
             )
@@ -949,10 +956,7 @@ class Engine:
                 s_log = tiles.to("engine.log")
                 cpu_now = time.thread_time()
                 # Post-eviction census at `now` — the same instant
-                # and slot set the step record's active_slots
-                # reflects, so the usage ledger's per-tenant
-                # integrals tile the step-log occupancy integrals
-                # exactly (conservation by construction).
+                # and slot set the step record's active_slots reflects.
                 now = time.time()
                 # step_s: the work, not the log
                 step_s, walls = self._iteration_walls(root, s_log, cpu_now)
@@ -961,20 +965,16 @@ class Engine:
                 if compile_s:
                     walls["compiled"] = compiled
                     self._note_compiled(compile_s, compiled, now)
-                held = [
-                    (r, self.kv.billed_blocks(i))
-                    for i, r in enumerate(self._slots) if r is not None
-                ]
                 self._log_step(
                     now, walls, admitted, chunks, occupancy,
                     self.counters["decode_tokens"] - tokens0,
                     self.counters["spec_drafted"] - drafted0,
                     self.counters["spec_accepted"] - accepted0,
-                    sum(b for _, b in held),
+                    sum(self.kv.billed_blocks(i)
+                        for i, r in enumerate(self._slots) if r is not None),
                 )
                 self._note_stall(root, step_s + self._log_prev_s, now,
                                  compile_s)
-                self.usage.on_step(now, step_s, held, self._step_id)
                 if self.decode_steps % self.log_every == 0:
                     self._log_metrics_row()
         self._tiles = None
@@ -1128,8 +1128,7 @@ class Engine:
         them: device time per phase is what a profiler trace holding these
         spans gives).
         ``blocks_billed`` is the pool's refcount-weighted block census at
-        ``now`` (the usage ledger's conservation reference); admissions
-        are additionally broken down by tenant."""
+        ``now``; admissions are additionally broken down by tenant."""
         phases = []
         if admitted:
             phases.append("admit")
@@ -1346,8 +1345,6 @@ class Engine:
         self._stream_flush()
         self._m_active.set(sum(r is not None for r in self._slots))
         self._update_kv_metrics()
-        for req in admitted:
-            self.usage.on_admit(req)
         return admitted
 
     def _run_prefill_budget(self) -> int:
@@ -1481,7 +1478,6 @@ class Engine:
         req.attr_prefill_s += max(req.t_first_token - req._t_attr, 0.0)
         req._t_attr = req.t_first_token
         req.tokens.append(tok)
-        self.usage.on_tokens({req.tenant: 1})
         self._last_tokens[req.slot] = tok
         self._m_ttft.observe(req.ttft_s)
         if req.stream:
@@ -1605,23 +1601,22 @@ class Engine:
         ``slots`` the same slots as an array, ``kept`` the tokens each
         request commits (one, or a verified burst).
 
-        Engine-wide counts, the usage ledger (a tenant at a time, one
-        lock) and the histograms are taken once; a request's own fields
-        are written in one loop that takes no lock and calls no method of
-        the engine.  Each request's
-        attribution frontier advances to ``now``, the interval split
-        exclusively: this iteration's decode dispatch wall to decode (or,
-        ``spec``, the speculative-verify component), up to this
-        iteration's prefill-phase wall to interference stall (the engine
-        ran other requests' chunks while this one had a token pending),
-        the remainder to scheduler gap (admit scans, bookkeeping, idle
-        waits between iterations) — worked out once for each frontier
-        there is (one, but for requests whose prefill ended this
-        iteration).  Last of all the streams' lines of this iteration go
-        to ``stream_sink`` in one call — one thread wakes for them, however
-        many streams there are, and it runs while the engine waits for the
-        next launch — then the requests that ended (EOS, length) are
-        finished, and their ends follow in a call of their own."""
+        Engine-wide counts and the histograms are taken once; a request's
+        own fields are written in one loop that takes no lock and calls no
+        method of the engine.  Each request's attribution frontier
+        advances to ``now``, the interval split exclusively: this
+        iteration's decode dispatch wall to decode (or, ``spec``, the
+        speculative-verify component), up to this iteration's
+        prefill-phase wall to interference stall (the engine ran other
+        requests' chunks while this one had a token pending), the
+        remainder to scheduler gap (admit scans, bookkeeping, idle waits
+        between iterations) — worked out once for each frontier there is
+        (one, but for requests whose prefill ended this iteration).  Last
+        of all the streams' lines of this iteration go to ``stream_sink``
+        in one call — one thread wakes for them, however many streams
+        there are, and it runs while the engine waits for the next launch
+        — then the requests that ended (EOS, length) are finished, and
+        their ends follow in a call of their own."""
         n_active = len(decoding)
         self.decode_steps += 1
         self.counters["decode_dispatches"] += 1
@@ -1630,7 +1625,7 @@ class Engine:
         self.occupancy_max = max(self.occupancy_max, n_active)
         decode_dt, prefill_s = max(decode_dt, 0.0), max(prefill_s, 0.0)
         splits: dict[float, tuple[float, float, float]] = {}
-        by_tenant: dict[str, int] = {}
+        tokens = 0
         by_count: dict[int, int] = {}
         finished = []
         for (_, req), toks in zip(decoding, kept):
@@ -1653,7 +1648,7 @@ class Engine:
                 req.occ_max = n_active
             req.tokens.extend(toks)
             n = len(toks)
-            by_tenant[req.tenant] = by_tenant.get(req.tenant, 0) + n
+            tokens += n
             by_count[n] = by_count.get(n, 0) + 1
             if req._t_last_token and now - req._t_last_token > req.itl_max_s:
                 req.itl_max_s = now - req._t_last_token
@@ -1662,8 +1657,7 @@ class Engine:
                     or len(req.tokens) >= req.max_new_tokens:
                 finished.append(req)
         self._last_tokens[slots] = [toks[-1] for toks in kept]
-        self.counters["decode_tokens"] += sum(by_tenant.values())
-        self.usage.on_tokens(by_tenant)
+        self.counters["decode_tokens"] += tokens
         for n, requests in by_count.items():
             self._m_tok_step.observe(float(n), count=requests)
         self._stream_out += [(req, toks, now)
@@ -1865,7 +1859,6 @@ class Engine:
         self._m_active.set(sum(r is not None for r in self._slots))
         self._update_kv_metrics()
         self._log_request(req)
-        self.usage.on_finish(req)
         if req.stream:
             self._stream_out.append((req, None, req.t_done))
         req._done.set()
@@ -2016,9 +2009,6 @@ class Engine:
             if self._step_log is not None:
                 self._step_log.close()
                 self._step_log = None
-        # Final per-tenant rollup (``final: true``) before the registry
-        # snapshot so usage.jsonl always ends with the ledger's totals.
-        self.usage.close()
         if self.logdir:
             self._registry.write_prometheus(
                 os.path.join(self.logdir, "metrics.prom")

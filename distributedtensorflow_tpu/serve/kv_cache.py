@@ -665,9 +665,9 @@ class PagedKVCache:
         """Refcount-weighted block footprint of one slot: each mapped
         block charged at ``1/refcount``, so a prefix block shared by N
         slots costs each of them 1/N and summing over all occupied slots
-        can never exceed the pool's mapped-block count (the per-tenant
-        usage ledger's no-double-billing invariant).  Engine thread only,
-        like all host-side page-table state."""
+        can never exceed the pool's mapped-block count (the step log's
+        ``kv_blocks_billed``).  Engine thread only, like all host-side
+        page-table state."""
         pages = self.pages[slot]
         if pages is None:
             return 0.0

@@ -21,8 +21,9 @@ reads that off the compiled programs:
 
 ``python -m distributedtensorflow_tpu.serve.pool_check --max-slots 32 ...``
 runs the check on the device JAX finds and prints one JSON line (a leg of
-``chip_smoke.py``); ``tests/test_kernel_export.py`` runs it against a
-described v5e, without a chip.
+``chip_smoke.py``); ``tests/test_kernel_export_gpt2.py`` and
+``tests/test_kernel_export_families.py`` run it against a described v5e,
+without a chip.
 """
 
 from __future__ import annotations

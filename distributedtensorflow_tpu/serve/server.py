@@ -18,9 +18,10 @@ Endpoint contract (docs/API.md "Serving"):
   "finish_reason", "prompt_tokens", "new_tokens", "ttft_s", "tpot_s",
   "e2e_s", "drafted", "accepted"}``.  ``trace_id`` is the
   distributed-tracing id the engine's queue/prefill/decode spans carry
-  (generated when absent); ``tenant`` is the validated usage-metering
-  identity (identifier-style, <= 64 chars; defaults to ``"default"``)
-  every requests.jsonl row and ``GET /usagez`` integral is keyed by.
+  (generated when absent); ``tenant`` is the validated identity
+  (identifier-style, <= 64 chars; defaults to ``"default"``) that the
+  request's requests.jsonl row, its slot in ``GET /generatez`` and the
+  step log's ``admitted_tenants`` carry.
   Error mapping: malformed body/parameters → 400, queue full
   (backpressure) → 429, engine failure → 500, wall-clock timeout → 504
   (the request keeps running server-side; poll ``GET /generatez`` for
@@ -577,7 +578,7 @@ class ServeServer:
             kwargs["trace_id"] = trace_id
         tenant = payload.get("tenant")
         if tenant is not None:
-            # Usage-metering identity: the engine validates the grammar
+            # The request's identity: the engine validates the grammar
             # (identifier-style) and maps violations to ValueError → 400
             # below; only the type is checked here.
             if not isinstance(tenant, str):

@@ -3,8 +3,7 @@
 
 Loads a GPT config (checkpoint or random init), builds the paged-KV
 serving engine, and fronts it with the ``/generatez`` HTTP endpoint plus
-the whole ``/statusz`` introspection family (including the per-tenant
-usage ledger at ``GET /usagez``).  One process per host; the
+the whole ``/statusz`` introspection family.  One process per host; the
 model may be mesh-sharded (GSPMD partitions both serving programs the
 same way it partitions the dense-cache reference, ``models.generate``).
 
@@ -201,7 +200,7 @@ def main(argv=None) -> int:
                    help="reject requests asking for more new tokens")
     p.add_argument("--logdir", default=None,
                    help="writes requests.jsonl / metrics.jsonl / "
-                        "steps.jsonl / usage.jsonl / history.jsonl / "
+                        "steps.jsonl / history.jsonl / "
                         "metrics.prom (and, with tracing, trace.jsonl) "
                         "here")
     p.add_argument("--drain-timeout", type=float, default=30.0,
@@ -344,10 +343,6 @@ def main(argv=None) -> int:
                  cache_row_bytes=engine.kv.row_bytes,
                  kv_groups=engine.kv_groups())
     server = ServeServer(engine, args.port, host=args.host).start()
-    # Per-tenant usage ledger: GET /usagez next to the generation
-    # endpoint (text / ?json / ?tenant= filter; usage.jsonl under
-    # --logdir via the engine).
-    engine.usage.install(server.status_server)
 
     slo_monitor = None
     if args.slo_rules:
@@ -377,9 +372,6 @@ def main(argv=None) -> int:
             logdir=args.logdir,
             rules=slo_monitor.rules if slo_monitor is not None else None,
         ).install(server.status_server).start()
-        # pin each tenant's usage series so tenant cardinality can't be
-        # crowded out of the sampling rings
-        engine.usage.attach_history(history)
         logging.info("metrics history: sampling every %.1fs (GET /histz)",
                      args.history_interval)
 

@@ -9,7 +9,9 @@ Must run before any JAX backend initialization.  The platform is pinned to
 the CPU here and in the environment the tests' subprocesses inherit; the
 persistent compilation cache is switched off for the whole session so
 neither this process nor a ``train.py`` child writes one into the
-checkout (``runtime.init_compile_cache`` would place it there).
+checkout (``runtime.init_compile_cache`` would place it there).  The three
+``test_kernel_export*`` files each load the TPU's library to describe a
+v5e: under xdist they are three processes, so more than one may load it.
 """
 
 import os
@@ -20,6 +22,7 @@ os.environ["XLA_FLAGS"] = (
 )
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax  # noqa: E402
 

@@ -7,8 +7,8 @@ Held here to the rule it replaced, written out slot by slot in the tests:
 - **tokens**: greedy = arg-max of the row the program computed; sampled =
   ``Engine._sample`` on that row with that request's seeded generator — in
   a mixed batch too — and the step records say which slots took which;
-- **the commit**: every per-request field, engine counter, usage-ledger
-  total and histogram after ``_commit_tokens`` of a whole batch equals what
+- **the commit**: every per-request field, engine counter and
+  histogram after ``_commit_tokens`` of a whole batch equals what
   the slot-at-a-time ``_charge_decode`` + ``_commit_tokens`` of the parent
   left, on the one-token path and on the fused path with bursts, and an EOS
   and a length finish that fall in one iteration both happen in it;
@@ -230,8 +230,7 @@ def _check_commits(eng):
         tokens0 = eng.counters["decode_tokens"]
         slot_steps0 = eng.counters["slot_steps"]
         hist0 = tok_hist.stats()
-        usage0 = {t: acc["new_tokens"]
-                  for t, acc in eng.usage._tenants.items()}
+        held0 = {r.id: len(r.tokens) for _, r in decoding}
         del handed[:]               # the first tokens' lines
         commit(decoding, slots, kept, now, decode_dt, prefill_s, spec)
         n_tokens = sum(len(t) for t in kept)
@@ -240,12 +239,8 @@ def _check_commits(eng):
         hist = tok_hist.stats()
         assert hist["count"] - hist0["count"] == len(decoding)
         assert hist["sum"] - hist0["sum"] == n_tokens
-        by_tenant = {}
         for (_, r), toks in zip(decoding, kept):
-            by_tenant[r.tenant] = by_tenant.get(r.tenant, 0) + len(toks)
-        for tenant, n in by_tenant.items():
-            assert eng.usage._tenants[tenant]["new_tokens"] \
-                - usage0.get(tenant, 0) == n
+            assert len(r.tokens) - held0[r.id] == len(toks)
         done, lines, ends = [], [], []
         for (slot, req), toks in zip(decoding, kept):
             st = want[req.id]
@@ -311,9 +306,10 @@ def test_batched_commit_is_the_slot_at_a_time_commit(families):
         == eng.counters["decode_tokens"] \
         == sum(len(r.tokens) - 1 for r in reqs)
     assert sum(r["evicted"] for r in records) == 4
-    for tenant, want in (("alpha", len(reqs[0].tokens) + len(reqs[2].tokens)),
-                         ("beta", len(reqs[1].tokens) + len(reqs[3].tokens))):
-        assert eng.usage._tenants[tenant]["new_tokens"] == want
+    # ... and a first token a request, which no decode step commits
+    assert eng.counters["tokens_generated"] \
+        == eng.counters["decode_tokens"] + len(reqs) \
+        == sum(len(r.tokens) for r in reqs) == (k + 1) * 2 + 19 + 13
     assert eng.kv.allocator.used_blocks == 0
 
 

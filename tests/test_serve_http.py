@@ -143,6 +143,35 @@ def test_error_mapping_400(frontend):
     assert e.value.code == 413
 
 
+@pytest.mark.parametrize("tenant, told", [
+    pytest.param("9lead", "tenant must match", id="leading_digit"),
+    pytest.param("a-b", "tenant must match", id="hyphen"),
+    pytest.param("a" * 65, "tenant must match", id="65_characters"),
+    pytest.param(123, "bad 'tenant': 123 (a string)", id="not_a_string"),
+    pytest.param(None, None, id="none"),
+    pytest.param("", None, id="empty"),
+])
+def test_tenant_is_validated_at_the_door(frontend, tenant, told):
+    """A malformed ``tenant`` is a 400 that states the grammar (a value
+    that is no string: that it must be one); none serves under
+    ``"default"``, which the reply names."""
+    server, engine, prompt = frontend
+    status, body = _post(server.port, "/generatez",
+                         {"prompt": prompt, "max_new_tokens": 2,
+                          "tenant": tenant})
+    if told is None:
+        assert status == 200, body
+        assert body["tenant"] == "default"
+        assert engine.counters["ok"] == 1
+        return
+    assert status == 400, body
+    assert told in body["error"]
+    if isinstance(tenant, str):
+        assert r"^[A-Za-z_][A-Za-z0-9_]{0,63}$" in body["error"]
+        assert "(identifier-style, <= 64 chars)" in body["error"]
+    assert engine.counters["submitted"] == 0
+
+
 def test_dead_engine_loop_visible_and_503(frontend):
     """A crashed scheduler loop flips /healthz to 503 and new POSTs are
     refused immediately instead of queueing onto a loop nothing drains."""

@@ -29,14 +29,7 @@ breakdown summing to ``admitted``); basenames starting with ``trace`` and ending
 ``.jsonl`` against the span-trace schema (obs/tracing.py: step rows of
 span trees, anomaly events, ``kind: "span"`` rows, and the ``startup.*``
 phases sharing ``trace_id`` ``"startup"`` and tiling time in order);
-basenames starting with ``usage``
-against the per-tenant usage-ledger schema (obs/usage.py: t-ordered
-``tenants`` rollup rows with identifier-style tenant names, non-negative
-cumulative integrals that never decrease, per-``request`` closeout rows
-whose token counts / tenant / status match the sibling requests.jsonl,
-and the conservation gate — Σ-over-tenants slot-seconds and
-block-seconds tiling the sibling steps.jsonl occupancy integrals within
-2%); basenames starting with ``history``
+basenames starting with ``history``
 against the metrics-history tick schema (obs/tsdb.py: non-decreasing
 ``t``, well-formed metric names mapping to finite numbers, cardinality
 bounded by :data:`HISTORY_MAX_SERIES`); basenames starting with
@@ -213,9 +206,6 @@ DEFAULT_REQUESTS_GLOB = os.path.join(
 DEFAULT_STEPS_GLOB = os.path.join(
     REPO, "ARTIFACTS", "serve_*", "steps*.jsonl"
 )
-DEFAULT_USAGE_GLOB = os.path.join(
-    REPO, "ARTIFACTS", "serve_*", "usage*.jsonl"
-)
 DEFAULT_HISTORY_GLOB = os.path.join(
     REPO, "ARTIFACTS", "*", "history*.jsonl"
 )
@@ -372,22 +362,10 @@ def _check_compiled(row: dict, where: str) -> list[str]:
                 f"{secs!r}: nothing took any time"]
     return []
 
-#: Per-tenant usage ledger schema (obs/usage.py ``UsageMeter``, ISSUE 19
-#: — duplicated, stdlib-only).  Tenant identities are identifier-style;
-#: a ``tenants`` rollup row carries one cumulative accumulator object per
-#: tenant (the integral fields float, the token/request counts integer);
-#: a ``request`` closeout row's token counts must match the request's
-#: requests.jsonl row.  Conservation gate: Σ-over-tenants slot-seconds /
-#: block-seconds in the LAST rollup row must tile the sibling
-#: steps.jsonl occupancy integrals (``active_slots * step_s`` /
-#: ``kv_blocks_billed * step_s``) within :data:`USAGE_CONSERVATION_RTOL`.
+#: Tenant identities are identifier-style (serve/engine.py ``TENANT_RE``
+#: — duplicated, stdlib-only): the ``tenant`` of a requests.jsonl row and
+#: the keys of a step row's ``admitted_tenants``.
 _TENANT_RE = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
-USAGE_ROW_KINDS = ("tenants", "request")
-USAGE_FLOAT_FIELDS = ("queue_s", "slot_s", "block_s", "est_flops",
-                      "est_compute_s")
-USAGE_COUNT_FIELDS = ("prefill_tokens", "new_tokens", "spec_accepted",
-                      "requests_ok", "requests_rejected", "requests_error")
-USAGE_CONSERVATION_RTOL = 0.02
 
 #: Series cap of the embedded metrics history store (obs/tsdb.py
 #: ``MetricsHistory`` default ``max_series`` — duplicated, stdlib-only).
@@ -1099,7 +1077,7 @@ def check_requests_file(path: str) -> tuple[list[str], list[str]]:
                 if not _nonneg_int(row.get(name)):
                     errors.append(f"line {i}: {name!r} {row.get(name)!r} is "
                                   "not a non-negative integer")
-            # usage-metering identity (ISSUE 19; validated when present
+            # the request's identity (ISSUE 19; validated when present
             # so pre-ISSUE-19 logs stay green): identifier-style tenant.
             tenant = row.get("tenant")
             if tenant is not None and (
@@ -1566,10 +1544,10 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
             if lines is not None and not _nonneg_int(lines):
                 errors.append(f"line {i}: 'stream_lines' {lines!r} is not "
                               "a non-negative integer")
-            # per-tenant usage accounting (ISSUE 19; validated when
-            # present so pre-ISSUE-19 logs stay green): the pool's
-            # refcount-weighted block census at the iteration boundary,
-            # and the admission count broken down by tenant.
+            # ISSUE 19's step fields (validated when present so
+            # pre-ISSUE-19 logs stay green): the pool's refcount-weighted
+            # block census at the iteration boundary, and the admission
+            # count broken down by tenant.
             billed = row.get("kv_blocks_billed")
             if billed is not None and (
                 isinstance(billed, bool)
@@ -1622,273 +1600,6 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
                             f"{sum(adm_t.values())} != 'admitted' "
                             f"{counts['admitted']}"
                         )
-    return errors, warnings
-
-
-def _usage_sibling_requests(path: str) -> dict[str, dict]:
-    """Best-effort id → row index of the sibling ``requests.jsonl`` in
-    the usage file's directory (empty when absent/corrupt — the sibling
-    is validated by its own checker; this join only powers the usage
-    token-identity checks)."""
-    sibling = os.path.join(os.path.dirname(os.path.abspath(path)),
-                           "requests.jsonl")
-    rows: dict[str, dict] = {}
-    try:
-        with open(sibling) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    row = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if isinstance(row, dict) and isinstance(row.get("id"), str):
-                    rows[row["id"]] = row
-    except OSError:
-        return {}
-    return rows
-
-
-def _usage_step_integrals(path: str, steps_total: int):
-    """Occupancy integrals of the sibling ``steps.jsonl`` over rows with
-    ``step <= steps_total``: ``(slot_integral, block_integral)`` where
-    the block integral is None when any covered row predates
-    ``kv_blocks_billed``.  Returns None when the sibling is absent or
-    unreadable (conservation is then not checkable)."""
-    sibling = os.path.join(os.path.dirname(os.path.abspath(path)),
-                           "steps.jsonl")
-    slot_integral = 0.0
-    block_integral: float | None = 0.0
-    seen = False
-    try:
-        with open(sibling) as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    row = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if not isinstance(row, dict):
-                    continue
-                step = row.get("step")
-                if not _nonneg_int(step) or int(step) > steps_total:
-                    continue
-                active = row.get("active_slots")
-                step_s = row.get("step_s")
-                if not _nonneg_int(active) or isinstance(step_s, bool) \
-                        or not isinstance(step_s, (int, float)) \
-                        or not math.isfinite(step_s):
-                    continue
-                seen = True
-                slot_integral += int(active) * float(step_s)
-                billed = row.get("kv_blocks_billed")
-                if isinstance(billed, bool) \
-                        or not isinstance(billed, (int, float)) \
-                        or not math.isfinite(billed):
-                    block_integral = None
-                elif block_integral is not None:
-                    block_integral += float(billed) * float(step_s)
-    except OSError:
-        return None
-    if not seen:
-        return None
-    return slot_integral, block_integral
-
-
-def check_usage_file(path: str) -> tuple[list[str], list[str]]:
-    """Validate one per-tenant usage ledger ``usage.jsonl``
-    (obs/usage.py ``UsageMeter``; docs/API.md "Serving observability"):
-    every row one JSON object with finite non-decreasing ``t`` and a
-    ``kind`` from :data:`USAGE_ROW_KINDS`.  ``tenants`` rollup rows carry
-    cumulative per-tenant accumulators (identifier-style tenant names,
-    non-negative integral/count fields, every field non-decreasing
-    across rows per tenant — the ledger is cumulative); at most the last
-    rollup may be stamped ``final``.  ``request`` closeout rows carry
-    the terminal status plus non-negative integrals, and their
-    ``prompt_tokens`` / ``new_tokens`` / ``tenant`` / ``status`` must
-    match the same ``id``'s row in the sibling ``requests.jsonl`` when
-    one exists.  Conservation gate (the ledger's design invariant):
-    Σ-over-tenants ``slot_s`` (and ``block_s``) in the last rollup row
-    must equal the sibling ``steps.jsonl``'s ``active_slots * step_s``
-    (``kv_blocks_billed * step_s``) integral over the covered steps
-    within :data:`USAGE_CONSERVATION_RTOL` — a miss means the meter and
-    the step log disagree about who held the pool."""
-    errors: list[str] = []
-    warnings: list[str] = []
-    prev_t: float | None = None
-    prev_acc: dict[str, dict] = {}
-    last_tenants_row: dict | None = None
-    final_seen_at: int | None = None
-    requests = _usage_sibling_requests(path)
-    with open(path) as f:
-        for i, line in enumerate(f, start=1):
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                row = json.loads(line)
-            except json.JSONDecodeError as e:
-                errors.append(f"line {i}: invalid JSON ({e})")
-                continue
-            if not isinstance(row, dict):
-                errors.append(f"line {i}: row is {type(row).__name__}, "
-                              "not an object")
-                continue
-            t = row.get("t")
-            if isinstance(t, bool) or not isinstance(t, (int, float)) \
-                    or not math.isfinite(t):
-                errors.append(f"line {i}: 't' {t!r} is not a finite number")
-            else:
-                if prev_t is not None and t < prev_t:
-                    errors.append(f"line {i}: 't' {t} decreases")
-                prev_t = float(t)
-            kind = row.get("kind")
-            if kind not in USAGE_ROW_KINDS:
-                errors.append(
-                    f"line {i}: 'kind' {kind!r} not in {USAGE_ROW_KINDS}"
-                )
-                continue
-            if kind == "request":
-                rid = row.get("id")
-                if not isinstance(rid, str) or not rid:
-                    errors.append(f"line {i}: 'id' {rid!r} is not a "
-                                  "non-empty string")
-                    rid = None
-                tenant = row.get("tenant")
-                if not isinstance(tenant, str) \
-                        or not _TENANT_RE.match(tenant):
-                    errors.append(f"line {i}: 'tenant' {tenant!r} does not "
-                                  f"match {_TENANT_RE.pattern}")
-                status = row.get("status")
-                if status not in REQUEST_STATES:
-                    errors.append(f"line {i}: 'status' {status!r} not in "
-                                  f"{REQUEST_STATES}")
-                for name in ("prompt_tokens", "new_tokens"):
-                    if not _nonneg_int(row.get(name)):
-                        errors.append(
-                            f"line {i}: {name!r} {row.get(name)!r} is not "
-                            "a non-negative integer"
-                        )
-                for name in ("queue_s", "slot_s", "block_s", "est_flops"):
-                    v = row.get(name)
-                    if isinstance(v, bool) \
-                            or not isinstance(v, (int, float)) \
-                            or not math.isfinite(v) or v < 0:
-                        errors.append(f"line {i}: {name!r} {v!r} is not a "
-                                      "non-negative finite number")
-                # token identities vs the sibling requests.jsonl row:
-                # the ledger and the request log describe ONE request.
-                req = requests.get(rid) if rid else None
-                if req is not None:
-                    for name in ("prompt_tokens", "new_tokens", "status",
-                                 "tenant"):
-                        if name in req and name in row \
-                                and row[name] != req[name]:
-                            errors.append(
-                                f"line {i}: {name!r} {row[name]!r} "
-                                f"disagrees with requests.jsonl "
-                                f"({req[name]!r}) for id {rid!r}"
-                            )
-                continue
-            # kind == "tenants"
-            for name in ("steps_total", "max_slots", "kv_blocks_total"):
-                if not _nonneg_int(row.get(name)):
-                    errors.append(f"line {i}: {name!r} {row.get(name)!r} "
-                                  "is not a non-negative integer")
-            if final_seen_at is not None:
-                errors.append(
-                    f"line {i}: rollup row after the final rollup "
-                    f"(line {final_seen_at})"
-                )
-            if row.get("final") is True:
-                final_seen_at = i
-            tenants = row.get("tenants")
-            if not isinstance(tenants, dict):
-                errors.append(f"line {i}: 'tenants' {tenants!r} is not an "
-                              "object")
-                continue
-            last_tenants_row = row
-            for tenant, acc in tenants.items():
-                if not isinstance(tenant, str) \
-                        or not _TENANT_RE.match(tenant):
-                    errors.append(f"line {i}: tenant name {tenant!r} does "
-                                  f"not match {_TENANT_RE.pattern}")
-                    continue
-                if not isinstance(acc, dict):
-                    errors.append(f"line {i}: tenants[{tenant!r}] is not "
-                                  "an object")
-                    continue
-                bad = False
-                for name in USAGE_FLOAT_FIELDS:
-                    v = acc.get(name)
-                    if isinstance(v, bool) \
-                            or not isinstance(v, (int, float)) \
-                            or not math.isfinite(v) or v < 0:
-                        errors.append(
-                            f"line {i}: tenants[{tenant!r}].{name} {v!r} "
-                            "is not a non-negative finite number"
-                        )
-                        bad = True
-                for name in USAGE_COUNT_FIELDS:
-                    if not _nonneg_int(acc.get(name)):
-                        errors.append(
-                            f"line {i}: tenants[{tenant!r}].{name} "
-                            f"{acc.get(name)!r} is not a non-negative "
-                            "integer"
-                        )
-                        bad = True
-                prev = prev_acc.get(tenant)
-                if prev is not None and not bad:
-                    for name in USAGE_FLOAT_FIELDS + USAGE_COUNT_FIELDS:
-                        if acc[name] < prev[name] - 1e-6:
-                            errors.append(
-                                f"line {i}: tenants[{tenant!r}].{name} "
-                                f"{acc[name]} decreases (previous "
-                                f"{prev[name]}) — the ledger is "
-                                "cumulative"
-                            )
-                if not bad:
-                    prev_acc[tenant] = acc
-    # Conservation gate against the sibling steps.jsonl.
-    row = last_tenants_row
-    if row is not None and _nonneg_int(row.get("steps_total")) \
-            and int(row["steps_total"]) > 0 \
-            and isinstance(row.get("tenants"), dict) and row["tenants"]:
-        integrals = _usage_step_integrals(path, int(row["steps_total"]))
-        if integrals is None:
-            warnings.append(
-                "no readable sibling steps.jsonl — conservation not "
-                "checkable"
-            )
-        else:
-            slot_ref, block_ref = integrals
-            accs = [a for a in row["tenants"].values()
-                    if isinstance(a, dict)]
-            pairs = [("slot_s", slot_ref, "active_slots * step_s")]
-            if block_ref is None:
-                warnings.append(
-                    "sibling steps.jsonl predates kv_blocks_billed — "
-                    "block-seconds conservation not checkable"
-                )
-            else:
-                pairs.append(
-                    ("block_s", block_ref, "kv_blocks_billed * step_s")
-                )
-            for name, ref, what in pairs:
-                total = sum(float(a.get(name, 0.0)) for a in accs
-                            if isinstance(a.get(name), (int, float))
-                            and not isinstance(a.get(name), bool))
-                tol = max(USAGE_CONSERVATION_RTOL * ref, 1e-2)
-                if abs(total - ref) > tol:
-                    errors.append(
-                        f"conservation violated: sum-over-tenants {name} "
-                        f"{total:.6f} vs steps.jsonl {what} integral "
-                        f"{ref:.6f} (|diff| {abs(total - ref):.6f} > "
-                        f"{tol:.6f})"
-                    )
     return errors, warnings
 
 
@@ -2828,8 +2539,6 @@ def check_file(path: str) -> tuple[list[str], list[str]]:
     if os.path.basename(path).startswith("trace") \
             and path.endswith(".jsonl"):
         return check_trace_file(path)
-    if os.path.basename(path).startswith("usage"):
-        return check_usage_file(path)
     if os.path.basename(path).startswith("history"):
         return check_history_file(path)
     if os.path.basename(path).startswith("alerts"):
@@ -2949,8 +2658,7 @@ def main(argv: list[str] | None = None) -> int:
         glob.glob(DEFAULT_GLOB) + glob.glob(DEFAULT_FLIGHT_GLOB)
         + glob.glob(DEFAULT_GOODPUT_GLOB) + glob.glob(DEFAULT_CAPTURES_GLOB)
         + glob.glob(DEFAULT_FAULTS_GLOB) + glob.glob(DEFAULT_REQUESTS_GLOB)
-        + glob.glob(DEFAULT_STEPS_GLOB) + glob.glob(DEFAULT_USAGE_GLOB)
-        + glob.glob(DEFAULT_HISTORY_GLOB)
+        + glob.glob(DEFAULT_STEPS_GLOB) + glob.glob(DEFAULT_HISTORY_GLOB)
         + glob.glob(DEFAULT_PROM_GLOB) + glob.glob(DEFAULT_FLASH_GLOB)
         + glob.glob(DEFAULT_SLO_GLOB) + glob.glob(DEFAULT_FLEET_GLOB)
         + glob.glob(DEFAULT_TIMELINE_GLOB)

@@ -560,97 +560,6 @@ def serving_summary(rows: list[dict], metrics_rows: list[dict] | None
     return out
 
 
-def usage_capacity_summary(usage_rows: list[dict],
-                           steps_rows: list[dict] | None = None) -> dict:
-    """The per-tenant usage & capacity digest from ``usage.jsonl``
-    (ISSUE 19): each tenant's share of decode-slot-seconds,
-    KV-block-seconds, and generated tokens from the last cumulative
-    rollup row, the top tenant by KV-block-seconds, request closeout
-    counts, and — when ``steps.jsonl`` is present — slot/block pool
-    utilization and a saturation verdict (utilization >= 85% or a
-    growing admission queue).  Empty when the logdir has no usage
-    ledger."""
-    if not usage_rows:
-        return {}
-    rollup = None
-    closed = {"ok": 0, "rejected": 0, "error": 0}
-    for r in usage_rows:
-        kind = r.get("kind")
-        if kind == "tenants" and isinstance(r.get("tenants"), dict):
-            rollup = r
-        elif kind == "request":
-            s = str(r.get("status", "?"))
-            if s in closed:
-                closed[s] += 1
-    if rollup is None:
-        return {}
-    tenants = rollup["tenants"]
-    tot_slot = sum(t.get("slot_s", 0.0) for t in tenants.values())
-    tot_block = sum(t.get("block_s", 0.0) for t in tenants.values())
-    tot_tokens = sum(t.get("new_tokens", 0) for t in tenants.values())
-    shares = {}
-    for name, acc in sorted(tenants.items(),
-                            key=lambda kv: -kv[1].get("block_s", 0.0)):
-        shares[name] = {
-            "slot_s": acc.get("slot_s", 0.0),
-            "block_s": acc.get("block_s", 0.0),
-            "new_tokens": acc.get("new_tokens", 0),
-            "slot_share": (acc.get("slot_s", 0.0) / tot_slot
-                           if tot_slot else 0.0),
-            "block_share": (acc.get("block_s", 0.0) / tot_block
-                            if tot_block else 0.0),
-            "token_share": (acc.get("new_tokens", 0) / tot_tokens
-                            if tot_tokens else 0.0),
-            "requests_ok": acc.get("requests_ok", 0),
-            "requests_rejected": acc.get("requests_rejected", 0),
-        }
-    out: dict = {
-        "tenants": shares,
-        "top_tenant_by_block_s": next(iter(shares)) if shares else None,
-        "requests_closed": closed,
-        "slot_seconds_total": tot_slot,
-        "block_seconds_total": tot_block,
-    }
-    max_slots = rollup.get("max_slots", 0)
-    kv_total = rollup.get("kv_blocks_total", 0)
-    # Pool utilization + saturation verdict from the step log, using the
-    # same occupancy integrals that the conservation gate checks the
-    # tenant ledger against (capacity_report.py does the full version).
-    srows = [
-        r for r in steps_rows or []
-        if isinstance(r.get("step_s"), (int, float))
-        and isinstance(r.get("active_slots"), (int, float))
-    ]
-    if srows and max_slots:
-        wall = sum(r["step_s"] for r in srows)
-        slot_int = sum(r["active_slots"] * r["step_s"] for r in srows)
-        slot_util = slot_int / (max_slots * wall) if wall else 0.0
-        block_rows = [r for r in srows
-                      if isinstance(r.get("kv_blocks_billed"), (int, float))]
-        block_util = None
-        if kv_total and len(block_rows) == len(srows):
-            block_int = sum(r["kv_blocks_billed"] * r["step_s"]
-                            for r in srows)
-            block_util = block_int / (kv_total * wall) if wall else 0.0
-        queued = [r.get("queue_depth", 0) for r in srows
-                  if isinstance(r.get("queue_depth"), (int, float))]
-        half = len(queued) // 2
-        trend = "unknown"
-        if half:
-            early = sum(queued[:half]) / half
-            late = sum(queued[half:]) / (len(queued) - half)
-            trend = ("growing" if late - early > 0.5
-                     else "draining" if early - late > 0.5 else "stable")
-        util_max = max(slot_util, block_util or 0.0)
-        out["capacity"] = {
-            "slot_utilization": slot_util,
-            "block_utilization": block_util,
-            "queue_depth_trend": trend,
-            "saturated": util_max >= 0.85 or trend == "growing",
-        }
-    return out
-
-
 def step_time_opt_summary(train: list[dict], logdir: str) -> dict:
     """The step-time-attack digest: quantized-compute mode
     (``quant_mode`` row stamp), collective-matmul overlap (bucket count +
@@ -1208,11 +1117,6 @@ def build_report(logdir: str) -> dict:
         _load_jsonl(steps_path) if os.path.exists(steps_path)
         else ([], 0)
     )
-    usage_path = os.path.join(logdir, "usage.jsonl")
-    usage_rows, bad_usage = (
-        _load_jsonl(usage_path) if os.path.exists(usage_path)
-        else ([], 0)
-    )
     goodput, bad_goodput = load_goodput(logdir)
     train, evals = split_rows(rows)
     fleet, bad_fleet = fleet_summary(logdir, train, trace, flight)
@@ -1254,7 +1158,6 @@ def build_report(logdir: str) -> dict:
         "resilience": resilience_summary(faults, flight, goodput),
         "elasticity": elasticity_summary(flight, goodput),
         "serving": serving_summary(requests, train, steps_rows),
-        "usage": usage_capacity_summary(usage_rows, steps_rows),
         "fleet": fleet,
         "rpc": rpc,
         "alerts": alerts,
@@ -1266,7 +1169,7 @@ def build_report(logdir: str) -> dict:
         "parse_errors": (bad_metrics + bad_trace + bad_goodput
                          + bad_captures + bad_faults + bad_requests
                          + bad_steps + bad_fleet + bad_journal
-                         + bad_alerts + bad_dynamics + bad_usage),
+                         + bad_alerts + bad_dynamics),
         "final_metrics": {
             k: v for k, v in final_train.items()
             if k in ("step", "loss", "accuracy", "steps_per_sec",
@@ -1532,44 +1435,6 @@ def render(report: dict) -> str:
         if srv.get("rejected"):
             lines.append(f"  REJECTED {srv['rejected']} request(s) "
                          "(queue backpressure)")
-    usg = report.get("usage")
-    if usg:
-        closed = usg["requests_closed"]
-        lines += [
-            "",
-            (
-                f"usage & capacity: {len(usg['tenants'])} tenant(s), "
-                f"{closed['ok']} ok / {closed['rejected']} rejected / "
-                f"{closed['error']} error request(s) closed"
-            ),
-            (
-                "  tenant               slot-share  block-share  "
-                "token-share  ok  rej"
-            ),
-        ]
-        for name, sh in usg["tenants"].items():
-            lines.append(
-                f"  {name:<20} {sh['slot_share']:>9.1%}  "
-                f"{sh['block_share']:>10.1%}  {sh['token_share']:>10.1%}  "
-                f"{sh['requests_ok']:>2}  {sh['requests_rejected']:>3}"
-            )
-        if usg.get("top_tenant_by_block_s"):
-            top = usg["top_tenant_by_block_s"]
-            lines.append(
-                f"  top tenant by KV-block-seconds: {top} "
-                f"({usg['tenants'][top]['block_s']:.3f} block-s of "
-                f"{usg['block_seconds_total']:.3f} total)"
-            )
-        cap = usg.get("capacity")
-        if cap:
-            bu = (f"{cap['block_utilization']:.0%}"
-                  if cap["block_utilization"] is not None else "n/a")
-            verdict = "SATURATED" if cap["saturated"] else "headroom"
-            lines.append(
-                f"  capacity: slot util {cap['slot_utilization']:.0%}, "
-                f"block util {bu}, queue {cap['queue_depth_trend']} "
-                f"— {verdict}"
-            )
     flt = report.get("fleet")
     if flt:
         parts = []

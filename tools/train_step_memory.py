@@ -9,7 +9,7 @@ say what the compiler says of it: the bytes a device holds while it runs
 
 On the chip the step is compiled for the devices that are there.  With
 ``--describe`` it is compiled for a *described* topology, no chip attached
-(``tests/test_kernel_export.py`` holds GPT-2 medium's step to its kernel
+(``tests/test_kernel_export_gpt2.py`` holds GPT-2 medium's step to its kernel
 counts and to 14.0 GB that way; a described compile says nothing of time).
 ``--policy-less`` compiles the same step with the blocks under a
 ``jax.checkpoint`` that keeps nothing (``models.gpt.remat_block`` before
