@@ -386,8 +386,12 @@ def local_moe(
 # and leaves the absent experts' terms out — in an expert-parallel
 # deployment those are other chips' terms, and the sum over the shares plus
 # the shared expert, once, is the whole layer.  No token loses an expert at
-# any load: the row buffer holds every pair plus one tile of padding an
-# expert.
+# any load: the row buffer has room for every routed pair plus one tile of
+# padding an expert, whatever share of them this chip holds.  What is
+# *done* follows the pairs held here: the plan is one sort and no scatter,
+# the grouped kernels fetch and write the tiles in use, and the combine
+# reads the rows that hold a pair (``ops.grouped_matmul.combine_rows``; the
+# plain form, a gather a routed pair, is the CPU's and the reference).
 
 #: rows of one tile of the grouped matmuls (the bf16 sublane tile)
 GROUP_TILE = 16
@@ -452,38 +456,81 @@ def group_plan(idx: jax.Array, held: tuple[int, int], token_mask=None,
     where it has none), ``tile_expert`` (rows // tile,) local expert of
     each tile (the last used tile's expert repeated behind it),
     ``tiles_used``, and the counters ``pairs``, ``experts_hit``,
-    ``max_load``."""
+    ``max_load``; also ``pair`` (rows,), the pair ``token * k + choice`` of
+    each row (T k where empty: ``src`` is ``pair // k``).
+
+    One sort and no scatter: a pair's expert, token and choice are packed
+    into one word (the expert in the high bits: shifts, where a division by
+    ``k`` is emulated on the chip), so one single-operand sort gives the
+    held pairs first, grouped by expert in the order of the pairs; an
+    expert's first sorted position is a count of smaller keys, a row's pair
+    is read back through its tile's expert, and a pair's row through a
+    second sort by pair (the plain pick's: a caller that reads no ``dest``
+    pays for none)."""
     t, k = idx.shape
+    n = t * k
     first, count = held
     local = idx - first
     here = (local >= 0) & (local < count)
     if token_mask is not None:
         here &= token_mask[:, None]
-    key = jnp.where(here, local, count).reshape(-1)          # (T*k,)
-    rows = -(-t * k // tile) * tile + count * tile
-    counts = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    key = jnp.where(here, local, count).astype(jnp.int32)     # (T, k)
+    rows = -(-n // tile) * tile + count * tile
+    j_bits = max(1, (k - 1).bit_length())
+    low = max(1, (t - 1).bit_length()) + j_bits
+    code = (jnp.arange(t, dtype=jnp.int32)[:, None] << j_bits
+            | jnp.arange(k, dtype=jnp.int32)[None, :])         # token | choice
+    if (count + 1) << low >= 2 ** 31:
+        raise ValueError(f"{count} experts over {t} tokens top {k} do not "
+                         "pack into the 31 bits of the plan's sort key")
+    both = lax.sort((key << low | code).reshape(-1))
+    skey, pairs_sorted = both >> low, both & ((1 << low) - 1)
+    # starts[e]: the sorted position of expert e's first pair
+    starts = (skey[None, :] < jnp.arange(count + 1)[:, None]).sum(
+        1, dtype=jnp.int32)
+    first_sorted, counts = starts[:count], starts[1:] - starts[:count]
     padded = -(-counts // tile) * tile
     ends = jnp.cumsum(padded)
-    order = jnp.argsort(key, stable=True)
-    skey = key[order]
-    first_sorted = jnp.cumsum(counts) - counts                # per expert
-    e = jnp.minimum(skey, count - 1)
-    dest_sorted = jnp.where(
-        skey < count,
-        (ends - padded)[e] + jnp.arange(t * k) - first_sorted[e], rows)
-    dest = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        dest_sorted.astype(jnp.int32))
-    src = jnp.full((rows + 1,), t, jnp.int32).at[dest_sorted].set(
-        (order // k).astype(jnp.int32), mode="drop")[:rows]
+    begins = ends - padded
     tiles_used = ends[-1] // tile
     # a tile's expert is the one whose padded rows hold its first row; the
     # tiles behind the used ones repeat the last used tile's
     tile_start = jnp.minimum(jnp.arange(rows // tile) * tile,
                              jnp.maximum(ends[-1] - 1, 0))
-    tile_expert = jnp.searchsorted(ends, tile_start, side="right",
-                                   method="compare_all").astype(jnp.int32)
-    return {"rows": rows, "src": src, "dest": dest.reshape(t, k),
-            "tile_expert": jnp.minimum(tile_expert, count - 1),
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, tile_start, side="right",
+                         method="compare_all").astype(jnp.int32), count - 1)
+    # a tile of expert e holds the sorted pairs from first_sorted[e] + (its
+    # first row - begins[e]) on, as many as are e's.  An element gathered
+    # alone costs the chip what a row of 128 does, so a tile gathers the two
+    # rows of 128 sorted pairs its own lie in and picks them out by position
+    e_begins, e_counts, e_first = jnp.stack(
+        [begins, counts, first_sorted], 1)[tile_expert].T
+    row0 = jnp.arange(rows // tile, dtype=jnp.int32) * tile - e_begins
+    at = jnp.clip(e_first + row0, 0, n)
+    lanes = 128 * -(-tile // 128)
+    table = jnp.pad(pairs_sorted,
+                    (0, -n % lanes + 2 * lanes)).reshape(-1, lanes)
+    window = jnp.concatenate(
+        [table[at // lanes], table[at // lanes + 1]], axis=1)
+    want = (at % lanes)[:, None] + jnp.arange(tile)[None, :]   # (tiles, tile)
+    tile_code = jnp.where(
+        want[:, :, None] == jnp.arange(2 * lanes)[None, None, :],
+        window[:, None, :], 0).sum(-1)
+    filled = jnp.arange(tile)[None, :] < (e_counts - row0)[:, None]
+    src = jnp.where(filled, tile_code >> j_bits, t).reshape(-1)
+    pair = src * k + jnp.where(
+        filled, tile_code & ((1 << j_bits) - 1), 0).reshape(-1)
+    # a sorted pair's row: its position plus the padding of the experts
+    # before its own (a sum of comparisons, where a lookup is a gather)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    dest_sorted = jnp.where(
+        pos < starts[count],
+        pos + ((pos[None, :] >= starts[1:, None])
+               * (padded - counts)[:, None]).sum(0, dtype=jnp.int32), rows)
+    _, dest = lax.sort((pairs_sorted, dest_sorted), num_keys=1)
+    return {"rows": rows, "src": src, "pair": pair,
+            "dest": dest.reshape(t, k), "tile_expert": tile_expert,
             "tiles_used": tiles_used.astype(jnp.int32),
             "pairs": counts.sum(), "experts_hit": (counts > 0).sum(),
             "max_load": counts.max()}
@@ -491,7 +538,9 @@ def group_plan(idx: jax.Array, held: tuple[int, int], token_mask=None,
 
 def _grouped_ffn_xla(x_rows, weights, tile_expert, tiles_used, tile):
     """Plain formulation of the grouped expert feed-forward: one loop turn a
-    used tile, the tile's expert sliced out of the stacked matrices.
+    used tile, the tile's expert sliced out of the stacked matrices; the
+    rows behind the used tiles come back zero (the kernels leave them
+    unwritten: a caller reads neither).
     ``weights`` is ``(w_gate, w_up, w_down)``, the SwiGLU ``silu(x Wg) * (x
     Wu)``, or ``(w_up, w_down)``, the ungated ``relu(x Wu)^2``."""
     *w_ups, w_down = weights
@@ -547,8 +596,12 @@ def dropless_moe(
     or without a ``w_gate`` the ungated ``relu(x W_up)^2 W_down``.  The
     shared expert is the caller's, added once.  On
     one chip nothing is exchanged and nothing stands in for the absent
-    experts.  ``impl``: ``"pallas"`` (``ops.grouped_matmul``), ``"xla"``,
-    or ``"auto"`` (the kernel on a TPU)."""
+    experts.  ``impl``: ``"pallas"`` (``ops.grouped_matmul``: the grouped
+    kernels, which write the tiles in use and nothing behind them, and the
+    combine kernel, which reads the rows that hold a pair), ``"xla"`` (the
+    plain loop and a gather a routed pair: the same sum, a token's terms in
+    the order of its choices where the kernel adds them in the order of
+    their rows), or ``"auto"`` (the kernels on a TPU)."""
     from ..ops import grouped_matmul as gmm
 
     tile = group_tile(h.shape[0], top_k, router_kernel.shape[-1])
@@ -561,11 +614,13 @@ def dropless_moe(
         plan = group_plan(idx, held, token_mask, tile)
     x = h if experts_in is None else experts_in
     with jax.named_scope("experts"):
+        # a row that holds no pair reads the zero row behind the tokens
         x_rows = jnp.concatenate(
             [x, jnp.zeros((1, x.shape[-1]), x.dtype)])[plan["src"]]
         weights = [experts[name] for name in ("w_gate", "w_up", "w_down")
                    if name in experts]
-        if runtime.use_kernel(impl):
+        kernel = runtime.use_kernel(impl)
+        if kernel:
             grouped = gmm.grouped_swiglu if len(weights) == 3 \
                 else gmm.grouped_relu2
             y_rows = grouped(x_rows, *weights, plan["tile_expert"],
@@ -573,10 +628,21 @@ def dropless_moe(
         else:
             y_rows = _grouped_ffn_xla(x_rows, weights, plan["tile_expert"],
                                       plan["tiles_used"], tile)
-        y_rows = jnp.concatenate(
-            [y_rows, jnp.zeros((1, y_rows.shape[-1]), y_rows.dtype)])
-        picked = y_rows[plan["dest"]].astype(jnp.float32)      # (T, k, d)
-        out = (picked * w[..., None]).sum(1).astype(x.dtype)
+        if kernel and held[1] < router_kernel.shape[-1]:
+            # most routed pairs are other chips': walk the rows held here
+            out = gmm.combine_rows(y_rows, plan["src"], plan["pair"], w,
+                                   plan["tiles_used"] * tile)
+        else:
+            # the plain pick, a gather a routed pair (every pair has a row
+            # where every expert is held, but a masked token's): a pair
+            # without a row reads a zero row, the first of the tile of them
+            # the down kernel leaves behind the buffer
+            if not kernel:
+                y_rows = jnp.concatenate(
+                    [y_rows, jnp.zeros((1, y_rows.shape[-1]), y_rows.dtype)])
+            picked = y_rows[plan["dest"]].astype(jnp.float32)  # (T, k, d)
+            out = (picked * w[..., None]).sum(1)
+        out = out.astype(x.dtype)
     counters = {k: plan[k] for k in ("pairs", "experts_hit", "max_load")}
     if n_group > 1:
         group = idx // (router_kernel.shape[-1] // n_group)

@@ -48,6 +48,7 @@ from distributedtensorflow_tpu.ops.flash_attention import (
 from distributedtensorflow_tpu.ops import fused_xent
 from distributedtensorflow_tpu.ops.fused_xent import fused_softmax_xent
 from distributedtensorflow_tpu.ops.grouped_matmul import (
+    combine_rows,
     grouped_relu2,
     grouped_swiglu,
 )
@@ -280,6 +281,18 @@ def _grouped_ungated(tile, rows):
                 sds((rows // tile,), jnp.int32), sds((), jnp.int32))
 
 
+def _pick(tokens, k, rows, d=1024):
+    # nemotron_h's combine at the published widths: the experts' rows of a
+    # chunk of 2,048 tokens top 22 of 512 (a buffer of 53,248 rows: its two
+    # index arrays and the weights 606 KB of SMEM), every token's float32
+    # sum resident in VMEM
+    def fn(y_rows, src, pair, w, rows_used):
+        return combine_rows(y_rows, src, pair, w, rows_used, interpret=False)
+    return fn, (sds((rows, d), BF16), sds((rows,), jnp.int32),
+                sds((rows,), jnp.int32), sds((tokens, k), F32),
+                sds((), jnp.int32))
+
+
 def _grouped(tile):
     def fn(x, w_gate, w_up, w_down, tile_expert, tiles_used):
         return grouped_swiglu(x, w_gate, w_up, w_down, tile_expert,
@@ -364,6 +377,10 @@ FAMILIES = {
     "moe_grouped_ungated": _grouped_ungated(16, 128 * 22 + 16 * 16),
     "moe_grouped_ungated_wide": _grouped_ungated(
         moe.GROUP_TILE_WIDE, 24 * moe.GROUP_TILE_WIDE),
+    "moe_pick_chunk": _pick(2048, 22, 2048 * 22 + 128 * 64),
+    "moe_pick_step": _pick(128, 22, 128 * 22 + 128 * 16),
+    # glm5's widths: a chunk of 1,024 tokens' sums in four column blocks
+    "moe_pick_d6144": _pick(1024, 8, 1024 * 8 + 16 * 64, d=6144),
     # a prefill chunk's attention over K/V rows.  mimo_v25_ep16: 1024
     # queries of 64 heads, 16 a K/V head, keys 192 over values 128, a table
     # of 4,224 columns over the cell's pool of 65,536 blocks
