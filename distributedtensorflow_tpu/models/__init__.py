@@ -55,6 +55,11 @@ from .nemotron_h import (  # noqa: F401
     nemotron3_super_ep4,
     nemotron_h_tiny,
 )
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    qwen3_next_ep4,
+    qwen3_next_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

@@ -462,8 +462,12 @@ def paged_chunk_attention(
 
 def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
                          v_hbm, *refs, block_size, n_steps, scale,
-                         rest_at=None, with_lse=False):
-    """``rest_at``: where a K head is wider than its tile (:func:`lay_heads`),
+                         rest_at=None, with_lse=False, head_tiles=1):
+    """``head_tiles``: lane tiles a K/V head (2 at a head of 256): a head's scores
+    are the sum of its tiles' products, its running maximum and sum are kept
+    alike in each of its tiles' rows of the scratch, and each of its V tiles
+    takes the one set of probabilities.
+    ``rest_at``: where a K head is wider than its tile (:func:`lay_heads`),
     the lane at which the heads' remainders start in the K row, two of 64 a
     tile; a head's scores are then two products, one over its whole tile and
     one over its remainder's (``q_ref`` holds the two query tiles of a head
@@ -541,12 +545,17 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
                 jnp.int32, (q_rows, rows), 1)
             valid = (kpos < n) & (kpos >= lo)
             here = pl.ds(part * rows, rows)
-            for hh in range(tiles):
+            for hh in range(0, tiles, head_tiles):
                 lanes = pl.ds(hh * LANES, LANES)
                 sc = jax.lax.dot_general(
                     q_ref[0, hh * parts], kbuf[here, lanes],
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)         # (8, rows)
+                for more in range(hh + 1, hh + head_tiles):
+                    sc += jax.lax.dot_general(
+                        q_ref[0, more], kbuf[here, pl.ds(more * LANES, LANES)],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
                 if rest_at is not None:
                     sc += jax.lax.dot_general(
                         q_ref[0, hh * parts + 1],
@@ -558,11 +567,14 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
                 m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
                 p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-                l_sc[hh] = alpha * l_sc[hh] + p.sum(axis=1, keepdims=True)
-                acc_sc[hh] = alpha * acc_sc[hh] + jnp.dot(
-                    p.astype(vbuf.dtype), vbuf[here, lanes],
-                    preferred_element_type=jnp.float32)
-                m_sc[hh] = m_new
+                l_new = alpha * l_sc[hh] + p.sum(axis=1, keepdims=True)
+                for tile in range(hh, hh + head_tiles):
+                    l_sc[tile] = l_new
+                    acc_sc[tile] = alpha * acc_sc[tile] + jnp.dot(
+                        p.astype(vbuf.dtype),
+                        vbuf[here, pl.ds(tile * LANES, LANES)],
+                        preferred_element_type=jnp.float32)
+                    m_sc[tile] = m_new
 
     @pl.when(c == n_steps - 1)
     def _():
@@ -580,10 +592,17 @@ def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
                              value_dim: int | None = None) -> str:
     """Which formulation :func:`paged_window_decode_attention` takes at
     these shapes: ``"paged_attn"`` (the kernel) or ``"plain"`` (the gather
-    of every table column).  A test of shapes and of ``impl`` alone, so a
-    program can say what it was built with (``serve.model``)."""
+    of every table column).  The kernel takes K/V heads of 64 (two a lane
+    tile: gpt, lfm2), 128 (afmoe, jamba, evabyte, nemotron_h) or 256 (two
+    tiles a head: qwen3_next) under values as wide, or keys of 192 over
+    values of 128 (mimo); up to 32 query heads a K/V head; a block size that
+    divides 128.  A test of shapes and of ``impl`` alone, so a program can
+    say what it was built with (``serve.model``)."""
     if (value_dim or head_dim) == head_dim:
-        fits = head_dim in (64, LANES) and kv_heads * head_dim % LANES == 0
+        # a tile is two K/V heads of 64 or one of 128; a head of 256 is two
+        # tiles, side by side in the row
+        fits = (head_dim in (64, LANES, 2 * LANES)
+                and kv_heads * head_dim % LANES == 0)
     else:
         # keys a tile and a half wide over values of one tile: the heads'
         # remainders lie two a tile after their whole tiles (``lay_heads``)
@@ -625,7 +644,9 @@ def paged_window_decode_attention(
     a step copying the needed blocks of its 4 x 128 key rows into VMEM and
     folding them, 128 rows at a time, into a running softmax, one 128-lane
     tile of the pool's row after the other on the MXU.  A tile is one K/V
-    head of 128 or two of 64, and the query heads that attend it are the
+    head of 128, two of 64 or half of one of 256 (Qwen3-Next: a head's
+    scores are the sum of its two tiles' products and each of its V tiles
+    takes the same probabilities), and the query heads that attend it are the
     rows of one small product: head ``i`` of the tile keeps its query in
     lanes ``[i * D, (i + 1) * D)`` of its rows and zeros in the others, so
     the product over all 128 lanes is that head's scores, and its output is
@@ -672,7 +693,12 @@ def paged_window_decode_attention(
     q_rows = -(-per_tile * g // 8) * 8
     pad_rows = ((0, 0), (0, 0), (0, q_rows - per_tile * g), (0, 0))
     rest_at = None
-    if d > LANES:
+    head_tiles = d // LANES if d == dv and d > LANES else 1
+    if head_tiles > 1:
+        # (B, head, tile of the head, query of the head, lanes)
+        qt = q.reshape(b, h_kv, g, head_tiles, LANES).swapaxes(2, 3).reshape(
+            b, tiles, g, LANES)
+    elif d > LANES:
         # (B, head, part, query of the head, lanes): a head's first 128
         # values, then its last 64 in its own half of the remainder tile
         rest_at = h_kv * LANES
@@ -692,21 +718,27 @@ def paged_window_decode_attention(
     qt = jnp.pad(qt, pad_rows)
     if sink is not None:
         # a row's bias across its lanes, as the running maximum and sum lie
-        sink = jnp.broadcast_to(jnp.pad(
-            sink.astype(jnp.float32).reshape(1, tiles, per_tile * g, 1),
-            pad_rows)[0], (tiles, q_rows, PAGED_ROWS))
+        sink = sink.astype(jnp.float32).reshape(1, tiles // head_tiles,
+                                                per_tile * g, 1)
+        if head_tiles > 1:
+            sink = sink.repeat(head_tiles, axis=1)
+        sink = jnp.broadcast_to(jnp.pad(sink, pad_rows)[0],
+                                (tiles, q_rows, PAGED_ROWS))
     out = _paged_attn_call(
         block_tables.astype(jnp.int32), lens, lo,
         jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool, sink,
         block_size=block_size, n_steps=-(-span // step_rows),
         scale=d ** -0.5, interpret=interpret, rest_at=rest_at,
-        with_lse=with_lse)
+        with_lse=with_lse, head_tiles=head_tiles)
     lse = None
     if with_lse:
         # a row's log-denominator lies across its lanes: one lane of it
         out, lse = out
-        lse = lse[:, :, :per_tile * g, 0].reshape(b, h)
-    if d > LANES:
+        lse = lse[:, ::head_tiles, :per_tile * g, 0].reshape(b, h)
+    if head_tiles > 1:
+        out = out[:, :, :g].reshape(b, h_kv, head_tiles, g, LANES).swapaxes(
+            2, 3).reshape(b, h, d)
+    elif d > LANES:
         out = out[:, :, :g].reshape(b, h, dv)
     else:
         out = out[:, :, :per_tile * g].reshape(
@@ -716,10 +748,11 @@ def paged_window_decode_attention(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "n_steps", "scale", "interpret", "rest_at", "with_lse"))
+    "block_size", "n_steps", "scale", "interpret", "rest_at", "with_lse",
+    "head_tiles"))
 def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
                      *, block_size, n_steps, scale, interpret, rest_at=None,
-                     with_lse=False):
+                     with_lse=False, head_tiles=1):
     """The kernel's call.  A jitted function of its own with the layer as a
     prefetched scalar, so that the layers of a program that call it at the
     same shapes share one trace and one lowering of the body (0.8 s a call
@@ -746,7 +779,8 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
     return pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, block_size=block_size, n_steps=n_steps,
-            scale=scale, rest_at=rest_at, with_lse=with_lse),
+            scale=scale, rest_at=rest_at, with_lse=with_lse,
+            head_tiles=head_tiles),
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, n_steps),

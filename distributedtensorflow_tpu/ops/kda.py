@@ -1,26 +1,44 @@
-"""Kimi Delta Attention: the gated delta rule over a matrix state a head, in
-three forms of one mathematics.
+"""The gated delta rule over a matrix state a head (Kimi Delta Attention,
+Gated DeltaNet), in three forms of one mathematics.
 
-A KDA head keeps, a sequence, a state ``S`` of ``key_dim x value_dim`` float32
+A head keeps, a sequence, a state ``S`` of ``key_dim x value_dim`` float32
 values whatever the context (:class:`ops.ssm.DeltaState` states it beside the
-three convolution tails of its q, k and v).  A token ``t`` **decays** the state
-by a channel of the key, **corrects** it by a rank-one delta and **reads** it::
+three convolution tails of KDA's q, k and v, :class:`ops.ssm.GatedDeltaState`
+beside Gated DeltaNet's one).  A token ``t`` **decays** the state, **corrects**
+it by a rank-one delta and **reads** it::
 
-    S'  = Diag(exp(g_t)) S_{t-1}                  g_t <= 0 a key channel
+    S'  = Diag(exp(g_t)) S_{t-1}                  g_t <= 0
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T      beta_t in (0, 1) a head
     o_t = S_t^T q_t
 
-The decay is a key *channel*, so rows of the state mix through the delta
-(``selective_scan``'s state never mixes channels).  The state is stored
-**transposed**, ``(heads, value_dim, key_dim)``: the key's channels across
-lanes, so the decay and both rank-one factors of the key broadcast along
-sublanes, and the chunked form's products with the state contract lanes.
+The gate ``g`` has **two forms**, told apart by its last dimension:
 
-Every form takes ``q, k, v, g`` ``(T, H, D)`` — ``q`` and ``k`` already
-L2-normalised, ``q`` scaled —, ``beta`` ``(T, H)`` and the state, computes in
-float32 and returns ``(o (T, H, value_dim) float32, state out)``.  A token with
-``g = 0`` and ``beta = 0`` is the identity on the state: that is how positions
-past ``valid`` and inactive slots are padded, in every form.
+- a key **channel** (``(T, H, key_dim)``; KDA, ``models.ling``): rows of the
+  state mix through the delta (``selective_scan``'s state never mixes
+  channels).  **Precondition of the chunked form: ``g >= -5`` a token** (KDA's
+  published lower bound, ``kda_lower_bound``): :func:`_chunk` splits a decay
+  ratio into two factors around a sub-block's middle and the first
+  overflows float32 under a stronger gate.  The recurrence and the step take
+  any ``g <= 0``;
+- one **scalar** a head (``(T, H, 1)``; Gated DeltaNet, ``models.qwen3_next``),
+  **unbounded below**: the chunked form (:func:`_chunk_scalar`) forms the
+  ratio itself, ``exp(G_t - G_s) <= 1``, one ``C x C`` matrix a head, so
+  nothing is clamped and no gate is too strong.  Here ``q`` and ``k`` may
+  have fewer heads than ``v`` (``Hk`` dividing ``H``): value head ``h`` reads
+  q/k head ``h // (H / Hk)``, and a chunk's products ``K K^T`` and ``Q K^T``
+  are a key head's, formed once for its value heads.
+
+The state is stored **transposed**, ``(heads, value_dim, key_dim)``: the key's
+channels across lanes, so the decay and both rank-one factors of the key
+broadcast along sublanes, and the chunked form's products with the state
+contract lanes.
+
+Every form takes ``q, k`` ``(T, Hk, D)``, ``v`` ``(T, H, Dv)`` — ``q`` and ``k``
+already L2-normalised, ``q`` scaled —, ``g`` in one of its two forms, ``beta``
+``(T, H)`` and the state, computes in float32 and returns ``(o (T, H,
+value_dim) float32, state out)``.  A token with ``g = 0`` and ``beta = 0`` is
+the identity on the state: that is how positions past ``valid`` and inactive
+slots are padded, in every form.
 
 - :func:`kda_recurrent`: the recurrence by ``lax.scan`` over tokens: the
   yardstick of the tests and the plain form on any backend;
@@ -41,9 +59,12 @@ past ``valid`` and inactive slots are padded, in every form.
       O = (Q exp(G)) S_0 + B U
       S_C = Diag(exp(G_C)) S_0 + (K exp(G_C - G))^T U
 
+  (a channel gate; a scalar gate's ``exp(G_t[c] - G_s[c])`` leaves the sum:
+  ``A = beta (K K^T) * D``, ``B = (Q K^T) * D`` with ``D[t, s] = exp(G_t -
+  G_s)``, and ``exp(G)`` scales rows.)  For a channel gate
   ``exp(G_t - G_s)`` is formed as ``exp(G_t - r) exp(r - G_s)`` with ``r`` the
   sum at the middle of ``t``'s sub-block of :data:`SUB` tokens, so under KDA's
-  lower bound of -5 a token both factors of a pair inside the sub-block lie in
+  lower bound of -5 a token (the precondition above) both factors of a pair inside the sub-block lie in
   ``e^-40 .. e^40``: inside float32 with room on both sides (around the
   sub-block's *start* the factors reach e^-80 and e^80, and a ``q`` or ``k``
   value under 6e-4 times e^-80 is a denormal, flushed to zero: a term lost);
@@ -111,11 +132,21 @@ def _pad_identity(g, beta, valid):
             jnp.where(real[:, None], beta, 0.0))
 
 
+def _share_heads(q, k, heads: int):
+    """``q`` and ``k`` (..., Hk, K) as every value head reads them, (...,
+    heads, K): value head ``h`` reads head ``h // (heads / Hk)``."""
+    per = heads // q.shape[-2]
+    if per == 1:
+        return q, k
+    return jnp.repeat(q, per, axis=-2), jnp.repeat(k, per, axis=-2)
+
+
 # -- the recurrence ----------------------------------------------------------
 
 def _token(st, q, k, v, g, beta):
     """One token of every head (or slot and head): ``st`` (..., V, K), ``q``,
-    ``k``, ``g`` (..., K), ``v`` (..., V), ``beta`` (...)."""
+    ``k`` (..., K), ``g`` (..., K) or (..., 1), ``v`` (..., V), ``beta``
+    (...)."""
     st = st * jnp.exp(g)[..., None, :]
     pred = (st * k[..., None, :]).sum(-1)
     u = beta[..., None] * (v - pred)
@@ -124,10 +155,12 @@ def _token(st, q, k, v, g, beta):
 
 
 def kda_recurrent(q, k, v, g, beta, state, valid=None):
-    """The plain form: ``q, k, v, g`` (T, H, D), ``beta`` (T, H), ``state``
-    (H, V, K) float32 -> ``(o (T, H, V) float32, state out)``.  Steps at ``t
-    >= valid`` leave the state as it is."""
+    """The plain form: ``q, k`` (T, Hk, K), ``v`` (T, H, V), ``g`` (T, H, K)
+    or (T, H, 1), ``beta`` (T, H), ``state`` (H, V, K) float32 -> ``(o (T, H,
+    V) float32, state out)``.  Steps at ``t >= valid`` leave the state as it
+    is."""
     g, beta = _pad_identity(g.astype(_F32), beta.astype(_F32), valid)
+    q, k = _share_heads(q, k, v.shape[1])
 
     def step(st, xs):
         return _token(st, *xs)
@@ -187,12 +220,39 @@ def _chunk(q, k, v, g, beta, st):
     return o, st
 
 
+def _chunk_scalar(q, k, v, gcum, beta, st):
+    """One chunk of one key head and the ``R`` value heads that read it, the
+    gate a scalar a value head: ``q, k`` (C, K), ``v`` (R, C, V), ``gcum`` (R,
+    C, 1) the running sum of ``g`` from the chunk's start, ``beta`` (R, C, 1),
+    ``st`` (R, V, K) -> ``(o (R, C, V), st out)``; module text.  Every
+    exponent is ``<= 0``: no sub-blocks, nothing to clamp."""
+    c = q.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    kk, qk = _mm_nt(k, k), _mm_nt(q, k)        # the key head's, once
+
+    def value_head(v, gcum, beta, st):
+        # D[t, s] = exp(G_t - G_s) for s <= t: masked before the exponential
+        d = jnp.exp(jnp.where(col <= row, gcum - gcum.T, -jnp.inf))
+        a = jnp.where(col < row, beta * kk * d, 0.0)
+        gam = jnp.exp(gcum)
+        u = _mm(_unit_lower_inverse(a), beta * (v - gam * _mm_nt(k, st)))
+        o = gam * _mm_nt(q, st) + _mm(qk * d, u)
+        last = gcum[c - 1:c]
+        return o, st * jnp.exp(last) + _mm_tn(u, k * jnp.exp(last - gcum))
+
+    return jax.vmap(value_head)(v, gcum, beta, st)
+
+
 def kda_chunked(q, k, v, g, beta, state, valid=None):
-    """The chunked form in plain ``jax.numpy``: :func:`_chunk` over the heads,
-    a ``lax.scan`` over the chunks.  Same arguments and results as
+    """The chunked form in plain ``jax.numpy``: :func:`_chunk` (a channel
+    gate) or :func:`_chunk_scalar` (``g`` (T, H, 1)) over the heads, a
+    ``lax.scan`` over the chunks.  Same arguments and results as
     :func:`kda_recurrent`; ``T`` a multiple of :data:`CHUNK`."""
-    t, h, _ = q.shape
+    t, h = v.shape[:2]
     g, beta = _pad_identity(g.astype(_F32), beta.astype(_F32), valid)
+    if g.shape[-1] == 1:
+        return _chunked_scalar(q, k, v, g, beta, state)
 
     def chunks(x):              # (T, H, D) -> (T / C, H, C, D)
         return x.astype(_F32).reshape(t // CHUNK, CHUNK, h, -1).swapaxes(1, 2)
@@ -204,6 +264,37 @@ def kda_chunked(q, k, v, g, beta, state, valid=None):
     state, o = lax.scan(step, state.astype(_F32), (
         chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta[..., None])))
     return o.swapaxes(1, 2).reshape(t, h, -1), state
+
+
+def _chunked_scalar(q, k, v, g, beta, state):
+    """:func:`kda_chunked` under a scalar gate: ``q, k`` (T, Hk, K), ``v`` (T,
+    H, V), ``g`` (T, H, 1) and ``beta`` (T, H) float32 with the pad steps made
+    identities, ``state`` (H, V, K)."""
+    (t, hk, _), h = q.shape, v.shape[1]
+    n, per = t // CHUNK, h // hk
+
+    def chunks(x, heads):       # (T, heads, D) -> (T / C, heads, C, D)
+        return x.astype(_F32).reshape(n, CHUNK, heads, -1).swapaxes(1, 2)
+
+    def shared(x):              # (T, H, D) -> (T / C, Hk, R, C, D)
+        return chunks(x, h).reshape(n, hk, per, CHUNK, -1)
+
+    # the running sum inside each chunk, every head at once: one product
+    lower = jnp.tril(jnp.ones((CHUNK, CHUNK), _F32))
+    gcum = jnp.einsum("ts,nsh->nth", lower, g.reshape(n, CHUNK, h),
+                      precision=_HI).reshape(t, h, 1)
+
+    def step(st, xs):
+        o, st = jax.vmap(_chunk_scalar)(*xs, st)
+        return st, o
+
+    state, o = lax.scan(
+        step, state.astype(_F32).reshape(hk, per, *state.shape[1:]), (
+            chunks(q, hk), chunks(k, hk), shared(v), shared(gcum),
+            shared(beta[..., None])))
+    # (T / C, Hk, R, C, V) -> (T, H, V)
+    return (o.reshape(n, h, CHUNK, -1).swapaxes(1, 2).reshape(t, h, -1),
+            state.reshape(h, *state.shape[2:]))
 
 
 def chunk_scan_formulation(chunk: int) -> str:
@@ -280,12 +371,15 @@ def _kda_step_call(rows, vt, pool, *, layer, interpret):
 
 def kda_step(q, k, v, g, beta, pool, layer: int, *, impl="auto",
              interpret: bool | None = None):
-    """One token a slot: ``q, k, v, g`` (B, H, D), ``beta`` (B, H), against
-    rows ``layer`` of ``pool`` (layers, B, H, V, K) float32 -> ``(o (B, H, V)
-    float32, pool)``.  The kernel takes the whole array and touches only the
-    layer's blocks (aliased in and out); the plain form reads the layer and
-    sets it back."""
+    """One token a slot: ``q, k`` (B, Hk, K), ``v`` (B, H, V), ``g`` (B, H,
+    K) or (B, H, 1), ``beta`` (B, H), against rows ``layer`` of ``pool``
+    (layers, B, H, V, K) float32 -> ``(o (B, H, V) float32, pool)``.  The
+    kernel takes the whole array and touches only the layer's blocks (aliased
+    in and out); the plain form reads the layer and sets it back.  A scalar
+    gate is broadcast along the key's channels into the kernel's rows (2 MB a
+    layer of 128 slots beside the states themselves)."""
     q, k, v, g, beta = (x.astype(_F32) for x in (q, k, v, g, beta))
+    q, k = _share_heads(q, k, v.shape[1])
     slots, h, dk = q.shape
     dv = v.shape[-1]
     if step_formulation(h, dk, dv, impl) == "plain":
@@ -293,7 +387,7 @@ def kda_step(q, k, v, g, beta, pool, layer: int, *, impl="auto",
         return o, pool.at[layer].set(st)
     if interpret is None:
         interpret = not on_tpu()
-    alpha = jnp.exp(g)
+    alpha = jnp.broadcast_to(jnp.exp(g), q.shape)
     bk = beta[..., None] * k
     kq = jnp.broadcast_to((bk * q).sum(-1, keepdims=True), q.shape)
     rows = jnp.stack([alpha * k, alpha * q, alpha, bk, kq,

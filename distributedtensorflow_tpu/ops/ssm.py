@@ -9,9 +9,11 @@ the scan state ``s`` of ``d_state`` values a channel, and the last ``d_conv -
 :class:`ConvTail` is the state of a layer that keeps the tail and has no scan
 (``models.lfm2``'s gated short convolution); :class:`DeltaState` that of a
 Kimi-Delta-Attention layer (``models.ling``): three tails and a matrix a head,
-whose forms are ``ops.kda``'s; :class:`SSDState` that of a Mamba-2 layer
-(``models.nemotron_h``): one tail and a matrix a head, whose forms are
-``ops.ssd``'s.
+whose forms are ``ops.kda``'s; :class:`GatedDeltaState` that of a Gated
+DeltaNet layer (``models.qwen3_next``): one tail over ``[q | k | v]`` and a
+matrix a value head, the same forms under a scalar gate; :class:`SSDState`
+that of a Mamba-2 layer (``models.nemotron_h``): one tail and a matrix a head,
+whose forms are ``ops.ssd``'s.
 
 The recurrence, a token ``t``, channels ``c`` and states ``n``, in float32::
 
@@ -142,6 +144,39 @@ class DeltaState(_SlotArrays):
         qk = _tail_array(self.heads * self.key_dim, self.d_conv, dtype)
         return (qk, qk,
                 _tail_array(self.heads * self.value_dim, self.d_conv, dtype),
+                ((self.heads, self.value_dim, self.key_dim),
+                 jnp.dtype(jnp.float32)))
+
+    def chunk_formulation(self, chunk: int, impl: str) -> str:
+        return kda.chunk_scan_formulation(chunk)
+
+    def step_formulation(self, impl: str) -> str:
+        return kda.step_formulation(self.heads, self.key_dim, self.value_dim,
+                                    impl)
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaState(_SlotArrays):
+    """What a Gated DeltaNet layer keeps a slot (``ops.kda`` under a scalar
+    gate): the tail of its one convolution over ``[q | k | v]`` (``2 *
+    key_heads * key_dim + heads * value_dim`` channels) and the matrix state a
+    *value* head in float32, stored transposed as :class:`DeltaState`'s:
+    ``(heads, value_dim, key_dim)``."""
+
+    key_heads: int
+    heads: int
+    key_dim: int
+    value_dim: int
+    d_conv: int
+
+    names = ("conv_tail", "delta_state")
+
+    @property
+    def conv_channels(self) -> int:
+        return 2 * self.key_heads * self.key_dim + self.heads * self.value_dim
+
+    def arrays(self, dtype) -> tuple[tuple[tuple[int, ...], jnp.dtype], ...]:
+        return (_tail_array(self.conv_channels, self.d_conv, dtype),
                 ((self.heads, self.value_dim, self.key_dim),
                  jnp.dtype(jnp.float32)))
 

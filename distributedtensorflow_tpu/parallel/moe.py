@@ -445,6 +445,22 @@ def sigmoid_topk_route(h: jax.Array, router_kernel: jax.Array,
     return idx, w * route_scale
 
 
+def softmax_topk_route(h: jax.Array, router_kernel: jax.Array, *,
+                       top_k: int, route_norm: bool = True):
+    """``(idx, weight)`` of shape ``(T, top_k)`` as :func:`sigmoid_topk_route`
+    gives them, under a softmax router (Qwen3-Next): float32 probabilities
+    over the router's full width, the ``top_k`` largest, their weights
+    renormalised over the chosen where ``route_norm`` (``norm_topk_prob``).
+    No selection bias, no groups, no scale."""
+    probs = jax.nn.softmax(jnp.dot(
+        h.astype(jnp.float32), router_kernel.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    w, idx = lax.top_k(probs, top_k)
+    if route_norm:
+        w = w / w.sum(-1, keepdims=True)
+    return idx, w
+
+
 def group_plan(idx: jax.Array, held: tuple[int, int], token_mask=None,
                tile: int = GROUP_TILE) -> dict:
     """Where each routed pair goes in the row buffer of the grouped
@@ -567,11 +583,12 @@ def _grouped_ffn_xla(x_rows, weights, tile_expert, tiles_used, tile):
 def dropless_moe(
     h: jax.Array,                 # (T, d)
     router_kernel: jax.Array,     # (d, E_published)
-    select_bias: jax.Array,       # (E_published,)
+    select_bias: jax.Array | None,  # (E_published,); a softmax router has none
     experts: dict,                # w_gate, w_up (E_held, d_in, m), w_down (E_held, m, d_in)
     *,
     held: tuple[int, int],
     top_k: int,
+    router: str = "sigmoid",
     route_norm: bool = True,
     route_scale: float = 1.0,
     route_norm_eps: float = 1e-20,
@@ -583,7 +600,11 @@ def dropless_moe(
 ) -> tuple[jax.Array, dict]:
     """The held experts' share of a token-choice MoE layer, dropless.
 
-    Routes every token over the router's full width, computes ``sum_j w_j
+    Routes every token over the router's full width (``router``:
+    ``"sigmoid"``, :func:`sigmoid_topk_route`, or ``"softmax"``,
+    :func:`softmax_topk_route`, which reads ``top_k`` and ``route_norm``
+    alone: a softmax router has no selection bias, no groups and no scale),
+    computes ``sum_j w_j
     * Expert_{top_j}(x)`` over the choices that are held here, and returns
     it with the counters of :func:`group_plan` (``pairs``, ``experts_hit``,
     ``max_load``; under group-limited routing, ``n_group`` > 1, also
@@ -606,11 +627,17 @@ def dropless_moe(
 
     tile = group_tile(h.shape[0], top_k, router_kernel.shape[-1])
     with jax.named_scope("router"):
-        idx, w = sigmoid_topk_route(
-            h, router_kernel, select_bias, top_k=top_k,
-            route_norm=route_norm, route_scale=route_scale,
-            route_norm_eps=route_norm_eps, n_group=n_group,
-            topk_group=topk_group)
+        if router == "softmax":
+            idx, w = softmax_topk_route(h, router_kernel, top_k=top_k,
+                                        route_norm=route_norm)
+        elif router == "sigmoid":
+            idx, w = sigmoid_topk_route(
+                h, router_kernel, select_bias, top_k=top_k,
+                route_norm=route_norm, route_scale=route_scale,
+                route_norm_eps=route_norm_eps, n_group=n_group,
+                topk_group=topk_group)
+        else:
+            raise ValueError(f"router {router!r}: \"sigmoid\" or \"softmax\"")
         plan = group_plan(idx, held, token_mask, tile)
     x = h if experts_in is None else experts_in
     with jax.named_scope("experts"):

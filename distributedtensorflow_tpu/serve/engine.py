@@ -566,6 +566,9 @@ class Engine:
         #: group: the real tokens of its prefill chunks and one a decoding
         #: slot; counted only where a group keeps a state a slot
         self._step_scan = 0
+        #: the real tokens of the current step's prefill chunks, and the
+        #: (query, key) pairs they attend in a layer that keeps every row
+        self._step_chunk_tokens = self._step_chunk_pairs = 0
         #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
         #: and closes its profiler windows by iteration, so a capture
         #: armed through ``POST /profilez?steps=N`` holds N iterations.
@@ -924,6 +927,7 @@ class Engine:
         self._step_rows_read = {}
         self._step_chunk_summaries = None
         self._step_scan = 0
+        self._step_chunk_tokens = self._step_chunk_pairs = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace) whose leaves tile it: a leaf begins where the one before
         # it ended (`obs.tracing.tiled`).  The step record's walls are its
@@ -1152,6 +1156,8 @@ class Engine:
             "admitted": len(admitted),
             "evicted": self._step_evicted,
             "prefill_chunks": chunks,
+            "chunk_tokens": self._step_chunk_tokens,
+            "chunk_pairs": self._step_chunk_pairs,
             "budget_stall": int(self._prefill_stalled),
             "tokens_committed": tokens,
             "spec_drafted": drafted,
@@ -1429,6 +1435,8 @@ class Engine:
                     and self.programs.chunk_attention.startswith("masked_")):
                 self._step_latent[3] += self.kv.latent_layers * select_walk(
                     start + c, self.kv.max_context)
+        self._step_chunk_tokens += real
+        self._step_chunk_pairs += real * start + real * (real + 1) // 2
         if self.kv.state is not None:
             self._step_scan += real
         if self._summaries is not None:

@@ -11,7 +11,8 @@ shapes, so a serving process compiles each once.
 
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
 ``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``,
-``models.evabyte``, ``models.ling``, ``models.nemotron_h``):
+``models.evabyte``, ``models.ling``, ``models.nemotron_h``,
+``models.qwen3_next``):
 ``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
@@ -80,7 +81,7 @@ and the three programs differ only in where the rows live:
 of latent rows, or a state group's arrays — ``(convolution tails, scan
 states)`` for jamba, ``(convolution tails,)`` for lfm2, ``(q tails, k tails,
 v tails, matrix states)`` for ling, ``(convolution tails, matrix states)``
-for nemotron_h: what ``cfg.state_rows.arrays`` lists —,
+for nemotron_h and qwen3_next: what ``cfg.state_rows.arrays`` lists —,
 ``(layers, slots, ...)`` each) and ``tables`` ``{group: page
 table}`` (a state group's is the one column that names the slot;
 ``serve.kv_cache.GroupedKVCache``: layers in groups by attention
@@ -122,7 +123,12 @@ nemotron_h, whose Mamba-2 layers have ``h<i>/{state_read,state_write}`` and
 ``h<i>/mamba2/{in_proj,conv,scan|step,gated_norm,out_proj}`` and whose expert
 layers ``h<i>/moe/{latent_down,latent_up}`` around the experts (a layer of
 that family is one part, and an expert layer is in no cache group: it calls
-no hook), ``h<i>/eva_attn`` for evabyte, whose hook adds
+no hook), ``h<i>/attn`` again for qwen3_next's gated attention layers
+(``proj``, ``gate`` and ``out_proj`` the block's own), whose Gated DeltaNet
+layers have ``h<i>/{state_read,state_write}`` and ``h<i>/gdn/{proj,conv,gate,
+scan|step,gated_norm,out_proj}`` and every layer ``h<i>/moe/shared_gate``
+beside the router, the experts and the shared expert, ``h<i>/eva_attn`` for
+evabyte, whose hook adds
 ``summarise`` and ``summary_write`` beside ``kv_write`` and ``paged_attn``
 (the block's own are ``qkv``, ``rope`` and ``proj``); an expert layer's FFN is
 ``h<i>/{router,experts}``, a dense one's ``h<i>/mlp``), ``head``, ``sample``,
@@ -139,7 +145,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import (afmoe, evabyte, gpt, jamba, joyai, lfm2, ling, mimo,
-                      nemotron_h)
+                      nemotron_h, qwen3_next)
 from ..ops.kda import kda_chunk_scan, kda_step
 from ..ops.ssd import ssd_chunk_scan, ssd_step
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
@@ -236,16 +242,18 @@ class _TwoPools:
 
 class _SlotState:
     """The ``mixer`` hook of a state layer (``models.jamba``,
-    ``models.lfm2``, ``models.ling``, ``models.nemotron_h``): the programs'
-    own, as ``attend`` is.  ``conv``, ``scan``, ``delta`` and ``ssd`` read the
-    layer's state out of the group's arrays (scope ``state_read``), run the
-    form (``<scope>/conv``; ``mamba/scan`` or ``mamba/ssm_step``; ``kda/scan``
-    or ``kda/step``; ``mamba2/scan`` or ``mamba2/step``) and store the state
-    back (``state_write``) — a convolution tail is array ``which`` (0 where
-    the layer has one convolution; ling's q, k and v are 0, 1, 2) and the scan
-    or matrix state the last of ``cfg.state_rows.arrays`` (a family that keeps
-    the tail alone calls none of the three).  ``pools`` is the program's dict
-    of pools, updated in place."""
+    ``models.lfm2``, ``models.ling``, ``models.nemotron_h``,
+    ``models.qwen3_next``): the programs' own, as ``attend`` is.  ``conv``,
+    ``scan``, ``delta`` and ``ssd`` read the layer's state out of the group's
+    arrays (scope ``state_read``), run the form (``<scope>/conv``;
+    ``mamba/scan`` or ``mamba/ssm_step``; ``kda/scan`` or ``kda/step``, under
+    ``gdn`` for qwen3_next's scalar gate; ``mamba2/scan`` or ``mamba2/step``)
+    and store the state back (``state_write``) — a convolution tail is array
+    ``which`` (0 where the layer has one convolution: jamba, lfm2, nemotron_h,
+    qwen3_next; ling's q, k and v are 0, 1, 2) and the scan or matrix state
+    the last of ``cfg.state_rows.arrays`` (a family that keeps the tail alone,
+    lfm2, calls none of the three).  ``pools`` is the program's dict of pools,
+    updated in place."""
 
     def __init__(self, pools: dict, li: int, impl: str = "auto"):
         self.pools, self.li, self.impl = pools, li, impl
@@ -303,12 +311,12 @@ class _ChunkState(_SlotState):
         return ssm_chunk_scan(u, delta, a, b, c, d, state, self.valid,
                               impl=self.impl)
 
-    def delta(self, q, k, v, g, beta):
+    def delta(self, q, k, v, g, beta, scope: str = "kda"):
         """The gated delta rule through the chunk, from the slot's matrix
-        state (``ops.kda``)."""
+        state (``ops.kda``), under the family's own scope ``<scope>/scan``."""
         with jax.named_scope("state_read"):
-            state = self._get(self.pools["state"][-1])
-        with jax.named_scope("kda"), jax.named_scope("scan"):
+            state = self._get_slot(self.pools["state"][-1])
+        with jax.named_scope(scope), jax.named_scope("scan"):
             o, state = kda_chunk_scan(q, k, v, g, beta, state, self.valid)
         with jax.named_scope("state_write"):
             self._store(-1, state)
@@ -361,12 +369,12 @@ class _StepState(_SlotState):
     def _scan(self, u, delta, a, b, c, d, states):
         return ssm_step(u, delta, a, b, c, d, states)
 
-    def delta(self, q, k, v, g, beta):
+    def delta(self, q, k, v, g, beta, scope: str = "kda"):
         """One token of the gated delta rule a slot, in place in the group's
         array of matrix states: an inactive slot's step is made the identity
         (no decay, no correction), so no pass over the array selects after
         it."""
-        with jax.named_scope("kda"), jax.named_scope("step"):
+        with jax.named_scope(scope), jax.named_scope("step"):
             arrays = list(self.pools["state"])
             o, arrays[-1] = kda_step(
                 q, k, v, jnp.where(self.active[:, None, None], g, 0.0),
@@ -687,6 +695,7 @@ PROGRAMS = {
     evabyte.EvaByteConfig: evabyte,
     ling.LingConfig: ling,
     nemotron_h.NemotronHConfig: nemotron_h,
+    qwen3_next.Qwen3NextConfig: qwen3_next,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -698,7 +707,8 @@ PROGRAMS = {
 #: formulation), so it is refused until it has
 #: such tests and a cell of its own.  Over a state group there is more in
 #: the way than tests: a rejected draft's steps cannot be rolled back out of
-#: a state, which keeps no earlier position (jamba, lfm2).
+#: a state, which keeps no earlier position (jamba, lfm2, ling, nemotron_h,
+#: qwen3_next).
 FUSED = (gpt,)
 
 #: the families a request may be admitted for onto cached prefix blocks: those
@@ -774,14 +784,15 @@ class Programs:
       not (every row selected: the dense sum);
     - ``chunk_scan``: the form ``prefill`` scans a state group's layers
       with: ``"ssm_chunk_scan"`` (the kernel that holds the state in VMEM),
-      ``"chunked"`` (ling, nemotron_h: the delta rule's, or the scalar
-      decay's, chunked mathematics in plain ``jax.numpy``) or ``"plain"``
+      ``"chunked"`` (ling, qwen3_next, nemotron_h: the delta rule's, or the
+      scalar decay's, chunked mathematics in plain ``jax.numpy``) or ``"plain"``
       (``lax.scan``); None where no
       layer keeps a state, or the state has no scan (lfm2);
     - ``state_form``: what a state layer keeps a slot, the names of the
       group's arrays joined by ``+``: ``"conv_tail+scan_state"`` (jamba),
       ``"conv_tail"`` (lfm2), ``"q_tail+k_tail+v_tail+delta_state"`` (ling),
-      ``"conv_tail+ssd_state"`` (nemotron_h);
+      ``"conv_tail+ssd_state"`` (nemotron_h), ``"conv_tail+delta_state"``
+      (qwen3_next);
       None where no layer keeps a state."""
 
     def __init__(self, family, cfg, *, chunk: int, block_size: int,

@@ -75,6 +75,12 @@ CONFIGS = {
     # attention layers without rotary; random init
     "nemotron_h_tiny": ("nemotron_h_tiny", None),
     "nemotron3_super_ep4": ("nemotron3_super_ep4", None),
+    # the qwen3_next family (models/qwen3_next.py): Gated DeltaNet layers (a
+    # matrix state a value head a slot under one unbounded scalar gate, fewer
+    # key heads) beside gated attention at heads of 256, softmax-routed
+    # experts and a gated shared expert in every layer; random init
+    "qwen3_next_tiny": ("qwen3_next_tiny", None),
+    "qwen3_next_ep4": ("qwen3_next_ep4", None),
 }
 
 

@@ -396,6 +396,14 @@ FAMILIES = {
     # jamba2_3b: 1024 queries of 20 heads on ONE K/V head of 128
     "kv_chunk_attn_20_on_1": _kv_chunk(1024, 20, 1, 128, 128, None, 2112,
                                        67584),
+    # qwen3_next_ep4's serving shapes: 48 slots, 16 query heads on 2 K/V
+    # heads of 256 (two lane tiles a head, 8 query rows a K/V head), a table
+    # of 4,224 columns (contexts to 67,584: 811 KB of page tables in SMEM)
+    "paged_attn_d256": _paged(None, slots=48, heads=16, d=256, columns=4224,
+                              width=512),
+    # ... a prefill chunk of 2,048 queries: 4 query heads a grid step
+    "kv_chunk_attn_d256": _kv_chunk(2048, 16, 2, 256, 256, None, 4224,
+                                    73728),
 }
 
 

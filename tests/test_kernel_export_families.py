@@ -249,6 +249,22 @@ _STATE_FAMILIES = {
                                    "kv_chunk_attn": 1},
                  "decode": {"moe_grouped_up": 1, "ssd_step": 2,
                             "paged_attn": 1}}),
+    # G G G A with 16 of 64 experts held in every layer: a state group (one
+    # tail over [q | k | v], the value heads' matrix states) beside a K/V
+    # full group at heads of 256.  A prefill chunk scans in plain
+    # ``jax.numpy`` (the scalar-gate body), decode steps through ling's
+    # ``kda_step`` with the gate broadcast into its rows; 8 query heads a
+    # K/V head of two lane tiles go through ``paged_attn`` and
+    # ``kv_chunk_attn``, the experts through the grouped kernels
+    "qwen3_next-delta_state": dict(
+        preset="qwen3_next_ep4",
+        cut=dict(num_experts=64, experts_held=16, num_layers=4),
+        slots=48, blocks=16384, row=512,
+        names=("conv_tail", "delta_state"), pools=("k_pool", "v_pool"),
+        kernels={"prefill_chunk": {"moe_grouped_up": 4, "kda_step": 0,
+                                   "kv_chunk_attn": 1},
+                 "decode": {"moe_grouped_up": 4, "kda_step": 3,
+                            "paged_attn": 1}}),
     # a dense conv layer, an attention layer and two conv layers with all 64
     # experts (one grouped call an expert layer): a state group of ONE array,
     # the convolution tails.  Decode attends heads of 64 (four query heads a

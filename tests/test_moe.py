@@ -256,3 +256,63 @@ def test_routers_exclude_padding_tokens():
     _, _, aux_small = top1_route(logits, cap, mask)
     _, _, aux_big = top1_route(big_logits, cap, big_mask)
     np.testing.assert_allclose(float(aux_big), float(aux_small), rtol=1e-6)
+
+
+# -- the softmax router of a dropless layer (Qwen3-Next) ----------------------
+
+@pytest.mark.parametrize("route_norm", [True, False])
+def test_softmax_topk_route_is_the_dense_softmax(route_norm):
+    """``(idx, weight)`` as ``sigmoid_topk_route`` gives them: the ``top_k``
+    largest of a float32 softmax over the router's full width, renormalised
+    over the chosen where ``route_norm``."""
+    from distributedtensorflow_tpu.parallel import moe
+
+    k = jax.random.split(jax.random.PRNGKey(7), 2)
+    h = jax.random.normal(k[0], (9, 32), jnp.bfloat16)
+    router = jax.random.normal(k[1], (32, 16))
+    idx, w = moe.softmax_topk_route(h, router, top_k=4,
+                                    route_norm=route_norm)
+    probs = np.asarray(jax.nn.softmax(
+        np.asarray(h, np.float32) @ np.asarray(router), -1))
+    want = np.sort(probs, -1)[:, ::-1][:, :4]
+    assert idx.shape == w.shape == (9, 4) and w.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.take_along_axis(probs, np.asarray(idx), -1), want, atol=1e-6)
+    if route_norm:
+        want = want / want.sum(-1, keepdims=True)
+    np.testing.assert_allclose(w, want, atol=1e-6)
+
+
+def test_dropless_moe_routes_by_the_router_it_is_told():
+    """``router="softmax"`` is the softmax router, which has no bias; the
+    default is the sigmoid one, as every caller before it: the two give
+    different sums from one router, the softmax one the by-hand sum of its
+    own route, and another name is refused."""
+    from distributedtensorflow_tpu.models.afmoe import swiglu
+    from distributedtensorflow_tpu.parallel import moe
+
+    k = jax.random.split(jax.random.PRNGKey(8), 6)
+    d, m, e = 32, 24, 8
+    h = jax.random.normal(k[0], (10, d))
+    router = jax.random.normal(k[1], (d, e))
+    experts = {"w_gate": jax.random.normal(k[2], (4, d, m)) * 0.2,
+               "w_up": jax.random.normal(k[3], (4, d, m)) * 0.2,
+               "w_down": jax.random.normal(k[4], (4, m, d)) * 0.2}
+    kw = dict(held=(2, 4), top_k=3, impl="xla")
+    soft, counters = moe.dropless_moe(h, router, None, experts,
+                                      router="softmax", **kw)
+    sig, _ = moe.dropless_moe(h, router, jnp.zeros((e,)), experts, **kw)
+    with pytest.raises(ValueError, match="\"sigmoid\" or \"softmax\""):
+        moe.dropless_moe(h, router, None, experts, router="top1", **kw)
+    assert np.abs(np.asarray(soft - sig)).max() > 1e-2
+    idx, w = moe.softmax_topk_route(h, router, top_k=3)
+    want = np.zeros((10, d), np.float32)
+    for t in range(10):
+        for j in range(3):
+            local = int(idx[t, j]) - 2
+            if 0 <= local < 4:
+                one = jax.tree.map(lambda a: a[local], experts)
+                want[t] += float(w[t, j]) * np.asarray(swiglu(one, h[t:t + 1]))[0]
+    np.testing.assert_allclose(soft, want, atol=1e-5)
+    assert int(counters["pairs"]) == int(
+        ((np.asarray(idx) >= 2) & (np.asarray(idx) < 6)).sum())

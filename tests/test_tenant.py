@@ -134,10 +134,12 @@ def tenant_logdir(served_model, tmp_path_factory):
     return logdir
 
 
-#: The key set of a step row of this run at the parent commit (PR 55),
-#: beside the ledger: every row has the first, some rows the second.
+#: The key set of a step row of this run at PR 55's parent commit, beside
+#: the ledger, and the chunk's two counters (PR 58: ``chunk_tokens``,
+#: ``chunk_pairs``): every row has the first, some rows the second.
 STEP_KEYS = {
     "active_slots", "admit_s", "admitted", "between_s", "budget_stall",
+    "chunk_pairs", "chunk_tokens",
     "commit_cpu_s", "commit_s", "compile_s", "decode_s", "device_sampled",
     "dispatch_s", "evicted", "fetch_s", "filling_slots", "first_token_s",
     "gc_s", "kv_blocks_billed", "kv_blocks_freed", "kv_blocks_used_full",
