@@ -385,13 +385,14 @@ LANES = 128
 #: lane width, so the (8, rows) scores, the running maximum and sum (kept
 #: replicated across lanes) and the (8, 128) accumulator all share one shape
 PAGED_ROWS = 128
-#: such stretches a grid step holds: all their block copies are started at
-#: the top of the step and each stretch waits only for its own, so the later
-#: stretches' copies run under the earlier ones' arithmetic
-PAGED_STRETCHES = 4
+#: rows a trip of the decode kernel's walk holds, in one of its two buffers
+#: a pool: 128 read as fast as 256 and 512 or faster at every cell's shape,
+#: and a trip's code, unrolled once a buffer, is lowered at every start-up
+#: (PERF.md, PR 59)
+PAGED_STRETCH = PAGED_ROWS
 #: rows a trip of the latent decode kernel's walk holds, in one of its two
 #: buffers: 256, 512 and 1,024 read the same on the chip (PERF.md, PR 55)
-PAGED_LATENT_STRETCH = PAGED_ROWS * PAGED_STRETCHES
+PAGED_LATENT_STRETCH = 4 * PAGED_ROWS
 
 
 def paged_chunk_attention(
@@ -461,9 +462,18 @@ def paged_chunk_attention(
 
 
 def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
-                         v_hbm, *refs, block_size, n_steps, scale,
-                         rest_at=None, with_lse=False, head_tiles=1):
-    """``head_tiles``: lane tiles a K/V head (2 at a head of 256): a head's scores
+                         v_hbm, *refs, block_size, scale, rest_at=None,
+                         with_lse=False, head_tiles=1):
+    """A grid step is a slot; its rows from ``lo`` to its length are walked
+    in a loop of trips read from the prefetched scalars, a stretch of the
+    buffers' rows a trip, over two K and two V buffers: a trip starts the
+    next stretch's copies — this slot's, or the first of the next slot's —
+    before it waits for its own, so they run under its arithmetic.  The
+    buffers, the semaphores and ``walked`` (trips of the slots before: the
+    buffer's parity) outlive a grid step; every copy started is waited for
+    by the trip that folds it.
+
+    ``head_tiles``: lane tiles a K/V head (2 at a head of 256): a head's scores
     are the sum of its tiles' products, its running maximum and sum are kept
     alike in each of its tiles' rows of the scratch, and each of its V tiles
     takes the one set of probabilities.
@@ -478,113 +488,146 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    lse_ref = refs[-7] if with_lse else None
-    sink_ref = refs[0] if len(refs) == 8 + with_lse else None
-    o_ref = refs[-7 - with_lse]
-    kbuf, vbuf, sem, m_sc, l_sc, acc_sc = refs[-6:]
-    s, c = pl.program_id(0), pl.program_id(1)
-    rows = PAGED_ROWS
-    step_rows = rows * PAGED_STRETCHES
-    bps = rows // block_size                   # blocks a stretch
-    nb = tables_ref.shape[1]
+    lse_ref = refs[-10] if with_lse else None
+    sink_ref = refs[0] if len(refs) == 11 + with_lse else None
+    o_ref = refs[-10 - with_lse]
+    *bufs, sem, walked, m_sc, l_sc, acc_sc = refs[-9:]
+    bufs = (bufs[:2], bufs[2:])                # (K, V) of each parity
+    s = pl.program_id(0)
+    slots, nb = tables_ref.shape
+    stretch, rows = bufs[0][0].shape[0], PAGED_ROWS
     tiles, q_rows = o_ref.shape[1], o_ref.shape[2]
     parts = q_ref.shape[1] // tiles            # query tiles a K/V tile
-    n, lo, layer = lens_ref[s], lo_ref[s], layer_ref[0]
-    first = (lo // step_rows + c) * step_rows  # first key row of this step
+    layer = layer_ref[0]
 
-    @pl.when((s == 0) & (c == 0))
+    def walk_of(of):
+        """Slot ``of``'s table row, length, first row, first trip and the
+        trip past its last; a slot past the last has no rows."""
+        row = jnp.minimum(of, slots - 1)
+        n = jnp.where(of < slots, lens_ref[row], 0)
+        lo = lo_ref[row]
+        c0 = lo // stretch
+        return row, n, lo, c0, jnp.maximum((n + stretch - 1) // stretch, c0)
+
+    _, n, lo, c0, c1 = walk_of(s)
+
+    @pl.when(s == 0)
     def _():
-        # skipped blocks leave these rows as they were: keep them finite
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        walked[0] = 0
 
-    @pl.when(c == 0)
+    before = walked[0]
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def copies(of, c, into, start):
+        """Start, or wait for, the copies of stretch ``c`` of slot ``of``
+        into buffers ``into``: the blocks of a part (``rows`` rows) together
+        and without a branch each, if the part holds a row the slot attends;
+        before the slot's first column its first block is copied again and
+        past its last column its last (the mask discards the rows)."""
+        row, live, low, _, _ = walk_of(of)
+        first = low // block_size
+        last = jnp.minimum(jnp.maximum(live - 1, 0) // block_size, nb - 1)
+
+        def a_part(part):
+            for j in range(rows // block_size):
+                at = part * rows + j * block_size
+                blk = tables_ref[row, jnp.clip(
+                    (c * stretch + at) // block_size, first, last)
+                ] if start else 0
+                src = pl.ds(blk * block_size, block_size)
+                dst = pl.ds(at, block_size)
+                for i, (hbm, buf) in enumerate(zip((k_hbm, v_hbm), bufs[into])):
+                    cp = pltpu.make_async_copy(
+                        hbm.at[layer, src], buf.at[dst],
+                        sem.at[i, into, part])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+
+        for part in range(stretch // rows):
+            at = c * stretch + part * rows
+            pl.when((at < live) & (at + rows > low))(
+                functools.partial(a_part, part))
+
+    # the walk's first stretch is slot 0's; a slot that walks nothing starts
+    # the next slot's, which its last trip would have
+    @pl.when((s == 0) | (c1 == c0))
     def _():
-        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[...] = jnp.zeros_like(l_sc)
-        acc_sc[...] = jnp.zeros_like(acc_sc)
+        of = s + (c1 == c0).astype(jnp.int32)
+        *_, first_trip, _ = walk_of(of)
+        for into in range(2):
+            pl.when(jax.lax.rem(before, 2) == into)(
+                functools.partial(copies, of, first_trip, into, True))
 
-    def copies(j):
-        """Whether block ``j`` of this step holds a row the slot attends,
-        and its two copies."""
-        b0 = first + j * block_size
-        blk = tables_ref[s, jnp.minimum(b0 // block_size, nb - 1)]
-        src = pl.ds(blk * block_size, block_size)
-        dst = pl.ds(j * block_size, block_size)
-        return (b0 < n) & (b0 + block_size > lo), (
-            pltpu.make_async_copy(k_hbm.at[layer, src], kbuf.at[dst],
-                                  sem.at[0, j]),
-            pltpu.make_async_copy(v_hbm.at[layer, src], vbuf.at[dst],
-                                  sem.at[1, j]))
+    def a_trip(c, into):
+        ahead = c + 1 < c1
+        *_, next_first, _ = walk_of(s + 1)
+        copies(jnp.where(ahead, s, s + 1),
+               jnp.where(ahead, c + 1, next_first), 1 - into, True)
+        copies(s, c, into, False)
+        kbuf, vbuf = bufs[into]
+        for part in range(stretch // rows):
+            start = c * stretch + part * rows
 
-    @pl.when(first < n)
-    def _():
-        # only the blocks that hold a row this slot attends are read
-        for j in range(bps * PAGED_STRETCHES):
-            need, (ck, cv) = copies(j)
-
-            @pl.when(need)
-            def _():
-                ck.start()
-                cv.start()
-
-    for part in range(PAGED_STRETCHES):
-        start = first + part * rows
-
-        @pl.when(start < n)
-        def _(part=part, start=start):
-            for j in range(part * bps, (part + 1) * bps):
-                need, (ck, cv) = copies(j)
-
-                @pl.when(need)
-                def _():
-                    ck.wait()
-                    cv.wait()
-
-            kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (q_rows, rows), 1)
-            valid = (kpos < n) & (kpos >= lo)
-            here = pl.ds(part * rows, rows)
-            for hh in range(0, tiles, head_tiles):
-                lanes = pl.ds(hh * LANES, LANES)
-                sc = jax.lax.dot_general(
-                    q_ref[0, hh * parts], kbuf[here, lanes],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)         # (8, rows)
-                for more in range(hh + 1, hh + head_tiles):
-                    sc += jax.lax.dot_general(
-                        q_ref[0, more], kbuf[here, pl.ds(more * LANES, LANES)],
+            @pl.when((start < n) & (start + rows > lo))
+            def _(part=part, start=start):
+                kpos = start + jax.lax.broadcasted_iota(
+                    jnp.int32, (q_rows, rows), 1)
+                valid = (kpos < n) & (kpos >= lo)
+                here = pl.ds(part * rows, rows)
+                for hh in range(0, tiles, head_tiles):
+                    lanes = pl.ds(hh * LANES, LANES)
+                    sc = jax.lax.dot_general(
+                        q_ref[0, hh * parts], kbuf[here, lanes],
                         (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                if rest_at is not None:
-                    sc += jax.lax.dot_general(
-                        q_ref[0, hh * parts + 1],
-                        kbuf[here, pl.ds(rest_at + hh // 2 * LANES, LANES)],
-                        (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                sc = jnp.where(valid, sc * scale, NEG_INF)
-                m_prev = m_sc[hh]
-                m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
-                l_new = alpha * l_sc[hh] + p.sum(axis=1, keepdims=True)
-                for tile in range(hh, hh + head_tiles):
-                    l_sc[tile] = l_new
-                    acc_sc[tile] = alpha * acc_sc[tile] + jnp.dot(
-                        p.astype(vbuf.dtype),
-                        vbuf[here, pl.ds(tile * LANES, LANES)],
-                        preferred_element_type=jnp.float32)
-                    m_sc[tile] = m_new
+                        preferred_element_type=jnp.float32)     # (8, rows)
+                    for more in range(hh + 1, hh + head_tiles):
+                        sc += jax.lax.dot_general(
+                            q_ref[0, more],
+                            kbuf[here, pl.ds(more * LANES, LANES)],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                    if rest_at is not None:
+                        sc += jax.lax.dot_general(
+                            q_ref[0, hh * parts + 1],
+                            kbuf[here,
+                                 pl.ds(rest_at + hh // 2 * LANES, LANES)],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                    sc = jnp.where(valid, sc * scale, NEG_INF)
+                    m_prev = m_sc[hh]
+                    m_new = jnp.maximum(m_prev, sc.max(axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+                    l_new = alpha * l_sc[hh] + p.sum(axis=1, keepdims=True)
+                    for tile in range(hh, hh + head_tiles):
+                        l_sc[tile] = l_new
+                        acc_sc[tile] = alpha * acc_sc[tile] + jnp.dot(
+                            p.astype(vbuf.dtype),
+                            vbuf[here, pl.ds(tile * LANES, LANES)],
+                            preferred_element_type=jnp.float32)
+                        m_sc[tile] = m_new
 
-    @pl.when(c == n_steps - 1)
-    def _():
-        l = l_sc[...]
-        if sink_ref is not None:
-            # the key without a value: one more term of the denominator
-            l = l + jnp.exp(sink_ref[...] - m_sc[...])
-        o_ref[0] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse_ref[0] = m_sc[...] + jnp.log(jnp.maximum(l, 1e-30))
+    def trip(c, _):
+        # a trip's code once a buffer: its VMEM and semaphore addresses are
+        # then constants (PERF.md, PRs 55 and 59)
+        parity = jax.lax.rem(before + c - c0, 2)
+        for into in range(2):
+            pl.when(parity == into)(functools.partial(a_trip, c, into))
+
+    jax.lax.fori_loop(c0, c1, trip, None)
+    walked[0] = before + c1 - c0
+
+    l = l_sc[...]
+    if sink_ref is not None:
+        # the key without a value: one more term of the denominator
+        l = l + jnp.exp(sink_ref[...] - m_sc[...])
+    o_ref[0] = (acc_sc[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if lse_ref is not None:
+        lse_ref[0] = m_sc[...] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def paged_decode_formulation(heads: int, kv_heads: int, head_dim: int,
@@ -639,11 +682,21 @@ def paged_window_decode_attention(
     walks under one softmax (:func:`merge_softmax_parts`) — both are the
     kernel's alone.
 
-    The kernel (``name="paged_attn"``) has the page tables and lengths
-    prefetched into SMEM and the pools left in HBM; grid ``(slot, step)``,
-    a step copying the needed blocks of its 4 x 128 key rows into VMEM and
-    folding them, 128 rows at a time, into a running softmax, one 128-lane
-    tile of the pool's row after the other on the MXU.  A tile is one K/V
+    The kernel (``name="paged_attn"``) has the page tables, lengths and
+    first rows prefetched into SMEM and the pools left in HBM; grid
+    ``(slot,)``.  A slot's rows are walked in a loop of trips read from its
+    length, from the stretch of ``PAGED_STRETCH`` rows that holds its first
+    attended row to the one that holds its last — a table column no slot
+    holds costs nothing — over two K and two V buffers in VMEM: a trip starts
+    the next stretch's block copies, this slot's or the next slot's first,
+    before it waits for its own, and folds its 128 rows
+    into a running softmax, one 128-lane tile of the pool's row after the
+    other on the MXU.  The blocks of 128 rows are copied under one test
+    (before a slot's first column its first block again, past its last its
+    last: the mask discards the rows), and only where the 128 hold a row the
+    slot attends.
+
+    A tile is one K/V
     head of 128, two of 64 or half of one of 256 (Qwen3-Next: a head's
     scores are the sum of its two tiles' products and each of its V tiles
     takes the same probabilities), and the query heads that attend it are the
@@ -651,8 +704,8 @@ def paged_window_decode_attention(
     lanes ``[i * D, (i + 1) * D)`` of its rows and zeros in the others, so
     the product over all 128 lanes is that head's scores, and its output is
     the same lanes of the same rows (the other lanes, its weights on the
-    neighbour's values, are dropped).  Steps past a slot's length copy and
-    compute nothing.  Needs ``D`` of 64 or 128, ``H // Hkv <= 32`` (a tile's
+    neighbour's values, are dropped).  A slot that attends nothing returns
+    zeros.  Needs ``D`` of 64, 128 or 256, ``H // Hkv <= 32`` (a tile's
     query heads are its rows: 24 at 20 on 1), a block size that divides 128
     and a pool row of whole tiles (:func:`paged_decode_formulation`); other
     shapes, and ``impl="xla"``, take the plain formulation.
@@ -669,7 +722,6 @@ def paged_window_decode_attention(
     h_kv = width // d
     dv = v_pool.shape[-1] // h_kv
     g = h // h_kv
-    step_rows = PAGED_ROWS * PAGED_STRETCHES
     if paged_decode_formulation(h, h_kv, d, block_size, impl,
                                 dv) == "plain":
         # the plain formulation (gathers every table column): the tests'
@@ -681,9 +733,6 @@ def paged_window_decode_attention(
             block_size=block_size, window=window, sink=sink)
     if interpret is None:
         interpret = not on_tpu()
-    cap = block_tables.shape[1] * block_size
-    # a window starts anywhere inside its first step
-    span = cap if window is None else min(cap, window + step_rows - 1)
     lens = attend_lens.astype(jnp.int32)
     if lo is None:
         lo = (jnp.zeros_like(lens) if window is None
@@ -727,9 +776,8 @@ def paged_window_decode_attention(
     out = _paged_attn_call(
         block_tables.astype(jnp.int32), lens, lo,
         jnp.full((1,), layer, jnp.int32), qt, k_pool, v_pool, sink,
-        block_size=block_size, n_steps=-(-span // step_rows),
-        scale=d ** -0.5, interpret=interpret, rest_at=rest_at,
-        with_lse=with_lse, head_tiles=head_tiles)
+        block_size=block_size, scale=d ** -0.5, interpret=interpret,
+        rest_at=rest_at, with_lse=with_lse, head_tiles=head_tiles)
     lse = None
     if with_lse:
         # a row's log-denominator lies across its lanes: one lane of it
@@ -748,10 +796,9 @@ def paged_window_decode_attention(
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_size", "n_steps", "scale", "interpret", "rest_at", "with_lse",
-    "head_tiles"))
+    "block_size", "scale", "interpret", "rest_at", "with_lse", "head_tiles"))
 def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
-                     *, block_size, n_steps, scale, interpret, rest_at=None,
+                     *, block_size, scale, interpret, rest_at=None,
                      with_lse=False, head_tiles=1):
     """The kernel's call.  A jitted function of its own with the layer as a
     prefetched scalar, so that the layers of a program that call it at the
@@ -762,14 +809,13 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
 
     b, q_tiles, q_rows, _ = qt.shape
     tiles = v_pool.shape[-1] // LANES
-    step_rows = PAGED_ROWS * PAGED_STRETCHES
+    stretch = PAGED_STRETCH
 
     def blk(n):
-        return pl.BlockSpec((1, n, q_rows, LANES),
-                            lambda s, c, *_: (s, 0, 0, 0))
+        return pl.BlockSpec((1, n, q_rows, LANES), lambda s, *_: (s, 0, 0, 0))
 
     whole = [] if sink is None else [pl.BlockSpec(
-        sink.shape, lambda s, c, *_: (0, 0, 0))]
+        sink.shape, lambda s, *_: (0, 0, 0))]
     out_specs = blk(tiles)
     out_shape = jax.ShapeDtypeStruct((b, tiles, q_rows, LANES), qt.dtype)
     if with_lse:
@@ -778,24 +824,26 @@ def _paged_attn_call(tables, lens, lo, layer, qt, k_pool, v_pool, sink=None,
         out_specs = [out_specs] * 2
     return pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, block_size=block_size, n_steps=n_steps,
-            scale=scale, rest_at=rest_at, with_lse=with_lse,
-            head_tiles=head_tiles),
+            _paged_decode_kernel, block_size=block_size, scale=scale,
+            rest_at=rest_at, with_lse=with_lse, head_tiles=head_tiles),
         name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(b, n_steps),
+            num_scalar_prefetch=4, grid=(b,),
             in_specs=[blk(q_tiles), pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY), *whole],
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((step_rows, k_pool.shape[-1]), k_pool.dtype),
-                pltpu.VMEM((step_rows, v_pool.shape[-1]), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, step_rows // block_size)),
+                *(pltpu.VMEM((stretch, pool.shape[-1]), pool.dtype)
+                  for pool in (k_pool, v_pool, k_pool, v_pool)),
+                pltpu.SemaphoreType.DMA((2, 2, stretch // PAGED_ROWS)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((tiles, q_rows, PAGED_ROWS), jnp.float32),
                 pltpu.VMEM((tiles, q_rows, LANES), jnp.float32),
             ]),
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables, lens, lo, layer, qt, k_pool, v_pool,
       *(() if sink is None else (sink,)))

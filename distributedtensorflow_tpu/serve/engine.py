@@ -128,7 +128,8 @@ import numpy as np
 
 from ..obs import registry as obs_registry
 from ..obs import tracing as obs_tracing
-from ..ops.attention import PAGED_LATENT_STRETCH, select_walk
+from ..ops.attention import (PAGED_LATENT_STRETCH, PAGED_STRETCH,
+                             select_walk)
 from ..utils.metrics import json_sanitize
 from . import draft as spec_draft
 from . import sampling
@@ -559,6 +560,22 @@ class Engine:
             self.kv.latent_layers * self.kv.max_slots
             * -(-self.kv.max_context // PAGED_LATENT_STRETCH)
             if self.programs.decode_attention == "paged_latent_attn" else 0)
+        #: the same pair of the ``paged_attn`` kernel's walks, summed over
+        #: the paged groups x their layers: a slot's walk in a group is the
+        #: trips from the stretch of its first attended row to its last
+        #: (``PagedKVCache.span_attended``; an idle slot attends a group of
+        #: token rows' one scratch row and no summary row), the capacity the
+        #: most trips the slots' walks can take (a table row's stretches; a
+        #: window group's ``ceil((window + stretch - 1) / stretch)``); counted
+        #: only where every group's decode attends through ``paged_attn``
+        self._step_paged_walk = None
+        self._paged_capacity = sum(
+            len(self.kv.layers[name]) * self.kv.max_slots * -(-min(
+                g.block_tables.shape[1] * g.block_size,
+                getattr(g, "window", np.inf) + PAGED_STRETCH - 1)
+                // PAGED_STRETCH)
+            for name, g in self.kv.paged.items()
+        ) if self.programs.decode_attention == "paged_attn" else 0
         #: the current step's {group: K/V rows its decode iteration
         #: attended}; counted only over several paged groups
         self._step_rows_read: dict[str, int] = {}
@@ -923,7 +940,7 @@ class Engine:
         self._step_evicted = 0
         self._step_sampled = (0, 0)
         self._step_latent = [0, 0, 0, 0]
-        self._step_walk = None
+        self._step_walk = self._step_paged_walk = None
         self._step_rows_read = {}
         self._step_chunk_summaries = None
         self._step_scan = 0
@@ -1234,6 +1251,9 @@ class Engine:
         if occupancy:
             for name, read in self._step_rows_read.items():
                 fields[f"{name}_rows_read"] = read
+            if self._step_paged_walk:
+                fields["paged_stretches_walked"] = self._step_paged_walk
+                fields["paged_stretches_capacity"] = self._paged_capacity
         recycled = self.kv.blocks_recycled
         fields["kv_blocks_freed"] = recycled - self._blocks_recycled0
         self._blocks_recycled0 = recycled
@@ -1565,6 +1585,15 @@ class Engine:
                 idle = self.kv.max_slots - len(slots)
                 self._step_walk = self.kv.latent_layers * (idle + int(
                     (-(-lens // PAGED_LATENT_STRETCH)).sum()))
+        if self._paged_capacity:
+            # an idle slot's query sits at 0
+            at = np.zeros(self.kv.max_slots, np.int64)
+            at[slots] = self.kv.seq_lens[slots] - 1
+            self._step_paged_walk = sum(
+                len(self.kv.layers[name]) * int(
+                    (-(-end // PAGED_STRETCH) - first // PAGED_STRETCH).sum())
+                for name, g in self.kv.paged.items()
+                for first, end in [g.span_attended(at)])
         if self._count_rows:
             positions = self.kv.seq_lens[slots] - 1    # the queries'
             for name, g in self.kv.paged.items():

@@ -469,6 +469,12 @@ class PagedKVCache:
         seen = getattr(self.rows, "visible_rows", None)
         return positions + 1 if seen is None else seen(positions)
 
+    def span_attended(self, positions):
+        """``(first, end)``: the rows ``[first, end)`` of a slot's table row
+        a query at each of ``positions`` attends."""
+        end = self.rows_attended(positions)
+        return np.zeros_like(end), end
+
     def reservation(self, tokens: int) -> int:
         """Blocks admission grants a slot of ``tokens`` positions."""
         return self.blocks_for(tokens)
@@ -869,6 +875,10 @@ class WindowKVGroup(PagedKVCache):
         if self.tumbling:
             return positions % self.window + 1
         return np.minimum(positions + 1, self.window)
+
+    def span_attended(self, positions):
+        end = positions + 1
+        return end - self.rows_attended(positions), end
 
 
 class StateGroup:

@@ -191,3 +191,109 @@ def check_kv_chunk_kernel(monkeypatch):
                 got[i], dense.reshape(heads, dv), atol=tol, rtol=0)
 
     return check
+
+
+@pytest.fixture()
+def check_paged_walk():
+    """``check(lens=, cols=, heads=, kv_heads=, d=, ...)``: one query a slot
+    through the kernel ``paged_attn``
+    (``ops.attention.paged_window_decode_attention``, interpreted) against
+    each slot's dense sum over the rows ``[lo, len)`` it attends and, where the
+    plain formulation takes the options, against ``impl="xla"``.  ``lens``
+    holds None for the table's whole capacity; ``lo`` (a slot's first row,
+    the tumbling ring's way) or ``window`` (the last ``window`` rows) says
+    where a walk starts; ``shared`` pairs of slots (a, b), b taking a's table
+    row.  Tables of ``cols`` columns are scattered; every block no slot
+    attends — the rest of the pool, and the scratch block that unmapped and
+    freed columns name — holds NaN and inf.  The slots are padded to
+    ``slots`` with slots that attend nothing and the pool holds ``nb`` blocks
+    whatever the case needs, so that the cases of a test share one lowering
+    of the interpreted kernel (11-15 s each).  Returns
+    ``(out, lse)``."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributedtensorflow_tpu.ops import attention
+
+    def check(*, lens, cols, heads, kv_heads, d, dv=None, window=None,
+              lo=None, sink=False, with_lse=False, shared=(), bs=16,
+              interpret=True, tol=2e-5, seed=0, slots=8, nb=448):
+        dv = dv or d
+        rng = np.random.default_rng(seed + len(lens))
+        pad = [0] * (slots - len(lens))
+        lens = np.asarray([cols * bs if n is None else n for n in lens] + pad)
+        lo = None if lo is None else list(lo) + pad
+        first = (np.asarray(lo) if lo is not None else np.zeros_like(lens)
+                 if window is None else np.maximum(lens - window, 0))
+        held = [range(a // bs, -(-n // bs)) for a, n in zip(first, lens)]
+        assert sum(len(r) for r in held) <= nb
+        k = rng.standard_normal((2, (nb + 1) * bs, kv_heads, d))
+        v = rng.standard_normal((2, (nb + 1) * bs, kv_heads, dv))
+        tables = np.full((len(lens), cols), nb, np.int32)
+        perm, o = rng.permutation(nb), 0
+        for i, r in enumerate(held):
+            tables[i, r.start:r.stop] = perm[o:o + len(r)]
+            o += len(r)
+        for a, b in shared:
+            tables[b] = tables[a]
+        poison = np.ones(nb + 1, bool)
+        for i, r in enumerate(held):
+            poison[tables[i, r.start:r.stop]] = False
+        bad = np.where(np.arange(d) % 2, np.nan, np.inf)
+        k[:, np.repeat(poison, bs)] = bad
+        v[:, np.repeat(poison, bs)] = bad[:dv]
+        q = rng.standard_normal((len(lens), heads, d))
+        bias = rng.standard_normal(heads) + 2.0 if sink else None
+        pools = (jax.vmap(attention.lay_heads)(jnp.asarray(k, jnp.float32)),
+                 jnp.asarray(v.reshape(2, -1, kv_heads * dv), jnp.float32))
+        args = (jnp.asarray(q, jnp.float32), *pools, jnp.asarray(tables),
+                jnp.asarray(lens, jnp.int32))
+        kw = dict(layer=1, block_size=bs, window=window,
+                  sink=None if bias is None else jnp.asarray(
+                      bias, jnp.float32))
+        assert attention.paged_decode_formulation(
+            heads, kv_heads, d, bs, "pallas", dv) == "paged_attn"
+        got = attention.paged_window_decode_attention(
+            *args, impl="pallas", interpret=interpret, with_lse=with_lse,
+            lo=None if lo is None else jnp.asarray(lo, jnp.int32), **kw)
+        got, lse = (np.asarray(x) for x in got) if with_lse else (
+            np.asarray(got), None)
+        assert got.shape == (len(lens), heads, dv)
+        assert not np.isnan(got).any()
+        g = heads // kv_heads
+        for i, (a, n) in enumerate(zip(first, lens)):
+            if n <= a:
+                # a walk over no key: zeros, the denominator's log under
+                # every real one
+                np.testing.assert_array_equal(got[i], 0.0)
+                assert lse is None or (lse[i] < attention.NEG_INF).all()
+                continue
+            at = np.arange(a, n)
+            at = tables[i, at // bs] * bs + at % bs
+            sc = np.einsum("hgd,khd->hgk", q[i].reshape(kv_heads, g, d),
+                           k[1, at]) * d ** -0.5
+            if bias is not None:
+                sc = np.concatenate(
+                    [sc, bias.reshape(kv_heads, g, 1)], axis=-1)
+            m = sc.max(-1, keepdims=True)
+            p = np.exp(sc - m)
+            total = p.sum(-1, keepdims=True)
+            dense = np.einsum("hgk,khd->hgd", (p / total)[..., :len(at)],
+                              v[1, at])
+            np.testing.assert_allclose(
+                got[i], dense.reshape(heads, dv), atol=tol, rtol=0)
+            if lse is not None:
+                np.testing.assert_allclose(
+                    lse[i], (m + np.log(total)).reshape(heads), atol=tol,
+                    rtol=0)
+        if lo is None and not with_lse:
+            # the gather reads every column: give it finite rows
+            clean = [jnp.nan_to_num(p, nan=0.0, posinf=0.0) for p in pools]
+            live = lens > 0
+            want = np.asarray(attention.paged_window_decode_attention(
+                args[0], *clean, *args[3:], impl="xla", **kw))
+            np.testing.assert_allclose(got[live], want[live], atol=tol,
+                                       rtol=0)
+        return got, lse
+
+    return check

@@ -313,6 +313,41 @@ def test_engine_serves_requests_of_mixed_lengths(f32_model):
     assert any("chunk_summary_rows_read" in r for r in rows)
 
 
+def test_step_log_counts_the_two_walks_of_a_decode_step(f32_model,
+                                                         monkeypatch):
+    """``paged_stretches_walked`` / ``_capacity`` where the decode program
+    attends through ``paged_attn`` (said to here: the tiny heads of 16 take
+    the plain gather; stretches of 8 rows so that windows of 16 span
+    several): a layer's ring walk runs from the stretch of the open window's
+    first row to the query's, its summary walk over the closed windows'
+    rows; an idle slot walks its ring's one scratch row and no summary; the
+    capacity is the most trips the slots' walks can take."""
+    from distributedtensorflow_tpu.serve import engine, model
+
+    cfg, params = f32_model
+    monkeypatch.setattr(engine, "PAGED_STRETCH", 8)
+    monkeypatch.setattr(model.Programs, "decode_attention",
+                        property(lambda self: "paged_attn"))
+    prompt, n_new = _prompt(24, 21, cfg), 30
+    eng, _ = _serve(cfg, params, [(prompt, n_new)])
+    w, per, layers, idle = cfg.window_size, cfg.chunk_size, 3, 2
+    decodes = [r for r in eng.step_records() if r["occupancy"]]
+    assert len(decodes) == n_new - 1
+    for i, r in enumerate(decodes):
+        pos = len(prompt) + i
+        ring = -(-(pos + 1) // 8) - pos // w * w // 8
+        summaries = -(-(pos // w * (w // per)) // 8)
+        assert r["paged_stretches_walked"] == layers * (
+            ring + summaries + idle), (i, r)
+    # 3 slots x (a ring's window + a stretch - 1 = 23 rows: 3 stretches;
+    # 128 positions' 32 summary rows: 4)
+    assert {r["paged_stretches_capacity"] for r in decodes} == {
+        layers * 3 * (3 + 4)}
+    monkeypatch.undo()
+    eng, _ = _serve(cfg, params, [(prompt, 3)])
+    assert not any("paged_stretches_walked" in r for r in eng.step_records())
+
+
 # -- the cache ----------------------------------------------------------------
 
 def test_cache_groups_advance_at_two_rates():
@@ -488,6 +523,28 @@ def test_decode_kernel_matches_the_plain_formulation(closed):
                                     **kw)
     np.testing.assert_allclose(np.asarray(kernel, np.float32),
                                np.asarray(plain, np.float32), atol=2e-2)
+
+
+#: (tokens a slot holds, its open window's first row): the ring's walk
+RING_WALKS = {
+    "window_just_opened": ([2048 + 1, 1, 0], [2048, 0, 0]),
+    "three_trips_from_a_window's_start": (
+        [2048 + 1300, 3 * 2048 + 513, 2048], [2048, 3 * 2048, 0]),
+    "windows_of_other_sizes": ([1328 + 600, 384 + 383], [1328, 384]),
+}
+
+
+@pytest.mark.parametrize("lens,lo", list(RING_WALKS.values()),
+                         ids=list(RING_WALKS))
+def test_ring_walk_starts_at_the_open_window(lens, lo, check_paged_walk):
+    """``paged_attn`` as the ring's walk calls it, interpreted, at heads of
+    128 with rows of their own: ``lo=`` names the open window's first row
+    (every earlier column of the table names the scratch block, which holds
+    NaN), ``with_lse=`` returns the log of the denominator that
+    ``merge_softmax_parts`` needs, and a walk over nothing (an idle slot's
+    summary walk) leaves it under ``NEG_INF``."""
+    check_paged_walk(lens=lens, lo=lo, cols=4 * 2048 // 16, heads=4,
+                     kv_heads=4, d=128, with_lse=True, slots=4, nb=256)
 
 
 @pytest.mark.parametrize("closed,start,chunk", [(0, 0, 256), (1, 0, 256),

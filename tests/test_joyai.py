@@ -675,6 +675,7 @@ def test_step_log_carries_the_family_counters(f32_model):
     assert all(r["moe_pairs"] == 8 == r["moe_experts_hit"] for r in decodes)
     # the plain gather walks nothing
     assert not any("latent_stretches_walked" in r for r in decodes)
+    assert not any("paged_stretches_walked" in r for r in decodes)
     # iteration i attends the prompt, the tokens before it and its own
     assert [r["latent_rows_read"] for r in decodes] == [
         cfg.num_layers * (40 + i + 1) for i in range(len(decodes))]

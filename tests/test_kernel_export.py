@@ -117,6 +117,22 @@ def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2,
                 sds((slots, columns), jnp.int32), sds((slots,), jnp.int32))
 
 
+def _paged_eva(columns, blocks, lo):
+    # the evabyte serving shapes: 28 slots, 32 heads of 128 each with rows of
+    # its own (a row of 8 KB a pool: the kernel's four buffers are 16 MB),
+    # the log of the denominator as a second output; the ring's walk starts
+    # where the caller says
+    def fn(q, k_pool, v_pool, tables, lens, *lo):
+        return paged_window_decode_attention(
+            q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
+            window=2048 if lo else None, impl="pallas", interpret=False,
+            lo=lo[0] if lo else None, with_lse=True)
+    pool = sds((8, blocks * 16, 4096), BF16)
+    return fn, (sds((28, 32, 128), BF16), pool, pool,
+                sds((28, columns), jnp.int32), sds((28,), jnp.int32),
+                *((sds((28,), jnp.int32),) if lo else ()))
+
+
 def _paged_wide(window, kv_heads, slots=32, heads=64, columns=4224):
     # the mimo serving shapes: 32 slots, 64 query heads on 4 (full) or 8
     # (window) K/V heads, keys 192 wide over values 128, a table of 4,224
@@ -401,6 +417,10 @@ FAMILIES = {
     # of 4,224 columns (contexts to 67,584: 811 KB of page tables in SMEM)
     "paged_attn_d256": _paged(None, slots=48, heads=16, d=256, columns=4224,
                               width=512),
+    # evabyte_6_5b's two walks: the ring (a table of 2,048 columns, contexts
+    # to 32,768) from the open window's start, the summary pool's 128 columns
+    "paged_attn_eva_ring": _paged_eva(2048, 3612, True),
+    "paged_attn_eva_summary": _paged_eva(128, 1200, False),
     # ... a prefill chunk of 2,048 queries: 4 query heads a grid step
     "kv_chunk_attn_d256": _kv_chunk(2048, 16, 2, 256, 256, None, 4224,
                                     73728),

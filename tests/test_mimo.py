@@ -264,6 +264,20 @@ def test_decode_kernel_takes_wide_keys_and_the_sink(h_kv, with_sink, window,
     np.testing.assert_allclose(kernel, want, atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("h_kv,window,sink", [
+    (4, None, False), (4, None, True), (8, 128, True), (8, 700, False)],
+    ids=["full", "full-sink", "window-sink", "window-of-two-trips"])
+def test_decode_walk_of_several_trips_takes_wide_keys(h_kv, window, sink,
+                                                      check_paged_walk):
+    """The walk past its first stretch at keys a tile and a half wide (a
+    head's scores two products, the remainder tile at ``rest_at`` in each of
+    the kernel's two buffers): slots of over three trips, of one row and of
+    nothing, the window layers' early blocks freed."""
+    check_paged_walk(lens=[3 * 512 + 70, 1, 0, 2 * 512, 640], cols=112,
+                     heads=64, kv_heads=h_kv, d=192, dv=128, window=window,
+                     sink=sink, slots=5, nb=256)
+
+
 @pytest.mark.parametrize("window", [None, 128])
 def test_chunk_loop_takes_wide_keys_and_the_sink(window):
     """The prefill chunk's plain loop over the same pools: 40 queries from

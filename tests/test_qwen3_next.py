@@ -658,6 +658,17 @@ def test_paged_attn_takes_a_head_of_256(sink):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
+@pytest.mark.parametrize("sink", [False, True])
+def test_paged_attn_walks_several_trips_at_a_head_of_256(sink,
+                                                         check_paged_walk):
+    """The walk past its first stretch at the served head shape (two lane
+    tiles a head in each of the kernel's two buffers): slots of over three
+    trips, of one row and of nothing beside a table far wider than they
+    use (the older case above stays inside two trips)."""
+    check_paged_walk(lens=[3 * 512 + 70, 1, 0, 2 * 512, 640], cols=264,
+                     heads=16, kv_heads=2, d=256, sink=sink, slots=5, nb=256)
+
+
 @pytest.mark.parametrize("start", [0, 300, 608])
 def test_chunk_kernel_takes_a_head_of_256(start, check_kv_chunk_kernel):
     check_kv_chunk_kernel(heads=16, kv_heads=2, d=256, dv=256, window=None,

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from distributedtensorflow_tpu.models import GPTLM, generate, gpt_tiny
+from distributedtensorflow_tpu.ops import attention
 from distributedtensorflow_tpu.ops.attention import KVRows
 from distributedtensorflow_tpu.serve import (
     BlockAllocator,
@@ -522,10 +523,22 @@ def test_engine_kernel_decode_matches_plain_decode_at_heads_of_64():
         _drain(eng, reqs)
         assert [r.status for r in reqs] == ["ok", "ok"]
         served[impl] = ([r.tokens for r in reqs],
-                        eng.state()["decode_attention"])
+                        eng.state()["decode_attention"],
+                        [r for r in eng.step_records() if r["occupancy"]])
     assert served["pallas"][0] == served["xla"][0]
     assert served["pallas"][1] == "paged_attn"
     assert served["xla"][1] == "plain"
+    # the walk's census, where the kernel walks: a slot's trips of a
+    # stretch's rows over the layers (both requests' contexts cross a
+    # stretch's end on the way), beside the most trips two slots' tables of
+    # 640 rows can take
+    stretch = attention.PAGED_STRETCH
+    assert [r["paged_stretches_walked"] for r in served["pallas"][2]] == [
+        cfg.num_layers * sum(-(-(n + i + 1) // stretch) for n in (120, 504))
+        for i in range(15)]
+    assert {r["paged_stretches_capacity"] for r in served["pallas"][2]} == {
+        cfg.num_layers * 2 * -(-640 // stretch)}
+    assert not any("paged_stretches_walked" in r for r in served["xla"][2])
     # a block size that does not divide the kernel's 128 rows: the silent
     # fallback is named
     assert _engine(dataclasses.replace(cfg, attn_impl="pallas"), params,
@@ -1163,3 +1176,101 @@ def test_run_report_prefix_section(served_model, tmp_path):
     assert "prefix cache: hit rate" in text
     assert "tokens/iteration" in text
     assert report["parse_errors"] == 0
+
+
+# ------------------------------- the decode kernel's walk (``paged_attn``)
+
+#: rows a trip of the kernel's walk holds, and of a walk of four trips
+STRETCH = attention.PAGED_STRETCH
+WALK = 4 * STRETCH
+#: table columns a slot: 10 trips (a table its long slots fill over half of)
+#: and 40 (a table far wider than any slot uses)
+WALK_COLS = {"table_of_10_trips": 10 * STRETCH // 16,
+             "table_of_40_trips": 40 * STRETCH // 16}
+#: rows a slot attends; None is the table's whole capacity
+WALK_RAGGED = {
+    "nothing": [0],
+    "one_row": [1],
+    "a_row_short_of_a_stretch": [STRETCH - 1],
+    "a_stretch": [STRETCH],
+    "a_row_into_the_next": [STRETCH + 1],
+    "a_row_into_the_fifth": [WALK + 1],
+    "capacity": [None],
+    # the first stretch of the slot after an empty one is started by the
+    # empty slot's step, not under a last trip; the walk ends on empties
+    "mixed": [2 * WALK + 5, 0, WALK + 200, 0, 0, 17, None, 0],
+    "empty_first": [0, 0, WALK + 1, 3],
+}
+#: GPT-2's heads of 64, two a lane tile
+WALK_HEADS = dict(heads=4, kv_heads=4, d=64)
+
+
+@pytest.mark.parametrize("cols", list(WALK_COLS.values()), ids=list(WALK_COLS))
+@pytest.mark.parametrize("lens", list(WALK_RAGGED.values()),
+                         ids=list(WALK_RAGGED))
+def test_paged_walk_over_ragged_slots(lens, cols, check_paged_walk):
+    """The walk, interpreted, against each slot's dense sum and the plain
+    gather: a slot's trips are counted from its length, the next slot's first
+    stretch is started under this slot's last one, and a slot that attends
+    nothing returns zeros."""
+    check_paged_walk(lens=lens, cols=cols, **WALK_HEADS)
+
+
+#: where a walk starts (``window``: the last rows of ``lens``; ``lo``: the
+#: rows from there, a tumbling ring's way) beside the kernel's stretches
+WALK_STARTS = {
+    "window_wider_than_any_slot": dict(lens=[1, 300, 700], window=1024),
+    "lo_inside_a_stretch": dict(lens=[700, 450, 90], window=300),
+    "lo_at_a_stretch's_first_row": dict(lens=[WALK + 300, 812], window=300),
+    "lo_past_the_first_stretch": dict(lens=[3 * WALK - 36, 0, 2 * WALK + 1],
+                                      window=300),
+    "lo_in_the_first_part's_last_block": dict(lens=[127 + 200], window=200),
+    "a_window_of_several_trips": dict(lens=[4 * WALK + 77, 5 * WALK],
+                                    window=2 * WALK + 100),
+    "ring_just_opened": dict(lens=[2048 + 1, 1], lo=[2048, 0]),
+    "ring_of_several_trips": dict(lens=[2048 + 1300, 4096 + 513, 0],
+                                lo=[2048, 4096, 0]),
+    "ring_from_inside_a_stretch": dict(lens=[1328 + 600], lo=[1328]),
+    "ring_with_nothing_to_attend": dict(lens=[1024, 77], lo=[1024, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_STARTS.values()),
+                         ids=list(WALK_STARTS))
+def test_paged_walk_starts_at_the_first_attended_row(case, check_paged_walk):
+    """A window layer's walk and a tumbling ring's start in the stretch that
+    holds their first row, whose earlier blocks are freed (their columns name
+    the scratch block, which holds NaN): nothing before it is read, the
+    rows of its block before it are masked."""
+    check_paged_walk(cols=WALK_COLS["table_of_40_trips"], with_lse=True,
+                     **case, **WALK_HEADS)
+
+
+def test_paged_walk_over_slots_that_share_blocks(check_paged_walk):
+    """Two slots whose tables are one shared prefix: a shorter length over
+    the same blocks reads its own rows, whatever slot walked before it."""
+    check_paged_walk(lens=[WALK + 40, 300, WALK + 40, WALK - 7],
+                     cols=WALK_COLS["table_of_10_trips"],
+                     shared=[(0, 2), (0, 3)], **WALK_HEADS)
+
+
+@pytest.mark.parametrize("case", [
+    dict(lens=WALK_RAGGED["mixed"]),
+    dict(lens=[3 * WALK - 36, 0, 2 * WALK + 1, 40], window=300),
+], ids=["ragged", "window"])
+def test_paged_walk_waits_for_every_copy_it_starts(case, check_paged_walk):
+    """Under the TPU interpreter a copy lands when it is waited for, memory
+    starts as NaN and races are looked for: the walk's result is the plain
+    interpreter's, bit for bit (which finishes a copy at its start and cannot
+    see a missing wait)."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    kw = dict(cols=WALK_COLS["table_of_40_trips"], **case, **WALK_HEADS)
+    got, _ = check_paged_walk(**kw, interpret=pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True,
+        uninitialized_memory="nan"))
+    races = interpret_pallas_call.races
+    assert races is None or not races.races_found
+    plain, _ = check_paged_walk(**kw)
+    np.testing.assert_array_equal(got, plain)
