@@ -22,7 +22,12 @@ as a reference, fatal for request serving.  This engine is what serves:
   whole prefill), and queued requests' time-to-first-token overlaps with
   in-flight decode.  A request's first token is sampled in the iteration
   its last chunk completes (TTFT stops there).  ``prefill_budget=None``
-  = unbudgeted (all pending chunks run before each decode step);
+  = unbudgeted (all pending chunks run before each decode step).  Under
+  a budget the chunks of iteration n + 1 are launched in iteration n,
+  behind its decode step and before its tokens are fetched: the device
+  has the chunk to run while the host fetches, commits, logs and admits,
+  and iteration n + 1 collects it (a first token, the counts) where it
+  would have launched — the same programs in the same order;
 - **paged KV with prefix caching** (``serve.kv_cache``): admission
   reserves only the request's worst-case footprint (prompt + max_new),
   not ``max_seq`` — and with ``prefix_cache=True``, whole token-aligned
@@ -310,6 +315,41 @@ class GenRequest:
         )
 
 
+@dataclasses.dataclass
+class _PrefillLaunch:
+    """One spend of the prefill budget: the chunks it launched and what
+    they add to the step record of the iteration whose budget they are.
+    ``ahead`` says where the launch ran: in its own iteration's
+    ``engine.prefill``, or under the decode step of the iteration before
+    (a leaf of that iteration's ``engine.decode``), to be collected by
+    its own."""
+
+    ahead: bool = False
+    chunks: int = 0
+    #: the chunks' real tokens, and the (query, key) pairs they attend in a
+    #: layer that keeps every row
+    tokens: int = 0
+    pairs: int = 0
+    #: (a cache with latent rows) the rows the chunks' queries walk, and
+    #: the positions of a query's scores the selection of its chunks past
+    #: ``kv.index_topk`` walked (the kernel's longest walk x latent layers)
+    context: int = 0
+    select_walked: int = 0
+    #: (a cache with chunk summaries) the summary rows the chunks attended
+    summaries: int = 0
+    #: the budget ran out with fillers still pending (the record's
+    #: ``budget_stall``): decided where the launch is accounted
+    stalled: bool = False
+    #: ``(request, its last chunk's logits)`` of the prompts that ended
+    #: among the chunks and wait for their first token (``ahead`` only: a
+    #: launch in its own iteration samples it where the last chunk went)
+    finished: list = dataclasses.field(default_factory=list)
+
+
+#: the launch of an iteration that ran no prefill chunk
+_NO_PREFILL = _PrefillLaunch()
+
+
 class Engine:
     """Continuous-batching scheduler over the compiled serving
     programs (``serve.model``).  See the module docstring for the loop
@@ -415,13 +455,10 @@ class Engine:
         self._routed = None
         self._blocks_recycled0 = 0
         #: the group of chunk summaries (rows that stand for several tokens
-        #: each), or None; its counters at the last step record, and the
-        #: summary rows this iteration's prefill chunks attended (None: no
-        #: chunk ran)
+        #: each), or None, and its counters at the last step record
         self._summaries = next(
             (g for g in self.kv.paged.values() if g.tokens_per_row > 1), None)
         self._closed0 = (0, 0)
-        self._step_chunk_summaries = None
         self.fused_sampling = bool(fused_sampling)
         self.speculate = speculate
         self.spec_ngram = int(spec_ngram)
@@ -473,6 +510,9 @@ class Engine:
         self.occupancy_max = 0
         self.prefill_iters = 0   # iterations that ran >= 1 prefill chunk
         self.prefill_chunks = 0  # chunks run across all iterations
+        #: of those chunks, the ones launched under the decode step of the
+        #: iteration before theirs (the per-step ``prefill_prelaunched``)
+        self.prefill_prelaunched = 0
         #: iterations where the prefill budget ran out with fillers still
         #: pending (the per-step ``budget_stall`` flag, accumulated).
         self.prefill_budget_stalls = 0
@@ -537,17 +577,13 @@ class Engine:
         self._step_evicted = 0     # requests finished in the current step
         #: the current step's (device_sampled, logits_fetched)
         self._step_sampled = (0, 0)
-        #: the current step's [context_tokens, latent_rows_read,
-        #: index_rows_scored, select_positions_walked]: the rows a prefill
-        #: chunk's queries walk, summed over the step's chunks, the latent
-        #: rows its decode iteration read (attended tokens x latent layers:
-        #: where an indexer selects them, ``kv.index_topk`` a slot a layer
-        #: at most), the index keys it scored to select them (every cached
-        #: token x latent layers) and the positions of a query's scores the
-        #: selection of its chunks past ``kv.index_topk`` walked (the
-        #: kernel's longest walk x latent layers); counted only where a
-        #: group stores latent rows
-        self._step_latent = [0, 0, 0, 0]
+        #: the current step's (latent_rows_read, index_rows_scored): the
+        #: latent rows its decode iteration read (attended tokens x latent
+        #: layers: where an indexer selects them, ``kv.index_topk`` a slot a
+        #: layer at most) and the index keys it scored to select them (every
+        #: cached token x latent layers); counted only where a group stores
+        #: latent rows
+        self._step_latent = (0, 0)
         #: the stretches the latent decode kernel walked in the current
         #: step, and the stretches the slots' table rows can hold, both x
         #: latent layers: a slot's walk is ``ceil(rows it attends /
@@ -579,18 +615,15 @@ class Engine:
         #: the current step's {group: K/V rows its decode iteration
         #: attended}; counted only over several paged groups
         self._step_rows_read: dict[str, int] = {}
-        #: the current step's tokens through the recurrence of a state
-        #: group: the real tokens of its prefill chunks and one a decoding
-        #: slot; counted only where a group keeps a state a slot
-        self._step_scan = 0
-        #: the real tokens of the current step's prefill chunks, and the
-        #: (query, key) pairs they attend in a layer that keeps every row
-        self._step_chunk_tokens = self._step_chunk_pairs = 0
+        #: the prefill launch the current step accounts for (its budget's:
+        #: the chunks of the step record), and the one launched under the
+        #: current step's decode step for the next iteration to collect
+        self._step_prefill = _NO_PREFILL
+        self._pending: _PrefillLaunch | None = None
         #: ``obs.capture.CaptureEngine`` (or None): the engine loop opens
         #: and closes its profiler windows by iteration, so a capture
         #: armed through ``POST /profilez?steps=N`` holds N iterations.
         self.capture = capture
-        self._prefill_stalled = False
         # prefix_lookups/hits/cached_tokens live on the PagedKVCache (the
         # admission path that owns the success-only counting rule) — one
         # source of truth, surfaced via kv.stats(); only the engine-level
@@ -900,6 +933,13 @@ class Engine:
             self._dev_tables_version = self.kv.tables_version
         return self._dev_tables
 
+    def _seq_lens_dev(self):
+        """The slots' resident-token counts for a decode launch.  A copy:
+        the chunks launched behind the step advance their slot's count
+        (``note_written``) while the step may not have started, and on
+        the CPU jnp.asarray can alias the numpy buffer."""
+        return jnp.asarray(self.kv.seq_lens.copy())
+
     def _padded_prompt_len(self, prompt_len: int) -> int:
         """Prompt length rounded up to whole prefill chunks — the extent
         the prefill program actually writes K/V through (pad positions
@@ -926,7 +966,10 @@ class Engine:
         """One scheduler iteration: admit → budgeted prefill → decode →
         evict.  Public so tests can drive the engine synchronously;
         returns True when any work happened.  Every iteration that did
-        work leaves one step-log record (ring + steps.jsonl)."""
+        work leaves one step-log record (ring + steps.jsonl).  Its prefill
+        is the launch the iteration before made ahead for it
+        (:meth:`_to_fetch`), collected here, or — nothing pending: no
+        decode step ran before it, or no budget — launched in line."""
         if not (self._queue or self._filling
                 or any(r is not None for r in self._slots)):
             # nothing queued, filling or decoding: no iteration to name
@@ -939,12 +982,9 @@ class Engine:
         accepted0 = self.counters["spec_accepted"]
         self._step_evicted = 0
         self._step_sampled = (0, 0)
-        self._step_latent = [0, 0, 0, 0]
+        self._step_latent = (0, 0)
         self._step_walk = self._step_paged_walk = None
         self._step_rows_read = {}
-        self._step_chunk_summaries = None
-        self._step_scan = 0
-        self._step_chunk_tokens = self._step_chunk_pairs = 0
         # The iteration is one span tree (mirrored into any open profiler
         # trace) whose leaves tile it: a leaf begins where the one before
         # it ended (`obs.tracing.tiled`).  The step record's walls are its
@@ -958,11 +998,15 @@ class Engine:
             if tid != self._tid:
                 self._adopt_thread(tid, root.t0)
             admitted = self._admit_from_queue()
-            chunks = 0
-            if self._filling:
-                chunks = self._run_prefill_budget()
+            launch, self._pending = self._pending, None
+            if launch is not None:
+                # its budget was spent where it was launched
+                self._collect_prefill(launch)
+            elif self._filling:
+                launch = self._launch_prefill()
             else:
-                self._prefill_stalled = False
+                launch = _NO_PREFILL
+            self._step_prefill = launch
             occupancy = sum(
                 r is not None and r._prefill_done for r in self._slots
             )
@@ -970,9 +1014,11 @@ class Engine:
                 # closes the leaf that held the census (admit, or the
                 # last of engine.prefill) and engine.prefill with it
                 tiles.to("engine.decode", "engine.decode.dispatch")
+                prefill = root.children[-1]
                 self._run_decode_step(
-                    root.children[-1].dur_s if chunks else 0.0)
-            did = bool(admitted or chunks or occupancy)
+                    prefill.dur_s if prefill.name == "engine.prefill"
+                    else 0.0)
+            did = bool(admitted or launch.chunks or occupancy)
             if did:
                 s_log = tiles.to("engine.log")
                 cpu_now = time.thread_time()
@@ -987,7 +1033,7 @@ class Engine:
                     walls["compiled"] = compiled
                     self._note_compiled(compile_s, compiled, now)
                 self._log_step(
-                    now, walls, admitted, chunks, occupancy,
+                    now, walls, admitted, occupancy,
                     self.counters["decode_tokens"] - tokens0,
                     self.counters["spec_drafted"] - drafted0,
                     self.counters["spec_accepted"] - accepted0,
@@ -1056,6 +1102,10 @@ class Engine:
         rounding), and a record's ``log_prev_s + between_s + wait_s +
         step_s`` is the wall from the previous record's ``engine.log`` to
         this one's: the records tile the engine thread's life.
+        ``prelaunch_s`` is the wall of the ``engine.prefill_chunk`` leaves
+        inside ``engine.decode``, between ``.dispatch`` and ``.fetch``: the
+        next iteration's chunks, launched under this one's decode step
+        (their counts are the next record's).
         ``offcpu_s`` is that wall less the leaves the thread blocks in by
         design (``engine.wait``, ``.fetch``, ``engine.first_token``), less
         the thread's CPU seconds outside them: time it had work and did
@@ -1063,7 +1113,7 @@ class Engine:
         call)."""
         step_s = s_log.t0 - root.t0
         admit_s = prefill_s = decode_s = first_token_s = 0.0
-        dispatch_s = fetch_s = commit_s = named = 0.0
+        dispatch_s = prelaunch_s = fetch_s = commit_s = named = 0.0
         for phase in root.children:     # engine.log is still open
             name = phase.name
             if name == "engine.admit":
@@ -1076,10 +1126,11 @@ class Engine:
                         first_token_s += leaf.dur_s
             else:
                 decode_s = phase.dur_s
-                dispatch, fetch, commit = phase.children
+                dispatch, *ahead, fetch, commit = phase.children
                 dispatch_s, fetch_s = dispatch.dur_s, fetch.dur_s
                 commit_s = commit.dur_s
-                named += dispatch_s + fetch_s + commit_s
+                prelaunch_s = sum(leaf.dur_s for leaf in ahead)
+                named += dispatch_s + prelaunch_s + fetch_s + commit_s
         wait_s, log_prev_s = self._wait_s, self._log_prev_s
         wall = s_log.t0 - self._mark_wall
         cpu = cpu_now - self._mark_cpu - self._cpu_blocked
@@ -1090,6 +1141,7 @@ class Engine:
             "decode_s": round(decode_s, 6),
             "step_s": round(step_s, 6),
             "dispatch_s": round(dispatch_s, 6),
+            "prelaunch_s": round(prelaunch_s, 6),
             "fetch_s": round(fetch_s, 6),
             "commit_s": round(commit_s, 6),
             "first_token_s": round(first_token_s, 6),
@@ -1138,11 +1190,13 @@ class Engine:
         recent.append(wall_s)
 
     def _log_step(self, now: float, walls: dict[str, float],
-                  admitted: list[GenRequest], chunks: int, occupancy: int,
+                  admitted: list[GenRequest], occupancy: int,
                   tokens: int, drafted: int, accepted: int,
                   blocks_billed: float) -> None:
         """One structured record for the iteration that just ran: phase
-        mix, occupancy, per-phase token deltas, the lines the stream
+        mix, occupancy, per-phase token deltas, the prefill chunks of its
+        budget (``self._step_prefill``: ``prefill_prelaunched`` of them
+        were launched under the decode step before), the lines the stream
         threads wrote since the previous record, and ``walls``, the
         seconds :meth:`_iteration_walls` read off the iteration's span
         tree, rounded (host wall and the engine thread's CPU, all of
@@ -1150,10 +1204,11 @@ class Engine:
         spans gives).
         ``blocks_billed`` is the pool's refcount-weighted block census at
         ``now``; admissions are additionally broken down by tenant."""
+        prefill = self._step_prefill
         phases = []
         if admitted:
             phases.append("admit")
-        if chunks:
+        if prefill.chunks:
             phases.append("prefill")
         if occupancy:
             phases.append("decode")
@@ -1168,14 +1223,15 @@ class Engine:
             "phase": "+".join(phases) or "idle",
             "occupancy": occupancy,
             "active_slots": sum(r is not None for r in self._slots),
-            "filling_slots": len(self._filling),
+            "filling_slots": self._filling_slots(),
             "queue_depth": len(self._queue),
             "admitted": len(admitted),
             "evicted": self._step_evicted,
-            "prefill_chunks": chunks,
-            "chunk_tokens": self._step_chunk_tokens,
-            "chunk_pairs": self._step_chunk_pairs,
-            "budget_stall": int(self._prefill_stalled),
+            "prefill_chunks": prefill.chunks,
+            "prefill_prelaunched": prefill.chunks if prefill.ahead else 0,
+            "chunk_tokens": prefill.tokens,
+            "chunk_pairs": prefill.pairs,
+            "budget_stall": int(prefill.stalled),
             "tokens_committed": tokens,
             "spec_drafted": drafted,
             "spec_accepted": accepted,
@@ -1228,6 +1284,7 @@ class Engine:
         slots holding live state and the tokens that went through the
         recurrence."""
         fields = {}
+        prefill = self._step_prefill
         if occupancy and self._routed is not None:
             pairs, hit, load, *groups = (
                 int(v) for v in np.asarray(self._routed))
@@ -1236,11 +1293,11 @@ class Engine:
             if groups:      # group-limited routing only
                 fields["moe_groups_hit"] = groups[0]
         if self.kv.latent_layers:
-            context, read, scored, walked = self._step_latent
-            if context:
-                fields["context_tokens"] = context
-            if walked:
-                fields["select_positions_walked"] = walked
+            read, scored = self._step_latent
+            if prefill.context:
+                fields["context_tokens"] = prefill.context
+            if prefill.select_walked:
+                fields["select_positions_walked"] = prefill.select_walked
             if occupancy:
                 fields["latent_rows_read"] = read
                 if self.kv.index_topk:
@@ -1266,13 +1323,14 @@ class Engine:
             fields["summary_rows_written"] = closed[0] - self._closed0[0]
             fields["windows_closed"] = closed[1] - self._closed0[1]
             self._closed0 = closed
-            if self._step_chunk_summaries is not None:
-                fields["chunk_summary_rows_read"] = self._step_chunk_summaries
+            if prefill.chunks:
+                fields["chunk_summary_rows_read"] = prefill.summaries
         for name, g in self.kv.paged.items():
             fields[f"kv_blocks_used_{name}"] = g.allocator.used_blocks
         if self.kv.state is not None:
             fields["state_slots_used"] = int(self.kv.state.live.sum())
-            fields["scan_tokens"] = self._step_scan
+            # the real tokens of the chunks and one a decoding slot
+            fields["scan_tokens"] = prefill.tokens + occupancy
         return fields
 
     def step_records(self, n: int | None = None) -> list[dict]:
@@ -1373,8 +1431,14 @@ class Engine:
         self._update_kv_metrics()
         return admitted
 
-    def _run_prefill_budget(self) -> int:
-        """At most ``prefill_budget`` tokens of prefill chunks this
+    def _filling_slots(self) -> int:
+        """Requests admitted and still without a first token: those with
+        chunks to run, and those whose last chunk was launched ahead."""
+        pending = self._pending
+        return len(self._filling) + (len(pending.finished) if pending else 0)
+
+    def _launch_prefill(self, ahead: bool = False) -> _PrefillLaunch:
+        """At most ``prefill_budget`` tokens of prefill chunks for one
         iteration, round-robin in budget-bounded BURSTS across the
         admitted-but-unfilled set: the head request runs consecutive
         chunks (its first token waits for its last chunk, so a budget
@@ -1385,37 +1449,71 @@ class Engine:
         prompt can therefore neither starve decode (the per-iteration
         bound) nor monopolize prefill across iterations (the rotation).
         Always makes progress: at least one chunk runs when any request
-        is filling, even with a budget below the chunk width.  Returns
-        the chunk count.  Called only while a request is filling."""
+        is filling, even with a budget below the chunk width.  Called
+        only while a request is filling.
+
+        In its own iteration (``engine.prefill``) a prompt's first token is
+        sampled where its last chunk went, and the launch is accounted
+        before it is returned.  ``ahead`` — under the decode step of the
+        iteration before, whose tokens the host has not fetched — nothing
+        here waits for the device: the chunks belong to slots that are not
+        decoding, the head of ``_filling`` is the head the next iteration
+        would pop (admission only appends), and the pools chain through
+        the programs' donated arguments, so the device runs the chunks
+        after that decode step as it would have.  The prompts that ended
+        wait in ``finished`` for :meth:`_collect_prefill`."""
         budget = self.prefill_budget
         spent = 0
-        chunks = 0
+        launch = _PrefillLaunch(ahead=ahead)
         while self._filling and (budget is None or spent < budget):
             req = self._filling.popleft()
             done = False
             while True:
-                last_logits = self._run_prefill_chunk(req)
+                last_logits = self._run_prefill_chunk(req, launch)
                 spent += self.prefill_chunk
-                chunks += 1
                 if req._fill_next >= req._fill_pad:
-                    self._finish_prefill(req, last_logits)
+                    if ahead:
+                        launch.finished.append((req, last_logits))
+                    else:
+                        self._finish_prefill(req, last_logits)
                     done = True
                     break
                 if budget is not None and spent >= budget:
                     break
             if not done:
                 self._filling.append(req)
+        if not ahead:
+            self._account_prefill(launch)
+        return launch
+
+    def _collect_prefill(self, launch: _PrefillLaunch) -> None:
+        """The iteration a launch was made ahead for is here: the first
+        token of each prompt whose last chunk was among its chunks (the
+        wait is for a chunk that has been running since before the last
+        fetch), and the launch's place in the engine's counts."""
+        for req, last_logits in launch.finished:
+            self._finish_prefill(req, last_logits)
+        launch.finished = []
+        self._account_prefill(launch)
+
+    def _account_prefill(self, launch: _PrefillLaunch) -> None:
+        """``launch`` is this iteration's budget, spent: the engine's
+        totals and the budget stall, in the iteration whose record holds
+        its chunks."""
         self.prefill_iters += 1
-        self.prefill_chunks += chunks
+        self.prefill_chunks += launch.chunks
+        if launch.ahead:
+            self.prefill_prelaunched += launch.chunks
         # budget stall: the token budget ran out with fillers still
         # pending — those requests eat >= 1 more iteration of TTFT (the
-        # step-log field that explains a prefill-bound tail)
-        self._prefill_stalled = bool(self._filling)
-        if self._prefill_stalled:
+        # step-log field that explains a prefill-bound tail).  For a
+        # launch made ahead this is asked after the admission it could
+        # not see, as a launch in this iteration would have asked it.
+        launch.stalled = bool(self._filling)
+        if launch.stalled:
             self.prefill_budget_stalls += 1
-        return chunks
 
-    def _run_prefill_chunk(self, req: GenRequest):
+    def _run_prefill_chunk(self, req: GenRequest, launch: _PrefillLaunch):
         """One fixed-width prefill chunk for one request: it reads the
         slot's earlier chunks through its page-table rows (and, over a state
         group, continues the state they left in the slot's own row), so
@@ -1424,9 +1522,11 @@ class Engine:
         c = self.prefill_chunk
         start = req._fill_next
         # the leaf before this one (another chunk, a first token,
-        # engine.admit) ends here, with whatever of the budget loop
-        # followed it
-        chunk = self._tiles.to("engine.prefill", "engine.prefill_chunk")
+        # engine.admit; ahead: engine.decode.dispatch) ends here, with
+        # whatever of the budget loop followed it
+        chunk = self._tiles.to(
+            "engine.decode" if launch.ahead else "engine.prefill",
+            "engine.prefill_chunk")
         # Since this request's attribution frontier (the start of its
         # last chunk, or its admission): its own chunk's wall, read off
         # that chunk's span, was prefill compute; the rest was spent on
@@ -1440,6 +1540,9 @@ class Engine:
         req._t_attr = t_chunk
         req._s_chunk = chunk
         real = self._chunk_real_tokens(len(req.prompt), start)
+        # (ahead, a window group maps and lets go of blocks of this slot's
+        # own ring after the decode step in flight was handed its tables:
+        # safe by the device's order, the chunk runs behind that step)
         self.kv.prepare_write(slot, start + c)
         last_logits, pools = self.programs.prefill(
             self.params, self.kv.pools(),
@@ -1449,19 +1552,17 @@ class Engine:
             real,
         )
         self.kv.set_pools(pools)
+        launch.chunks += 1
         if self.kv.latent_layers:
-            self._step_latent[0] += start + c
+            launch.context += start + c
             if (self.kv.index_topk and start + c > self.kv.index_topk
                     and self.programs.chunk_attention.startswith("masked_")):
-                self._step_latent[3] += self.kv.latent_layers * select_walk(
+                launch.select_walked += self.kv.latent_layers * select_walk(
                     start + c, self.kv.max_context)
-        self._step_chunk_tokens += real
-        self._step_chunk_pairs += real * start + real * (real + 1) // 2
-        if self.kv.state is not None:
-            self._step_scan += real
+        launch.tokens += real
+        launch.pairs += real * start + real * (real + 1) // 2
         if self._summaries is not None:
-            self._step_chunk_summaries = (self._step_chunk_summaries or 0) \
-                + int(self._summaries.rows_attended(start))
+            launch.summaries += int(self._summaries.rows_attended(start))
         req._fill_next = start + c
         self.kv.note_written(
             slot, max(min(start + c, len(req.prompt)),
@@ -1522,6 +1623,9 @@ class Engine:
         are three leaves that tile ``engine.decode``:
         ``engine.decode.dispatch``, open since ``engine.decode`` began
         (the batch's slots, CoW guard, slot meta, table upload, launch),
+        then — only under a budget with a prompt filling — the
+        ``engine.prefill_chunk`` leaves of the next iteration's prefill
+        (:meth:`_to_fetch`),
         ``engine.decode.fetch`` (the wait
         for the device, and what the host needs of the result: a token a
         slot, and the logits only if a live request samples) and
@@ -1554,7 +1658,7 @@ class Engine:
         logits, greedy, pools, self._routed = self.programs.decode(
             self.params, self.kv.pools(),
             jnp.asarray(self._last_tokens), self._tables_dev(),
-            jnp.asarray(self.kv.seq_lens), self._dev_active,
+            self._seq_lens_dev(), self._dev_active,
         )
         self.kv.set_pools(pools)
         # what the engine sees in its input decides what it fetches: the
@@ -1573,14 +1677,13 @@ class Engine:
         self.kv.note_written(slots, self.kv.seq_lens[slots] + 1)
         if self.kv.latent_layers:
             lens = self.kv.seq_lens[slots]
-            scored = self.kv.latent_layers * int(lens.sum())
-            self._step_latent[1] = scored
+            read = scored = self.kv.latent_layers * int(lens.sum())
             if self.kv.index_topk:
-                self._step_latent[1] = self.kv.latent_layers * int(
+                read = self.kv.latent_layers * int(
                     np.minimum(lens, self.kv.index_topk).sum())
-                self._step_latent[2] = scored
-                self._m_latent_read.inc(self._step_latent[1])
+                self._m_latent_read.inc(read)
                 self._m_index_scored.inc(scored)
+            self._step_latent = (read, scored)
             if self._walk_capacity:
                 idle = self.kv.max_slots - len(slots)
                 self._step_walk = self.kv.latent_layers * (idle + int(
@@ -1601,8 +1704,6 @@ class Engine:
                     g.rows_attended(positions).sum())
                 self._step_rows_read[name] = read
                 self._m_rows_read[name].inc(read)
-        if self.kv.state is not None:
-            self._step_scan += n_active
         self._commit_tokens(
             decoding, slots, [[t] for t in tokens.tolist()], now,
             decode_dt, prefill_s, spec=False)
@@ -1610,7 +1711,17 @@ class Engine:
     def _to_fetch(self) -> None:
         """``engine.decode.dispatch`` ends and ``.fetch`` begins: the
         engine thread is about to wait for the device, so its CPU clock is
-        read beside the span's wall (``_to_commit`` reads it again)."""
+        read beside the span's wall (``_to_commit`` reads it again).
+
+        Between the two, where a budget bounds an iteration's prefill and
+        a prompt is still filling, the next iteration's prefill is launched
+        behind the decode step (:meth:`_launch_prefill`, ``ahead``): the
+        device has it to run while the host waits for this step's tokens,
+        commits them, logs and admits.  An engine without a budget has no
+        filler left here (every pending chunk ran before the decode step),
+        so it never takes the branch."""
+        if self.prefill_budget is not None and self._filling:
+            self._pending = self._launch_prefill(ahead=True)
         self._tiles.to("engine.decode", "engine.decode.fetch")
         self._cpu_leaf0 = time.thread_time()
 
@@ -1769,7 +1880,7 @@ class Engine:
         packed, next_feed, pools = fn(
             self.params, self.kv.pools(), tokens_in,
             dev_draft_lens, self._tables_dev(),
-            jnp.asarray(self.kv.seq_lens), self._dev_active,
+            self._seq_lens_dev(), self._dev_active,
             self._dev_keys, self._dev_prompt_lens, self._dev_temp,
             self._dev_topk,
         )
@@ -1875,6 +1986,11 @@ class Engine:
             self._slot_meta_dirty = True
         if req in self._filling:  # error paths only; finished fills popped
             self._filling.remove(req)
+        pending = self._pending
+        if pending is not None and pending.finished:
+            # it left between its last chunk's launch and its first token
+            # (whatever takes its slot and blocks is queued behind the chunk)
+            pending.finished = [f for f in pending.finished if f[0] is not req]
         req.status = status
         req.finish_reason = reason if status == "ok" else None
         req.t_done = time.time()
@@ -2063,6 +2179,7 @@ class Engine:
             self._queue.clear()
             self._m_queue.set(0)
         self._filling.clear()  # entries are also in _slots, failed below
+        self._pending = None   # nobody is left to collect it
         doomed += [r for r in self._slots if r is not None]
         for req in doomed:
             req.error = message
@@ -2099,6 +2216,7 @@ class Engine:
             "occupancy_max": self.occupancy_max,
             "prefill_iters": self.prefill_iters,
             "prefill_chunks": self.prefill_chunks,
+            "prefill_prelaunched": self.prefill_prelaunched,
             "prefill_budget_stalls": self.prefill_budget_stalls,
             "steps_total": self._step_id,
             "step_ring_size": self.step_ring_size,
@@ -2206,7 +2324,7 @@ class Engine:
             "step": self.decode_steps,
             "queue_depth": len(self._queue),
             "active_slots": sum(r is not None for r in self._slots),
-            "filling_slots": len(self._filling),
+            "filling_slots": self._filling_slots(),
             "occupancy_max": self.occupancy_max,
             "blocks_free": kv["blocks_free"],
             "blocks_cached": kv["blocks_cached"],
@@ -2222,6 +2340,7 @@ class Engine:
             "cow_copies_total": kv["cow_copies"],
             "prefill_iters": self.prefill_iters,
             "prefill_chunks": self.prefill_chunks,
+            "prefill_prelaunched": self.prefill_prelaunched,
             "prefill_chunk": self.prefill_chunk,
             "prefill_budget": self.prefill_budget or 0,
             "requests_ok_total": self.counters["ok"],

@@ -431,8 +431,8 @@ def test_profilez_captures_engine_iterations(served_model, tmp_path,
 
 # ----------------------------------- the leaves tile the iteration (ISSUE 36)
 
-LEAF_FIELDS = ("dispatch_s", "fetch_s", "commit_s", "first_token_s",
-               "log_prev_s", "between_s", "wait_s", "offcpu_s",
+LEAF_FIELDS = ("dispatch_s", "prelaunch_s", "fetch_s", "commit_s",
+               "first_token_s", "log_prev_s", "between_s", "wait_s", "offcpu_s",
                "commit_cpu_s", "gc_s", "unnamed_s", "stream_lines",
                "stream_lag_max_s", "compile_s")
 
@@ -463,9 +463,11 @@ def _mixed_traffic(eng, cfg, n_requests=6):
 def test_leaves_tile_the_iteration_and_rows_tile_the_thread(
         served_model, tmp_path, fused, budget):
     """Every row of a working iteration carries the leaves; they sum to
-    ``step_s`` (``unnamed_s``), the three of a decode stay under
-    ``decode_s``, and ``log_prev_s + between_s + wait_s + step_s`` of
-    consecutive rows is the wall between their stamps."""
+    ``step_s`` (``unnamed_s``), those of a decode — with, under a budget,
+    the next iteration's chunks launched between dispatch and fetch,
+    ``prelaunch_s`` — tile ``decode_s``, and ``log_prev_s + between_s +
+    wait_s + step_s`` of consecutive rows is the wall between their
+    stamps.  A chunk is counted by the record whose budget it spent."""
     cfg, params, _ = served_model
     eng = _engine(cfg, params, logdir=str(tmp_path), fused_sampling=fused,
                   prefill_budget=budget, max_slots=3)
@@ -474,11 +476,19 @@ def test_leaves_tile_the_iteration_and_rows_tile_the_thread(
     rows = _load_jsonl(os.path.join(tmp_path, "steps.jsonl"))
     assert len(rows) > 12
     assert {"admit+prefill+decode", "decode"} <= {r["phase"] for r in rows}
-    for r in rows:
+    for before, r in zip([{"prelaunch_s": 0.0}] + rows, rows):
         assert set(LEAF_FIELDS) <= set(r), r
         assert r["unnamed_s"] <= 0.02 * r["step_s"] + 1e-5
-        leaves = r["dispatch_s"] + r["fetch_s"] + r["commit_s"]
+        leaves = r["dispatch_s"] + r["prelaunch_s"] + r["fetch_s"] \
+            + r["commit_s"]
         assert leaves <= r["decode_s"] + 1e-5
+        # launched under the decode step of the record before, all of a
+        # record's chunks or none, and never more than a budget's worth
+        assert r["prefill_prelaunched"] in (0, r["prefill_chunks"])
+        assert (r["prefill_prelaunched"] > 0) == (before["prelaunch_s"] > 0)
+        assert r["prefill_chunks"] * 4 <= (budget or 10 ** 6)
+        assert r["chunk_tokens"] <= r["prefill_chunks"] * 4
+        assert (r["chunk_tokens"] > 0) == (r["prefill_chunks"] > 0)
         if r["occupancy"]:
             assert leaves >= r["decode_s"] - 1e-5      # and tile it
             assert 0 < r["commit_cpu_s"] <= r["commit_s"] + 1e-4
@@ -489,6 +499,14 @@ def test_leaves_tile_the_iteration_and_rows_tile_the_thread(
         assert r["first_token_s"] <= r["prefill_s"] + 1e-6
         assert r["wait_s"] == 0 and r["stream_lines"] == 0
     assert sum(r["first_token_s"] > 0 for r in rows) >= 3
+    ahead = sum(r["prefill_prelaunched"] for r in rows)
+    assert ahead == eng.prefill_prelaunched \
+        == eng.state()["prefill_prelaunched"]
+    assert sum(r["prefill_chunks"] for r in rows) == eng.prefill_chunks
+    if budget is None:      # no filler is left where the decode step went
+        assert ahead == 0 == sum(r["prelaunch_s"] for r in rows)
+    else:
+        assert ahead > 0
     # the rows tile the thread's life: row i's account begins where the
     # engine.log of row i-1 began, and `t` is stamped at that place
     tiled = sum(r["log_prev_s"] + r["between_s"] + r["wait_s"] + r["step_s"]
@@ -504,6 +522,8 @@ def test_leaves_tile_the_iteration_and_rows_tile_the_thread(
 
 @pytest.mark.parametrize("field,value,message", [
     ("commit_s", 9.0, "dispatch_s+fetch_s+commit_s"),
+    ("prelaunch_s", 9.0, "dispatch_s+fetch_s+commit_s"),
+    ("prefill_prelaunched", 5, "'prefill_prelaunched'"),
     ("unnamed_s", 0.5, "do not tile"),
     ("offcpu_s", -0.1, "'offcpu_s'"),
     ("gc_s", "x", "'gc_s'"),
@@ -532,7 +552,8 @@ def test_schema_checker_holds_the_leaf_fields(served_model, tmp_path, field,
     with open(path, "w") as f:
         for r in rows:
             f.write(json.dumps({k: v for k, v in r.items()
-                                if k not in LEAF_FIELDS + ("compiled",)})
+                                if k not in LEAF_FIELDS + (
+                                    "compiled", "prefill_prelaunched")})
                     + "\n")
     assert checker.check_steps_file(path)[0] == []
 

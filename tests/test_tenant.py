@@ -144,7 +144,8 @@ STEP_KEYS = {
     "dispatch_s", "evicted", "fetch_s", "filling_slots", "first_token_s",
     "gc_s", "kv_blocks_billed", "kv_blocks_freed", "kv_blocks_used_full",
     "log_prev_s", "logits_fetched", "occupancy", "offcpu_s", "phase",
-    "prefill_chunks", "prefill_s", "queue_depth", "spec_accepted",
+    "prefill_chunks", "prefill_prelaunched", "prefill_s", "prelaunch_s",
+    "queue_depth", "spec_accepted",
     "spec_drafted", "step", "step_s", "stream_lag_max_s", "stream_lines",
     "t", "tokens_committed", "unnamed_s", "wait_s",
 }

@@ -331,10 +331,14 @@ STEP_WALL_FIELDS = (
 )
 #: The iteration's leaves and the engine thread's account (ISSUE 36;
 #: validated where present, so older logs stay green): seconds, all of
-#: them — ``dispatch_s + fetch_s + commit_s <= decode_s`` and the leaves
-#: leave ``unnamed_s <= 2 %`` of ``step_s`` (up to rounding).
+#: them — ``dispatch_s + prelaunch_s + fetch_s + commit_s <= decode_s``
+#: (``prelaunch_s``, ISSUE 60: the next iteration's prefill chunks launched
+#: under this one's decode step, whose count ``prefill_prelaunched`` of the
+#: NEXT record's ``prefill_chunks`` is) and the leaves leave ``unnamed_s <=
+#: 2 %`` of ``step_s`` (up to rounding).
 STEP_LEAF_FIELDS = (
-    "dispatch_s", "fetch_s", "commit_s", "first_token_s", "log_prev_s",
+    "dispatch_s", "prelaunch_s", "fetch_s", "commit_s", "first_token_s",
+    "log_prev_s",
     "between_s", "wait_s", "offcpu_s", "commit_cpu_s", "gc_s", "unnamed_s",
     "stream_lag_max_s", "compile_s",
 )
@@ -403,7 +407,8 @@ SERVE_ROW_COUNTERS = (
     "prefix_hits_total", "prefix_lookups_total",
     "prefix_cached_tokens_total", "prefill_tokens_total",
     "prefix_evictions_total", "cow_copies_total", "blocks_cached",
-    "block_refs", "prefill_iters", "prefill_chunks", "prefill_budget",
+    "block_refs", "prefill_iters", "prefill_chunks", "prefill_prelaunched",
+    "prefill_budget",
     "spec_drafted_total", "spec_accepted_total", "decode_tokens_total",
     "decode_dispatches_total", "host_sample_rounds_total", "speculate",
     "fused_sampling", "tokens_per_step",
@@ -1429,8 +1434,9 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
     and non-negative finite wall fields whose phase split tiles the
     iteration: ``admit_s + prefill_s + decode_s <= step_s`` (up to
     rounding); where present, the leaves and the engine thread's account
-    (:data:`STEP_LEAF_FIELDS`), with ``dispatch_s + fetch_s + commit_s <=
-    decode_s`` and ``unnamed_s`` within 2 % of ``step_s``."""
+    (:data:`STEP_LEAF_FIELDS`), with ``dispatch_s + prelaunch_s + fetch_s +
+    commit_s <= decode_s`` and ``unnamed_s`` within 2 % of ``step_s``, and
+    ``prefill_prelaunched <= prefill_chunks``."""
     errors: list[str] = []
     warnings: list[str] = []
     prev_t: float | None = None
@@ -1490,6 +1496,14 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
             if counts.get("budget_stall", 0) > 1:
                 errors.append(f"line {i}: 'budget_stall' "
                               f"{counts['budget_stall']} is not 0/1")
+            ahead = row.get("prefill_prelaunched")
+            if ahead is not None and (
+                    not _nonneg_int(ahead)
+                    or ahead > counts.get("prefill_chunks", ahead)):
+                errors.append(
+                    f"line {i}: 'prefill_prelaunched' {ahead!r} is not a "
+                    "count of this record's 'prefill_chunks' "
+                    f"{row.get('prefill_chunks')!r}")
             if "spec_drafted" in counts and "spec_accepted" in counts \
                     and counts["spec_accepted"] > counts["spec_drafted"]:
                 errors.append(
@@ -1526,8 +1540,8 @@ def check_steps_file(path: str) -> tuple[list[str], list[str]]:
                     walls[name] = float(v)
             if all(k in walls for k in ("dispatch_s", "fetch_s", "commit_s",
                                         "decode_s")):
-                parts = (walls["dispatch_s"] + walls["fetch_s"]
-                         + walls["commit_s"])
+                parts = (walls["dispatch_s"] + walls.get("prelaunch_s", 0.0)
+                         + walls["fetch_s"] + walls["commit_s"])
                 if parts > walls["decode_s"] + 1e-5:
                     errors.append(
                         f"line {i}: dispatch_s+fetch_s+commit_s "

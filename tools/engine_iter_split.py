@@ -3,7 +3,7 @@
 
     python tools/engine_iter_split.py [ROOT] [--streams] [--profile]
         [--host-only] [--iterations N] [--config NAME] [--slots N]
-        [--tokens N] [--sample N]
+        [--tokens N] [--sample N] [--budget TOKENS --fillers N]
 
 The profiler's Python tracer slows exactly the host code between two
 launches of ``jit_decode`` (PERF.md §5), so the traced ``decode_span_host_ms``
@@ -36,6 +36,16 @@ with a log directory of its own (``steps.jsonl`` is written, as under
 and ``--pairs`` stretches of ``--iterations`` alternate; the row is the
 paired difference of the engine thread's CPU time an iteration (ISSUE 36's
 budget of 30 us is read this way).
+``--budget TOKENS`` gives the engine a prefill budget and ``--fillers N``
+holds N of the requests back, with prompts of ``--filler-prompt`` tokens,
+until the others decode: their chunks then run in the timed iterations, one
+budget each, and since ISSUE 60 under the decode step of the iteration
+before.  The row's ``prefill`` says how many of the timed records' chunks
+were launched that way (``prelaunched_share``) and what share of the
+iterations' wall the launch took (``prelaunch_share_of_step``: the new leaf
+``prelaunch_s`` of ``step_ms`` over ``step_s``); in a tree from before it
+the same command prices the stretch it put under a chunk (``prefill_s`` of
+those records).
 Defaults are the trinity cell's; a tiny one runs on the CPU:
 ``--config afmoe_tiny --slots 4 --prompt 20 --tokens 90 --block 4 --chunk 8
 --context 128 --kv-blocks 0 --kv-window-blocks 0``.  Prints one JSON row
@@ -123,8 +133,16 @@ def main(argv=None) -> int:
     p.add_argument("--kv-window-blocks", type=int, default=11264)
     p.add_argument("--sample", type=int, default=0,
                    help="how many of the requests sample (temperature 0.8)")
+    p.add_argument("--budget", type=int, default=None,
+                   help="the engine's prefill budget in tokens (none)")
+    p.add_argument("--fillers", type=int, default=0,
+                   help="requests that arrive once the others decode")
+    p.add_argument("--filler-prompt", type=int, default=0,
+                   help="their prompt's tokens (default: 8 chunks)")
     args = p.parse_args(argv)
     args.host_only = args.host_only or bool(args.beside)
+    if args.fillers and args.host_only:
+        p.error("--fillers needs the device: not with --host-only/--beside")
     sys.path.insert(0, os.path.abspath(args.root))
 
     import jax
@@ -147,7 +165,8 @@ def main(argv=None) -> int:
         prefill and every program compiled."""
         eng = mod.Engine(
             params, cfg, max_slots=args.slots, block_size=args.block,
-            prefill_chunk=args.chunk, max_context=args.context,
+            prefill_chunk=args.chunk, prefill_budget=args.budget,
+            max_context=args.context,
             num_blocks=args.kv_blocks, window_blocks=args.kv_window_blocks,
             max_queue=512, step_ring=max(args.tokens, args.iterations, 64),
             registry=registry.Registry(),
@@ -175,11 +194,14 @@ def main(argv=None) -> int:
                     return
                 line(payload, *stamp)
 
-        reqs = [eng.submit(
-            rng.integers(0, cfg.vocab_size, args.prompt).tolist(),
-            max_new_tokens=args.tokens, stream=args.streams,
-            temperature=0.8 if i < args.sample else 0.0, seed=i)
-            for i in range(args.slots)]
+        def submit(i, prompt):
+            return eng.submit(
+                rng.integers(0, cfg.vocab_size, prompt).tolist(),
+                max_new_tokens=args.tokens, stream=args.streams,
+                temperature=0.8 if i < args.sample else 0.0, seed=i)
+
+        reqs = [submit(i, args.prompt)
+                for i in range(args.slots - args.fillers)]
         if args.streams and hasattr(eng, "stream_sink"):
             batches = queue.SimpleQueue()
             eng.stream_sink = batches.put
@@ -193,6 +215,9 @@ def main(argv=None) -> int:
             eng.step()
         for _ in range(20):
             eng.step()
+        # the fillers' prompts fill under the timed decode steps
+        reqs += [submit(i, args.filler_prompt or 8 * args.chunk)
+                 for i in range(args.slots - args.fillers, args.slots)]
         if args.host_only:
             # the pools it hands back are the live ones; its token is no
             # EOS and max_new_tokens is out of reach: the batch stays whole
@@ -236,6 +261,8 @@ def main(argv=None) -> int:
     wall = time.perf_counter() - t0
     n = eng.decode_steps - n0
     recs = [r for r in eng.step_records() if r["occupancy"]][-n:]
+    chunks = sum(r["prefill_chunks"] for r in recs)
+    ahead = sum(r.get("prefill_prelaunched", 0) for r in recs)
     print(json.dumps({
         "root": args.root, "streams": args.streams, "profile": args.profile,
         "host_only": args.host_only,
@@ -246,6 +273,15 @@ def main(argv=None) -> int:
         "iter_cpu_ms": 1e3 * cpu[0] / n,    # the engine thread's own
         "step_ms": {k: round(1e3 * statistics.fmean(r[k] for r in recs), 4)
                     for k in recs[-1] if k.endswith("_s")},
+        # the timed records' chunks, those of them launched under the
+        # decode step before, and the launch's share of the iterations
+        "prefill": {
+            "chunks": chunks, "prelaunched": ahead,
+            "prelaunched_share": round(ahead / chunks, 4) if chunks else None,
+            "prelaunch_share_of_step": round(
+                sum(r.get("prelaunch_s", 0.0) for r in recs)
+                / sum(r["step_s"] for r in recs), 4),
+            "filling_iterations": sum(r["filling_slots"] > 0 for r in recs)},
         "stream_lines_per_iter": statistics.fmean(
             r.get("stream_lines", 0) for r in recs),
         "counters": {k: v for k, v in eng.counters.items() if k in (
