@@ -60,6 +60,11 @@ from .qwen3_next import (  # noqa: F401
     qwen3_next_ep4,
     qwen3_next_tiny,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig,
+    ouro_2_6b,
+    ouro_tiny,
+)
 from .lenet import LeNet5  # noqa: F401
 from .resnet import (  # noqa: F401
     CifarResNet,

@@ -451,7 +451,8 @@ class Engine:
         #: the newest decode iteration's expert-routing counters (None
         #: from programs without expert layers): pairs on held experts,
         #: held experts hit (both summed over the expert layers), largest
-        #: load of one expert
+        #: load of one expert; from the programs of a stack that is run
+        #: several times (``programs.passes`` > 1) the passes' exit mass
         self._routed = None
         self._blocks_recycled0 = 0
         #: the group of chunk summaries (rows that stand for several tokens
@@ -1285,7 +1286,13 @@ class Engine:
         recurrence."""
         fields = {}
         prefill = self._step_prefill
-        if occupancy and self._routed is not None:
+        if occupancy and self.programs.passes > 1:
+            # a stack run several times: the decode program's fourth output
+            # is the passes' exit mass, the active slots' mean of p_u
+            fields["ut_steps"] = self.programs.passes
+            for u, mass in enumerate(np.asarray(self._routed).tolist()):
+                fields[f"ut_exit_mass_{u}"] = round(mass, 6)
+        elif occupancy and self._routed is not None:
             pairs, hit, load, *groups = (
                 int(v) for v in np.asarray(self._routed))
             fields.update(moe_pairs=pairs, moe_experts_hit=hit,
@@ -2243,6 +2250,9 @@ class Engine:
             "state_form": self.programs.state_form,
             # bytes the cache stores a token over all layers
             "cache_row_bytes": self.kv.row_bytes,
+            # the layer slots a token keeps rows in: the paged groups'
+            # layers, times the passes of a stack that is run several times
+            "cache_layer_slots": self.kv.layer_slots,
             "kv_groups": self.kv_groups(),
             "spec_acceptance_rate": (
                 self.counters["spec_accepted"] / self.counters["spec_drafted"]

@@ -127,7 +127,9 @@ class OutOfBlocksError(RuntimeError):
 def pool_shape(num_layers: int, num_blocks: int, block_size: int,
                width: int) -> tuple[int, int, int]:
     """Shape of one pool of ``num_blocks`` blocks plus the scratch block:
-    token rows ``width`` wide (module docstring, "Stored form")."""
+    token rows ``width`` wide (module docstring, "Stored form").
+    ``num_layers`` is the group's layer slots: its layers, times the passes
+    of a stack that is run several times (:func:`layer_groups`)."""
     return (num_layers, (num_blocks + 1) * block_size, width)
 
 
@@ -1013,6 +1015,13 @@ class GroupedKVCache:
         return sum(g.row_bytes * len(self.layers[name]) // g.tokens_per_row
                    for name, g in self.paged.items())
 
+    @property
+    def layer_slots(self) -> int:
+        """Layer slots a token keeps rows in, over the paged groups: their
+        layers, each once a pass of a stack that is run several times
+        (:func:`layer_groups`: 192 for Ouro-2.6B's 48 layers x 4 passes)."""
+        return sum(len(self.layers[name]) for name in self.paged)
+
     def check_fits(self, tokens: int) -> None:
         """Raise ``ValueError`` if no pool state could ever hold a request
         of ``tokens`` positions (it would wedge the FIFO head forever)."""
@@ -1130,7 +1139,17 @@ def layer_groups(cfg) -> dict[str, tuple[int, ...]]:
     (``cfg.window_of(layer)``); a group with no layer is left out.  A layer
     whose rows live in several groups (``cfg.groups_of(layer)``, where a
     config has it: ``models.evabyte`` keeps token rows in a ring and chunk
-    summaries in a pool that grows) is listed in each."""
+    summaries in a pool that grows) is listed in each.
+
+    A config that runs its stack of layers several times a token
+    (``cfg.stack_passes``: ``models.ouro``, 4 passes) keeps the rows of every
+    pass — pass ``u`` of a layer attends what *pass u* wrote for the earlier
+    tokens —, so each group lists its layers once a pass, pass-major: a
+    group of ``n`` layers holds ``passes * n`` **layer slots**, pass ``u`` of
+    the layer at index ``i`` in slot ``u * n + i`` (48 layers x 4 passes = 192
+    slots, 1,572,864 B a token at Ouro-2.6B's widths).  Everything that
+    counts a group's layers — the pools' leading dimension, ``row_bytes``,
+    the census, admission — counts these."""
     keeps_state = getattr(cfg, "keeps_state", lambda layer: False)
     groups_of = getattr(cfg, "groups_of", lambda layer: (
         "state" if keeps_state(layer) else
@@ -1139,7 +1158,8 @@ def layer_groups(cfg) -> dict[str, tuple[int, ...]]:
     for i in range(cfg.num_layers):
         for name in groups_of(i):
             kinds[name].append(i)
-    return {name: tuple(ls) for name, ls in kinds.items() if ls}
+    passes = getattr(cfg, "stack_passes", 1)
+    return {name: tuple(ls) * passes for name, ls in kinds.items() if ls}
 
 
 def group_rows(cfg, name: str):

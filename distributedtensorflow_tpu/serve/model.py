@@ -12,11 +12,23 @@ shapes, so a serving process compiles each once.
 A family is a module of layer functions (``models.gpt``, ``models.afmoe``,
 ``models.joyai``, ``models.jamba``, ``models.mimo``, ``models.lfm2``,
 ``models.evabyte``, ``models.ling``, ``models.nemotron_h``,
-``models.qwen3_next``):
+``models.qwen3_next``, ``models.ouro``):
 ``embed(params, ids, cfg)``, ``block(p,
 x, cfg, layer, positions, attend, token_mask=None) -> (x, counters)`` and
 ``head(params, x, cfg)``, over activations ``(T, d)``, plus
 ``init_params(cfg, key)``.  Its
+configuration says how many times its stack of layers is run a token
+(``cfg.stack_passes``, one attribute, 1 where a config does not say:
+``models.ouro`` runs its 48 layers 4 times over the same weights); such a
+family also has ``end_pass(params, x, cfg) -> (x, gate)``, what every pass
+ends with (the final norm, and the exit gate's logit a token), and its
+programs run the passes under ONE device loop (:func:`_through_passes`:
+scope ``ut_loop``, the body — the layers, ``ut_norm``, ``ut_gate`` — traced
+and lowered once, the activations and the donated pools its carry), pass
+``u`` of the layer at index ``l`` of a group writing and attending pool layer
+``u * layers + l``, a traced scalar that the kernels take as they take a
+Python one (``_paged_attn_call`` and ``_kv_chunk_call`` prefetch it,
+``_write_rows`` indexes with it).  The head runs once, after the loop.  Its
 configuration says what a cached row is (``cfg.cache_rows``, an
 ``ops.attention.KVRows``, ``LatentRows`` or ``SparseLatentRows``: the widths
 of the group's pools and the paged formulations over them; ``{group:
@@ -130,7 +142,11 @@ scan|step,gated_norm,out_proj}`` and every layer ``h<i>/moe/shared_gate``
 beside the router, the experts and the shared expert, ``h<i>/eva_attn`` for
 evabyte, whose hook adds
 ``summarise`` and ``summary_write`` beside ``kv_write`` and ``paged_attn``
-(the block's own are ``qkv``, ``rope`` and ``proj``); an expert layer's FFN is
+(the block's own are ``qkv``, ``rope`` and ``proj``), and under ``ut_loop``
+(the device loop over the passes; a profiler's path reads
+``ut_loop/while/body/h<i>/...``) ouro's ``h<i>/{norm_in,attn/{qkv,rope,
+kv_write,paged_attn,proj},norm_attn_out,norm_mlp_in,mlp,norm_mlp_out}`` and,
+once a pass, ``ut_norm`` and ``ut_gate``; an expert layer's FFN is
 ``h<i>/{router,experts}``, a dense one's ``h<i>/mlp``), ``head``, ``sample``,
 and ``cast_params`` wherever a family casts a stored weight at its use.
 Metadata only, so a profiler trace can say which stage a device operation
@@ -145,7 +161,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import (afmoe, evabyte, gpt, jamba, joyai, lfm2, ling, mimo,
-                      nemotron_h, qwen3_next)
+                      nemotron_h, ouro, qwen3_next)
 from ..ops.kda import kda_chunk_scan, kda_step
 from ..ops.ssd import ssd_chunk_scan, ssd_step
 from ..ops.ssm import causal_conv, conv_step, ssm_chunk_scan, ssm_step
@@ -197,6 +213,66 @@ def _write_rows(form, pools: tuple, li: int, at, rows: tuple,
     with jax.named_scope(scope):
         return tuple(pool.at[li, at].set(r.reshape(at.shape[0], -1))
                      for pool, r in zip(pools, form.stored(*rows)))
+
+
+def _passes(cfg) -> int:
+    """How many times ``cfg``'s stack of layers is run a token
+    (``cfg.stack_passes``: ``models.ouro``); 1 where a config does not say."""
+    return getattr(cfg, "stack_passes", 1)
+
+
+def _slots_a_pass(cfg, layers: dict[str, tuple[int, ...]]) -> dict[str, int]:
+    """``{group: the layer slots one pass of the stack fills in its pools}``:
+    a group of a looped config lists its layers once a pass
+    (``serve.kv_cache.layer_groups``), pass-major, so pass ``u`` of the layer
+    at index ``li`` of the group keeps its rows in slot ``u * slots + li``
+    (``u`` is a Python 0 where the stack runs once: the index it had).
+    Refuses, with the reason, the groups no loop is written for."""
+    passes = _passes(cfg)
+    if passes > 1 and ("state" in layers or _two_pool_form(cfg) is not None):
+        raise ValueError(
+            "a stack run several times over a state group or over rows at "
+            "two rates is not implemented: which pass's state a slot keeps "
+            "between tokens is a question no served family answers yet")
+    return {name: len(ls) // passes for name, ls in layers.items()}
+
+
+def _through_passes(family, cfg, params, stack, x, pools, weigh=None):
+    """``(x, pools, mass)``: ``x`` and the donated pools through the
+    config's stack, ``stack(x, pools, u) -> (x, pools)`` one pass of all its
+    layers with the pool layer slots of pass ``u``.
+
+    A config that runs its stack once gets ``stack(x, pools, 0)``, a Python
+    ``0``: the program it had.  One that runs it ``stack_passes`` times gets
+    ONE device loop over the pass (scope ``ut_loop``) whose body — the
+    layers, then ``family.end_pass`` (the final norm and the exit gate:
+    ``ut_norm``, ``ut_gate``) — is traced and lowered once, ``u`` a traced
+    scalar; the activations and the pools are the loop's carry, so the pools
+    stay the donated buffers.  ``mass`` is float32 ``(passes,)``, the exit
+    distribution ``p_u`` (``models.ouro``) weighed by ``weigh`` ((T,), summing
+    to 1), or None where ``weigh`` is or the stack runs once."""
+    passes = _passes(cfg)
+    if passes == 1:
+        return (*stack(x, pools, 0), None)
+    t = x.shape[0]
+
+    def body(u, carry):
+        x, pools, stay, mass = carry
+        x, pools = stack(x, pools, u)
+        x, gate = family.end_pass(params, x, cfg)
+        if weigh is not None:
+            with jax.named_scope("ut_gate"):
+                lam = jax.nn.sigmoid(gate)
+                leave = jnp.where(u == passes - 1, stay, lam * stay)
+                mass = mass.at[u].set((leave * weigh).sum())
+                stay = stay * (1.0 - lam)
+        return x, pools, stay, mass
+
+    with jax.named_scope("ut_loop"):
+        x, pools, _, mass = jax.lax.fori_loop(
+            0, passes, body, (x, pools, jnp.ones((t,), jnp.float32),
+                              jnp.zeros((passes,), jnp.float32)))
+    return x, pools, (None if weigh is None else mass)
 
 
 class _TwoPools:
@@ -410,6 +486,7 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
     the real ones); the slot's state is row ``table_rows["state"][0]`` of the
     group's arrays.  The pools are donated."""
     where, forms = _group_of(layers), _forms_of(cfg, layers)
+    a_pass = _slots_a_pass(cfg, layers)
     two = _two_pool_form(cfg)
     #: the groups whose rows are not one a token: a state, chunk summaries
     by_chunk = {"state"} | ({two.summary_group} if two else set())
@@ -441,26 +518,34 @@ def make_prefill_fn(family, cfg, *, chunk: int, block_size: int,
                 return two.chunk(q, start, pools, table_rows, layer=li,
                                  block_size=bs, impl=cfg.kernel_impl)
         x = family.embed(params, tokens, cfg)
-        for layer in range(cfg.num_layers):
-            name, li = where.get(layer, _NO_GROUP)
 
-            def attend(q, *stored, name=name, li=li, layer=layer, **weights):
-                form = forms[name]
-                pools[name] = _write_rows(form, pools[name], li, rows[name],
-                                          stored)
-                return form.chunk(
-                    q, start, pools[name], table_rows[name], layer=li,
-                    block_size=block_size, window=cfg.window_of(layer),
-                    impl=cfg.kernel_impl, **weights)
+        def stack(x, pools, u):
+            pools = dict(pools)
+            for layer in range(cfg.num_layers):
+                name, li = where.get(layer, _NO_GROUP)
+                li = u * a_pass.get(name, 0) + li
 
-            mixer = attend if name != "state" else _ChunkState(
-                pools, li, table_rows[name][0], start, *valid,
-                cfg.kernel_impl)
-            if two is not None:
-                mixer = _TwoPools(two, pools, layers, layer, rows, read)
-            with jax.named_scope(f"h{layer}"):
-                x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
-                                   positions, mixer, token_mask=real)
+                def attend(q, *stored, name=name, li=li, layer=layer,
+                           **weights):
+                    form = forms[name]
+                    pools[name] = _write_rows(form, pools[name], li,
+                                              rows[name], stored)
+                    return form.chunk(
+                        q, start, pools[name], table_rows[name], layer=li,
+                        block_size=block_size, window=cfg.window_of(layer),
+                        impl=cfg.kernel_impl, **weights)
+
+                mixer = attend if name != "state" else _ChunkState(
+                    pools, li, table_rows[name][0], start, *valid,
+                    cfg.kernel_impl)
+                if two is not None:
+                    mixer = _TwoPools(two, pools, layers, layer, rows, read)
+                with jax.named_scope(f"h{layer}"):
+                    x, _ = family.block(params[f"h{layer}"], x, cfg, layer,
+                                       positions, mixer, token_mask=real)
+            return x, pools
+
+        x, pools, _ = _through_passes(family, cfg, params, stack, x, pools)
         last = jax.lax.dynamic_slice_in_dim(x, last_ix, 1, 0)
         return family.head(params, last, cfg)[0], pools
 
@@ -485,8 +570,12 @@ def make_decode_fn(family, cfg, *, block_size: int,
     ``(3,)``: over the expert layers, the routed (token, choice) pairs that
     landed on held experts (sum), the held experts hit (sum) and the largest
     load of one expert (max) — active slots only; None from a model without
-    expert layers."""
+    expert layers.  From a config whose stack is run several times
+    (``cfg.stack_passes`` > 1) that fourth output is instead float32
+    ``(passes,)``: the passes' exit mass, the mean over the active slots of
+    ``p_u`` (``models.ouro``), which sums to 1."""
     where, forms = _group_of(layers), _forms_of(cfg, layers)
+    passes, a_pass = _passes(cfg), _slots_a_pass(cfg, layers)
     two = _two_pool_form(cfg)
     #: the groups whose rows are not one a token: a state, chunk summaries
     by_chunk = {"state"} | ({two.summary_group} if two else set())
@@ -533,29 +622,42 @@ def make_decode_fn(family, cfg, *, block_size: int,
                                   block_size=bs, impl=cfg.kernel_impl)
         x = family.embed(params, tokens, cfg)
         routed = []
-        for layer in range(cfg.num_layers):
-            name, li = where.get(layer, _NO_GROUP)
 
-            def attend(q, *stored, name=name, li=li, layer=layer, **weights):
-                form = forms[name]
-                pools[name] = _write_rows(form, pools[name], li, rows[name],
-                                          stored)
-                return form.decode(
-                    q, pools[name], tables[name], attend_lens, layer=li,
-                    block_size=bs, window=cfg.window_of(layer),
-                    impl=cfg.kernel_impl, **weights)
+        def stack(x, pools, u):
+            pools = dict(pools)
+            for layer in range(cfg.num_layers):
+                name, li = where.get(layer, _NO_GROUP)
+                li = u * a_pass.get(name, 0) + li
 
-            mixer = attend if name != "state" else _StepState(
-                pools, li, active, cfg.kernel_impl)
-            if two is not None:
-                mixer = _TwoPools(two, pools, layers, layer, rows, read, last)
-            with jax.named_scope(f"h{layer}"):
-                x, counters = family.block(
-                    params[f"h{layer}"], x, cfg, layer, positions, mixer,
-                    token_mask=active)
-            if counters is not None:
-                routed.append(counters)
-        stat = None
+                def attend(q, *stored, name=name, li=li, layer=layer,
+                           **weights):
+                    form = forms[name]
+                    pools[name] = _write_rows(form, pools[name], li,
+                                              rows[name], stored)
+                    return form.decode(
+                        q, pools[name], tables[name], attend_lens, layer=li,
+                        block_size=bs, window=cfg.window_of(layer),
+                        impl=cfg.kernel_impl, **weights)
+
+                mixer = attend if name != "state" else _StepState(
+                    pools, li, active, cfg.kernel_impl)
+                if two is not None:
+                    mixer = _TwoPools(two, pools, layers, layer, rows, read,
+                                      last)
+                with jax.named_scope(f"h{layer}"):
+                    x, counters = family.block(
+                        params[f"h{layer}"], x, cfg, layer, positions, mixer,
+                        token_mask=active)
+                if counters is not None:
+                    routed.append(counters)
+            return x, pools
+
+        weigh = None
+        if passes > 1:      # the exit mass: the active slots' mean
+            live = active.astype(jnp.float32)
+            weigh = live / jnp.maximum(live.sum(), 1.0)
+        x, pools, stat = _through_passes(family, cfg, params, stack, x,
+                                         pools, weigh)
         if routed:
             stat = [sum(c["pairs"] for c in routed),
                     sum(c["experts_hit"] for c in routed),
@@ -607,6 +709,7 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
     ``paged_verify_attention`` masks no window: full layers only.
     """
     where, forms = _group_of(layers), _forms_of(cfg, layers)
+    a_pass = _slots_a_pass(cfg, layers)
     t_width = draft + 1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -637,24 +740,31 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
                     valid_w, blk * bs + positions % bs,
                     pools[name][0].shape[1] - bs).reshape(-1)  # else: scratch
         x = family.embed(params, tokens.reshape(-1), cfg)      # (B * T, d)
-        for layer in range(cfg.num_layers):
-            name, li = where[layer]
 
-            def attend(q, *stored, name=name, li=li, **weights):
-                form = forms[name]
-                pools[name] = _write_rows(form, pools[name], li, rows[name],
-                                          stored)
-                out = form.verify(
-                    q.reshape(b, t_width, *q.shape[1:]), pools[name],
-                    tables[name], attend_lens, layer=li, block_size=bs,
-                    **weights)
-                return out.reshape(b * t_width, *out.shape[2:])
+        def stack(x, pools, u):
+            pools = dict(pools)
+            for layer in range(cfg.num_layers):
+                name, li = where[layer]
+                li = u * a_pass[name] + li
 
-            with jax.named_scope(f"h{layer}"):
-                x, _ = family.block(
-                    params[f"h{layer}"], x, cfg, layer,
-                    positions.reshape(-1), attend,
-                    token_mask=valid_w.reshape(-1))
+                def attend(q, *stored, name=name, li=li, **weights):
+                    form = forms[name]
+                    pools[name] = _write_rows(form, pools[name], li,
+                                              rows[name], stored)
+                    out = form.verify(
+                        q.reshape(b, t_width, *q.shape[1:]), pools[name],
+                        tables[name], attend_lens, layer=li, block_size=bs,
+                        **weights)
+                    return out.reshape(b * t_width, *out.shape[2:])
+
+                with jax.named_scope(f"h{layer}"):
+                    x, _ = family.block(
+                        params[f"h{layer}"], x, cfg, layer,
+                        positions.reshape(-1), attend,
+                        token_mask=valid_w.reshape(-1))
+            return x, pools
+
+        x, pools, _ = _through_passes(family, cfg, params, stack, x, pools)
         logits = family.head(params, x, cfg).reshape(b, t_width, -1)
         # Emitted-token index of each slot's next sample, derived
         # on-device (decode invariant: seq_len = prompt + emitted - 1)
@@ -696,6 +806,7 @@ PROGRAMS = {
     ling.LingConfig: ling,
     nemotron_h.NemotronHConfig: nemotron_h,
     qwen3_next.Qwen3NextConfig: qwen3_next,
+    ouro.OuroConfig: ouro,
 }
 
 #: the families served through the fused and verify programs: those whose
@@ -708,7 +819,9 @@ PROGRAMS = {
 #: such tests and a cell of its own.  Over a state group there is more in
 #: the way than tests: a rejected draft's steps cannot be rolled back out of
 #: a state, which keeps no earlier position (jamba, lfm2, ling, nemotron_h,
-#: qwen3_next).
+#: qwen3_next).  ouro keeps only rows a token, so nothing but the missing
+#: tests is in its way: :func:`make_fused_decode_fn` runs its passes under the
+#: same device loop as the other two programs.
 FUSED = (gpt,)
 
 #: the families a request may be admitted for onto cached prefix blocks: those
@@ -765,7 +878,8 @@ class Programs:
       one slot (:func:`make_prefill_fn`);
     - ``decode(params, pools, tokens (slots,), tables, seq_lens, active) ->
       (logits, greedy, pools, routed)``: one token a slot, and the arg-max of
-      its logits (:func:`make_decode_fn`);
+      its logits (:func:`make_decode_fn`); where ``passes`` > 1 the fourth
+      output is the passes' exit mass;
     - ``fused(draft)``: the sampled (``draft`` = 0) or verify program
       (:func:`make_fused_decode_fn`), or a ``ValueError`` that says it is
       not implemented;
@@ -804,6 +918,14 @@ class Programs:
         self.decode = make_decode_fn(
             family, cfg, block_size=block_size, layers=layers)
         self._fused = False
+
+    @property
+    def passes(self) -> int:
+        """How many times the stack of layers is run a token
+        (``cfg.stack_passes``, 1 where a config does not say): above 1 the
+        programs loop on the device and ``decode``'s fourth output is the
+        passes' exit mass."""
+        return _passes(self.cfg)
 
     @property
     def decode_attention(self) -> str:

@@ -81,6 +81,11 @@ CONFIGS = {
     # experts and a gated shared expert in every layer; random init
     "qwen3_next_tiny": ("qwen3_next_tiny", None),
     "qwen3_next_ep4": ("qwen3_next_ep4", None),
+    # the ouro family (models/ouro.py): a looped stack — the layers run
+    # total_ut_steps times over shared weights, a K/V layer slot a pass a
+    # layer, sandwich norms, an exit gate read after every pass; random init
+    "ouro_tiny": ("ouro_tiny", None),
+    "ouro_2_6b": ("ouro_2_6b", None),
 }
 
 
@@ -347,6 +352,7 @@ def main(argv=None) -> int:
                  chunk_scan=engine.programs.chunk_scan,
                  state_form=engine.programs.state_form,
                  cache_row_bytes=engine.kv.row_bytes,
+                 cache_layer_slots=engine.kv.layer_slots,
                  kv_groups=engine.kv_groups())
     server = ServeServer(engine, args.port, host=args.host).start()
 

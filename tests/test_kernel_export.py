@@ -105,14 +105,14 @@ def _ln(x, g, b):
 
 
 def _paged(window, slots=64, heads=48, d=128, columns=512, layers=2,
-           width=1024):
+           width=1024, blocks=2048):
     # the afmoe serving shapes: 64 slots, 48 query / 8 K/V heads of 128,
     # blocks of 16, a table of 512 columns, one layer group's pool
     def fn(q, k_pool, v_pool, tables, lens):
         return paged_window_decode_attention(
             q, k_pool, v_pool, tables, lens, layer=1, block_size=16,
             window=window, impl="pallas", interpret=False)
-    pool = sds((layers, 2049 * 16, width), BF16)
+    pool = sds((layers, (blocks + 1) * 16, width), BF16)
     return fn, (sds((slots, heads, d), BF16), pool, pool,
                 sds((slots, columns), jnp.int32), sds((slots,), jnp.int32))
 
@@ -424,6 +424,14 @@ FAMILIES = {
     # ... a prefill chunk of 2,048 queries: 4 query heads a grid step
     "kv_chunk_attn_d256": _kv_chunk(2048, 16, 2, 256, 256, None, 4224,
                                     73728),
+    # ouro_2_6b's serving shapes: 16 slots, 16 query = 16 K/V heads of 128
+    # (one query row a tile, rows of 4 KB a pool), a table of 96 columns
+    # (contexts to 1,536) over 192 layer slots of a pool of 320 blocks
+    "paged_attn_mha_192_slots": _paged(None, slots=16, heads=16, d=128,
+                                       columns=96, layers=192, width=2048,
+                                       blocks=320),
+    # ... a prefill chunk of 256 queries, one query head a K/V head
+    "kv_chunk_attn_mha": _kv_chunk(256, 16, 16, 128, 128, None, 96, 320),
 }
 
 

@@ -326,3 +326,46 @@ def test_state_program_keeps_pools_and_state_in_place_on_a_v5e(
             calls = re.findall(r"call @(\w*scan_call\w*)\(", text)
             assert len(calls) == case["scan_calls"], calls
             assert len(set(calls)) == 1, calls
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode",
+                                     "copy_block"])
+def test_looped_program_carries_the_pools_through_its_loop_in_place_on_a_v5e(
+        program, monkeypatch):
+    """The ouro family at its published widths, two layers deep and with a
+    small vocabulary, four passes: 8 layer slots a pool.  The pools are the
+    device loop's carry (``serve.model._through_passes``): the programs copy
+    or convert no layer slot of either outside ``paged_attn`` and hand both
+    back in place, and each kernel is lowered ONCE — two call sites in the
+    loop's one body, the pool layer ``u * 2 + l`` a traced scalar —, not
+    once a pass.  (It is refused the fused programs.)"""
+    import dataclasses
+
+    from distributedtensorflow_tpu.models import ouro_2_6b
+    from distributedtensorflow_tpu.serve import kv_cache, pool_check
+
+    one_chip = NamedSharding(v5e_mesh(1), P())
+    as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(ouro_2_6b(), num_layers=2, vocab_size=1024)
+    programs = pool_check.pool_programs(
+        cfg, max_slots=16, num_blocks=2048, block_size=16, chunk=256, draft=4,
+        sharding=one_chip)
+    assert sorted(programs) == ["copy_block", "decode", "prefill_chunk"]
+    slots, rows, width = kv_cache.pool_shape(
+        len(kv_cache.layer_groups(cfg)["full"]), 2048, 16, 2048)
+    assert slots == 8
+    report = pool_check.check_pool_programs(
+        {program: programs[program]}, layer_elems=rows * width)
+    assert pool_check.failures(report) == []
+    assert report[program]["k_pool"] == \
+        "bf16[8,32784,2048]{2,1,0:T(8,128)(2,1)}"
+    if program != "copy_block":
+        fn, args = programs[program]
+        text = fn.lower(*args).as_text()
+        kernel, call = {"decode": ("paged_attn", "_paged_attn_call"),
+                        "prefill_chunk": ("kv_chunk_attn", "_kv_chunk_call")
+                        }[program]
+        assert text.count(f'kernel_name = "{kernel}"') == 1
+        calls = re.findall(rf"call @(\w*{call}\w*)\(", text)
+        assert len(calls) == 2 and len(set(calls)) == 1, calls
+        assert text.count("stablehlo.while") >= 1
