@@ -252,6 +252,8 @@ def paged_decode_attention(
     scores = jnp.where(valid[:, None, :], scores, NEG_INF)
     weights = (jax.nn.softmax(scores, axis=-1) if sink is None
                else sink_softmax(scores, sink))
+    # a slot that attends nothing returns zeros, as the kernel's does
+    weights = jnp.where(valid[:, None, :], weights, 0.0)
     if h != h_kv:
         wg = weights.astype(q.dtype).reshape(b, h_kv, g, cap)
         out = jnp.einsum(
@@ -314,6 +316,8 @@ def paged_verify_attention(
     scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
     weights = (jax.nn.softmax(scores, axis=-1) if sink is None
                else sink_softmax(scores, sink))
+    # a query that attends nothing (an inactive slot's first) returns zeros
+    weights = jnp.where(valid[:, None, :, :], weights, 0.0)
     if h != h_kv:
         wg = weights.astype(q.dtype).reshape(b, h_kv, g, t, cap)
         out = jnp.einsum(
@@ -471,7 +475,10 @@ def _paged_decode_kernel(tables_ref, lens_ref, lo_ref, layer_ref, q_ref, k_hbm,
     before it waits for its own, so they run under its arithmetic.  The
     buffers, the semaphores and ``walked`` (trips of the slots before: the
     buffer's parity) outlive a grid step; every copy started is waited for
-    by the trip that folds it.
+    by the trip that folds it.  A walk over no row (a length of 0: a slot
+    nobody holds, as the decode programs hand it over) takes no trip: it
+    starts the next slot's first stretch and closes an empty running softmax,
+    which is zeros.
 
     ``head_tiles``: lane tiles a K/V head (2 at a head of 256): a head's scores
     are the sum of its tiles' products, its running maximum and sum are kept
@@ -704,8 +711,10 @@ def paged_window_decode_attention(
     lanes ``[i * D, (i + 1) * D)`` of its rows and zeros in the others, so
     the product over all 128 lanes is that head's scores, and its output is
     the same lanes of the same rows (the other lanes, its weights on the
-    neighbour's values, are dropped).  A slot that attends nothing returns
-    zeros.  Needs ``D`` of 64, 128 or 256, ``H // Hkv <= 32`` (a tile's
+    neighbour's values, are dropped).  A slot that attends nothing — every
+    inactive slot of a decode program (``serve.model``) — returns zeros, in
+    both formulations, and costs the kernel a grid step without a trip.
+    Needs ``D`` of 64, 128 or 256, ``H // Hkv <= 32`` (a tile's
     query heads are its rows: 24 at 20 on 1), a block size that divides 128
     and a pool row of whole tiles (:func:`paged_decode_formulation`); other
     shapes, and ``impl="xla"``, take the plain formulation.
@@ -1299,8 +1308,10 @@ def _plain_latent_decode(q, pool, block_tables, attend_lens, *, layer,
                      width)[layer, block_tables].reshape(b, -1, width)
     s = jnp.einsum("bhw,bkw->bhk", q, x,
                    preferred_element_type=jnp.float32) * scale
-    valid = jnp.arange(x.shape[1])[None, :] < attend_lens[:, None]
-    w = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    valid = (jnp.arange(x.shape[1])[None, :] < attend_lens[:, None])[:, None]
+    # a slot that attends nothing returns zeros, as the kernel's does
+    w = jnp.where(valid, jax.nn.softmax(jnp.where(valid, s, NEG_INF),
+                                        axis=-1), 0.0)
     return jnp.einsum("bhk,bkr->bhr", w.astype(q.dtype), x[..., :rank],
                       preferred_element_type=jnp.float32)
 
@@ -1314,7 +1325,10 @@ def _paged_latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, pool_hbm,
     next slot's — before it waits for its own, so they run under its
     arithmetic.  The buffers, the semaphores and ``walked`` (stretches of the
     slots before: the buffer's parity) outlive a grid step; every copy
-    started is waited for by the trip that folds it."""
+    started is waited for by the trip that folds it.  A slot that holds
+    nothing (a length of 0: a slot nobody holds, as the decode programs hand
+    it over) takes no trip: it starts the next slot's first stretch and
+    closes an empty running softmax, which is zeros."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1483,7 +1497,9 @@ def paged_latent_decode_attention(
     rows of the left operand, so 32 heads fill the MXU's rows where
     grouped-query attention brings 8 — and the values are the first ``rank``
     lanes of the same rows, a 128-lane tile at a time.  A slot that attends
-    nothing returns zeros.  Other shapes and ``impl="xla"`` take the plain
+    nothing — every inactive slot of a decode program — returns zeros, in
+    both formulations, and costs the kernel a grid step without a trip.
+    Other shapes and ``impl="xla"`` take the plain
     gather.  Scopes ``absorb``, ``paged_attn``, ``v_up``."""
     rank, width = w_uk.shape[0], pool.shape[-1]
     q = _latent_queries(q_nope, q_rope, w_uk, width)
@@ -1619,7 +1635,8 @@ def _index_scores_call(lens, q, w, keys, *, interpret):
                     # a stretch past the slot's rows is not fetched: the
                     # block index stays at the last live one
                     pl.BlockSpec((1, stretch, dim), lambda b, c, lens: (
-                        b, jnp.minimum(c, (lens[b] - 1) // stretch), 0))],
+                        b, jnp.minimum(
+                            c, jnp.maximum(lens[b] - 1, 0) // stretch), 0))],
                 out_specs=pl.BlockSpec((1, 1, stretch),
                                        lambda b, c, *_: (b, 0, c))),
             out_shape=jax.ShapeDtypeStruct((n, 1, s), jnp.float32),
@@ -1857,7 +1874,8 @@ def _plain_sparse_latent(q, x, counts, *, rank, scale):
                    preferred_element_type=jnp.float32) * scale
     ok = (jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
           < counts[:, None])[:, None]
-    p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+    # a query with no real row returns zeros, as the kernel's does
+    p = jnp.where(ok, jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1), 0.0)
     return jnp.einsum("nhk,nkr->nhr", p.astype(q.dtype), x[..., :rank],
                       preferred_element_type=jnp.float32)
 
@@ -2693,9 +2711,13 @@ def eva_decode_attention(
     — each of which also returns the log of its denominator, merged
     (:func:`merge_softmax_parts`); the plain formulation gathers the open
     window's columns of the ring's table and every column of the summary
-    table, and takes one softmax over both."""
+    table, and takes one softmax over both.  A slot of length 0 attends
+    nothing, in either pool: zeros."""
     b, h, d = q.shape
-    pos = attend_lens.astype(jnp.int32) - 1
+    lens = attend_lens.astype(jnp.int32)
+    # a slot that holds nothing (length 0) has no query position: its span
+    # is position 0's, of which it attends no row
+    pos = jnp.maximum(lens - 1, 0)
     first, seen = _eva_span(pos, window, chunk_size)
     if paged_decode_formulation(h, h, d, block_size, impl) == "paged_attn":
         walk = functools.partial(
@@ -2704,8 +2726,7 @@ def eva_decode_attention(
             with_lse=True)
         # ``window`` bounds the ring's walk: ``lo`` says where it starts
         return merge_softmax_parts(
-            [walk(*ring_pools, ring_tables, pos + 1, lo=first,
-                  window=window),
+            [walk(*ring_pools, ring_tables, lens, lo=first, window=window),
              walk(*summary_pools, summary_tables, seen)], q.dtype)
     blocks = _window_blocks(ring_tables, first, window, block_size)
     kpos = first[:, None] + jnp.arange(blocks.shape[1] * block_size)
@@ -2713,10 +2734,12 @@ def eva_decode_attention(
                   for p in ring_pools)
     summ = tuple(_block_rows(p, layer, summary_tables, block_size, d)
                  for p in summary_pools)
-    return softmax_over_parts(q[:, None], [
-        (*local, (kpos <= pos[:, None])[:, None]),
+    out = softmax_over_parts(q[:, None], [
+        (*local, (kpos < lens[:, None])[:, None]),
         (*summ, (jnp.arange(summ[0].shape[1])[None] < seen[:, None])[:, None]),
     ])[:, 0]
+    # a slot that attends nothing returns zeros, as the kernel's walks do
+    return jnp.where((lens > 0)[:, None, None], out, 0)
 
 
 def eva_chunk_attention(

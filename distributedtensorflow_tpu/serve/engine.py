@@ -587,21 +587,22 @@ class Engine:
         self._step_latent = (0, 0)
         #: the stretches the latent decode kernel walked in the current
         #: step, and the stretches the slots' table rows can hold, both x
-        #: latent layers: a slot's walk is ``ceil(rows it attends /
-        #: PAGED_LATENT_STRETCH)`` trips (an idle slot attends the scratch
-        #: block's one row), so the pair says what share of the table held
-        #: rows; counted only where the decode program attends through
-        #: ``paged_latent_attn`` (a capacity of 0 elsewhere)
+        #: latent layers: a decoding slot's walk is ``ceil(rows it attends /
+        #: PAGED_LATENT_STRETCH)`` trips (an idle slot attends nothing, a
+        #: grid step of no trip, and is not counted), so the pair says
+        #: what share of the table held rows; counted only where the decode
+        #: program attends through ``paged_latent_attn`` (a capacity of 0
+        #: elsewhere)
         self._step_walk = None
         self._walk_capacity = (
             self.kv.latent_layers * self.kv.max_slots
             * -(-self.kv.max_context // PAGED_LATENT_STRETCH)
             if self.programs.decode_attention == "paged_latent_attn" else 0)
         #: the same pair of the ``paged_attn`` kernel's walks, summed over
-        #: the paged groups x their layers: a slot's walk in a group is the
-        #: trips from the stretch of its first attended row to its last
-        #: (``PagedKVCache.span_attended``; an idle slot attends a group of
-        #: token rows' one scratch row and no summary row), the capacity the
+        #: the paged groups x their layers: a decoding slot's walk in a group
+        #: is the trips from the stretch of its first attended row to its
+        #: last (``PagedKVCache.span_attended``; an idle slot attends no row
+        #: of any group and is not counted), the capacity the
         #: most trips the slots' walks can take (a table row's stretches; a
         #: window group's ``ceil((window + stretch - 1) / stretch)``); counted
         #: only where every group's decode attends through ``paged_attn``
@@ -1692,20 +1693,17 @@ class Engine:
                 self._m_index_scored.inc(scored)
             self._step_latent = (read, scored)
             if self._walk_capacity:
-                idle = self.kv.max_slots - len(slots)
-                self._step_walk = self.kv.latent_layers * (idle + int(
-                    (-(-lens // PAGED_LATENT_STRETCH)).sum()))
+                self._step_walk = self.kv.latent_layers * int(
+                    (-(-lens // PAGED_LATENT_STRETCH)).sum())
+        if self._paged_capacity or self._count_rows:
+            positions = self.kv.seq_lens[slots] - 1    # the queries'
         if self._paged_capacity:
-            # an idle slot's query sits at 0
-            at = np.zeros(self.kv.max_slots, np.int64)
-            at[slots] = self.kv.seq_lens[slots] - 1
             self._step_paged_walk = sum(
                 len(self.kv.layers[name]) * int(
                     (-(-end // PAGED_STRETCH) - first // PAGED_STRETCH).sum())
                 for name, g in self.kv.paged.items()
-                for first, end in [g.span_attended(at)])
+                for first, end in [g.span_attended(positions)])
         if self._count_rows:
-            positions = self.kv.seq_lens[slots] - 1    # the queries'
             for name, g in self.kv.paged.items():
                 read = len(self.kv.layers[name]) * int(
                     g.rows_attended(positions).sum())

@@ -215,6 +215,16 @@ def _write_rows(form, pools: tuple, li: int, at, rows: tuple,
                      for pool, r in zip(pools, form.stored(*rows)))
 
 
+def _attend_lens(seq_lens, active):
+    """The rows each slot's first query attends, its own included: ``seq_len
+    + 1`` of a slot that decodes, 0 of an inactive one — a slot nobody holds
+    attends nothing (every form returns zeros for a length of 0, and the
+    decode kernels spend a grid step of no trip on it); only its write goes
+    somewhere, to the scratch block.  The one rule of every program that
+    masks by ``active``."""
+    return jnp.where(active, seq_lens.astype(jnp.int32) + 1, 0)
+
+
 def _passes(cfg) -> int:
     """How many times ``cfg``'s stack of layers is run a token
     (``cfg.stack_passes``: ``models.ouro``); 1 where a config does not say."""
@@ -559,9 +569,11 @@ def make_decode_fn(family, cfg, *, block_size: int,
     is each slot's last sampled token, ``seq_lens`` the resident token counts
     (the new token is written at that position, then attends ``seq_len + 1``
     positions), and ``active`` masks unoccupied slots: their write lands in
-    the reserved scratch block and their logits are discarded by the engine,
-    so the program shape never depends on occupancy (a state group has no
-    scratch: an inactive slot's state is written back as it was).  ``greedy``
+    the reserved scratch block, they attend nothing (:func:`_attend_lens`: a
+    length of 0, zeros from every form) and their logits are discarded by
+    the engine, so the program shape never depends on occupancy (a state
+    group has no scratch: an inactive slot's state is written back as it
+    was).  ``greedy``
     is int32
     ``(slots,)``, the arg-max of each row of the float32 ``logits`` (the
     first of equal maxima, as ``np.argmax`` takes it): all the host needs of
@@ -585,7 +597,7 @@ def make_decode_fn(family, cfg, *, block_size: int,
         pools = dict(pools)
         bs = block_size
         positions = seq_lens.astype(jnp.int32)
-        attend_lens = jnp.where(active, positions + 1, 1)
+        attend_lens = _attend_lens(seq_lens, active)
         with jax.named_scope("kv_rows"):
             rows = {}
             for name, table in tables.items():
@@ -729,7 +741,7 @@ def make_fused_decode_fn(family, cfg, *, block_size: int,
         valid_w = active[:, None] & (
             jnp.arange(t_width)[None, :] <= draft_lens[:, None]
         )
-        attend_lens = jnp.where(active, seq_lens + 1, 1)
+        attend_lens = _attend_lens(seq_lens, active)
         with jax.named_scope("kv_rows"):
             rows = {}
             for name, table in tables.items():

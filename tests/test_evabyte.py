@@ -320,8 +320,8 @@ def test_step_log_counts_the_two_walks_of_a_decode_step(f32_model,
     the plain gather; stretches of 8 rows so that windows of 16 span
     several): a layer's ring walk runs from the stretch of the open window's
     first row to the query's, its summary walk over the closed windows'
-    rows; an idle slot walks its ring's one scratch row and no summary; the
-    capacity is the most trips the slots' walks can take."""
+    rows; the two idle slots attend nothing, a grid step of no trip each, and
+    are not counted; the capacity is the most trips the slots' walks can take."""
     from distributedtensorflow_tpu.serve import engine, model
 
     cfg, params = f32_model
@@ -330,7 +330,7 @@ def test_step_log_counts_the_two_walks_of_a_decode_step(f32_model,
                         property(lambda self: "paged_attn"))
     prompt, n_new = _prompt(24, 21, cfg), 30
     eng, _ = _serve(cfg, params, [(prompt, n_new)])
-    w, per, layers, idle = cfg.window_size, cfg.chunk_size, 3, 2
+    w, per, layers = cfg.window_size, cfg.chunk_size, 3
     decodes = [r for r in eng.step_records() if r["occupancy"]]
     assert len(decodes) == n_new - 1
     for i, r in enumerate(decodes):
@@ -338,7 +338,7 @@ def test_step_log_counts_the_two_walks_of_a_decode_step(f32_model,
         ring = -(-(pos + 1) // 8) - pos // w * w // 8
         summaries = -(-(pos // w * (w // per)) // 8)
         assert r["paged_stretches_walked"] == layers * (
-            ring + summaries + idle), (i, r)
+            ring + summaries), (i, r)
     # 3 slots x (a ring's window + a stretch - 1 = 23 rows: 3 stretches;
     # 128 positions' 32 summary rows: 4)
     assert {r["paged_stretches_capacity"] for r in decodes} == {

@@ -239,9 +239,9 @@ def test_interleaved_rotary_is_the_definition_at_an_offset(offset):
 def test_latent_decode_kernel_matches_the_plain_formulation(dtype, tol):
     """The published head shape — 32 heads over rows of 512 + 64 (640 with
     the lane padding), blocks of 16 — at lengths inside one block, across
-    blocks, across the kernel's 128-row stretches and 512-row steps; an
-    inactive slot attends the scratch block's one row.  (bfloat16 outputs of
-    size 4 to 8 step by 0.031: two steps.)"""
+    blocks, across the kernel's 128-row stretches and 512-row steps, and one
+    row of the scratch block.  (bfloat16 outputs of size 4 to 8 step by
+    0.031: two steps.)"""
     bs, nb, heads = 16, 80, 32
     case = _latent_case(1, t=5, heads=heads, rank=512, rope=64, nope=128,
                         v=128, blocks=nb, bs=bs)
@@ -496,12 +496,12 @@ def test_served_through_the_kernels_matches_the_reference(prompt_len, n_new,
     want = _reference_logits(cfg, params, prompt, tokens)
     np.testing.assert_allclose(logits, want, atol=F32_TOL, rtol=0)
     # the walk's census: iteration i's one live slot attends prompt + i + 1
-    # rows, the two idle slots the scratch block's one row; a table row of
-    # 256 tokens holds one stretch
+    # rows, the two idle slots nothing (not counted); a table row of 256
+    # tokens holds one stretch
     stretch = attention.PAGED_LATENT_STRETCH
     decodes = [r for r in eng.step_records() if r["occupancy"]]
     assert [r["latent_stretches_walked"] for r in decodes] == [
-        cfg.num_layers * (-(-(prompt_len + i + 1) // stretch) + 2)
+        cfg.num_layers * -(-(prompt_len + i + 1) // stretch)
         for i in range(len(decodes))]
     assert {r["latent_stretches_capacity"] for r in decodes} == {
         cfg.num_layers * 3 * -(-256 // stretch)}
